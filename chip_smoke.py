@@ -1,0 +1,902 @@
+"""Quickest proof that the system still starts on the chip.
+
+One process. Drives the normal entry points once at the full published
+width of gpt2-125m with seeded weights and seeded tokens (no network):
+
+- train:   ``ds.initialize`` -> ``engine.train_batch`` (bf16, ZeRO-1,
+           seq 1024, micro 32, chunked vocab loss), a few steps on a fixed
+           batch;
+- serve:   ``ds.init_inference(...).serve({...paging...})``, more
+           mixed-length requests than slots, ``submit`` -> ``run``, checked
+           against the float32 reference at the logit level;
+- kernels: every Pallas kernel compiled by Mosaic and run once at a real
+           shape against its jnp reference;
+- offload: offload configs really place state in ``pinned_host``, or
+           refuse by name;
+- several chips (``jax.device_count() >= 4``): gpt2-1.3b ZeRO-3 over all
+           of them, and tensor-parallel serving.
+
+Exits nonzero, and prints no result line, when JAX's platform is not
+``tpu`` or when any phase failed. On success the last line of stdout is
+``{"ok": true, "device": {...}}``. Figures printed on the way (step time,
+GB/s) are smoke readings, not benchmark results.
+"""
+
+import contextlib
+import dataclasses
+import faulthandler
+import json
+import math
+import os
+import sys
+import time
+import traceback
+
+# The driver allows 1200 s; leave room for interpreter exit.
+TOTAL_BUDGET_S = 1150
+
+FULL = {
+    "train": dict(preset="gpt2-125m", seq=1024, micro=32, steps=4,
+                  n_layers=None),
+    "serve": dict(preset="gpt2-125m", num_slots=4, max_len=512,
+                  page_len=128, n_requests=10, prompt_max=300, new_max=24,
+                  paging_kernel="auto", n_layers=None, logit_tol=0.1),
+    "kernels": dict(seq=1024, heads=12, batch=2, cache_len=1024,
+                    gemv_k=4096, gemv_n=16384, sparse_seq=2048,
+                    gemv_timeout_s=180),
+    "offload": dict(preset="gpt2-125m", n_layers=2, seq=256, micro=4),
+    "multichip": dict(preset="gpt2-1.3b", seq=1024, micro=4, steps=3,
+                      n_layers=None,
+                      serve=dict(preset="gpt2-1.3b", n_layers=4, num_slots=4,
+                                 max_len=512, page_len=128, n_requests=8,
+                                 prompt_max=200, new_max=16,
+                                 paging_kernel="auto", logit_tol=0.1)),
+}
+
+_T0 = time.monotonic()
+
+
+def _say(msg):
+    print(f"[chip_smoke +{time.monotonic() - _T0:6.1f}s] {msg}", flush=True)
+
+
+def _budget_left():
+    return max(1.0, TOTAL_BUDGET_S - (time.monotonic() - _T0))
+
+
+@contextlib.contextmanager
+def _deadline(seconds):
+    """Fail — dump every thread's stack and ``_exit(1)`` — if the body
+    outlives ``seconds`` (or the run's total budget). A kernel that hangs
+    the chip blocks in native code, where no Python exception reaches."""
+    faulthandler.dump_traceback_later(min(seconds, _budget_left()),
+                                      exit=True)
+    try:
+        yield
+    finally:
+        faulthandler.dump_traceback_later(_budget_left(), exit=True)
+
+
+def _check(cond, msg):
+    if not cond:
+        raise AssertionError(msg)
+
+
+def _mosaic(rec, what):
+    """A dispatch record says a Pallas kernel ran compiled, not
+    interpreted and not swapped for a jnp path."""
+    _check(rec, f"{what}: no dispatch record — the kernel never traced")
+    _check(rec.get("interpret") is False,
+           f"{what}: ran in interpret mode ({rec})")
+    _check(rec.get("impl", "kernel") == "kernel",
+           f"{what}: dispatched {rec.get('impl')!r}, not the kernel ({rec})")
+
+
+def _close(name, got, want, tol):
+    """``got`` within ``tol`` of ``want``, relative to ``want``'s scale."""
+    import numpy as np
+    want = np.asarray(want, np.float32)
+    got = np.asarray(got, np.float32)
+    _check(got.shape == want.shape,
+           f"{name}: shape {got.shape} != reference {want.shape}")
+    _check(np.isfinite(got).all(), f"{name}: non-finite values")
+    scale = max(float(np.max(np.abs(want))), 1e-6)
+    err = float(np.max(np.abs(got - want))) / scale
+    _check(err <= tol, f"{name}: max err {err:.3g} of scale exceeds {tol}")
+    return err
+
+
+def _gpt(preset, n_layers, **kw):
+    from deepspeed_tpu.models import GPT, GPT2_PRESETS
+    cfg = GPT2_PRESETS[preset]
+    if n_layers is not None:
+        cfg = dataclasses.replace(cfg, n_layers=n_layers)
+    return GPT(dataclasses.replace(cfg, **kw))
+
+
+def _loss_fn(model, params, batch, rng, train):
+    """Next-token loss with the chunked vocab head: the model runs on
+    ``seq`` tokens (128-aligned, flash-eligible) and the labels are the
+    same window shifted by one, so the chunk divides and [B, S, V] logits
+    never materialize."""
+    from deepspeed_tpu.models import gpt_chunked_loss_fn
+    ids = batch["input_ids"]
+    h, wte = model.apply(params, ids[:, :-1], deterministic=not train,
+                         return_hidden=True)
+    return gpt_chunked_loss_fn(h, wte, ids[:, 1:], chunk=128)
+
+
+def _trainer(preset, n_layers, seq, micro, zero, param_dtype, **config):
+    """``ds.initialize`` on a seeded model and one fixed seeded batch of
+    ``seq + 1`` tokens per row (see ``_loss_fn``): (engine, batch, vocab).
+    The recipe is ``bench.py:_train_bench``'s — bf16, full remat, scanned
+    layers, Adam 1e-4, no accumulation."""
+    import numpy as np
+    import jax
+    import jax.numpy as jnp
+    import deepspeed_tpu as ds
+    model = _gpt(preset, n_layers, dtype=jnp.bfloat16,
+                 param_dtype=param_dtype, scan_layers=True, remat="full",
+                 max_seq_len=seq)
+    rows = micro * jax.device_count()
+    vocab = model.config.vocab_size
+    batch = {"input_ids": np.random.default_rng(0).integers(
+        0, vocab, size=(rows, seq + 1), dtype=np.int32)}
+    engine, _, _, _ = ds.initialize(
+        model=model, loss_fn=_loss_fn, rng=jax.random.PRNGKey(0),
+        sample_batch={"input_ids": batch["input_ids"][:1, :-1]},
+        config={"train_batch_size": rows,
+                "train_micro_batch_size_per_gpu": micro,
+                "gradient_accumulation_steps": 1,
+                "optimizer": {"type": "Adam", "params": {"lr": 1e-4}},
+                "bf16": {"enabled": True},
+                "zero_optimization": zero,
+                "steps_per_print": 10_000, **config})
+    return engine, batch, vocab
+
+
+def _seeded_params(model):
+    import jax
+    import jax.numpy as jnp
+    import flax.core.meta as flax_meta
+    return jax.jit(lambda r: flax_meta.unbox(model.init(
+        r, jnp.ones((1, 8), jnp.int32)))["params"])(jax.random.PRNGKey(0))
+
+
+def _train_steps(engine, batch, steps):
+    import jax
+    losses, times = [], []
+    for _ in range(steps):
+        t0 = time.monotonic()
+        loss = engine.train_batch(batch)
+        jax.block_until_ready((loss, engine.params))
+        times.append(time.monotonic() - t0)
+        losses.append(float(loss))
+    return losses, times
+
+
+def _check_losses(losses, vocab):
+    _check(all(math.isfinite(x) for x in losses),
+           f"non-finite loss: {losses}")
+    _check(abs(losses[0] - math.log(vocab)) < 0.7,
+           f"first loss {losses[0]:.3f} not near ln({vocab}) = "
+           f"{math.log(vocab):.3f}")
+    _check(losses[-1] < losses[0],
+           f"loss did not fall on a fixed batch: {losses}")
+
+
+# ---------------------------------------------------------------------------
+# train
+# ---------------------------------------------------------------------------
+
+def phase_train(preset, seq, micro, steps, n_layers):
+    import jax.numpy as jnp
+    from deepspeed_tpu.ops.pallas import tuning
+
+    tuning.clear_last_dispatch()
+    engine, batch, vocab = _trainer(preset, n_layers, seq, micro,
+                                    {"stage": 1}, jnp.float32)
+    losses, times = _train_steps(engine, batch, steps)
+    _say(f"train {preset}: losses {[round(x, 3) for x in losses]}; step "
+         f"times s {[round(t, 3) for t in times]} (first includes compile)")
+    _check_losses(losses, vocab)
+    choice = tuning.last_dispatch("attention").get("backend")
+    flash = tuning.last_dispatch("flash_attention")
+    fwd = [s for s in flash if s.startswith("fwd_")]
+    bwd = [s for s in flash if s.startswith("bwd_")]
+    _check(choice and choice["backend"] == "pallas",
+           f"training attention did not dispatch the flash kernel: {choice}")
+    _check(fwd and bwd, f"flash fwd+bwd structures not both traced: {flash}")
+    for s in fwd + bwd:
+        _mosaic(flash[s], f"flash_attention/{s}")
+    _say(f"train: flash structures {fwd + bwd}, blocks "
+         f"{ {s: flash[s].get('block_q') for s in fwd + bwd} }")
+    engine.destroy()
+
+
+# ---------------------------------------------------------------------------
+# serve
+# ---------------------------------------------------------------------------
+
+def _requests(rng, n, vocab, prompt_max, new_max):
+    """Mixed-length (prompt, max_new) pairs; two share a long prefix so
+    the prefix cache is exercised."""
+    reqs = []
+    for _ in range(n):
+        plen = int(rng.integers(5, prompt_max))
+        reqs.append((rng.integers(0, vocab, size=plen, dtype="int32"),
+                     int(rng.integers(4, new_max))))
+    shared = reqs[0][0]
+    if len(shared) > 4:
+        reqs[1] = (shared.copy(), reqs[1][1])
+    return reqs
+
+
+def _serve_and_check(eng, module, params, reqs, num_slots, max_len,
+                     page_len, paging_kernel, logit_tol, label):
+    import numpy as np
+    import jax
+    import jax.numpy as jnp
+    from deepspeed_tpu.ops.pallas import tuning
+
+    tuning.clear_last_dispatch()
+    srv = eng.serve({"num_slots": num_slots, "max_len": max_len,
+                     "paging": {"page_len": page_len,
+                                "kernel": paging_kernel}})
+    stream = []
+    handles = [srv.submit(p, max_new_tokens=m,
+                          on_token=lambda r, tok, _s=stream:
+                          _s.append(r.request_id))
+               for p, m in reqs]
+    t0 = time.monotonic()
+    srv.run()
+    wall = time.monotonic() - t0
+    bad = [(h.request_id, h.status) for h in handles
+           if h.status != "finished"]
+    _check(not bad, f"{label}: requests not finished (shed/timeout): {bad}")
+    for h, (_, m) in zip(handles, reqs):
+        _check(len(h.output_tokens) == m,
+               f"{label}: request {h.request_id} produced "
+               f"{len(h.output_tokens)} of {m} tokens")
+    # continuous batching: some request's tokens are split by another's
+    runs = sum(1 for a, b in zip(stream, stream[1:]) if a != b) + 1
+    _check(runs > len(handles),
+           f"{label}: streamed tokens never interleaved ({runs} runs over "
+           f"{len(handles)} requests)")
+    path = tuning.last_dispatch("paged_decode").get("path")
+    _mosaic(path, f"{label} paged decode path")
+    kern = tuning.last_dispatch("paged_attention").get(f"page{page_len}")
+    _mosaic(kern, f"{label} paged_attention kernel")
+    _say(f"{label}: {len(handles)} requests over {num_slots} slots finished "
+         f"in {wall:.2f}s (compiles included); paged kernel {kern}")
+    srv.close()
+
+    # logit-level check against the float32 reference: teacher-force every
+    # served sequence through a plain float32 forward of the same weights;
+    # each served token must sit within ``logit_tol`` of that position's
+    # best reference logit (a random-init greedy chain has near-ties, so
+    # the token may differ from the reference argmax, but not by more).
+    ref_model = type(module)(dataclasses.replace(
+        module.config, dtype=jnp.float32, param_dtype=jnp.float32,
+        attn_backend="reference"))
+    params32 = jax.tree.map(lambda x: jnp.asarray(x, jnp.float32), params)
+    width = max(len(p) + m for p, m in reqs)
+    full = np.zeros((len(reqs), width), np.int32)
+    for i, (h, (p, m)) in enumerate(zip(handles, reqs)):
+        full[i, :len(p) + m] = np.concatenate([p, h.output_tokens])
+    ref_logits = np.asarray(jax.jit(
+        lambda prm, ids: ref_model.apply({"params": prm}, ids))(
+            params32, jnp.asarray(full)))
+    # the tolerance is stated in units of the reference logits' spread:
+    # a wrong attention path moves a token's logit by whole sigmas, bf16
+    # rounding by hundredths of one
+    sigma = float(ref_logits.std())
+    logit_tol = logit_tol * sigma
+    worst, exact, total = 0.0, 0, 0
+    for i, (h, (p, m)) in enumerate(zip(handles, reqs)):
+        for j, tok in enumerate(h.output_tokens):
+            row = ref_logits[i, len(p) + j - 1]
+            gap = float(row.max() - row[tok])
+            worst = max(worst, gap)
+            exact += int(gap == 0.0)
+            total += 1
+    _say(f"{label}: {exact}/{total} served tokens are the float32 argmax; "
+         f"largest logit gap {worst:.4f} = {worst / sigma:.3f} sigma "
+         f"(tolerance {logit_tol:.4f} = {logit_tol / sigma:.2f} sigma)")
+    _check(worst <= logit_tol,
+           f"{label}: a served token is {worst:.4f} below the float32 "
+           f"reference's best logit (tolerance {logit_tol:.4f})")
+
+    # agreement with generate() (the contiguous-cache one-shot path),
+    # reported as a count; a mismatch is a failure only when the two
+    # candidates' reference logits differ by more than the tolerance
+    lens = np.asarray([len(p) for p, _ in reqs], np.int32)
+    pw = int(lens.max())
+    padded = np.zeros((len(reqs), pw), np.int32)
+    for i, (p, _) in enumerate(reqs):
+        padded[i, :len(p)] = p
+    new_max = max(m for _, m in reqs)
+    gen = np.asarray(eng.generate(padded, max_new_tokens=new_max,
+                                  prompt_lengths=lens))
+    agree = 0
+    for i, (h, (p, m)) in enumerate(zip(handles, reqs)):
+        g = gen[i, len(p):len(p) + m]
+        s = np.asarray(h.output_tokens)
+        diff = np.nonzero(g != s)[0]
+        if diff.size == 0:
+            agree += 1
+            continue
+        j = int(diff[0])
+        row = ref_logits[i, len(p) + j - 1]
+        gap = abs(float(row[g[j]] - row[s[j]]))
+        _check(gap <= logit_tol,
+               f"{label}: request {i} diverges from generate() at token {j} "
+               f"by a reference logit gap of {gap:.4f} > {logit_tol:.4f}")
+    _say(f"{label}: {agree}/{len(reqs)} requests token-identical to "
+         f"generate(); the rest diverge at a near-tie inside the tolerance")
+
+
+def phase_serve(preset, num_slots, max_len, page_len, n_requests,
+                prompt_max, new_max, paging_kernel, n_layers, logit_tol):
+    import numpy as np
+    import jax.numpy as jnp
+    import deepspeed_tpu as ds
+
+    model = _gpt(preset, n_layers, dtype=jnp.bfloat16,
+                 param_dtype=jnp.bfloat16, scan_layers=True,
+                 max_seq_len=max(max_len, 128))
+    params = _seeded_params(model)
+    eng = ds.init_inference(model, params=params, dtype=jnp.bfloat16)
+    reqs = _requests(np.random.default_rng(1), n_requests,
+                     model.config.vocab_size, prompt_max, new_max)
+    _check(n_requests > num_slots, "serve needs more requests than slots")
+    _serve_and_check(eng, model, params, reqs, num_slots, max_len, page_len,
+                     paging_kernel, logit_tol, f"serve {preset}")
+
+
+# ---------------------------------------------------------------------------
+# kernels
+# ---------------------------------------------------------------------------
+
+def _kernel_checks(seq, heads, batch, cache_len, gemv_k, gemv_n, sparse_seq,
+                   gemv_timeout_s):
+    """(name, fn) pairs; each fn runs one kernel against its reference."""
+    import numpy as np
+    import jax
+    import jax.numpy as jnp
+    import optax
+    from deepspeed_tpu.ops.pallas import (decode_attention, flash_attention,
+                                          fused_adamw, fused_lamb,
+                                          fused_layer_norm, paged_attention,
+                                          quantize, dequantize, tuning)
+    from deepspeed_tpu.ops.pallas import wo_int8_matmul as wo
+    from deepspeed_tpu.ops.transformer.attention import (
+        _reference_attention, attention)
+
+    rng = np.random.default_rng(0)
+
+    def normal(shape, dtype=jnp.bfloat16, scale=1.0):
+        return jnp.asarray(rng.standard_normal(shape) * scale, dtype)
+
+    def flash(d, dropout):
+        def run():
+            q, k, v = (normal((batch, seq, heads, d)) for _ in range(3))
+            kw = (dict(dropout_rate=0.1, dropout_rng=jax.random.PRNGKey(3))
+                  if dropout else {})
+
+            def kern(q, k, v):
+                return flash_attention(q, k, v, causal=True, **kw)
+
+            def ref(q, k, v):
+                return _reference_attention(
+                    q.astype(jnp.float32), k.astype(jnp.float32),
+                    v.astype(jnp.float32), causal=True,
+                    deterministic=not dropout, **kw)
+
+            def loss(f):
+                return lambda q, k, v: jnp.sum(
+                    f(q, k, v).astype(jnp.float32) ** 2)
+
+            tuning.clear_last_dispatch()
+            fwd = jax.jit(kern)
+            _check("tpu_custom_call" in fwd.lower(q, k, v).compile()
+                   .as_text(),
+                   "flash forward compiled without a Mosaic custom call")
+            _close("fwd", fwd(q, k, v), jax.jit(ref)(q, k, v), 0.03)
+            got = jax.jit(jax.grad(loss(kern), argnums=(0, 1, 2)))(q, k, v)
+            want = jax.jit(jax.grad(loss(ref), argnums=(0, 1, 2)))(q, k, v)
+            for name, g, w in zip("qkv", got, want):
+                _close(f"d{name}", g, w, 0.05)
+            rec = tuning.last_dispatch("flash_attention")
+            for s in rec:
+                _mosaic(rec[s], f"flash_attention/{s}")
+            return sorted(rec)
+        return run
+
+    # the decode kernels' head block (_common.pick_head_block) is 8 for 16
+    # heads, 4 for GPT-2's 12, and 2 / 1 for the 6 / 3 heads one device
+    # holds at mp_size 2 / 4: each size is its own Mosaic tiling, and a
+    # refusal there is a SIGABRT
+    def decode(heads, head_block):
+        def run():
+            d = 64
+            q = normal((batch * 2, 1, heads, d))
+            k, v = (normal((batch * 2, heads, d, cache_len))
+                    for _ in range(2))
+            lengths = jnp.asarray(
+                rng.integers(1, cache_len, size=batch * 2), jnp.int32)
+            from deepspeed_tpu.ops.pallas.decode_attention import \
+                _decode_dense
+            tuning.clear_last_dispatch()
+            got = jax.jit(decode_attention)(q, k, v, lengths)
+            want = _decode_dense(
+                q[:, 0].astype(jnp.float32), k, v, lengths,
+                jnp.zeros((heads,), jnp.float32), scale=d ** -0.5,
+                alibi=False)
+            _close("decode", got[:, 0], want, 0.03)
+            rec = tuning.last_dispatch("decode_attention").get("dma")
+            _mosaic(rec, "decode_attention")
+            _check(rec["head_block"] == head_block,
+                   f"{heads} heads ran at head block {rec['head_block']}, "
+                   f"not {head_block}")
+        return run
+
+    def paged(heads, head_block, int8):
+        def run():
+            d, page_len, slots, max_pages = 64, 128, 4, cache_len // 128
+            num_pages = slots * max_pages + 1
+            q = normal((slots, 1, heads, d))
+            kp, vp = (normal((num_pages, heads, d, page_len))
+                      for _ in range(2))
+            kn, vn = (normal((slots, heads, d, 1)) for _ in range(2))
+            ptab = jnp.asarray(
+                1 + rng.permutation(num_pages - 1)[:slots * max_pages]
+                .reshape(slots, max_pages), jnp.int32)
+            lengths = jnp.asarray(
+                [0, 1, cache_len // 2 + 3, cache_len - 1][:slots], jnp.int32)
+            scales = {}
+            if int8:
+                from deepspeed_tpu.inference.cache import _quantize_kv
+                (kp, ks), (vp, vs) = _quantize_kv(kp), _quantize_kv(vp)
+                scales = dict(k_scale=ks, v_scale=vs)
+            tuning.clear_last_dispatch()
+            got = jax.jit(lambda *a: paged_attention(*a, **scales))(
+                q, kp, vp, ptab, lengths, kn, vn)
+            rec = tuning.last_dispatch("paged_attention").get(
+                f"page{page_len}")
+            _mosaic(rec, "paged_attention")
+            _check(rec["head_block"] == head_block,
+                   f"{heads} heads ran at head block {rec['head_block']}, "
+                   f"not {head_block}")
+            want = paged_attention(q, kp, vp, ptab, lengths, kn, vn,
+                                   impl="dense", **scales)
+            _close("paged", got, want, 0.03)
+        return run
+
+    def adam_like(make_fused, make_optax, tol):
+        def run():
+            params = {"w": normal((768, 3072), jnp.float32, 0.02),
+                      "odd": normal((50257, 3), jnp.float32, 0.02),
+                      "b": normal((768,), jnp.float32, 0.02)}
+            grads = jax.tree.map(
+                lambda p: normal(p.shape, jnp.float32, 0.01), params)
+            outs = []
+            for tx in (make_fused(), make_optax()):
+                @jax.jit
+                def two_steps(params, grads, tx=tx):
+                    state = tx.init(params)
+                    for _ in range(2):
+                        upd, state = tx.update(grads, state, params)
+                        params = optax.apply_updates(params, upd)
+                    return params
+                outs.append(two_steps(params, grads))
+            for name in params:
+                _close(name, outs[0][name] - params[name],
+                       outs[1][name] - params[name], tol)
+        return run
+
+    def layernorm():
+        for rows, d in ((4096, 768), (512, 4096)):
+            x = normal((rows, d), jnp.bfloat16)
+            g, b = normal((d,), jnp.float32), normal((d,), jnp.float32)
+
+            def ref(x, g, b):
+                x32 = x.astype(jnp.float32)
+                mu = x32.mean(-1, keepdims=True)
+                var = ((x32 - mu) ** 2).mean(-1, keepdims=True)
+                return ((x32 - mu) * jax.lax.rsqrt(var + 1e-5) * g + b
+                        ).astype(x.dtype)
+
+            _close(f"ln{d}", jax.jit(fused_layer_norm)(x, g, b),
+                   ref(x, g, b), 0.02)
+            loss = lambda f: (lambda x, g, b: jnp.sum(
+                f(x, g, b).astype(jnp.float32) ** 2))
+            got = jax.jit(jax.grad(loss(fused_layer_norm),
+                                   argnums=(0, 1, 2)))(x, g, b)
+            want = jax.jit(jax.grad(loss(ref), argnums=(0, 1, 2)))(x, g, b)
+            for name, a, w in zip(("dx", "dgamma", "dbeta"), got, want):
+                _close(f"ln{d}/{name}", a, w, 0.03)
+
+    def quantizer():
+        x = normal((64, 4096), jnp.float32)
+        q, s = jax.jit(lambda x: quantize(x, groups=64))(x)
+        _close("sym", dequantize(q, s), x, 1.0 / 127)
+        q, s, zp = jax.jit(
+            lambda x: quantize(x, groups=64, asymmetric=True))(x)
+        _close("asym", dequantize(q, s, zp), x, 1.0 / 127)
+        # stochastic rounding: the pltpu.prng_* kernel body. Every code is
+        # floor or floor+1 of the scaled value, the rounding is unbiased,
+        # and it is not plain round-to-nearest.
+        q, s = jax.jit(
+            lambda x: quantize(x, groups=64, stochastic=True, seed=7))(x)
+        scaled = np.asarray(x) / np.asarray(s)[:, None]
+        qn = np.asarray(q, np.float32)
+        _check(((qn == np.floor(scaled)) | (qn == np.floor(scaled) + 1)
+                | (np.abs(scaled) > 127)).all(),
+               "stochastic codes are not floor/floor+1 of the scaled input")
+        bias = float(np.mean(qn - scaled))
+        _check(abs(bias) < 0.01, f"stochastic rounding is biased: {bias}")
+        _check((qn != np.round(scaled)).mean() > 0.05,
+               "stochastic rounding equals round-to-nearest")
+
+    def int8_weight(n, k=gemv_k):
+        x1 = normal((1, k))
+        xm = normal((256, k))
+        q = jnp.asarray(rng.integers(-127, 128, size=(k, n),
+                                     dtype=np.int8))
+        s = jnp.asarray(np.abs(rng.standard_normal((1, n))) * 0.01,
+                        jnp.float32)
+        ref = lambda x: jnp.dot(
+            x.astype(jnp.float32), q.astype(jnp.float32) * s)
+        return x1, xm, q, s, ref
+
+    def _gbps(fn, *args, nbytes):
+        jax.block_until_ready(fn(*args))
+        t0 = time.monotonic()
+        for _ in range(20):
+            out = fn(*args)
+        jax.block_until_ready(out)
+        return nbytes / ((time.monotonic() - t0) / 20) / 1e9
+
+    def int8_mxu():
+        x1, xm, q, s, ref = int8_weight(gemv_n)
+        f = jax.jit(lambda x: wo.wo_int8_matmul(x, q, s))
+        tuning.clear_last_dispatch()
+        _close("m=1", f(x1), ref(x1), 0.02)
+        _close("m=256", f(xm), ref(xm), 0.02)
+        rec = tuning.last_dispatch("wo_int8_matmul")
+        _check(rec["m1"]["impl"] == "mxu" and rec["mN"]["impl"] == "mxu",
+               f"int8 matmul did not take the MXU kernel: {rec}")
+        return (f"m=1 weight read {_gbps(f, x1, nbytes=q.size):.0f} GB/s "
+                "(smoke reading)")
+
+    def int8_gemv():
+        # the comment on the kernel records a variant that hung the
+        # backend: a hang here must fail the phase, not the machine
+        with _deadline(gemv_timeout_s):
+            x1, _, q, s, ref = int8_weight(gemv_n)
+            f = jax.jit(lambda x: wo._wo_int8_gemv(
+                x, q, s.reshape(-1), wo.GEMV_BLOCK_N, wo.GEMV_BLOCK_K,
+                jnp.bfloat16))
+            _close("gemv", f(x1), ref(x1), 0.02)
+            return (f"m=1 weight read {_gbps(f, x1, nbytes=q.size):.0f} GB/s "
+                    "(smoke reading)")
+
+    def int8_ragged_vocab():
+        # vocab 50257 has no 128-aligned tiling: the dispatch must SAY it
+        # took the jnp dequant path, never take it quietly
+        x1, _, q, s, ref = int8_weight(50257, k=min(gemv_k, 768))
+        tuning.clear_last_dispatch()
+        _close("vocab", jax.jit(
+            lambda x: wo.wo_int8_matmul(x, q, s))(x1), ref(x1), 0.02)
+        rec = tuning.last_dispatch("wo_int8_matmul")["m1"]
+        _check(rec["impl"] == "dense" and rec["reason"],
+               f"ragged-vocab int8 matmul left no dense+reason record: {rec}")
+
+    def head_dim_80():
+        # gpt2-2.7b: auto keeps the reference path and says why
+        q, k, v = (normal((1, 256, 4, 80)) for _ in range(3))
+        tuning.clear_last_dispatch()
+        out = jax.jit(lambda q, k, v: attention(
+            q, k, v, causal=True, seq_parallel="none"))(q, k, v)
+        _check(bool(jnp.isfinite(out.astype(jnp.float32)).all()),
+               "d=80 attention produced non-finite values")
+        rec = tuning.last_dispatch("attention")["backend"]
+        _check(rec["backend"] == "reference"
+               and "head_dim 80" in (rec["reason"] or ""),
+               f"d=80 auto dispatch not recorded as reference+reason: {rec}")
+
+    def block_sparse():
+        from deepspeed_tpu.ops.sparse_attention import (
+            BSLongformerSparsityConfig, sparse_attention)
+        h = 8
+        cfg = BSLongformerSparsityConfig(
+            num_heads=h, block=16, num_sliding_window_blocks=8,
+            global_block_indices=[0])
+        q, k, v = (normal((batch, sparse_seq, h, 64)) for _ in range(3))
+
+        def loss(backend):
+            return lambda q, k, v: jnp.sum(sparse_attention(
+                q.astype(jnp.float32) if backend == "dense" else q,
+                k.astype(jnp.float32) if backend == "dense" else k,
+                v.astype(jnp.float32) if backend == "dense" else v,
+                cfg, backend=backend).astype(jnp.float32) ** 2)
+
+        fwd = jax.jit(lambda q, k, v: sparse_attention(
+            q, k, v, cfg, backend="pallas"))
+        _check("tpu_custom_call" in fwd.lower(q, k, v).compile().as_text(),
+               "block-sparse forward has no Mosaic call")
+        want = jax.jit(lambda q, k, v: sparse_attention(
+            q.astype(jnp.float32), k.astype(jnp.float32),
+            v.astype(jnp.float32), cfg, backend="dense"))(q, k, v)
+        _close("fwd", fwd(q, k, v), want, 0.03)
+        got = jax.jit(jax.grad(loss("pallas"), argnums=(0, 1, 2)))(q, k, v)
+        ref = jax.jit(jax.grad(loss("dense"), argnums=(0, 1, 2)))(q, k, v)
+        for name, g, w in zip("qkv", got, ref):
+            _close(f"d{name}", g, w, 0.05)
+
+    return [
+        ("flash d=64", flash(64, False)),
+        ("flash d=64 dropout", flash(64, True)),
+        ("flash d=128", flash(128, False)),
+        ("flash d=128 dropout", flash(128, True)),
+        ("attention d=80 (auto -> reference)", head_dim_80),
+        (f"decode_attention {heads} heads", decode(heads, 4)),
+        ("decode_attention 16 heads (head block 8)", decode(16, 8)),
+        ("decode_attention 6 heads (head block 2)", decode(6, 2)),
+        ("decode_attention 3 heads (head block 1)", decode(3, 1)),
+        (f"paged_attention bf16 pages {heads} heads",
+         paged(heads, 4, False)),
+        ("paged_attention bf16 pages 16 heads (head block 8)",
+         paged(16, 8, False)),
+        ("paged_attention bf16 pages 6 heads (head block 2)",
+         paged(6, 2, False)),
+        ("paged_attention bf16 pages 3 heads (head block 1)",
+         paged(3, 1, False)),
+        (f"paged_attention int8 pages {heads} heads", paged(heads, 4, True)),
+        ("paged_attention int8 pages 3 heads (head block 1)",
+         paged(3, 1, True)),
+        ("fused_adam", adam_like(lambda: fused_adamw(1e-3, weight_decay=0.01),
+                                 lambda: optax.adamw(1e-3, weight_decay=0.01),
+                                 1e-4)),
+        ("fused_lamb", adam_like(lambda: fused_lamb(1e-3, weight_decay=0.01),
+                                 lambda: optax.lamb(1e-3, weight_decay=0.01),
+                                 1e-3)),
+        ("layernorm", layernorm),
+        ("quantizer (sym, asym, prng stochastic)", quantizer),
+        ("wo_int8_matmul MXU", int8_mxu),
+        ("wo_int8_matmul m=1 GEMV", int8_gemv),
+        ("wo_int8_matmul vocab 50257 (-> recorded dense)",
+         int8_ragged_vocab),
+        ("block_sparse fwd+bwd", block_sparse),
+    ]
+
+
+def phase_kernels(**sizes):
+    """Every kernel runs; the phase fails with the full list of refusals
+    (one chip call should show them all)."""
+    failed = []
+    for name, fn in _kernel_checks(**sizes):
+        t0 = time.monotonic()
+        try:
+            note = fn()
+        except Exception:
+            failed.append(name)
+            _say(f"kernel {name}: FAILED\n{traceback.format_exc()}")
+        else:
+            _say(f"kernel {name}: ok in {time.monotonic() - t0:.1f}s"
+                 + (f" — {note}" if note else ""))
+    _check(not failed, f"kernels failed: {failed}")
+
+
+# ---------------------------------------------------------------------------
+# offload
+# ---------------------------------------------------------------------------
+
+def phase_offload(preset, n_layers, seq, micro):
+    """An offload config must move state to host memory, or refuse by
+    name: streamed host Adam (``offload_optimizer``) trains with its
+    moments in ``pinned_host``; ZeRO-Inference (``init_inference(
+    offload_params=True)``) decodes with its block kernels there; the
+    training-side ``offload_param`` raises its named error on TPU."""
+    import numpy as np
+    import jax
+    import jax.numpy as jnp
+    import deepspeed_tpu as ds
+    from deepspeed_tpu.runtime.engine import ParamOffloadUnsupportedError
+
+    def build(zero):
+        return _trainer(preset, n_layers, seq, micro, zero, jnp.bfloat16)
+
+    host_adam = {"stage": 2, "offload_optimizer": {"device": "cpu"}}
+    engine, batch, vocab = build(host_adam)
+    losses, _ = _train_steps(engine, batch, 3)
+    _check_losses(losses, vocab)
+    kinds = {x.sharding.memory_kind for x in jax.tree.leaves(
+        engine.optimizer_state) if getattr(x, "ndim", 0) >= 1}
+    _check(kinds == {"pinned_host"},
+           f"offload_optimizer moments not in pinned_host: {kinds}")
+    _say(f"offload_optimizer: losses {[round(x, 3) for x in losses]}, "
+         f"moments in {sorted(kinds)}")
+    engine.destroy()
+
+    try:
+        build({**host_adam, "offload_param": {"device": "cpu"}})
+    except ParamOffloadUnsupportedError as e:
+        _say(f"offload_param: refused by name — {type(e).__name__}")
+    else:
+        raise AssertionError(
+            "offload_param built an engine on TPU; its train step aborts "
+            "XLA (ROADMAP) and must be refused by name")
+
+    model = _gpt(preset, n_layers, dtype=jnp.bfloat16,
+                 param_dtype=jnp.bfloat16, scan_layers=True,
+                 max_seq_len=seq)
+    params = _seeded_params(model)
+    prompt = np.random.default_rng(3).integers(
+        0, model.config.vocab_size, size=(1, 16), dtype=np.int32)
+    resident = ds.init_inference(model, params=params, dtype=jnp.bfloat16)
+    streamed = ds.init_inference(model, params=params, dtype=jnp.bfloat16,
+                                 offload_params=True)
+    kinds = {x.sharding.memory_kind
+             for x in jax.tree.leaves(streamed.params["h"]) if x.ndim >= 3}
+    _check(kinds == {"pinned_host"},
+           f"ZeRO-Inference block kernels not in pinned_host: {kinds}")
+    want = np.asarray(resident.generate(prompt, max_new_tokens=8))
+    got = np.asarray(streamed.generate(prompt, max_new_tokens=8))
+    _check((got == want).all(),
+           f"host-streamed decode {got[0, -8:]} != resident {want[0, -8:]}")
+    _say(f"ZeRO-Inference: block kernels in {sorted(kinds)}, 8 tokens "
+         "identical to the resident engine")
+
+
+# ---------------------------------------------------------------------------
+# several chips
+# ---------------------------------------------------------------------------
+
+def phase_multichip(preset, seq, micro, steps, n_layers, serve):
+    import numpy as np
+    import jax
+    import jax.numpy as jnp
+    import deepspeed_tpu as ds
+    from deepspeed_tpu.ops.pallas import tuning
+
+    n = jax.device_count()
+    # ZeRO-3 partitions parameters over the mesh's ``fsdp`` axis (docs/
+    # config.md); on ``{data: -1}`` they would stay replicated. fp32 master
+    # + Adam moments + bf16 compute copies of 1.3B are ~21 GB: the steps
+    # only fit a 16 GB chip if the state really is partitioned.
+    tuning.clear_last_dispatch()
+    engine, batch, vocab = _trainer(
+        preset, n_layers, seq, micro, {"stage": 3}, jnp.float32,
+        mesh={"data": 1, "fsdp": n})
+    losses, times = _train_steps(engine, batch, steps)
+    _say(f"{n}-chip train {preset} ZeRO-3: losses "
+         f"{[round(x, 3) for x in losses]}; step times s "
+         f"{[round(t, 3) for t in times]} (first includes compile)")
+    _check_losses(losses, vocab)
+    flash = tuning.last_dispatch("flash_attention")
+    _check(flash, "multi-chip training never traced the flash kernel")
+    for s in flash:
+        _mosaic(flash[s], f"flash_attention/{s}")
+    # the kernel saw ONE chip's share of the batch, not the gathered whole
+    seen = tuning.last_dispatch("attention")["backend"]
+    _check(seen["batch"] == micro,
+           f"flash kernel ran on batch {seen['batch']}, not one chip's "
+           f"micro batch {micro}: {seen}")
+    # every optimizer leaf, and every param leaf above the ZeRO-3
+    # persistence threshold (small params stay replicated by design),
+    # spans all n devices with 1/n of its bytes on each
+    persist = engine.config.zero_optimization.stage3_param_persistence_threshold
+    for label, tree, floor in (("param", engine.params, persist),
+                               ("optimizer", engine.optimizer_state, 0)):
+        total = 0
+        for leaf in jax.tree.leaves(tree):
+            if getattr(leaf, "ndim", 0) < 1:
+                continue
+            total += leaf.nbytes
+            devs = {s.device for s in leaf.addressable_shards}
+            _check(len(devs) == n,
+                   f"{label} leaf {leaf.shape} lives on {len(devs)} of {n} "
+                   "devices")
+            shard = leaf.addressable_shards[0].data
+            _check(leaf.size <= floor or shard.nbytes * n == leaf.nbytes,
+                   f"{label} leaf {leaf.shape} is not split {n} ways: "
+                   f"{leaf.sharding}")
+        _say(f"{label} state: {total / 1e9:.2f} GB, every leaf"
+             + (f" above {floor} elements" if floor else "")
+             + f" split {n} ways")
+    for d in jax.devices():
+        stats = d.memory_stats() or {}
+        _say(f"  {d}: bytes_in_use {stats.get('bytes_in_use', 0) / 1e9:.2f} "
+             f"GB, peak {stats.get('peak_bytes_in_use', 0) / 1e9:.2f} GB")
+    engine.destroy()
+    del engine
+
+    # tensor-parallel serving over the same chips
+    smodel = _gpt(serve["preset"], serve["n_layers"], dtype=jnp.bfloat16,
+                  param_dtype=jnp.bfloat16, scan_layers=True,
+                  max_seq_len=max(serve["max_len"], 128))
+    params = _seeded_params(smodel)
+    eng = ds.init_inference(smodel, params=params, dtype=jnp.bfloat16,
+                            mp_size=n)
+    qkv = eng.params["h"]["attn"]["qkv"]["kernel"]
+    _check(len({s.device for s in qkv.addressable_shards}) == n
+           and qkv.addressable_shards[0].data.nbytes * n == qkv.nbytes,
+           f"mp_size={n} left the qkv kernel unsplit: {qkv.sharding}")
+    reqs = _requests(np.random.default_rng(2), serve["n_requests"],
+                     smodel.config.vocab_size, serve["prompt_max"],
+                     serve["new_max"])
+    _serve_and_check(eng, smodel, params, reqs, serve["num_slots"],
+                     serve["max_len"], serve["page_len"],
+                     serve["paging_kernel"], serve["logit_tol"],
+                     f"{n}-chip serve {serve['preset']} mp_size={n}")
+    rec = tuning.last_dispatch("paged_attention").get(
+        f"page{serve['page_len']}")
+    _check(rec and rec.get("model_shards") == n,
+           f"paged kernel was not mapped over the model axis: {rec}")
+
+
+# ---------------------------------------------------------------------------
+
+def main():
+    faulthandler.dump_traceback_later(_budget_left(), exit=True)
+    import jax
+    dev = jax.devices()[0]
+    device = {"platform": dev.platform, "kind": dev.device_kind,
+              "count": len(jax.devices())}
+    _say(f"jax {jax.__version__} platform={device['platform']} "
+         f"device_kind={device['kind']!r} count={device['count']}")
+    if device["platform"] != "tpu":
+        print(f"chip_smoke: JAX platform is {device['platform']!r}, not "
+              f"'tpu' (JAX_PLATFORMS={os.environ.get('JAX_PLATFORMS')!r}) — "
+              "nothing to prove here", file=sys.stderr)
+        return 2
+
+    from deepspeed_tpu.utils.host_env import configure_compile_cache
+    cache_dir = configure_compile_cache()
+    cache = {"hits": 0, "misses": 0}
+
+    def on_event(event, **_):
+        if event == "/jax/compilation_cache/cache_hits":
+            cache["hits"] += 1
+        elif event == "/jax/compilation_cache/cache_misses":
+            cache["misses"] += 1
+    jax.monitoring.register_event_listener(on_event)
+    _say(f"compile cache at {cache_dir}")
+
+    phases = [("train", phase_train), ("serve", phase_serve),
+              ("kernels", phase_kernels)]
+    if device["count"] >= 4:
+        phases.append(("multichip", phase_multichip))
+    else:
+        _say(f"phase multichip: SKIPPED — {device['count']} device(s); it "
+             "needs jax.device_count() >= 4 (chiprun --chips 4)")
+    # last: a compiler abort in the host-offload pass (it has happened)
+    # would take every later phase with it
+    phases.append(("offload", phase_offload))
+    failed = []
+    for name, fn in phases:
+        t0 = time.monotonic()
+        h0, m0 = cache["hits"], cache["misses"]
+        _say(f"phase {name}: start")
+        try:
+            fn(**FULL[name])
+        except Exception:
+            failed.append(name)
+            _say(f"phase {name}: FAILED\n{traceback.format_exc()}")
+        else:
+            _say(f"phase {name}: PASSED in {time.monotonic() - t0:.1f}s "
+                 f"(compile cache: {cache['hits'] - h0} hits, "
+                 f"{cache['misses'] - m0} misses)")
+    _say(f"total {time.monotonic() - _T0:.1f}s; compile cache "
+         f"{cache['hits']} hits, {cache['misses']} misses")
+    if failed:
+        print(f"chip_smoke: FAILED phases: {failed}", file=sys.stderr)
+        return 1
+    print(json.dumps({"ok": True, "device": device}), flush=True)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

@@ -9,7 +9,6 @@ DeepSpeedEngine (runtime/engine.py here vs runtime/engine.py:180 there).
 
 __version__ = "0.1.0"
 
-from .utils import jax_compat as _jax_compat  # noqa: F401  (API-drift shims)
 from . import comm  # noqa: F401
 from .comm import init_distributed  # noqa: F401
 from .runtime.config import DeepSpeedConfig  # noqa: F401

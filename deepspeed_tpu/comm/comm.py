@@ -207,27 +207,11 @@ def _declared_axes():
     mesh = peek_global_mesh()
     if mesh is not None:
         axes.update(mesh.axis_names)
-    try:
-        from jax.sharding import get_abstract_mesh
-        am = get_abstract_mesh()
-        if not am.empty:
-            axes.update(am.axis_names)
-    except ImportError:  # older jax: no abstract-mesh API
-        pass
+    from jax.sharding import get_abstract_mesh
+    am = get_abstract_mesh()
+    if not am.empty:
+        axes.update(am.axis_names)
     return axes
-
-
-def _currently_bound(name) -> bool:
-    """Is ``name`` a bound axis in the active trace? Covers user
-    shard_maps over custom meshes on jax versions without the
-    abstract-mesh API (jax.core.axis_frame resolves bound axis names
-    there; raises NameError for unbound ones)."""
-    try:
-        import jax.core
-        jax.core.axis_frame(name)
-        return True
-    except (NameError, AttributeError, ImportError, TypeError, KeyError):
-        return False
 
 
 def _axis(group):
@@ -238,9 +222,7 @@ def _axis(group):
         return MESH_AXES  # whole mesh
     names = (group,) if isinstance(group, str) else tuple(group)
     declared = _declared_axes()
-    bad = [n for n in names
-           if isinstance(n, str) and n not in declared
-           and not _currently_bound(n)]
+    bad = [n for n in names if isinstance(n, str) and n not in declared]
     if bad:
         raise ValueError(
             f"unknown mesh axis/group {bad[0]!r}: declared axes are "

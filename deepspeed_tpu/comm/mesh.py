@@ -23,6 +23,7 @@ over DCN-ish links is fine, ``model`` innermost so TP collectives ride
 nearest-neighbor ICI.
 """
 
+import contextlib
 from dataclasses import dataclass, field
 from typing import Optional, Tuple
 
@@ -101,6 +102,20 @@ def peek_global_mesh():
     """Current global mesh or None — no lazy construction (for callers
     that must not invent a mesh, e.g. activation constraints)."""
     return _GLOBAL_MESH
+
+
+@contextlib.contextmanager
+def global_mesh_scope(mesh):
+    """``mesh`` is the global mesh inside the block, the previous one
+    after it. The global mesh is read while a program TRACES (activation
+    constraints, the attention dispatch), so an engine that shares its
+    process with another runs its own traces under its own mesh."""
+    global _GLOBAL_MESH
+    previous, _GLOBAL_MESH = _GLOBAL_MESH, mesh
+    try:
+        yield
+    finally:
+        _GLOBAL_MESH = previous
 
 
 def axis_size(axis, mesh=None) -> int:

@@ -10,6 +10,7 @@ TP groups are the mesh's "model" axis; kernel injection swaps HF modules
 for our fused flax modules (module_inject/).
 """
 
+import contextlib
 from typing import Any, Callable, Dict, Optional
 
 import jax
@@ -38,17 +39,20 @@ class InferenceEngine:
                  quantize_min_size: int = 4096,
                  offload_params: bool = False, **kwargs):
         dist.init_distributed()
-        # serving never fake-quantizes activations. The rule table is
-        # process-global, so DON'T clear it (a concurrently-training
-        # compression engine would silently lose fake-quant on its next
-        # retrace); instead this engine's own traces run under a
-        # rules-suspended scope (_clean_trace below) — a distillation
-        # teacher serves clean while the student keeps quantizing.
+        # serving never fake-quantizes or constrains activations, and it
+        # partitions over ITS mesh. Those tables and the global mesh are
+        # process-global, so DON'T clear them (a concurrently-training
+        # engine would silently lose them on its next retrace); instead
+        # this engine's own traces run under _own_trace_state below — a
+        # distillation teacher serves clean while the student keeps
+        # training.
         self.module = model
         self.dtype = dtype
         self.mp_world_size = mp_size
         if mesh is None:
             mesh = dist.build_mesh(dist.MeshSpec(model=mp_size))
+        elif isinstance(mesh, dist.MeshSpec):
+            mesh = dist.build_mesh(mesh)
         self.mesh = mesh
         self.params = params
         self.checkpoint = checkpoint
@@ -73,6 +77,17 @@ class InferenceEngine:
 
         if self.params is None and checkpoint is not None:
             self._load_checkpoint(checkpoint)
+        elif (self.params is not None and not self._injected
+              and isinstance(model, nn.Module)
+              and dist.mp_world_size(mesh) > 1):
+            # caller-supplied weights get the same tensor-parallel
+            # placement as converted/loaded ones; left alone they would
+            # sit whole on the first device and mp_size would be inert
+            from flax.core import meta
+            from ..module_inject.replace_module import \
+                shard_params_for_inference
+            self.params = shard_params_for_inference(
+                self.module, meta.unbox(self.params), mesh, None)
 
         if quantize_weights:
             # Weight-only int8 serving (reference: module_quantize.py +
@@ -124,22 +139,27 @@ class InferenceEngine:
         semantics, and required on TPU (host-space scan xs with ndim<3
         leaves hit XLA layout bugs; see models/gpt.py offload branch)."""
         import jax
-        from ..utils.streaming import HAS_MEMORY_SPACE, to_host_tree
+        from ..utils.streaming import to_host_tree
         from flax.core import meta as _meta
         params = dict(_meta.unbox(params))
         if "h" not in params:
             raise ValueError(
                 "offload_params serving expects scan-stacked block params "
                 f"under 'h'; got keys {sorted(params)}")
-        # routing is version-independent; only the small-leaf device
-        # pinning needs typed memory spaces (to_host_tree degrades to
-        # identity on jax versions without them)
         params["h"] = jax.tree.map(
             lambda a: (to_host_tree(a) if getattr(a, "ndim", 0) >= 3
-                       else (jax.device_put(a, jax.memory.Space.Device)
-                             if HAS_MEMORY_SPACE else a)),
+                       else jax.device_put(a, jax.memory.Space.Device)),
             params["h"])
         return params
+
+    @contextlib.contextmanager
+    def _own_trace_state(self):
+        """This engine's mesh as the global mesh and no training-side
+        rule tables, for the programs it traces."""
+        from ..comm.mesh import global_mesh_scope
+        from ..models.layers import training_rules_suspended
+        with global_mesh_scope(self.mesh), training_rules_suspended():
+            yield
 
     def _load_checkpoint(self, checkpoint):
         from ..module_inject.load_checkpoint import load_model_checkpoint
@@ -169,8 +189,7 @@ class InferenceEngine:
                         {"params": transform(p) if transform else p},
                         *a, **kw, **static)),
                 subsystem="inference")
-        from ..models.layers import activation_quantization_suspended
-        with activation_quantization_suspended():
+        with self._own_trace_state():
             return self._compiled[key](self.params, args, arrays)
 
     __call__ = forward
@@ -220,8 +239,7 @@ class InferenceEngine:
                 cache_len = min(cache_len, model_max)
             kwargs.setdefault("max_len", cache_len)
         kwargs.setdefault("param_transform", self._param_transform)
-        from ..models.layers import activation_quantization_suspended
-        with activation_quantization_suspended():
+        with self._own_trace_state():
             return _generate(self.module, self.params, input_ids,
                              max_new_tokens=max_new_tokens, **kwargs)
 
@@ -232,4 +250,5 @@ class InferenceEngine:
         override individual knobs."""
         from ..serving.engine import ServingEngine
         return ServingEngine(self.module, self.params, config,
-                             param_transform=self._param_transform, **kwargs)
+                             param_transform=self._param_transform,
+                             trace_scope=self._own_trace_state, **kwargs)

@@ -49,6 +49,8 @@ def main(argv=None):
 
     cmd = [sys.executable, "-u", args.training_script] + args.training_script_args
     logger.info(f"node {args.node_rank}/{num_hosts}: {' '.join(cmd)}")
+    from ..utils.host_env import assert_not_holding_chip
+    assert_not_holding_chip("the per-host launcher")
     proc = subprocess.Popen(cmd, env=env)
 
     def _kill(signum, frame):

@@ -23,23 +23,33 @@ from jax.ad_checkpoint import checkpoint_name
 
 from ..ops.transformer.attention import attention
 
-# Set by the engine: dict logical-name -> mesh axis (or None). Activation
-# constraints no-op when empty so models run un-meshed.
+# Set by the training engine: dict logical-name -> mesh axis (or None).
+# Activation constraints no-op when empty so models run un-meshed.
 _ACTIVATION_RULES = {}
 
 
 def set_activation_rules(rules: dict):
+    """Install the table and return it: the engine that installed it
+    clears it at ``destroy()`` only if it is still the one in place."""
     global _ACTIVATION_RULES
     _ACTIVATION_RULES = dict(rules or {})
+    return _ACTIVATION_RULES
+
+
+def activation_rules_installed(table) -> bool:
+    """True while ``table`` (a ``set_activation_rules`` result) is the one
+    in place."""
+    return _ACTIVATION_RULES is table
 
 
 def _usable_global_mesh():
     """The global mesh if a sharding constraint can be applied here, else
     None. Inside shard_map (Manual axes) the global-mesh NamedSharding is
-    from a different (Auto) mesh view and would poison downstream ops."""
-    from jax.sharding import get_abstract_mesh
-    am = get_abstract_mesh()
-    if not am.empty and any("Manual" in str(t) for t in am.axis_types):
+    from a different (Auto) mesh view and would poison downstream ops.
+    Also the mesh handed to the decode kernels, which split their heads
+    over its ``model`` axis and read no global themselves."""
+    from ..ops.pallas._common import in_manual_region
+    if in_manual_region():
         return None
     from ..comm.mesh import peek_global_mesh
     return peek_global_mesh()
@@ -57,26 +67,33 @@ def activation_constraint(x, logical_names):
     axes = tuple(_ACTIVATION_RULES.get(n) for n in logical_names)
     if all(a is None for a in axes):
         return x
-    try:
-        mesh = _usable_global_mesh()
-        if mesh is None:
-            return x
-        # drop constraints the array can't honor (dim not divisible by the
-        # axis degree — e.g. batch 1 on an 8-way dp axis in eval paths)
-        def ok(dim, a):
-            if a is None:
-                return None
-            from ..comm.mesh import axis_size
-            return a if dim % axis_size(a, mesh) == 0 else None
-        axes = tuple(ok(d, a) for d, a in zip(x.shape, axes))
-        if all(a is None for a in axes):
-            return x
-        return jax.lax.with_sharding_constraint(
-            x, NamedSharding(mesh, P(*axes)))
-    except Exception as e:  # never break an un-meshed model run
-        from ..utils.logging import warn_once
-        warn_once(f"activation sharding constraint skipped: {e}")
+    mesh = _usable_global_mesh()
+    if mesh is None:   # un-meshed model run: nothing to constrain against
         return x
+    # drop constraints the array can't honor (dim not divisible by the
+    # axis degree — e.g. batch 1 on an 8-way dp axis in eval paths)
+    def ok(dim, a):
+        if a is None:
+            return None
+        from ..comm.mesh import axis_size
+        return a if dim % axis_size(a, mesh) == 0 else None
+    axes = [ok(d, a) for d, a in zip(x.shape, axes)]
+    # a mesh axis may shard one dim only. partition_activations maps
+    # "seq" to the model axis, which a tensor-parallel feature dim
+    # ("mlp", "qkv") of the same tensor also claims: the later (feature)
+    # dim keeps it, as the weights it meets are sharded that way.
+    claimed = set()
+    for i in reversed(range(len(axes))):
+        names = axes[i] if isinstance(axes[i], (tuple, list)) else (axes[i],)
+        if axes[i] is not None and claimed.intersection(names):
+            axes[i] = None
+        claimed.update(n for n in names if n is not None)
+    if all(a is None for a in axes):
+        return x
+    # a constraint that throws propagates: skipping it would change
+    # placement on a real mesh without a word
+    return jax.lax.with_sharding_constraint(
+        x, NamedSharding(mesh, P(*axes)))
 
 
 # Set by the engine from the compression_training.activation_quantization
@@ -93,21 +110,22 @@ def set_activation_quantization(rules):
     _ACT_QUANT_RULES = list(rules or [])
 
 
-class activation_quantization_suspended:
-    """Context manager: trace with the rule table empty, then restore.
+class training_rules_suspended:
+    """Context manager: trace with both training-side rule tables
+    (activation sharding, activation quantization) empty, then restore.
     Lets an InferenceEngine (e.g. a distillation teacher) compile clean
-    forwards in the same process as a compression-training engine whose
-    global rules must survive its own retraces."""
+    forwards in the same process as a training engine whose global rules
+    must survive its own retraces."""
 
     def __enter__(self):
-        global _ACT_QUANT_RULES
-        self._saved = _ACT_QUANT_RULES
-        _ACT_QUANT_RULES = []
+        global _ACTIVATION_RULES, _ACT_QUANT_RULES
+        self._saved = _ACTIVATION_RULES, _ACT_QUANT_RULES
+        _ACTIVATION_RULES, _ACT_QUANT_RULES = {}, []
         return self
 
     def __exit__(self, *exc):
-        global _ACT_QUANT_RULES
-        _ACT_QUANT_RULES = self._saved
+        global _ACTIVATION_RULES, _ACT_QUANT_RULES
+        _ACTIVATION_RULES, _ACT_QUANT_RULES = self._saved
         return False
 
 
@@ -137,16 +155,11 @@ def replicated_constraint(x):
     clean psum instead of the reverse reshard."""
     if not _ACTIVATION_RULES:
         return x
-    try:
-        from jax.sharding import PartitionSpec as P, NamedSharding
-        mesh = _usable_global_mesh()
-        if mesh is None:
-            return x
-        return jax.lax.with_sharding_constraint(x, NamedSharding(mesh, P()))
-    except Exception as e:
-        from ..utils.logging import warn_once
-        warn_once(f"replicated sharding constraint skipped: {e}")
+    from jax.sharding import PartitionSpec as P, NamedSharding
+    mesh = _usable_global_mesh()
+    if mesh is None:
         return x
+    return jax.lax.with_sharding_constraint(x, NamedSharding(mesh, P()))
 
 
 def dense_init(names, scale=1.0):
@@ -371,7 +384,7 @@ class SelfAttention(nn.Module):
                 decode_out = paged_attention(
                     q, cached_key.value, cached_value.value, ptab, idx,
                     kc, vc, alibi_slopes=slopes, k_scale=k_sc,
-                    v_scale=v_sc)
+                    v_scale=v_sc, mesh=_usable_global_mesh())
                 cache_index.value = idx + 1
             else:
                 max_len = cached_key.value.shape[3]
@@ -463,8 +476,9 @@ class SelfAttention(nn.Module):
                     from ..ops.pallas import decode_attention
                     slopes = (alibi_slopes(self.n_heads)
                               if self.alibi else None)
-                    decode_out = decode_attention(q, k_all, v_all, idx + 1,
-                                                  alibi_slopes=slopes)
+                    decode_out = decode_attention(
+                        q, k_all, v_all, idx + 1, alibi_slopes=slopes,
+                        mesh=_usable_global_mesh())
                 else:
                     # prefill / externally-masked chunks: dense path over
                     # the cache with an explicit validity+causality mask

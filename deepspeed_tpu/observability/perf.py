@@ -40,7 +40,6 @@ _DEVICE_KIND_ALIASES = {
     "tpu v4": "tpu-v4",
     "tpu v5 lite": "tpu-v5e",
     "tpu v5e": "tpu-v5e",
-    "tpu v5": "tpu-v5p",
     "tpu v5p": "tpu-v5p",
     "tpu v6 lite": "tpu-v6e",
     "tpu v6e": "tpu-v6e",
@@ -48,24 +47,31 @@ _DEVICE_KIND_ALIASES = {
 
 
 def detect_chip() -> Optional[str]:
-    """Chip-table key for the local accelerator, or None (unknown
-    device kind, or no jax in this process)."""
-    try:
-        import jax
-        kind = jax.local_devices()[0].device_kind.lower()
-    except (ImportError, RuntimeError, IndexError):
-        return None
+    """Chip-table key for the local accelerator. None off-accelerator
+    (the CPU backend has no peak: MFU is "not measured" there). On
+    platform ``tpu`` a device kind the table does not know raises — a
+    silently absent MFU reads as a healthy run with nothing to report."""
+    import jax
+    dev = jax.local_devices()[0]
+    kind = dev.device_kind.lower()
     if kind in _DEVICE_KIND_ALIASES:
         return _DEVICE_KIND_ALIASES[kind]
     key = kind.replace(" ", "-")
-    return key if key in CHIP_PEAK_TFLOPS else None
+    if key in CHIP_PEAK_TFLOPS:
+        return key
+    if dev.platform == "tpu":
+        raise ValueError(
+            f"unknown TPU device kind {dev.device_kind!r} for MFU "
+            f"accounting — known: {sorted(_DEVICE_KIND_ALIASES)}; add it "
+            "to observability/perf.py or set observability.peak_tflops")
+    return None
 
 
 def resolve_peak_flops(config) -> Optional[float]:
     """Per-chip peak FLOP/s for MFU from an ObservabilityConfig:
     ``peak_tflops`` override wins, else ``chip`` (or the detected device
-    kind) looked up in the table. None = MFU unavailable (e.g. the CPU
-    test backend without an override)."""
+    kind) looked up in the table. None = MFU not measured (the CPU
+    backend without an override)."""
     if getattr(config, "peak_tflops", None):
         return float(config.peak_tflops) * 1e12
     chip = getattr(config, "chip", None) or detect_chip()

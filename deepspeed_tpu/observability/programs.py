@@ -199,8 +199,6 @@ class TrackedProgram:
             cost = compiled.cost_analysis() or {}
         except (RuntimeError, NotImplementedError, AttributeError):
             cost = {}
-        if isinstance(cost, list):        # older jax returns [dict]
-            cost = cost[0] if cost else {}
         if cost.get("flops") is not None:
             info["flops"] = float(cost["flops"])
         if cost.get("bytes accessed") is not None:

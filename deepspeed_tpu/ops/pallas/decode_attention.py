@@ -42,13 +42,20 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from jax.sharding import PartitionSpec as P
+
+from . import tuning
 from ._common import NEG_INF
 from ._common import interpret_mode as _interpret
+from ._common import (log_fallback_on_tpu, model_axis_size, over_model_axis,
+                      pick_head_block)
 from ._common import online_softmax_block as _attend_block
 from ._common import read_slopes as _read_slopes
 
 DEFAULT_BLOCK_K = 512
 DEFAULT_HEAD_BLOCK = 8
+
+KERNEL = "decode_attention"
 
 
 def _dma_kernel(len_ref, slopes_ref, q_ref, k_hbm, v_hbm, o_ref,
@@ -98,7 +105,7 @@ def _dma_kernel(len_ref, slopes_ref, q_ref, k_hbm, v_hbm, o_ref,
                 wk, wv = copies(j, parity)
                 wk.wait()
                 wv.wait()
-                q = q_ref[0].astype(jnp.float32) * scale
+                q = q_ref[0, 0].astype(jnp.float32) * scale
                 kb, vb = bufs[parity]
                 _attend_block(q, kb, vb, j * block_k, length, length - 1,
                               slopes, m_ref, l_ref, acc_ref, hb=hb,
@@ -112,27 +119,34 @@ def _dma_kernel(len_ref, slopes_ref, q_ref, k_hbm, v_hbm, o_ref,
     # for them.
     l = l_ref[...]
     safe = acc_ref[...] / jnp.where(l > 0.0, l, 1.0)
-    o_ref[0] = jnp.where(l > 0.0, safe, 0.0).astype(o_ref.dtype)
+    o_ref[0, 0] = jnp.where(l > 0.0, safe, 0.0).astype(o_ref.dtype)
 
 
 def _decode_dma(q_bhd, k, v, lengths, slopes, *, scale, block_k, hb, alibi):
     b, heads, d = q_bhd.shape
     s = k.shape[3]
-    kr = k.reshape(b, heads // hb, hb, d, s)
-    vr = v.reshape(b, heads // hb, hb, d, s)
+    nhb = heads // hb
+    kr = k.reshape(b, nhb, hb, d, s)
+    vr = v.reshape(b, nhb, hb, d, s)
     kv_buf = lambda: pltpu.VMEM((hb, d, block_k), k.dtype)
-    return pl.pallas_call(
+    # q/out ride as [B, heads/hb, hb, d] so the (hb, d) tile is the
+    # array's own last two dims: a (1, hb, d) block of [B, H, d] is
+    # refused by the Mosaic lowering unless hb % 8 == 0 or hb == H
+    # (12 heads -> hb 4)
+    tok_spec = pl.BlockSpec((1, 1, hb, d),
+                            lambda bi, hi, *_: (bi, hi, 0, 0))
+    out = pl.pallas_call(
         functools.partial(_dma_kernel, scale=scale, block_k=block_k,
                           hb=hb, alibi=alibi),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
-            grid=(b, heads // hb),
+            grid=(b, nhb),
             in_specs=[
-                pl.BlockSpec((1, hb, d), lambda bi, hi, *_: (bi, hi, 0)),
+                tok_spec,
                 pl.BlockSpec(memory_space=pl.ANY),
                 pl.BlockSpec(memory_space=pl.ANY),
             ],
-            out_specs=pl.BlockSpec((1, hb, d), lambda bi, hi, *_: (bi, hi, 0)),
+            out_specs=tok_spec,
             scratch_shapes=[
                 kv_buf(), kv_buf(), kv_buf(), kv_buf(),
                 pltpu.SemaphoreType.DMA((2, 2)),
@@ -141,14 +155,12 @@ def _decode_dma(q_bhd, k, v, lengths, slopes, *, scale, block_k, hb, alibi):
                 pltpu.VMEM((hb, d), jnp.float32),
             ],
         ),
-        out_shape=jax.ShapeDtypeStruct((b, heads, d), q_bhd.dtype),
-        # jax renamed TPUCompilerParams -> CompilerParams around 0.5;
-        # support both so the kernel runs on the pinned CI jax too
-        compiler_params=getattr(pltpu, "CompilerParams",
-                                getattr(pltpu, "TPUCompilerParams", None))(
+        out_shape=jax.ShapeDtypeStruct((b, nhb, hb, d), q_bhd.dtype),
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")),
         interpret=_interpret(),
-    )(lengths, slopes, q_bhd, kr, vr)
+    )(lengths, slopes, q_bhd.reshape(b, nhb, hb, d), kr, vr)
+    return out.reshape(b, heads, d)
 
 
 def _decode_dense(q_bhd, k, v, lengths, slopes, *, scale, alibi):
@@ -173,7 +185,7 @@ def _decode_dense(q_bhd, k, v, lengths, slopes, *, scale, alibi):
 
 def decode_attention(q, k, v, length, *, softmax_scale=None,
                      alibi_slopes=None, block_k=DEFAULT_BLOCK_K,
-                     head_block=DEFAULT_HEAD_BLOCK):
+                     head_block=DEFAULT_HEAD_BLOCK, mesh=None):
     """Single-token KV-cache attention over transposed caches.
 
     q: [B, 1, H, d] (or [B, H, d]) — the current token's queries (BSHD).
@@ -182,6 +194,9 @@ def decode_attention(q, k, v, length, *, softmax_scale=None,
         (the query sits at position length-1). Rows with length <= 0
         (empty serving slots) return zeros.
     alibi_slopes: optional [H] per-head ALiBi slopes (BLOOM).
+    mesh: the caller's mesh when its ``model`` axis splits the heads
+        (tensor-parallel serving): the kernel runs once per head shard.
+        None = one unpartitioned call.
 
     Returns [B, 1, H, d] (or [B, H, d], matching q's rank).
     """
@@ -193,7 +208,8 @@ def decode_attention(q, k, v, length, *, softmax_scale=None,
         raise ValueError(f"decode_attention is single-token (q_len 1), got {one}")
     s = k.shape[3]
     scale = softmax_scale if softmax_scale is not None else 1.0 / math.sqrt(d)
-    hb = math.gcd(heads, head_block)
+    tp = model_axis_size(mesh, heads)
+    hb = pick_head_block(heads // tp, head_block)
 
     lengths = jnp.broadcast_to(jnp.asarray(length, jnp.int32), (b,))
     alibi = alibi_slopes is not None
@@ -207,11 +223,22 @@ def decode_attention(q, k, v, length, *, softmax_scale=None,
     bk = (bk // 128) * 128
     while bk >= 128 and s % bk != 0:
         bk -= 128
-    if bk >= 128:
-        out = _decode_dma(q_bhd, k, v, lengths, slopes, scale=scale,
-                          block_k=bk, hb=hb, alibi=alibi)
+    use_kernel = bk >= 128
+    reason = None
+    if use_kernel:
+        run = functools.partial(_decode_dma, scale=scale, block_k=bk, hb=hb,
+                                alibi=alibi)
     else:
-        out = _decode_dense(q_bhd, k, v, lengths, slopes, scale=scale,
-                            alibi=alibi)
+        reason = f"cache length {s} not a multiple of 128"
+        log_fallback_on_tpu(KERNEL, "dense", reason)
+        run = functools.partial(_decode_dense, scale=scale, alibi=alibi)
+    tuning.record_dispatch(
+        KERNEL, "dma", f"b{b}_h{heads}_d{d}_s{s}", None, block_k=bk,
+        head_block=hb, impl="kernel" if use_kernel else "dense",
+        reason=reason, model_shards=tp)
+    heads_1 = P(None, "model")
+    out = over_model_axis(
+        run, mesh, in_specs=(heads_1, heads_1, heads_1, P(), P("model")),
+        out_specs=heads_1)(q_bhd, k, v, lengths, slopes)
     out = out[:, None]                                       # [B, 1, H, d]
     return out[:, 0].reshape(b, heads, d) if squeeze else out

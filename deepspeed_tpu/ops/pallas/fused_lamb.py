@@ -26,6 +26,7 @@ from ._common import interpret_mode as _interpret
 
 BLOCK = 1024 * 128
 LANE = 128
+_PART_TILE = (8, LANE)   # one fp32 tile per norm partial
 
 
 def _lamb_phase1_kernel(p_ref, g_ref, m_ref, v_ref, sc_ref,
@@ -41,9 +42,11 @@ def _lamb_phase1_kernel(p_ref, g_ref, m_ref, v_ref, sc_ref,
     u_ref[:] = u
     new_m_ref[:] = m
     new_v_ref[:] = v
-    # in-kernel norm reduction partials (one scalar per grid block)
-    pn_ref[0, 0] = jnp.sum(p * p)
-    un_ref[0, 0] = jnp.sum(u * u)
+    # in-kernel norm reduction partials, one per grid block. Mosaic
+    # cannot store a scalar to VMEM, so each partial fills one (8, 128)
+    # tile and the caller reads a single element of it.
+    pn_ref[:] = jnp.full(pn_ref.shape, jnp.sum(p * p), jnp.float32)
+    un_ref[:] = jnp.full(un_ref.shape, jnp.sum(u * u), jnp.float32)
 
 
 def _lamb_phase1_flat(p, g, m, v, scalars, *, b1, b2, eps, wd):
@@ -60,14 +63,15 @@ def _lamb_phase1_flat(p, g, m, v, scalars, *, b1, b2, eps, wd):
         n = n + pad_rows
     grid = (pl.cdiv(n, block_rows),)
     spec = pl.BlockSpec((block_rows, LANE), lambda i: (i, 0))
-    part = pl.BlockSpec((1, 1), lambda i: (i, 0))
+    part = pl.BlockSpec(_PART_TILE, lambda i: (i, 0))
     nblocks = grid[0]
+    part_shape = jax.ShapeDtypeStruct(
+        (nblocks * _PART_TILE[0], _PART_TILE[1]), jnp.float32)
     out_shape = (jax.ShapeDtypeStruct(p.shape, jnp.float32),     # u
                  jax.ShapeDtypeStruct(m.shape, jnp.float32),
                  jax.ShapeDtypeStruct(v.shape, jnp.float32),
-                 jax.ShapeDtypeStruct((nblocks, 1), jnp.float32),
-                 jax.ShapeDtypeStruct((nblocks, 1), jnp.float32))
-    return pl.pallas_call(
+                 part_shape, part_shape)
+    u, nm, nv, pn, un = pl.pallas_call(
         functools.partial(_lamb_phase1_kernel, b1=b1, b2=b2, eps=eps, wd=wd),
         grid=grid,
         in_specs=[spec, spec, spec, spec,
@@ -77,6 +81,7 @@ def _lamb_phase1_flat(p, g, m, v, scalars, *, b1, b2, eps, wd):
         input_output_aliases={2: 1, 3: 2},
         interpret=_interpret(),
     )(p, g, m, v, scalars)
+    return u, nm, nv, pn[::_PART_TILE[0], 0], un[::_PART_TILE[0], 0]
 
 
 class FusedLambState(NamedTuple):

@@ -27,8 +27,8 @@ def _ln_fwd_kernel(x_ref, g_ref, b_ref, y_ref, mean_ref, rstd_ref, *, eps):
     rstd = jax.lax.rsqrt(var + eps)
     y = (x - mean) * rstd * g_ref[:].astype(jnp.float32) + b_ref[:].astype(jnp.float32)
     y_ref[:] = y.astype(y_ref.dtype)
-    mean_ref[:] = mean[:, 0]
-    rstd_ref[:] = rstd[:, 0]
+    mean_ref[:] = mean
+    rstd_ref[:] = rstd
 
 
 def _ln_fwd(x2d, gamma, beta, eps):
@@ -41,15 +41,17 @@ def _ln_fwd(x2d, gamma, beta, eps):
         in_specs=[pl.BlockSpec((rows, d), lambda i: (i, 0)),
                   pl.BlockSpec((d,), lambda i: (0,)),
                   pl.BlockSpec((d,), lambda i: (0,))],
+        # per-row stats are [n, 1] columns: a 1-D f32[n] output tiled at
+        # ``rows`` does not match XLA's 1-D layout (Mosaic refuses it)
         out_specs=(pl.BlockSpec((rows, d), lambda i: (i, 0)),
-                   pl.BlockSpec((rows,), lambda i: (i,)),
-                   pl.BlockSpec((rows,), lambda i: (i,))),
+                   pl.BlockSpec((rows, 1), lambda i: (i, 0)),
+                   pl.BlockSpec((rows, 1), lambda i: (i, 0))),
         out_shape=(jax.ShapeDtypeStruct(x2d.shape, x2d.dtype),
-                   jax.ShapeDtypeStruct((n,), jnp.float32),
-                   jax.ShapeDtypeStruct((n,), jnp.float32)),
+                   jax.ShapeDtypeStruct((n, 1), jnp.float32),
+                   jax.ShapeDtypeStruct((n, 1), jnp.float32)),
         interpret=_interpret(),
     )(x2d, gamma, beta)
-    return y, mean, rstd
+    return y, mean[:, 0], rstd[:, 0]
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3,))

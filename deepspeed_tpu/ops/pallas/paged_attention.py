@@ -56,9 +56,13 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from jax.sharding import PartitionSpec as P
+
 from . import tuning
 from ._common import NEG_INF
 from ._common import interpret_mode as _interpret
+from ._common import (log_fallback_on_tpu, model_axis_size, over_model_axis,
+                      pick_head_block)
 from ._common import online_softmax_block as _attend_block
 from ._common import read_slopes as _read_slopes
 
@@ -145,7 +149,7 @@ def _dma_kernel(len_ref, ptab_ref, slopes_ref, q_ref, kn_ref, vn_ref,
             def _compute():
                 for c in copies(j, parity):
                     c.wait()
-                q = q_ref[0].astype(jnp.float32) * scale
+                q = q_ref[0, 0].astype(jnp.float32) * scale
                 if quant:
                     kb, vb, ksb, vsb = bufs[parity]
                     kblk = kb[...].astype(jnp.float32) * ksb[...]
@@ -160,14 +164,15 @@ def _dma_kernel(len_ref, ptab_ref, slopes_ref, q_ref, kn_ref, vn_ref,
         return carry
 
     jax.lax.fori_loop(0, nb, body, 0)
-    q = q_ref[0].astype(jnp.float32) * scale
-    _fold_current_token(q, kn_ref[0].astype(jnp.float32),
-                        vn_ref[0].astype(jnp.float32), m_ref, l_ref, acc_ref)
-    o_ref[0] = (acc_ref[...] / l_ref[...]).astype(o_ref.dtype)
+    q = q_ref[0, 0].astype(jnp.float32) * scale
+    _fold_current_token(q, kn_ref[0, 0].astype(jnp.float32),
+                        vn_ref[0, 0].astype(jnp.float32), m_ref, l_ref,
+                        acc_ref)
+    o_ref[0, 0] = (acc_ref[...] / l_ref[...]).astype(o_ref.dtype)
 
 
-def _paged_dma(q_bhd, kp, vp, ptab, lengths, kn, vn, ks, vs, *, scale,
-               page_len, ppb, hb, alibi, slopes):
+def _paged_dma(q_bhd, kp, vp, ptab, lengths, kn, vn, ks, vs, slopes, *,
+               scale, page_len, ppb, hb, alibi):
     b, heads, d = q_bhd.shape
     num_pages = kp.shape[0]
     max_pages = ptab.shape[1]
@@ -191,8 +196,14 @@ def _paged_dma(q_bhd, kp, vp, ptab, lengths, kn, vn, ks, vs, *, scale,
         pltpu.VMEM((hb, 1), jnp.float32),
         pltpu.VMEM((hb, d), jnp.float32),
     ]
-    tok_spec = lambda: pl.BlockSpec((1, hb, d), lambda bi, hi, *_: (bi, hi, 0))
-    return pl.pallas_call(
+    # per-token operands ride as [B, heads/hb, hb, d] so the (hb, d)
+    # tile is the array's own last two dims: a (1, hb, d) block of
+    # [B, H, d] is refused by the Mosaic lowering unless hb % 8 == 0
+    # or hb == H
+    tok_spec = lambda: pl.BlockSpec((1, 1, hb, d),
+                                    lambda bi, hi, *_: (bi, hi, 0, 0))
+    tok = lambda x: x.reshape(b, nhb, hb, d)
+    out = pl.pallas_call(
         functools.partial(_dma_kernel, scale=scale, page_len=page_len,
                           ppb=ppb, hb=hb, alibi=alibi, quant=quant,
                           max_pages=max_pages),
@@ -204,18 +215,16 @@ def _paged_dma(q_bhd, kp, vp, ptab, lengths, kn, vn, ks, vs, *, scale,
             out_specs=tok_spec(),
             scratch_shapes=scratch,
         ),
-        out_shape=jax.ShapeDtypeStruct((b, heads, d), q_bhd.dtype),
-        # jax renamed TPUCompilerParams -> CompilerParams around 0.5;
-        # support both so the kernel runs on the pinned CI jax too
-        compiler_params=getattr(pltpu, "CompilerParams",
-                                getattr(pltpu, "TPUCompilerParams", None))(
+        out_shape=jax.ShapeDtypeStruct((b, nhb, hb, d), q_bhd.dtype),
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")),
         interpret=_interpret(),
-    )(lengths, ptab, slopes, q_bhd, kn, vn, *pools)
+    )(lengths, ptab, slopes, tok(q_bhd), tok(kn), tok(vn), *pools)
+    return out.reshape(b, heads, d)
 
 
-def _paged_dense(q_bhd, kp, vp, ptab, lengths, kn, vn, ks, vs, *, scale,
-                 alibi, slopes):
+def _paged_dense(q_bhd, kp, vp, ptab, lengths, kn, vn, ks, vs, slopes, *,
+                 scale, alibi):
     """jnp fallback with IDENTICAL semantics for pools the kernel cannot
     tile (page_len not a 128 multiple on real TPU) — and the reference
     the kernel parity suite checks against. Gathers the table's pages
@@ -256,7 +265,7 @@ def _paged_dense(q_bhd, kp, vp, ptab, lengths, kn, vn, ks, vs, *, scale,
 def paged_attention(q, k_pages, v_pages, page_table, lengths, k_new, v_new,
                     *, softmax_scale=None, alibi_slopes=None, k_scale=None,
                     v_scale=None, block_tokens=None, head_block=None,
-                    impl=None):
+                    impl=None, mesh=None):
     """Single-token attention straight over a paged KV pool.
 
     q: [B, 1, H, d] (or [B, H, d]) — the current token's queries.
@@ -271,6 +280,9 @@ def paged_attention(q, k_pages, v_pages, page_table, lengths, k_new, v_new,
     k_scale, v_scale: optional [num_pages, H, 1, page_len] fp32 per-
         token-per-head scale planes of an int8 pool.
     impl: None (auto), "kernel", or "dense" — parity/testing override.
+    mesh: the caller's mesh when its ``model`` axis splits the heads
+        (tensor-parallel serving): the kernel runs once per head shard.
+        None = one unpartitioned call.
 
     Returns [B, 1, H, d] (or [B, H, d], matching q's rank): softmax
     attention over the row's ``lengths`` pool tokens plus the current
@@ -307,8 +319,10 @@ def paged_attention(q, k_pages, v_pages, page_table, lengths, k_new, v_new,
         KERNEL, structure, sq=b, sk=max_pages * page_len, d=d,
         dtype=k_pages.dtype, causal=True)
     bt = int(entry.get("block_k") or block_tokens or DEFAULT_BLOCK_TOKENS)
-    hb = math.gcd(heads, int(entry.get("head_block") or head_block
-                             or DEFAULT_HEAD_BLOCK))
+    tp = model_axis_size(mesh, heads)
+    hb = pick_head_block(heads // tp, int(entry.get("head_block")
+                                          or head_block
+                                          or DEFAULT_HEAD_BLOCK))
     ppb = max(1, min(bt // page_len, max_pages))
 
     kernel_ok = page_len % 128 == 0 or _interpret()
@@ -317,17 +331,31 @@ def paged_attention(q, k_pages, v_pages, page_table, lengths, k_new, v_new,
         raise ValueError(
             f"paged_attention kernel needs page_len % 128 == 0 on TPU "
             f"(got {page_len}); use page_len=128 or impl='dense'")
+    reason = None
+    if not use_kernel:
+        reason = ("impl='dense' requested" if impl == "dense"
+                  else f"page_len {page_len} not a multiple of 128")
+        log_fallback_on_tpu(KERNEL, "dense", reason)
     tuning.record_dispatch(
         KERNEL, structure, key, source, block_k=ppb * page_len,
-        head_block=hb, impl="kernel" if use_kernel else "dense")
+        head_block=hb, impl="kernel" if use_kernel else "dense",
+        reason=reason, model_shards=tp)
     if use_kernel:
-        out = _paged_dma(q_bhd, k_pages, v_pages, page_table, lengths, kn,
-                         vn, k_scale, v_scale, scale=scale,
-                         page_len=page_len, ppb=ppb, hb=hb, alibi=alibi,
-                         slopes=slopes)
+        run = functools.partial(_paged_dma, scale=scale, page_len=page_len,
+                                ppb=ppb, hb=hb, alibi=alibi)
     else:
-        out = _paged_dense(q_bhd, k_pages, v_pages, page_table, lengths,
-                           kn, vn, k_scale, v_scale, scale=scale,
-                           alibi=alibi, slopes=slopes)
+        run = functools.partial(_paged_dense, scale=scale, alibi=alibi)
+    # positional: q, kp, vp, ptab, lengths, kn, vn, ks, vs, slopes — the
+    # head dim (axis 1 of all but ptab/lengths, axis 0 of slopes) splits
+    # over the model axis
+    heads_1 = P(None, "model")
+    quant_spec = heads_1 if k_scale is not None else None
+    out = over_model_axis(
+        run, mesh,
+        in_specs=(heads_1, heads_1, heads_1, P(), P(), heads_1, heads_1,
+                  quant_spec, quant_spec, P("model")),
+        out_specs=heads_1,
+    )(q_bhd, k_pages, v_pages, page_table, lengths, kn, vn, k_scale,
+      v_scale, slopes)
     out = out[:, None]                                       # [B, 1, H, d]
     return out[:, 0].reshape(b, heads, d) if squeeze else out

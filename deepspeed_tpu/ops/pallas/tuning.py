@@ -13,8 +13,7 @@ ONE shape). This module makes those choices table-driven:
      sweep harness timing candidates),
   2. a JSON artifact named by ``$DS_TPU_KERNEL_TUNING_CACHE``
      (fleet-specific, regenerated by ``bin/ds_tpu_bench kernels`` on
-     hardware — same committed-artifact pattern as the int8 GEMV
-     calibration in ``wo_int8_matmul.py``),
+     hardware),
   3. the committed default table (``flash_tuning_defaults.json``) beside
      this file;
 - a miss everywhere returns ``{}`` and the dispatch falls back to its
@@ -144,10 +143,14 @@ def lookup(kernel, structure, *, sq, sk, d, dtype, causal):
 
 def record_dispatch(kernel, structure, key, source, **blocks):
     """Called by the kernel dispatch with the blocks it settled on
-    (post-validation, so this reflects what actually runs)."""
+    (post-validation, so this reflects what actually runs).
+    ``interpret`` says how a Pallas call in this dispatch lowers: False
+    = compiled by Mosaic (platform tpu), True = interpreted."""
+    from ._common import interpret_mode
     with _lock:
         _last_dispatch.setdefault(kernel, {})[structure] = dict(
-            key=key, source=source or "constants", **blocks)
+            key=key, source=source or "constants",
+            interpret=interpret_mode(), **blocks)
 
 
 def last_dispatch(kernel="flash_attention"):

@@ -433,11 +433,12 @@ def _build_sparse_fn(plan_key, scale, dropout_rate, total_heads):
     [seed0, seed1, head_offset, batch_offset]) feeding the in-kernel
     counter-based keep hash shared with the flash kernel."""
     plan = _PLAN_CACHE[plan_key]
-    masks = jnp.asarray(plan.masks)
-    kv = (jnp.asarray(plan.kv_idx), jnp.asarray(plan.kv_pid),
-          jnp.asarray(plan.kv_cnt))
-    qt = (jnp.asarray(plan.qt_idx), jnp.asarray(plan.qt_pid),
-          jnp.asarray(plan.qt_cnt))
+    # numpy, not jnp: this builder is cached and is first called under
+    # whichever jit happens to trace it — a jnp array made here would be
+    # that trace's tracer, leaked into every later one
+    masks = plan.masks
+    kv = (plan.kv_idx, plan.kv_pid, plan.kv_cnt)
+    qt = (plan.qt_idx, plan.qt_pid, plan.qt_cnt)
     dkw = dict(dropout_rate=dropout_rate, total_heads=total_heads)
 
     @jax.custom_vjp

@@ -109,15 +109,50 @@ def attention(q, k, v, bias=None, mask=None, *, causal=False,
                               block_q=ring_block_q)
 
     drop_on = dropout_rate > 0.0 and not deterministic
+    source, reason = "explicit", None
     if backend is None:
-        backend = _auto_backend(q, k, bias, mask, drop_on, dropout_rng)
+        source = "auto"
+        backend, reason = _auto_backend(q, k, bias, mask, drop_on,
+                                        dropout_rng)
     elif backend == "pallas" and not _pallas_operands_ok(
             q, k, bias, mask, drop_on, dropout_rng):
-        # operand shapes the kernel's block specs can't express — honor
-        # the semantics over the explicit backend request
-        _warn_pallas_fallback()
-        backend = "reference"
+        raise ValueError(
+            "attn_backend='pallas' cannot be honoured: the flash kernel "
+            "needs 4-D bias/mask operands shaped [b|1, h|1, sq|1, sk] and "
+            f"an rng when dropout is live (q {q.shape}, bias "
+            f"{None if bias is None else bias.shape}, mask "
+            f"{None if mask is None else mask.shape}, dropout rng "
+            f"{'missing' if drop_on and dropout_rng is None else 'ok'}); "
+            "use attn_backend='reference' or leave it unset")
+    from ..pallas import tuning
+    from ..pallas._common import log_fallback_on_tpu
+    # shapes as THIS dispatch sees them: inside a shard_map region (the
+    # kernel-partition path below re-enters here per shard) they are one
+    # device's share, and that inner record is the one left standing
+    tuning.record_dispatch(
+        "attention", "backend",
+        f"sq{q.shape[-3]}_sk{k.shape[-3]}_d{q.shape[-1]}", source,
+        backend=backend, reason=reason, batch=q.shape[0],
+        heads=q.shape[-2])
+    if reason is not None:
+        log_fallback_on_tpu("attention", "reference", reason)
     if backend == "pallas":
+        mesh = _kernel_partition_mesh(q)
+        if mesh is not None:
+            # a Mosaic custom call cannot be auto-partitioned (jax
+            # refuses to compile one under a multi-device jit). Map it
+            # over the batch/head axes instead — the Ulysses region with
+            # a seq axis of 1 is exactly that.
+            from ...comm.mesh import DENSE_DP_AXES
+            from ...sequence_parallel import ulysses_attention
+            inner = functools.partial(attention, backend="pallas",
+                                      seq_parallel="none")
+            return ulysses_attention(
+                q, k, v, bias=bias, mask=mask, causal=causal,
+                softmax_scale=softmax_scale, dropout_rate=dropout_rate,
+                dropout_rng=dropout_rng, deterministic=deterministic,
+                attn_fn=inner, mesh=mesh, batch_axes=DENSE_DP_AXES,
+                local_region=True)
         from ..pallas import flash_attention
         return flash_attention(
             q, k, v, bias=_combined_bias(bias, mask), causal=causal,
@@ -204,26 +239,27 @@ def _warn_sp_fallback():
                   "operand dims) require the replicated path; falling back")
 
 
-@functools.lru_cache(None)
-def _warn_pallas_fallback():
-    import warnings
-    warnings.warn("attn_backend='pallas' requested but the bias/mask "
-                  "operand shapes (or dropout without an rng) require the "
-                  "reference path; falling back")
-
-
-def _on_tpu():
-    from ..pallas._common import on_tpu
-    return on_tpu()
-
-
-@functools.lru_cache(None)
-def _pallas_available():
-    try:
-        from ..pallas import flash_attention  # noqa: F401
-        return True
-    except Exception:
-        return False
+def _kernel_partition_mesh(q):
+    """The global mesh when a Pallas attention call must be mapped over
+    it by hand: the batch or the heads would really be split (an axis of
+    more than one device divides them), and we are not already inside a
+    manual (shard_map) region. None otherwise — with nothing to split
+    the call stays on the devices its operands live on. Like the
+    sequence-parallel choice above, this dispatch reads the mesh of the
+    engine that is tracing: the trainer installs its own for its
+    lifetime, an InferenceEngine scopes its own around its traces."""
+    from ...comm.mesh import DENSE_DP_AXES, axis_size, peek_global_mesh
+    from ...sequence_parallel.ulysses import _fit_axes
+    from ..pallas._common import in_manual_region
+    mesh = peek_global_mesh()
+    if mesh is None or q.ndim != 4 or in_manual_region():
+        return None
+    # the axes the region's specs would really put on batch and heads
+    split = [_fit_axes(q.shape[0], DENSE_DP_AXES, mesh),
+             _fit_axes(q.shape[2], "model", mesh)]
+    if all(a is None or axis_size(a, mesh) == 1 for a in split):
+        return None
+    return mesh
 
 
 def _pallas_operands_ok(q, k, bias, mask, drop_on, dropout_rng):
@@ -245,10 +281,18 @@ def _pallas_operands_ok(q, k, bias, mask, drop_on, dropout_rng):
 
 
 def _auto_backend(q, k, bias, mask, drop_on, dropout_rng):
+    """("pallas", None) when the flash kernel can take the call, else
+    ("reference", reason) — the reason lands in the dispatch record and,
+    on TPU, in the log."""
+    from ..pallas._common import on_tpu
     head_dim = q.shape[-1]
     seq = q.shape[-3]
-    eligible = (_on_tpu() and _pallas_available()
-                and head_dim in (64, 128, 256) and seq % 128 == 0
-                and _pallas_operands_ok(q, k, bias, mask, drop_on,
-                                        dropout_rng))
-    return "pallas" if eligible else "reference"
+    if not on_tpu():
+        return "reference", "platform is not tpu"
+    if head_dim not in (64, 128, 256):
+        return "reference", f"head_dim {head_dim} not in (64, 128, 256)"
+    if seq % 128 != 0:
+        return "reference", f"seq {seq} not a multiple of 128"
+    if not _pallas_operands_ok(q, k, bias, mask, drop_on, dropout_rng):
+        return "reference", "bias/mask operand shapes or missing dropout rng"
+    return "pallas", None

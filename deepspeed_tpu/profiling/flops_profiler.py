@@ -34,8 +34,6 @@ def analyze_fn(fn: Callable, *args, static_argnums=(), **kwargs) -> Dict[str, An
     lowered = jax.jit(fn, static_argnums=static_argnums).lower(*args, **kwargs)
     compiled = lowered.compile()
     cost = compiled.cost_analysis() or {}
-    if isinstance(cost, list):  # older jax returns [dict]
-        cost = cost[0] if cost else {}
     mem = {}
     try:
         ma = compiled.memory_analysis()

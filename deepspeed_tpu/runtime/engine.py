@@ -31,8 +31,9 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P, NamedSharding
 
 from .. import comm as dist
-from ..comm.mesh import DENSE_DP_AXES
-from ..models.layers import set_activation_rules
+from ..comm.mesh import DENSE_DP_AXES, peek_global_mesh
+from ..models.layers import (activation_rules_installed,
+                             set_activation_rules)
 from ..observability.goodput import get_ledger as _goodput_ledger
 from ..observability.goodput import timed as _goodput
 from ..observability.programs import track_program
@@ -112,7 +113,7 @@ class DeepSpeedEngine:
         self._apply_activation_checkpointing_config()
         self._apply_param_offload_config()
         self._warn_inert_zero_knobs()
-        set_activation_rules(self._activation_rules)
+        self._installed_rules = set_activation_rules(self._activation_rules)
 
         # ---- precision ----------------------------------------------
         self.fp16_enabled = config.fp16.enabled
@@ -504,6 +505,19 @@ class DeepSpeedEngine:
                     "(one block live at a time) and prefetch is scheduled by "
                     "XLA; use stage3_param_persistence_threshold to control "
                     "which params stay replicated")
+        if (self.zero_stage == 3 and self.mesh.shape.get("fsdp", 1) == 1
+                and self.dp_world_size > 1):
+            # found bringing gpt2-1.3b up on four chips (PR 21): the
+            # reference partitions stage-3 params over the DP group, this
+            # engine over the mesh's fsdp axis (docs/config.md) — on a
+            # data-only mesh stage 3 trains, with every parameter whole
+            # on every device
+            logger.warning(
+                f"zero_optimization.stage 3 on a mesh with fsdp=1: "
+                f"parameters stay REPLICATED over the {self.dp_world_size} "
+                "data-parallel devices (optimizer state and gradients are "
+                "still partitioned). Parameter partitioning rides the "
+                "mesh's fsdp axis — set \"mesh\": {\"fsdp\": N}.")
 
     def _remember_extra(self, extra, loss_kwargs):
         """Record the step's extra-operand STRUCTURE for later consumers
@@ -1603,7 +1617,15 @@ class DeepSpeedEngine:
     def destroy(self):
         """Release engine-held background resources: the async
         checkpointer's worker (after joining any pending save) and the
-        NVMe param swapper's aio threads (reference: engine.destroy)."""
+        NVMe param swapper's aio threads (reference: engine.destroy) —
+        and the process-global trace state this engine installed (its
+        mesh, its activation-sharding rules), if still in place: an
+        engine built after this one must not trace under them."""
+        if activation_rules_installed(getattr(self, "_installed_rules",
+                                              None)):
+            set_activation_rules({})
+        if peek_global_mesh() is getattr(self, "mesh", None):
+            dist.set_global_mesh(None)
         obs = getattr(self, "observability", None)
         if obs is not None:
             obs.close()   # release the module-global tracer if held
@@ -1954,15 +1976,32 @@ def _init_kwargs(sample_batch):
     return {"input_ids": jnp.asarray(sample_batch)}
 
 
+class ParamOffloadUnsupportedError(DeepSpeedConfigError):
+    """Training-side parameter offload cannot run on this TPU stack."""
+
+
 def _host_kind(sharding):
-    """One sharding moved to pinned host memory (no-op on CPU backends)."""
+    """One PARAMETER sharding moved to pinned host memory (no-op on CPU
+    backends, where device memory is host RAM). A backend that cannot
+    express ``pinned_host`` raises: an offload config that cannot offload
+    is an error, not a warning."""
     if jax.default_backend() == "cpu":
         return sharding
-    try:
-        return sharding.with_memory_kind("pinned_host")
-    except Exception:
-        logger.warning("pinned_host unsupported; param offload inert")
-        return sharding
+    if jax.default_backend() == "tpu":
+        # measured on a v5e, jax 0.9.0 / libtpu 0.0.34 (PR 21): with block
+        # params in pinned_host the train step aborts the PROCESS inside
+        # XLA's host-offload pass (host_offload_utils.cc: "Expecting
+        # instruction add to have 1 operand") — the host-space parameter
+        # cotangents meet an add. No Python error could be caught there,
+        # so refuse before compiling. ROADMAP Queue 1 has the repair.
+        raise ParamOffloadUnsupportedError(
+            "training-side parameter offload (zero_optimization."
+            "offload_param, tiering.offload_params with a host plan) is "
+            "not supported on TPU with this JAX/XLA: the compiled step "
+            "aborts in XLA's host-offload pass. Optimizer-state offload "
+            "(offload_optimizer) and serving-side init_inference("
+            "offload_params=True) do work on the chip.")
+    return sharding.with_memory_kind("pinned_host")
 
 
 def _with_host_memory(shardings):

@@ -9,16 +9,15 @@ TPU-native: every optimizer is an optax ``GradientTransformation`` operating
 on the fp32 master params (the model computes in bf16/fp16 via flax's dtype
 casting — this replaces the reference's fp16 master-weight optimizers,
 runtime/fp16/fused_optimizer.py). "Fused" variants resolve to the Pallas
-fused kernels in deepspeed_tpu.ops when available, else to optax (XLA fuses
-the update chain anyway — the Pallas path exists to beat it on HBM traffic
-for very large flat shards).
+fused kernels in deepspeed_tpu.ops (which exist to beat XLA's fused update
+chain on HBM traffic for very large flat shards); a kernel that cannot be
+built raises rather than giving way to optax.
 """
 
 from typing import Callable, Optional, Union
 
 import optax
 
-from ..utils.logging import logger
 
 ADAM_OPTIMIZER = "adam"
 ADAMW_OPTIMIZER = "adamw"
@@ -61,11 +60,8 @@ def build_optimizer(opt_type: str, params: dict,
 
     if name in (ADAM_OPTIMIZER, FUSED_ADAM, CPU_ADAM):
         if name == FUSED_ADAM and use_pallas:
-            try:
-                from ..ops.pallas.fused_adam import fused_adamw
-                return fused_adamw(lr, weight_decay=wd, **_adam_args(params))
-            except Exception as e:  # pragma: no cover
-                logger.warning(f"Pallas fused adam unavailable ({e}); using optax")
+            from ..ops.pallas.fused_adam import fused_adamw
+            return fused_adamw(lr, weight_decay=wd, **_adam_args(params))
         if wd > 0 and params.get("adam_w_mode", True):
             return optax.adamw(lr, weight_decay=wd, **_adam_args(params))
         tx = optax.adam(lr, **_adam_args(params))
@@ -78,14 +74,11 @@ def build_optimizer(opt_type: str, params: dict,
 
     if name in (LAMB_OPTIMIZER, FUSED_LAMB):
         if name == FUSED_LAMB and use_pallas:
-            try:
-                from ..ops.pallas.fused_lamb import fused_lamb
-                return fused_lamb(lr, weight_decay=wd,
-                                  eps=params.get("eps", 1e-6),
-                                  b1=params.get("betas", (0.9, 0.999))[0],
-                                  b2=params.get("betas", (0.9, 0.999))[1])
-            except Exception as e:  # pragma: no cover
-                logger.warning(f"Pallas fused lamb unavailable ({e}); using optax")
+            from ..ops.pallas.fused_lamb import fused_lamb
+            return fused_lamb(lr, weight_decay=wd,
+                              eps=params.get("eps", 1e-6),
+                              b1=params.get("betas", (0.9, 0.999))[0],
+                              b2=params.get("betas", (0.9, 0.999))[1])
         return optax.lamb(lr, weight_decay=wd, **_adam_args(params))
 
     if name == ADAGRAD_OPTIMIZER:

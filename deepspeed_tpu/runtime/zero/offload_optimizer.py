@@ -258,8 +258,7 @@ class StreamedHostAdam:
     ``utils.streaming.double_buffered``), so the transfer and compute
     chains stay exactly one leaf apart for the scheduler to overlap.
     Unlike the native path, traffic rides the accelerator host's PCIe —
-    nothing crosses the client process, so it works at full speed on
-    remote/tunneled backends.
+    nothing crosses the client process.
 
     Update math matches ``build_optimizer``'s Adam/AdamW exactly
     (bias-corrected moments; adamw=True -> decoupled weight decay,
@@ -398,14 +397,10 @@ def _with_host_memory_tree(shardings):
     if jax.default_backend() == "cpu":
         return shardings   # CPU device memory IS host RAM
 
-    def to_host(s):
-        try:
-            return s.with_memory_kind("pinned_host")
-        except Exception:
-            logger.warning("pinned_host memory kind unsupported; optimizer "
-                           "state stays in device memory")
-            return s
-    return jax.tree.map(to_host, shardings,
+    # a backend that cannot express pinned_host raises here: an offload
+    # config whose optimizer state stays in device memory is an error
+    return jax.tree.map(lambda s: s.with_memory_kind("pinned_host"),
+                        shardings,
                         is_leaf=lambda x: isinstance(x, NamedSharding))
 
 
@@ -416,9 +411,6 @@ def _index_key(index) -> str:
 def _device_memory(sharding):
     """The same sharding placed in default device memory (grads arrive in
     pinned_host; the rebuilt params go straight to HBM)."""
-    try:
-        if getattr(sharding, "memory_kind", None) not in (None, "device"):
-            return sharding.with_memory_kind("device")
-    except Exception:
-        pass
+    if getattr(sharding, "memory_kind", None) not in (None, "device"):
+        return sharding.with_memory_kind("device")
     return sharding

@@ -70,7 +70,7 @@ def ulysses_attention(q, k, v, *, bias=None, mask=None, causal=False,
                       softmax_scale=None, dropout_rate=0.0, dropout_rng=None,
                       deterministic=True, attn_fn=None, mesh=None,
                       axis_name=_SEQ_AXIS, batch_axes=_BATCH_AXES,
-                      head_axis=_HEAD_AXIS):
+                      head_axis=_HEAD_AXIS, local_region=False):
     """Full-sequence attention over seq-sharded inputs, [B, S, H, D] global.
 
     ``attn_fn(q, k, v, causal=..., softmax_scale=...)`` is the local
@@ -87,6 +87,11 @@ def ulysses_attention(q, k, v, *, bias=None, mask=None, causal=False,
     the replicated sample — nothing of shape [sq, sk] is ever
     materialized (on TPU the flash kernel samples in-tile; the dense
     fallback fuses the hash into the softmax chain).
+
+    ``local_region=True`` keeps the shard_map region even when the seq
+    axis is 1 (the all-to-alls drop out): the attention dispatch uses it
+    to run a Pallas core per batch/head shard, which GSPMD cannot
+    partition by itself.
     """
     mesh = mesh or get_global_mesh()
     sp = mesh.shape[axis_name]
@@ -97,7 +102,7 @@ def ulysses_attention(q, k, v, *, bias=None, mask=None, causal=False,
     if dropout_on and dropout_rng is None:
         raise ValueError("ulysses_attention: dropout_rate > 0 with "
                          "deterministic=False requires dropout_rng")
-    if sp == 1:
+    if sp == 1 and not local_region:
         # keep the documented (q, k, v, causal=, softmax_scale=) attn_fn
         # contract when no operands ride along; only operand-carrying
         # calls need the full attention() signature
@@ -143,9 +148,11 @@ def ulysses_attention(q, k, v, *, bias=None, mask=None, causal=False,
 
     def local_fn(q, k, v, *extra):
         ops = dict(zip(extra_names, extra))
-        # [b, s/sp, h, d] -> [b, s, h/sp, d]: the head<->seq swap
-        q, k, v = (lax.all_to_all(t, axis_name, split_axis=2, concat_axis=1,
-                                  tiled=True) for t in (q, k, v))
+        if sp > 1:
+            # [b, s/sp, h, d] -> [b, s, h/sp, d]: the head<->seq swap
+            q, k, v = (lax.all_to_all(t, axis_name, split_axis=2,
+                                      concat_axis=1, tiled=True)
+                       for t in (q, k, v))
         kwargs = {n: t for n, t in ops.items() if n != "dropout_rng"}
         if dropout_on:
             # global coordinates of this device's head/batch window, so
@@ -165,6 +172,8 @@ def ulysses_attention(q, k, v, *, bias=None, mask=None, causal=False,
                           dropout_offsets=(n_heads, head_off, batch_off))
         out = attn_fn(q, k, v, causal=causal, softmax_scale=softmax_scale,
                       **kwargs)
+        if sp == 1:
+            return out
         # [b, s, h/sp, d] -> [b, s/sp, h, d]
         return lax.all_to_all(out, axis_name, split_axis=1, concat_axis=2,
                               tiled=True)

@@ -51,6 +51,7 @@ every queued + active request over a rebuilt device state. With the
 block absent the pre-QoS FIFO engine runs untouched.
 """
 
+import contextlib
 import os
 import threading
 import time
@@ -176,7 +177,8 @@ class ServingEngine:
     """
 
     def __init__(self, module, params, config: Optional[ServingConfig] = None,
-                 *, param_transform=None, monitor=None, rng=None, **overrides):
+                 *, param_transform=None, monitor=None, rng=None,
+                 trace_scope=contextlib.nullcontext, **overrides):
         if config is None:
             config = ServingConfig(**overrides)
         elif isinstance(config, dict):
@@ -188,6 +190,10 @@ class ServingEngine:
         self.module = module
         self.params = params
         self._param_transform = param_transform
+        # entered around each iteration's device dispatches, where the
+        # programs trace: InferenceEngine.serve() passes the scope that
+        # makes its mesh the global one (the mesh is read at trace time)
+        self._trace_scope = trace_scope
         if self.config.weights_int8:
             # checkpoint->int8 weight-only serving (serving.quantize.
             # weights): the shared module_inject pipeline step — direct
@@ -660,20 +666,22 @@ class ServingEngine:
         if self._watchdog is not None:
             self._watchdog.step_started()
         try:
-            if self._paged is not None:
-                self._admit_ready_paged()
-                self._run_prefill_chunks()
-            else:
-                self._admit_ready()
-            if self.prefill_only:
-                # prefill role: no decode ever dispatches (the decode
-                # replica owns generation past token 1), but the
-                # deterministic iteration clock still ticks — deadline
-                # sweeps and the fleet's lockstep replay depend on it
-                dispatched = False
-                self._iteration += 1
-            else:
-                dispatched = self._dispatch_decode()
+            with self._trace_scope():
+                if self._paged is not None:
+                    self._admit_ready_paged()
+                    self._run_prefill_chunks()
+                else:
+                    self._admit_ready()
+                if self.prefill_only:
+                    # prefill role: no decode ever dispatches (the decode
+                    # replica owns generation past token 1), but the
+                    # deterministic iteration clock still ticks —
+                    # deadline sweeps and the fleet's lockstep replay
+                    # depend on it
+                    dispatched = False
+                    self._iteration += 1
+                else:
+                    dispatched = self._dispatch_decode()
             # keep at most pipeline_depth dispatches in flight; drain fully
             # when nothing new was dispatched (tail of the workload)
             target = self.config.pipeline_depth if dispatched else 0

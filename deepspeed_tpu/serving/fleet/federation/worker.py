@@ -245,8 +245,8 @@ class FederationWorkerServer:
 
 def serve_listen(address: str,
                  max_frame_bytes: int = DEFAULT_MAX_FRAME_BYTES) -> int:
-    from deepspeed_tpu.utils.host_env import honor_jax_platforms_env
-    honor_jax_platforms_env()
+    from deepspeed_tpu.utils.host_env import configure_compile_cache
+    configure_compile_cache()
     host, port = parse_address(address)
     server = FederationWorkerServer(host, port,
                                     max_frame_bytes=max_frame_bytes)

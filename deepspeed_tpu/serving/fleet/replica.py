@@ -293,8 +293,20 @@ class ProcessReplica:
                               # folded into queue_depth so a same-step
                               # burst spreads instead of piling onto one
                               # stale snapshot
+        # one process per chip: this parent has imported the fleet and
+        # so holds whatever device JAX gave it. Workers get the parent's
+        # platform explicitly; under a TPU parent they could only fail
+        # or hang reaching the chip — or, worse, serve from CPU silently.
+        import jax
+        platform = jax.default_backend()
+        if platform == "tpu":
+            raise ValueError(
+                "fleet backend 'process' cannot start under a parent whose "
+                "JAX platform is tpu: the parent holds the chip, so worker "
+                "subprocesses cannot reach it. Use FleetConfig(backend="
+                "'inprocess') — one device per replica in this process.")
         env = dict(os.environ)
-        env.setdefault("JAX_PLATFORMS", "cpu")
+        env["JAX_PLATFORMS"] = platform
         # binary pipes + an explicit byte buffer: select() watches the
         # raw fd, so a buffering text wrapper could strand a complete
         # reply line in userspace while select blocks on a drained fd

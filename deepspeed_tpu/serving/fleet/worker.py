@@ -288,8 +288,8 @@ class _Worker:
 
 
 def main():
-    from ...utils.host_env import honor_jax_platforms_env
-    honor_jax_platforms_env()
+    from ...utils.host_env import configure_compile_cache
+    configure_compile_cache()
     first = sys.stdin.readline()
     if not first:
         return 2

@@ -259,17 +259,31 @@ class PagedKVManager:
         exactly where it is the proven win: real TPU with an aligned
         page_len. CPU runs stay on the gather path by default so
         replay/bit-reproducibility contracts hold."""
-        from ...ops.pallas._common import interpret_mode
+        from ...ops.pallas import tuning
+        from ...ops.pallas._common import interpret_mode, log_fallback_on_tpu
         aligned = self.page_len % 128 == 0
         if mode == "on":
             if not (aligned or interpret_mode()):
                 raise ValueError(
                     f"serving.paging.kernel='on' needs page_len % 128 == "
                     f"0 on TPU (got {self.page_len})")
-            return True
-        if mode == "off":
-            return False
-        return aligned and not interpret_mode()
+            use, reason = True, None
+        elif mode == "off":
+            use, reason = False, "serving.paging.kernel='off'"
+        elif interpret_mode():
+            use, reason = False, "platform is not tpu"
+        else:
+            use = aligned
+            reason = (None if aligned else
+                      f"page_len {self.page_len} not a multiple of 128")
+        # the engine-level choice (kernel vs gather-then-contiguous-
+        # decode), visible next to the kernel-level records
+        tuning.record_dispatch(
+            "paged_decode", "path", f"page{self.page_len}", mode,
+            impl="kernel" if use else "gather", reason=reason)
+        if not use:
+            log_fallback_on_tpu("serving paged decode", "gather", reason)
+        return use
 
     def _build_pool(self):
         """Fresh zeroed pool; ``dequant_dtype`` records the model's KV

@@ -1,36 +1,6 @@
-"""Small compatibility layer over jax API drift.
-
-Keeps the rest of the framework on one spelling of shard_map regardless of
-jax version (0.8 experimental check_rep vs 0.9 jax.shard_map check_vma),
-and installs the ``jax.tree.*_with_path`` aliases on versions that only
-ship them under ``jax.tree_util`` (pre-0.5).
-"""
-
-import inspect
-import functools
+"""The framework's one spelling of ``jax.shard_map``."""
 
 import jax
-
-# jax.tree.{flatten,leaves,map}_with_path landed after the pinned CI jax;
-# alias the identical tree_util functions so the whole framework (and
-# future jax) use ONE spelling. No-op on jax versions that have them.
-if not hasattr(jax.tree, "flatten_with_path"):  # pragma: no branch
-    import jax.tree_util as _tree_util
-    jax.tree.flatten_with_path = _tree_util.tree_flatten_with_path
-    jax.tree.leaves_with_path = _tree_util.tree_leaves_with_path
-    jax.tree.map_with_path = _tree_util.tree_map_with_path
-
-
-@functools.lru_cache(None)
-def _shard_map_fn_and_kw():
-    if hasattr(jax, "shard_map"):
-        fn = jax.shard_map
-    else:  # pragma: no cover - older jax
-        from jax.experimental.shard_map import shard_map as fn
-    params = inspect.signature(fn).parameters
-    if "check_vma" in params:
-        return fn, "check_vma"
-    return fn, "check_rep"
 
 
 def shard_map(f, mesh, in_specs, out_specs, check=False, axis_names=None):
@@ -40,12 +10,8 @@ def shard_map(f, mesh, in_specs, out_specs, check=False, axis_names=None):
     ``axis_names``: map over only these mesh axes; the rest stay under
     automatic GSPMD partitioning (used by the pipeline engine to permute
     over "stage" while data/model axes shard transparently)."""
-    fn, kw = _shard_map_fn_and_kw()
-    kwargs = {kw: check}
+    kwargs = {"check_vma": check}
     if axis_names is not None:
-        if "axis_names" not in inspect.signature(fn).parameters:
-            raise NotImplementedError(
-                "this jax version's shard_map lacks axis_names (partial "
-                "manual axes); upgrade jax for pipeline parallelism")
         kwargs["axis_names"] = set(axis_names)
-    return fn(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kwargs)
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, **kwargs)

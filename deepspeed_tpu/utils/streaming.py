@@ -16,41 +16,31 @@ host-side too — device residency stays bounded by the live block.
 
 import jax
 
-# jax.memory.Space (typed memory-space placement) postdates some pinned
-# CI/runtime jax versions. Where it is absent, memory kinds cannot be
-# expressed at all and every "fetch"/"home" placement is the identity —
-# so the streaming layer degrades to identity functions with the SAME
-# call surface, keeping offload configs loadable (and mathematically
-# exact) on such versions instead of crashing at trace time.
-HAS_MEMORY_SPACE = hasattr(jax, "memory") and hasattr(jax.memory, "Space")
 
-if HAS_MEMORY_SPACE:
-    @jax.custom_vjp
-    def stream_in(x):
-        """Host -> device fetch (identity math). Under remat the fetch
-        replays in the backward recompute — the reference fetches params
-        for the backward walk the same way. The vjp returns the
-        cotangent in the PRIMAL's memory space (host params get host
-        grads; no-op for device-resident params, e.g. on the CPU test
-        backend where memory kinds don't exist)."""
-        return jax.device_put(x, jax.memory.Space.Device)
+@jax.custom_vjp
+def stream_in(x):
+    """Host -> device fetch (identity math). Under remat the fetch
+    replays in the backward recompute — the reference fetches params
+    for the backward walk the same way. The vjp returns the
+    cotangent in the PRIMAL's memory space (host params get host
+    grads; no-op for device-resident params, e.g. on the CPU test
+    backend where memory kinds don't exist)."""
+    return jax.device_put(x, jax.memory.Space.Device)
 
-    def _stream_in_fwd(x):
-        # zero-sized residual carries the primal's memory space (aval-static)
-        return stream_in(x), x.ravel()[:0]
 
-    def _stream_in_bwd(res, ct):
-        space = res.aval.memory_space
-        if ct.aval.memory_space == space:
-            return (ct,)
-        return (jax.device_put(ct, space),)
+def _stream_in_fwd(x):
+    # zero-sized residual carries the primal's memory space (aval-static)
+    return stream_in(x), x.ravel()[:0]
 
-    stream_in.defvjp(_stream_in_fwd, _stream_in_bwd)
-else:  # pragma: no cover - version-dependent
-    def stream_in(x):
-        """Identity on jax versions without jax.memory.Space: no memory
-        kinds exist, so the fetch has nothing to move."""
-        return x
+
+def _stream_in_bwd(res, ct):
+    space = res.aval.memory_space
+    if ct.aval.memory_space == space:
+        return (ct,)
+    return (jax.device_put(ct, space),)
+
+
+stream_in.defvjp(_stream_in_fwd, _stream_in_bwd)
 
 
 def stream_in_tree(tree):
@@ -83,10 +73,7 @@ def double_buffered(items, fetch):
 
 
 def to_host_tree(tree):
-    """Place a pytree in host memory space (init-time placement);
-    identity where typed memory spaces are unavailable."""
-    if not HAS_MEMORY_SPACE:
-        return tree
+    """Place a pytree in host memory space (init-time placement)."""
     return jax.tree.map(
         lambda x: jax.device_put(x, jax.memory.Space.Host), tree)
 

@@ -34,23 +34,26 @@ def test_help_exits_zero(script):
     assert "usage" in r.stdout.lower()
 
 
-def test_lint_gate_subprocess(tmp_path):
+def test_lint_gate_subprocess():
     """The CI gate invocation, as a real subprocess — with the accelerator
-    stack genuinely blocked (a sitecustomize import hook raises on
-    jax/numpy/flax), proving the lint job needs no dependency install."""
-    (tmp_path / "sitecustomize.py").write_text(
-        "import sys, importlib.abc\n"
+    stack genuinely blocked (an import hook installed before the script
+    runs raises on jax/numpy/flax), proving the lint job needs no
+    dependency install."""
+    script = os.path.join(BIN, "ds_tpu_lint")
+    shim = (
+        "import sys, importlib.abc, runpy\n"
         "class _B(importlib.abc.MetaPathFinder):\n"
         "    def find_spec(self, fullname, path=None, target=None):\n"
         "        if fullname.split('.')[0] in ('jax', 'jaxlib', 'numpy',\n"
         "                                      'flax', 'optax', 'torch'):\n"
         "            raise ImportError('blocked by test: ' + fullname)\n"
-        "sys.meta_path.insert(0, _B())\n")
-    r = _run([os.path.join(BIN, "ds_tpu_lint"),
+        "sys.meta_path.insert(0, _B())\n"
+        f"sys.argv = [{script!r}] + sys.argv[1:]\n"
+        f"runpy.run_path({script!r}, run_name='__main__')\n")
+    r = _run(["-c", shim,
               os.path.join(REPO, "deepspeed_tpu"),
               "--baseline", os.path.join(REPO, ".ds_tpu_lint_baseline.json"),
-              "-q"],
-             env_extra={"PYTHONPATH": str(tmp_path)})
+              "-q"])
     assert r.returncode == 0, r.stdout[-2000:] + r.stderr[-500:]
     assert "0 new" in r.stdout
 
@@ -147,8 +150,8 @@ def test_serve_qos_smoke(tmp_path):
 def test_serve_crash_leaves_partial_snapshot_and_exits_nonzero(tmp_path):
     """The fault-containment satellite: a serving loop that dies mid-run
     (chaos hook --inject-crash-at) exits NONZERO and still leaves the
-    partial metrics snapshot — stdout JSON + the sidecar file (the
-    bench.py partial-artifact pattern; a crash used to leave nothing)."""
+    partial metrics snapshot — stdout JSON + the sidecar file (a crash
+    used to leave nothing)."""
     out = tmp_path / "metrics.json"
     r = _run([os.path.join(BIN, "ds_tpu_serve"), "--synthetic", "4",
               "--num-slots", "2", "--max-len", "48", "--prefill-bucket",

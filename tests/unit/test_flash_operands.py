@@ -172,9 +172,6 @@ class TestDispatch:
         """The r4 behavior warned and ran the dense core; operands now
         ride the kernel."""
         import warnings as w
-        attn_mod = importlib.import_module(
-            "deepspeed_tpu.ops.transformer.attention")
-        attn_mod._warn_pallas_fallback.cache_clear()
         q, k, v = _qkv(seed=5)
         mask = jnp.ones((B, 1, 1, S), bool).at[:, :, :, -5:].set(False)
         with w.catch_warnings():
@@ -190,23 +187,41 @@ class TestDispatch:
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                    rtol=2e-5, atol=2e-5)
 
-    def test_unsupported_shape_still_falls_back(self):
-        """A 3-D mask the block specs can't express warns and uses the
-        dense path instead of miscomputing."""
-        attn_mod = importlib.import_module(
-            "deepspeed_tpu.ops.transformer.attention")
-        attn_mod._warn_pallas_fallback.cache_clear()
+    def test_explicit_pallas_on_unsupported_operands_raises(self):
+        """An explicit backend="pallas" the kernel's block specs can't
+        honour raises — it neither miscomputes nor quietly runs the
+        dense path."""
         q, k, v = _qkv(seed=6)
         # broadcast-sk bias: dense broadcasts it, but the kernel's block
         # specs require the sk dim at full extent
         bad_bias = jnp.asarray(
             np.random.default_rng(0).standard_normal((1, H, S, 1)),
             jnp.float32)
-        with pytest.warns(UserWarning, match="falling back"):
-            out = attention(q, k, v, bias=bad_bias,
-                            causal=True, backend="pallas",
-                            seq_parallel="none")
-        assert np.isfinite(np.asarray(out)).all()
+        with pytest.raises(ValueError, match="cannot be honoured"):
+            attention(q, k, v, bias=bad_bias, causal=True,
+                      backend="pallas", seq_parallel="none")
+        # live dropout without an rng is the other refused operand set
+        with pytest.raises(ValueError, match="dropout rng missing"):
+            attention(q, k, v, causal=True, dropout_rate=RATE,
+                      deterministic=False, backend="pallas",
+                      seq_parallel="none")
+
+    def test_auto_choice_lands_in_the_dispatch_record(self):
+        """auto keeps choosing the reference path off-TPU, and says so
+        where the chip smoke reads it."""
+        from deepspeed_tpu.ops.pallas import tuning
+        q, k, v = _qkv(seed=8)
+        tuning.clear_last_dispatch()
+        attention(q, k, v, causal=True, seq_parallel="none")
+        rec = tuning.last_dispatch("attention")["backend"]
+        assert rec["backend"] == "reference" and rec["source"] == "auto"
+        assert rec["reason"] == "platform is not tpu"
+        assert rec["interpret"] is True
+        attention(q, k, v, causal=True, backend="pallas",
+                  seq_parallel="none")
+        rec = tuning.last_dispatch("attention")["backend"]
+        assert rec["backend"] == "pallas" and rec["source"] == "explicit"
+        assert rec["reason"] is None
 
     def test_reference_and_pallas_dropout_bits_identical(self):
         """Cross-backend parity: the SAME rng gives the SAME dropout

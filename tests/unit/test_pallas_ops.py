@@ -365,80 +365,8 @@ class TestOneBitLamb:
         assert float(jnp.max(jnp.abs(s.error["w"]))) > 0
 
 
-class TestGemvCalibrationRouting:
-    """m=1 routing consults the committed hardware-calibration artifact
-    (tools/validate_gemv.py output) so the default flips autonomously
-    once tpu_watch captures numbers; the env flag always wins."""
-
-    def _routing(self, monkeypatch, tmp_path, artifact=None, env=None):
-        import importlib
-        import json
-        mod = importlib.import_module(
-            "deepspeed_tpu.ops.pallas.wo_int8_matmul")
-        mod._gemv_calibration.cache_clear()
-        monkeypatch.setenv("DS_TPU_GEMV_CALIBRATION_DIR", str(tmp_path))
-        if artifact is not None:
-            (tmp_path / "gemv_r5_t.json").write_text(json.dumps(artifact))
-        if env is None:
-            monkeypatch.delenv("DS_TPU_INT8_GEMV", raising=False)
-        else:
-            monkeypatch.setenv("DS_TPU_INT8_GEMV", env)
-        try:
-            return mod._gemv_enabled()
-        finally:
-            mod._gemv_calibration.cache_clear()
-
-    def test_no_artifact_defaults_off(self, monkeypatch, tmp_path):
-        assert self._routing(monkeypatch, tmp_path) is False
-
-    def test_artifact_recommendation_flips_default(self, monkeypatch,
-                                                   tmp_path):
-        art = {"mxu_gbps": 146.0, "gemv_gbps": 700.0, "speedup": 4.79,
-               "recommend_default_gemv": True}
-        assert self._routing(monkeypatch, tmp_path, artifact=art) is True
-        art["recommend_default_gemv"] = False
-        assert self._routing(monkeypatch, tmp_path, artifact=art) is False
-
-    def test_env_flag_overrides_artifact(self, monkeypatch, tmp_path):
-        art = {"speedup": 4.5, "recommend_default_gemv": True}
-        assert self._routing(monkeypatch, tmp_path, artifact=art,
-                             env="0") is False
-        # ANY set value is an override — '' is false per env_flag, so
-        # `export DS_TPU_INT8_GEMV=` still forces the GEMV off
-        assert self._routing(monkeypatch, tmp_path, artifact=art,
-                             env="") is False
-        art = {"speedup": 0.9, "recommend_default_gemv": False}
-        assert self._routing(monkeypatch, tmp_path, artifact=art,
-                             env="1") is True
-
-    def test_partial_diagnostic_does_not_revoke_complete_calibration(
-            self, monkeypatch, tmp_path):
-        import json
-        # older complete run says flip; newer wedged diagnostic (no
-        # "speedup") must NOT revoke it
-        (tmp_path / "gemv_r5_a.json").write_text(json.dumps(
-            {"speedup": 4.5, "recommend_default_gemv": True}))
-        art = {"stage1_ok": False, "stage1_error": "timeout",
-               "recommend_default_gemv": False}
-        assert self._routing(monkeypatch, tmp_path, artifact=art) is True
-
-
 class TestWOInt8Matmul:
     """Fused-dequant int8 matmul (reference: pt_binding.cpp int8 gemms)."""
-
-    @pytest.fixture(autouse=True)
-    def _no_calibration(self, monkeypatch, tmp_path):
-        """Pin calibration-driven m=1 routing to its no-artifact default:
-        once tpu_watch commits a real gemv_r5_*.json into
-        benchmarks/results, unset-env test runs would silently flip to
-        the GEMV path and lose MXU coverage."""
-        import importlib
-        mod = importlib.import_module(
-            "deepspeed_tpu.ops.pallas.wo_int8_matmul")
-        monkeypatch.setenv("DS_TPU_GEMV_CALIBRATION_DIR", str(tmp_path))
-        mod._gemv_calibration.cache_clear()
-        yield
-        mod._gemv_calibration.cache_clear()
 
     def _mk(self, m, k, n, seed=0):
         key = jax.random.PRNGKey(seed)

@@ -698,35 +698,6 @@ class TestSnapshotDiff:
 
 
 # ---------------------------------------------------------------------------
-# bench partial-failure artifact (satellite)
-# ---------------------------------------------------------------------------
-
-class TestBenchFailureArtifact:
-    def test_failure_artifact_schema(self):
-        import bench
-        art = bench.failure_artifact("backend unreachable",
-                                     {"decode": {"p50": 1.0}})
-        assert art["failed"] is True
-        assert art["reason"] == "backend unreachable"
-        assert art["metric"] == bench.NORTH_STAR_METRIC
-        assert art["value"] is None
-        assert art["extra"] == {"decode": {"p50": 1.0}}
-        json.dumps(art)               # JSON-able end to end
-
-    def test_emit_failure_writes_sidecar(self, tmp_path, capsys,
-                                         monkeypatch):
-        import bench
-        monkeypatch.chdir(tmp_path)
-        bench.emit_failure("killed by signal 15", {"partial": 1})
-        out = capsys.readouterr().out
-        parsed = json.loads(out.strip().splitlines()[-1])
-        assert parsed["failed"] and parsed["extra"] == {"partial": 1}
-        sidecar = json.loads(
-            (tmp_path / bench.PARTIAL_ARTIFACT_PATH).read_text())
-        assert sidecar == parsed
-
-
-# ---------------------------------------------------------------------------
 # lint gate (satellite): the new modules + touched comm files ship clean
 # ---------------------------------------------------------------------------
 
