@@ -25,7 +25,6 @@ from enum import Enum
 from typing import Optional
 
 from ..observability.metrics import get_registry, record_traced_collective
-from ..observability.trace import span as _span
 from ..utils.logging import logger, log_dist
 from .mesh import (MESH_AXES, MeshSpec, build_mesh, get_global_mesh,
                    peek_global_mesh, set_global_mesh,
@@ -184,15 +183,14 @@ def _payload_nbytes(tensor) -> int:
 
 
 def _note_collective(op: str, group, tensor, nbytes: Optional[int] = None):
-    """Record one collective (trace-time) and return the ``comm/<op>``
-    span to wrap the lax call — the span's wall time is TRACE time (a
-    compile-cost signal), its args are the payload record."""
+    """Record one collective's payload in the trace-time tally
+    (``comm/traced_calls|traced_bytes/<op>:<axis>``). No span: under
+    ``jit`` this runs while the program traces, once per compile, so a
+    duration here would be Python tracing time under a collective's
+    name."""
     if nbytes is None:
         nbytes = _payload_nbytes(tensor)
-    axis = _group_label(group)
-    record_traced_collective(op, axis, nbytes)
-    return _span(f"comm/{op}", {"axis": axis, "bytes": int(nbytes),
-                                "dtype": str(getattr(tensor, "dtype", "?"))})
+    record_traced_collective(op, _group_label(group), nbytes)
 
 
 # ---------------------------------------------------------------------------
@@ -239,21 +237,21 @@ def all_reduce(tensor, op: ReduceOp = ReduceOp.SUM, group=None):
         # validate BEFORE recording: a rejected op must not inflate the
         # traced-bytes tally (or a compiling program's attribution)
         raise ValueError(f"Unsupported reduce op {op}")
-    with _note_collective("all_reduce", group, tensor):
-        if op == ReduceOp.SUM:
-            return jax.lax.psum(tensor, axis)
-        if op == ReduceOp.AVG:
-            return jax.lax.pmean(tensor, axis)
-        if op == ReduceOp.MAX:
-            return jax.lax.pmax(tensor, axis)
-        if op == ReduceOp.MIN:
-            return jax.lax.pmin(tensor, axis)
-        # PRODUCT: no lax product-reduce primitive — gather the factors
-        # and multiply. (Correct for zeros/negatives, unlike
-        # exp(psum(log)).)
-        import jax.numpy as jnp
-        gathered = jax.lax.all_gather(tensor, axis, axis=0, tiled=False)
-        return jnp.prod(gathered, axis=0)
+    _note_collective("all_reduce", group, tensor)
+    if op == ReduceOp.SUM:
+        return jax.lax.psum(tensor, axis)
+    if op == ReduceOp.AVG:
+        return jax.lax.pmean(tensor, axis)
+    if op == ReduceOp.MAX:
+        return jax.lax.pmax(tensor, axis)
+    if op == ReduceOp.MIN:
+        return jax.lax.pmin(tensor, axis)
+    # PRODUCT: no lax product-reduce primitive — gather the factors
+    # and multiply. (Correct for zeros/negatives, unlike
+    # exp(psum(log)).)
+    import jax.numpy as jnp
+    gathered = jax.lax.all_gather(tensor, axis, axis=0, tiled=False)
+    return jnp.prod(gathered, axis=0)
 
 
 def inference_all_reduce(tensor, op: ReduceOp = ReduceOp.SUM, group="model"):
@@ -267,31 +265,31 @@ def all_gather(tensor, group=None, axis: int = 0, tiled: bool = True):
     semantics); ``tiled=False`` stacks a new leading dim.
     """
     import jax
-    with _note_collective("all_gather", group, tensor):
-        return jax.lax.all_gather(tensor, _axis(group), axis=axis,
-                                  tiled=tiled)
+    _note_collective("all_gather", group, tensor)
+    return jax.lax.all_gather(tensor, _axis(group), axis=axis,
+                              tiled=tiled)
 
 
 def reduce_scatter(tensor, op: ReduceOp = ReduceOp.SUM, group=None, scatter_dimension: int = 0):
     """lax.psum_scatter (reference: reduce_scatter_fn comm.py:256)."""
     import jax
     assert op in (ReduceOp.SUM, ReduceOp.AVG)
-    with _note_collective("reduce_scatter", group, tensor):
-        out = jax.lax.psum_scatter(tensor, _axis(group),
-                                   scatter_dimension=scatter_dimension,
-                                   tiled=True)
-        if op == ReduceOp.AVG:
-            out = out / axis_size(_axis(group))
+    _note_collective("reduce_scatter", group, tensor)
+    out = jax.lax.psum_scatter(tensor, _axis(group),
+                               scatter_dimension=scatter_dimension,
+                               tiled=True)
+    if op == ReduceOp.AVG:
+        out = out / axis_size(_axis(group))
     return out
 
 
 def all_to_all_single(tensor, group=None, split_axis: int = 0, concat_axis: int = 0):
     """lax.all_to_all (reference: all_to_all_single comm.py:355)."""
     import jax
-    with _note_collective("all_to_all", group, tensor):
-        return jax.lax.all_to_all(tensor, _axis(group),
-                                  split_axis=split_axis,
-                                  concat_axis=concat_axis, tiled=True)
+    _note_collective("all_to_all", group, tensor)
+    return jax.lax.all_to_all(tensor, _axis(group),
+                              split_axis=split_axis,
+                              concat_axis=concat_axis, tiled=True)
 
 
 def broadcast(tensor, src: int = 0, group=None):
@@ -303,17 +301,17 @@ def broadcast(tensor, src: int = 0, group=None):
     import jax
     import jax.numpy as jnp
     axis = _axis(group)
-    with _note_collective("broadcast", group, tensor):
-        idx = jax.lax.axis_index(axis)
-        masked = jnp.where(idx == src, tensor, jnp.zeros_like(tensor))
-        return jax.lax.psum(masked, axis)
+    _note_collective("broadcast", group, tensor)
+    idx = jax.lax.axis_index(axis)
+    masked = jnp.where(idx == src, tensor, jnp.zeros_like(tensor))
+    return jax.lax.psum(masked, axis)
 
 
 def ppermute(tensor, perm, group):
     """Neighbor exchange (pipeline p2p / ring attention building block)."""
     import jax
-    with _note_collective("ppermute", group, tensor):
-        return jax.lax.ppermute(tensor, _axis(group), perm)
+    _note_collective("ppermute", group, tensor)
+    return jax.lax.ppermute(tensor, _axis(group), perm)
 
 
 def send_recv_next(tensor, group):

@@ -274,11 +274,7 @@ def classify_spans(events, wall_ns: Optional[int] = None) -> dict:
 def _category_of(name) -> Optional[str]:
     if not isinstance(name, str):
         return None
-    if name in SPAN_CATEGORIES:
-        return SPAN_CATEGORIES[name]
-    if name.startswith("comm/"):
-        return None                      # trace-time records, not runtime
-    return None
+    return SPAN_CATEGORIES.get(name)
 
 
 def format_goodput(breakdown: dict) -> str:

@@ -85,10 +85,10 @@ def compressed_allreduce(x, error, axis_name: str):
     # scalar — the whole point of the compression is that this is what
     # ships, so this is what the collective accountant records
     wire_bytes = _payload_nbytes(wire_signs) + 4
-    with _note_collective("compressed_allreduce", axis_name, wire_signs,
-                          nbytes=wire_bytes):
-        summed_signs = lax.psum(wire_signs, axis_name).astype(jnp.float32)
-        mean_scale = lax.psum(scale, axis_name) / n
+    _note_collective("compressed_allreduce", axis_name, wire_signs,
+                     nbytes=wire_bytes)
+    summed_signs = lax.psum(wire_signs, axis_name).astype(jnp.float32)
+    mean_scale = lax.psum(scale, axis_name) / n
     # EF identity per worker: mean_scale*sign_i + new_error_i == x_i + e_i
     new_error = corrected - mean_scale * signs
     return mean_scale * summed_signs / n, new_error
@@ -131,18 +131,18 @@ def int8_compressed_allreduce(x, error, axis_name: str, chunk: int = 256):
     # each phase records its ACTUAL wire payload (int8 tensors + fp32
     # scales) in the collective accountant — the 4x comm-volume cut vs
     # fp32 is visible in comm/traced_bytes, not just claimed
-    with _note_collective("int8_allreduce", axis_name, q,
-                          nbytes=_payload_nbytes(q) + _payload_nbytes(s)):
-        qx = lax.all_to_all(q, axis_name, split_axis=0, concat_axis=0,
-                            tiled=True)
-        sx = lax.all_to_all(s, axis_name, split_axis=0, concat_axis=0,
-                            tiled=True)
+    _note_collective("int8_allreduce", axis_name, q,
+                     nbytes=_payload_nbytes(q) + _payload_nbytes(s))
+    qx = lax.all_to_all(q, axis_name, split_axis=0, concat_axis=0,
+                        tiled=True)
+    sx = lax.all_to_all(s, axis_name, split_axis=0, concat_axis=0,
+                        tiled=True)
     my_shard = dequant(qx, sx).sum(axis=0)          # fp32 accumulate
     q2, s2 = quant(my_shard)                        # re-quantize reduced
-    with _note_collective("int8_allreduce", axis_name, q2,
-                          nbytes=_payload_nbytes(q2) + _payload_nbytes(s2)):
-        qg = lax.all_gather(q2, axis_name, tiled=True)
-        sg = lax.all_gather(s2, axis_name, tiled=True)
+    _note_collective("int8_allreduce", axis_name, q2,
+                     nbytes=_payload_nbytes(q2) + _payload_nbytes(s2))
+    qg = lax.all_gather(q2, axis_name, tiled=True)
+    sg = lax.all_gather(s2, axis_name, tiled=True)
     out = dequant(qg, sg)[: size] / n
     return out.reshape(x.shape), new_error
 

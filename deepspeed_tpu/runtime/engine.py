@@ -36,6 +36,7 @@ from ..models.layers import (activation_rules_installed,
                              set_activation_rules)
 from ..observability.goodput import get_ledger as _goodput_ledger
 from ..observability.goodput import timed as _goodput
+from ..observability.metrics import get_registry
 from ..observability.programs import track_program
 from ..observability.trace import span as _span
 from ..utils.logging import logger, log_dist
@@ -1081,6 +1082,7 @@ class DeepSpeedEngine:
         for inputs that aren't per-example data — e.g. the other model's
         parameters in adversarial (GAN) training, auxiliary targets, or
         schedule scalars."""
+        t_entry = time.perf_counter_ns()
         cfg = self.config
         gas = cfg.gradient_accumulation_steps
         micro_global = cfg.train_micro_batch_size_per_gpu * self.dp_world_size
@@ -1117,23 +1119,24 @@ class DeepSpeedEngine:
             batch = jax.tree.map(to_micro, batch)
             batch = self._place_batch(batch, with_gas_dim=True)
 
-        self.tput_timer.start()
-        if self.resilience is not None:
-            self.resilience.on_step_start()
-        self._ensure_params_resident()
-        self._sync_activation_quantization()
-        scaler = self.loss_scale_state or init_loss_scale(1.0)
-        rng = jax.random.fold_in(self.rng, self.global_steps + 1)
-        extra = dict(loss_kwargs)
-        if (self.progressive_layer_drop is not None
-                and self._loss_accepts("layer_keep_prob")):
-            theta = self.progressive_layer_drop.update_state(self.global_steps)
-            extra["layer_keep_prob"] = jnp.float32(theta)  # traced: no recompile
-        self._remember_extra(extra, loss_kwargs)
-        if (self.moq_quantizer is not None
-                and self.moq_quantizer.config.eigenvalue_enabled
-                and self.config.eigenvalue.enabled):
-            self._last_eval_batch = jax.tree.map(lambda x: x[0], batch)
+        with _span("train/prepare"):
+            self.tput_timer.start()
+            if self.resilience is not None:
+                self.resilience.on_step_start()
+            self._ensure_params_resident()
+            self._sync_activation_quantization()
+            scaler = self.loss_scale_state or init_loss_scale(1.0)
+            rng = jax.random.fold_in(self.rng, self.global_steps + 1)
+            extra = dict(loss_kwargs)
+            if (self.progressive_layer_drop is not None
+                    and self._loss_accepts("layer_keep_prob")):
+                theta = self.progressive_layer_drop.update_state(self.global_steps)
+                extra["layer_keep_prob"] = jnp.float32(theta)  # traced: no recompile
+            self._remember_extra(extra, loss_kwargs)
+            if (self.moq_quantizer is not None
+                    and self.moq_quantizer.config.eigenvalue_enabled
+                    and self.config.eigenvalue.enabled):
+                self._last_eval_batch = jax.tree.map(lambda x: x[0], batch)
         # the fused jit is one program, so host-side it is one span;
         # the fwd / bwd / optimizer split lives in the device profile
         # (named_scope above) and in the split calling convention
@@ -1156,35 +1159,41 @@ class DeepSpeedEngine:
                 # + program table) before the error propagates
                 self._note_dispatch_failure(err)
                 raise
-        if self.fp16_enabled:
-            self.loss_scale_state = new_scaler
-            self._accumulate_skipped(metrics["skipped"])
+        # entry -> the step program enqueued: the serial host path the
+        # device waits through between two steps (always on: two clock
+        # reads a step, no device touch)
+        get_registry().histogram("train/host_to_dispatch_ms").observe(
+            (time.perf_counter_ns() - t_entry) / 1e6)
+        with _span("train/finish"):
+            if self.fp16_enabled:
+                self.loss_scale_state = new_scaler
+                self._accumulate_skipped(metrics["skipped"])
 
-        self.global_steps += 1
-        self.micro_steps += gas
-        self.global_samples += cfg.train_batch_size
-        self._apply_weight_projections()
-        self.tput_timer.stop(global_step=True)
-        self._last_loss = metrics["loss"]
-        self._last_grad_norm = metrics["grad_norm"]
-        if obs is not None:
-            self._observe_step(metrics)
+            self.global_steps += 1
+            self.micro_steps += gas
+            self.global_samples += cfg.train_batch_size
+            self._apply_weight_projections()
+            self.tput_timer.stop(global_step=True)
+            self._last_loss = metrics["loss"]
+            self._last_grad_norm = metrics["grad_norm"]
+            if obs is not None:
+                self._observe_step(metrics)
 
-        if (cfg.flops_profiler.enabled
-                and self.global_steps == cfg.flops_profiler.profile_step):
-            self._print_flops_profile(batch)
+            if (cfg.flops_profiler.enabled
+                    and self.global_steps == cfg.flops_profiler.profile_step):
+                self._print_flops_profile(batch)
 
-        if self.global_steps % cfg.steps_per_print == 0:
-            self._report_step(metrics)
-        self._write_monitor(metrics)
-        self._evict_params_to_nvme()
-        if self.tiering is not None:
-            self.params, self.optimizer_state = self.tiering.stage_out(
-                self.params, self.optimizer_state)
-        if self.resilience is not None:
-            # device-side health fold every step; host check (and possible
-            # rollback) only on the bounded check_interval cadence
-            self.resilience.on_step_end(metrics)
+            if self.global_steps % cfg.steps_per_print == 0:
+                self._report_step(metrics)
+            self._write_monitor(metrics)
+            self._evict_params_to_nvme()
+            if self.tiering is not None:
+                self.params, self.optimizer_state = self.tiering.stage_out(
+                    self.params, self.optimizer_state)
+            if self.resilience is not None:
+                # device-side health fold every step; host check (and possible
+                # rollback) only on the bounded check_interval cadence
+                self.resilience.on_step_end(metrics)
         return metrics["loss"]
 
     def _sync_activation_quantization(self):
@@ -1902,7 +1911,6 @@ class DeepSpeedEngine:
         ``ds_tpu_trace --metrics-out`` writes and ``ds_tpu_report``
         prints)."""
         if self.observability is None:
-            from ..observability import get_registry
             from ..observability.memory import get_accountant
             from ..observability.programs import get_program_registry
             return {"registry": get_registry().snapshot(),

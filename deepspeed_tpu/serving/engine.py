@@ -262,6 +262,7 @@ class ServingEngine:
         self._slot_req = [None] * n       # host view of slot -> Request
         self._free = deque(range(n))
         self._pending = deque()           # in-flight readbacks, FIFO
+        self._readback_ns = 0             # this advance()'s blocked reads
         self._iteration = 0
         self._seq = 0
         # QoS plane (serving/qos.py): priority preemption, SLO shedding,
@@ -652,7 +653,21 @@ class ServingEngine:
         paged mode: reserve pages + run at most ``max_chunks_per_iter``
         prefill chunks), dispatch one decode over the slot batch, harvest
         readbacks beyond the pipeline depth. Safe to call when idle
-        (no-op)."""
+        (no-op).
+
+        An iteration that had work accounts for its own time in the
+        process registry: ``serving/advance_readback_ms`` (blocked on
+        device->host reads) and ``serving/advance_host_ms`` (the rest)."""
+        t0 = time.perf_counter_ns()
+        had_work = self.busy
+        self._readback_ns = 0
+        with _span("serving/advance", {"iteration": self._iteration}):
+            self._advance()
+        if had_work:
+            self.metrics.on_advance(time.perf_counter_ns() - t0,
+                                    self._readback_ns)
+
+    def _advance(self):
         if self._watchdog_report is not None:
             report, self._watchdog_report = self._watchdog_report, None
             self.recover("hung decode dispatch", kind="watchdog",
@@ -667,11 +682,9 @@ class ServingEngine:
             self._watchdog.step_started()
         try:
             with self._trace_scope():
+                self._admit()
                 if self._paged is not None:
-                    self._admit_ready_paged()
                     self._run_prefill_chunks()
-                else:
-                    self._admit_ready()
                 if self.prefill_only:
                     # prefill role: no decode ever dispatches (the decode
                     # replica owns generation past token 1), but the
@@ -690,14 +703,16 @@ class ServingEngine:
         finally:
             if self._watchdog is not None:
                 self._watchdog.step_finished()
-        busy = sum(r is not None for r in self._slot_req)
-        self.metrics.sample(self.scheduler.depth, busy,
-                            self.config.num_slots, self._iteration,
-                            paged=(self._paged.stats()
-                                   if self._paged is not None else None),
-                            qos_level=(self._qos.level
-                                       if self._qos is not None else None),
-                            slot_cap=self._slot_cap)
+        with _span("serving/sample"):
+            busy = sum(r is not None for r in self._slot_req)
+            self.metrics.sample(
+                self.scheduler.depth, busy, self.config.num_slots,
+                self._iteration,
+                paged=(self._paged.stats()
+                       if self._paged is not None else None),
+                qos_level=(self._qos.level
+                           if self._qos is not None else None),
+                slot_cap=self._slot_cap)
         if self._iteration % self.config.metrics_interval == 0:
             self.metrics.flush()
 
@@ -743,23 +758,24 @@ class ServingEngine:
 
     # -- per-request distributed tracing -----------------------------------
     def _record_queue_wait(self, req):
-        """Emit the retroactive ``serving/queue_wait`` span for the
-        period the request ACTUALLY spent queued this time — submit ->
-        first admit, or preempt -> re-admit for a resumption (measuring
-        from submit again would fold the prior RUNNING period into the
-        queue stage). Pure host clock arithmetic on stamps the request
-        already carries — no clock reads when tracing is off, never a
-        device touch."""
-        tracer = _active_tracer()
-        if tracer is None:
-            return
+        """The period the request ACTUALLY spent queued this time —
+        submit -> first admit, or preempt -> re-admit for a resumption
+        (measuring from submit again would fold the prior RUNNING period
+        into the queue stage) — into the ``serving/queue_wait_ms``
+        histogram always, and as the retroactive ``serving/queue_wait``
+        span while a tracer is active. Host clock arithmetic on stamps
+        the request already carries plus one clock read — never a device
+        touch."""
         t0 = (req.preempted_at_ns if req.preempted_at_ns is not None
               else req.submitted_at_ns)
-        now = time.perf_counter_ns()
-        tracer.record_complete(
-            "serving/queue_wait", t0, max(0, now - t0),
-            {"request_id": req.request_id, "trace_id": req.trace_id,
-             "resumed": req.preempted_at_ns is not None})
+        wait_ns = max(0, time.perf_counter_ns() - t0)
+        self.metrics.on_queue_wait(wait_ns)
+        tracer = _active_tracer()
+        if tracer is not None:
+            tracer.record_complete(
+                "serving/queue_wait", t0, wait_ns,
+                {"request_id": req.request_id, "trace_id": req.trace_id,
+                 "resumed": req.preempted_at_ns is not None})
 
     def _record_residency(self, req):
         """Emit the retroactive ``serving/decode_residency`` span
@@ -866,6 +882,19 @@ class ServingEngine:
         log_dist(f"serving: preempted request {req.request_id!r} "
                  f"(slot {slot}, {len(req.tokens)} tokens retained, "
                  f"reason={reason})", ranks=[0])
+
+    def _admit(self):
+        """This iteration's admissions, under one ``serving/admission``
+        span: queue peeks, slot and page reservation, chunk planning
+        (and, contiguous mode, the ``serving/admit`` dispatches)."""
+        args = {"queue_depth": self.scheduler.depth, "admitted": 0}
+        before = self.metrics.requests_admitted
+        with _span("serving/admission", args):
+            if self._paged is not None:
+                self._admit_ready_paged()
+            else:
+                self._admit_ready()
+            args["admitted"] = self.metrics.requests_admitted - before
 
     def _admit_ready(self):
         while True:
@@ -1007,6 +1036,10 @@ class ServingEngine:
         padded[0, :real] = prompt[start:start + real]
         greedy, has_k, has_p, t, k, p = self._mode
         mgr = self._paged
+        if req.first_chunk_at_ns is None:
+            req.first_chunk_at_ns = time.perf_counter_ns()
+            self.metrics.on_prefill_wait(
+                req.first_chunk_at_ns - req.admitted_at_ns)
         try:
             with _span("serving/prefill_chunk",
                        {"slot": slot, "request_id": req.request_id,
@@ -1035,6 +1068,14 @@ class ServingEngine:
             mgr.publish(slot, prompt)
             self._pending.append(("admit", slot, req, tok, done))
         return True
+
+    def _decoding_slots(self, busy: int) -> int:
+        """Of ``busy`` held slots, those a decode dispatch advances: a
+        paged slot whose prefill chunks are still queued rides the batch
+        masked."""
+        if self._paged is None:
+            return busy
+        return busy - len(self._prefill_tasks)
 
     def _dispatch_decode(self) -> bool:
         if all(r is None for r in self._slot_req):
@@ -1066,7 +1107,8 @@ class ServingEngine:
                     self.module, self.params, self._cache, self._state,
                     rng, jnp.int32(self._iteration), self._eos, t, k, p,
                     self._param_transform, greedy, has_k, has_p)
-        self.metrics.on_decode_dispatch(busy, self.config.num_slots)
+        self.metrics.on_decode_dispatch(self._decoding_slots(busy),
+                                        self.config.num_slots)
         self._pending.append(("decode", snapshot, toks, done))
         self._iteration += 1
         return True
@@ -1150,10 +1192,23 @@ class ServingEngine:
                     self._state, jnp.asarray(props), jnp.asarray(counts),
                     rng, jnp.int32(self._iteration), self._eos, t, k, p,
                     self._param_transform, greedy, has_k, has_p, None)
-        self.metrics.on_decode_dispatch(busy, self.config.num_slots)
+        self.metrics.on_decode_dispatch(self._decoding_slots(busy),
+                                        self.config.num_slots)
         self._pending.append(("spec", snapshot, toks, done, counts))
         self._iteration += 1
         return True
+
+    def _read_back(self, *arrays):
+        """The blocking device->host reads of one harvest: the only
+        place an iteration waits for the device. Timed always (summed
+        into this ``advance()``'s ``serving/advance_readback_ms``) and
+        spanned, so ``serving/harvest``'s self time is token emission
+        and the callers' ``on_token`` callbacks."""
+        t0 = time.perf_counter_ns()
+        with _span("serving/readback"):
+            out = [np.asarray(a) for a in arrays]
+        self._readback_ns += time.perf_counter_ns() - t0
+        return out
 
     def _harvest_one(self):
         """Read back the oldest in-flight dispatch (blocks only on work
@@ -1174,9 +1229,10 @@ class ServingEngine:
                 _, slot, req, tok, done = entry
                 if req.done:     # cancelled between dispatch and readback
                     return
-                req._emit(int(np.asarray(tok)), self._iteration)
+                tok, done = self._read_back(tok, done)
+                req._emit(int(tok), self._iteration)
                 self.metrics.on_token()
-                if bool(np.asarray(done)):
+                if bool(done):
                     self._finish(slot, req)
                 elif self.prefill_only:
                     # prefill role: mask the device row (this engine
@@ -1197,8 +1253,7 @@ class ServingEngine:
                 # accepted step); the iteration clock already ticked
                 # exactly once at dispatch.
                 _, snapshot, toks, done, counts = entry
-                toks = np.asarray(toks)
-                done = np.asarray(done)
+                toks, done = self._read_back(toks, done)
                 for slot, req in enumerate(snapshot):
                     if req is None or req.done:
                         continue
@@ -1217,8 +1272,7 @@ class ServingEngine:
                         self._finish(slot, req)
                 return
             _, snapshot, toks, done = entry
-            toks = np.asarray(toks)
-            done = np.asarray(done)
+            toks, done = self._read_back(toks, done)
             for slot, req in enumerate(snapshot):
                 if req is None or req.done:  # empty, or cancelled in flight
                     continue

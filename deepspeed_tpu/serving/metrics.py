@@ -190,6 +190,9 @@ class ServingMetrics:
         self.requests_admitted += 1
         self.prefills += 1
         self.prefill_tokens_reused += shared_tokens
+        if self.registry is not None:
+            self.registry.counter("serving/prefill_tokens_reused").inc(
+                shared_tokens)
         c = self._cls(request)
         if c is not None:
             c["admitted"] += 1
@@ -200,10 +203,47 @@ class ServingMetrics:
     def on_prefill_chunk(self, tokens_computed: int):
         self.prefill_chunks += 1
         self.prefill_tokens_computed += tokens_computed
+        if self.registry is not None:
+            self.registry.counter("serving/prefill_tokens_computed").inc(
+                tokens_computed)
 
     def on_decode_dispatch(self, busy_slots: int, num_slots: int):
+        """One decode dispatch over ``num_slots`` rows of which
+        ``busy_slots`` hold a DECODING request (a paged slot still
+        waiting for its prefill chunks rides along masked and is not
+        busy). The registry pair sums to the batch occupancy."""
         self.decode_iterations += 1
         self.wasted_slot_steps += num_slots - busy_slots
+        if self.registry is not None:
+            self.registry.counter("serving/decode_slots_busy").inc(busy_slots)
+            self.registry.counter("serving/decode_slots_offered").inc(
+                num_slots)
+
+    # always-on host-loop accounting (process registry, so a reader that
+    # runs after the engine is gone still finds it): host clock
+    # arithmetic on stamps the engine took anyway, never a device touch
+    def on_queue_wait(self, wait_ns: int):
+        """Submit (or preempt) -> admitted, once per admission."""
+        if self.registry is not None:
+            self.registry.histogram("serving/queue_wait_ms").observe(
+                wait_ns / 1e6)
+
+    def on_prefill_wait(self, wait_ns: int):
+        """Admitted -> its first prefill chunk dispatched: in paged mode
+        a request holds a slot from admission but takes its turn at
+        ``max_chunks_per_iter`` chunks an iteration."""
+        if self.registry is not None:
+            self.registry.histogram("serving/prefill_wait_ms").observe(
+                wait_ns / 1e6)
+
+    def on_advance(self, total_ns: int, readback_ns: int):
+        """One ``advance()`` that had work: the part of it spent blocked
+        on device->host reads, and the rest (the host's own time)."""
+        if self.registry is not None:
+            self.registry.histogram("serving/advance_readback_ms").observe(
+                readback_ns / 1e6)
+            self.registry.histogram("serving/advance_host_ms").observe(
+                (total_ns - readback_ns) / 1e6)
 
     def on_token(self, n: int = 1):
         """``n`` EMITTED tokens streamed to requests. With speculation

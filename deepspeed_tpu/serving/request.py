@@ -80,6 +80,9 @@ class Request:
         self.submitted_at = time.perf_counter()
         self.submitted_at_ns = time.perf_counter_ns()
         self.admitted_at_ns: Optional[int] = None
+        # paged mode: when this admission's first prefill chunk was
+        # dispatched (admitted -> here is the wait in the prefill queue)
+        self.first_chunk_at_ns: Optional[int] = None
         self.preempted_at_ns: Optional[int] = None
         self.admitted_at: Optional[float] = None
         self.first_token_at: Optional[float] = None
@@ -98,6 +101,7 @@ class Request:
         self.status = RUNNING
         self.admitted_at = time.perf_counter()
         self.admitted_at_ns = time.perf_counter_ns()
+        self.first_chunk_at_ns = None
         self.admitted_iteration = iteration
 
     def _emit(self, token: int, iteration: int):
