@@ -232,6 +232,36 @@ def _requests(rng, n, vocab, prompt_max, new_max):
     return reqs
 
 
+def _check_pool_stays_in_place(srv, label):
+    """The paged decode program as the chip's compiler built it: the
+    donated page pool is its output buffer (``alias_bytes``) and its
+    scratch (``temp_bytes``) is smaller than one layer's K pages — so no
+    layer's slice of the pool, let alone the pool, is copied anywhere.
+    On-chip twin of tests/unit/test_serving_paging.py's structure test:
+    only here are the layouts real. A pool split over a model axis is
+    left to that test (the registry re-lowers from unsharded shapes)."""
+    import jax
+    from deepspeed_tpu.observability.programs import get_program_registry
+
+    kv = [x for x in jax.tree.leaves(srv._paged.pool) if x.ndim >= 4]
+    if any(len(x.sharding.device_set) > 1 for x in kv):
+        return
+    mem = get_program_registry().get("serving/paged_decode").analyze() or {}
+    pool_bytes = srv._paged.pool_bytes()
+    layer_slice = kv[0].nbytes // (kv[0].shape[0] if kv[0].ndim == 5 else 1)
+    _say(f"{label}: serving/paged_decode temp_bytes "
+         f"{mem.get('temp_bytes')}, alias_bytes {mem.get('alias_bytes')}; "
+         f"pool {pool_bytes} bytes, one layer's K pages {layer_slice}")
+    _check(mem.get("alias_bytes", 0) >= pool_bytes,
+           f"{label}: the paged decode program's outputs alias "
+           f"{mem.get('alias_bytes')} bytes of its arguments, less than "
+           f"the pool's {pool_bytes}: a pool leaf is not updated in place")
+    _check(mem.get("temp_bytes", pool_bytes) < layer_slice,
+           f"{label}: the paged decode program needs "
+           f"{mem.get('temp_bytes')} bytes of scratch, at least one "
+           f"layer's K pages ({layer_slice}): part of the pool is copied")
+
+
 def _serve_and_check(eng, module, params, reqs, num_slots, max_len,
                      page_len, paging_kernel, logit_tol, label):
     import numpy as np
@@ -269,6 +299,7 @@ def _serve_and_check(eng, module, params, reqs, num_slots, max_len,
     _mosaic(kern, f"{label} paged_attention kernel")
     _say(f"{label}: {len(handles)} requests over {num_slots} slots finished "
          f"in {wall:.2f}s (compiles included); paged kernel {kern}")
+    _check_pool_stays_in_place(srv, label)
     srv.close()
 
     # logit-level check against the float32 reference: teacher-force every

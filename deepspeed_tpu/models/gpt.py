@@ -272,6 +272,11 @@ class GPT(nn.Module):
                 # paged-serving scatter (models/layers.py); the collection
                 # only materializes when the caller marks it mutable
                 variable_axes={"params": 0, "cache": 0, "kv_token": 0},
+                # the paged page pool (inference/cache.py make_paged_view):
+                # read-only and the same for every layer, so it crosses
+                # the scan whole; the collection exists only in the paged
+                # decode program
+                variable_broadcast="kv_pool",
                 split_rngs={"params": True, "dropout": True},
                 length=cfg.n_layers,
                 metadata_params={nn.PARTITION_NAME: "layers"},

@@ -324,10 +324,12 @@ class SelfAttention(nn.Module):
             # MXU matmul (see ops/pallas/decode_attention.py).
             kc = k.transpose(0, 2, 3, 1)                 # [b, h, d, s]
             vc = v.transpose(0, 2, 3, 1)
-            cached_key = self.variable("cache", "cached_key", jnp.zeros,
-                                       kc.shape, kc.dtype)
-            cached_value = self.variable("cache", "cached_value", jnp.zeros,
-                                         vc.shape, vc.dtype)
+            paged = self.has_variable("cache", "page_table")
+            if not paged:
+                cached_key = self.variable("cache", "cached_key", jnp.zeros,
+                                           kc.shape, kc.dtype)
+                cached_value = self.variable("cache", "cached_value",
+                                             jnp.zeros, vc.shape, vc.dtype)
             cache_index = self.variable("cache", "cache_index",
                                         lambda: jnp.zeros((), jnp.int32))
             if not self.is_initializing() and \
@@ -343,16 +345,20 @@ class SelfAttention(nn.Module):
                 self.variable("kv_token", "v", lambda: vc).value = vc
             if self.is_initializing():
                 max_len = s
-            elif self.has_variable("cache", "page_table"):
-                # Paged-pool decode (serving/paging kernel path): the
-                # cache variables ARE the page pool ([pages, h, d,
-                # page_len]; int8 + scale planes when KV-quantized) plus
-                # the slot page table — the paged-attention kernel
-                # consumes them in place, so no contiguous per-slot view
-                # is ever gathered (decode_gather_transient ~ 0). The
+            elif paged:
+                # Paged-pool decode (serving/paging kernel path,
+                # inference/cache.py make_paged_view): "cache" holds the
+                # slot page table, the per-row lengths and — inside a
+                # layer scan — this layer's index; the page pool itself
+                # ([pages, h, d, page_len], or the whole layer-stacked
+                # [L, pages, ...] broadcast through the scan; int8 +
+                # scale planes when KV-quantized) is the read-only
+                # "kv_pool" collection. The paged-attention kernel reads
+                # its pages in place, so neither a per-slot view nor a
+                # layer's slice of the pool ever materializes. The
                 # current token's K/V attends via explicit operands and
-                # is scattered into the pool by the ENGINE after the
-                # step (quantized on scatter), which is why kv_token
+                # is appended to the pool by the ENGINE after the step
+                # (quantized on write), which is why kv_token
                 # publication is mandatory here.
                 if s != 1:
                     raise NotImplementedError(
@@ -373,18 +379,17 @@ class SelfAttention(nn.Module):
                 from ..ops.pallas.paged_attention import paged_attention
                 ptab = self.get_variable("cache", "page_table")
                 idx = cache_index.value          # [slots] pooled tokens
-                k_sc = (self.get_variable("cache", "key_scale")
-                        if self.has_variable("cache", "key_scale")
-                        else None)
-                v_sc = (self.get_variable("cache", "value_scale")
-                        if self.has_variable("cache", "value_scale")
-                        else None)
+                pool = self.variables["kv_pool"]
                 slopes = (alibi_slopes(self.n_heads) if self.alibi
                           else None)
                 decode_out = paged_attention(
-                    q, cached_key.value, cached_value.value, ptab, idx,
-                    kc, vc, alibi_slopes=slopes, k_scale=k_sc,
-                    v_scale=v_sc, mesh=_usable_global_mesh())
+                    q, pool["cached_key"], pool["cached_value"], ptab, idx,
+                    kc, vc, alibi_slopes=slopes,
+                    layer=(self.get_variable("cache", "layer")
+                           if self.has_variable("cache", "layer") else None),
+                    k_scale=pool.get("key_scale"),
+                    v_scale=pool.get("value_scale"),
+                    mesh=_usable_global_mesh())
                 cache_index.value = idx + 1
             else:
                 max_len = cached_key.value.shape[3]

@@ -19,11 +19,19 @@ HBM->VMEM *in place*, one page (or a tuned multi-page block) at a
 time. Flash-style online softmax (the ``_common.online_softmax_block``
 inner loop shared with ``decode_attention``) accumulates partial
 attention per page block; no contiguous per-slot view ever
-materializes (transient ~ 0, and DMA traffic scales with the VALID
-length, not the allocated table width).
+materializes, and DMA traffic scales with the VALID length, not the
+allocated table width.
+
+A scanned model's pool is layer-stacked, ``[L, num_pages, h, d,
+page_len]``. It reaches this kernel whole — the model's layer scan
+broadcasts it and passes the layer index — and the DMA source is
+``pool.at[layer, page, head_block]``: no layer's slice of the pool is
+ever cut out to be the operand (on the v5e that cut was a 168 MB copy
+per layer for K and again for V, every decode step). A 4-D pool is the
+same kernel over a stack of one; the two are told apart by rank.
 
 The current decode step's K/V is NOT in the pool yet (the engine
-scatters it after the step, quantized when the pool is int8): it
+appends it in place after the step, quantized when the pool is int8): it
 arrives as separate full-precision ``k_new``/``v_new`` operands and is
 folded into the softmax as a final single-column update — bias 0 under
 ALiBi (distance 0), always valid, so every row's normalizer is > 0.
@@ -86,8 +94,9 @@ def _fold_current_token(q, kn, vn, m_ref, l_ref, acc_ref):
     m_ref[...] = m_new
 
 
-def _dma_kernel(len_ref, ptab_ref, slopes_ref, q_ref, kn_ref, vn_ref,
-                *refs, scale, page_len, ppb, hb, alibi, quant, max_pages):
+def _dma_kernel(len_ref, ptab_ref, slopes_ref, layer_ref, q_ref, kn_ref,
+                vn_ref, *refs, scale, page_len, ppb, hb, alibi, quant,
+                max_pages):
     if quant:
         (kp_hbm, vp_hbm, ksp_hbm, vsp_hbm, o_ref,
          kbuf0, vbuf0, kbuf1, vbuf1, ksb0, vsb0, ksb1, vsb1,
@@ -99,6 +108,7 @@ def _dma_kernel(len_ref, ptab_ref, slopes_ref, q_ref, kn_ref, vn_ref,
         bufs = ((kbuf0, vbuf0), (kbuf1, vbuf1))
     b, hi = pl.program_id(0), pl.program_id(1)
     length = len_ref[b]
+    layer = layer_ref[0]
     bt = ppb * page_len
     nb = pl.cdiv(length, bt)
     m_ref[...] = jnp.full_like(m_ref, NEG_INF)
@@ -123,7 +133,8 @@ def _dma_kernel(len_ref, ptab_ref, slopes_ref, q_ref, kn_ref, vn_ref,
                           (vsp_hbm, bufs[slot][3], 3)]
             for src, buf, ch in pairs:
                 descs.append(pltpu.make_async_copy(
-                    src.at[phys, hi], buf.at[:, :, dst], sem.at[slot, ch, i]))
+                    src.at[layer, phys, hi], buf.at[:, :, dst],
+                    sem.at[slot, ch, i]))
         return descs
 
     # the prologue must not start copies a zero-block row never waits:
@@ -171,19 +182,20 @@ def _dma_kernel(len_ref, ptab_ref, slopes_ref, q_ref, kn_ref, vn_ref,
     o_ref[0, 0] = (acc_ref[...] / l_ref[...]).astype(o_ref.dtype)
 
 
-def _paged_dma(q_bhd, kp, vp, ptab, lengths, kn, vn, ks, vs, slopes, *,
-               scale, page_len, ppb, hb, alibi):
+def _paged_dma(q_bhd, kp, vp, ptab, lengths, kn, vn, ks, vs, slopes, layer,
+               *, scale, page_len, ppb, hb, alibi):
     b, heads, d = q_bhd.shape
-    num_pages = kp.shape[0]
+    n_layers, num_pages = kp.shape[:2]
     max_pages = ptab.shape[1]
     nhb = heads // hb
     quant = ks is not None
-    kpr = kp.reshape(num_pages, nhb, hb, d, page_len)
-    vpr = vp.reshape(num_pages, nhb, hb, d, page_len)
-    pools = [kpr, vpr]
+    # the whole stacked pool is the operand (memory_space ANY: it stays in
+    # HBM, nothing is copied for the call); the kernel picks the layer
+    split = lambda x: x.reshape(n_layers, num_pages, nhb, hb, x.shape[-2],
+                                page_len)
+    pools = [split(kp), split(vp)]
     if quant:
-        pools += [ks.reshape(num_pages, nhb, hb, 1, page_len),
-                  vs.reshape(num_pages, nhb, hb, 1, page_len)]
+        pools += [split(ks), split(vs)]
     bt = ppb * page_len
     kv_buf = lambda: pltpu.VMEM((hb, d, bt), kp.dtype)
     scratch = [kv_buf(), kv_buf(), kv_buf(), kv_buf()]
@@ -208,7 +220,7 @@ def _paged_dma(q_bhd, kp, vp, ptab, lengths, kn, vn, ks, vs, slopes, *,
                           ppb=ppb, hb=hb, alibi=alibi, quant=quant,
                           max_pages=max_pages),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3,
+            num_scalar_prefetch=4,
             grid=(b, nhb),
             in_specs=[tok_spec(), tok_spec(), tok_spec()]
             + [pl.BlockSpec(memory_space=pl.ANY)] * len(pools),
@@ -219,24 +231,25 @@ def _paged_dma(q_bhd, kp, vp, ptab, lengths, kn, vn, ks, vs, slopes, *,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")),
         interpret=_interpret(),
-    )(lengths, ptab, slopes, tok(q_bhd), tok(kn), tok(vn), *pools)
+    )(lengths, ptab, slopes, layer, tok(q_bhd), tok(kn), tok(vn), *pools)
     return out.reshape(b, heads, d)
 
 
-def _paged_dense(q_bhd, kp, vp, ptab, lengths, kn, vn, ks, vs, slopes, *,
-                 scale, alibi):
+def _paged_dense(q_bhd, kp, vp, ptab, lengths, kn, vn, ks, vs, slopes,
+                 layer, *, scale, alibi):
     """jnp fallback with IDENTICAL semantics for pools the kernel cannot
     tile (page_len not a 128 multiple on real TPU) — and the reference
     the kernel parity suite checks against. Gathers the table's pages
     (XLA scratch — exactly what the kernel path eliminates), attends
     cols < length plus the current token as one extra column."""
     b, heads, d = q_bhd.shape
-    page_len = kp.shape[3]
-    gk = kp[ptab]                                  # [B, M, H, d, p]
-    gv = vp[ptab]
+    page_len = kp.shape[-1]
+    at = (layer[0], ptab)
+    gk = kp[at]                                    # [B, M, H, d, p]
+    gv = vp[at]
     if ks is not None:
-        gk = gk.astype(jnp.float32) * ks[ptab]
-        gv = gv.astype(jnp.float32) * vs[ptab]
+        gk = gk.astype(jnp.float32) * ks[at]
+        gv = gv.astype(jnp.float32) * vs[at]
     m = ptab.shape[1]
     s_tot = m * page_len
     k_all = gk.transpose(0, 2, 3, 1, 4).reshape(b, heads, d, s_tot)
@@ -263,14 +276,20 @@ def _paged_dense(q_bhd, kp, vp, ptab, lengths, kn, vn, ks, vs, slopes, *,
 
 
 def paged_attention(q, k_pages, v_pages, page_table, lengths, k_new, v_new,
-                    *, softmax_scale=None, alibi_slopes=None, k_scale=None,
-                    v_scale=None, block_tokens=None, head_block=None,
-                    impl=None, mesh=None):
+                    *, layer=None, softmax_scale=None, alibi_slopes=None,
+                    k_scale=None, v_scale=None, block_tokens=None,
+                    head_block=None, impl=None, mesh=None):
     """Single-token attention straight over a paged KV pool.
 
     q: [B, 1, H, d] (or [B, H, d]) — the current token's queries.
     k_pages, v_pages: [num_pages, H, d, page_len] page pool (K^T
-        layout); int8 when ``k_scale``/``v_scale`` are given.
+        layout); int8 when ``k_scale``/``v_scale`` are given. Or the
+        layer-stacked pool ``[L, num_pages, H, d, page_len]`` of a
+        scanned model, with ``layer`` naming the layer to attend over:
+        the pool is never sliced, the kernel indexes it. The two forms
+        are told apart by rank.
+    layer: int32 scalar (traced inside the layer scan) — required with
+        a stacked pool, refused with a 4-D one.
     page_table: [B, max_pages] int32 — physical page per logical page;
         unowned entries hold the null page (always safe to read).
     lengths: [B] int32 — tokens already IN the pool per row (the
@@ -278,7 +297,8 @@ def paged_attention(q, k_pages, v_pages, page_table, lengths, k_new, v_new,
     k_new, v_new: [B, H, d, 1] (or [B, H, d]) — the current token's
         K/V in compute precision (quantized on scatter AFTER the step).
     k_scale, v_scale: optional [num_pages, H, 1, page_len] fp32 per-
-        token-per-head scale planes of an int8 pool.
+        token-per-head scale planes of an int8 pool (stacked like the
+        pool when it is).
     impl: None (auto), "kernel", or "dense" — parity/testing override.
     mesh: the caller's mesh when its ``model`` axis splits the heads
         (tensor-parallel serving): the kernel runs once per head shard.
@@ -298,7 +318,21 @@ def paged_attention(q, k_pages, v_pages, page_table, lengths, k_new, v_new,
                          f"got {one}")
     if (k_scale is None) != (v_scale is None):
         raise ValueError("k_scale and v_scale must be given together")
-    page_len = k_pages.shape[3]
+    stacked = k_pages.ndim == 5
+    if stacked != (layer is not None):
+        raise ValueError(
+            "paged_attention takes `layer` with a stacked [L, pages, H, d, "
+            f"page_len] pool and only then (pool rank {k_pages.ndim}, layer "
+            f"{'given' if layer is not None else 'missing'})")
+    if not stacked:
+        # one kernel for both forms: a 4-D pool is a stack of one layer
+        # (a reshape the compiler lowers to nothing)
+        layer = 0
+        k_pages, v_pages = k_pages[None], v_pages[None]
+        if k_scale is not None:
+            k_scale, v_scale = k_scale[None], v_scale[None]
+    layer = jnp.asarray(layer, jnp.int32).reshape(1)
+    page_len = k_pages.shape[-1]
     max_pages = page_table.shape[1]
     scale = softmax_scale if softmax_scale is not None else 1.0 / math.sqrt(d)
 
@@ -345,17 +379,18 @@ def paged_attention(q, k_pages, v_pages, page_table, lengths, k_new, v_new,
                                 ppb=ppb, hb=hb, alibi=alibi)
     else:
         run = functools.partial(_paged_dense, scale=scale, alibi=alibi)
-    # positional: q, kp, vp, ptab, lengths, kn, vn, ks, vs, slopes — the
-    # head dim (axis 1 of all but ptab/lengths, axis 0 of slopes) splits
-    # over the model axis
+    # positional: q, kp, vp, ptab, lengths, kn, vn, ks, vs, slopes, layer
+    # — the head dim (axis 1 of the per-token operands, axis 2 of the
+    # stacked pools, axis 0 of slopes) splits over the model axis
     heads_1 = P(None, "model")
-    quant_spec = heads_1 if k_scale is not None else None
+    pool_spec = P(None, None, "model")
+    quant_spec = pool_spec if k_scale is not None else None
     out = over_model_axis(
         run, mesh,
-        in_specs=(heads_1, heads_1, heads_1, P(), P(), heads_1, heads_1,
-                  quant_spec, quant_spec, P("model")),
+        in_specs=(heads_1, pool_spec, pool_spec, P(), P(), heads_1, heads_1,
+                  quant_spec, quant_spec, P("model"), P()),
         out_specs=heads_1,
     )(q_bhd, k_pages, v_pages, page_table, lengths, kn, vn, k_scale,
-      v_scale, slopes)
+      v_scale, slopes, layer)
     out = out[:, None]                                       # [B, 1, H, d]
     return out[:, 0].reshape(b, heads, d) if squeeze else out

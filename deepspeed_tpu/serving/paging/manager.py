@@ -11,10 +11,17 @@ the TPU compile-once discipline:
 
 - the page table is a fixed-shape array operand, so admissions and
   frees change DATA, never compiled shapes;
-- decode gathers each slot's pages into the classic contiguous view
-  inside the jitted program (``inference/cache.py gather_pages``), runs
-  the unchanged attention path, then scatters the step's K/V token back
-  to its page — ONE compiled decode program, ever;
+- decode is ONE compiled program, ever, and on the kernel path it
+  leaves the pool where it is: the pool is a donated argument that
+  rides ``module.apply`` as a read-only collection (``inference/
+  cache.py make_paged_view``; a scanned model broadcasts it through its
+  layer scan, never slicing or restacking it), the paged-attention
+  kernel reads each slot's pages out of it by layer, and the step's K/V
+  token is appended to its page in place — the program's output pool is
+  its input buffer. The gather path (CPU default, unaligned pages)
+  gathers each slot's pages into the classic contiguous view inside
+  the program (``gather_pages``), runs the unchanged attention path,
+  and appends the same way;
 - prefill runs in page-aligned chunks through a single gathered row,
   one jit specialization per chunk-length bucket, interleaved between
   decode iterations by the engine (chunked prefill).
@@ -89,13 +96,17 @@ def _paged_decode_iter_impl(module, params, pool, page_table, state, rng, it,
 
     ``use_kernel`` (static — one compiled program per engine either
     way): the paged-attention kernel consumes the pool + page table IN
-    PLACE via ``make_paged_view`` — no contiguous per-slot view is ever
-    gathered (``decode_gather_transient`` ~ 0). Off-kernel, the PR-6
-    gather path runs unchanged: gather pages -> contiguous view (int8
-    pools dequantize to ``dequant_dtype`` during the gather) -> the
-    unchanged attention path. Both scatter the new token's K/V back to
-    each active slot's tail page (quantized on scatter for int8
-    pools); inactive slots write the null page."""
+    PLACE via ``make_paged_view`` — neither a contiguous per-slot view
+    nor a layer's slice of the pool is ever materialized. Off-kernel,
+    the PR-6 gather path runs unchanged: gather pages -> contiguous
+    view (int8 pools dequantize to ``dequant_dtype`` during the gather)
+    -> the unchanged attention path. Both append the new token's K/V to
+    each active slot's tail page in place (quantized on write for int8
+    pools); inactive slots write the null page. ``pool`` is donated and
+    is the buffer the returned pool lives in: nothing between the
+    argument and the result makes a fresh one (the registry's
+    ``temp_bytes`` / ``alias_bytes`` of ``serving/paged_decode`` say so
+    on the chip; ``chip_smoke.py`` checks them)."""
     lengths = state["lengths"]
     active = state["active"]
     page_len = cache_page_len(pool)
@@ -105,7 +116,7 @@ def _paged_decode_iter_impl(module, params, pool, page_table, state, rng, it,
     if use_kernel:
         view = make_paged_view(pool, page_table, idx_w)
         logits, vars_out = module.apply(
-            {"params": p_, "cache": view}, state["last_token"][:, None],
+            {"params": p_, **view}, state["last_token"][:, None],
             decode=True, positions=idx_w[:, None],
             mutable=["cache", "kv_token"])
         tok = vars_out.get("kv_token")
@@ -436,16 +447,19 @@ class PagedKVManager:
 
     def decode_gather_transient_bytes(self) -> int:
         """Bytes of the contiguous ``[num_slots, h, d, cache_len]`` view
-        each jitted decode step gathers as XLA-managed scratch — derived
-        from the pool's own leaf shapes (the figure the PR-6 bench
-        artifact hand-computed; resident-vs-transient honesty in
-        docs/serving.md). On the paged-attention KERNEL path this is 0:
-        pages stream HBM->VMEM in place and no per-slot view ever
-        materializes. On the gather path, per attention unit: one
-        page's K/V elements (at the DEQUANT dtype — an int8 pool still
-        gathers a full-precision view, so quantization does NOT shrink
-        this figure, only the kernel eliminates it) times
-        ``num_slots * max_pages``."""
+        each jitted decode step of the GATHER path gathers as
+        XLA-managed scratch — a hand count from the pool's own leaf
+        shapes (the figure the PR-6 bench artifact computed;
+        resident-vs-transient honesty in docs/serving.md), not a
+        measurement. On the paged-attention KERNEL path no view is
+        gathered and this returns 0; what that program really takes
+        beside its arguments is the compiler's figure, ``temp_bytes``
+        of ``serving/paged_decode`` in the program registry
+        (``observability/programs.py``). On the gather path, per
+        attention unit: one page's K/V elements (at the DEQUANT dtype —
+        an int8 pool still gathers a full-precision view, so
+        quantization does NOT shrink this figure, only the kernel
+        eliminates it) times ``num_slots * max_pages``."""
         if self.use_kernel:
             return 0
         from jax.tree_util import tree_flatten_with_path

@@ -156,6 +156,83 @@ class TestKernelVsDenseParity:
                                       np.asarray(out3))
 
 
+class TestStackedPool:
+    """The layer-stacked pool ``[L, pages, H, d, page_len]`` of a scanned
+    model reaches the kernel whole and the kernel picks the layer: the
+    result is the call on that layer's own 4-D slice, bit for bit."""
+    L = 3
+
+    def _case(self, dtype, heads=8, seed=5):
+        r = np.random.RandomState(seed)
+        kp, vp = (jnp.asarray(r.randn(self.L, 9, heads, 16, PAGE)
+                              .astype(np.float32)) for _ in range(2))
+        q, kn, vn = _operands(seed=seed + 1, b=2, heads=heads)
+        ptab = jnp.asarray([[1, 4, 2, 0], [3, 5, 6, 7]], np.int32)
+        lens = jnp.asarray([PAGE + 3, 4 * PAGE - 1], jnp.int32)
+        scales = {}
+        if dtype == "int8":
+            kq, vq, ks, vs = jax.vmap(_quantize_pool)(kp, vp)
+            kp, vp, scales = kq, vq, {"k_scale": ks, "v_scale": vs}
+        else:
+            kp, vp = kp.astype(jnp.bfloat16), vp.astype(jnp.bfloat16)
+        return (q, kp, vp, ptab, lens, kn, vn), scales
+
+    @pytest.mark.parametrize("layer", [0, L - 1])
+    @pytest.mark.parametrize("head_block", [1, 2, 4, 8])
+    @pytest.mark.parametrize("dtype", ["bf16", "int8"])
+    def test_layer_of_stacked_pool_equals_its_slice(self, dtype, head_block,
+                                                    layer):
+        (q, kp, vp, *rest), scales = self._case(dtype)
+        tuning.clear_last_dispatch()
+        # the layer is a traced scalar, as inside the model's layer scan
+        got = jax.jit(lambda i: paged_attention(
+            q, kp, vp, *rest, layer=i, head_block=head_block, impl="kernel",
+            **scales))(jnp.int32(layer))
+        rec = tuning.last_dispatch(KERNEL)["page%d" % PAGE]
+        assert rec["head_block"] == head_block and rec["impl"] == "kernel"
+        want = paged_attention(
+            q, kp[layer], vp[layer], *rest, head_block=head_block,
+            impl="kernel", **{k: v[layer] for k, v in scales.items()})
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+        # same key as the 4-D call: the cell must not run other blocks
+        assert tuning.last_dispatch(KERNEL)["page%d" % PAGE]["key"] \
+            == rec["key"]
+
+    @pytest.mark.parametrize("dtype", ["bf16", "int8"])
+    def test_stacked_pool_kernel_vs_dense(self, dtype):
+        (q, kp, vp, *rest), scales = self._case(dtype, heads=2)
+        got, dense = (paged_attention(q, kp, vp, *rest, layer=self.L - 1,
+                                      impl=impl, **scales)
+                      for impl in ("kernel", "dense"))
+        np.testing.assert_allclose(np.asarray(got), np.asarray(dense),
+                                   atol=2e-2 if dtype == "bf16" else 1e-4,
+                                   rtol=2e-2)
+
+    def test_rank_and_layer_must_agree(self):
+        (q, kp, vp, *rest), _ = self._case("bf16")
+        with pytest.raises(ValueError, match="stacked"):
+            paged_attention(q, kp, vp, *rest)              # 5-D, no layer
+        with pytest.raises(ValueError, match="stacked"):
+            paged_attention(q, kp[0], vp[0], *rest, layer=0)
+
+    @pytest.mark.parametrize("dtype", ["bf16", "int8"])
+    def test_stacked_pool_over_the_model_axis(self, dtype):
+        """``mp_size > 1``: the head axis of the stacked pool is its
+        third, and each device runs the kernel on its own heads."""
+        from deepspeed_tpu.comm import MeshSpec, build_mesh
+        (q, kp, vp, *rest), scales = self._case(dtype, heads=4)
+        want = paged_attention(q, kp, vp, *rest, layer=1, impl="kernel",
+                               **scales)
+        mesh = build_mesh(MeshSpec(model=2, data=4))
+        tuning.clear_last_dispatch()
+        got = jax.jit(lambda i: paged_attention(
+            q, kp, vp, *rest, layer=i, impl="kernel", mesh=mesh,
+            **scales))(jnp.int32(1))
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+        assert tuning.last_dispatch(KERNEL)["page%d" % PAGE][
+            "model_shards"] == 2
+
+
 class TestTuningDispatch:
     def test_runtime_table_entry_consumed(self):
         """The shape-keyed tuning cache resolves the kernel's blocks at

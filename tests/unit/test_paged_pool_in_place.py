@@ -1,0 +1,129 @@
+"""The paged decode program, compiled for a described v5e at the serve
+cell's widths: what the chip's compiler does with the page pool.
+
+Layouts are the TPU's here, which the CPU suite cannot see: the parent's
+scatter over the page and the in-page offset compiled to four copies of
+the whole pool (a layout change and back, K and V) and 4 GB of scratch.
+Nothing runs — no time comes out of this file — but a program that
+slices, restacks or relays out the pool does not pass it. The topology
+is described inside a fixture and only here, so under xdist one worker
+loads the TPU's compiler (on-chip-measurement guide, section 2).
+"""
+
+import importlib
+import os
+import re
+
+import pytest
+import jax
+import jax.numpy as jnp
+
+from deepspeed_tpu.models.gpt import GPT, GPTConfig
+from deepspeed_tpu.inference.cache import (init_page_pool,
+                                           quantize_page_pool)
+from deepspeed_tpu.serving.paging.manager import _paged_decode_iter_impl
+
+# the serve cell (benchmarks/chip/configs/gpt2-1.3b-serve.json) but for
+# its depth: two layers compile in seconds and hold every pool operation
+WIDTH = dict(vocab_size=50257, max_seq_len=2048, d_model=2048, n_heads=16)
+LAYERS, SLOTS, PAGES, PAGE_LEN, MAX_PAGES = 2, 32, 321, 128, 16
+
+# instructions that may carry a pool-shaped value without moving it
+IN_PLACE = {"parameter", "get-tuple-element", "tuple", "bitcast", "while",
+            "dynamic-update-slice"}
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:            # no TPU compiler in this install
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _fusion_roots(hlo):
+    """{fused computation: opcode of its ROOT} of an optimized module."""
+    roots, name = {}, None
+    for line in hlo.splitlines():
+        head = re.match(r"%(fused_computation[\w.\-]*) ", line)
+        if head:
+            name = head.group(1)
+        root = re.match(r"\s+ROOT %[\w.\-]+ = \S+ ([\w\-]+)\(", line)
+        if root and name:
+            roots[name], name = root.group(1), None
+    return roots
+
+
+@pytest.mark.parametrize("scan_layers,kv_int8", [
+    (True, False), (False, False), (True, True)],
+    ids=["scanned-bf16", "unscanned-bf16", "scanned-int8"])
+def test_decode_program_leaves_the_pool_where_it_is(one_chip, monkeypatch,
+                                                    scan_layers, kv_int8):
+    # the kernel must lower through Mosaic as on the chip: this process'
+    # platform is the CPU, where it would be interpreted
+    monkeypatch.setattr(
+        importlib.import_module("deepspeed_tpu.ops.pallas.paged_attention"),
+        "_interpret", lambda: False)
+    model = GPT(GPTConfig(n_layers=LAYERS, scan_layers=scan_layers,
+                          dtype=jnp.bfloat16, param_dtype=jnp.bfloat16,
+                          **WIDTH))
+    import flax.core.meta as flax_meta
+    params = jax.eval_shape(
+        lambda r: flax_meta.unbox(model.init(
+            r, jnp.ones((1, 8), jnp.int32)))["params"],
+        jax.random.PRNGKey(0))
+
+    def pool():
+        p = init_page_pool(model, params, PAGES, PAGE_LEN)
+        return quantize_page_pool(p) if kv_int8 else p
+
+    def on_chip(tree):
+        """``tree``'s shapes (of a thunk: of what it would build), placed
+        on the described chip — nothing is allocated anywhere."""
+        shapes = jax.eval_shape(tree) if callable(tree) else tree
+        return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=one_chip), shapes)
+
+    slot = lambda dtype: jax.ShapeDtypeStruct((SLOTS,), dtype)
+    state = {"lengths": slot(jnp.int32), "last_token": slot(jnp.int32),
+             "active": slot(jnp.bool_), "remaining": slot(jnp.int32)}
+    pool_shapes = on_chip(pool)
+    compiled = jax.jit(
+        _paged_decode_iter_impl, static_argnums=(0, 11, 12, 13, 14, 15, 16),
+        donate_argnums=(2, 4)).lower(
+            model, on_chip(params), pool_shapes,
+            on_chip(jax.ShapeDtypeStruct((SLOTS, MAX_PAGES), jnp.int32)),
+            on_chip(state), on_chip(lambda: jax.random.PRNGKey(0)),
+            on_chip(jax.ShapeDtypeStruct((), jnp.int32)), 50256, 1.0, 0, 1.0,
+            None, True, False, False, True, jnp.bfloat16).compile()
+
+    kv = [x for x in jax.tree.leaves(pool_shapes) if x.ndim >= 4]
+    pool_bytes = sum(x.size * x.dtype.itemsize for x in kv)
+    key = next(x for x in kv if x.shape[-2] == 128)
+    layer_k_bytes = (key.size * key.dtype.itemsize
+                     // (LAYERS if scan_layers else 1))
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= pool_bytes
+    assert mem.temp_size_in_bytes < layer_k_bytes, (
+        f"{mem.temp_size_in_bytes} bytes of scratch: some of the pool is "
+        "copied")
+
+    hlo = compiled.as_text()
+    assert "tpu_custom_call" in hlo            # the Mosaic kernel is there
+    roots = _fusion_roots(hlo)
+    # the pool, a layer's slice of it, and the same of the scale planes
+    moved = re.compile(r"\[(?:\d+,)?%d,16,(?:128|1),%d\]" % (PAGES, PAGE_LEN))
+    for line in hlo.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%[\w.\-]+ = (\S+) ([\w\-]+)\(", line)
+        if not m or m.group(1).startswith("(") or not moved.search(
+                m.group(1)):
+            continue
+        op = m.group(2)
+        if op == "fusion":
+            op = roots[re.search(r"calls=%([\w.\-]+)", line).group(1)]
+        assert op in IN_PLACE, f"the pool is moved by: {line.strip()[:200]}"

@@ -514,6 +514,275 @@ class TestPagedDensityAcceptance:
 
 
 # ---------------------------------------------------------------------------
+# the decode program leaves the pool where it is: read in place by layer,
+# appended in place, and the output pool is the donated input
+# ---------------------------------------------------------------------------
+
+def _walk_eqns(jaxpr):
+    """Every equation of ``jaxpr`` and of the jaxprs nested in it."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for v in eqn.params.values():
+            for sub in (v if isinstance(v, (tuple, list)) else (v,)):
+                inner = getattr(sub, "jaxpr", sub)
+                if hasattr(inner, "eqns"):
+                    yield from _walk_eqns(inner)
+
+
+def _scan_streams(eqn):
+    """Shapes of what a ``scan`` slices per step (xs) and stacks (ys)."""
+    skip = eqn.params["num_consts"] + eqn.params["num_carry"]
+    xs = [v.aval.shape for v in eqn.invars[skip:]]
+    ys = [v.aval.shape for v in eqn.outvars[eqn.params["num_carry"]:]]
+    return xs, ys
+
+
+PAGED_PAGE = 128            # the cell's page: offset 127 -> 0 of the next
+
+
+class _PagedScene:
+    """Four slots over a hand-made pool: slots 0 and 1 share a full
+    prefix page, slot 0 is two tokens from its page's end, slot 2 rides
+    the batch inactive, slot 3 is active with nothing in the pool."""
+    SHARED = 6
+    TABLE = np.array([[6, 1, 2], [6, 4, 5], [7, 0, 0], [8, 0, 0]], np.int32)
+    LENGTHS = np.array([2 * PAGED_PAGE - 2, PAGED_PAGE + 5, 3, 0], np.int32)
+    ACTIVE = np.array([True, True, False, True])
+
+    def __init__(self, scan_layers, kv_int8):
+        from deepspeed_tpu.inference.cache import init_page_pool
+        self.m, self.params = _model(vocab=101, max_seq_len=3 * PAGED_PAGE,
+                                     scan_layers=scan_layers)
+        pool = init_page_pool(self.m, self.params, 9, PAGED_PAGE)
+        leaves, tree = jax.tree.flatten(pool)
+        r = np.random.RandomState(3)
+        leaves = [jnp.asarray(r.randn(*x.shape).astype(np.float32)) * 0.3
+                  if x.ndim >= 4 else x for x in leaves]
+        self.pool = jax.tree.unflatten(tree, leaves)
+        if kv_int8:
+            self.pool = self._quantized(self.pool)
+
+    @staticmethod
+    def _quantized(pool):
+        """The filled pool as ``quantize_page_pool`` lays an int8 pool
+        out: int8 pages beside their scale planes."""
+        from deepspeed_tpu.inference.cache import _map_units, _quantize_kv
+
+        def fill(unit):
+            out = dict(unit)
+            for name, sname in (("cached_key", "key_scale"),
+                                ("cached_value", "value_scale")):
+                out[name], out[sname] = _quantize_kv(unit[name])
+            return out
+
+        return _map_units(pool, fill)
+
+    def state(self):
+        return {"lengths": jnp.asarray(self.LENGTHS),
+                "last_token": jnp.asarray([5, 17, 29, 41], jnp.int32),
+                "active": jnp.asarray(self.ACTIVE),
+                "remaining": jnp.full((4,), 99, jnp.int32)}
+
+    def args(self, use_kernel, it=0, pool=None, state=None):
+        """Positional arguments of ``_paged_decode_iter_impl``."""
+        return (self.m, self.params, self.pool if pool is None else pool,
+                jnp.asarray(self.TABLE), state or self.state(),
+                jax.random.PRNGKey(0), jnp.int32(it), -1, 1.0, 0, 1.0, None,
+                True, False, False, use_kernel, jnp.float32)
+
+    def run(self, use_kernel, steps=4):
+        from deepspeed_tpu.serving.paging.manager import \
+            _paged_decode_iter_impl
+        step = jax.jit(_paged_decode_iter_impl,
+                       static_argnums=(0, 11, 12, 13, 14, 15, 16))
+        pool, state, toks = self.pool, self.state(), []
+        for it in range(steps):
+            pool, state, tok, _ = step(*self.args(use_kernel, it, pool,
+                                                  state))
+            toks.append(np.asarray(tok))
+        return pool, state, np.stack(toks)
+
+
+def _pages(leaf):
+    """A pool leaf with the page axis first (4-D and stacked 5-D)."""
+    x = np.asarray(leaf)
+    return x if x.ndim == 4 else np.moveaxis(x, 1, 0)
+
+
+class TestPoolStaysInPlace:
+    @pytest.mark.parametrize("kv_int8", [False, True], ids=["fp", "int8"])
+    @pytest.mark.parametrize("scan_layers", [True, False],
+                             ids=["scanned", "unscanned"])
+    def test_kernel_path_steps_across_a_page_boundary(self, scan_layers,
+                                                      kv_int8):
+        """Four steps on the kernel path (interpret mode), slot 0 going
+        from offset 126 over 127 to 0 and 1 of its next page: the tokens
+        and every page but the null page are the gather path's."""
+        scene = _PagedScene(scan_layers, kv_int8)
+        pool_k, state_k, toks_k = scene.run(use_kernel=True)
+        pool_g, state_g, toks_g = scene.run(use_kernel=False)
+        np.testing.assert_array_equal(toks_k, toks_g)
+        assert (toks_k[:, 2] == -1).all() and (toks_k[:, [0, 1, 3]] >= 0).all()
+        np.testing.assert_array_equal(
+            np.asarray(state_k["lengths"]), scene.LENGTHS + 4 * scene.ACTIVE)
+        for (path, a), b, before in zip(
+                jax.tree_util.tree_flatten_with_path(pool_k)[0],
+                jax.tree.leaves(pool_g), jax.tree.leaves(scene.pool)):
+            if a.ndim < 4:
+                continue
+            a, b, before = _pages(a), _pages(b), _pages(before)
+            exact = a.dtype == np.int8
+            # layer 0's K/V is the same arithmetic on both paths; deeper
+            # layers sit behind two softmax implementations
+            np.testing.assert_allclose(
+                a[1:].astype(np.float32), b[1:].astype(np.float32),
+                atol=1 if exact else 2e-5, rtol=0 if exact else 2e-5,
+                err_msg=str(path))
+            # the shared prefix page, and every page no slot appends
+            # to, is bit-unchanged
+            for page in (scene.SHARED, 3, 5, 7):
+                np.testing.assert_array_equal(a[page], before[page],
+                                              err_msg=f"{path} {page}")
+            # what was appended: slot 0 filled its page and began the
+            # next, slot 3 wrote its first four tokens
+            assert (a[1][..., -2:] != before[1][..., -2:]).any()
+            assert (a[2][..., :2] != before[2][..., :2]).any()
+            np.testing.assert_array_equal(a[2][..., 2:], before[2][..., 2:])
+            assert (a[8][..., :4] != before[8][..., :4]).any()
+
+    @pytest.mark.parametrize("kv_int8", [False, True], ids=["fp", "int8"])
+    @pytest.mark.parametrize("stacked", [True, False],
+                             ids=["stacked", "4d"])
+    def test_append_is_the_scatter_it_replaced(self, stacked, kv_int8):
+        """``scatter_token_pages`` against the indexed scatter the parent
+        ran (``kv.at[:, pages, :, :, offsets].set``): bit-identical on
+        every page but the null page, scale planes included."""
+        from deepspeed_tpu.inference.cache import (_quantize_kv,
+                                                   scatter_token_pages)
+        r = np.random.RandomState(11)
+        lead = (3,) if stacked else ()
+        kv = lambda: jnp.asarray(
+            r.randn(*lead, 7, 2, 8, 16).astype(np.float32))
+        unit = {"cached_key": kv(), "cached_value": kv(),
+                "cache_index": jnp.zeros(lead + (4,), jnp.int32)}
+        if kv_int8:
+            for name, sname in (("cached_key", "key_scale"),
+                                ("cached_value", "value_scale")):
+                unit[name], unit[sname] = _quantize_kv(unit[name])
+        tok = {"k": jnp.asarray(r.randn(*lead, 4, 2, 8, 1), jnp.float32),
+               "v": jnp.asarray(r.randn(*lead, 4, 2, 8, 1), jnp.float32)}
+        pages = jnp.asarray([3, 0, 5, 0], jnp.int32)    # two on the null page
+        offsets = jnp.asarray([15, 4, 0, 9], jnp.int32)
+        got = jax.jit(scatter_token_pages)(
+            {"attn": unit}, {"attn": tok}, pages, offsets)["attn"]
+
+        def parent(dst, val):
+            if stacked:
+                return dst.at[:, pages, :, :, offsets].set(
+                    val[..., 0].transpose(1, 0, 2, 3))
+            return dst.at[pages, :, :, offsets].set(val[..., 0])
+
+        for name, sname, leaf in (("cached_key", "key_scale", tok["k"]),
+                                  ("cached_value", "value_scale", tok["v"])):
+            planes = {name: leaf}
+            if kv_int8:
+                planes[name], planes[sname] = _quantize_kv(leaf)
+            for plane, val in planes.items():
+                want = parent(unit[plane], val)
+                assert got[plane].dtype == unit[plane].dtype
+                np.testing.assert_array_equal(_pages(got[plane])[1:],
+                                              _pages(want)[1:])
+
+    @pytest.mark.parametrize("kv_int8", [False, True], ids=["fp", "int8"])
+    def test_no_scan_streams_the_pool_and_every_leaf_is_aliased(self,
+                                                                kv_int8):
+        """Structure of the kernel-path program of a scanned model: the
+        layer scan neither slices (xs) nor restacks (ys) anything of the
+        pool's per-layer shape — only the small per-layer state and the
+        step's one-token K/V — and every donated pool leaf is an output
+        buffer of the lowered program."""
+        from deepspeed_tpu.serving.paging.manager import \
+            _paged_decode_iter_impl
+        scene = _PagedScene(scan_layers=True, kv_int8=kv_int8)
+        static = (0, 11, 12, 13, 14, 15, 16)
+        per_layer = {x.shape[1:] for x in jax.tree.leaves(scene.pool)
+                     if x.ndim == 5}
+        assert (9, 2, 16, PAGED_PAGE) in per_layer
+        seen = 0
+        for use_kernel, streams_pool in ((True, False), (False, True)):
+            jaxpr = jax.make_jaxpr(_paged_decode_iter_impl,
+                                   static_argnums=static)(
+                *scene.args(use_kernel))
+            layer_scans = [e for e in _walk_eqns(jaxpr.jaxpr)
+                           if e.primitive.name == "scan"
+                           and e.params["length"] == 2]
+            assert layer_scans
+            for eqn in layer_scans:
+                xs, ys = _scan_streams(eqn)
+                seen += 1
+                # K/V-shaped streams longer than the step's one token:
+                # the gather path streams its gathered view by design
+                # (which shows that this looks in the right place)
+                kv = [sh for sh in xs + ys if len(sh) == 5 and sh[-1] > 1]
+                assert bool(kv) == streams_pool, (use_kernel, kv)
+                assert not any(sh[1:] in per_layer for sh in xs + ys)
+        assert seen >= 2
+        lowered = jax.jit(_paged_decode_iter_impl, static_argnums=static,
+                          donate_argnums=(2, 4)).lower(*scene.args(True))
+        main = lowered.as_text().split("func.func public @main(", 1)[1]
+        args = main.split(") -> ", 1)[0].split("%arg")[1:]
+        for leaf in jax.tree.leaves(scene.pool):
+            if leaf.ndim < 4:
+                continue
+            dims = "x".join(map(str, leaf.shape))
+            mine = [a for a in args if f"tensor<{dims}x" in a]
+            assert mine and all("tf.aliasing_output" in a for a in mine), \
+                (dims, mine)
+
+    @pytest.mark.parametrize("program", ["train", "generate"])
+    def test_without_a_paged_view_the_layer_scan_is_what_it_was(self,
+                                                               program):
+        """The shared scan of ``GPT.__call__``: ``value_and_grad`` of a
+        training loss streams ``params`` alone, ``generate()`` streams
+        ``params`` and restacks ``cache`` — no pool collection, no layer
+        index, nothing the parent's trace did not carry."""
+        m, params = _model(vocab=64, max_seq_len=128)
+        stacked = sorted(x.shape for x in jax.tree.leaves(params["h"]))
+        ids = jnp.ones((2, 8), jnp.int32)
+        if program == "train":
+            def loss(p):
+                logits = m.apply({"params": p}, ids)
+                return jnp.mean(jax.nn.log_softmax(logits)[..., 0])
+            jaxpr = jax.make_jaxpr(jax.value_and_grad(loss))(params)
+            cache = []
+        else:
+            jaxpr = jax.make_jaxpr(lambda p: generate(
+                m, p, ids, max_new_tokens=4, temperature=0.0, max_len=128))(
+                    params)
+            cache = sorted(x.shape for x in jax.tree.leaves(
+                init_cache(m, params, 2, 128)["h"]))
+        scans = [e for e in _walk_eqns(jaxpr.jaxpr)
+                 if e.primitive.name == "scan" and e.params["length"] == 2]
+        assert scans
+        forward = 0
+        for eqn in scans:
+            assert not any(
+                v.aval.ndim >= 5 for v in eqn.invars[:eqn.params[
+                    "num_consts"]]), "a stacked pool crossed the scan"
+            xs, ys = _scan_streams(eqn)
+            ints = [v.aval for v in eqn.invars
+                    if jnp.issubdtype(v.aval.dtype, jnp.integer)]
+            if sorted(xs) == sorted(stacked + cache):
+                forward += 1
+                # (under value_and_grad the ys are the residuals)
+                assert program == "train" or sorted(ys) == cache
+                # cache_index is the one integer a decode scan streams
+                assert len(ints) == (1 if cache else 0), ints
+        # train: the forward scan; generate: prefill and the decode step
+        assert forward == (1 if program == "train" else 2)
+
+
+# ---------------------------------------------------------------------------
 # paging disabled: bit-identical to the contiguous engine
 # ---------------------------------------------------------------------------
 
