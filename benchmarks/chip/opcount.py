@@ -2,20 +2,8 @@
 with the benchmark so that no PR that claims a gain can change what a
 share of the peak is a share of. A multiply-add counts as 2 operations;
 recomputed operations (remat, the flash backward's second pass over the
-scores) do not count."""
-
-
-def ops_per_token(n_embd, n_layer, vocab_size, seq, ffn_mult=4):
-    """Forward + backward operations per trained token of a GPT-2 block
-    stack with a tied head: 6 per weight that a token multiplies (2
-    forward, 4 backward), plus causal attention's two ``S x S`` products
-    per layer (scores and values: ``2 * 2 * seq * n_embd`` forward over
-    the full square, half of it under the causal mask, times 3 for
-    forward + backward)."""
-    per_layer_weights = (4 + 2 * ffn_mult) * n_embd * n_embd
-    weights = n_layer * per_layer_weights + vocab_size * n_embd
-    attention = n_layer * 3 * (2 * 2 * seq * n_embd) // 2
-    return 6 * weights + attention
+scores) do not count. What a whole model needs per trained token is its
+family's count (``families/<family>.py`` ``ops_per_token``)."""
 
 
 def flash_ops(batch, heads, seq, head_dim, backward):

@@ -9,16 +9,23 @@ import time
 
 import numpy as np
 
-from . import model as bench_model
+from . import families, model as bench_model
 from . import reference, traffic as traffic_mod, tracing
 from .phases import peak_bytes
 from .stats import percentile, rate, samples_beyond, tail, token_spans_ms
 
 # A served token may sit at most this many standard deviations of the
 # reference's logits below the reference's best logit at its position
-# (chip_smoke.py's rule): bf16 rounding moves a logit by hundredths of a
-# sigma (0.015 measured, PR 21), a wrong attention path by whole sigmas.
-# Never token equality: random weights have near-ties.
+# (chip_smoke.py's rule). Never token equality: random weights have
+# near-ties, and bf16 rounding flips 1-3% of them. The limit refuses a
+# wrong path (a wrong attention path reads whole sigmas, PR 21) and
+# nothing finer: the program's own int8 weight-only serving moves a
+# logit only 1.8 times as far as bf16 does, and no number made from the
+# served tokens alone parts the two by the factor of three a limit
+# needs — the mean gap, the mean gap of the flipped tokens and the share
+# of flipped tokens were read on twelve seeds of 3,750 tokens each
+# (tools/control.py; both readings of each: PERF.md section 2). That
+# takes the served logits, which the server does not hand out.
 LOGIT_TOL_SIGMA = 0.1
 CHECKED_REQUESTS = 4
 
@@ -221,15 +228,16 @@ def run(cell, args, phases, compile_log, devices, say):
     from deepspeed_tpu.ops.pallas import tuning
 
     config = cell.config
+    family = families.load(config)
     mix = traffic_mod.resolve(cell.traffic, args.rehearse)
-    sizes = bench_model.sizes(config, args.rehearse)
+    sizes = family.sizes(config, args.rehearse)
     serving = (config["rehearse"]["serving"] if args.rehearse
                else config["serving"])
     vocab = sizes["vocab_size"]
     page_len = serving["paging"]["page_len"]
 
     tuning.clear_last_dispatch()
-    module = bench_model.build_gpt(config, args.rehearse)
+    module = family.build(config, args.rehearse)
     params = bench_model.seeded_params(module, args.seed)
     eng = ds.init_inference(module, params=params,
                             dtype=getattr(jnp, config["compute_dtype"]))
@@ -240,7 +248,8 @@ def run(cell, args, phases, compile_log, devices, say):
     phases.mark("first_call")
     next(warm)
     if not args.rehearse:
-        _assert_paged_kernel(tuning, page_len)
+        families.check_kernels(tuning.last_dispatch,
+                               family.expected_kernels(serving))
     t_lead = phases.mark("warmup")
 
     lead_in, seconds = mix["lead_in_s"], args.seconds
@@ -270,15 +279,15 @@ def run(cell, args, phases, compile_log, devices, say):
     del srv
     gc.collect()                    # the page pool goes before the check
     t0 = time.monotonic()
-    worst, sigma, exact, total = _reference_check(
-        params, sampled, sizes, config, serving["max_len"])
-    say(f"reference check: {exact}/{total} served tokens of "
-        f"{len(sampled)} requests are the float32 argmax; largest logit "
-        f"gap {worst:.4f} = {worst / sigma:.4f} sigma (tolerance "
-        f"{LOGIT_TOL_SIGMA} sigma) in {time.monotonic() - t0:.1f}s")
+    check = _reference_check(family, params, sampled, sizes, config,
+                             serving["max_len"])
+    say(f"reference check: {check['exact']}/{check['tokens']} served tokens "
+        f"of {len(sampled)} requests are the float32 argmax; largest logit "
+        f"gap {check['max']:.4f} sigma (tolerance {LOGIT_TOL_SIGMA} sigma), "
+        f"mean {check['mean']:.2e}, in {time.monotonic() - t0:.1f}s")
     if compiled["compile_events"]:
         say(f"COMPILED INSIDE THE WINDOW: {compiled['compiled']}")
-    correct = (total > 0 and worst <= LOGIT_TOL_SIGMA * sigma
+    correct = (check["tokens"] > 0 and check["max"] <= LOGIT_TOL_SIGMA
                and compiled["compile_events"] == 0)
 
     # host-clock samples of a traced run end where its capture begins
@@ -356,46 +365,46 @@ def _open_loop_tails(judged, stopped, span_ms, cutoff, say):
     return tails, series
 
 
-def _assert_paged_kernel(tuning, page_len):
-    path = tuning.last_dispatch("paged_decode").get("path")
-    kern = tuning.last_dispatch("paged_attention").get(f"page{page_len}")
-    for what, rec in (("paged decode path", path),
-                      ("paged_attention kernel", kern)):
-        if not rec or rec.get("interpret") is not False \
-                or rec.get("impl", "kernel") != "kernel":
-            raise RuntimeError(f"{what} did not run the Mosaic kernel: {rec}")
-
-
 def _sample(finished, seed):
     done = [r for r in finished if r.finished]
     pick = np.random.default_rng(seed + 3).permutation(len(done))
     return [done[i] for i in pick[:CHECKED_REQUESTS]]
 
 
-def _reference_check(params, sampled, sizes, config, width):
+def _reference_check(family, params, sampled, sizes, config, width):
     """Teacher-force each sampled request through the plain float32
     reference of the same weights, one request at a time at a fixed
-    width; every served token must sit within the tolerance of its
-    position's best reference logit."""
+    width, and reduce on the device: each position's gap between the
+    best logit and the logit of the token that follows, and the standard
+    deviation of the request's logits. Returns the largest and the mean
+    gap of the served tokens in sigmas, how many are the reference's
+    argmax, and every token's gap (``gaps``, for ``tools/control.py``)."""
     import jax
     import jax.numpy as jnp
+
+    def reduce(p, ids, n):
+        lg = family.reference_logits(p, ids, sizes, config)[0]
+        follows = jnp.roll(ids[0], -1)
+        gap = lg.max(-1) - jnp.take_along_axis(lg, follows[:, None], -1)[:, 0]
+        real = (jnp.arange(lg.shape[0]) < n)[:, None]
+        mean = jnp.sum(jnp.where(real, lg, 0.0)) / (n * lg.shape[1])
+        var = jnp.sum(jnp.where(real, jnp.square(lg - mean), 0.0))
+        return gap, jnp.sqrt(var / (n * lg.shape[1]))
+
+    gaps = []
     with reference.highest():
-        forward = jax.jit(lambda p, ids: reference.logits(
-            p, ids, sizes["n_head"], config["layer_norm_epsilon"]))
-        worst, exact, total, sigmas = 0.0, 0, 0, []
+        forward = jax.jit(reduce)
         for rec in sampled:
             prompt = np.asarray(rec.spec["prompt"])
             out = np.asarray(rec.handle.output_tokens, np.int32)
             n = len(prompt) + len(out)
             ids = np.zeros((1, width), np.int32)
             ids[0, :n] = np.concatenate([prompt, out])
-            lg = np.asarray(forward(params, jnp.asarray(ids)))[0, :n]
-            sigmas.append(float(lg.std()))
-            for j, tok in enumerate(out):
-                row = lg[len(prompt) + j - 1]
-                gap = float(row.max() - row[tok])
-                worst = max(worst, gap)
-                exact += int(gap == 0.0)
-                total += 1
-    sigma = min(sigmas) if sigmas else 1.0
-    return worst, sigma, exact, total
+            gap, sigma = forward(params, jnp.asarray(ids), n)
+            gaps.append(np.asarray(gap)[len(prompt) - 1:n - 1]
+                        / float(sigma))
+    flat = np.concatenate(gaps) if gaps else np.zeros(0)
+    return {"max": float(flat.max()) if flat.size else math.inf,
+            "mean": float(flat.mean()) if flat.size else math.inf,
+            "exact": int((flat == 0.0).sum()), "tokens": int(flat.size),
+            "gaps": gaps}

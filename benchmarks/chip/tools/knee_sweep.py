@@ -40,14 +40,16 @@ def main():
     import jax.numpy as jnp
     import deepspeed_tpu as ds
     from deepspeed_tpu.utils.host_env import configure_compile_cache
-    from benchmarks.chip import manifest, model, serve_runner, traffic
+    from benchmarks.chip import (families, manifest, model, serve_runner,
+                                 traffic)
     from benchmarks.chip.stats import percentile
     configure_compile_cache()
     cell = manifest.Cell(ROOT, manifest.load(ROOT), args.workload)
     config = cell.config
+    family = families.load(config)
     mix = traffic.resolve(cell.traffic, False)
-    vocab = config["vocab_size"]
-    module = model.build_gpt(config, False)
+    vocab = family.sizes(config, False)["vocab_size"]
+    module = family.build(config, False)
     params = model.seeded_params(module, args.seed)
     srv = ds.init_inference(module, params=params,
                             dtype=getattr(jnp, config["compute_dtype"])

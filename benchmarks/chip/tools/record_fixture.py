@@ -49,6 +49,7 @@ def main():
     from deepspeed_tpu.models import GPT, GPTConfig
     from deepspeed_tpu.observability import trace as spans
     from benchmarks.chip import model as bench_model
+    from benchmarks.chip.families import gpt2
 
     out = os.path.join(ROOT, "chiprun_out", "fixture")
     os.makedirs(out, exist_ok=True)
@@ -65,7 +66,7 @@ def main():
     _serve(ds, smodule, sparams, jnp, np)
     rows = micro * n
     engine, _, _, _ = ds.initialize(
-        model=GPT(cfg), loss_fn=bench_model.chunked_loss(128),
+        model=GPT(cfg), loss_fn=gpt2.train_loss({"loss_chunk": 128}),
         rng=jax.random.PRNGKey(0),
         sample_batch={"input_ids": np.zeros((1, seq), np.int32)},
         config={"train_batch_size": rows,

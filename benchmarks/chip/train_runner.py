@@ -6,20 +6,24 @@ import time
 
 import numpy as np
 
-from . import model as bench_model
+from . import families, model as bench_model
 from . import reference, traffic as traffic_mod, tracing
 from .phases import peak_bytes
 from .stats import rate
 
-# |engine loss - float32 reference loss| on the check rows. The loss of a
-# random-weight model sits within a few tenths of ln(vocab) and each row
-# averages over `seq` positions, so the two agree closely: bf16 rounding
-# of the logits (std ~0.5) moves single positions by ~1e-2 with either
-# sign and the mean of thousands of positions by ~1e-3 (measured on the
-# chip: see PERF.md). A forward that attends to the wrong tokens
-# decorrelates each position's logit from its label and moves the mean by
-# sigma_token / sqrt(positions) ~ 0.55 / sqrt(2048) = 1.2e-2.
-LOSS_TOL = 4e-3
+# The engine's loss against the plain float32 reference's on the same
+# parameters, over CHECK_GROUPS groups of seeded rows: the number compared
+# is the root mean square of the groups' differences. One difference
+# alone has either sign and reads near zero by chance whatever the
+# arithmetic (the mean of thousands of positions, each moved by the
+# logits' rounding): under the single-group limit of 4e-3 that stood
+# until PR 27 the control was never refused. The rms over sixteen grows
+# in step with the logits' error and is steady from seed to seed. On the
+# chip (PERF.md section 2): sound runs' largest 9.3e-5 over 29 readings
+# of both training cells; the control's smallest — the reference with
+# fp8 weights in the engine's place — 2.3e-4 over 16.
+LOSS_RMS_TOL = 1.5e-4
+CHECK_GROUPS = 16
 
 
 def _engine_config(config, micro, chips):
@@ -37,8 +41,9 @@ def run(cell, args, phases, compile_log, devices, say):
     from deepspeed_tpu.ops.pallas import tuning
 
     config = cell.config
+    family = families.load(config)
     mix = traffic_mod.resolve(cell.traffic, args.rehearse)
-    sizes = bench_model.sizes(config, args.rehearse)
+    sizes = family.sizes(config, args.rehearse)
     chips = len(devices)
     seq = mix["seq"]
     micro = mix.get("micro_per_chip", config.get("micro_per_chip"))
@@ -47,10 +52,9 @@ def run(cell, args, phases, compile_log, devices, say):
     tokens_per_step = rows * seq
 
     tuning.clear_last_dispatch()
-    module = bench_model.build_gpt(config, args.rehearse,
-                                   remat=config["remat"])
+    module = family.build(config, args.rehearse)
     engine, _, _, _ = ds.initialize(
-        model=module, loss_fn=bench_model.chunked_loss(config["loss_chunk"]),
+        model=module, loss_fn=family.train_loss(config),
         rng=bench_model.prng_key(args.seed),
         sample_batch={"input_ids": np.zeros((1, seq), np.int32)},
         config=_engine_config(config, micro, chips))
@@ -79,7 +83,8 @@ def run(cell, args, phases, compile_log, devices, say):
     step(record=False)                  # a fixed number of warm calls: 2
     window_start = phases.mark("warmup")
     if not args.rehearse:
-        _assert_flash(tuning)
+        families.check_kernels(tuning.last_dispatch,
+                               family.expected_kernels(None))
     in_window = compile_log.mark()
 
     capture = None
@@ -100,16 +105,18 @@ def run(cell, args, phases, compile_log, devices, say):
     losses = [float(x) for x in losses]
     finite = all(math.isfinite(x) for x in losses)
     t0 = time.monotonic()
-    check = _reference_check(engine, module, config, sizes, seq, chips,
+    check = _reference_check(family, engine, config, sizes, seq, chips,
                              args.seed)
-    say(f"reference check: engine loss {check['engine']:.6f} vs float32 "
-        f"reference {check['reference']:.6f}, |diff| {check['diff']:.2e} "
-        f"(tolerance {LOSS_TOL:.0e}) in {time.monotonic() - t0:.1f}s; "
+    say(f"reference check: rms of (engine loss - float32 reference loss) "
+        f"over {CHECK_GROUPS} groups of {max(2, chips)} rows "
+        f"{check['rms']:.3e} (tolerance {LOSS_RMS_TOL:.1e}), largest "
+        f"{check['max']:.2e}, mean loss {check['engine']:.6f} vs "
+        f"{check['reference']:.6f}, in {time.monotonic() - t0:.1f}s; "
         f"window losses {losses[0]:.4f} .. {losses[-1]:.4f}, "
         f"{'all finite' if finite else 'NOT ALL FINITE'}")
     if compiled["compile_events"]:
         say(f"COMPILED INSIDE THE WINDOW: {compiled['compiled']}")
-    correct = (finite and check["diff"] <= LOSS_TOL
+    correct = (finite and check["rms"] <= LOSS_RMS_TOL
                and compiled["compile_events"] == 0)
     engine.destroy()
 
@@ -124,6 +131,7 @@ def run(cell, args, phases, compile_log, devices, say):
         "observed": {
             "series": {"train_step_ms": [1e3 * s for s in step_s]},
             "tokens_per_s_chip": tokens_per_s_chip,
+            "ops_per_token": family.ops_per_token(sizes, seq),
             "sizes": sizes, "seq": seq, "micro": micro, "chips": chips,
             "compiles_in_window": compiled["compile_events"],
             "compile_mark_at_window": in_window,
@@ -132,37 +140,44 @@ def run(cell, args, phases, compile_log, devices, say):
     }
 
 
-def _assert_flash(tuning):
-    """The step that was warmed ran the Mosaic flash kernel, forward and
-    backward — not the interpreter and not the jnp path."""
-    choice = tuning.last_dispatch("attention").get("backend")
-    flash = tuning.last_dispatch("flash_attention")
-    if not (choice and choice["backend"] == "pallas"):
-        raise RuntimeError(f"attention did not dispatch flash: {choice}")
-    for name, rec in flash.items():
-        if rec.get("interpret") is not False:
-            raise RuntimeError(f"flash_attention/{name} interpreted: {rec}")
-    if not (any(s.startswith("fwd_") for s in flash)
-            and any(s.startswith("bwd_") for s in flash)):
-        raise RuntimeError(f"flash fwd+bwd not both traced: {list(flash)}")
+def check_rows(sizes, seq, chips, seed):
+    """``[CHECK_GROUPS, rows, seq + 1]`` seeded tokens: the check's own
+    rows, none of the window's."""
+    return np.random.default_rng(seed + 1).integers(
+        0, sizes["vocab_size"],
+        size=(CHECK_GROUPS, max(2, chips), seq + 1), dtype=np.int32)
 
 
-def _reference_check(engine, module, config, sizes, seq, chips, seed):
-    """The engine's loss on a few seeded rows against the plain float32
-    reference on the same parameters. Runs after the window, so neither
-    its compile nor its run is in ``setup_s``."""
+def reference_losses(family, params, groups, sizes, config):
+    """The plain float32 reference's mean loss on each group of rows."""
     import jax
     import jax.numpy as jnp
-    import flax.core.meta as flax_meta
-    rows = max(2, chips)
-    ids = np.random.default_rng(seed + 1).integers(
-        0, sizes["vocab_size"], size=(rows, seq + 1), dtype=np.int32)
-    got = float(engine.eval_batch({"input_ids": ids}))
-    params = flax_meta.unbox(engine.params)
-    params = params.get("params", params)
     with reference.highest():
-        want = float(jax.jit(
-            lambda p, x: jnp.mean(reference.next_token_losses(
-                p, x, sizes["n_head"], config["layer_norm_epsilon"])))(
-                    params, jnp.asarray(ids)))
-    return {"engine": got, "reference": want, "diff": abs(got - want)}
+        loss = jax.jit(
+            lambda p, x: jnp.mean(family.reference_next_token_losses(
+                p, x, sizes, config)))
+        return np.array([float(loss(params, jnp.asarray(g)))
+                         for g in groups])
+
+
+def rms(diffs):
+    return float(np.sqrt(np.mean(np.square(diffs))))
+
+
+def engine_params(engine):
+    import flax.core.meta as flax_meta
+    params = flax_meta.unbox(engine.params)
+    return params.get("params", params)
+
+
+def _reference_check(family, engine, config, sizes, seq, chips, seed):
+    """The engine's loss on each group of seeded rows against the plain
+    float32 reference on the same parameters. Runs after the window, so
+    neither its compile nor its run is in ``setup_s``."""
+    groups = check_rows(sizes, seq, chips, seed)
+    got = np.array([float(engine.eval_batch({"input_ids": g}))
+                    for g in groups])
+    want = reference_losses(family, engine_params(engine), groups, sizes,
+                            config)
+    return {"engine": float(got.mean()), "reference": float(want.mean()),
+            "rms": rms(got - want), "max": float(np.abs(got - want).max())}

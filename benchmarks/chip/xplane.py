@@ -20,7 +20,7 @@ import gzip
 import re
 
 WINDOW_SPAN = "bench/trace_window"
-SPAN_PREFIXES = ("serving/", "bench/")
+SPAN_PREFIXES = ("serving/", "train/", "bench/")
 SPAN_NAMES = ("fwd_bwd_step", "step", "data", "fwd", "bwd")
 SHORT_GAP_NS = 2_000
 _COLLECTIVE = re.compile(

@@ -8,6 +8,7 @@ import os
 import pytest
 
 from benchmarks.chip import opcount, peaks
+from benchmarks.chip.families import gpt2
 from benchmarks.chip.stats import (beta_cdf, percentile, rate,
                                    samples_beyond, spread, tail,
                                    token_spans_ms)
@@ -124,7 +125,9 @@ def test_ops_per_token_of_gpt2_125m_by_hand():
     assert weights == 123_532_032
     # causal attention, forward + backward: 12 layers x 6 x 1024 x 768
     attention = 12 * 6 * 1024 * 768
-    assert opcount.ops_per_token(768, 12, 50257, 1024) \
+    sizes = {"n_embd": 768, "n_layer": 12, "n_head": 12,
+             "vocab_size": 50257, "n_positions": 1024}
+    assert gpt2.ops_per_token(sizes, 1024) \
         == 6 * weights + attention == 797_815_296
 
 

@@ -7,7 +7,7 @@ import re
 
 import pytest
 
-from benchmarks.chip import manifest as manifest_mod
+from benchmarks.chip import families, manifest as manifest_mod
 from benchmarks.chip import readers
 
 from ._paths import BENCH, ROOT, manifest
@@ -19,6 +19,29 @@ PATH = re.compile(r"^[A-Za-z0-9_.\-/]{1,200}$")
 SOURCES = {"device_trace", "program_span", "program_counter", "host_clock"}
 CELLS = [w["name"] for w in M["workloads"]]
 METRICS = M["end_to_end"] + M["per_layer"]
+# PR 23's three configurations: GPT-2 small and the GPT-3 1.3B recipe at
+# published widths, uncut (n_embd, n_layer, n_head, n_positions)
+PR23_WIDTHS = {"gpt2-125m-zero1": (768, 12, 12, 1024),
+               "gpt2-1.3b-zero3-fsdp4": (2048, 24, 16, 2048),
+               "gpt2-1.3b-serve": (2048, 24, 16, 2048)}
+PR23_PER_LAYER = {
+    "setup.compile_s", "setup.compiles", "train.step_ms_p50",
+    "train.mfu_pct", "train.flash_share_pct", "train.flash_roofline_pct",
+    "train.collective_exposed_pct", "train.device_idle_pct",
+    "train.peak_hbm_gb", "serve.iter_ms_p50.chat",
+    "serve.iter_ms_max.chat", "serve.tpot_p90_ms.chat",
+    "serve.ttft_p90_ms.chat", "serve.gen_lag_ms_p90.chat",
+    "serve.paged_attn_share_pct.chat",
+    "serve.device_idle_pct.chat", "serve.iter_ms_p50.longprompt",
+    "serve.prefill_share_pct.longprompt", "serve.ttft_p50_ms.longprompt",
+    "serve.device_idle_pct.longprompt"}
+PR24_PER_LAYER = {
+    "serve.queue_wait_p90_ms.chat", "serve.prefill_wait_p90_ms.chat",
+    "serve.batch_occupancy_pct.chat", "serve.prefix_hit_pct.chat",
+    "serve.host_ms_p50.chat", "serve.host_ms_max.chat",
+    "serve.readback_ms_max.chat", "serve.prefill_wait_p50_ms.longprompt",
+    "serve.batch_occupancy_pct.longprompt", "serve.host_ms_p50.longprompt",
+    "train.host_to_dispatch_ms_p50"}
 
 
 def _line(text):
@@ -60,13 +83,24 @@ def test_config_entry(conf):
         body = json.load(f)
     assert body["kind"] in ("train", "serve")
     assert body["source"] == conf["source"]
-    # published widths, uncut: GPT-2 small and the GPT-3 1.3B recipe
-    widths = {"gpt2-125m-zero1": (768, 12, 12, 1024),
-              "gpt2-1.3b-zero3-fsdp4": (2048, 24, 16, 2048),
-              "gpt2-1.3b-serve": (2048, 24, 16, 2048)}[conf["name"]]
-    assert (body["n_embd"], body["n_layer"], body["n_head"],
-            body["n_positions"]) == widths
-    assert body["vocab_size"] == 50257 and conf["reduced"] == []
+    # the sizes the cell runs are the file's own, through its family
+    sizes = families.load(body).sizes(body, rehearse=False)
+    assert sizes["vocab_size"] > 0
+    assert all(body[k] == v for k, v in sizes.items())
+    if conf["name"] in PR23_WIDTHS:
+        assert (sizes["n_embd"], sizes["n_layer"], sizes["n_head"],
+                sizes["n_positions"]) == PR23_WIDTHS[conf["name"]]
+        assert sizes["vocab_size"] == 50257 and conf["reduced"] == []
+    # published widths, from the file itself: the source's own keys and
+    # values; what the file changes is named in `reduced`, and is one of
+    # the keys its family declares as depth: nothing else can be cut
+    assert "published" in body or conf["name"] in PR23_WIDTHS
+    published = body.get("published", {})
+    assert set(sizes) <= set(published) or not published
+    assert set(conf["reduced"]) <= set(published)
+    for key, value in published.items():
+        assert (body[key] == value) != (key in conf["reduced"]), key
+    assert set(conf["reduced"]) <= set(families.load(body).DEPTH_KEYS)
 
 
 def test_config_names_and_files_are_distinct():
@@ -89,8 +123,8 @@ def test_cells_are_distinct_and_one_in_four_takes_four_chips():
     pairs = {(w["config"], w["traffic"]) for w in M["workloads"]}
     assert len(pairs) == len(CELLS)
     four = [w for w in M["workloads"] if w["chips"] == 4]
-    assert len(four) == 1 and len(four) <= max(1, len(CELLS) // 4)
-    assert four[0]["name"] == "train-1p3b-zero3-4chip"
+    assert 1 <= len(four) <= max(1, len(CELLS) // 4)
+    assert "train-1p3b-zero3-4chip" in [w["name"] for w in four]
 
 
 @pytest.mark.parametrize("metric", METRICS, ids=lambda m: m["name"])
@@ -132,17 +166,9 @@ def test_the_issues_metrics_letter_for_letter():
     assert [m["name"] for m in M["end_to_end"]] == [
         "train_tokens_per_s_chip", "itl_p90_ms", "serve_tokens_per_s",
         "setup_s"]
-    assert {m["name"] for m in M["per_layer"]} == {
-        "setup.compile_s", "setup.compiles", "train.step_ms_p50",
-        "train.mfu_pct", "train.flash_share_pct", "train.flash_roofline_pct",
-        "train.collective_exposed_pct", "train.device_idle_pct",
-        "train.peak_hbm_gb", "serve.iter_ms_p50.chat",
-        "serve.iter_ms_max.chat", "serve.tpot_p90_ms.chat",
-        "serve.ttft_p90_ms.chat", "serve.gen_lag_ms_p90.chat",
-        "serve.paged_attn_share_pct.chat",
-        "serve.device_idle_pct.chat", "serve.iter_ms_p50.longprompt",
-        "serve.prefill_share_pct.longprompt", "serve.ttft_p50_ms.longprompt",
-        "serve.device_idle_pct.longprompt"}
+    # PR 23's twenty and PR 24's eleven stay; later PRs append their own
+    assert {m["name"] for m in M["per_layer"]} \
+        >= PR23_PER_LAYER | PR24_PER_LAYER
 
 
 @pytest.mark.parametrize("name", CELLS)

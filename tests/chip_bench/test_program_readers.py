@@ -1,17 +1,13 @@
 """The per-layer metrics that read what the program counts about itself
 (ISSUE 24): the two readers of ``readers/program.py`` on a hand-filled
-registry, the eleven metric files, and a rehearsal of three cells whose
-manifest names them.
+registry, the eleven metric files row for row, and a traced rehearsal of
+each cell that has such a metric.
 
-``BENCHMARK.json`` does not name them yet: ``test_manifest.py`` pins the
-per-layer set by equality, and a PR that is not a benchmark PR may edit
-no file the benchmark has. A metric's manifest entry is its file less
-``reader``, ``args`` and ``note`` (``entry`` below); the benchmark PR
-that appends the eleven relaxes that one comparison."""
+A metric's manifest entry is its file less ``reader``, ``args`` and
+``note`` (``entry`` below). A later PR adds a metric on a ``registry_``
+reader as a file and an entry: the eleven are pinned, the set is not."""
 
-import json
 import os
-import shutil
 
 import pytest
 
@@ -19,8 +15,9 @@ from benchmarks.chip import manifest as manifest_mod
 from benchmarks.chip import readers, stats
 from deepspeed_tpu.observability import metrics as registry_mod
 
-from ._paths import BENCH, ROOT, manifest
+from ._paths import BENCH, PYTHONPATH, ROOT, RUN, manifest
 from .test_rehearse import _last_line, _run
+from .test_manifest import PR23_PER_LAYER
 
 # ISSUE 24's table, letter for letter: name -> (unit, better, layer,
 # moves, cells)
@@ -119,18 +116,24 @@ def test_ratio_of_counters_and_nothing_over_a_zero_denominator(registry):
     assert got == 25.0 and len(said) == 1
 
 
-def test_the_issues_eleven_metric_files_and_no_other_read_the_registry():
-    ours = set()
+def _registry_metrics():
+    """Every metric file on a ``registry_`` reader, by name."""
+    out = {}
     for f in os.listdir(os.path.join(BENCH, "metrics")):
         s = manifest_mod.load_json(os.path.join(BENCH, "metrics", f))
         if s["reader"].startswith("registry_"):
-            ours.add(s["name"])
             assert f == s["name"] + ".json"
-    assert ours == set(ISSUE_24)
-    # in the manifest all of them or none, and PR 23's twenty stay
+            out[s["name"]] = s
+    return out
+
+
+def test_the_issues_eleven_metric_files_read_the_registry():
+    ours = set(_registry_metrics())
+    assert ours >= set(ISSUE_24)
+    # the manifest names the eleven, and PR 23's twenty are none of them
     named = {m["name"] for m in M["per_layer"]}
-    assert ours <= named or not ours & named
-    assert len(named - ours) == 20
+    assert set(ISSUE_24) <= named
+    assert PR23_PER_LAYER <= named - ours
 
 
 @pytest.mark.parametrize("name", sorted(ISSUE_24))
@@ -153,32 +156,17 @@ def test_metric_file_is_the_issues_row_and_names_a_registered_reader(name):
             == entry(name)
 
 
-@pytest.fixture(scope="module")
-def checkout(tmp_path_factory):
-    """The benchmark copied unchanged beside a manifest that names the
-    eleven (appended, as the benchmark PR will)."""
-    root = tmp_path_factory.mktemp("with_program_metrics")
-    shutil.copytree(BENCH, root / "benchmarks" / "chip",
-                    ignore=shutil.ignore_patterns("__pycache__",
-                                                  ".bench_out"))
-    m = manifest()
-    named = {e["name"] for e in m["per_layer"]}
-    m["per_layer"] += [entry(n) for n in ISSUE_24 if n not in named]
-    (root / "BENCHMARK.json").write_text(json.dumps(m))
-    return root
-
-
-@pytest.mark.parametrize("cell", ["serve-1p3b-chat", "serve-1p3b-longprompt",
-                                  "train-125m-zero1"])
-def test_a_traced_rehearsal_reads_every_program_metric_of_the_cell(
-        checkout, cell):
-    proc = _run(str(checkout / "benchmarks" / "chip" / "run.py"),
-                "--workload", cell, "--seed", "11", "--seconds", "1.5",
-                "--trace", "1", "--rehearse", cwd=checkout,
-                extra_env={"PYTHONPATH": ROOT})
+@pytest.mark.parametrize("cell", sorted(
+    {cell for s in _registry_metrics().values() for cell in s["workloads"]}))
+def test_a_traced_rehearsal_reads_every_program_metric_of_the_cell(cell):
+    proc = _run(RUN, "--workload", cell, "--seed", "11", "--seconds", "1.5",
+                "--trace", "1", "--rehearse",
+                extra_env={"PYTHONPATH": PYTHONPATH})
     line = _last_line(proc)
     assert line["correct"] is True and line["metrics"] == {}
     said = next(ln for ln in proc.stdout.splitlines()
                 if "readers gave a value for" in ln)
-    want = [n for n, row in ISSUE_24.items() if cell in row[4]]
+    want = [n for n, s in _registry_metrics().items()
+            if cell in s["workloads"]]
     assert want and all(repr(n) in said for n in want), said
+    assert all(n in want for n, row in ISSUE_24.items() if cell in row[4])

@@ -11,7 +11,17 @@ from benchmarks.chip import traffic
 
 from ._paths import BENCH
 
-MIXES = ["chat-open-0p8knee", "longprompt-closed-16"]
+def _request_mixes():
+    """Every traffic file of requests: a mix is held by being there."""
+    out = []
+    for f in sorted(os.listdir(os.path.join(BENCH, "traffic"))):
+        with open(os.path.join(BENCH, "traffic", f)) as fh:
+            if json.load(fh)["kind"] == "requests":
+                out.append(f[:-len(".json")])
+    return out
+
+
+MIXES = _request_mixes()
 BIG = 2 ** 31 + 12345
 
 
@@ -65,6 +75,10 @@ def test_lengths_stay_inside_the_mix_and_the_server(name):
         assert r["prompt"].dtype == np.int32 and r["prompt"].min() >= 1
 
 
+def test_the_two_mixes_of_today_are_among_them():
+    assert {"chat-open-0p8knee", "longprompt-closed-16"} <= set(MIXES)
+
+
 def test_chat_shares_its_system_prompts_as_the_file_says():
     mix = _mix("chat-open-0p8knee")
     reqs = _take(mix, 3, mix["block"])
@@ -82,6 +96,31 @@ def test_open_schedule_falls_due_on_the_wall_clock_at_the_files_rate():
     assert due == sorted(due) and due[-1] <= 200.0
     assert len(sched) / 200.0 == pytest.approx(mix["rate_per_s"], rel=0.1)
     assert mix["rate_per_s"] == pytest.approx(0.8 * mix["knee_per_s"])
+
+
+def test_a_mix_of_bursts_is_its_lengths_arriving_sixteen_at_a_time():
+    # the mix a burst cell would add as a file (PERF.md section 7): the
+    # chat mix with another arrival process and a lead-in between bursts
+    chat = _mix("chat-open-0p8knee")
+    burst = dict(chat, lead_in_s=6.0,
+                 arrivals={"process": "bursts", "size": 16, "every_s": 4.0})
+    sched = traffic.open_schedule(burst, BIG, 50257, 6.0 + 45.0)
+    due = [r["due_s"] for r in sched]
+    assert sorted(set(due)) == [4.0 * i for i in range(1, 13)]
+    assert all(due.count(t) == 16 for t in set(due))
+    # the window [lead-in, lead-in + 45) opens and closes between bursts,
+    # so rounding of the clock never moves a burst across its edge
+    lead = burst["lead_in_s"]
+    assert all(abs(t - edge) >= 1.0 for t in set(due)
+               for edge in (lead, lead + 45.0))
+    assert sum(lead <= t < lead + 45.0 for t in due) == 176
+    # the chat mix's lengths, block for block (bursts leave the gaps in
+    # place, so the fixed order is another one)
+    chat_reqs, burst_reqs = _take(chat, BIG, 64), _take(burst, BIG, 64)
+    for lo in (0, 32):
+        for key in (lambda r: len(r["prompt"]), lambda r: r["max_new_tokens"]):
+            assert sorted(map(key, chat_reqs[lo:lo + 32])) == \
+                sorted(map(key, burst_reqs[lo:lo + 32]))
 
 
 def test_bursts_keep_the_mean_rate():

@@ -153,6 +153,30 @@ def test_a_gap_goes_to_the_shortest_span_over_its_middle():
         ["_gaps_under_2_us_", 1e-6]]
 
 
+@pytest.mark.parametrize("name,kept", [
+    ("train/prepare", True), ("train/finish", True),
+    ("serving/decode_iter", True), ("bench/train_batch", True),
+    ("fwd_bwd_step", True), ("training", False), ("PjitFunction(f)", False),
+])
+def test_spans_are_the_programs_and_the_benchmarks_by_prefix(name, kept):
+    assert xplane._is_span(name) is kept
+
+
+def test_the_trainers_gap_goes_to_its_own_span_inside_the_benchmarks():
+    """``train_batch`` entry -> the step program's enqueue is
+    ``train/prepare``, inside ``bench/train_batch``: the idle gap under
+    it files there and no longer under the outer span."""
+    ops = [(0, 100, "%a = f32[2] add()"), (600, 900, "%b = f32[2] add()"),
+           (960, 1000, "%c = f32[2] add()")]
+    spans = [(50, 950, "bench/train_batch"), (110, 590, "train/prepare"),
+             (905, 940, "train/finish")]
+    t = _trace([(s * 1000, e * 1000, n) for s, e, n in ops],
+               [(s * 1000, e * 1000, n) for s, e, n in spans
+                if xplane._is_span(n)], window=(0.0, 1_000_000.0))
+    assert xplane.idle_gaps(t) == [["train/prepare", 500e-6],
+                                   ["train/finish", 60e-6]]
+
+
 def test_operations_count_only_inside_the_named_program():
     ops = [(10, 20, '%attn.1 = bf16[1,2,128,64] custom-call(), '
             'custom_call_target="tpu_custom_call"'),
