@@ -9,6 +9,10 @@ width of gpt2-125m with seeded weights and seeded tokens (no network):
 - serve:   ``ds.init_inference(...).serve({...paging...})``, more
            mixed-length requests than slots, ``submit`` -> ``run``, checked
            against the float32 reference at the logit level;
+- olmoe:   the same serving path with OLMoE-1B-7B's block at its
+           published widths, two layers deep: a dropless top-8 router
+           over 64 SwiGLU experts, RMSNorm, QK-norm and RoPE on the paged
+           path, the grouped expert matmul compiled by Mosaic;
 - kernels: every Pallas kernel compiled by Mosaic and run once at a real
            shape against its jnp reference;
 - offload: offload configs really place state in ``pinned_host``, or
@@ -41,6 +45,9 @@ FULL = {
     "serve": dict(preset="gpt2-125m", num_slots=4, max_len=512,
                   page_len=128, n_requests=10, prompt_max=300, new_max=24,
                   paging_kernel="auto", n_layers=None, logit_tol=0.1),
+    "olmoe": dict(n_layers=2, num_slots=4, max_len=512, page_len=128,
+                  n_requests=10, prompt_max=300, new_max=24,
+                  paging_kernel="auto", logit_tol=0.1),
     "kernels": dict(seq=1024, heads=12, batch=2, cache_len=1024,
                     gemv_k=4096, gemv_n=16384, sparse_seq=2048,
                     gemv_timeout_s=180),
@@ -307,9 +314,10 @@ def _serve_and_check(eng, module, params, reqs, num_slots, max_len,
     # each served token must sit within ``logit_tol`` of that position's
     # best reference logit (a random-init greedy chain has near-ties, so
     # the token may differ from the reference argmax, but not by more).
-    ref_model = type(module)(dataclasses.replace(
-        module.config, dtype=jnp.float32, param_dtype=jnp.float32,
-        attn_backend="reference"))
+    plain = {"dtype": jnp.float32, "param_dtype": jnp.float32}
+    if hasattr(module.config, "attn_backend"):
+        plain["attn_backend"] = "reference"
+    ref_model = type(module)(dataclasses.replace(module.config, **plain))
     params32 = jax.tree.map(lambda x: jnp.asarray(x, jnp.float32), params)
     width = max(len(p) + m for p, m in reqs)
     full = np.zeros((len(reqs), width), np.int32)
@@ -383,6 +391,53 @@ def phase_serve(preset, num_slots, max_len, page_len, n_requests,
     _check(n_requests > num_slots, "serve needs more requests than slots")
     _serve_and_check(eng, model, params, reqs, num_slots, max_len, page_len,
                      paging_kernel, logit_tol, f"serve {preset}")
+
+
+def phase_olmoe(n_layers, num_slots, max_len, page_len, n_requests,
+                prompt_max, new_max, paging_kernel, logit_tol, **widths):
+    """OLMoE's block at published widths through the same serving path:
+    the chunk-prefill and paged-decode programs carry an expert layer,
+    the grouped matmul is a Mosaic call in the compiled decode program,
+    and the router's counts come back with the tokens."""
+    import numpy as np
+    import jax.numpy as jnp
+    import deepspeed_tpu as ds
+    from deepspeed_tpu.models.olmoe import OLMoE, OLMoEConfig
+    from deepspeed_tpu.observability.metrics import get_registry
+    from deepspeed_tpu.observability.programs import get_program_registry
+
+    model = OLMoE(OLMoEConfig(num_hidden_layers=n_layers, dtype=jnp.bfloat16,
+                              param_dtype=jnp.bfloat16, **widths))
+    params = _seeded_params(model)
+    eng = ds.init_inference(model, params=params, dtype=jnp.bfloat16)
+    reqs = _requests(np.random.default_rng(2), n_requests,
+                     model.config.vocab_size, prompt_max, new_max)
+    counters = {name: get_registry().counter("moe/" + name)
+                for name in ("assignments", "expert_calls",
+                             "experts_touched", "experts_offered")}
+    before = {name: c.value for name, c in counters.items()}
+    _serve_and_check(eng, model, params, reqs, num_slots, max_len, page_len,
+                     paging_kernel, logit_tol, "serve olmoe")
+    decode = get_program_registry().get("serving/paged_decode")
+    args, kwargs = decode._last_avals
+    hlo = decode.lower(*args, **kwargs).compile().as_text()
+    _check("ragged-dot" in hlo and "tpu_custom_call" in hlo,
+           "serve olmoe: the compiled decode program holds no Mosaic "
+           "ragged-dot call: the grouped expert matmul did not lower to "
+           "XLA's kernel")
+    moved = {name: c.value - before[name] for name, c in counters.items()}
+    _say(f"serve olmoe: router counted {moved}")
+    k, experts = model.config.num_experts_per_tok, model.config.num_experts
+    _check(moved["expert_calls"] > 0
+           and moved["experts_offered"] == moved["expert_calls"] * experts,
+           f"serve olmoe: no routing was counted: {moved}")
+    # every generated token but a request's last is routed once a layer,
+    # and so is every prompt token the prefix cache did not serve
+    least = sum(m - 1 for _, m in reqs) * k * n_layers
+    most = sum(len(p) + m - 1 for p, m in reqs) * k * n_layers
+    _check(least < moved["assignments"] <= most,
+           f"serve olmoe: {moved['assignments']} token-expert pairs, "
+           f"outside ({least}, {most}]: idle slots or padding were routed")
 
 
 # ---------------------------------------------------------------------------
@@ -897,7 +952,7 @@ def main():
     _say(f"compile cache at {cache_dir}")
 
     phases = [("train", phase_train), ("serve", phase_serve),
-              ("kernels", phase_kernels)]
+              ("olmoe", phase_olmoe), ("kernels", phase_kernels)]
     if device["count"] >= 4:
         phases.append(("multichip", phase_multichip))
     else:

@@ -36,6 +36,27 @@ def init_cache(module, params, batch_size: int, max_len: int):
     return jax.tree.map(lambda s: jnp.zeros(s.shape, s.dtype), cache_shape)
 
 
+def apply_decode(module, variables, ids, positions, live, mutable):
+    """``module.apply(variables, ids, decode=True, ...)`` as a serving
+    program makes it: ``(logits, vars_out, counts)``.
+
+    A module with an expert layer (``routes_tokens``, models/olmoe.py) is
+    handed ``live()`` — ``[b, s]`` bool, the rows that hold a request's
+    token — so that an idle slot or a chunk's padding is routed to no
+    expert, and hands back its router's counts ``[L, E]``. For any other
+    module this is the plain call: ``live`` is not called and ``counts``
+    is None, an empty pytree that adds nothing to the program."""
+    if not getattr(type(module), "routes_tokens", False):
+        logits, vars_out = module.apply(
+            variables, ids, decode=True, positions=positions,
+            mutable=mutable)
+        return logits, vars_out, None
+    (logits, router), vars_out = module.apply(
+        variables, ids, decode=True, positions=positions, mutable=mutable,
+        token_mask=live(), return_router=True)
+    return logits, vars_out, router["counts"]
+
+
 def _prefill_impl(module, params, cache, input_ids, positions,
                   param_transform=None):
     if param_transform is not None:

@@ -14,6 +14,7 @@ Logical axis vocabulary:
   "batch"/"seq" - activation dims (constraints only, never params)
 """
 
+import functools
 from typing import Any, Callable, Optional
 
 import jax
@@ -265,6 +266,25 @@ class LayerNorm(nn.Module):
         return y.astype(orig_dtype)
 
 
+class RMSNorm(nn.Module):
+    """Root-mean-square norm with a learned scale and no bias, the mean
+    of squares in float32: ``x / sqrt(mean(x^2) + eps) * scale``."""
+    epsilon: float = 1e-5
+
+    @nn.compact
+    def __call__(self, x):
+        orig_dtype = x.dtype
+        x = x.astype(jnp.float32)
+        y = x * jax.lax.rsqrt(
+            jnp.mean(jnp.square(x), axis=-1, keepdims=True) + self.epsilon)
+        scale = self.param("scale", nn.with_logical_partitioning(
+            nn.initializers.ones, ("embed",)), (x.shape[-1],), jnp.float32)
+        return (y * scale).astype(orig_dtype)
+
+
+NORMS = {"layernorm": LayerNorm, "rmsnorm": RMSNorm}
+
+
 class SelfAttention(nn.Module):
     """Fused-QKV multi-head attention (reference: DeepSpeedSelfAttention,
     ops/transformer/inference/transformer_inference.py:473, training kernel
@@ -286,6 +306,10 @@ class SelfAttention(nn.Module):
     use_bias: bool = True
     rotary: bool = False
     rotary_dim: Optional[int] = None
+    rotary_base: float = 10000.0
+    qk_norm: bool = False                # RMSNorm over the whole q and k
+                                         # projections, before the heads split
+    norm_epsilon: float = 1e-5           # (qk_norm's)
     attn_backend: Optional[str] = None
     alibi: bool = False
     seq_parallel: Optional[str] = None   # None=auto, "ulysses", "ring", "none"
@@ -304,6 +328,11 @@ class SelfAttention(nn.Module):
             bias_init=nn.with_logical_partitioning(nn.initializers.zeros, ("qkv",)),
             name="qkv")(x)
         q, k, v = jnp.split(qkv, 3, axis=-1)
+        if self.qk_norm:
+            # here and nowhere else: the flash path, the chunked prefill
+            # and the paged decode all take q and k from this point
+            q = RMSNorm(epsilon=self.norm_epsilon, name="q_norm")(q)
+            k = RMSNorm(epsilon=self.norm_epsilon, name="k_norm")(k)
         b, s = x.shape[0], x.shape[1]
         q = q.reshape(b, s, self.n_heads, head_dim)
         k = k.reshape(b, s, self.n_heads, head_dim)
@@ -313,7 +342,8 @@ class SelfAttention(nn.Module):
             from ..ops.transformer.rotary import apply_rotary_pos_emb
             rdim = self.rotary_dim or head_dim
             q, k = apply_rotary_pos_emb(q, k, rotary_dim=rdim,
-                                        positions=positions)
+                                        positions=positions,
+                                        base=self.rotary_base)
 
         causal = self.causal
         decode_out = None
@@ -657,6 +687,9 @@ class Block(nn.Module):
     ln_epsilon: float = 1e-5
     rotary: bool = False
     rotary_dim: Optional[int] = None
+    rotary_base: float = 10000.0
+    norm: str = "layernorm"              # key into NORMS
+    qk_norm: bool = False
     activation: str = "gelu"
     mlp_factory: Optional[Callable[..., nn.Module]] = None
     attn_backend: Optional[str] = None
@@ -670,13 +703,22 @@ class Block(nn.Module):
 
     @nn.compact
     def __call__(self, x, mask=None, bias=None, deterministic=True,
-                 layer_keep_prob=None, decode=False, positions=None):
+                 layer_keep_prob=None, decode=False, positions=None,
+                 mlp_kwargs=None):
+        """``mlp_kwargs`` go to the ``mlp_factory`` module's call and to
+        nothing else: what an expert layer takes beside the hidden state
+        (``moe.layer.DroplessMoE``: the mask of rows that hold a token,
+        the layers' stacked expert weights). Such a module returns
+        ``(out, aux)``, and so does the block."""
         attn_bias = self.use_bias if self.attn_use_bias is None else self.attn_use_bias
         attn = SelfAttention(n_heads=self.n_heads, d_model=self.d_model,
                              causal=self.causal, dropout_rate=self.attn_dropout_rate,
                              dtype=self.dtype, param_dtype=self.param_dtype,
                              use_bias=attn_bias, rotary=self.rotary,
                              rotary_dim=self.rotary_dim,
+                             rotary_base=self.rotary_base,
+                             qk_norm=self.qk_norm,
+                             norm_epsilon=self.ln_epsilon,
                              attn_backend=self.attn_backend,
                              alibi=self.alibi, seq_parallel=self.seq_parallel,
                              sparsity_config=self.sparsity_config,
@@ -687,7 +729,10 @@ class Block(nn.Module):
             param_dtype=self.param_dtype, use_bias=self.use_bias,
             activation=self.activation, dropout_rate=self.dropout_rate, name=name))
         mlp = mlp_cls(name="mlp")
-        ln1 = LayerNorm(epsilon=self.ln_epsilon, name="ln_1")
+        if mlp_kwargs:
+            mlp = functools.partial(mlp, **mlp_kwargs)
+        norm = NORMS[self.norm]
+        ln1 = norm(epsilon=self.ln_epsilon, name="ln_1")
 
         aux = None
         if self.parallel_residual:
@@ -695,7 +740,7 @@ class Block(nn.Module):
             if self.shared_parallel_ln:
                 h2 = h1
             else:
-                h2 = LayerNorm(epsilon=self.ln_epsilon, name="ln_2")(x)
+                h2 = norm(epsilon=self.ln_epsilon, name="ln_2")(x)
             a = attn(h1, mask=mask, bias=bias, deterministic=deterministic,
                      decode=decode, positions=positions)
             m = mlp(h2, deterministic=deterministic)
@@ -703,7 +748,7 @@ class Block(nn.Module):
                 m, aux = m
             y = x + a + m
         elif self.pre_ln:
-            ln2 = LayerNorm(epsilon=self.ln_epsilon, name="ln_2")
+            ln2 = norm(epsilon=self.ln_epsilon, name="ln_2")
             a = attn(ln1(x), mask=mask, bias=bias, deterministic=deterministic,
                      decode=decode, positions=positions)
             x = x + a
@@ -712,7 +757,7 @@ class Block(nn.Module):
                 m, aux = m
             y = x + m
         else:
-            ln2 = LayerNorm(epsilon=self.ln_epsilon, name="ln_2")
+            ln2 = norm(epsilon=self.ln_epsilon, name="ln_2")
             a = attn(x, mask=mask, bias=bias, deterministic=deterministic,
                      decode=decode, positions=positions)
             x = ln1(x + a)

@@ -9,13 +9,15 @@ creating process groups (deepspeed/utils/groups.py).
 from typing import Any, Callable, Optional
 
 import flax.linen as nn
+import jax
+import jax.numpy as jnp
 
 from deepspeed_tpu.models.layers import QDense
-import jax.numpy as jnp
 
 from ..comm.mesh import get_global_mesh
 from ..utils.logging import logger
-from .sharded_moe import MOELayer
+from .sharded_moe import (MOELayer, dropless_experts, mean_gate,
+                          topk_routing)
 
 
 class ExpertMLP(nn.Module):
@@ -29,7 +31,6 @@ class ExpertMLP(nn.Module):
 
     @nn.compact
     def __call__(self, x):
-        import jax
         h = QDense(features=self.d_ff, dtype=self.dtype,
                             param_dtype=self.param_dtype,
                             kernel_init=nn.with_logical_partitioning(
@@ -99,6 +100,71 @@ class MoE(nn.Module):
         except Exception:
             pass
         return self.moe_layer(x, deterministic=deterministic)
+
+
+def expert_stack(module, n_layers, num_experts, d, f, param_dtype):
+    """Every layer's gated experts as params of ``module``, one
+    ``[L, E, ...]`` stack a matrix: ``w_gate``, ``w_up`` ``[L, E, d, f]``,
+    ``w_down`` ``[L, E, f, d]``, no bias, the ``experts`` logical axis on
+    the expert mesh axis as ``MOELayer``'s are (``dropless_experts`` says
+    why the layers' weights are kept together; a lone layer is L = 1)."""
+    init = nn.initializers.variance_scaling(
+        1.0, "fan_in", "normal", in_axis=-2, out_axis=-1, batch_axis=(0, 1))
+
+    def one(name, shape, names):
+        return module.param(
+            name, nn.with_logical_partitioning(
+                init, ("layers", "experts") + names),
+            (n_layers, num_experts) + shape, param_dtype)
+    return (one("w_gate", (d, f), ("embed", "mlp")),
+            one("w_up", (d, f), ("embed", "mlp")),
+            one("w_down", (f, d), ("mlp", "embed")))
+
+
+class DroplessMoE(nn.Module):
+    """A dropless top-k expert layer of gated (SwiGLU) experts, as
+    OLMoE, Mixtral and their kin publish it:
+    ``y = sum_{e in topk(p)} p_e W_down,e (silu(W_gate,e x) * W_up,e x)``,
+    ``p = softmax_float32(W_router x)`` over all experts. No capacity, no
+    bias, no shared expert (``sharded_moe.dropless_experts``).
+
+    The router is this module's, float32 whatever the model's dtype, and
+    its matmul runs at full precision: two gate probabilities that nearly
+    tie decide which expert a token gets. The experts' weights are the
+    model's (``expert_stack``): it keeps every layer's in one stack and
+    hands ``experts=(w_gate, w_up, w_down)`` ``[L, E, ...]`` to each
+    layer with its index ``layer``.
+
+    ``__call__(x [b, s, d], token_mask [b, s] or None, experts, layer)
+    -> (out, aux)``, ``out`` in ``x``'s dtype (the matmuls run in
+    ``dtype``), with ``aux = {"gate_mean": [E], "counts": [E] int32}``:
+    each expert's mean gate probability and the assignments counted, rows
+    masked out in neither — what ``sharded_moe.load_balancing_loss``
+    takes, of one layer or of all a model's layers together."""
+    num_experts: int
+    num_experts_per_tok: int
+    norm_topk_prob: bool = False
+    dtype: Any = jnp.bfloat16
+
+    @nn.compact
+    def __call__(self, x, deterministic=True, token_mask=None, *, experts,
+                 layer):
+        b, s, d = x.shape
+        tokens = x.reshape(b * s, d)
+        live = None if token_mask is None else token_mask.reshape(b * s)
+
+        router = self.param("router", nn.with_logical_partitioning(
+            nn.initializers.lecun_normal(), ("embed", None)),
+            (d, self.num_experts), jnp.float32)
+        logits = jnp.dot(tokens.astype(jnp.float32), router,
+                         precision=jax.lax.Precision.HIGHEST)
+        probs, weights, chosen = topk_routing(
+            logits, self.num_experts_per_tok, self.norm_topk_prob)
+        out, counts = dropless_experts(
+            tokens.astype(self.dtype), weights, chosen, *experts, layer,
+            live=live)
+        aux = {"gate_mean": mean_gate(probs, live), "counts": counts}
+        return out.reshape(b, s, d).astype(x.dtype), aux
 
 
 def split_params_into_different_moe_groups_for_optimizer(param_groups):

@@ -195,7 +195,149 @@ class TopKGate(nn.Module):
                               self.drop_tokens, self.use_rts, rng)
         if self.k == 2:
             return top2gating(logits, factor, self.min_capacity, rng)
-        raise ValueError("only k=1 and k=2 are supported (reference parity)")
+        raise ValueError(
+            f"TopKGate routes k=1 or k=2 under a capacity (got k={self.k}); "
+            "more experts a token take the dropless path: "
+            "moe.layer.DroplessMoE (topk_routing + dropless_experts), which "
+            "has no capacity and drops no token")
+
+
+# -- the dropless path ------------------------------------------------------
+# No capacity, no [T, E, C] one-hot: a token's output depends on its own
+# row and the weights of its k experts, whatever else shares its batch —
+# what a server needs, where a capacity gate would let a neighbour's
+# routing push a token over an expert's limit.
+
+def topk_routing(logits, k: int, renormalize: bool = False):
+    """Softmax over ALL experts in float32, then the k largest.
+
+    logits: [T, E]. Returns (probs [T, E] f32, weights [T, k] f32,
+    experts [T, k] int32). ``renormalize`` divides the k weights by their
+    sum (a ``config.json``'s ``norm_topk_prob``); off, they are used as
+    the softmax gave them."""
+    probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
+    weights, experts = jax.lax.top_k(probs, k)
+    if renormalize:
+        weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
+    return probs, weights, experts.astype(jnp.int32)
+
+
+def mean_gate(probs, live=None):
+    """The mean gate probability of each expert over the tokens (the
+    live ones): ``[T, E] -> [E]``."""
+    if live is None:
+        return jnp.mean(probs, axis=0)
+    n = jnp.maximum(jnp.sum(live.astype(jnp.float32)), 1.0)
+    return jnp.sum(jnp.where(live[:, None], probs, 0.0), axis=0) / n
+
+
+def load_balancing_loss(gate_mean, counts, k: int):
+    """The published auxiliary loss (Switch; HF ``load_balancing_loss_func``,
+    which OLMoE's ``router_aux_loss_coef`` multiplies): over all tokens
+    of all layers together, the mean gate probability of an expert times
+    the share of tokens that chose it, summed over experts, times E.
+
+    ``gate_mean`` ``[E]`` (``mean_gate``) and ``counts`` ``[E]`` (the
+    assignments ``dropless_experts`` counted) of one layer, or ``[L, E]``
+    of a model's layers, each layer having routed the same tokens."""
+    n_experts = gate_mean.shape[-1]
+    gate_mean = gate_mean.reshape(-1, n_experts).mean(0)
+    counts = counts.reshape(-1, n_experts).sum(0).astype(jnp.float32)
+    share = k * counts / jnp.maximum(jnp.sum(counts), 1.0)
+    return jnp.sum(gate_mean * share) * n_experts
+
+
+# Rows a grouped-matmul call takes. XLA's kernel for ``ragged_dot`` makes
+# its row tile as tall as the call has rows (up to 512) and multiplies a
+# whole tile for every group that has a row in it: at 256 rows over 64
+# experts that is 63 tiles of 256 rows for 256 rows of work, and the MXU,
+# not the weights' stream, sets the time. Shorter calls waste less and
+# launch more: on a v5e at OLMoE's widths one matmul of 256 rows took
+# 0.64 ms whole, 0.49 in calls of 128 rows, 0.52 of 64, 0.60 of 32 (the
+# weights' stream alone: 0.32; my chip run, PR 28: PERF.md section 6).
+# No more than MAX_CALLS calls a matmul, whatever the rows: a program's
+# size, and the time to trace it, grow with the calls (the page pool's
+# shape-only init traces the model over every token the pool holds).
+ROW_TILE = 128
+MAX_CALLS = 8
+
+
+def grouped_matmul(rows, w, groups, **kw):
+    """``rows [m, k]``, sorted by group, times ``w [G, k, n]``: row i by
+    its group's matrix; ``groups [G]`` are the groups' sizes, rows past
+    their sum are in none. ``jax.lax.ragged_dot`` over ``ROW_TILE`` rows
+    at a time (more where that would take over ``MAX_CALLS`` calls),
+    each call with the sizes of the groups' parts that lie in its
+    rows."""
+    m = rows.shape[0]
+    tile = max(ROW_TILE, -(-m // MAX_CALLS))
+    if m <= tile:
+        return jax.lax.ragged_dot(rows, w, groups, **kw)
+    ends = jnp.cumsum(groups)
+    starts = ends - groups
+    parts = []
+    for lo in range(0, m, tile):
+        hi = min(lo + tile, m)
+        inside = jnp.clip(jnp.minimum(ends, hi) - jnp.maximum(starts, lo),
+                          0, None)
+        parts.append(jax.lax.ragged_dot(rows[lo:hi], w, inside, **kw))
+    return jnp.concatenate(parts)
+
+
+def dropless_experts(tokens, weights, experts, w_gate, w_up, w_down, layer,
+                     live=None):
+    """Every token through each of its k experts, no token dropped:
+    sort the T*k assignments by expert, one grouped matmul for the gate
+    and one for the up projection, SiLU(gate) * up, one for the down
+    projection, and the weighted sum back in token order.
+
+    tokens [T, d]; weights, experts [T, k]; ``live`` [T] bool or None.
+    A row that is not live (an idle slot, a chunk's padding) joins no
+    group and adds nothing to a count. Returns (out [T, d] float32 — the
+    down projection and the weighted sum accumulate in it —, counts [E]
+    int32).
+
+    ``w_gate``, ``w_up`` ``[L, E, d, f]`` and ``w_down`` ``[L, E, f, d]``
+    hold the experts of every layer of the model (L = 1 for a lone
+    layer) and ``layer`` is this call's index: the stacks go to the
+    grouped matmul whole, as ``L*E`` groups of which only this layer's
+    have rows. A layer's slice of them — what a scan over the layers
+    would hand its body — would be a copy: the matmul is a custom call,
+    whose operand is a buffer: 805 MB written and read again per layer at
+    OLMoE's widths, as much as the matmuls themselves move.
+
+    The grouped matmul (``grouped_matmul``) is ``jax.lax.ragged_dot``:
+    on a TPU XLA lowers it to its own Mosaic kernel (``%ragged-dot`` in
+    a trace), which walks the groups that have rows and reads no other's
+    weights."""
+    n_tokens, k = experts.shape
+    n_experts = w_gate.shape[-3]
+    flat = experts.reshape(-1)
+    if live is not None:
+        # expert E does not exist: it sorts last and is in no group
+        flat = jnp.where(jnp.repeat(live, k), flat, n_experts)
+    order = jnp.argsort(flat)                       # stable: by expert
+    counts = jnp.bincount(flat, length=n_experts + 1)[:n_experts].astype(
+        jnp.int32)
+    n_layers = w_gate.shape[0]
+    groups = jax.lax.dynamic_update_slice(
+        jnp.zeros((n_layers * n_experts,), jnp.int32), counts,
+        (layer * n_experts,))
+    w_gate, w_up, w_down = (w.reshape((-1,) + w.shape[2:])
+                            for w in (w_gate, w_up, w_down))
+    rows = jnp.take(tokens, order // k, axis=0)     # [T*k, d]
+    g = grouped_matmul(rows, w_gate.astype(rows.dtype), groups)
+    u = grouped_matmul(rows, w_up.astype(rows.dtype), groups)
+    h = jax.nn.silu(g) * u
+    y = grouped_matmul(h, w_down.astype(rows.dtype), groups,
+                       preferred_element_type=jnp.float32)
+    # rows past the last group belong to no expert
+    in_group = jnp.arange(n_tokens * k) < jnp.sum(counts)
+    y = jnp.where(in_group[:, None], y, 0.0)
+    # back in token order: assignment i sits at rank[i] of the sort
+    rank = jnp.argsort(order)
+    y = jnp.take(y, rank, axis=0).reshape(n_tokens, k, -1)
+    return jnp.sum(y * weights[:, :, None], axis=1), counts
 
 
 class MOELayer(nn.Module):

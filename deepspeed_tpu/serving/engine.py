@@ -62,7 +62,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from ..inference.generation import (init_cache, _prefill_impl, _sample_impl,
+from ..inference.generation import (apply_decode, init_cache, _sample_impl,
                                     _sampling_mode)
 from ..inference.cache import (cache_max_len, make_row_cache, set_cache_index,
                                write_cache_row)
@@ -84,6 +84,12 @@ from .paging.manager import _chunk_prefill_jit, _paged_decode_jit
 from .speculation import NgramProposer, _spec_verify_jit
 
 
+def _counts_read(counts):
+    """The arrays a harvest reads back beside a dispatch's tokens: an
+    expert layer's router counts, or nothing for a module without one."""
+    return [] if counts is None else [counts]
+
+
 def _admit_impl(module, params, cache, state, prompt, prompt_len, slot,
                 max_new, rng, eos_id, t, k, p, param_transform,
                 greedy, has_k, has_p):
@@ -94,8 +100,12 @@ def _admit_impl(module, params, cache, state, prompt, prompt_len, slot,
     length mask never reads and later decode tokens overwrite in order.
     """
     row = make_row_cache(cache)
-    logits, row = _prefill_impl(module, params, row, prompt,
-                                jnp.arange(prompt.shape[1]), param_transform)
+    positions = jnp.arange(prompt.shape[1])
+    p_ = param_transform(params) if param_transform is not None else params
+    logits, vars_out, counts = apply_decode(
+        module, {"params": p_, "cache": row}, prompt, positions,
+        lambda: (positions < prompt_len)[None], ["cache"])
+    row = vars_out["cache"]
     last = jax.lax.dynamic_slice_in_dim(logits, prompt_len - 1, 1,
                                         axis=1)[:, 0]            # [1, vocab]
     tok = _sample_impl(last, rng, t, k, p, greedy, has_k, has_p)[0]
@@ -111,7 +121,7 @@ def _admit_impl(module, params, cache, state, prompt, prompt_len, slot,
         "active": state["active"].at[slot].set(~done),
         "remaining": state["remaining"].at[slot].set(remaining),
     }
-    return cache, state, tok, done
+    return cache, state, tok, done, counts
 
 
 _admit_jit = track_program(
@@ -137,9 +147,9 @@ def _decode_iter_impl(module, params, cache, state, rng, it, eos_id,
     idx_w = jnp.minimum(lengths, s_max - 1)
     cache = set_cache_index(cache, idx_w)
     p_ = param_transform(params) if param_transform is not None else params
-    logits, vars_out = module.apply(
-        {"params": p_, "cache": cache}, state["last_token"][:, None],
-        decode=True, positions=idx_w[:, None], mutable=["cache"])
+    logits, vars_out, counts = apply_decode(
+        module, {"params": p_, "cache": cache}, state["last_token"][:, None],
+        idx_w[:, None], lambda: active[:, None], ["cache"])
     nxt = _sample_impl(logits[:, -1, :], jax.random.fold_in(rng, it),
                        t, k, p, greedy, has_k, has_p)
 
@@ -152,7 +162,7 @@ def _decode_iter_impl(module, params, cache, state, rng, it, eos_id,
         "remaining": remaining,
     }
     out_tok = jnp.where(active, nxt, -1)
-    return vars_out["cache"], new_state, out_tok, done
+    return vars_out["cache"], new_state, out_tok, done, counts
 
 
 _decode_iter_jit = track_program(
@@ -247,6 +257,10 @@ class ServingEngine:
         self._init_device_state()
         self._rng = rng if rng is not None else jax.random.PRNGKey(
             self.config.seed)
+        # every decode dispatch folds its iteration into this one key
+        # inside the program: made once, not by two small dispatches an
+        # iteration that the device then waits for
+        self._decode_rng = jax.random.fold_in(self._rng, 2**31)
         self._mode = _sampling_mode(self.config.temperature,
                                     self.config.top_k, self.config.top_p)
         # -1 when eos is disabled: sampled tokens are always >= 0, so the
@@ -263,6 +277,8 @@ class ServingEngine:
         self._free = deque(range(n))
         self._pending = deque()           # in-flight readbacks, FIFO
         self._readback_ns = 0             # this advance()'s blocked reads
+        self._chunk_counts = {}           # slot -> router counts of its
+                                          # prefill chunks so far (device)
         self._iteration = 0
         self._seq = 0
         # QoS plane (serving/qos.py): priority preemption, SLO shedding,
@@ -928,7 +944,7 @@ class ServingEngine:
                                              "trace_id": req.trace_id,
                                              "prompt_len": n}), \
                         _goodput("compute"):
-                    self._cache, self._state, tok, done = _admit_jit(
+                    self._cache, self._state, tok, done, counts = _admit_jit(
                         self.module, self.params, self._cache, self._state,
                         jnp.asarray(padded), jnp.int32(n), jnp.int32(slot),
                         jnp.int32(max_new), rng, self._eos, t, k, p,
@@ -943,7 +959,8 @@ class ServingEngine:
             self.metrics.on_admit(req)
             if resumed:
                 self.metrics.on_resume(req)
-            self._pending.append(("admit", slot, req, tok, done))
+            self._pending.append(("admit", slot, req, tok, done,
+                                  _counts_read(counts)))
 
     # -- paged admission + chunked prefill ---------------------------------
     def _admit_ready_paged(self):
@@ -1037,6 +1054,7 @@ class ServingEngine:
         greedy, has_k, has_p, t, k, p = self._mode
         mgr = self._paged
         if req.first_chunk_at_ns is None:
+            self._chunk_counts.pop(slot, None)  # a preempted prefill's
             req.first_chunk_at_ns = time.perf_counter_ns()
             self.metrics.on_prefill_wait(
                 req.first_chunk_at_ns - req.admitted_at_ns)
@@ -1047,7 +1065,7 @@ class ServingEngine:
                         "start": start, "tokens": real,
                         "last": bool(is_last)}), \
                     _goodput("compute"):
-                mgr.pool, self._state, tok, done = _chunk_prefill_jit(
+                mgr.pool, self._state, tok, done, counts = _chunk_prefill_jit(
                     self.module, self.params, mgr.pool, self._state,
                     mgr.page_table[slot], jnp.asarray(padded),
                     jnp.int32(start), jnp.int32(p_len), jnp.int32(slot),
@@ -1061,12 +1079,17 @@ class ServingEngine:
             self._shed_on_oom(req, "chunk_prefill", e)
             return False
         self.metrics.on_prefill_chunk(real)
+        if counts is not None:
+            # an expert layer's routing of this chunk: read back with the
+            # first token, by when every earlier chunk has finished
+            self._chunk_counts.setdefault(slot, []).append(counts)
         if is_last:
             # pages below the prompt's full-page boundary are immutable
             # from here (decode appends strictly past them): publish them
             # for copy-free reuse by later identical prefixes
             mgr.publish(slot, prompt)
-            self._pending.append(("admit", slot, req, tok, done))
+            self._pending.append(("admit", slot, req, tok, done,
+                                  self._chunk_counts.pop(slot, [])))
         return True
 
     def _decoding_slots(self, busy: int) -> int:
@@ -1089,7 +1112,7 @@ class ServingEngine:
         greedy, has_k, has_p, t, k, p = self._mode
         snapshot = list(self._slot_req)
         busy = sum(r is not None for r in snapshot)
-        rng = jax.random.fold_in(self._rng, 2**31)
+        rng = self._decode_rng
         # active request count on the span: trace captures show how full
         # each decode dispatch ran (the SLO-reconstruction groundwork)
         with _span("serving/decode_iter", {"active_requests": busy,
@@ -1097,19 +1120,20 @@ class ServingEngine:
                 _goodput("compute"):
             if self._paged is not None:
                 mgr = self._paged
-                mgr.pool, self._state, toks, done = _paged_decode_jit(
+                mgr.pool, self._state, toks, done, counts = _paged_decode_jit(
                     self.module, self.params, mgr.pool, mgr.page_table,
                     self._state, rng, jnp.int32(self._iteration),
                     self._eos, t, k, p, self._param_transform, greedy,
                     has_k, has_p, mgr.use_kernel, mgr.dequant_dtype)
             else:
-                self._cache, self._state, toks, done = _decode_iter_jit(
+                self._cache, self._state, toks, done, counts = _decode_iter_jit(
                     self.module, self.params, self._cache, self._state,
                     rng, jnp.int32(self._iteration), self._eos, t, k, p,
                     self._param_transform, greedy, has_k, has_p)
         self.metrics.on_decode_dispatch(self._decoding_slots(busy),
                                         self.config.num_slots)
-        self._pending.append(("decode", snapshot, toks, done))
+        self._pending.append(("decode", snapshot, toks, done,
+                              _counts_read(counts)))
         self._iteration += 1
         return True
 
@@ -1173,7 +1197,7 @@ class ServingEngine:
         greedy, has_k, has_p, t, k, p = self._mode
         snapshot = list(self._slot_req)
         busy = sum(r is not None for r in snapshot)
-        rng = jax.random.fold_in(self._rng, 2**31)
+        rng = self._decode_rng
         with _span("serving/spec_verify",
                    {"active_requests": busy, "iteration": self._iteration,
                     "proposed_tokens": int(counts.sum())}), \
@@ -1226,10 +1250,11 @@ class ServingEngine:
         with _span("serving/harvest", harvest_args), \
                 _goodput("compute"):
             if entry[0] == "admit":
-                _, slot, req, tok, done = entry
+                _, slot, req, tok, done, counts = entry
                 if req.done:     # cancelled between dispatch and readback
                     return
-                tok, done = self._read_back(tok, done)
+                tok, done, *counts = self._read_back(tok, done, *counts)
+                self._fold_moe_counts(counts)
                 req._emit(int(tok), self._iteration)
                 self.metrics.on_token()
                 if bool(done):
@@ -1271,8 +1296,9 @@ class ServingEngine:
                     if done[slot]:
                         self._finish(slot, req)
                 return
-            _, snapshot, toks, done = entry
-            toks, done = self._read_back(toks, done)
+            _, snapshot, toks, done, counts = entry
+            toks, done, *counts = self._read_back(toks, done, *counts)
+            self._fold_moe_counts(counts)
             for slot, req in enumerate(snapshot):
                 if req is None or req.done:  # empty, or cancelled in flight
                     continue
@@ -1281,6 +1307,14 @@ class ServingEngine:
                     self.metrics.on_token()
                 if done[slot]:
                     self._finish(slot, req)
+
+    def _fold_moe_counts(self, counts):
+        """An expert layer's routing of the dispatches just read back
+        (``[L, E]`` each), folded into the process registry."""
+        if counts:
+            with _span("serving/moe_counts", {"dispatches": len(counts)}):
+                for c in counts:
+                    self.metrics.on_moe_counts(c)
 
     def _finish(self, slot: int, req: Request):
         self._record_residency(req)

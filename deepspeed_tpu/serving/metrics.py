@@ -219,6 +219,21 @@ class ServingMetrics:
             self.registry.counter("serving/decode_slots_offered").inc(
                 num_slots)
 
+    def on_moe_counts(self, counts):
+        """One dispatch's routing, ``[L, E]``: the token-expert pairs
+        each layer's router sent to each expert (rows that held no
+        request are in none). Per layer call: the pairs, the experts
+        that got at least one row — whose weights the grouped matmul
+        had to read — and the largest group; the mean group is
+        ``moe/assignments / (moe/expert_calls * E)``."""
+        if self.registry is not None:
+            reg = self.registry
+            reg.counter("moe/assignments").inc(int(counts.sum()))
+            reg.counter("moe/expert_calls").inc(int(counts.shape[0]))
+            reg.counter("moe/experts_touched").inc(int((counts > 0).sum()))
+            reg.counter("moe/experts_offered").inc(int(counts.size))
+            reg.counter("moe/load_max").inc(int(counts.max(axis=1).sum()))
+
     # always-on host-loop accounting (process registry, so a reader that
     # runs after the engine is gone still finds it): host clock
     # arithmetic on stamps the engine took anyway, never a device touch

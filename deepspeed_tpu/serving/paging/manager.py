@@ -46,7 +46,7 @@ from ...inference.cache import (cache_page_len, export_pages,
                                 make_paged_view, pool_is_quantized,
                                 quantize_page_pool, scatter_chunk_pages,
                                 scatter_token_pages, set_cache_index)
-from ...inference.generation import _sample_impl
+from ...inference.generation import _sample_impl, apply_decode
 from ...observability.programs import track_program
 from ...observability.trace import span as _span
 from ...utils.logging import log_dist
@@ -115,10 +115,9 @@ def _paged_decode_iter_impl(module, params, pool, page_table, state, rng, it,
     p_ = param_transform(params) if param_transform is not None else params
     if use_kernel:
         view = make_paged_view(pool, page_table, idx_w)
-        logits, vars_out = module.apply(
-            {"params": p_, **view}, state["last_token"][:, None],
-            decode=True, positions=idx_w[:, None],
-            mutable=["cache", "kv_token"])
+        logits, vars_out, counts = apply_decode(
+            module, {"params": p_, **view}, state["last_token"][:, None],
+            idx_w[:, None], lambda: active[:, None], ["cache", "kv_token"])
         tok = vars_out.get("kv_token")
         if tok is None or len(jax.tree.leaves(tok)) == 0:
             raise ValueError(
@@ -129,10 +128,10 @@ def _paged_decode_iter_impl(module, params, pool, page_table, state, rng, it,
     else:
         cache = gather_pages(pool, page_table, dequant_dtype=dequant_dtype)
         cache = set_cache_index(cache, idx_w)
-        logits, vars_out = module.apply(
-            {"params": p_, "cache": cache}, state["last_token"][:, None],
-            decode=True, positions=idx_w[:, None],
-            mutable=["cache", "kv_token"])
+        logits, vars_out, counts = apply_decode(
+            module, {"params": p_, "cache": cache},
+            state["last_token"][:, None], idx_w[:, None],
+            lambda: active[:, None], ["cache", "kv_token"])
         tok = _token_tree(vars_out, vars_out["cache"], idx_w)
     nxt = _sample_impl(logits[:, -1, :], jax.random.fold_in(rng, it),
                        t, k, p, greedy, has_k, has_p)
@@ -151,7 +150,7 @@ def _paged_decode_iter_impl(module, params, pool, page_table, state, rng, it,
         "remaining": remaining,
     }
     out_tok = jnp.where(active, nxt, -1)
-    return pool, new_state, out_tok, done
+    return pool, new_state, out_tok, done, counts
 
 
 _paged_decode_jit = track_program(
@@ -181,9 +180,11 @@ def _chunk_prefill_impl(module, params, pool, state, ptab_row, chunk_ids,
     row = set_cache_index(row, chunk_start)
     positions = chunk_start + jnp.arange(chunk_ids.shape[1])
     p_ = param_transform(params) if param_transform is not None else params
-    logits, vars_out = module.apply(
-        {"params": p_, "cache": row}, chunk_ids, decode=True,
-        positions=positions, mutable=["cache", "kv_token"])
+    # the chunk's right padding is no token: an expert layer routes it
+    # nowhere
+    logits, vars_out, counts = apply_decode(
+        module, {"params": p_, "cache": row}, chunk_ids, positions,
+        lambda: (positions < end_pos)[None], ["cache", "kv_token"])
 
     chunk = chunk_ids.shape[1]
     page_len = cache_page_len(pool)
@@ -215,7 +216,7 @@ def _chunk_prefill_impl(module, params, pool, state, ptab_row, chunk_ids,
         "remaining": state["remaining"].at[slot].set(
             sel(remaining, state["remaining"][slot])),
     }
-    return pool, state, tok, done
+    return pool, state, tok, done, counts
 
 
 _chunk_prefill_jit = track_program(

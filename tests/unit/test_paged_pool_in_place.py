@@ -19,6 +19,7 @@ import jax
 import jax.numpy as jnp
 
 from deepspeed_tpu.models.gpt import GPT, GPTConfig
+from deepspeed_tpu.models.olmoe import OLMoE, OLMoEConfig
 from deepspeed_tpu.inference.cache import (init_page_pool,
                                            quantize_page_pool)
 from deepspeed_tpu.serving.paging.manager import _paged_decode_iter_impl
@@ -27,6 +28,11 @@ from deepspeed_tpu.serving.paging.manager import _paged_decode_iter_impl
 # its depth: two layers compile in seconds and hold every pool operation
 WIDTH = dict(vocab_size=50257, max_seq_len=2048, d_model=2048, n_heads=16)
 LAYERS, SLOTS, PAGES, PAGE_LEN, MAX_PAGES = 2, 32, 321, 128, 16
+# the OLMoE cell (configs/olmoe-1b-7b-8l-serve.json) at its published
+# widths, two layers deep: 64 experts of 1024, 8 a token
+OLMOE_WIDTH = dict(vocab_size=50304, hidden_size=2048, intermediate_size=1024,
+                   num_attention_heads=16, num_experts=64,
+                   num_experts_per_tok=8, max_position_embeddings=4096)
 
 # instructions that may carry a pool-shaped value without moving it
 IN_PLACE = {"parameter", "get-tuple-element", "tuple", "bitcast", "while",
@@ -46,6 +52,10 @@ def one_chip():
     return SingleDeviceSharding(topo.devices[0])
 
 
+def hlo_has(compiled, name):
+    return name in compiled.as_text()
+
+
 def _fusion_roots(hlo):
     """{fused computation: opcode of its ROOT} of an optimized module."""
     roots, name = {}, None
@@ -59,19 +69,26 @@ def _fusion_roots(hlo):
     return roots
 
 
-@pytest.mark.parametrize("scan_layers,kv_int8", [
-    (True, False), (False, False), (True, True)],
-    ids=["scanned-bf16", "unscanned-bf16", "scanned-int8"])
+@pytest.mark.parametrize("scan_layers,kv_int8,experts", [
+    (True, False, False), (False, False, False), (True, True, False),
+    (True, False, True)],
+    ids=["scanned-bf16", "unscanned-bf16", "scanned-int8", "olmoe-bf16"])
 def test_decode_program_leaves_the_pool_where_it_is(one_chip, monkeypatch,
-                                                    scan_layers, kv_int8):
+                                                    scan_layers, kv_int8,
+                                                    experts):
     # the kernel must lower through Mosaic as on the chip: this process'
     # platform is the CPU, where it would be interpreted
     monkeypatch.setattr(
         importlib.import_module("deepspeed_tpu.ops.pallas.paged_attention"),
         "_interpret", lambda: False)
-    model = GPT(GPTConfig(n_layers=LAYERS, scan_layers=scan_layers,
-                          dtype=jnp.bfloat16, param_dtype=jnp.bfloat16,
-                          **WIDTH))
+    if experts:
+        model = OLMoE(OLMoEConfig(num_hidden_layers=LAYERS,
+                                  dtype=jnp.bfloat16,
+                                  param_dtype=jnp.bfloat16, **OLMOE_WIDTH))
+    else:
+        model = GPT(GPTConfig(n_layers=LAYERS, scan_layers=scan_layers,
+                              dtype=jnp.bfloat16, param_dtype=jnp.bfloat16,
+                              **WIDTH))
     import flax.core.meta as flax_meta
     params = jax.eval_shape(
         lambda r: flax_meta.unbox(model.init(
@@ -112,6 +129,12 @@ def test_decode_program_leaves_the_pool_where_it_is(one_chip, monkeypatch,
     assert mem.temp_size_in_bytes < layer_k_bytes, (
         f"{mem.temp_size_in_bytes} bytes of scratch: some of the pool is "
         "copied")
+    if experts:
+        # the dropless dispatch sorts 32 x 8 rows: its scratch is rows of
+        # activations, nowhere near a capacity gate's [T, E, C] one-hot,
+        # one expert's weights (12 MB) or a copy of a layer's 805 MB
+        assert mem.temp_size_in_bytes < 8 * 2 ** 20, mem.temp_size_in_bytes
+        assert hlo_has(compiled, "ragged-dot")
 
     hlo = compiled.as_text()
     assert "tpu_custom_call" in hlo            # the Mosaic kernel is there

@@ -597,8 +597,9 @@ class _PagedScene:
                        static_argnums=(0, 11, 12, 13, 14, 15, 16))
         pool, state, toks = self.pool, self.state(), []
         for it in range(steps):
-            pool, state, tok, _ = step(*self.args(use_kernel, it, pool,
-                                                  state))
+            # (pool, state, tokens, done, an expert layer's counts)
+            pool, state, tok, _, _ = step(*self.args(use_kernel, it, pool,
+                                                     state))
             toks.append(np.asarray(tok))
         return pool, state, np.stack(toks)
 
