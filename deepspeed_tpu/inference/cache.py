@@ -444,9 +444,12 @@ def make_paged_view(pool, page_table, lengths):
     reach the paged-attention kernel whole and loop-invariant, and the
     program never slices or restacks them. ``cache`` holds the small
     per-step state only: the ``page_table`` (``[slots, max_pages]``),
-    per-row ``lengths`` as ``cache_index``, and for scan-stacked units
-    the ``layer`` index (``arange(L)``) — each with a leading ``[L]``
-    there, so nn.scan hands every layer its own copy. SelfAttention
+    per-row ``lengths`` as ``cache_index`` (how many pooled tokens the
+    kernel walks for the row and nothing else — positions travel beside
+    it — so the decode program hands 0 for a row that does not decode),
+    and for scan-stacked units the ``layer`` index (``arange(L)``) — each
+    with a leading ``[L]`` there, so nn.scan hands every layer its own
+    copy. SelfAttention
     detects the ``page_table`` variable structurally and runs the
     kernel straight over the pool — no contiguous view is gathered."""
     page_table = jnp.asarray(page_table, jnp.int32)

@@ -1299,6 +1299,8 @@ class ServingEngine:
             _, snapshot, toks, done, counts = entry
             toks, done, *counts = self._read_back(toks, done, *counts)
             self._fold_moe_counts(counts)
+            if self._paged is not None and self._paged.use_kernel:
+                self.metrics.on_decode_harvest(np.count_nonzero(toks >= 0))
             for slot, req in enumerate(snapshot):
                 if req is None or req.done:  # empty, or cancelled in flight
                     continue

@@ -219,6 +219,16 @@ class ServingMetrics:
             self.registry.counter("serving/decode_slots_offered").inc(
                 num_slots)
 
+    def on_decode_harvest(self, rows_walked: int):
+        """One kernel-path paged decode dispatch, read back: the rows
+        whose token the program kept (not -1) are the rows it was active
+        for, and only those were handed a non-zero length for the paged
+        kernel to walk. Against ``serving/decode_slots_offered``: the
+        share of the batch the kernel read pages for."""
+        if self.registry is not None:
+            self.registry.counter("serving/paged_rows_walked").inc(
+                rows_walked)
+
     def on_moe_counts(self, counts):
         """One dispatch's routing, ``[L, E]``: the token-expert pairs
         each layer's router sent to each expert (rows that held no

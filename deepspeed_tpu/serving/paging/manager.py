@@ -114,7 +114,12 @@ def _paged_decode_iter_impl(module, params, pool, page_table, state, rng, it,
     idx_w = jnp.minimum(lengths, s_max - 1)
     p_ = param_transform(params) if param_transform is not None else params
     if use_kernel:
-        view = make_paged_view(pool, page_table, idx_w)
+        # the kernel walks every page of a row's length, so a row that
+        # does not decode (released, or waiting for its prefill chunks:
+        # it keeps its old length) is handed 0 and attends its own token
+        # only; its output was thrown away below already. Positions, the
+        # append and the new lengths keep idx_w.
+        view = make_paged_view(pool, page_table, jnp.where(active, idx_w, 0))
         logits, vars_out, counts = apply_decode(
             module, {"params": p_, **view}, state["last_token"][:, None],
             idx_w[:, None], lambda: active[:, None], ["cache", "kv_token"])

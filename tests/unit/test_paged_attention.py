@@ -233,6 +233,64 @@ class TestStackedPool:
             "model_shards"] == 2
 
 
+class TestZeroRowsBetweenLongRows:
+    """What the serving decode program hands the kernel since it masks
+    the rows that do not decode: length 0 for a released slot or one
+    still waiting for its prefill chunks, between rows of several pages.
+    Such a row's table still names the pages it held — poisoned here, so
+    one column read from them would show — and its output is attention
+    over its own token alone."""
+    L = 2
+    LENS = [3 * PAGE + 5, 0, 4 * PAGE - 1, 0]
+    TABLE = [[1, 2, 3, 4], [7, 8, 7, 8], [5, 6, 1, 2], [8, 7, 0, 0]]
+    POISONED = (7, 8)          # the zero rows' pages and nobody else's
+
+    def _case(self, dtype, stacked, seed=21):
+        r = np.random.RandomState(seed)
+        kp, vp = (jnp.asarray(r.randn(self.L, 9, 4, 16, PAGE)
+                              .astype(np.float32)) for _ in range(2))
+        scales = {}
+        bad = jnp.asarray(self.POISONED)
+        if dtype == "int8":
+            # an int8 page cannot hold a NaN: its scale plane does
+            kq, vq, ks, vs = jax.vmap(_quantize_pool)(kp, vp)
+            kp, vp = kq.at[:, bad].set(127), vq.at[:, bad].set(127)
+            scales = {"k_scale": ks.at[:, bad].set(jnp.nan),
+                      "v_scale": vs.at[:, bad].set(jnp.nan)}
+        else:
+            kp = kp.astype(jnp.bfloat16).at[:, bad].set(jnp.nan)
+            vp = vp.astype(jnp.bfloat16).at[:, bad].set(jnp.nan)
+        layer = {"layer": jnp.int32(self.L - 1)}
+        if not stacked:
+            kp, vp, layer = kp[-1], vp[-1], {}
+            scales = {k: v[-1] for k, v in scales.items()}
+        q, kn, vn = _operands(seed=seed + 1, b=4)
+        return (q, kp, vp, jnp.asarray(self.TABLE, jnp.int32),
+                jnp.asarray(self.LENS, jnp.int32), kn, vn), {**scales,
+                                                             **layer}
+
+    @pytest.mark.parametrize("alibi", [False, True], ids=["plain", "alibi"])
+    @pytest.mark.parametrize("stacked", [False, True], ids=["4d", "stacked"])
+    @pytest.mark.parametrize("dtype", ["bf16", "int8"])
+    def test_zero_rows_attend_their_own_token_only(self, dtype, stacked,
+                                                   alibi):
+        args, kw = self._case(dtype, stacked)
+        if alibi:
+            kw["alibi_slopes"] = np.linspace(0.1, 0.5, 4).astype(np.float32)
+        got, dense = (np.asarray(paged_attention(*args, impl=impl, **kw))
+                      for impl in ("kernel", "dense"))
+        assert np.isfinite(got).all() and np.isfinite(dense).all()
+        np.testing.assert_allclose(got, dense,
+                                   atol=2e-2 if dtype == "bf16" else 1e-4,
+                                   rtol=2e-2)
+        # softmax over one column is 1: the row's output is its own V
+        own = np.asarray(args[6])[..., 0]                  # [B, H, d]
+        for row in (1, 3):
+            np.testing.assert_allclose(got[row, 0], own[row], atol=1e-6)
+        # and the long rows beside them are not the same thing
+        assert np.abs(got[0, 0] - own[0]).max() > 1e-2
+
+
 class TestTuningDispatch:
     def test_runtime_table_entry_consumed(self):
         """The shape-keyed tuning cache resolves the kernel's blocks at

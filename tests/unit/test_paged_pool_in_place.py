@@ -69,6 +69,58 @@ def _fusion_roots(hlo):
     return roots
 
 
+# equations a value passes through unchanged but for its shape or type
+PASS_THROUGH = {"broadcast_in_dim", "convert_element_type", "reshape",
+                "squeeze", "copy"}
+
+
+def _sub_jaxprs(eqn):
+    for v in eqn.params.values():
+        for sub in (v if isinstance(v, (tuple, list)) else (v,)):
+            inner = getattr(sub, "jaxpr", sub)
+            if hasattr(inner, "eqns"):
+                yield inner
+
+
+def _mosaic_calls(frames):
+    """Every ``pallas_call`` under ``frames[-1]`` with the frames it
+    sits in, outermost first: ``(jaxpr, the equation of the jaxpr before
+    it that holds it)``, the program's own being ``(program, None)``."""
+    for eqn in frames[-1][0].eqns:
+        if eqn.primitive.name == "pallas_call":
+            yield eqn, frames
+        for sub in _sub_jaxprs(eqn):
+            yield from _mosaic_calls(frames + ((sub, eqn),))
+
+
+def _origin(var, frames):
+    """Where ``var`` of the jaxpr ``frames[-1]`` comes from: ``("input",
+    i)`` (the i-th argument of the program), ``("literal", value)``, or
+    ``(equation, frames)`` for the first equation behind it that is more
+    than a change of shape or type. A call's operands line up with its
+    jaxpr's (scan: constants, carry, xs; jit and closed calls: one for
+    one)."""
+    while True:
+        if hasattr(var, "val"):
+            return "literal", var.val
+        jaxpr, call = frames[-1]
+        made = next((e for e in jaxpr.eqns if var in e.outvars), None)
+        if made is None:                          # an argument of this jaxpr
+            at = jaxpr.invars.index(var)
+            if call is None:
+                return "input", at
+            assert len(call.invars) == len(jaxpr.invars), call.primitive
+            var, frames = call.invars[at], frames[:-1]
+        elif made.primitive.name in PASS_THROUGH:
+            var = made.invars[0]
+        elif made.primitive.name in ("jit", "pjit", "closed_call"):
+            sub = next(_sub_jaxprs(made))
+            var = sub.outvars[made.outvars.index(var)]
+            frames = frames + ((sub, made),)
+        else:
+            return made, frames
+
+
 @pytest.mark.parametrize("scan_layers,kv_int8,experts", [
     (True, False, False), (False, False, False), (True, True, False),
     (True, False, True)],
@@ -110,14 +162,38 @@ def test_decode_program_leaves_the_pool_where_it_is(one_chip, monkeypatch,
     state = {"lengths": slot(jnp.int32), "last_token": slot(jnp.int32),
              "active": slot(jnp.bool_), "remaining": slot(jnp.int32)}
     pool_shapes = on_chip(pool)
-    compiled = jax.jit(
-        _paged_decode_iter_impl, static_argnums=(0, 11, 12, 13, 14, 15, 16),
-        donate_argnums=(2, 4)).lower(
-            model, on_chip(params), pool_shapes,
+    # params, pool, page table, state, rng, iteration; then the statics
+    args = (on_chip(params), pool_shapes,
             on_chip(jax.ShapeDtypeStruct((SLOTS, MAX_PAGES), jnp.int32)),
             on_chip(state), on_chip(lambda: jax.random.PRNGKey(0)),
-            on_chip(jax.ShapeDtypeStruct((), jnp.int32)), 50256, 1.0, 0, 1.0,
-            None, True, False, False, True, jnp.bfloat16).compile()
+            on_chip(jax.ShapeDtypeStruct((), jnp.int32)))
+    static = (50256, 1.0, 0, 1.0, None, True, False, False, True,
+              jnp.bfloat16)
+    compiled = jax.jit(
+        _paged_decode_iter_impl, static_argnums=(0, 11, 12, 13, 14, 15, 16),
+        donate_argnums=(2, 4)).lower(model, *args, *static).compile()
+
+    # the lengths the Mosaic call walks are masked by ``active``: a row
+    # that does not decode is handed 0, whatever length it still holds
+    program = jax.make_jaxpr(lambda *a: _paged_decode_iter_impl(
+        model, *a, *static))(*args).jaxpr
+    paths = [jax.tree_util.keystr(path) for path, _ in
+             jax.tree_util.tree_flatten_with_path(args)[0]]
+    active_at, = [i for i, k in enumerate(paths) if "active" in k]
+    lengths_at, = [i for i, k in enumerate(paths) if "lengths" in k]
+    calls = list(_mosaic_calls(((program, None),)))
+    assert len(calls) == (1 if scan_layers else LAYERS)
+    for call, frames in calls:
+        # scalar prefetch: lengths is the kernel's first operand
+        select, at = _origin(call.invars[0], frames)
+        assert select.primitive.name == "select_n", select
+        which, idle, decoding = select.invars
+        assert _origin(which, at) == ("input", active_at)
+        assert _origin(idle, at) == ("literal", 0)
+        clamp, at = _origin(decoding, at)               # min(lengths, S - 1)
+        assert clamp.primitive.name == "min", clamp
+        assert ("input", lengths_at) in [_origin(v, at)
+                                         for v in clamp.invars]
 
     kv = [x for x in jax.tree.leaves(pool_shapes) if x.ndim >= 4]
     pool_bytes = sum(x.size * x.dtype.itemsize for x in kv)

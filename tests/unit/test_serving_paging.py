@@ -540,6 +540,17 @@ def _scan_streams(eqn):
 PAGED_PAGE = 128            # the cell's page: offset 127 -> 0 of the next
 
 
+def _filled_pool(m, params, num_pages, page_len, seed):
+    """A page pool of ``m`` whose K/V pages hold seeded noise."""
+    from deepspeed_tpu.inference.cache import init_page_pool
+    pool = init_page_pool(m, params, num_pages, page_len)
+    leaves, tree = jax.tree.flatten(pool)
+    r = np.random.RandomState(seed)
+    leaves = [jnp.asarray(r.randn(*x.shape).astype(np.float32)) * 0.3
+              if x.ndim >= 4 else x for x in leaves]
+    return jax.tree.unflatten(tree, leaves)
+
+
 class _PagedScene:
     """Four slots over a hand-made pool: slots 0 and 1 share a full
     prefix page, slot 0 is two tokens from its page's end, slot 2 rides
@@ -550,15 +561,9 @@ class _PagedScene:
     ACTIVE = np.array([True, True, False, True])
 
     def __init__(self, scan_layers, kv_int8):
-        from deepspeed_tpu.inference.cache import init_page_pool
         self.m, self.params = _model(vocab=101, max_seq_len=3 * PAGED_PAGE,
                                      scan_layers=scan_layers)
-        pool = init_page_pool(self.m, self.params, 9, PAGED_PAGE)
-        leaves, tree = jax.tree.flatten(pool)
-        r = np.random.RandomState(3)
-        leaves = [jnp.asarray(r.randn(*x.shape).astype(np.float32)) * 0.3
-                  if x.ndim >= 4 else x for x in leaves]
-        self.pool = jax.tree.unflatten(tree, leaves)
+        self.pool = _filled_pool(self.m, self.params, 9, PAGED_PAGE, seed=3)
         if kv_int8:
             self.pool = self._quantized(self.pool)
 
@@ -781,6 +786,236 @@ class TestPoolStaysInPlace:
                 assert len(ints) == (1 if cache else 0), ints
         # train: the forward scan; generate: prefill and the decode step
         assert forward == (1 if program == "train" else 2)
+
+
+# ---------------------------------------------------------------------------
+# the kernel is handed length 0 for every row that does not decode
+# ---------------------------------------------------------------------------
+
+def _watch_kernel_lengths(monkeypatch):
+    """Every ``lengths`` the paged-attention kernel is called with from
+    here on, in call order (one entry a layer a dispatch): programs
+    traced under the patch report through a debug callback."""
+    import importlib
+    pa_mod = importlib.import_module(
+        "deepspeed_tpu.ops.pallas.paged_attention")
+    seen, inner = [], pa_mod.paged_attention
+
+    def watched(q, k_pages, v_pages, page_table, lengths, *rest, **kw):
+        jax.debug.callback(lambda x: seen.append(np.asarray(x)), lengths,
+                           ordered=True)
+        return inner(q, k_pages, v_pages, page_table, lengths, *rest, **kw)
+
+    monkeypatch.setattr(pa_mod, "paged_attention", watched)
+    return seen
+
+
+class _IdleRowsScene:
+    """Four slots over a hand-made pool, two of them decoding. ``stale``:
+    the other two were released and keep their lengths and their page
+    table rows, whose pages have since been poisoned. ``fresh``: the
+    same two rows as a server that never used them has them."""
+    PAGE, LAYERS = 16, 2
+    TABLE = np.array([[1, 2, 0, 0], [9, 10, 11, 0], [3, 0, 0, 0],
+                      [10, 9, 0, 0]], np.int32)
+    LENGTHS = np.array([20, 40, 5, 30], np.int32)
+    ACTIVE = np.array([True, False, True, False])
+    POISONED = (9, 10, 11)
+
+    def __init__(self, family):
+        import flax.core.meta as flax_meta
+        if family == "olmoe":
+            from deepspeed_tpu.models.olmoe import OLMoE, OLMoEConfig
+            self.m = OLMoE(OLMoEConfig(
+                vocab_size=103, hidden_size=32, intermediate_size=16,
+                num_hidden_layers=self.LAYERS, num_attention_heads=2,
+                num_experts=4, num_experts_per_tok=2,
+                max_position_embeddings=64, dtype=jnp.float32))
+        else:
+            self.m = GPT(GPTConfig(
+                vocab_size=103, max_seq_len=64, d_model=32,
+                n_layers=self.LAYERS, n_heads=2, dtype=jnp.float32))
+        self.params = flax_meta.unbox(self.m.init(
+            jax.random.PRNGKey(0), jnp.ones((1, 8), jnp.int32)))["params"]
+        self.pool = _filled_pool(self.m, self.params, 12, self.PAGE, seed=7)
+
+    def _pool(self, stale):
+        """Pages 9-11: NaN where they were a released slot's, zeros
+        where nobody ever wrote them."""
+        bad = jnp.asarray(self.POISONED)
+        fill = jnp.nan if stale else 0.0
+        return jax.tree.map(
+            lambda x: (x.at[:, bad].set(fill) if x.ndim == 5
+                       else x.at[bad].set(fill)) if x.ndim >= 4 else x,
+            self.pool)
+
+    def run(self, stale, steps=3):
+        from deepspeed_tpu.serving.paging import manager
+        idle = ~self.ACTIVE
+        table, lengths = self.TABLE.copy(), self.LENGTHS.copy()
+        if not stale:
+            table[idle], lengths[idle] = 0, 0
+        state = {"lengths": jnp.asarray(lengths),
+                 "last_token": jnp.asarray([5, 17, 29, 41], jnp.int32),
+                 "active": jnp.asarray(self.ACTIVE),
+                 "remaining": jnp.full((4,), 99, jnp.int32)}
+        step = jax.jit(manager._paged_decode_iter_impl,
+                       static_argnums=(0, 11, 12, 13, 14, 15, 16))
+        pool, toks, counts = self._pool(stale), [], []
+        for it in range(steps):
+            pool, state, tok, _, cnt = step(
+                self.m, self.params, pool, jnp.asarray(table), state,
+                jax.random.PRNGKey(0), jnp.int32(it), -1, 1.0, 0, 1.0, None,
+                True, False, False, True, jnp.float32)
+            toks.append(np.asarray(tok))
+            counts.append(None if cnt is None else np.asarray(cnt))
+        jax.effects_barrier()
+        return pool, state, np.stack(toks), counts
+
+
+class TestIdleRowsAreNotWalked:
+    @pytest.mark.parametrize("family", ["gpt", "olmoe"])
+    def test_released_slots_with_stale_lengths_and_poisoned_pages(
+            self, family, monkeypatch):
+        """The live rows get, token for token and logit for logit, what a
+        server that never used the other two slots gives them (an expert
+        layer's counts too); the kernel is told 0 for the released rows,
+        so nothing of their poisoned pages reaches the null page that
+        their append is sent to."""
+        from deepspeed_tpu.serving.paging import manager
+        lengths_seen = _watch_kernel_lengths(monkeypatch)
+        logits_seen, sample = [], manager._sample_impl
+
+        def watched(logits, *rest):
+            jax.debug.callback(
+                lambda x: logits_seen.append(np.asarray(x, np.float32)),
+                logits, ordered=True)
+            return sample(logits, *rest)
+        monkeypatch.setattr(manager, "_sample_impl", watched)
+
+        scene = _IdleRowsScene(family)
+        live, steps = scene.ACTIVE, 3
+        pool_s, state_s, toks_s, counts_s = scene.run(stale=True)
+        stale_logits, stale_lengths = list(logits_seen), list(lengths_seen)
+        del logits_seen[:], lengths_seen[:]
+        pool_f, state_f, toks_f, counts_f = scene.run(stale=False)
+
+        np.testing.assert_array_equal(toks_s, toks_f)
+        assert (toks_s[:, live] >= 0).all() and (toks_s[:, ~live] == -1).all()
+        assert len(stale_logits) == len(logits_seen) == steps
+        for a, b in zip(stale_logits, logits_seen):
+            np.testing.assert_allclose(a[live], b[live], atol=1e-6, rtol=0)
+        for a, b in zip(counts_s, counts_f):
+            assert (a is None) == (family == "gpt")
+            if a is not None:
+                np.testing.assert_array_equal(a, b)
+                assert a.sum(1).tolist() == [2 * live.sum()] * scene.LAYERS
+        # one call a layer a step, and every one of them masked
+        assert len(stale_lengths) == steps * scene.LAYERS
+        for i, got in enumerate(stale_lengths):
+            want = np.where(live, scene.LENGTHS + i // scene.LAYERS, 0)
+            np.testing.assert_array_equal(got, want)
+        # a released row keeps its length (admission overwrites it)
+        np.testing.assert_array_equal(np.asarray(state_s["lengths"]),
+                                      scene.LENGTHS + steps * live)
+        # its K/V of this step goes to the null page: finite, because the
+        # row attended its own token and none of its poisoned pages
+        for leaf, fresh in zip(jax.tree.leaves(pool_s),
+                               jax.tree.leaves(pool_f)):
+            if leaf.ndim >= 4:
+                pages = _pages(leaf)
+                assert np.isfinite(pages[0]).all()
+                assert np.isnan(pages[list(scene.POISONED)]).all()
+                np.testing.assert_allclose(pages[1:9], _pages(fresh)[1:9],
+                                           atol=1e-6, rtol=0)
+
+    def _engine(self, vocab, monkeypatch, **serving):
+        from deepspeed_tpu.observability import metrics as registry_mod
+        reg = registry_mod.MetricsRegistry()
+        monkeypatch.setattr(registry_mod, "_DEFAULT_REGISTRY", reg)
+        m, params = _model(vocab=vocab)
+        paging = dict(page_len=16, prefill_chunk=16, kernel="on",
+                      enable_prefix_cache=False)
+        paging.update(serving.pop("paging", {}))
+        eng = ServingEngine(m, params, ServingConfig(
+            max_len=128, prefill_bucket=16, seed=0,
+            paging=PagingConfig(**paging), **serving))
+        assert eng._paged.use_kernel == (paging["kernel"] == "on")
+        count = lambda name: reg.counter("serving/" + name).value
+        return m, params, eng, count
+
+    def test_a_slot_waiting_for_its_prefill_chunks_is_masked(self,
+                                                             monkeypatch):
+        """Slot 0 served a request and was released: it keeps length 32.
+        The next request it is given has three chunks of prompt; while
+        they run, the decode dispatches (for the request beside it) hand
+        the kernel 0 for slot 0, then the prompt's 40, 41, ... — and its
+        tokens, the first one after the last chunk too, are
+        ``generate()``'s."""
+        lengths_seen = _watch_kernel_lengths(monkeypatch)
+        m, params, eng, count = self._engine(163, monkeypatch, num_slots=2)
+        r = np.random.RandomState(11)
+        first = eng.submit(r.randint(1, 163, size=30).astype(np.int32),
+                           max_new_tokens=3)
+        eng.run()
+        assert first.done and eng._slot_req == [None, None]
+        assert int(np.asarray(eng._state["lengths"])[0]) == 32
+        # two tokens from decode dispatches, and a third dispatch went out
+        # before the host had read that the request was done
+        assert count("paged_rows_walked") == 2
+        assert count("decode_slots_busy") == 3
+        del lengths_seen[:]
+
+        short_p = r.randint(1, 163, size=5).astype(np.int32)
+        long_p = r.randint(1, 163, size=40).astype(np.int32)
+        short = eng.submit(short_p, max_new_tokens=12)     # slot 1
+        long = eng.submit(long_p, max_new_tokens=4)        # slot 0, stale
+        eng.advance()
+        assert eng._slot_req == [long, short] and eng._prefill_tasks
+        eng.run()
+        jax.effects_barrier()
+        per_dispatch = np.stack(lengths_seen[::2])         # layer 0's calls
+        slot0 = per_dispatch[:, 0].tolist()
+        waiting = slot0.index(40)
+        assert waiting >= 3 and slot0[:waiting] == [0] * waiting
+        assert slot0[waiting:waiting + 3] == [40, 41, 42]
+        assert per_dispatch[:waiting, 1].tolist() == list(range(5, 5 + waiting))
+        for req, prompt, n in ((short, short_p, 12), (long, long_p, 4)):
+            np.testing.assert_array_equal(
+                np.asarray(req.output_tokens),
+                _generate_ref(m, params, prompt, n))
+        walked, busy, offered = (count("paged_rows_walked"),
+                                 count("decode_slots_busy"),
+                                 count("decode_slots_offered"))
+        assert 0 < walked <= busy < offered
+        # every token but a request's first comes from a decode dispatch
+        assert walked == (3 - 1) + (12 - 1) + (4 - 1)
+
+    def test_a_full_batch_walks_every_row_it_is_offered(self, monkeypatch):
+        """Two slots, two requests admitted and prefilled in the same
+        iteration, as many tokens each, read back before the next
+        dispatch: no dispatch has a row to mask."""
+        m, params, eng, count = self._engine(
+            167, monkeypatch, num_slots=2, pipeline_depth=0,
+            paging=dict(max_chunks_per_iter=2))
+        r = np.random.RandomState(13)
+        reqs = [eng.submit(r.randint(1, 167, size=9).astype(np.int32),
+                           max_new_tokens=6) for _ in range(2)]
+        eng.run()
+        assert all(q.done and len(q.output_tokens) == 6 for q in reqs)
+        assert count("paged_rows_walked") == count("decode_slots_busy") \
+            == count("decode_slots_offered") == 2 * (6 - 1)
+
+    def test_the_gather_path_counts_no_walked_rows(self, monkeypatch):
+        """``kernel='off'``: no kernel is handed a length, and the
+        gathered view's cost does not follow lengths."""
+        m, params, eng, count = self._engine(
+            173, monkeypatch, num_slots=2, paging=dict(kernel="off"))
+        req = eng.submit(np.arange(1, 8, dtype=np.int32), max_new_tokens=4)
+        eng.run()
+        assert req.done and not eng._paged.use_kernel
+        assert count("decode_slots_busy") == 4
+        assert count("paged_rows_walked") == 0
 
 
 # ---------------------------------------------------------------------------
