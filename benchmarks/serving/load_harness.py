@@ -300,7 +300,7 @@ def _scenario_knobs(args):
         if not knobs["repeat_frac"]:
             knobs["repeat_frac"] = 0.9
     if args.scenario == "prefix-adversarial":
-        page = args.page_len if args.paged else 128
+        page = args.page_len
         if not knobs["shared_prefix_len"]:
             # two full pages so the cached run is page-granular-shareable
             knobs["shared_prefix_len"] = min(2 * page,
@@ -345,32 +345,30 @@ def run_benchmark(args):
         vocab_size=args.vocab_size, max_seq_len=args.max_len,
         d_model=args.d_model, n_layers=args.n_layers, n_heads=args.n_heads,
         seed=args.seed)
-    paging = None
-    if args.paged:
-        num_pages = None
-        if args.hbm_rows is not None:
-            # pool budget expressed in full-length-row equivalents: the
-            # density experiment holds the BYTE budget fixed while slots
-            # scale. The budget is always priced at the model's dense
-            # dtype; int8 KV pages cost fewer bytes each (int8 K/V +
-            # fp32 per-head-per-token scale planes), so the same budget
-            # buys proportionally more pages — the second density lever
-            cache_len = -(-args.max_len // 128) * 128
-            if args.kv_int8:
-                # per-token bytes per layer: K+V at d_model elements each
-                dense_tok = 2 * args.n_layers * args.d_model * 4
-                int8_tok = 2 * args.n_layers * (args.d_model
-                                                + args.n_heads * 4)
-                budget = args.hbm_rows * cache_len * dense_tok
-                num_pages = budget // (int8_tok * args.page_len) + 1
-            else:
-                num_pages = args.hbm_rows * (cache_len // args.page_len) + 1
-        paging = PagingConfig(
-            page_len=args.page_len, num_pages=num_pages,
-            prefill_chunk=args.prefill_chunk,
-            max_chunks_per_iter=args.max_chunks_per_iter,
-            enable_prefix_cache=not args.no_prefix_cache,
-            kernel=args.kernel)
+    num_pages = None
+    if args.hbm_rows is not None:
+        # pool budget expressed in full-length-row equivalents: the
+        # density experiment holds the BYTE budget fixed while slots
+        # scale. The budget is always priced at the model's dense
+        # dtype; int8 KV pages cost fewer bytes each (int8 K/V +
+        # fp32 per-head-per-token scale planes), so the same budget
+        # buys proportionally more pages — the second density lever
+        cache_len = -(-args.max_len // 128) * 128
+        if args.kv_int8:
+            # per-token bytes per layer: K+V at d_model elements each
+            dense_tok = 2 * args.n_layers * args.d_model * 4
+            int8_tok = 2 * args.n_layers * (args.d_model
+                                            + args.n_heads * 4)
+            budget = args.hbm_rows * cache_len * dense_tok
+            num_pages = budget // (int8_tok * args.page_len) + 1
+        else:
+            num_pages = args.hbm_rows * (cache_len // args.page_len) + 1
+    paging = PagingConfig(
+        page_len=args.page_len, num_pages=num_pages,
+        prefill_chunk=args.prefill_chunk,
+        max_chunks_per_iter=args.max_chunks_per_iter,
+        enable_prefix_cache=not args.no_prefix_cache,
+        kernel=args.kernel)
     quantize = None
     if args.kv_int8 or args.quantize_weights:
         from deepspeed_tpu.serving.config import QuantizeConfig
@@ -385,8 +383,7 @@ def run_benchmark(args):
             max_spec_tokens=args.max_spec_tokens,
             ngram_max=args.spec_ngram_max, ngram_min=args.spec_ngram_min)
     cfg = ServingConfig(num_slots=args.num_slots, max_len=args.max_len,
-                        prefill_bucket=args.prefill_bucket, seed=args.seed,
-                        paging=paging, quantize=quantize,
+                        seed=args.seed, paging=paging, quantize=quantize,
                         speculation=speculation,
                         qos=(_qos_config(args)
                              if (args.qos or qos_scenario) else None))
@@ -441,47 +438,44 @@ def run_benchmark(args):
                 if peak_tflops else None),
     }
 
-    # paged-mode accounting (CPU-backend byte arithmetic, no device
+    # pool accounting (CPU-backend byte arithmetic, no device
     # introspection): the pool's resident K/V bytes vs what the SAME
-    # byte budget buys as contiguous full-length rows — the density
+    # byte budget buys as full-length rows — the density
     # claim is concurrent_requests_peak / full_length_rows_equivalent
-    paging_block = None
-    if engine._paged is not None:
-        mgr = engine._paged
-        stats = mgr.stats()
-        pool_bytes = mgr.pool_bytes()
-        bytes_per_token = pool_bytes / (mgr.num_pages * mgr.page_len)
-        rows_equiv = stats["full_length_rows_equivalent"]
-        peak = agg.get("concurrent_requests_peak", 0)
-        # the density denominator: the BYTE budget in dense full-row
-        # equivalents (--hbm-rows when given). int8 pools hold more
-        # TOKENS than the dense budget would (that is the point), so
-        # the token-based rows_equiv overstates the denominator there.
-        budget_rows = args.hbm_rows if args.hbm_rows is not None \
-            else rows_equiv
-        paging_block = {
-            **stats,
-            "pool_bytes": pool_bytes,
-            "contiguous_bytes_equivalent": int(
-                bytes_per_token * rows_equiv * cfg.cache_len),
-            "concurrent_requests_peak": peak,
-            "hbm_budget_rows": budget_rows,
-            "density_gain_vs_full_rows": (peak / budget_rows
-                                          if budget_rows else None),
-            # resident-vs-transient honesty (docs/serving.md): the
-            # density claim prices the page pool, but each jitted decode
-            # step also gathers a contiguous [num_slots, cache_len] view
-            # as XLA-managed scratch — derived by the HBM accountant
-            # from the pool's own leaf shapes (observability/memory.py),
-            # no longer hand arithmetic
-            "decode_gather_transient_bytes":
-                mgr.decode_gather_transient_bytes(),
-            "prefill_tokens_computed": agg.get("prefill_tokens_computed", 0),
-            "prefill_tokens_reused": agg.get("prefill_tokens_reused", 0),
-            "prefill_recompute_skipped_frac": agg.get(
-                "prefill_recompute_skipped_frac", 0.0),
-            "ttft_steps_under_load_p95": agg.get("ttft_steps_under_load_p95"),
-        }
+    mgr = engine._paged
+    stats = mgr.stats()
+    pool_bytes = mgr.pool_bytes()
+    bytes_per_token = pool_bytes / (mgr.num_pages * mgr.page_len)
+    rows_equiv = stats["full_length_rows_equivalent"]
+    peak = agg.get("concurrent_requests_peak", 0)
+    # the density denominator: the BYTE budget in dense full-row
+    # equivalents (--hbm-rows when given). int8 pools hold more
+    # TOKENS than the dense budget would (that is the point), so
+    # the token-based rows_equiv overstates the denominator there.
+    budget_rows = args.hbm_rows if args.hbm_rows is not None \
+        else rows_equiv
+    paging_block = {
+        **stats,
+        "pool_bytes": pool_bytes,
+        "contiguous_bytes_equivalent": int(
+            bytes_per_token * rows_equiv * cfg.cache_len),
+        "concurrent_requests_peak": peak,
+        "hbm_budget_rows": budget_rows,
+        "density_gain_vs_full_rows": (peak / budget_rows
+                                      if budget_rows else None),
+        # resident-vs-transient honesty (docs/serving.md): the
+        # density claim prices the page pool, but each jitted decode
+        # step of the gather path also gathers a contiguous
+        # [num_slots, cache_len] view as XLA-managed scratch — derived
+        # from the pool's own leaf shapes, not hand arithmetic
+        "decode_gather_transient_bytes":
+            mgr.decode_gather_transient_bytes(),
+        "prefill_tokens_computed": agg.get("prefill_tokens_computed", 0),
+        "prefill_tokens_reused": agg.get("prefill_tokens_reused", 0),
+        "prefill_recompute_skipped_frac": agg.get(
+            "prefill_recompute_skipped_frac", 0.0),
+        "ttft_steps_under_load_p95": agg.get("ttft_steps_under_load_p95"),
+    }
 
     # QoS accounting: per-class latency/shed breakdown plus the EXACT
     # shed/preempted id sets — the bit-reproducibility regression surface
@@ -547,9 +541,7 @@ def run_benchmark(args):
         "bench": "serving",
         "config": {
             "num_slots": cfg.num_slots, "max_len": cfg.max_len,
-            "prefill_bucket": cfg.prefill_bucket,
-            "paging": (None if cfg.paging is None else {
-                "enabled": cfg.paging.enabled,
+            "paging": {
                 "page_len": cfg.paging.page_len,
                 "num_pages": cfg.paging.pool_pages(cfg.num_slots,
                                                    cfg.cache_len),
@@ -557,7 +549,7 @@ def run_benchmark(args):
                 "max_chunks_per_iter": cfg.paging.max_chunks_per_iter,
                 "enable_prefix_cache": cfg.paging.enable_prefix_cache,
                 "kernel": cfg.paging.kernel,
-            }),
+            },
             "quantize": (None if cfg.quantize is None else {
                 "weights": cfg.quantize.weights,
                 "kv": cfg.quantize.kv,
@@ -582,9 +574,8 @@ def run_benchmark(args):
         # ``memory`` block next to the PR-5 ``perf`` block
         "memory": engine.memory_report(),
         "per_request": per_request,
+        "paging": paging_block,
     }
-    if paging_block is not None:
-        result["paging"] = paging_block
     if qos_block is not None:
         result["qos"] = qos_block
     if spec_block is not None:
@@ -647,7 +638,7 @@ def train_demo_model_on_motifs(model, params, *, vocab_size: int,
     return params
 
 
-def _spec_arm(model, params, args, trace, *, paged: bool, speculate: bool):
+def _spec_arm(model, params, args, trace, *, speculate: bool):
     """One A/B arm of the speculation benchmark: same model, same seeded
     trace, same engine geometry — the ONLY difference is whether the
     ``serving.speculation`` block is present. Returns the arm's artifact
@@ -659,10 +650,8 @@ def _spec_arm(model, params, args, trace, *, paged: bool, speculate: bool):
     from deepspeed_tpu.serving.paging import PagingConfig
 
     cfg = ServingConfig(
-        num_slots=args.num_slots, max_len=args.max_len,
-        prefill_bucket=args.prefill_bucket, seed=args.seed,
-        paging=(PagingConfig(page_len=args.page_len, kernel=args.kernel)
-                if paged else None),
+        num_slots=args.num_slots, max_len=args.max_len, seed=args.seed,
+        paging=PagingConfig(page_len=args.page_len, kernel=args.kernel),
         speculation=(SpeculationConfig(
             max_spec_tokens=args.max_spec_tokens,
             ngram_max=args.spec_ngram_max,
@@ -694,10 +683,10 @@ def _spec_arm(model, params, args, trace, *, paged: bool, speculate: bool):
 
 def run_spec_benchmark(args):
     """The speculation A/B pack (``--scenario repetitive``): the SAME
-    seeded self-similar trace through spec-off and spec-on engines, on
-    BOTH the contiguous and the paged cache, asserting the spec-on arm
-    emits bitwise-identical per-request outputs (token-exactness is the
-    speedup's precondition, so the artifact carries the proof). Writes
+    seeded self-similar trace through spec-off and spec-on engines,
+    asserting the spec-on arm emits bitwise-identical per-request
+    outputs (token-exactness is the speedup's precondition, so the
+    artifact carries the proof). Writes
     the ``BENCH_serving_spec`` artifact; the headline figure is
     ``decode_iterations_ratio`` — emitted-tokens-per-dispatch
     compression on the deterministic step clock (wall tokens/s rides
@@ -718,34 +707,18 @@ def run_spec_benchmark(args):
             model, params, vocab_size=args.vocab_size,
             motif_len=knobs["motif_len"] or 4,
             steps=args.spec_train_steps, seed=args.seed + 123)
-    # warmup: pay every jit specialization (prefill buckets + decode +
-    # spec verify, contiguous and paged) on a throwaway slice so the
-    # arms' wall-clock numbers compare speculation, not compilation
-    for paged in (False, True):
-        for speculate in (False, True):
-            _spec_arm(model, params, args, trace[: min(4, len(trace))],
-                      paged=paged, speculate=speculate)
-    modes = {}
-    for mode, paged in (("contiguous", False), ("paged", True)):
-        off, out_off = _spec_arm(model, params, args, trace,
-                                 paged=paged, speculate=False)
-        on, out_on = _spec_arm(model, params, args, trace,
-                               paged=paged, speculate=True)
-        modes[mode] = {
-            "spec_off": off,
-            "spec_on": on,
-            "bitwise_identical_outputs": out_off == out_on,
-            "decode_iterations_ratio": (
-                off["decode_iterations"] / max(1, on["decode_iterations"])),
-            "tokens_per_s_ratio": (
-                on["throughput_tokens_per_s"]
-                / max(1e-9, off["throughput_tokens_per_s"])),
-        }
+    # warmup: pay every jit specialization (prefill chunk + decode +
+    # spec verify) on a throwaway slice so the arms' wall-clock numbers
+    # compare speculation, not compilation
+    for speculate in (False, True):
+        _spec_arm(model, params, args, trace[: min(4, len(trace))],
+                  speculate=speculate)
+    off, out_off = _spec_arm(model, params, args, trace, speculate=False)
+    on, out_on = _spec_arm(model, params, args, trace, speculate=True)
     return {
         "bench": "serving_spec",
         "config": {
             "num_slots": args.num_slots, "max_len": args.max_len,
-            "prefill_bucket": args.prefill_bucket,
             "page_len": args.page_len,
             "speculation": {"max_spec_tokens": args.max_spec_tokens,
                             "ngram_max": args.spec_ngram_max,
@@ -760,14 +733,21 @@ def run_spec_benchmark(args):
                   "prompt_len_range": [args.min_prompt, args.max_prompt],
                   "output_len_range": [args.min_output, args.max_output],
                   **knobs},
-        "modes": modes,
+        "spec_off": off,
+        "spec_on": on,
+        "bitwise_identical_outputs": out_off == out_on,
+        "decode_iterations_ratio": (
+            off["decode_iterations"] / max(1, on["decode_iterations"])),
+        "tokens_per_s_ratio": (
+            on["throughput_tokens_per_s"]
+            / max(1e-9, off["throughput_tokens_per_s"])),
     }
 
 
 def _build_fleet(args, router: str):
     """One fleet per A/B arm: same model/seed/geometry, only the router
     policy differs — the comparison is dispatch policy, nothing else.
-    Always paged: prefix affinity exists to feed the radix cache."""
+    Prefix affinity exists to feed the radix cache."""
     from deepspeed_tpu.serving import ServingConfig
     from deepspeed_tpu.serving.fleet.config import FleetConfig
     from deepspeed_tpu.serving.fleet.manager import ServingFleet
@@ -778,8 +758,7 @@ def _build_fleet(args, router: str):
         d_model=args.d_model, n_layers=args.n_layers, n_heads=args.n_heads,
         seed=args.seed)
     cfg = ServingConfig(
-        num_slots=args.num_slots, max_len=args.max_len,
-        prefill_bucket=args.prefill_bucket, seed=args.seed,
+        num_slots=args.num_slots, max_len=args.max_len, seed=args.seed,
         paging=PagingConfig(page_len=args.page_len, kernel=args.kernel),
         fleet=FleetConfig(replicas=args.replicas, router=router,
                           disaggregate=args.disaggregate,
@@ -950,7 +929,6 @@ def build_parser():
     p.add_argument("--num-requests", type=int, default=64)
     p.add_argument("--num-slots", type=int, default=8)
     p.add_argument("--max-len", type=int, default=256)
-    p.add_argument("--prefill-bucket", type=int, default=128)
     p.add_argument("--mean-interarrival", type=float, default=2.0,
                    help="mean request inter-arrival in decode steps")
     p.add_argument("--min-prompt", type=int, default=4)
@@ -1024,18 +1002,16 @@ def build_parser():
     p.add_argument("--shared-prefix-frac", type=float, default=0.0)
     p.add_argument("--long-prompt-len", type=int, default=0)
     p.add_argument("--long-prompt-frac", type=float, default=0.0)
-    p.add_argument("--paged", action="store_true",
-                   help="serve through the block-paged KV cache "
-                        "(serving/paging/) instead of contiguous slot rows")
-    p.add_argument("--page-len", type=int, default=128)
+    p.add_argument("--page-len", type=int, default=128,
+                   help="tokens per KV page (serving.paging.page_len)")
     p.add_argument("--prefill-chunk", type=int, default=None,
                    help="tokens prefilled per engine iteration (page_len "
                         "multiple; default one page)")
     p.add_argument("--max-chunks-per-iter", type=int, default=1)
     p.add_argument("--hbm-rows", type=int, default=None,
                    help="page-pool budget in full-length-row equivalents "
-                        "(default: memory parity with num_slots contiguous "
-                        "rows) — the density experiment holds this fixed "
+                        "(default: num_slots full-length rows) — the "
+                        "density experiment holds this fixed "
                         "while num_slots scales")
     p.add_argument("--no-prefix-cache", action="store_true")
     p.add_argument("--kernel", choices=["auto", "on", "off"],
@@ -1097,16 +1073,15 @@ def main(argv=None):
         result = run_spec_benchmark(args)
         with open(args.out, "w") as f:
             json.dump(result, f, indent=1)
-        for mode, m in result["modes"].items():
-            on, off = m["spec_on"], m["spec_off"]
-            print(f"BENCH_serving_spec [{mode}]: "
-                  f"{off['decode_iterations']} -> {on['decode_iterations']} "
-                  f"decode iterations "
-                  f"({m['decode_iterations_ratio']:.2f}x step-clock), "
-                  f"{on['tokens_per_decode_iteration']:.2f} tok/dispatch, "
-                  f"acceptance {on.get('spec_acceptance_rate', 0.0):.0%}, "
-                  f"outputs bitwise-identical: "
-                  f"{m['bitwise_identical_outputs']}")
+        on, off = result["spec_on"], result["spec_off"]
+        print(f"BENCH_serving_spec: "
+              f"{off['decode_iterations']} -> {on['decode_iterations']} "
+              f"decode iterations "
+              f"({result['decode_iterations_ratio']:.2f}x step-clock), "
+              f"{on['tokens_per_decode_iteration']:.2f} tok/dispatch, "
+              f"acceptance {on.get('spec_acceptance_rate', 0.0):.0%}, "
+              f"outputs bitwise-identical: "
+              f"{result['bitwise_identical_outputs']}")
         print(f"  artifact -> {args.out}")
         return 0
     if args.scenario in FLEET_SCENARIOS:
@@ -1150,17 +1125,16 @@ def main(argv=None):
         print(f"  qos: level {qb['level']}, shed {qb['requests_shed']}, "
               f"preempted {qb['requests_preempted']} "
               f"(resumed {qb['requests_resumed']}) | {per_cls}")
-    pg = result.get("paging")
-    if pg is not None:
-        gain = pg["density_gain_vs_full_rows"]
-        print(f"  paged: util {pg['page_utilization']:.2f}, "
-              f"prefix hit rate {pg.get('prefix_hit_rate', 0.0):.2f} "
-              f"({pg['prefill_recompute_skipped_frac']:.0%} prefill "
-              f"recompute skipped), peak {pg['concurrent_requests_peak']} "
-              f"concurrent on {pg['full_length_rows_equivalent']} "
-              f"full-row HBM ({'-' if gain is None else f'{gain:.1f}x'} "
-              f"density), ttft-under-load p95 "
-              f"{pg['ttft_steps_under_load_p95']} steps")
+    pg = result["paging"]
+    gain = pg["density_gain_vs_full_rows"]
+    print(f"  paged: util {pg['page_utilization']:.2f}, "
+          f"prefix hit rate {pg.get('prefix_hit_rate', 0.0):.2f} "
+          f"({pg['prefill_recompute_skipped_frac']:.0%} prefill "
+          f"recompute skipped), peak {pg['concurrent_requests_peak']} "
+          f"concurrent on {pg['full_length_rows_equivalent']} "
+          f"full-row HBM ({'-' if gain is None else f'{gain:.1f}x'} "
+          f"density), ttft-under-load p95 "
+          f"{pg['ttft_steps_under_load_p95']} steps")
     return 0
 
 

@@ -1,4 +1,4 @@
-"""KV-cache tree plumbing for ragged decode and slot-based serving.
+"""KV-cache tree plumbing for ragged decode and paged serving.
 
 The flax "cache" collection produced by ``init_cache`` is a nested dict
 whose attention units hold three leaves (models/layers.py SelfAttention):
@@ -70,21 +70,6 @@ def cache_max_len(cache) -> int:
     return found[0]
 
 
-def cache_num_rows(cache) -> int:
-    """The batch (slot) dimension of the cache (static python int)."""
-    found = []
-
-    def probe(unit):
-        kv = unit["cached_key"]
-        found.append(int(kv.shape[kv.ndim - 4]))
-        return unit
-
-    _map_units(cache, probe)
-    if not found:
-        raise ValueError("no attention cache units found in the cache tree")
-    return found[0]
-
-
 def set_cache_index(cache, lengths):
     """Overwrite every ``cache_index`` with per-row ``lengths`` ([b] int32).
 
@@ -104,27 +89,6 @@ def set_cache_index(cache, lengths):
         return unit
 
     return _map_units(cache, setter)
-
-
-def make_row_cache(cache):
-    """A zeroed single-row cache with the same structure/capacity as
-    ``cache`` (batch axis 1, scalar-mode ``cache_index``) — the prefill
-    scratch a request runs through before its row is scattered into the
-    slot pool."""
-
-    def shrink(unit):
-        out = {}
-        for name in _KV_KEYS:
-            kv = unit[name]
-            ax = kv.ndim - 4
-            shape = kv.shape[:ax] + (1,) + kv.shape[ax + 1:]
-            out[name] = jnp.zeros(shape, kv.dtype)
-        stacked = unit["cached_key"].ndim == 5
-        idx_shape = (unit["cached_key"].shape[0],) if stacked else ()
-        out["cache_index"] = jnp.zeros(idx_shape, jnp.int32)
-        return out
-
-    return _map_units(cache, shrink)
 
 
 # ---------------------------------------------------------------------------
@@ -468,29 +432,3 @@ def make_paged_view(pool, page_table, lengths):
         }
 
     return {"cache": _map_units(pool, small_state), "kv_pool": _as_dict(pool)}
-
-
-def write_cache_row(cache, row_cache, row):
-    """Scatter ``row_cache`` (batch 1, from ``make_row_cache`` + prefill)
-    into batch row ``row`` of ``cache``. Only K/V leaves are written —
-    ``cache_index`` is scheduler state, managed via ``set_cache_index``.
-    ``row`` may be a traced scalar."""
-    cache = _as_dict(cache)
-    row_cache = _as_dict(row_cache)
-
-    def walk(dst, src):
-        if _is_attn_unit(dst):
-            out = dict(dst)
-            for name in _KV_KEYS:
-                leaf = dst[name]
-                ax = leaf.ndim - 4
-                starts = [0] * leaf.ndim
-                starts[ax] = row
-                out[name] = jax.lax.dynamic_update_slice(
-                    leaf, src[name], tuple(starts))
-            return out
-        if isinstance(dst, dict):
-            return {k: walk(v, src[k]) for k, v in dst.items()}
-        return dst
-
-    return walk(cache, row_cache)

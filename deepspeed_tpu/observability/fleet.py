@@ -193,7 +193,6 @@ def per_request_breakdown(events, include_requests: bool = True) -> dict:
 # span name -> waterfall stage, for the trace-file variant
 _SPAN_STAGE = {
     "serving/queue_wait": "queue",
-    "serving/admit": "prefill",
     "serving/prefill_chunk": "prefill",
     "serving/handoff_export": "handoff",
     "serving/handoff_inject": "wire",

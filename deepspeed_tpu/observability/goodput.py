@@ -64,7 +64,6 @@ SPAN_CATEGORIES = {
     "device_probe": "compute",       # blocked draining dispatched work
     "checkpoint_save": "checkpoint_save",
     "rollback_recovery": "rollback_recovery",
-    "serving/admit": "compute",
     "serving/prefill_chunk": "compute",
     "serving/decode_iter": "compute",
     "serving/harvest": "compute",    # waiting on dispatched decode output

@@ -5,8 +5,8 @@ wire the block into ``DeepSpeedConfig``, and that module must stay
 importable without jax (the ds_tpu_lint job runs dependency-free).
 """
 
-from dataclasses import dataclass
-from typing import Optional, Tuple
+from dataclasses import dataclass, field
+from typing import Optional
 
 from .fleet.config import FleetConfig
 from .paging.config import PagingConfig
@@ -26,7 +26,7 @@ class QuantizeConfig:
     ``kv``: "int8" stores the paged KV pool int8 with per-page scale
     planes (quantize on scatter, dequantize inside the paged-attention
     kernel's page loop / on gather) — halving page bytes doubles pool
-    density again on top of paging. Requires the ``paging`` block.
+    density again on top of paging.
 
     Parity ladder (docs/serving.md): weights-only int8 is token-exact
     vs a generate() reference over the SAME int8 params under greedy
@@ -35,22 +35,17 @@ class QuantizeConfig:
     tests/unit/test_quantized_serving.py).
     """
     weights: Optional[str] = None    # None | "int8"
-    kv: Optional[str] = None         # None | "int8" (paged engines only)
+    kv: Optional[str] = None         # None | "int8"
     min_size: int = 4096             # smallest weight (elements) to
                                      # quantize; everything below stays
                                      # in its own dtype
 
-    def validate(self, paged: bool):
+    def validate(self):
         for field_name, val in (("weights", self.weights), ("kv", self.kv)):
             if val not in (None, "int8"):
                 raise ValueError(
                     f"serving.quantize.{field_name} must be null or "
                     f"'int8', got {val!r}")
-        if self.kv is not None and not paged:
-            raise ValueError(
-                "serving.quantize.kv requires the block-paged KV cache "
-                "(serving.paging) — per-page scales live in the page "
-                "pool")
         if self.min_size < 1:
             raise ValueError(
                 f"serving.quantize.min_size must be >= 1, got "
@@ -113,14 +108,14 @@ class ServingConfig:
     """Continuous-batching serving knobs (reference analog: the
     init_inference kwargs + DeepSpeed-MII deployment config).
 
-    The engine owns ``num_slots`` preallocated KV-cache rows of
-    ``max_len`` tokens each; prompts are padded to a small fixed set of
-    prefill buckets (multiples of ``prefill_bucket``) so XLA compiles one
-    prefill executable per bucket and ONE decode executable total.
+    The engine decodes a fixed batch of ``num_slots`` rows of at most
+    ``max_len`` tokens each, their K/V held in a block-paged pool (the
+    ``paging`` sub-block); prompts prefill in page-aligned chunks, so XLA
+    compiles one prefill executable per chunk width and ONE decode
+    executable total.
     """
     num_slots: int = 8
     max_len: int = 1024              # per-request token budget (prompt+output)
-    prefill_bucket: int = 128        # bucket quantum for prompt padding
     max_queue: Optional[int] = None  # submit() raises past this depth
     eos_token_id: Optional[int] = None
     default_max_new_tokens: int = 128
@@ -143,12 +138,10 @@ class ServingConfig:
                                      # partial-snapshot/crash path dumps;
                                      # 0 disables recording
     seed: int = 0
-    paging: Optional[PagingConfig] = None
-                                     # block-paged KV cache (serving/paging/):
-                                     # absent or enabled=False keeps the
-                                     # contiguous slot pool — the default
-                                     # path, bit-identical to a build without
-                                     # the paging subsystem
+    paging: PagingConfig = field(default_factory=PagingConfig)
+                                     # the block-paged KV pool (serving/
+                                     # paging/): page size, pool size, prefix
+                                     # cache, prefill chunking, decode kernel
     qos: Optional[QosConfig] = None  # priority classes / SLO shedding /
                                      # degradation ladder / watchdog
                                      # (serving/qos.py, docs/serving.md):
@@ -175,7 +168,9 @@ class ServingConfig:
     def __post_init__(self):
         # nested-block plumbing: runtime/config.py's dict_to_dataclass is
         # shallow, so {"serving": {"paging": {...}}} arrives here as a dict
-        if isinstance(self.paging, dict):
+        if self.paging is None:             # a JSON null: the block is absent
+            self.paging = PagingConfig()
+        elif isinstance(self.paging, dict):
             self.paging = PagingConfig(**self.paging)
         if isinstance(self.qos, dict):
             self.qos = QosConfig(**self.qos)
@@ -191,9 +186,6 @@ class ServingConfig:
             raise ValueError(f"num_slots must be >= 1, got {self.num_slots}")
         if self.max_len < 2:
             raise ValueError(f"max_len must be >= 2, got {self.max_len}")
-        if self.prefill_bucket < 1:
-            raise ValueError(
-                f"prefill_bucket must be >= 1, got {self.prefill_bucket}")
         if self.max_queue is not None and self.max_queue < 1:
             raise ValueError(
                 f"max_queue must be >= 1 (or null for unbounded), got "
@@ -216,22 +208,16 @@ class ServingConfig:
             raise ValueError(
                 f"flight_recorder_events must be >= 0 (0 disables), got "
                 f"{self.flight_recorder_events}")
-        if self.paging is not None:
-            self.paging.validate(self.cache_len)
+        self.paging.validate(self.cache_len)
         if self.qos is not None:
             self.qos.validate()
         if self.quantize is not None:
-            self.quantize.validate(self.paged)
+            self.quantize.validate()
         if self.fleet is not None:
-            self.fleet.validate(self)
+            self.fleet.validate()
         if self.speculation is not None:
             self.speculation.validate(self.temperature)
         return self
-
-    @property
-    def paged(self) -> bool:
-        """True when the block-paged KV cache is configured AND enabled."""
-        return self.paging is not None and self.paging.enabled
 
     @property
     def weights_int8(self) -> bool:
@@ -273,22 +259,3 @@ class ServingConfig:
         the k+1-token window)."""
         pad = self.speculation.max_spec_tokens if self.spec_enabled else 0
         return (self.max_len + pad + 127) // 128 * 128
-
-    def bucket_lengths(self) -> Tuple[int, ...]:
-        """The fixed prefill-length set: multiples of ``prefill_bucket``
-        up to the cache capacity (capacity itself included when
-        unaligned). Prefill jit-specializes at most once per entry."""
-        step = self.prefill_bucket
-        out = list(range(step, self.cache_len + 1, step))
-        if not out or out[-1] != self.cache_len:
-            out.append(self.cache_len)
-        return tuple(out)
-
-    def bucket_for(self, prompt_len: int) -> int:
-        """Smallest bucket >= prompt_len."""
-        for b in self.bucket_lengths():
-            if b >= prompt_len:
-                return b
-        raise ValueError(
-            f"prompt length {prompt_len} exceeds the largest prefill "
-            f"bucket ({self.bucket_lengths()[-1]})")

@@ -1,37 +1,31 @@
-"""Continuous-batching serving engine with a slot-based KV cache.
+"""Continuous-batching serving engine over a block-paged KV pool.
 
 Reference frame: DeepSpeed-Inference (arXiv:2207.00032) wins at-scale
 transformer serving at the scheduling/KV-cache layer, not the kernel
 layer; on TPU the extra constraint is that decode SHAPES must never
 change across requests (every new shape is an XLA recompile). The
-engine therefore owns a fixed pool of ``num_slots`` preallocated cache
-rows (``[num_slots, heads, head_dim, cache_len]`` per layer, K^T
-layout) and drives exactly TWO compiled programs:
+engine therefore decodes a fixed batch of ``num_slots`` rows whose K/V
+lives in a global page pool with one page table per slot
+(serving/paging/, the ``serving.paging`` block) and drives exactly TWO
+compiled programs (serving/paging/manager.py):
 
-- ``_admit``: prefill one request (padded to a fixed length bucket)
-  through a single-row scratch cache, scatter the row into its slot,
-  sample its first token — one jit specialization per bucket;
-- ``_decode_iter``: ONE masked single-token decode step over the full
-  slot batch — per-slot lengths (per-row cache_index,
-  models/layers.py), per-slot positions, per-slot eos/budget
-  completion. Compiles once, ever.
+- ``serving/chunk_prefill``: prefill one page-aligned chunk of one
+  request into its slot's pages; the last chunk samples the first
+  token — one jit specialization per chunk width;
+- ``serving/paged_decode``: ONE masked single-token decode step over
+  the full slot batch — per-slot lengths, per-slot positions, per-slot
+  eos/budget completion. Compiles once, ever.
 
-Requests queue host-side (scheduler.py) and are admitted into free
-slots BETWEEN decode steps; finished slots recycle immediately. Token
-readback is pipelined: the host reads step k's tokens while the device
-runs step k+1 (``pipeline_depth``), so streaming never serializes
-device and host. Metrics derive from those already-read tokens plus
-host scheduler state — no extra per-step syncs (PR-2 rule).
-
-Paged mode (``serving.paging`` block, serving/paging/): the slot rows
-are replaced by a global page pool + per-slot page tables, admission
-gates on free PAGES instead of free slots, shared prompt prefixes are
-referenced copy-free from a radix cache, and long prompts prefill in
-page-aligned chunks interleaved between decode iterations. The slot
-API, the compile-once discipline (ONE paged decode program, one chunk
-prefill per chunk bucket), and token-exactness vs ``generate()`` are
-all preserved; with paging absent or disabled this module's original
-code paths run untouched — bit-identical to the pre-paging engine.
+Requests queue host-side (scheduler.py) and are admitted BETWEEN decode
+steps into a free slot once the pool has the PAGES to cover them;
+shared prompt prefixes are referenced copy-free from a radix cache,
+long prompts prefill chunk by chunk interleaved with decode, and
+finished slots recycle immediately. Token readback is pipelined: the
+host reads step k's tokens while the device runs step k+1
+(``pipeline_depth``), so streaming never serializes device and host.
+Metrics derive from those already-read tokens plus host scheduler
+state — no extra per-step syncs (PR-2 rule). Output is token-exact vs
+``generate()``.
 
 QoS mode (``serving.qos`` block, serving/qos.py): requests carry a
 ``priority``; a high-priority queue head past its class's
@@ -62,10 +56,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from ..inference.generation import (apply_decode, init_cache, _sample_impl,
-                                    _sampling_mode)
-from ..inference.cache import (cache_max_len, make_row_cache, set_cache_index,
-                               write_cache_row)
+from ..inference.generation import _sampling_mode
 from ..observability.goodput import get_ledger as _goodput_ledger
 from ..observability.goodput import timed as _goodput
 from ..observability.fleet import make_trace_id
@@ -80,7 +71,8 @@ from .qos import QosController
 from .request import PREEMPTED, Request
 from .scheduler import FifoScheduler
 from .metrics import ServingMetrics
-from .paging.manager import _chunk_prefill_jit, _paged_decode_jit
+from .paging.manager import (PagedKVManager, _chunk_prefill_jit,
+                             _paged_decode_jit)
 from .speculation import NgramProposer, _spec_verify_jit
 
 
@@ -90,89 +82,8 @@ def _counts_read(counts):
     return [] if counts is None else [counts]
 
 
-def _admit_impl(module, params, cache, state, prompt, prompt_len, slot,
-                max_new, rng, eos_id, t, k, p, param_transform,
-                greedy, has_k, has_p):
-    """Prefill ``prompt`` ([1, bucket_len], right-padded) through a fresh
-    single-row cache, scatter the row into ``slot``, sample the first
-    token, and activate the slot's metadata row. The pad tail's K/V is
-    garbage but sits at positions >= prompt_len, which the per-slot
-    length mask never reads and later decode tokens overwrite in order.
-    """
-    row = make_row_cache(cache)
-    positions = jnp.arange(prompt.shape[1])
-    p_ = param_transform(params) if param_transform is not None else params
-    logits, vars_out, counts = apply_decode(
-        module, {"params": p_, "cache": row}, prompt, positions,
-        lambda: (positions < prompt_len)[None], ["cache"])
-    row = vars_out["cache"]
-    last = jax.lax.dynamic_slice_in_dim(logits, prompt_len - 1, 1,
-                                        axis=1)[:, 0]            # [1, vocab]
-    tok = _sample_impl(last, rng, t, k, p, greedy, has_k, has_p)[0]
-    cache = write_cache_row(cache, row, slot)
-
-    remaining = max_new - 1
-    # eos_id is -1 when eos is disabled — sampled tokens are always >= 0,
-    # so the comparison stays False without a structure flag
-    done = (tok == eos_id) | (remaining <= 0)
-    state = {
-        "lengths": state["lengths"].at[slot].set(prompt_len),
-        "last_token": state["last_token"].at[slot].set(tok),
-        "active": state["active"].at[slot].set(~done),
-        "remaining": state["remaining"].at[slot].set(remaining),
-    }
-    return cache, state, tok, done, counts
-
-
-_admit_jit = track_program(
-    "serving/admit",
-    jax.jit(_admit_impl, static_argnums=(0, 13, 14, 15, 16),
-            donate_argnums=(2, 3)), subsystem="serving")
-
-
-def _decode_iter_impl(module, params, cache, state, rng, it, eos_id,
-                      t, k, p, param_transform, greedy, has_k, has_p):
-    """One masked decode step over the full slot batch.
-
-    Every slot — active or not — runs the same static-shape computation;
-    inactive slots write their garbage token at a clamped position inside
-    their own row (re-prefilled on the next admission) and their output
-    is masked to -1. Active slots append at their own length, attend over
-    their own valid prefix (per-row cache_index -> per-slot length mask
-    in the decode kernel), and complete on eos or an exhausted budget.
-    """
-    lengths = state["lengths"]
-    active = state["active"]
-    s_max = cache_max_len(cache)
-    idx_w = jnp.minimum(lengths, s_max - 1)
-    cache = set_cache_index(cache, idx_w)
-    p_ = param_transform(params) if param_transform is not None else params
-    logits, vars_out, counts = apply_decode(
-        module, {"params": p_, "cache": cache}, state["last_token"][:, None],
-        idx_w[:, None], lambda: active[:, None], ["cache"])
-    nxt = _sample_impl(logits[:, -1, :], jax.random.fold_in(rng, it),
-                       t, k, p, greedy, has_k, has_p)
-
-    remaining = jnp.where(active, state["remaining"] - 1, state["remaining"])
-    done = active & ((nxt == eos_id) | (remaining <= 0))
-    new_state = {
-        "lengths": jnp.where(active, lengths + 1, lengths),
-        "last_token": jnp.where(active, nxt, state["last_token"]),
-        "active": active & ~done,
-        "remaining": remaining,
-    }
-    out_tok = jnp.where(active, nxt, -1)
-    return vars_out["cache"], new_state, out_tok, done, counts
-
-
-_decode_iter_jit = track_program(
-    "serving/decode_iter",
-    jax.jit(_decode_iter_impl, static_argnums=(0, 10, 11, 12, 13),
-            donate_argnums=(2, 3)), subsystem="serving")
-
-
 class ServingEngine:
-    """Continuous-batching serving over a fixed slot pool.
+    """Continuous-batching serving over a fixed slot batch.
 
     Usage::
 
@@ -253,7 +164,9 @@ class ServingEngine:
                 f"model's max_seq_len {model_max}")
 
         n = self.config.num_slots
-        self._paged = None
+        # the manager owns the page pool, the allocator, the prefix cache
+        # and the page tables
+        self._paged = PagedKVManager(self.module, self.params, self.config)
         self._init_device_state()
         self._rng = rng if rng is not None else jax.random.PRNGKey(
             self.config.seed)
@@ -323,37 +236,16 @@ class ServingEngine:
         _goodput_ledger().start()
         self.telemetry = None             # live endpoint; start_telemetry()
         log_dist(f"serving engine: {n} slots x {self.config.cache_len} "
-                 f"tokens, prefill buckets {self.config.bucket_lengths()}",
-                 ranks=[0])
+                 "tokens", ranks=[0])
 
     def _init_device_state(self):
-        """(Re)build the device-side cache/pool and slot-state arrays.
-        Called at construction and from ``recover()`` — shapes are
-        identical both times, so every compiled program stays cached."""
+        """(Re)build the slot-state arrays and the prefill queue beside a
+        fresh page pool. Called at construction and, after the manager's
+        ``reset()``, from ``recover()`` — shapes are identical both
+        times, so every compiled program stays cached."""
         n = self.config.num_slots
-        if self.config.paged:
-            if self._paged is None:
-                # block-paged KV: the manager owns the page pool,
-                # allocator, prefix cache, and page tables; no contiguous
-                # slot rows exist
-                from .paging.manager import PagedKVManager
-                self._paged = PagedKVManager(self.module, self.params,
-                                             self.config)
-            else:
-                self._paged.reset()
-            self._cache = None
-            self._prefill_tasks = deque()   # (slot, req, prompt, max_new,
-                                            #  [chunk plans])
-        else:
-            self._paged = None
-            self._cache = init_cache(self.module, self.params, n,
-                                     self.config.cache_len)
-            # normalize cache_index to per-row form ([b]-shaped) up front:
-            # init_cache creates the scalar form, and a tree whose index
-            # shape flips after the first decode would cost every jit a
-            # second specialization (the "decode compiles once" contract)
-            self._cache = set_cache_index(self._cache,
-                                          jnp.zeros((n,), jnp.int32))
+        self._prefill_tasks = deque()   # (slot, req, prompt, max_new,
+                                        #  [chunk plans])
         self._state = {
             "lengths": jnp.zeros((n,), jnp.int32),
             "last_token": jnp.zeros((n,), jnp.int32),
@@ -421,16 +313,12 @@ class ServingEngine:
         own leaf shapes (the figure the PR-6 artifact hand-computed)."""
         acct = get_accountant()
         acct.account("serving/params", self.params)
-        if self._paged is not None:
-            acct.account("serving/kv_pool",
-                         num_bytes=self._paged.pool_bytes(),
-                         name="page_pool")
-            acct.account("serving/kv_pool", self._paged.page_table,
-                         name="page_table")
-            transient = self._paged.decode_gather_transient_bytes()
-            acct.registry.gauge("mem/decode_gather_transient").set(transient)
-        else:
-            acct.account("serving/kv_pool", self._cache, name="slot_cache")
+        acct.account("serving/kv_pool", num_bytes=self._paged.pool_bytes(),
+                     name="page_pool")
+        acct.account("serving/kv_pool", self._paged.page_table,
+                     name="page_table")
+        acct.registry.gauge("mem/decode_gather_transient").set(
+            self._paged.decode_gather_transient_bytes())
         acct.account("serving/state", self._state)
         acct.registry.gauge("mem/kv_pool_resident").set(
             acct.subsystem_bytes("serving/kv_pool"))
@@ -445,22 +333,20 @@ class ServingEngine:
         the paged-attention kernel path (no gather exists to charge)."""
         from ..module_inject.module_quantize import quantized_nbytes
         acct = get_accountant()
-        out = {
+        return {
             "by_subsystem": {
                 tag: info["bytes"]
                 for tag, info in acct.report()["by_subsystem"].items()
                 if tag.startswith("serving/")},
             "kv_pool_resident_bytes": acct.subsystem_bytes("serving/kv_pool"),
             "params_bytes": quantized_nbytes(self.params),
-        }
-        if self._paged is not None:
-            out["decode_gather_transient_bytes"] = \
-                self._paged.decode_gather_transient_bytes()
-            out["kv_page_dtype"] = (
+            "decode_gather_transient_bytes":
+                self._paged.decode_gather_transient_bytes(),
+            "kv_page_dtype": (
                 "int8" if self._paged.kv_quant
-                else jnp.dtype(self._paged.dequant_dtype).name)
-            out["paged_kernel"] = self._paged.use_kernel
-        return out
+                else jnp.dtype(self._paged.dequant_dtype).name),
+            "paged_kernel": self._paged.use_kernel,
+        }
 
     def close(self):
         """Release this engine's accountant attribution (the serving
@@ -480,8 +366,7 @@ class ServingEngine:
         for tag in ("serving/params", "serving/kv_pool", "serving/state"):
             acct.discard(tag)
         acct.registry.gauge("mem/kv_pool_resident").set(0)
-        if self._paged is not None:
-            acct.registry.gauge("mem/decode_gather_transient").set(0)
+        acct.registry.gauge("mem/decode_gather_transient").set(0)
 
     # -- live telemetry ----------------------------------------------------
     def metrics_snapshot(self) -> dict:
@@ -610,13 +495,12 @@ class ServingEngine:
                     "active": self._state["active"].at[slot].set(False),
                     "remaining": self._state["remaining"].at[slot].set(0),
                 }
-                if self._paged is not None:
-                    # drop any unfinished prefill chunks and return the
-                    # slot's page references (prefix-published pages stay
-                    # alive through the tree's own reference)
-                    self._prefill_tasks = deque(
-                        t for t in self._prefill_tasks if t[0] != slot)
-                    self._paged.release_slot(slot)
+                # drop any unfinished prefill chunks and return the
+                # slot's page references (prefix-published pages stay
+                # alive through the tree's own reference)
+                self._prefill_tasks = deque(
+                    t for t in self._prefill_tasks if t[0] != slot)
+                self._paged.release_slot(slot)
                 self._slot_req[slot] = None
                 self._free.append(slot)
                 req._cancelled(self._iteration)
@@ -665,9 +549,9 @@ class ServingEngine:
     def advance(self):
         """One engine iteration: run any pending fault recovery, evaluate
         the QoS ladder, expire overdue queued requests, admit into free
-        slots (preempting lower classes for an at-risk high-priority head;
-        paged mode: reserve pages + run at most ``max_chunks_per_iter``
-        prefill chunks), dispatch one decode over the slot batch, harvest
+        slots (reserving their pages; preempting lower classes for an
+        at-risk high-priority head), run at most ``max_chunks_per_iter``
+        prefill chunks, dispatch one decode over the slot batch, harvest
         readbacks beyond the pipeline depth. Safe to call when idle
         (no-op).
 
@@ -693,14 +577,13 @@ class ServingEngine:
             self._qos_tick()
         self._expire_queued()
         # the watchdog covers everything that can block on the device:
-        # admit/prefill dispatches, the decode dispatch, and readbacks
+        # prefill dispatches, the decode dispatch, and readbacks
         if self._watchdog is not None:
             self._watchdog.step_started()
         try:
             with self._trace_scope():
                 self._admit()
-                if self._paged is not None:
-                    self._run_prefill_chunks()
+                self._run_prefill_chunks()
                 if self.prefill_only:
                     # prefill role: no decode ever dispatches (the decode
                     # replica owns generation past token 1), but the
@@ -724,8 +607,7 @@ class ServingEngine:
             self.metrics.sample(
                 self.scheduler.depth, busy, self.config.num_slots,
                 self._iteration,
-                paged=(self._paged.stats()
-                       if self._paged is not None else None),
+                paged=self._paged.stats(),
                 qos_level=(self._qos.level
                            if self._qos is not None else None),
                 slot_cap=self._slot_cap)
@@ -737,15 +619,11 @@ class ServingEngine:
         plus the queued-request shed sweep the current level implies.
         Inputs are host scheduler state and step-denominated percentiles
         only — decisions replay bit-exactly for a replayed trace."""
-        free_frac = None
-        if self._paged is not None:
-            stats = self._paged.stats()
-            free_frac = 1.0 - stats["page_utilization"]
         self._qos.observe(
             iteration=self._iteration,
             queue_depth=self.scheduler.depth,
             ttft_p95_steps=self.metrics.ttft_under_load_p95(),
-            free_frac=free_frac)
+            free_frac=1.0 - self._paged.stats()["page_utilization"])
         pred = self._qos.queued_shed_predicate()
         if pred is not None:
             for req in self.scheduler.shed_queued(pred):
@@ -822,8 +700,8 @@ class ServingEngine:
     def _try_preempt_for(self, head: Request, need: str = "slot") -> bool:
         """Free capacity for an at-risk high-priority queue head by
         preempting the lowest-priority active request back to the queue.
-        ``need`` names the starved resource — ``"slot"`` (contiguous
-        engine / no free slot) or ``"pages"`` (paged admission failed) —
+        ``need`` names the starved resource — ``"slot"`` (no free
+        slot) or ``"pages"`` (the pool cannot cover the head) —
         so the retry signal matches what admission actually checks: a
         free slot alone never un-starves a page-starved head. Returns
         True when admission should be retried. Deterministic: runs on
@@ -882,10 +760,9 @@ class ServingEngine:
             "active": self._state["active"].at[slot].set(False),
             "remaining": self._state["remaining"].at[slot].set(0),
         }
-        if self._paged is not None:
-            self._prefill_tasks = deque(
-                t for t in self._prefill_tasks if t[0] != slot)
-            self._paged.release_slot(slot)
+        self._prefill_tasks = deque(
+            t for t in self._prefill_tasks if t[0] != slot)
+        self._paged.release_slot(slot)
         self._slot_req[slot] = None
         self._free.append(slot)
         # close this RUNNING period's residency span now: resumption
@@ -901,15 +778,16 @@ class ServingEngine:
 
     def _admit(self):
         """This iteration's admissions, under one ``serving/admission``
-        span: queue peeks, slot and page reservation, chunk planning
-        (and, contiguous mode, the ``serving/admit`` dispatches)."""
+        span: queue peeks, slot and page reservation, chunk planning.
+        Admission gates on free PAGES, not free slots: a page-starved
+        queue head stays queued (class order preserved) until running
+        requests release pages, the prefix cache evicts, or — with QoS
+        on — an at-risk high-priority head preempts a lower class's
+        pages free."""
         args = {"queue_depth": self.scheduler.depth, "admitted": 0}
         before = self.metrics.requests_admitted
         with _span("serving/admission", args):
-            if self._paged is not None:
-                self._admit_ready_paged()
-            else:
-                self._admit_ready()
+            self._admit_ready()
             args["admitted"] = self.metrics.requests_admitted - before
 
     def _admit_ready(self):
@@ -922,62 +800,8 @@ class ServingEngine:
                 if self._try_preempt_for(req):
                     continue        # a slot (or a completion) freed up
                 return
-            self.scheduler.next_request()   # actually pop the head
-            self._take_slot(slot)
             # resumption re-prefills prompt + retained partial output;
             # for a fresh request these are just prompt / max_new_tokens
-            prompt = req.effective_prompt()
-            max_new = req.remaining_budget()
-            resumed = req.status == PREEMPTED
-            n = prompt.shape[0]
-            bucket = self.config.bucket_for(n)
-            padded = np.zeros((1, bucket), np.int32)
-            padded[0, :n] = prompt
-            greedy, has_k, has_p, t, k, p = self._mode
-            rng = self._req_rng(req)
-            self._record_queue_wait(req)
-            # request_id + trace_id in the span args: a trace capture
-            # (or the fleet stitcher) can rebuild per-request latency
-            # (queue wait -> admit -> decode iterations -> harvest)
-            try:
-                with _span("serving/admit", {"request_id": req.request_id,
-                                             "trace_id": req.trace_id,
-                                             "prompt_len": n}), \
-                        _goodput("compute"):
-                    self._cache, self._state, tok, done, counts = _admit_jit(
-                        self.module, self.params, self._cache, self._state,
-                        jnp.asarray(padded), jnp.int32(n), jnp.int32(slot),
-                        jnp.int32(max_new), rng, self._eos, t, k, p,
-                        self._param_transform, greedy, has_k, has_p)
-            except Exception as e:
-                if not is_oom_error(e):
-                    raise
-                self._shed_on_oom(req, "admit", e)
-                return
-            self._slot_req[slot] = req
-            req._admitted(slot, self._iteration)
-            self.metrics.on_admit(req)
-            if resumed:
-                self.metrics.on_resume(req)
-            self._pending.append(("admit", slot, req, tok, done,
-                                  _counts_read(counts)))
-
-    # -- paged admission + chunked prefill ---------------------------------
-    def _admit_ready_paged(self):
-        """Admit queued requests while pages cover them. Admission gates
-        on free PAGES, not free slots: a page-starved queue head stays
-        queued (class order preserved) until running requests release
-        pages, the prefix cache evicts, or — with QoS on — an at-risk
-        high-priority head preempts a lower class's pages free."""
-        while True:
-            req = self.scheduler.peek()
-            if req is None:
-                return
-            slot = self._peek_free_slot()
-            if slot is None:
-                if self._try_preempt_for(req):
-                    continue
-                return
             prompt = req.effective_prompt()
             max_new = req.remaining_budget()
             shared = self._paged.try_admit(slot, prompt, max_new)
@@ -1041,10 +865,10 @@ class ServingEngine:
     def _dispatch_chunk(self, slot: int, req, prompt, max_new: int,
                         start: int, width: int, is_last: bool) -> bool:
         """Prefill one page-aligned chunk of one request. Mid-chunks only
-        fill pages; the LAST chunk also samples the first token (pipelined
-        like a contiguous admit) and publishes the prompt's full pages to
-        the prefix cache. Same program either way — ``is_last`` is a
-        traced flag, not a jit specialization. Returns False when a
+        fill pages; the LAST chunk also samples the first token (read
+        back pipelined, like a decode's) and publishes the prompt's full
+        pages to the prefix cache. Same program either way — ``is_last``
+        is a traced flag, not a jit specialization. Returns False when a
         RESOURCE_EXHAUSTED was contained (the caller must stop driving
         the now-reset prefill queue)."""
         p_len = int(prompt.shape[0])
@@ -1094,10 +918,8 @@ class ServingEngine:
 
     def _decoding_slots(self, busy: int) -> int:
         """Of ``busy`` held slots, those a decode dispatch advances: a
-        paged slot whose prefill chunks are still queued rides the batch
+        slot whose prefill chunks are still queued rides the batch
         masked."""
-        if self._paged is None:
-            return busy
         return busy - len(self._prefill_tasks)
 
     def _dispatch_decode(self) -> bool:
@@ -1118,18 +940,12 @@ class ServingEngine:
         with _span("serving/decode_iter", {"active_requests": busy,
                                            "iteration": self._iteration}), \
                 _goodput("compute"):
-            if self._paged is not None:
-                mgr = self._paged
-                mgr.pool, self._state, toks, done, counts = _paged_decode_jit(
-                    self.module, self.params, mgr.pool, mgr.page_table,
-                    self._state, rng, jnp.int32(self._iteration),
-                    self._eos, t, k, p, self._param_transform, greedy,
-                    has_k, has_p, mgr.use_kernel, mgr.dequant_dtype)
-            else:
-                self._cache, self._state, toks, done, counts = _decode_iter_jit(
-                    self.module, self.params, self._cache, self._state,
-                    rng, jnp.int32(self._iteration), self._eos, t, k, p,
-                    self._param_transform, greedy, has_k, has_p)
+            mgr = self._paged
+            mgr.pool, self._state, toks, done, counts = _paged_decode_jit(
+                self.module, self.params, mgr.pool, mgr.page_table,
+                self._state, rng, jnp.int32(self._iteration),
+                self._eos, t, k, p, self._param_transform, greedy,
+                has_k, has_p, mgr.use_kernel, mgr.dequant_dtype)
         self.metrics.on_decode_dispatch(self._decoding_slots(busy),
                                         self.config.num_slots)
         self._pending.append(("decode", snapshot, toks, done,
@@ -1202,20 +1018,13 @@ class ServingEngine:
                    {"active_requests": busy, "iteration": self._iteration,
                     "proposed_tokens": int(counts.sum())}), \
                 _goodput("compute"):
-            if self._paged is not None:
-                mgr = self._paged
-                mgr.pool, self._state, toks, done = _spec_verify_jit(
-                    self.module, self.params, mgr.pool, mgr.page_table,
-                    self._state, jnp.asarray(props), jnp.asarray(counts),
-                    rng, jnp.int32(self._iteration), self._eos, t, k, p,
-                    self._param_transform, greedy, has_k, has_p,
-                    mgr.dequant_dtype)
-            else:
-                self._cache, self._state, toks, done = _spec_verify_jit(
-                    self.module, self.params, self._cache, None,
-                    self._state, jnp.asarray(props), jnp.asarray(counts),
-                    rng, jnp.int32(self._iteration), self._eos, t, k, p,
-                    self._param_transform, greedy, has_k, has_p, None)
+            mgr = self._paged
+            mgr.pool, self._state, toks, done = _spec_verify_jit(
+                self.module, self.params, mgr.pool, mgr.page_table,
+                self._state, jnp.asarray(props), jnp.asarray(counts),
+                rng, jnp.int32(self._iteration), self._eos, t, k, p,
+                self._param_transform, greedy, has_k, has_p,
+                mgr.dequant_dtype)
         self.metrics.on_decode_dispatch(self._decoding_slots(busy),
                                         self.config.num_slots)
         self._pending.append(("spec", snapshot, toks, done, counts))
@@ -1299,7 +1108,7 @@ class ServingEngine:
             _, snapshot, toks, done, counts = entry
             toks, done, *counts = self._read_back(toks, done, *counts)
             self._fold_moe_counts(counts)
-            if self._paged is not None and self._paged.use_kernel:
+            if self._paged.use_kernel:
                 self.metrics.on_decode_harvest(np.count_nonzero(toks >= 0))
             for slot, req in enumerate(snapshot):
                 if req is None or req.done:  # empty, or cancelled in flight
@@ -1322,10 +1131,9 @@ class ServingEngine:
         self._record_residency(req)
         req._finished(self._iteration)
         self.metrics.on_finish(req)
-        if self._paged is not None:
-            # return the slot's page references; prefix-published pages
-            # survive through the radix tree's own refcount
-            self._paged.release_slot(slot)
+        # return the slot's page references; prefix-published pages
+        # survive through the radix tree's own refcount
+        self._paged.release_slot(slot)
         self._slot_req[slot] = None
         self._free.append(slot)
 
@@ -1336,7 +1144,7 @@ class ServingEngine:
         attributed-buffer view, not a bare error string), shed the
         offending request with explicit status, and rebuild the device
         state via ``recover()`` so the engine keeps serving everyone
-        else. The jitted admit/prefill programs donate their cache/pool
+        else. The jitted prefill program donates its pool and state
         operands, so after a failed call those buffers cannot be trusted
         — a full device-state rebuild is the only safe continuation."""
         report = oom_forensics(
@@ -1360,13 +1168,13 @@ class ServingEngine:
 
         Drops in-flight readbacks (their tokens were never streamed, so
         re-prefill regenerates them exactly), rebuilds the device-side
-        cache/pool/state from scratch (same shapes: every compiled
+        pool and state from scratch (same shapes: every compiled
         program stays cached), and pushes every live admitted request
         back to the queue in original arrival order with its generated
         tokens retained. Queued requests are untouched. The next
         ``advance()`` re-admits and re-prefills prompt + partial output —
         token-exact under greedy sampling, page-granular prefix-cache
-        hits making the recompute cheap on the paged engine."""
+        hits making the recompute cheap."""
         self._pending.clear()
         self._handoff_ready.clear()   # staged slots are requeued below —
                                       # their page contents are stale
@@ -1375,6 +1183,7 @@ class ServingEngine:
         n = self.config.num_slots
         self._slot_req = [None] * n
         self._free = deque(range(n))
+        self._paged.reset()
         self._init_device_state()
         # requeue_front in reverse arrival order: the earliest-submitted
         # victim ends up at its class head, restoring FIFO-within-class
@@ -1424,13 +1233,7 @@ class ServingEngine:
         prefill role: admissions and chunked prefill run normally, the
         decode program never dispatches, and every prefilled request
         stages in ``take_handoff_ready()`` for a page-granular KV
-        transfer to a decode replica. Paged engines only — the handoff
-        IS a page transfer."""
-        if on and self._paged is None:
-            raise ValueError(
-                "prefill role (disaggregated fleet) requires the "
-                "block-paged KV cache (serving.paging) — the handoff is "
-                "a page transfer, not a cache copy")
+        transfer to a decode replica."""
         self.prefill_only = bool(on)
 
     def take_handoff_ready(self):
@@ -1448,8 +1251,6 @@ class ServingEngine:
         pages' contents, the page-table run length, and the request +
         sampler state a decode replica needs to continue token-exactly.
         Frees the slot — the pages travel as values, not references."""
-        if self._paged is None:
-            raise ValueError("export_handoff requires the paged engine")
         from .fleet.handoff import HANDOFF_VERSION
         # what was prefilled = the effective prompt at admission; tokens
         # holds exactly one post-prefill sample (the handoff fires at
@@ -1498,8 +1299,6 @@ class ServingEngine:
         later step. Token-exact under greedy sampling: decode continues
         from the transferred KV + last token exactly as the prefilling
         engine would have."""
-        if self._paged is None:
-            raise ValueError("inject_handoff requires the paged engine")
         from .fleet.handoff import COMPAT_HANDOFF_VERSIONS
         if payload.get("version") not in COMPAT_HANDOFF_VERSIONS:
             raise ValueError(

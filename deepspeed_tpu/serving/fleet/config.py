@@ -55,7 +55,7 @@ class FleetConfig:
     disaggregate: bool = False       # split roles: prefill replicas run
                                      # chunked prefill + first token then
                                      # hand page-granular KV to decode
-                                     # replicas (requires serving.paging)
+                                     # replicas
     prefill_replicas: int = 1        # leading replicas that take the
                                      # prefill role when disaggregated
     health_every_steps: int = 8      # fleet steps between health sweeps
@@ -150,7 +150,7 @@ class FleetConfig:
             from deepspeed_tpu.observability.slo import SloConfig
             self.slo = SloConfig(**self.slo)
 
-    def validate(self, serving_config=None) -> "FleetConfig":
+    def validate(self) -> "FleetConfig":
         if self.replicas < 1:
             raise ValueError(
                 f"serving.fleet.replicas must be >= 1, got {self.replicas}")
@@ -181,11 +181,6 @@ class FleetConfig:
                     f"serving.fleet.prefill_replicas must satisfy 1 <= n "
                     f"< replicas ({self.replicas}), got "
                     f"{self.prefill_replicas}")
-            if serving_config is not None and not serving_config.paged:
-                raise ValueError(
-                    "serving.fleet.disaggregate requires the block-paged "
-                    "KV cache (serving.paging) — the prefill->decode "
-                    "handoff is a page transfer")
         if self.health_every_steps < 1:
             raise ValueError(
                 "serving.fleet.health_every_steps must be >= 1, got "

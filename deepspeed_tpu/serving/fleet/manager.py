@@ -192,9 +192,7 @@ class ServingFleet:
         self.weights_version = 0    # bumped when a rolling update lands
         self.rolling_updates = 0    # completed updates
         self.rolling_swaps = 0      # individual replicas swapped
-        page_len = (self.config.paging.page_len if self.config.paged
-                    else self.config.prefill_bucket)
-        self.router = Router(self.fcfg, page_len)
+        self.router = Router(self.fcfg, self.config.paging.page_len)
         self._replicas: Dict[int, object] = {}
         self._next_rid = 0
         self._failed = set()            # rids whose failover already ran

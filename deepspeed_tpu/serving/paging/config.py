@@ -24,14 +24,13 @@ class PagingConfig:
     page: unowned page-table entries point at it and masked/inactive
     writes land there, so scatters never need a branch.
     """
-    enabled: bool = True
     page_len: int = 128              # tokens per page (128 = the Pallas
                                      # tiling quantum; smaller only for
                                      # CPU-backend tests)
     num_pages: Optional[int] = None  # pool size INCLUDING the null page;
                                      # None = num_slots * (cache_len /
-                                     # page_len) + 1 (memory parity with
-                                     # the contiguous slot pool)
+                                     # page_len) + 1 (every slot can hold
+                                     # a full-length request at once)
     enable_prefix_cache: bool = True  # radix-tree sharing of full prompt-
                                       # prefix pages (system prompts)
     prefill_chunk: Optional[int] = None  # tokens prefilled per engine

@@ -1,10 +1,9 @@
 """Paged-KV manager: the device page pool + host page-table ownership.
 
-This is the memory model swap under the live engine. Instead of one
-``[num_slots, h, d, cache_len]`` row per slot, every layer's K/V lives
+This is the serving engine's memory model. Every layer's K/V lives
 in a global pool ``[num_pages, h, d, page_len]`` and each slot holds a
-dense int32 page table ``[num_slots, max_pages]``. HBM now scales with
-*realized* context (pages actually allocated) instead of
+dense int32 page table ``[num_slots, max_pages]``. HBM scales with
+*realized* context (pages actually allocated), not with
 ``num_slots * max_len`` — the density lever DeepSpeed-Inference
 (arXiv:2207.00032) attributes serving-at-scale wins to, applied under
 the TPU compile-once discipline:
@@ -91,8 +90,11 @@ def _chunk_tree_from_cache(cache, start, chunk):
 def _paged_decode_iter_impl(module, params, pool, page_table, state, rng, it,
                             eos_id, t, k, p, param_transform, greedy, has_k,
                             has_p, use_kernel=False, dequant_dtype=None):
-    """One masked decode step over the full slot batch, paged twin of
-    engine._decode_iter_impl.
+    """One masked decode step over the full slot batch: every slot,
+    active or not, runs the same static-shape computation; an active
+    slot appends at its own length, attends over its own valid prefix
+    and completes on eos or an exhausted budget, an inactive slot's
+    output is masked to -1.
 
     ``use_kernel`` (static — one compiled program per engine either
     way): the paged-attention kernel consumes the pool + page table IN
@@ -249,14 +251,7 @@ class PagedKVManager:
         self._num_slots = config.num_slots
         self.kv_quant = "int8" if config.kv_int8 else None
         self.use_kernel = self._resolve_kernel(pcfg.kernel)
-        self.pool = self._build_pool()
-        self.allocator = PageAllocator(self.num_pages)
-        self.prefix = (PrefixCache(self.page_len, self.allocator)
-                       if pcfg.enable_prefix_cache else None)
-        self.page_table = jnp.full((config.num_slots, self.max_pages),
-                                   NULL_PAGE, jnp.int32)
-        self._slot_pages: List[Optional[List[int]]] = \
-            [None] * config.num_slots
+        self.reset()
         log_dist(
             f"paged KV: {self.num_pages - 1} usable pages x "
             f"{self.page_len} tokens "
@@ -430,19 +425,20 @@ class PagedKVManager:
         return True
 
     def reset(self):
-        """Rebuild the device pool and every host-side ownership structure
-        from scratch — the fault-containment path (engine.recover): after
-        a RESOURCE_EXHAUSTED mid-admit the donated pool buffers may be
-        invalid, and after a requeue-and-re-prefill recovery every page's
-        contents are stale anyway. Shapes are unchanged, so the compiled
-        paged programs stay cached."""
+        """(Re)build the device pool and every host-side ownership structure
+        from scratch — at construction, and on the fault-containment path
+        (engine.recover): after a RESOURCE_EXHAUSTED mid-admit the donated
+        pool buffers may be invalid, and after a requeue-and-re-prefill
+        recovery every page's contents are stale anyway. Shapes are
+        unchanged, so the compiled paged programs stay cached."""
         self.pool = self._build_pool()
         self.allocator = PageAllocator(self.num_pages)
         self.prefix = (PrefixCache(self.page_len, self.allocator)
                        if self.config.enable_prefix_cache else None)
         self.page_table = jnp.full((self._num_slots, self.max_pages),
                                    NULL_PAGE, jnp.int32)
-        self._slot_pages = [None] * self._num_slots
+        self._slot_pages: List[Optional[List[int]]] = \
+            [None] * self._num_slots
 
     # -- accounting --------------------------------------------------------
     def pool_bytes(self) -> int:

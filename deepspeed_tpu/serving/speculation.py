@@ -28,13 +28,13 @@ Rollback is length-granular, alloc-free, and page-safe by
 construction: the verification step writes all K+1 candidate K/V
 entries at each slot's frontier, and acceptance simply decides how far
 ``lengths`` advances. Rejected entries sit PAST the new frontier —
-exactly the admit pad-tail convention — where the per-slot length mask
-never reads them and later steps overwrite them in order. On the paged
-engine every write lands inside the slot's admission-time page budget
-(or the null-page garbage sink past it), so speculation never
-allocates, frees, or leaks a page and the allocator ``check()``
-invariant holds after every rollback. Proposal-free iterations ride
-the existing ``_decode_iter``/``_paged_decode`` programs untouched.
+exactly the prefill chunk's pad-tail convention — where the per-slot
+length mask never reads them and later steps overwrite them in order.
+Every write lands inside the slot's admission-time page budget (or the
+null-page garbage sink past it), so speculation never allocates, frees,
+or leaks a page and the allocator ``check()`` invariant holds after
+every rollback. Proposal-free iterations ride the existing
+``serving/paged_decode`` program untouched.
 
 Greedy-only by construction (config.validate refuses otherwise): the
 acceptance rule IS greedy argmax — speculating under a sampling engine
@@ -45,9 +45,9 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from ..inference.cache import (cache_max_len, cache_page_len,
-                               extract_token_kv, gather_pages,
-                               scatter_token_pages, set_cache_index)
+from ..inference.cache import (cache_page_len, extract_token_kv,
+                               gather_pages, scatter_token_pages,
+                               set_cache_index)
 from ..inference.generation import _sample_impl
 from ..observability.programs import track_program
 from .paging.allocator import NULL_PAGE
@@ -89,29 +89,34 @@ class NgramProposer:
         return np.zeros((0,), np.int32)
 
 
-def _spec_verify_impl(module, params, kv, page_table, state, proposals,
+def _spec_verify_impl(module, params, pool, page_table, state, proposals,
                       counts, rng, it, eos_id, t, k, p, param_transform,
                       greedy, has_k, has_p, dequant_dtype=None):
     """One batched speculative verification step over the full slot
-    batch — the multi-token sibling of ``engine._decode_iter_impl`` /
+    batch — the multi-token sibling of
     ``paging.manager._paged_decode_iter_impl``, and the ONLY program
-    speculation adds.
+    speculation adds; it compiles exactly once per engine shape.
 
     ``proposals`` is ``[slots, K]`` int32 (K = ``max_spec_tokens``, a
     fixed shape — the QoS budget shrinks ``counts``, never the shape),
     ``counts`` the per-slot valid-proposal count (0 = slot rides along
-    masked). ``kv`` is the contiguous slot cache when ``page_table`` is
-    None, else the page pool — one registered program either way; the
-    None-vs-array pytree structure keys one specialization per engine
-    mode, and within a mode the program compiles exactly once.
+    masked).
 
-    Per slot: run ``[last_token, p_1 .. p_K]`` through one decode step
-    at the slot's own frontier (per-row multi-token cache_index path),
-    take the greedy argmax chain ``nxt``, accept the longest proposal
-    prefix matching it, and emit ``e = min(accepted + 1, first eos,
-    remaining budget)`` tokens. Rejected candidate K/V stays past the
-    advanced frontier (garbage by the admit pad-tail convention) — the
-    rollback is "don't advance ``lengths``", never an alloc or free.
+    Per slot: gather the contiguous view (the kernel path is
+    single-token-only — verification always gathers), run
+    ``[last_token, p_1 .. p_K]`` through one decode step at the slot's
+    own frontier (per-row multi-token cache_index path; the cache
+    headroom — ``config.cache_len`` pads ``max_len`` by
+    ``max_spec_tokens`` — guarantees an ACTIVE slot's K+1-token window
+    never clamps), take the greedy argmax chain ``nxt``, accept the
+    longest proposal prefix matching it, and emit ``e = min(accepted +
+    1, first eos, remaining budget)`` tokens. The K+1 K/V entries are
+    scattered back position by position; writes past a slot's allocated
+    budget hit NULL_PAGE table entries — the garbage sink — so
+    speculation never touches a page it doesn't own. Rejected candidate
+    K/V stays past the advanced frontier (garbage by the prefill
+    chunk's pad-tail convention) — the rollback is "don't advance
+    ``lengths``", never an alloc or free.
     """
     lengths = state["lengths"]
     active = state["active"]
@@ -120,50 +125,29 @@ def _spec_verify_impl(module, params, kv, page_table, state, proposals,
     inp = jnp.concatenate([state["last_token"][:, None], proposals], axis=1)
 
     p_ = param_transform(params) if param_transform is not None else params
-    if page_table is None:
-        # contiguous slot rows: the cache headroom (config.cache_len
-        # pads max_len by max_spec_tokens) guarantees an ACTIVE slot's
-        # K+1-token window never clamps; inactive rows may clamp into
-        # their own stale garbage, which admission re-prefills wholesale
-        s_max = cache_max_len(kv)
-        idx_w = jnp.minimum(lengths, s_max - s)
-        cache = set_cache_index(kv, idx_w)
-        positions = idx_w[:, None] + jnp.arange(s)[None, :]
-        logits, vars_out = module.apply(
-            {"params": p_, "cache": cache}, inp, decode=True,
-            positions=positions, mutable=["cache"])
-        kv_out = vars_out["cache"]
-    else:
-        # paged: gather the contiguous view (the kernel path is
-        # single-token-only — verification always gathers), run the
-        # same per-row multi-token step, then scatter the K+1 K/V
-        # entries back position-by-position. Writes past a slot's
-        # allocated budget hit NULL_PAGE table entries — the garbage
-        # sink — so speculation never touches a page it doesn't own.
-        page_len = cache_page_len(kv)
-        s_max = page_len * page_table.shape[1]
-        idx_w = jnp.minimum(lengths, s_max - s)
-        cache = gather_pages(kv, page_table, dequant_dtype=dequant_dtype)
-        cache = set_cache_index(cache, idx_w)
-        positions = idx_w[:, None] + jnp.arange(s)[None, :]
-        logits, vars_out = module.apply(
-            {"params": p_, "cache": cache}, inp, decode=True,
-            positions=positions, mutable=["cache", "kv_token"])
-        tok = vars_out.get("kv_token")
-        has_tok = tok is not None and len(jax.tree.leaves(tok)) > 0
-        kv_out = kv
-        for i in range(s):
-            if has_tok:
-                tok_i = jax.tree.map(
-                    lambda leaf: jax.lax.slice_in_dim(
-                        leaf, i, i + 1, axis=-1), tok)
-            else:
-                tok_i = extract_token_kv(vars_out["cache"], idx_w + i)
-            pos = idx_w + i
-            phys = jnp.take_along_axis(page_table, (pos // page_len)[:, None],
-                                       axis=1)[:, 0]
-            phys = jnp.where(active, phys, NULL_PAGE)
-            kv_out = scatter_token_pages(kv_out, tok_i, phys, pos % page_len)
+    page_len = cache_page_len(pool)
+    s_max = page_len * page_table.shape[1]
+    idx_w = jnp.minimum(lengths, s_max - s)
+    cache = gather_pages(pool, page_table, dequant_dtype=dequant_dtype)
+    cache = set_cache_index(cache, idx_w)
+    positions = idx_w[:, None] + jnp.arange(s)[None, :]
+    logits, vars_out = module.apply(
+        {"params": p_, "cache": cache}, inp, decode=True,
+        positions=positions, mutable=["cache", "kv_token"])
+    tok = vars_out.get("kv_token")
+    has_tok = tok is not None and len(jax.tree.leaves(tok)) > 0
+    for i in range(s):
+        if has_tok:
+            tok_i = jax.tree.map(
+                lambda leaf: jax.lax.slice_in_dim(
+                    leaf, i, i + 1, axis=-1), tok)
+        else:
+            tok_i = extract_token_kv(vars_out["cache"], idx_w + i)
+        pos = idx_w + i
+        phys = jnp.take_along_axis(page_table, (pos // page_len)[:, None],
+                                   axis=1)[:, 0]
+        phys = jnp.where(active, phys, NULL_PAGE)
+        pool = scatter_token_pages(pool, tok_i, phys, pos % page_len)
 
     # greedy chain: nxt[:, i] is the argmax given last_token + the first
     # i proposals — when those proposals all match the chain, it IS the
@@ -196,7 +180,7 @@ def _spec_verify_impl(module, params, kv, page_table, state, proposals,
         "remaining": remaining,
     }
     out_toks = jnp.where(active[:, None] & (pos_s < e[:, None]), nxt, -1)
-    return kv_out, new_state, out_toks, done
+    return pool, new_state, out_toks, done
 
 
 _spec_verify_jit = track_program(

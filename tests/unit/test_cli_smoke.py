@@ -98,8 +98,8 @@ def test_serve_synthetic_demo(tmp_path):
     """End-to-end serving CLI: tiny synthetic workload, metrics JSON."""
     out = tmp_path / "metrics.json"
     r = _run([os.path.join(BIN, "ds_tpu_serve"), "--synthetic", "3",
-              "--num-slots", "2", "--max-len", "48", "--prefill-bucket",
-              "16", "--max-new-tokens", "3", "--d-model", "32",
+              "--num-slots", "2", "--max-len", "48",
+              "--max-new-tokens", "3", "--d-model", "32",
               "--n-layers", "1", "--vocab-size", "64", "--quiet",
               "--metrics-out", str(out)], timeout=300)
     assert r.returncode == 0, r.stderr[-800:]
@@ -115,8 +115,8 @@ def test_serve_metrics_port_endpoint(tmp_path):
     scraped in-process by test_telemetry.py — a subprocess race against
     a 3-request run would flake)."""
     r = _run([os.path.join(BIN, "ds_tpu_serve"), "--synthetic", "3",
-              "--num-slots", "2", "--max-len", "48", "--prefill-bucket",
-              "16", "--max-new-tokens", "3", "--d-model", "32",
+              "--num-slots", "2", "--max-len", "48",
+              "--max-new-tokens", "3", "--d-model", "32",
               "--n-layers", "1", "--vocab-size", "64", "--quiet",
               "--metrics-port", "0"], timeout=300)
     assert r.returncode == 0, r.stderr[-800:]
@@ -134,7 +134,7 @@ def test_serve_qos_smoke(tmp_path):
     out = tmp_path / "metrics.json"
     r = _run([os.path.join(BIN, "ds_tpu_serve"), "--synthetic", "5",
               "--qos", "--num-slots", "2", "--max-len", "48",
-              "--prefill-bucket", "16", "--max-new-tokens", "3",
+              "--max-new-tokens", "3",
               "--d-model", "32", "--n-layers", "1", "--vocab-size", "64",
               "--quiet", "--metrics-out", str(out)], timeout=300)
     assert r.returncode == 0, r.stderr[-800:]
@@ -154,8 +154,8 @@ def test_serve_crash_leaves_partial_snapshot_and_exits_nonzero(tmp_path):
     used to leave nothing)."""
     out = tmp_path / "metrics.json"
     r = _run([os.path.join(BIN, "ds_tpu_serve"), "--synthetic", "4",
-              "--num-slots", "2", "--max-len", "48", "--prefill-bucket",
-              "16", "--max-new-tokens", "4", "--d-model", "32",
+              "--num-slots", "2", "--max-len", "48",
+              "--max-new-tokens", "4", "--d-model", "32",
               "--n-layers", "1", "--vocab-size", "64", "--quiet",
               "--inject-crash-at", "2", "--metrics-out", str(out)],
              timeout=300)
@@ -167,9 +167,9 @@ def test_serve_crash_leaves_partial_snapshot_and_exits_nonzero(tmp_path):
     assert artifact["serving"].get("requests_submitted") == 4
 
 
-FLEET_ARGS = ["--num-slots", "2", "--max-len", "48", "--prefill-bucket",
-              "16", "--max-new-tokens", "3", "--d-model", "32",
-              "--n-layers", "1", "--vocab-size", "64", "--paged",
+FLEET_ARGS = ["--num-slots", "2", "--max-len", "48",
+              "--max-new-tokens", "3", "--d-model", "32",
+              "--n-layers", "1", "--vocab-size", "64",
               "--page-len", "16", "--quiet"]
 
 
@@ -384,7 +384,7 @@ def test_bench_serving_writes_artifact(tmp_path):
     out = tmp_path / "BENCH_serving.json"
     r = _run([os.path.join(BIN, "ds_tpu_bench"), "serving",
               "--num-requests", "4", "--num-slots", "2", "--max-len", "48",
-              "--prefill-bucket", "16", "--min-prompt", "3", "--max-prompt",
+              "--min-prompt", "3", "--max-prompt",
               "8", "--min-output", "2", "--max-output", "3", "--d-model",
               "32", "--n-layers", "1", "--vocab-size", "64",
               "--out", str(out)], timeout=300)
@@ -399,24 +399,24 @@ def test_bench_serving_writes_artifact(tmp_path):
 
 @pytest.mark.slow
 def test_bench_serving_paged_prefix_adversarial(tmp_path):
-    """`ds_tpu_bench serving --paged --scenario prefix-adversarial`: the
-    paged engine serves the shared-prefix + long-prompt trace and the
+    """`ds_tpu_bench serving --scenario prefix-adversarial`: the
+    engine serves the shared-prefix + long-prompt trace and the
     artifact embeds the paging accounting block (page utilization,
     prefix hit rate, TTFT-under-load, density vs full-length rows)."""
     out = tmp_path / "BENCH_serving.json"
     r = _run([os.path.join(BIN, "ds_tpu_bench"), "serving",
-              "--paged", "--page-len", "16", "--prefill-chunk", "16",
+              "--page-len", "16", "--prefill-chunk", "16",
               "--scenario", "prefix-adversarial",
               "--shared-prefix-len", "32", "--long-prompt-len", "64",
               "--num-requests", "8", "--num-slots", "3", "--max-len", "96",
-              "--prefill-bucket", "16", "--min-prompt", "3", "--max-prompt",
+              "--min-prompt", "3", "--max-prompt",
               "8", "--min-output", "2", "--max-output", "4", "--d-model",
               "32", "--n-layers", "1", "--vocab-size", "64",
               "--out", str(out)], timeout=300)
     assert r.returncode == 0, r.stderr[-800:]
     art = json.loads(out.read_text())
     assert art["aggregate"]["requests_finished"] == 8
-    assert art["config"]["paging"]["enabled"]
+    assert art["config"]["paging"]["page_len"] == 16
     assert art["trace"]["scenario"] == "prefix-adversarial"
     pg = art["paging"]
     for key in ("page_utilization", "prefix_hit_rate", "pool_bytes",
@@ -495,14 +495,14 @@ def test_bench_trace_attaches_capture(tmp_path):
     r = _run([os.path.join(BIN, "ds_tpu_bench"), "serving",
               "--trace", str(trace),
               "--num-requests", "3", "--num-slots", "2", "--max-len", "48",
-              "--prefill-bucket", "16", "--min-prompt", "3", "--max-prompt",
+              "--min-prompt", "3", "--max-prompt",
               "8", "--min-output", "2", "--max-output", "3", "--d-model",
               "32", "--n-layers", "1", "--vocab-size", "64",
               "--out", str(out)], timeout=300)
     assert r.returncode == 0, r.stderr[-800:]
     names = {e["name"]
              for e in json.loads(trace.read_text())["traceEvents"]}
-    assert {"serving/admit", "serving/decode_iter",
+    assert {"serving/prefill_chunk", "serving/decode_iter",
             "serving/harvest"} <= names, names
     # the artifact also embeds the static-estimator perf block
     perf = json.loads(out.read_text())["perf"]

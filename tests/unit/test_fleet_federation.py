@@ -429,7 +429,6 @@ def _start_worker(port=0):
 def _serving_cfg(fleet_cfg, num_slots=2):
     from deepspeed_tpu.serving import PagingConfig, ServingConfig
     return ServingConfig(num_slots=num_slots, max_len=128,
-                         prefill_bucket=32,
                          paging=PagingConfig(page_len=16),
                          fleet=fleet_cfg)
 

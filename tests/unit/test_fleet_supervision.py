@@ -70,7 +70,6 @@ def _model(vocab, seed=0):
 
 def _cfg(fleet, num_slots=2, **kw):
     return ServingConfig(num_slots=num_slots, max_len=128,
-                         prefill_bucket=32,
                          paging=PagingConfig(page_len=16),
                          fleet=fleet, **kw)
 

@@ -424,7 +424,7 @@ class TestSubsystemIntegration:
         params = m.init(jax.random.PRNGKey(0),
                         jnp.ones((1, 8), jnp.int32))["params"]
         eng = ServingEngine(m, params, ServingConfig(
-            num_slots=2, max_len=64, prefill_bucket=16, seed=0))
+            num_slots=2, max_len=64, seed=0))
         t = Tracer()
         activate(t)
         rng = np.random.default_rng(0)
@@ -434,8 +434,8 @@ class TestSubsystemIntegration:
         eng.run()
         deactivate()
         names = {e[0] for e in t.events}
-        assert {"serving/admit", "serving/decode_iter",
-                "serving/harvest"} <= names
+        assert {"serving/admission", "serving/prefill_chunk",
+                "serving/decode_iter", "serving/harvest"} <= names
 
     def test_serving_metrics_registry_collector(self):
         from deepspeed_tpu.serving.metrics import ServingMetrics

@@ -232,7 +232,6 @@ class TestSloWatch:
 def _paged_fleet_cfg(fleet, num_slots=2, max_len=128, page_len=16):
     from deepspeed_tpu.serving import PagingConfig, ServingConfig
     return ServingConfig(num_slots=num_slots, max_len=max_len,
-                         prefill_bucket=32,
                          paging=PagingConfig(page_len=page_len),
                          fleet=fleet)
 

@@ -513,7 +513,7 @@ class TestEngineTracing:
         activate(tracer)
         try:
             eng = ServingEngine(m, params, ServingConfig(
-                num_slots=2, max_len=64, prefill_bucket=16))
+                num_slots=2, max_len=64))
             r = np.random.RandomState(0)
             reqs = [eng.submit(r.randint(1, 151, size=6), 3)
                     for _ in range(3)]
@@ -562,7 +562,6 @@ class TestEngineTracing:
 def _paged_fleet_cfg(fleet, num_slots=2, max_len=128, page_len=16):
     from deepspeed_tpu.serving import PagingConfig, ServingConfig
     return ServingConfig(num_slots=num_slots, max_len=max_len,
-                         prefill_bucket=32,
                          paging=PagingConfig(page_len=page_len),
                          fleet=fleet)
 

@@ -239,9 +239,10 @@ class TestProgramRegistry:
         import deepspeed_tpu.serving.paging.manager  # noqa: F401
         import deepspeed_tpu.inference.generation    # noqa: F401
         names = set(get_program_registry().table())
-        assert {"serving/admit", "serving/decode_iter",
-                "serving/paged_decode", "serving/chunk_prefill",
+        assert {"serving/paged_decode", "serving/chunk_prefill",
+                "serving/spec_verify_iter",
                 "inference/prefill", "inference/decode_loop"} <= names
+        assert not {"serving/admit", "serving/decode_iter"} & names
 
 
 # ---------------------------------------------------------------------------
@@ -425,14 +426,16 @@ class TestEngineIntegration:
         params = m.init(jax.random.PRNGKey(0),
                         jnp.ones((1, 8), jnp.int32))["params"]
         eng = ServingEngine(m, params, ServingConfig(
-            num_slots=2, max_len=64, prefill_bucket=16, seed=0))
+            num_slots=2, max_len=64, seed=0))
         acct = get_accountant()
         kv = acct.subsystem_bytes("serving/kv_pool")
-        assert kv == tree_bytes(eng._cache)
+        assert kv == (eng._paged.pool_bytes()
+                      + tree_bytes(eng._paged.page_table))
         assert acct.subsystem_bytes("serving/params") == tree_bytes(params)
         report = eng.memory_report()
         assert report["kv_pool_resident_bytes"] == kv
-        assert "decode_gather_transient_bytes" not in report  # contiguous
+        assert report["kv_page_dtype"] == "float32"
+        assert not report["paged_kernel"]               # CPU: gather path
         assert get_registry().gauge("mem/kv_pool_resident").value == kv
         # close() is the serving mirror of destroy(): attribution released
         eng.close()
@@ -455,7 +458,7 @@ class TestEngineIntegration:
         params = m.init(jax.random.PRNGKey(0),
                         jnp.ones((1, 8), jnp.int32))["params"]
         eng = ServingEngine(m, params, ServingConfig(
-            num_slots=3, max_len=64, prefill_bucket=16, seed=0,
+            num_slots=3, max_len=64, seed=0,
             paging=PagingConfig(page_len=16)))
         mgr = eng._paged
         derived = mgr.decode_gather_transient_bytes()
@@ -485,7 +488,7 @@ class TestEngineIntegration:
         params = m.init(jax.random.PRNGKey(0),
                         jnp.ones((1, 8), jnp.int32))["params"]
         eng = ServingEngine(m, params, ServingConfig(
-            num_slots=2, max_len=64, prefill_bucket=16, seed=0))
+            num_slots=2, max_len=64, seed=0))
         t = Tracer()
         activate(t)
         rng = np.random.default_rng(0)
@@ -497,8 +500,9 @@ class TestEngineIntegration:
         by_name = {}
         for name, _t0, _dur, _tid, args in t.events:
             by_name.setdefault(name, []).append(args)
-        admit_ids = {a["request_id"] for a in by_name["serving/admit"]}
-        assert admit_ids == {100, 101, 102}
+        chunk_ids = {a["request_id"]
+                     for a in by_name["serving/prefill_chunk"]}
+        assert chunk_ids == {100, 101, 102}
         assert all("active_requests" in a and "iteration" in a
                    for a in by_name["serving/decode_iter"])
         assert max(a["active_requests"]

@@ -346,7 +346,7 @@ def _model(vocab, **kw):
 
 def _drive(m, params, prompts, outs, kernel):
     eng = ServingEngine(m, params, ServingConfig(
-        num_slots=3, max_len=128, prefill_bucket=16, seed=0,
+        num_slots=3, max_len=128, seed=0,
         paging=PagingConfig(page_len=16, prefill_chunk=16, kernel=kernel)))
     reqs = [eng.submit(p, max_new_tokens=o) for p, o in zip(prompts, outs)]
     eng.run()
@@ -430,7 +430,7 @@ class TestEngineKernelPath:
         is the measured win (real TPU, aligned page_len)."""
         m, params = _model(157)
         eng = ServingEngine(m, params, ServingConfig(
-            num_slots=2, max_len=128, prefill_bucket=16, seed=0,
+            num_slots=2, max_len=128, seed=0,
             paging=PagingConfig(page_len=16)))
         assert not eng._paged.use_kernel
 

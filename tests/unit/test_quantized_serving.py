@@ -63,7 +63,7 @@ def _prompts(vocab, n=5, seed=11):
 
 def _drive(m, params, prompts, outs, *, paging=None, quantize=None):
     eng = ServingEngine(m, params, ServingConfig(
-        num_slots=3, max_len=128, prefill_bucket=16, seed=0,
+        num_slots=3, max_len=128, seed=0,
         paging=paging, quantize=quantize))
     reqs = [eng.submit(p, max_new_tokens=o) for p, o in zip(prompts, outs)]
     eng.run()
@@ -78,12 +78,12 @@ def _agreement(a, b):
 class TestQuantizeConfig:
     def test_validation(self):
         with pytest.raises(ValueError, match="weights"):
-            QuantizeConfig(weights="int4").validate(paged=True)
-        with pytest.raises(ValueError, match="kv requires"):
-            QuantizeConfig(kv="int8").validate(paged=False)
+            QuantizeConfig(weights="int4").validate()
+        with pytest.raises(ValueError, match="kv"):
+            QuantizeConfig(kv="fp8").validate()
         with pytest.raises(ValueError, match="min_size"):
-            QuantizeConfig(min_size=0).validate(paged=True)
-        QuantizeConfig(weights="int8", kv="int8").validate(paged=True)
+            QuantizeConfig(min_size=0).validate()
+        QuantizeConfig(weights="int8", kv="int8").validate()
 
     def test_serving_config_lift_and_flags(self):
         cfg = ServingConfig(
@@ -93,10 +93,9 @@ class TestQuantizeConfig:
         assert isinstance(cfg.quantize, QuantizeConfig)
         assert cfg.weights_int8 and cfg.kv_int8
         assert not ServingConfig(num_slots=2).validate().weights_int8
-        # kv quant without paging fails at VALIDATE, not engine build
-        with pytest.raises(ValueError, match="kv requires"):
-            ServingConfig(num_slots=2, max_len=128,
-                          quantize={"kv": "int8"}).validate()
+        # int8 pages need no paging block: every engine pages
+        assert ServingConfig(num_slots=2, max_len=128,
+                             quantize={"kv": "int8"}).validate().kv_int8
 
     def test_deepspeed_config_nested_block(self):
         from deepspeed_tpu.runtime.config import DeepSpeedConfig

@@ -4,7 +4,7 @@ The acceptance test drives 33 requests with mixed prompt/output lengths
 through 4 slots (slots << requests) and requires every request's tokens
 to EXACTLY match a per-request whole-batch generate() reference, with
 jit-cache-size assertions proving decode compiles once and prefill at
-most once per length bucket.
+most once per chunk width.
 """
 
 import os
@@ -16,9 +16,12 @@ import jax.numpy as jnp
 
 from deepspeed_tpu.models.gpt import GPT, GPTConfig
 from deepspeed_tpu.inference.generation import generate, init_cache
-from deepspeed_tpu.serving import ServingConfig
-from deepspeed_tpu.serving.engine import (ServingEngine, _admit_jit,
-                                          _decode_iter_jit)
+from deepspeed_tpu.serving import (PagingConfig, ServingConfig,
+                                   SpeculationConfig)
+from deepspeed_tpu.serving.engine import ServingEngine
+from deepspeed_tpu.serving.paging.manager import (PagedKVManager,
+                                                  _chunk_prefill_jit,
+                                                  _paged_decode_jit)
 
 REPO_ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
@@ -44,30 +47,34 @@ def _mixed_workload(n, vocab, seed=0, prompt_range=(3, 24), out_range=(1, 8)):
 
 
 # ---------------------------------------------------------------------------
-# config / bucketing policy
+# config
 # ---------------------------------------------------------------------------
 
 class TestServingConfig:
-    def test_bucket_policy(self):
-        cfg = ServingConfig(num_slots=2, max_len=100, prefill_bucket=16)
-        assert cfg.cache_len == 128                    # rounds up to 128s
-        assert cfg.bucket_lengths() == (16, 32, 48, 64, 80, 96, 112, 128)
-        assert cfg.bucket_for(1) == 16
-        assert cfg.bucket_for(16) == 16
-        assert cfg.bucket_for(17) == 32
-        assert cfg.bucket_for(128) == 128
-        with pytest.raises(ValueError, match="largest prefill bucket"):
-            cfg.bucket_for(129)
-
-    def test_unaligned_quantum_includes_capacity(self):
-        cfg = ServingConfig(num_slots=1, max_len=128, prefill_bucket=48)
-        assert cfg.bucket_lengths() == (48, 96, 128)
+    @pytest.mark.parametrize("max_len, spec_tokens, cache_len", [
+        (48, 0, 128), (100, 0, 128), (128, 0, 128), (1000, 0, 1024),
+        (2048, 0, 2048), (128, 4, 256)])
+    def test_absent_paging_block_validates_and_pages(self, max_len,
+                                                     spec_tokens, cache_len):
+        """No ``paging`` block is the default block: the slot capacity is
+        a 128 multiple, so the default page always tiles it, and the
+        default pool holds a full-length request in every slot."""
+        spec = (SpeculationConfig(max_spec_tokens=spec_tokens)
+                if spec_tokens else None)
+        cfg = ServingConfig(num_slots=3, max_len=max_len,
+                            speculation=spec).validate()
+        assert cfg.paging == PagingConfig()
+        assert cfg.cache_len == cache_len
+        assert cfg.cache_len % cfg.paging.page_len == 0
+        assert cfg.paging.chunk_tokens == cfg.paging.page_len == 128
+        assert cfg.paging.pool_pages(3, cache_len) == 3 * cache_len // 128 + 1
+        assert ServingConfig(max_len=max_len, paging=None).paging == cfg.paging
 
     def test_validation(self):
         with pytest.raises(ValueError, match="num_slots"):
             ServingConfig(num_slots=0).validate()
-        with pytest.raises(ValueError, match="prefill_bucket"):
-            ServingConfig(prefill_bucket=0).validate()
+        with pytest.raises(ValueError, match="page_len"):
+            ServingConfig(max_len=128, paging={"page_len": 48}).validate()
         with pytest.raises(ValueError, match="pipeline_depth"):
             ServingConfig(pipeline_depth=-1).validate()
         with pytest.raises(ValueError, match="max_queue"):
@@ -94,14 +101,12 @@ class TestCacheHelpers:
         pytest.param(True, marks=pytest.mark.slow),
         False,
     ])
-    def test_set_index_and_row_roundtrip(self, scan_layers):
-        from deepspeed_tpu.inference.cache import (
-            cache_max_len, cache_num_rows, make_row_cache, set_cache_index,
-            write_cache_row)
+    def test_set_index_reaches_every_unit(self, scan_layers):
+        from deepspeed_tpu.inference.cache import (cache_max_len,
+                                                   set_cache_index)
         m, params = _model(scan_layers=scan_layers)
         cache = init_cache(m, params, 3, 128)
         assert cache_max_len(cache) == 128
-        assert cache_num_rows(cache) == 3
 
         lens = jnp.asarray([5, 0, 7], jnp.int32)
         cache = set_cache_index(cache, lens)
@@ -120,22 +125,6 @@ class TestCacheHelpers:
         assert idxs
         for a in idxs:
             np.testing.assert_array_equal(a.reshape(-1, 3)[-1], [5, 0, 7])
-
-        # scatter a marked row and read it back
-        row = make_row_cache(cache)
-        row = jax.tree.map(lambda a: jnp.ones_like(a)
-                           if a.ndim >= 4 else a, row)
-        cache2 = write_cache_row(cache, row, jnp.int32(1))
-
-        def kv_leaves(tree):
-            return [a for a in jax.tree.leaves(tree)
-                    if getattr(a, "ndim", 0) >= 4]
-        for leaf in kv_leaves(cache2):
-            ax = leaf.ndim - 4
-            got = np.moveaxis(np.asarray(leaf), ax, 0)
-            np.testing.assert_array_equal(got[1], 1.0)    # written row
-            np.testing.assert_array_equal(got[0], 0.0)    # neighbors intact
-            np.testing.assert_array_equal(got[2], 0.0)
 
 
 # ---------------------------------------------------------------------------
@@ -176,7 +165,8 @@ class TestContinuousBatchingParity:
     def test_33_requests_through_4_slots_match_generate(self):
         """33 mixed-length requests, 4 slots: every request's streamed
         tokens exactly match its whole-batch generate() reference;
-        decode compiled once, prefill at most once per bucket used."""
+        decode compiled once, prefill once (every prompt here is one
+        chunk of one page)."""
         # vocab 101 is unique to this test so the jit-cache deltas below
         # cannot be absorbed by entries from other tests' shapes
         m, params = _model(vocab=101)
@@ -188,17 +178,15 @@ class TestContinuousBatchingParity:
             streamed.setdefault(req.request_id, []).append(tok)
 
         eng = ServingEngine(m, params,
-                            ServingConfig(num_slots=4, max_len=128,
-                                          prefill_bucket=16, seed=0))
-        decode_before = _decode_iter_jit._cache_size()
-        admit_before = _admit_jit._cache_size()
+                            ServingConfig(num_slots=4, max_len=128, seed=0))
+        decode_before = _paged_decode_jit._cache_size()
+        prefill_before = _chunk_prefill_jit._cache_size()
         reqs = [eng.submit(p, max_new_tokens=o, on_token=on_token)
                 for p, o in zip(prompts, outs)]
         eng.run()
 
-        buckets_used = {eng.config.bucket_for(len(p)) for p in prompts}
-        assert _decode_iter_jit._cache_size() == decode_before + 1
-        assert (_admit_jit._cache_size() - admit_before) <= len(buckets_used)
+        assert _paged_decode_jit._cache_size() == decode_before + 1
+        assert _chunk_prefill_jit._cache_size() == prefill_before + 1
 
         for req, p, o in zip(reqs, prompts, outs):
             assert req.done
@@ -219,6 +207,43 @@ class TestContinuousBatchingParity:
         assert snap["tokens_generated"] == sum(outs)
         assert not eng.busy and eng.num_free_slots == 4
 
+    @pytest.mark.parametrize("prompt_len", [1, 128, 129, 300])
+    def test_every_prompt_length_has_a_compiled_home(self, prompt_len):
+        """One token, one page exactly, one page plus one, three chunks:
+        through the DEFAULT config (no paging block) each equals
+        generate() token for token, from one decode program and one
+        prefill program — a chunk is a page, whatever the prompt."""
+        m, params = _model(vocab=83, max_seq_len=512)
+        prompt = np.random.RandomState(prompt_len).randint(
+            1, 83, size=prompt_len).astype(np.int32)
+        eng = ServingEngine(m, params, ServingConfig(num_slots=2,
+                                                     max_len=512))
+        assert isinstance(eng._paged, PagedKVManager)
+        req = eng.submit(prompt, max_new_tokens=4)
+        eng.run()
+        ref = np.asarray(generate(m, params, prompt[None], max_new_tokens=4,
+                                  temperature=0.0, max_len=512)
+                         )[0, prompt_len:]
+        np.testing.assert_array_equal(np.asarray(req.output_tokens), ref)
+        assert eng.metrics.snapshot()["prefill_chunks"] == \
+            -(-prompt_len // 128)
+
+    def test_the_registry_holds_the_two_paged_programs_and_no_others(self):
+        from deepspeed_tpu.observability.programs import get_program_registry
+        m, params = _model(vocab=83, max_seq_len=512)
+        eng = ServingEngine(m, params, ServingConfig(num_slots=2,
+                                                     max_len=512))
+        eng.submit(np.arange(1, 6, dtype=np.int32), max_new_tokens=2)
+        eng.run()
+        serving = {name for name in get_program_registry().table()
+                   if name.startswith("serving/")}
+        assert {"serving/paged_decode", "serving/chunk_prefill"} <= serving
+        assert serving <= {"serving/paged_decode", "serving/chunk_prefill",
+                           "serving/spec_verify_iter"}
+        table = eng.metrics_snapshot()["programs"]
+        assert table["serving/paged_decode"]["calls"] >= 1
+        assert table["serving/chunk_prefill"]["calls"] >= 1
+
     @pytest.mark.parametrize("arch", [
         pytest.param("gptj", marks=pytest.mark.slow),
         pytest.param("bloom", marks=pytest.mark.slow),
@@ -235,8 +260,7 @@ class TestContinuousBatchingParity:
         m, params = _model(vocab=89, **variants[arch])
         prompts, outs = _mixed_workload(8, 89, seed=1, out_range=(2, 6))
         eng = ServingEngine(m, params,
-                            ServingConfig(num_slots=2, max_len=128,
-                                          prefill_bucket=16))
+                            ServingConfig(num_slots=2, max_len=128))
         reqs = [eng.submit(p, max_new_tokens=o)
                 for p, o in zip(prompts, outs)]
         eng.run()
@@ -261,7 +285,6 @@ class TestContinuousBatchingParity:
         eos = int(probe[0, len(prompts[0])])
         eng = ServingEngine(m, params,
                             ServingConfig(num_slots=2, max_len=128,
-                                          prefill_bucket=16,
                                           eos_token_id=eos))
         reqs = [eng.submit(p, max_new_tokens=8) for p in prompts]
         eng.run()
@@ -303,8 +326,7 @@ class TestEnginePlumbing:
         m, params = _model(vocab=53)
         eng = deepspeed_tpu.init_inference(m, params=params,
                                            dtype=jnp.float32)
-        srv = eng.serve({"num_slots": 2, "max_len": 64,
-                         "prefill_bucket": 16})
+        srv = eng.serve({"num_slots": 2, "max_len": 64})
         req = srv.submit(np.arange(1, 6, dtype=np.int32), max_new_tokens=3)
         srv.run()
         ref = np.asarray(eng.generate(np.arange(1, 6, dtype=np.int32)[None],
@@ -325,7 +347,6 @@ class TestEnginePlumbing:
         mon = FakeMonitor()
         srv = ServingEngine.from_config(
             m, params, {"serving": {"num_slots": 2, "max_len": 64,
-                                    "prefill_bucket": 16,
                                     "metrics_interval": 1}}, monitor=mon)
         for p in (np.arange(1, 5, dtype=np.int32),
                   np.arange(1, 9, dtype=np.int32)):
@@ -347,8 +368,7 @@ class TestEnginePlumbing:
         m, params = _model(vocab=71)
         prompts, outs = _mixed_workload(6, 71, seed=3, out_range=(3, 6))
         eng = ServingEngine(m, params,
-                            ServingConfig(num_slots=2, max_len=128,
-                                          prefill_bucket=16))
+                            ServingConfig(num_slots=2, max_len=128))
         first = [eng.submit(p, max_new_tokens=o)
                  for p, o in zip(prompts[:2], outs[:2])]
         for _ in range(2):
@@ -388,7 +408,7 @@ class TestBenchHarness:
         def run_once():
             eng = ServingEngine(m, params,
                                 ServingConfig(num_slots=2, max_len=128,
-                                              prefill_bucket=16, seed=0))
+                                              seed=0))
             handles = replay(eng, make_trace(
                 7, 12, prompt_len_range=(3, 10), output_len_range=(2, 5),
                 vocab_size=59))
@@ -411,8 +431,7 @@ class TestBenchHarness:
         from benchmarks.serving.load_harness import replay
         m, params = _model(vocab=59)
         eng = ServingEngine(m, params,
-                            ServingConfig(num_slots=3, max_len=128,
-                                          prefill_bucket=16, seed=0))
+                            ServingConfig(num_slots=3, max_len=128, seed=0))
         r = np.random.RandomState(0)
         trace = [{"id": i, "arrival_step": 50,
                   "prompt": r.randint(1, 59, size=5).tolist(),
@@ -442,8 +461,7 @@ class TestServingRobustness:
         instead of waiting forever, and never consumes a slot."""
         m, params = _model(vocab=61)
         eng = ServingEngine(m, params,
-                            ServingConfig(num_slots=1, max_len=128,
-                                          prefill_bucket=16))
+                            ServingConfig(num_slots=1, max_len=128))
         r = np.random.RandomState(0)
         head = eng.submit(r.randint(1, 61, size=4), max_new_tokens=12)
         late = eng.submit(r.randint(1, 61, size=4), max_new_tokens=4,
@@ -462,7 +480,6 @@ class TestServingRobustness:
         m, params = _model(vocab=61)
         eng = ServingEngine(m, params,
                             ServingConfig(num_slots=1, max_len=128,
-                                          prefill_bucket=16,
                                           default_deadline_steps=2))
         r = np.random.RandomState(1)
         head = eng.submit(r.randint(1, 61, size=4), max_new_tokens=10)
@@ -479,8 +496,7 @@ class TestServingRobustness:
         from deepspeed_tpu.inference.generation import generate as gen
         m, params = _model(vocab=61)
         eng = ServingEngine(m, params,
-                            ServingConfig(num_slots=1, max_len=128,
-                                          prefill_bucket=16))
+                            ServingConfig(num_slots=1, max_len=128))
         r = np.random.RandomState(2)
         active = eng.submit(r.randint(1, 61, size=5), max_new_tokens=20,
                             request_id="active")
@@ -510,7 +526,7 @@ class TestServingRobustness:
         m, params = _model(vocab=61)
         eng = ServingEngine(m, params,
                             ServingConfig(num_slots=1, max_len=32,
-                                          prefill_bucket=16, max_queue=1))
+                                          max_queue=1))
         r = np.random.RandomState(3)
         with pytest.raises(ValueError, match="per-slot budget"):
             eng.submit(r.randint(1, 61, size=30), max_new_tokens=10)

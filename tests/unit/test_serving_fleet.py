@@ -60,7 +60,6 @@ def _model(vocab, max_seq_len=128, d_model=32, n_layers=2, n_heads=2,
 
 def _cfg(fleet, num_slots=2, max_len=128, page_len=16, **kw):
     return ServingConfig(num_slots=num_slots, max_len=max_len,
-                         prefill_bucket=32,
                          paging=PagingConfig(page_len=page_len),
                          fleet=fleet, **kw)
 
@@ -103,12 +102,14 @@ class TestFleetConfig:
         with pytest.raises(ValueError, match="min_replicas"):
             FleetConfig(min_replicas=4, max_replicas=2).validate()
 
-    def test_disaggregate_requires_paging(self):
+    def test_disaggregate_validates_without_a_paging_block(self):
+        """The handoff is a page transfer and every engine pages: a
+        disaggregated fleet needs no ``paging`` block of its own."""
         cfg = ServingConfig(
             num_slots=2, max_len=128,
-            fleet=FleetConfig(replicas=2, disaggregate=True))
-        with pytest.raises(ValueError, match="paging"):
-            cfg.validate()
+            fleet=FleetConfig(replicas=2, disaggregate=True)).validate()
+        assert cfg.paging == PagingConfig()
+        assert cfg.fleet.role_for(0) == "prefill"
 
     def test_roles_and_min_replica_pinning(self):
         cfg = FleetConfig(disaggregate=True, replicas=3,
@@ -338,7 +339,6 @@ class TestDisaggregatedHandoff:
 
         def cfg(fleet):
             return ServingConfig(num_slots=2, max_len=128,
-                                 prefill_bucket=32,
                                  paging=PagingConfig(page_len=16),
                                  quantize=QuantizeConfig(kv="int8"),
                                  fleet=fleet)
@@ -593,7 +593,7 @@ def test_fleet_bench_ab_and_kill_scenario(tmp_path):
     rc = load_harness.main([
         "--scenario", "fleet-burst", "--num-requests", "24",
         "--replicas", "2", "--num-slots", "2", "--max-len", "96",
-        "--prefill-bucket", "16", "--page-len", "16",
+        "--page-len", "16",
         "--num-prefix-groups", "2", "--prefix-pages", "1",
         "--max-output", "8", "--vocab-size", "173",
         "--d-model", "32", "--out", str(out)])

@@ -59,9 +59,9 @@ def _kv_bytes(tree):
 class TestPagingConfig:
     def test_defaults_and_derived(self):
         p = PagingConfig()
-        assert p.enabled and p.page_len == 128
+        assert p.page_len == 128
         assert p.chunk_tokens == 128                 # prefill_chunk default
-        # memory parity with the contiguous pool, plus the null page
+        # a full-length request in every slot, plus the null page
         assert p.pool_pages(num_slots=4, cache_len=1024) == 4 * 8 + 1
         assert PagingConfig(num_pages=33).pool_pages(4, 1024) == 33
 
@@ -79,14 +79,15 @@ class TestPagingConfig:
             PagingConfig(page_len=16, num_pages=8).validate(128)
         PagingConfig(page_len=16, num_pages=9).validate(128)
 
-    def test_serving_config_lift_and_paged_flag(self):
+    def test_serving_config_lifts_a_dict_and_defaults_an_absent_block(self):
         cfg = ServingConfig(num_slots=2, max_len=128,
-                            paging={"page_len": 16, "enabled": True})
+                            paging={"page_len": 16})
         assert isinstance(cfg.paging, PagingConfig)
-        assert cfg.validate().paged
-        assert not ServingConfig(num_slots=2).paged
-        assert not ServingConfig(
-            num_slots=2, paging=PagingConfig(enabled=False)).paged
+        assert cfg.validate().paging.page_len == 16
+        assert ServingConfig(num_slots=2).paging == PagingConfig()
+        # no key names another layout: the retired switch is unknown
+        with pytest.raises(TypeError, match="enabled"):
+            ServingConfig(num_slots=2, paging={"enabled": False})
 
     def test_deepspeed_config_nested_block(self):
         from deepspeed_tpu.runtime.config import (DeepSpeedConfig,
@@ -265,7 +266,7 @@ class TestChunkedPrefill:
         m, params = _model()
         r = np.random.RandomState(3)
         eng = ServingEngine(m, params, ServingConfig(
-            num_slots=3, max_len=128, prefill_bucket=16, seed=0,
+            num_slots=3, max_len=128, seed=0,
             paging=PagingConfig(page_len=16, prefill_chunk=16)))
         short = [eng.submit(r.randint(1, 97, size=5).astype(np.int32),
                             max_new_tokens=24) for _ in range(2)]
@@ -298,7 +299,7 @@ class TestChunkedPrefill:
         m, params = _model()
         r = np.random.RandomState(5)
         eng = ServingEngine(m, params, ServingConfig(
-            num_slots=2, max_len=128, prefill_bucket=16, seed=0,
+            num_slots=2, max_len=128, seed=0,
             paging=PagingConfig(page_len=16, prefill_chunk=16,
                                 max_chunks_per_iter=4)))
         long_p = r.randint(1, 97, size=90).astype(np.int32)
@@ -326,7 +327,7 @@ class TestPrefixSharingEndToEnd:
                                    .astype(np.int32)])
                    for n in r.randint(2, 10, size=6)]
         eng = ServingEngine(m, params, ServingConfig(
-            num_slots=2, max_len=128, prefill_bucket=16, seed=0,
+            num_slots=2, max_len=128, seed=0,
             paging=PagingConfig(page_len=16, prefill_chunk=16)))
         reqs = [eng.submit(p, max_new_tokens=4) for p in prompts]
         eng.run()
@@ -354,7 +355,7 @@ class TestPrefixSharingEndToEnd:
         m, params = _model()
         r = np.random.RandomState(7)
         eng = ServingEngine(m, params, ServingConfig(
-            num_slots=2, max_len=128, prefill_bucket=16, seed=0,
+            num_slots=2, max_len=128, seed=0,
             paging=PagingConfig(page_len=16, prefill_chunk=16,
                                 num_pages=9)))
         pm, a = eng._paged, eng._paged.allocator
@@ -391,7 +392,7 @@ class TestPrefixSharingEndToEnd:
         r = np.random.RandomState(13)
         # tiny pool: 1 full-length row equivalent (8 usable pages of 16)
         eng = ServingEngine(m, params, ServingConfig(
-            num_slots=2, max_len=128, prefill_bucket=16, seed=0,
+            num_slots=2, max_len=128, seed=0,
             paging=PagingConfig(page_len=16, prefill_chunk=16,
                                 num_pages=9)))
         a = eng._paged.allocator
@@ -431,7 +432,7 @@ class TestPagedDensityAcceptance:
 
         rows_budget = 2
         cfg = ServingConfig(
-            num_slots=32, max_len=256, prefill_bucket=16, seed=0,
+            num_slots=32, max_len=256, seed=0,
             paging=PagingConfig(page_len=16, prefill_chunk=16,
                                 max_chunks_per_iter=4,
                                 num_pages=rows_budget * (256 // 16) + 1))
@@ -488,7 +489,7 @@ class TestPagedDensityAcceptance:
         prompts = [r.randint(1, 89, size=int(n)).astype(np.int32)
                    for n in r.randint(3, 40, size=6)]
         eng = ServingEngine(m, params, ServingConfig(
-            num_slots=2, max_len=128, prefill_bucket=16, seed=0,
+            num_slots=2, max_len=128, seed=0,
             paging=PagingConfig(page_len=16, prefill_chunk=32)))
         reqs = [eng.submit(p, max_new_tokens=5) for p in prompts]
         eng.run()
@@ -504,7 +505,7 @@ class TestPagedDensityAcceptance:
         prompts = [r.randint(1, 91, size=int(n)).astype(np.int32)
                    for n in r.randint(3, 30, size=4)]
         eng = ServingEngine(m, params, ServingConfig(
-            num_slots=2, max_len=128, prefill_bucket=16, seed=0,
+            num_slots=2, max_len=128, seed=0,
             paging=PagingConfig(page_len=16, prefill_chunk=32)))
         reqs = [eng.submit(p, max_new_tokens=4) for p in prompts]
         eng.run()
@@ -938,7 +939,7 @@ class TestIdleRowsAreNotWalked:
                       enable_prefix_cache=False)
         paging.update(serving.pop("paging", {}))
         eng = ServingEngine(m, params, ServingConfig(
-            max_len=128, prefill_bucket=16, seed=0,
+            max_len=128, seed=0,
             paging=PagingConfig(**paging), **serving))
         assert eng._paged.use_kernel == (paging["kernel"] == "on")
         count = lambda name: reg.counter("serving/" + name).value
@@ -1019,39 +1020,6 @@ class TestIdleRowsAreNotWalked:
 
 
 # ---------------------------------------------------------------------------
-# paging disabled: bit-identical to the contiguous engine
-# ---------------------------------------------------------------------------
-
-class TestPagedOffIdentity:
-    @pytest.mark.slow
-    def test_disabled_paging_matches_no_paging_block(self):
-        """enabled=False (or no paging block at all) runs the original
-        contiguous code paths — same outputs, same iteration trace."""
-        m, params = _model(vocab=87)
-        r = np.random.RandomState(17)
-        prompts = [r.randint(1, 87, size=int(n)).astype(np.int32)
-                   for n in r.randint(3, 20, size=8)]
-        outs = [int(o) for o in r.randint(1, 6, size=8)]
-
-        def drive(paging):
-            eng = ServingEngine(m, params, ServingConfig(
-                num_slots=3, max_len=128, prefill_bucket=16, seed=0,
-                paging=paging))
-            reqs = [eng.submit(p, max_new_tokens=o)
-                    for p, o in zip(prompts, outs)]
-            eng.run()
-            return eng, [list(q.output_tokens) for q in reqs], \
-                [(q.admitted_iteration, q.finished_iteration) for q in reqs]
-
-        base_eng, base_toks, base_trace = drive(None)
-        off_eng, off_toks, off_trace = drive(PagingConfig(enabled=False))
-        assert base_eng._paged is None and off_eng._paged is None
-        assert off_eng._cache is not None      # contiguous rows exist
-        assert off_toks == base_toks
-        assert off_trace == base_trace         # identical scheduling
-
-
-# ---------------------------------------------------------------------------
 # trace spans + lint gate
 # ---------------------------------------------------------------------------
 
@@ -1062,7 +1030,7 @@ def test_paged_trace_spans():
     m, params = _model()
     r = np.random.RandomState(21)
     eng = ServingEngine(m, params, ServingConfig(
-        num_slots=2, max_len=128, prefill_bucket=16, seed=0,
+        num_slots=2, max_len=128, seed=0,
         paging=PagingConfig(page_len=16, prefill_chunk=16)))
     t = Tracer()
     activate(t)

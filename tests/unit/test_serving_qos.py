@@ -3,12 +3,12 @@
 Acceptance surface of the overload-resilience PR:
 
 - priority preemption-to-queue with token-exact resumption vs an
-  uncontended ``generate()`` reference (contiguous AND paged engines);
+  uncontended ``generate()`` reference (slot-starved AND page-starved);
 - deterministic SLO-aware shedding: the same overload trace produces
   the same shed set bit-for-bit, protected classes never shed, and the
   high-priority class's p95 TTFT stays inside its SLO target under a
   ~3x-overload burst scenario;
-- fault containment: an injected RESOURCE_EXHAUSTED during admit and an
+- fault containment: an injected RESOURCE_EXHAUSTED during prefill and an
   injected hung decode dispatch both leave the engine serving the
   remaining requests (no process death), with the events visible in the
   metrics snapshot / statusz payload;
@@ -233,7 +233,7 @@ class TestPreemption:
         waiting for a natural slot release."""
         m, params = _model(vocab=61)
         eng = ServingEngine(m, params, ServingConfig(
-            num_slots=2, max_len=128, prefill_bucket=16, qos=_qos()))
+            num_slots=2, max_len=128, qos=_qos()))
         r = np.random.RandomState(0)
         lows = [eng.submit(r.randint(1, 61, size=6), max_new_tokens=20,
                            request_id=f"low{i}", priority=0)
@@ -261,13 +261,13 @@ class TestPreemption:
 
     @pytest.mark.slow
     def test_preempt_resume_token_exact_paged(self):
-        """Same contract on the paged engine: pages released at
+        """Same contract for a page-starved head: pages released at
         preemption, resumption re-prefills prompt + partial output
         (prefix-cache hits make it cheap), outputs stay token-exact."""
         m, params = _model(vocab=61)
         paging = PagingConfig(page_len=16, num_pages=2 * (128 // 16) + 1)
         eng = ServingEngine(m, params, ServingConfig(
-            num_slots=3, max_len=128, prefill_bucket=16, paging=paging,
+            num_slots=3, max_len=128, paging=paging,
             qos=_qos()))
         r = np.random.RandomState(3)
         # two requests whose budgets together exhaust the 2-row pool
@@ -291,7 +291,7 @@ class TestPreemption:
         nothing is ever preempted — the pre-QoS engine is untouched."""
         m, params = _model(vocab=61)
         eng = ServingEngine(m, params, ServingConfig(
-            num_slots=1, max_len=128, prefill_bucket=16))
+            num_slots=1, max_len=128))
         r = np.random.RandomState(1)
         a = eng.submit(r.randint(1, 61, size=4), max_new_tokens=10,
                        priority=0)
@@ -324,7 +324,7 @@ class TestOverloadShedding:
                        {"name": "batch", "priority": 0},
                    ])
         eng = ServingEngine(m, params, ServingConfig(
-            num_slots=4, max_len=128, prefill_bucket=128, qos=qos))
+            num_slots=4, max_len=128, qos=qos))
         trace = make_qos_trace("burst", seed=0, num_requests=40,
                                vocab_size=61, prompt_len_range=(4, 32),
                                output_len_range=(4, 16),
@@ -362,7 +362,7 @@ class TestOverloadShedding:
         deadline still times out deterministically."""
         m, params = _model(vocab=61)
         eng = ServingEngine(m, params, ServingConfig(
-            num_slots=1, max_len=128, prefill_bucket=16))
+            num_slots=1, max_len=128))
         r = np.random.RandomState(5)
         head = eng.submit(r.randint(1, 61, size=4), max_new_tokens=12)
         late = eng.submit(r.randint(1, 61, size=4), max_new_tokens=4,
@@ -377,16 +377,16 @@ class TestOverloadShedding:
 
 class TestFaultContainment:
     def test_oom_on_admit_sheds_and_keeps_serving(self, monkeypatch):
-        """An injected RESOURCE_EXHAUSTED during admit sheds exactly that
+        """An injected RESOURCE_EXHAUSTED during prefill sheds exactly that
         request (status shed, reason oom, forensics captured) and the
         engine finishes everyone else token-exactly — no process death."""
         m, params = _model(vocab=61)
         eng = ServingEngine(m, params, ServingConfig(
-            num_slots=2, max_len=128, prefill_bucket=16, qos=_qos()))
+            num_slots=2, max_len=128, qos=_qos()))
         r = np.random.RandomState(1)
         reqs = [eng.submit(r.randint(1, 61, size=5), max_new_tokens=4,
                            request_id=i, priority=1) for i in range(3)]
-        orig = engine_mod._admit_jit
+        orig = engine_mod._chunk_prefill_jit
         calls = {"n": 0}
 
         def flaky(*a, **kw):
@@ -396,7 +396,7 @@ class TestFaultContainment:
                     "RESOURCE_EXHAUSTED: Out of memory while trying to "
                     "allocate 9437184 bytes.")
             return orig(*a, **kw)
-        monkeypatch.setattr(engine_mod, "_admit_jit", flaky)
+        monkeypatch.setattr(engine_mod, "_chunk_prefill_jit", flaky)
         eng.run()
 
         statuses = [q.status for q in reqs]
@@ -414,7 +414,7 @@ class TestFaultContainment:
         assert "oom" in kinds and "recovery" in kinds
         # a non-OOM error still propagates (no blanket swallowing)
         monkeypatch.setattr(
-            engine_mod, "_admit_jit",
+            engine_mod, "_chunk_prefill_jit",
             lambda *a, **kw: (_ for _ in ()).throw(RuntimeError("boom")))
         eng.submit(r.randint(1, 61, size=4), max_new_tokens=2, priority=1)
         with pytest.raises(RuntimeError, match="boom"):
@@ -428,13 +428,13 @@ class TestFaultContainment:
         still finishes token-exactly (no process death)."""
         m, params = _model(vocab=61)
         eng = ServingEngine(m, params, ServingConfig(
-            num_slots=2, max_len=128, prefill_bucket=16,
+            num_slots=2, max_len=128,
             qos=_qos(watchdog_timeout_s=0.15)))
         r = np.random.RandomState(2)
         reqs = [eng.submit(r.randint(1, 61, size=5), max_new_tokens=6,
                            request_id=f"w{i}", priority=1)
                 for i in range(3)]
-        orig = engine_mod._decode_iter_jit
+        orig = engine_mod._paged_decode_jit
         calls = {"n": 0}
         escalations = []
         # the stall spans two watchdog windows, so the hard-abort
@@ -447,7 +447,7 @@ class TestFaultContainment:
             if calls["n"] == 2:
                 time.sleep(0.5)     # well past the 0.15s watchdog budget
             return orig(*a, **kw)
-        monkeypatch.setattr(engine_mod, "_decode_iter_jit", stalled)
+        monkeypatch.setattr(engine_mod, "_paged_decode_jit", stalled)
         try:
             eng.run()
         finally:
@@ -469,7 +469,7 @@ class TestFaultContainment:
         escalate."""
         m, params = _model(vocab=61)
         eng = ServingEngine(m, params, ServingConfig(
-            num_slots=1, max_len=128, prefill_bucket=16,
+            num_slots=1, max_len=128,
             qos=_qos(watchdog_timeout_s=0.1)))
         fatals = []
         eng.on_watchdog_fatal = fatals.append
@@ -488,7 +488,7 @@ class TestFaultContainment:
         arm/disarm bracket really disarms between dispatches)."""
         m, params = _model(vocab=61)
         eng = ServingEngine(m, params, ServingConfig(
-            num_slots=2, max_len=128, prefill_bucket=16,
+            num_slots=2, max_len=128,
             qos=_qos(watchdog_timeout_s=30.0)))
         r = np.random.RandomState(4)
         reqs = [eng.submit(r.randint(1, 61, size=4), max_new_tokens=3,
@@ -507,7 +507,7 @@ class TestFaultContainment:
         queued, and the rerun finishes everyone token-exactly."""
         m, params = _model(vocab=61)
         eng = ServingEngine(m, params, ServingConfig(
-            num_slots=2, max_len=128, prefill_bucket=16, qos=_qos()))
+            num_slots=2, max_len=128, qos=_qos()))
         r = np.random.RandomState(6)
         reqs = [eng.submit(r.randint(1, 61, size=5), max_new_tokens=8,
                            request_id=f"r{i}", priority=i % 2)
@@ -537,12 +537,12 @@ class TestElasticity:
     def test_set_slot_cap_drains_via_preemption(self):
         m, params = _model(vocab=61)
         eng = ServingEngine(m, params, ServingConfig(
-            num_slots=3, max_len=128, prefill_bucket=16, qos=_qos()))
+            num_slots=3, max_len=128, qos=_qos()))
         r = np.random.RandomState(7)
         reqs = [eng.submit(r.randint(1, 61, size=5), max_new_tokens=10,
                            request_id=f"s{i}", priority=1)
                 for i in range(3)]
-        for _ in range(2):
+        for _ in range(4):                      # one prefill chunk each
             eng.advance()
         assert sum(q.status == "running" for q in reqs) == 3
         eng.set_slot_cap(1)                     # drain, don't drop
@@ -561,7 +561,7 @@ class TestElasticity:
                                               ServingAutoscaler)
         m, params = _model(vocab=61)
         eng = ServingEngine(m, params, ServingConfig(
-            num_slots=4, max_len=128, prefill_bucket=16, qos=_qos()))
+            num_slots=4, max_len=128, qos=_qos()))
         eng.set_slot_cap(2)
         scaler = ServingAutoscaler(
             eng, ServingAutoscaleConfig(patience=2, min_slots=1))
@@ -621,7 +621,7 @@ class TestQosTelemetry:
         from deepspeed_tpu.observability.export import build_statusz
         m, params = _model(vocab=61)
         eng = ServingEngine(m, params, ServingConfig(
-            num_slots=2, max_len=128, prefill_bucket=16, qos=_qos()))
+            num_slots=2, max_len=128, qos=_qos()))
         r = np.random.RandomState(9)
         for i in range(3):
             eng.submit(r.randint(1, 61, size=4), max_new_tokens=3,

@@ -225,7 +225,7 @@ class TestQosSpeculationRung:
 # ---------------------------------------------------------------------------
 
 @pytest.mark.slow
-class TestSpecEngineContiguous:
+class TestSpecEngineDefaultPaging:
     def test_token_exact_and_compile_once(self):
         """Mixed repetitive + uniform workload through 3 slots: every
         output bitwise-equal to generate(), real speculation happened,
@@ -237,7 +237,7 @@ class TestSpecEngineContiguous:
         snaps = []
         for _ in range(2):
             eng = ServingEngine(m, params, ServingConfig(
-                num_slots=3, max_len=128, prefill_bucket=16,
+                num_slots=3, max_len=128,
                 speculation=_spec()))
             reqs = []
             for i in range(7):
@@ -278,7 +278,7 @@ class TestSpecEngineContiguous:
 
         def run(speculate):
             eng = ServingEngine(m, params, ServingConfig(
-                num_slots=1, max_len=128, prefill_bucket=16,
+                num_slots=1, max_len=128,
                 speculation=_spec() if speculate else None))
             if speculate:
                 eng._spec = _OracleProposer([full])
@@ -318,7 +318,7 @@ class TestSpecEngineContiguous:
         refs = [np.concatenate([p, _ref(m, params, p, o)])
                 for p, o in zip(prompts, outs)]
         eng = ServingEngine(m, params, ServingConfig(
-            num_slots=2, max_len=128, prefill_bucket=16,
+            num_slots=2, max_len=128,
             speculation=_spec()))
         eng._spec = _AdversaryProposer(refs, vocab)
         reqs = [eng.submit(p, o, request_id=i)
@@ -359,7 +359,7 @@ class TestSpecEnginePaged:
         r = np.random.RandomState(3)
         for _ in range(2):
             eng = ServingEngine(m, params, ServingConfig(
-                num_slots=3, max_len=128, prefill_bucket=16,
+                num_slots=3, max_len=128,
                 paging=PagingConfig(page_len=16),
                 speculation=_spec()))
             reqs = []
@@ -399,7 +399,7 @@ class TestSpecEnginePaged:
         for proposer in (_OracleProposer(refs),
                          _AdversaryProposer(refs, vocab)):
             eng = ServingEngine(m, params, ServingConfig(
-                num_slots=2, max_len=128, prefill_bucket=16,
+                num_slots=2, max_len=128,
                 paging=PagingConfig(page_len=page_len),
                 speculation=_spec()))
             eng._spec = proposer
@@ -419,7 +419,7 @@ class TestSpecEnginePaged:
         m, params = _model(vocab)
         r = np.random.RandomState(5)
         eng = ServingEngine(m, params, ServingConfig(
-            num_slots=2, max_len=128, prefill_bucket=16,
+            num_slots=2, max_len=128,
             paging=PagingConfig(page_len=16, prefill_chunk=16),
             speculation=_spec()))
         prompts = [_motif_prompt(r, vocab, 4, 40),
@@ -443,7 +443,7 @@ class TestSpecEnginePaged:
         r = np.random.RandomState(6)
         prompt = _motif_prompt(r, vocab, 3, 20)
         max_new = 24
-        cfg = ServingConfig(num_slots=2, max_len=128, prefill_bucket=16,
+        cfg = ServingConfig(num_slots=2, max_len=128,
                             paging=PagingConfig(page_len=16),
                             speculation=_spec())
         a = ServingEngine(m, params, cfg)
@@ -495,7 +495,7 @@ class TestSpecQosIntegration:
         vocab = 59
         m, params = _model(vocab)
         eng = ServingEngine(m, params, ServingConfig(
-            num_slots=2, max_len=128, prefill_bucket=16,
+            num_slots=2, max_len=128,
             speculation=_spec(), qos=self._qos()))
         r = np.random.RandomState(7)
         lows = [eng.submit(_motif_prompt(r, vocab, 3, 8), 20,
@@ -522,7 +522,7 @@ class TestSpecQosIntegration:
         runs = []
         for _ in range(2):
             eng = ServingEngine(m, params, ServingConfig(
-                num_slots=1, max_len=128, prefill_bucket=16,
+                num_slots=1, max_len=128,
                 speculation=_spec(), qos=self._qos()))
             r = np.random.RandomState(8)
             reqs = [eng.submit(_motif_prompt(r, vocab, 3,

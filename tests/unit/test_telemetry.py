@@ -609,7 +609,7 @@ class TestServingEndpoint:
         params = m.init(jax.random.PRNGKey(0),
                         jnp.ones((1, 8), jnp.int32))["params"]
         return ServingEngine(m, params, ServingConfig(
-            num_slots=2, max_len=64, prefill_bucket=16, seed=0))
+            num_slots=2, max_len=64, seed=0))
 
     @pytest.mark.slow
     def test_serving_run_scrapeable_with_queue_gauges(self):

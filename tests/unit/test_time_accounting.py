@@ -58,7 +58,7 @@ class Since:
 def _paged_server():
     m, params = _model()
     return ServingEngine(m, params, ServingConfig(
-        num_slots=SLOTS, max_len=128, prefill_bucket=16, seed=0,
+        num_slots=SLOTS, max_len=128, seed=0,
         paging=PagingConfig(page_len=16, prefill_chunk=16)))
 
 
