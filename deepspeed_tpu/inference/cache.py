@@ -19,6 +19,8 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from ..serving.paging.allocator import NULL_PAGE
+
 _KV_KEYS = ("cached_key", "cached_value")
 # int8 page pools carry one fp32 scale plane per KV leaf (serving int8
 # KV pages): [num_pages, h, 1, page_len] — one scale per head per token,
@@ -95,7 +97,8 @@ def set_cache_index(cache, lengths):
 # paged-pool plumbing (serving/paging): the same cache-tree walkers applied
 # to a page pool — a cache tree whose "batch" axis is physical pages and
 # whose "sequence" axis is one page. Pure jnp, safe inside jit; page 0 is
-# the reserved null page (garbage sink for masked/unowned writes).
+# the reserved null page (what masked/unowned table entries and writes
+# name: gathered as garbage, never written by the append).
 # ---------------------------------------------------------------------------
 
 def init_page_pool(module, params, num_pages: int, page_len: int):
@@ -254,41 +257,65 @@ def extract_token_kv(cache, idx):
     return walk(cache)
 
 
-def _append_rows(dst, val, pages, offsets):
-    """``dst[..., pages[b], :, :, offsets[b]] = val[..., b, :, :, 0]`` for
-    every row ``b``, in place: the row's page (of every layer, when
-    stacked) is read, one lane of it replaced, and the page written
-    back where it was. A scatter over the page and the in-page offset —
-    the lane dimension of the K^T layout — makes XLA:TPU change the
-    whole pool's layout and change it back, every step; this touches
-    the pages it writes and nothing else, so a donated pool stays the
-    donated pool (on the v5e, 1.5 ms for 32 rows of a 3.75 GiB pool
-    against the scatter's 24.8). Rows are written in order: rows routed
-    to the null page overwrite each other there, and nowhere else do
-    two rows meet."""
+def _append_rows(dst, val, tokens, count):
+    """Write ``count`` tokens of ``val`` into ``dst`` in place. Token
+    ``i`` is row ``b`` of ``val``'s ``rows`` and lands on lane ``o`` of
+    page ``p``, all three in one number, ``tokens[i] = (p * page_len +
+    o) * rows + b``: ``dst[..., p, :, :, o] = val[..., b, :, :, 0]``.
+    The page (of every layer, when stacked) is read, one lane of it
+    replaced, and the page written back where it was. A scatter over the
+    page and the in-page offset — the lane dimension of the K^T layout —
+    makes XLA:TPU change the whole pool's layout and change it back,
+    every step; this touches the pages it writes and nothing else, so a
+    donated pool stays the donated pool. One trip moves a page of every
+    layer at the memory's speed (38 us for 24 x 512 KiB on the v5e), so
+    the loop makes ``count`` of them, a number the program reads off its
+    own arguments, and not one per slot. One number a trip, because the
+    trip waits for each one it reads: with page, lane and row read
+    apart a trip took 1.9 us longer than the loop over every slot's,
+    which a full batch would pay 64 times a step. A token-sized write
+    would not be cheaper than a trip: tokens lie on the lane dimension,
+    so one token is one lane of every (16, 128) tile of the page
+    (ROADMAP 1.0a')."""
     page_axis = dst.ndim - 4                      # 1 when layer-stacked
+    rows, page_len = val.shape[page_axis], dst.shape[-1]
+    if dst.shape[page_axis] * page_len * rows >= 2 ** 31:
+        raise ValueError(
+            f"{dst.shape[page_axis]} pages of {page_len} tokens written "
+            f"from {rows} rows: a token's place in the pool and its row "
+            "do not fit one int32")
     one_page = dst.shape[:page_axis] + (1,) + dst.shape[page_axis + 1:]
     lane = jax.lax.broadcasted_iota(
-        jnp.int32, (1,) * (dst.ndim - 1) + dst.shape[-1:], dst.ndim - 1)
+        jnp.int32, (1,) * (dst.ndim - 1) + (page_len,), dst.ndim - 1)
 
-    def append_row(b, out):
-        at = (0,) * page_axis + (pages[b], 0, 0, 0)
+    def append_token(i, out):
+        where, b = jnp.divmod(tokens[i], rows)
+        p, o = jnp.divmod(where, page_len)
+        at = (0,) * page_axis + (p, 0, 0, 0)
         page = jax.lax.dynamic_slice(out, at, one_page)
         row = jax.lax.dynamic_slice_in_dim(val, b, 1, axis=page_axis)
-        page = jnp.where(lane == offsets[b], row.astype(out.dtype), page)
+        page = jnp.where(lane == o, row.astype(out.dtype), page)
         return jax.lax.dynamic_update_slice(out, page, at)
 
-    return jax.lax.fori_loop(0, pages.shape[0], append_row, dst)
+    return jax.lax.fori_loop(0, count, append_token, dst)
 
 
 def scatter_token_pages(pool, token_tree, pages, offsets):
     """Append one decode step's K/V to the pool in place: row ``b``'s
     token lands at ``pool[pages[b], :, :, offsets[b]]`` (every layer of
     a stacked pool at once). Distinct active rows own distinct tail
-    pages by construction; masked rows are routed to the null page by
-    the caller, so rows only ever collide on garbage."""
+    pages by construction. A row the caller routes to the null page —
+    one that does not decode, or a write past a slot's budget — holds
+    no token: it is not written, the null page stays as it was, and the
+    append costs what the rows that are written cost."""
     pages = jnp.asarray(pages, jnp.int32)
     offsets = jnp.asarray(offsets, jnp.int32)
+    rows, page_len = pages.shape[0], cache_page_len(pool)
+    # the rows that hold a token, in row order, ahead of the others
+    live = pages != NULL_PAGE
+    first = jnp.argsort(~live, stable=True)
+    tokens = ((pages * page_len + offsets) * rows + jnp.arange(rows))[first]
+    count = jnp.sum(live, dtype=jnp.int32)
 
     def scatter(unit, tok):
         out = dict(unit)
@@ -300,8 +327,8 @@ def scatter_token_pages(pool, token_tree, pages, offsets):
                 # precision (kv_token), lands int8 with its scale plane
                 leaf, sc = _quantize_kv(leaf)
                 sname = _SCALE_KEYS[name]
-                out[sname] = _append_rows(unit[sname], sc, pages, offsets)
-            out[name] = _append_rows(unit[name], leaf, pages, offsets)
+                out[sname] = _append_rows(unit[sname], sc, tokens, count)
+            out[name] = _append_rows(unit[name], leaf, tokens, count)
         return out
 
     return _walk_with(pool, token_tree, scatter)

@@ -4,7 +4,8 @@ The device never sees this object — it owns the *meaning* of the dense
 page table (which physical page belongs to whom), while the table itself
 is a plain int32 array the jitted programs index with. Page 0 is the
 reserved null page: never allocated, never refcounted; unowned table
-entries and masked writes land there.
+entries name it, masked writes are routed to it and the append
+(``inference/cache.py scatter_token_pages``) writes none of them.
 
 Refcounts implement copy-free sharing: a request admitted against a
 cached prefix retains the prefix pages (+1 each) instead of recomputing

@@ -104,11 +104,14 @@ def _paged_decode_iter_impl(module, params, pool, page_table, state, rng, it,
     view (int8 pools dequantize to ``dequant_dtype`` during the gather)
     -> the unchanged attention path. Both append the new token's K/V to
     each active slot's tail page in place (quantized on write for int8
-    pools); inactive slots write the null page. ``pool`` is donated and
-    is the buffer the returned pool lives in: nothing between the
-    argument and the result makes a fresh one (the registry's
-    ``temp_bytes`` / ``alias_bytes`` of ``serving/paged_decode`` say so
-    on the chip; ``chip_smoke.py`` checks them)."""
+    pools); an inactive slot is routed to the null page, which the
+    append does not write: it makes one trip per active slot, so its
+    cost follows ``active`` as the kernel's lengths do. ``pool`` is
+    donated and is the buffer the returned pool lives in: nothing
+    between the argument and the result makes a fresh one (the
+    registry's ``temp_bytes`` / ``alias_bytes`` of
+    ``serving/paged_decode`` say so on the chip; ``chip_smoke.py``
+    checks them)."""
     lengths = state["lengths"]
     active = state["active"]
     page_len = cache_page_len(pool)

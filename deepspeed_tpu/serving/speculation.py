@@ -30,10 +30,10 @@ entries at each slot's frontier, and acceptance simply decides how far
 ``lengths`` advances. Rejected entries sit PAST the new frontier —
 exactly the prefill chunk's pad-tail convention — where the per-slot
 length mask never reads them and later steps overwrite them in order.
-Every write lands inside the slot's admission-time page budget (or the
-null-page garbage sink past it), so speculation never allocates, frees,
-or leaks a page and the allocator ``check()`` invariant holds after
-every rollback. Proposal-free iterations ride the existing
+Every write lands inside the slot's admission-time page budget (one
+past it names the null page and is skipped), so speculation never
+allocates, frees, or leaks a page and the allocator ``check()``
+invariant holds after every rollback. Proposal-free iterations ride the existing
 ``serving/paged_decode`` program untouched.
 
 Greedy-only by construction (config.validate refuses otherwise): the
@@ -111,12 +111,13 @@ def _spec_verify_impl(module, params, pool, page_table, state, proposals,
     never clamps), take the greedy argmax chain ``nxt``, accept the
     longest proposal prefix matching it, and emit ``e = min(accepted +
     1, first eos, remaining budget)`` tokens. The K+1 K/V entries are
-    scattered back position by position; writes past a slot's allocated
-    budget hit NULL_PAGE table entries — the garbage sink — so
-    speculation never touches a page it doesn't own. Rejected candidate
-    K/V stays past the advanced frontier (garbage by the prefill
-    chunk's pad-tail convention) — the rollback is "don't advance
-    ``lengths``", never an alloc or free.
+    scattered back position by position, one append each, as short as
+    the rows it writes; writes past a slot's allocated budget hit
+    NULL_PAGE table entries, which the append skips as it skips an
+    inactive slot's, so speculation never touches a page it doesn't
+    own. Rejected candidate K/V stays past the advanced frontier
+    (garbage by the prefill chunk's pad-tail convention) — the rollback
+    is "don't advance ``lengths``", never an alloc or free.
     """
     lengths = state["lengths"]
     active = state["active"]

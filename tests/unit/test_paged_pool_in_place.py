@@ -34,9 +34,17 @@ OLMOE_WIDTH = dict(vocab_size=50304, hidden_size=2048, intermediate_size=1024,
                    num_attention_heads=16, num_experts=64,
                    num_experts_per_tok=8, max_position_embeddings=4096)
 
-# instructions that may carry a pool-shaped value without moving it
+# instructions that may carry a pool-shaped value without moving it: no
+# ``copy``, and no ``conditional`` whose branches would each carry it
 IN_PLACE = {"parameter", "get-tuple-element", "tuple", "bitcast", "while",
             "dynamic-update-slice"}
+# ``temp_before``: what each program took of scratch before the append's
+# trips followed the rows that decode (PR 31's tree, same compiler). Ordering
+# those rows and counting them adds vectors of 32 numbers: one to three
+# padded buffers of 32,256 bytes (the unscanned program's scratch fell
+# by 830,464). The smallest thing of the pool's shape, one page of one
+# layer, is 512 KiB (256 KiB int8), and a trip moves one of every layer
+SMALL_VECTORS = 128 * 1024
 
 
 @pytest.fixture(scope="module")
@@ -56,16 +64,27 @@ def hlo_has(compiled, name):
     return name in compiled.as_text()
 
 
-def _fusion_roots(hlo):
-    """{fused computation: opcode of its ROOT} of an optimized module."""
-    roots, name = {}, None
+def _computations(hlo):
+    """{computation: its lines} of an optimized module."""
+    comps, name = {}, None
     for line in hlo.splitlines():
-        head = re.match(r"%(fused_computation[\w.\-]*) ", line)
+        head = re.match(r"(?:ENTRY )?%([\w.\-]+) \(.*\{$", line)
         if head:
             name = head.group(1)
-        root = re.match(r"\s+ROOT %[\w.\-]+ = \S+ ([\w\-]+)\(", line)
-        if root and name:
-            roots[name], name = root.group(1), None
+            comps[name] = []
+        elif name:
+            comps[name].append(line)
+    return comps
+
+
+def _roots(comps):
+    """{computation: opcode of its ROOT}."""
+    roots = {}
+    for name, lines in comps.items():
+        for line in lines:
+            root = re.match(r"\s+ROOT %[\w.\-]+ = \S+ ([\w\-]+)\(", line)
+            if root:
+                roots[name] = root.group(1)
     return roots
 
 
@@ -82,15 +101,16 @@ def _sub_jaxprs(eqn):
                 yield inner
 
 
-def _mosaic_calls(frames):
-    """Every ``pallas_call`` under ``frames[-1]`` with the frames it
-    sits in, outermost first: ``(jaxpr, the equation of the jaxpr before
-    it that holds it)``, the program's own being ``(program, None)``."""
+def _equations(frames, primitive):
+    """Every ``primitive`` equation under ``frames[-1]`` with the frames
+    it sits in, outermost first: ``(jaxpr, the equation of the jaxpr
+    before it that holds it)``, the program's own being ``(program,
+    None)``."""
     for eqn in frames[-1][0].eqns:
-        if eqn.primitive.name == "pallas_call":
+        if eqn.primitive.name == primitive:
             yield eqn, frames
         for sub in _sub_jaxprs(eqn):
-            yield from _mosaic_calls(frames + ((sub, eqn),))
+            yield from _equations(frames + ((sub, eqn),), primitive)
 
 
 def _origin(var, frames):
@@ -121,13 +141,13 @@ def _origin(var, frames):
             return made, frames
 
 
-@pytest.mark.parametrize("scan_layers,kv_int8,experts", [
-    (True, False, False), (False, False, False), (True, True, False),
-    (True, False, True)],
+@pytest.mark.parametrize("scan_layers,kv_int8,experts,temp_before", [
+    (True, False, False, 742400), (False, False, False, 2734080),
+    (True, True, False, 935936), (True, False, True, 4657664)],
     ids=["scanned-bf16", "unscanned-bf16", "scanned-int8", "olmoe-bf16"])
 def test_decode_program_leaves_the_pool_where_it_is(one_chip, monkeypatch,
                                                     scan_layers, kv_int8,
-                                                    experts):
+                                                    experts, temp_before):
     # the kernel must lower through Mosaic as on the chip: this process'
     # platform is the CPU, where it would be interpreted
     monkeypatch.setattr(
@@ -181,7 +201,7 @@ def test_decode_program_leaves_the_pool_where_it_is(one_chip, monkeypatch,
              jax.tree_util.tree_flatten_with_path(args)[0]]
     active_at, = [i for i, k in enumerate(paths) if "active" in k]
     lengths_at, = [i for i, k in enumerate(paths) if "lengths" in k]
-    calls = list(_mosaic_calls(((program, None),)))
+    calls = list(_equations(((program, None),), "pallas_call"))
     assert len(calls) == (1 if scan_layers else LAYERS)
     for call, frames in calls:
         # scalar prefetch: lengths is the kernel's first operand
@@ -196,6 +216,26 @@ def test_decode_program_leaves_the_pool_where_it_is(one_chip, monkeypatch,
                                          for v in clamp.invars]
 
     kv = [x for x in jax.tree.leaves(pool_shapes) if x.ndim >= 4]
+    # the append: one loop a pool leaf, the leaf its carry, and as many
+    # trips as rows decode — the bound is no constant, it is how many
+    # pages are not the null page that ``active`` routes the others to
+    loops = [(eqn, frames) for eqn, frames in
+             _equations(((program, None),), "while")
+             if any(v.aval.shape == x.shape for v in eqn.outvars for x in kv)]
+    assert len(loops) == len(kv)
+    for loop, frames in loops:
+        consts = loop.params["cond_nconsts"] + loop.params["body_nconsts"]
+        lower, upper = loop.invars[consts], loop.invars[consts + 1]
+        assert _origin(lower, frames) == ("literal", 0)
+        summed, at = _origin(upper, frames)
+        assert summed.primitive.name == "reduce_sum", summed
+        written, at = _origin(summed.invars[0], at)
+        assert written.primitive.name == "ne", written
+        assert _origin(written.invars[1], at) == ("literal", 0)   # NULL_PAGE
+        routed, at = _origin(written.invars[0], at)
+        assert routed.primitive.name == "select_n", routed
+        assert _origin(routed.invars[0], at) == ("input", active_at)
+
     pool_bytes = sum(x.size * x.dtype.itemsize for x in kv)
     key = next(x for x in kv if x.shape[-2] == 128)
     layer_k_bytes = (key.size * key.dtype.itemsize
@@ -205,6 +245,9 @@ def test_decode_program_leaves_the_pool_where_it_is(one_chip, monkeypatch,
     assert mem.temp_size_in_bytes < layer_k_bytes, (
         f"{mem.temp_size_in_bytes} bytes of scratch: some of the pool is "
         "copied")
+    assert mem.temp_size_in_bytes <= temp_before + SMALL_VECTORS, (
+        f"{mem.temp_size_in_bytes} bytes of scratch against {temp_before} "
+        "before: more than vectors of a number a row")
     if experts:
         # the dropless dispatch sorts 32 x 8 rows: its scratch is rows of
         # activations, nowhere near a capacity gate's [T, E, C] one-hot,
@@ -214,7 +257,8 @@ def test_decode_program_leaves_the_pool_where_it_is(one_chip, monkeypatch,
 
     hlo = compiled.as_text()
     assert "tpu_custom_call" in hlo            # the Mosaic kernel is there
-    roots = _fusion_roots(hlo)
+    comps = _computations(hlo)
+    roots = _roots(comps)
     # the pool, a layer's slice of it, and the same of the scale planes
     moved = re.compile(r"\[(?:\d+,)?%d,16,(?:128|1),%d\]" % (PAGES, PAGE_LEN))
     for line in hlo.splitlines():
@@ -226,3 +270,15 @@ def test_decode_program_leaves_the_pool_where_it_is(one_chip, monkeypatch,
         if op == "fusion":
             op = roots[re.search(r"calls=%([\w.\-]+)", line).group(1)]
         assert op in IN_PLACE, f"the pool is moved by: {line.strip()[:200]}"
+    # in the module the chip runs, the loops whose carry is a counter and
+    # a pool leaf (the layer scan carries the hidden state, and has the
+    # pool beside it) stop at a number they are handed, not at 32
+    appends = [line for line in hlo.splitlines()
+               if re.search(r" while\(", line) and re.search(
+                   r"= \(s32\[\]\S*, \S*" + moved.pattern, line)]
+    assert len(appends) == len(kv)
+    for line in appends:
+        cond = comps[re.search(r"condition=%([\w.\-]+)", line).group(1)]
+        assert not any(" constant(" in x for x in cond), cond
+        compare, = [x for x in cond if " compare(" in x]
+        assert compare.lstrip().startswith("ROOT") and "direction=LT" in compare

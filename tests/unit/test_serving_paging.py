@@ -660,10 +660,18 @@ class TestPoolStaysInPlace:
     @pytest.mark.parametrize("kv_int8", [False, True], ids=["fp", "int8"])
     @pytest.mark.parametrize("stacked", [True, False],
                              ids=["stacked", "4d"])
-    def test_append_is_the_scatter_it_replaced(self, stacked, kv_int8):
+    @pytest.mark.parametrize("pages", [
+        [3, 0, 5, 0], [0, 0, 0, 0], [3, 1, 5, 6], [0, 3, 0, 5],
+        [0, 0, 0, 6]],
+        ids=["rows-0-and-2", "no-live-row", "every-row-live",
+             "live-rows-not-a-prefix", "last-row-alone"])
+    def test_append_is_the_scatter_it_replaced(self, pages, stacked, kv_int8):
         """``scatter_token_pages`` against the indexed scatter the parent
         ran (``kv.at[:, pages, :, :, offsets].set``): bit-identical on
-        every page but the null page, scale planes included."""
+        every page but the null page, scale planes included — and the
+        null page, which rows that hold no token are routed to (two of
+        them at once in three of the cases), is what it was before the
+        call: the append makes no trip for them."""
         from deepspeed_tpu.inference.cache import (_quantize_kv,
                                                    scatter_token_pages)
         r = np.random.RandomState(11)
@@ -678,7 +686,7 @@ class TestPoolStaysInPlace:
                 unit[name], unit[sname] = _quantize_kv(unit[name])
         tok = {"k": jnp.asarray(r.randn(*lead, 4, 2, 8, 1), jnp.float32),
                "v": jnp.asarray(r.randn(*lead, 4, 2, 8, 1), jnp.float32)}
-        pages = jnp.asarray([3, 0, 5, 0], jnp.int32)    # two on the null page
+        pages = jnp.asarray(pages, jnp.int32)
         offsets = jnp.asarray([15, 4, 0, 9], jnp.int32)
         got = jax.jit(scatter_token_pages)(
             {"attn": unit}, {"attn": tok}, pages, offsets)["attn"]
@@ -693,12 +701,40 @@ class TestPoolStaysInPlace:
                                   ("cached_value", "value_scale", tok["v"])):
             planes = {name: leaf}
             if kv_int8:
-                planes[name], planes[sname] = _quantize_kv(leaf)
+                # compiled, as the append's is: eager division rounds a
+                # scale one ulp apart in one row of these
+                planes[name], planes[sname] = jax.jit(_quantize_kv)(leaf)
             for plane, val in planes.items():
                 want = parent(unit[plane], val)
                 assert got[plane].dtype == unit[plane].dtype
                 np.testing.assert_array_equal(_pages(got[plane])[1:],
                                               _pages(want)[1:])
+                np.testing.assert_array_equal(_pages(got[plane])[0],
+                                              _pages(unit[plane])[0])
+                live = np.asarray(pages) != 0
+                assert live.any() == (_pages(got[plane])[1:]
+                                      != _pages(unit[plane])[1:]).any()
+
+    def test_a_pool_too_large_for_one_number_a_token_is_refused(self):
+        """The append reads one int32 a trip: a token's place in the
+        pool and the row it comes from. Pages x page_len x rows past
+        2**31 is refused where the program is traced."""
+        from deepspeed_tpu.inference.cache import scatter_token_pages
+        sds = jax.ShapeDtypeStruct
+
+        def trace(num_pages, rows):
+            unit = {"cached_key": sds((num_pages, 2, 8, 128), jnp.bfloat16),
+                    "cached_value": sds((num_pages, 2, 8, 128), jnp.bfloat16),
+                    "cache_index": sds((rows,), jnp.int32)}
+            tok = {"k": sds((rows, 2, 8, 1), jnp.bfloat16),
+                   "v": sds((rows, 2, 8, 1), jnp.bfloat16)}
+            return jax.eval_shape(scatter_token_pages, {"attn": unit},
+                                  {"attn": tok}, sds((rows,), jnp.int32),
+                                  sds((rows,), jnp.int32))
+
+        trace(65535, 256)                              # 2**31 - 32768
+        with pytest.raises(ValueError, match="one int32"):
+            trace(65536, 256)
 
     @pytest.mark.parametrize("kv_int8", [False, True], ids=["fp", "int8"])
     def test_no_scan_streams_the_pool_and_every_leaf_is_aliased(self,
@@ -881,8 +917,8 @@ class TestIdleRowsAreNotWalked:
         """The live rows get, token for token and logit for logit, what a
         server that never used the other two slots gives them (an expert
         layer's counts too); the kernel is told 0 for the released rows,
-        so nothing of their poisoned pages reaches the null page that
-        their append is sent to."""
+        and the append makes no trip for them: the null page their
+        writes are routed to is what it was before the first step."""
         from deepspeed_tpu.serving.paging import manager
         lengths_seen = _watch_kernel_lengths(monkeypatch)
         logits_seen, sample = [], manager._sample_impl
@@ -919,13 +955,16 @@ class TestIdleRowsAreNotWalked:
         # a released row keeps its length (admission overwrites it)
         np.testing.assert_array_equal(np.asarray(state_s["lengths"]),
                                       scene.LENGTHS + steps * live)
-        # its K/V of this step goes to the null page: finite, because the
-        # row attended its own token and none of its poisoned pages
-        for leaf, fresh in zip(jax.tree.leaves(pool_s),
-                               jax.tree.leaves(pool_f)):
+        # its K/V of this step goes nowhere: the null page it is routed
+        # to is not written, in either run
+        for leaf, fresh, before in zip(jax.tree.leaves(pool_s),
+                                       jax.tree.leaves(pool_f),
+                                       jax.tree.leaves(scene.pool)):
             if leaf.ndim >= 4:
                 pages = _pages(leaf)
-                assert np.isfinite(pages[0]).all()
+                np.testing.assert_array_equal(pages[0], _pages(before)[0])
+                np.testing.assert_array_equal(_pages(fresh)[0],
+                                              _pages(before)[0])
                 assert np.isnan(pages[list(scene.POISONED)]).all()
                 np.testing.assert_allclose(pages[1:9], _pages(fresh)[1:9],
                                            atol=1e-6, rtol=0)
@@ -1017,6 +1056,112 @@ class TestIdleRowsAreNotWalked:
         assert req.done and not eng._paged.use_kernel
         assert count("decode_slots_busy") == 4
         assert count("paged_rows_walked") == 0
+
+
+def _slot_tokens_kv(eng, req):
+    """What the pool holds for ``req``'s slot, up to the length the
+    device has it at: per attention unit and leaf, ``[..., h, d|1, n]``
+    with the pages laid end to end, and ``n``."""
+    slot = eng._slot_req.index(req)
+    n = int(np.asarray(eng._state["lengths"])[slot])
+    units, _ = eng._paged.export_slot(slot, n)
+    out = []
+    for unit in units:
+        for name, leaf in sorted(unit.items()):
+            x = np.moveaxis(leaf, -4, -2)               # [..., h, d, pages, p]
+            out.append(x.reshape(x.shape[:-2] + (-1,))[..., :n])
+    return out, n
+
+
+class TestTheAppendWritesTheRowsThatDecode:
+    """A server with 1, then 3, of its 4 slots decoding — the others
+    never used, released with a stale length, or in the middle of their
+    prefill chunks — gives every request the tokens and the pages a
+    server that holds that request alone gives it, and never writes the
+    null page."""
+    VOCAB, MAX_NEW = 181, 40
+
+    def _engine(self, m, params, path):
+        from deepspeed_tpu.serving import SpeculationConfig
+        return ServingEngine(m, params, ServingConfig(
+            num_slots=4, max_len=128, seed=0,
+            paging=PagingConfig(
+                page_len=16, prefill_chunk=16, enable_prefix_cache=False,
+                kernel="on" if path == "kernel" else "off"),
+            speculation=(SpeculationConfig() if path == "speculative"
+                         else None)))
+
+    def _advance_until(self, eng, ready):
+        for _ in range(200):
+            if ready():
+                return
+            eng.advance()
+        raise AssertionError("the server never got there")
+
+    @pytest.mark.parametrize("path", ["kernel", "gather", "speculative"])
+    def test_tokens_and_pages_are_a_lone_requests(self, path):
+        m, params = _model(vocab=self.VOCAB)
+        r = np.random.RandomState(23)
+        motif = r.randint(1, self.VOCAB, size=4).astype(np.int32)
+        prompts = {
+            "first": np.tile(motif, 3)[:9],            # speculation has
+            "long": np.tile(motif[::-1], 13)[:50],     # something to propose
+            "longer": r.randint(1, self.VOCAB, size=37).astype(np.int32),
+            "brief": r.randint(1, self.VOCAB, size=5).astype(np.int32)}
+        decoding = lambda eng: int(np.asarray(eng._state["active"]).sum())
+        tokens = lambda req: len(req.output_tokens)
+
+        eng = self._engine(m, params, path)
+        assert eng._paged.use_kernel == (path == "kernel")
+        first = eng.submit(prompts["first"], max_new_tokens=self.MAX_NEW)
+        brief = eng.submit(prompts["brief"], max_new_tokens=2)
+        self._advance_until(eng, lambda: brief.done and tokens(first) >= 6)
+        # one row decodes; one was released and keeps its length; two
+        # were never used
+        assert decoding(eng) == 1 and eng._slot_req.count(None) == 3
+        assert int(np.asarray(eng._state["lengths"])[
+            eng._slot_req.index(first) ^ 1]) > 0
+        long = eng.submit(prompts["long"], max_new_tokens=self.MAX_NEW)
+        longer = eng.submit(prompts["longer"], max_new_tokens=self.MAX_NEW)
+        eng.advance()
+        eng.advance()
+        # still one: the two new ones wait for their chunks
+        assert decoding(eng) == 1 and len(eng._prefill_tasks) == 2
+        self._advance_until(
+            eng, lambda: min(tokens(long), tokens(longer)) >= 5)
+        assert decoding(eng) == 3 and not first.done
+        together = {name: _slot_tokens_kv(eng, req) for name, req in
+                    (("first", first), ("long", long), ("longer", longer))}
+        eng.run()
+        for leaf in jax.tree.leaves(eng._paged.pool):
+            if leaf.ndim >= 4:
+                assert not _pages(leaf)[NULL_PAGE].any()
+        if path == "speculative":
+            assert eng.metrics.snapshot()["spec_proposed_tokens"] > 0
+
+        for name, req in (("first", first), ("long", long),
+                          ("longer", longer)):
+            np.testing.assert_array_equal(
+                np.asarray(req.output_tokens),
+                _generate_ref(m, params, prompts[name], self.MAX_NEW))
+            alone = self._engine(m, params, path)
+            lone = alone.submit(prompts[name], max_new_tokens=self.MAX_NEW)
+            kv, n = together[name]
+            self._advance_until(alone, lambda: lone in alone._slot_req and int(
+                np.asarray(alone._state["lengths"])[
+                    alone._slot_req.index(lone)]) >= n)
+            assert not lone.done
+            kv_alone, n_alone = _slot_tokens_kv(alone, lone)
+            assert n > len(prompts[name]) and n_alone >= n
+            # to the bit; a verify window places a token where its
+            # proposals' fate put it, and a matmul's rounding follows
+            for a, b in zip(kv, kv_alone):
+                np.testing.assert_allclose(
+                    a, b[..., :n], rtol=0, err_msg=name,
+                    atol=1e-5 if path == "speculative" else 0)
+            alone.run()
+            np.testing.assert_array_equal(np.asarray(lone.output_tokens),
+                                          np.asarray(req.output_tokens))
 
 
 # ---------------------------------------------------------------------------
