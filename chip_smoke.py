@@ -13,6 +13,12 @@ width of gpt2-125m with seeded weights and seeded tokens (no network):
            published widths, two layers deep: a dropless top-8 router
            over 64 SwiGLU experts, RMSNorm, QK-norm and RoPE on the paged
            path, the grouped expert matmul compiled by Mosaic;
+- lfm2:    the same serving path with LFM2-24B-A2B's first four layers
+           at published widths (conv, conv, attention, conv; two dense
+           and two expert layers): the gated short convolution's state
+           beside the paged K/V of 8 heads that 32 query heads read in
+           groups, a sigmoid top-4 router with its bias, and a prefix hit
+           that restores the state;
 - kernels: every Pallas kernel compiled by Mosaic and run once at a real
            shape against its jnp reference;
 - offload: offload configs really place state in ``pinned_host``, or
@@ -48,6 +54,12 @@ FULL = {
     "olmoe": dict(n_layers=2, num_slots=4, max_len=512, page_len=128,
                   n_requests=10, prompt_max=300, new_max=24,
                   paging_kernel="auto", logit_tol=0.1),
+    # max_len 2048: a pool of 65 pages, so that one layer's K pages (17
+    # MB in float32) stay well over the decode program's scratch (6.6 MB
+    # of float32 activations: logits over 65,536 words, the experts' rows)
+    "lfm2": dict(n_layers=4, num_slots=4, max_len=2048, page_len=128,
+                 n_requests=10, prompt_max=300, new_max=24,
+                 paging_kernel="auto", logit_tol=0.1),
     "kernels": dict(seq=1024, heads=12, batch=2, cache_len=1024,
                     gemv_k=4096, gemv_n=16384, sparse_seq=2048,
                     gemv_timeout_s=180),
@@ -254,7 +266,9 @@ def _check_pool_stays_in_place(srv, label):
     if any(len(x.sharding.device_set) > 1 for x in kv):
         return
     mem = get_program_registry().get("serving/paged_decode").analyze() or {}
-    pool_bytes = srv._paged.pool_bytes()
+    # (a model with recurrent state keeps it in the pool's tree: the
+    # slots' and the pages', aliased like the K/V)
+    pool_bytes = srv._paged.pool_bytes() + srv._paged.state_bytes()
     layer_slice = kv[0].nbytes // (kv[0].shape[0] if kv[0].ndim == 5 else 1)
     _say(f"{label}: serving/paged_decode temp_bytes "
          f"{mem.get('temp_bytes')}, alias_bytes {mem.get('alias_bytes')}; "
@@ -270,7 +284,8 @@ def _check_pool_stays_in_place(srv, label):
 
 
 def _serve_and_check(eng, module, params, reqs, num_slots, max_len,
-                     page_len, paging_kernel, logit_tol, label):
+                     page_len, paging_kernel, logit_tol, label,
+                     against_generate=True, reference_logits=None):
     import numpy as np
     import jax
     import jax.numpy as jnp
@@ -323,9 +338,10 @@ def _serve_and_check(eng, module, params, reqs, num_slots, max_len,
     full = np.zeros((len(reqs), width), np.int32)
     for i, (h, (p, m)) in enumerate(zip(handles, reqs)):
         full[i, :len(p) + m] = np.concatenate([p, h.output_tokens])
-    ref_logits = np.asarray(jax.jit(
-        lambda prm, ids: ref_model.apply({"params": prm}, ids))(
-            params32, jnp.asarray(full)))
+    if reference_logits is None:
+        reference_logits = jax.jit(
+            lambda prm, ids: ref_model.apply({"params": prm}, ids))
+    ref_logits = np.asarray(reference_logits(params32, jnp.asarray(full)))
     # the tolerance is stated in units of the reference logits' spread:
     # a wrong attention path moves a token's logit by whole sigmas, bf16
     # rounding by hundredths of one
@@ -346,6 +362,8 @@ def _serve_and_check(eng, module, params, reqs, num_slots, max_len,
            f"{label}: a served token is {worst:.4f} below the float32 "
            f"reference's best logit (tolerance {logit_tol:.4f})")
 
+    if not against_generate:
+        return
     # agreement with generate() (the contiguous-cache one-shot path),
     # reported as a count; a mismatch is a failure only when the two
     # candidates' reference logits differ by more than the tolerance
@@ -438,6 +456,90 @@ def phase_olmoe(n_layers, num_slots, max_len, page_len, n_requests,
     _check(least < moved["assignments"] <= most,
            f"serve olmoe: {moved['assignments']} token-expert pairs, "
            f"outside ({least}, {most}]: idle slots or padding were routed")
+
+
+def phase_lfm2(n_layers, num_slots, max_len, page_len, n_requests,
+               prompt_max, new_max, paging_kernel, logit_tol):
+    """LFM2-24B-A2B's first layers at published widths through the same
+    serving path: the chunk-prefill and paged-decode programs carry the
+    convolution state beside the K/V pages (the pool stays where it is,
+    state included: ``_check_pool_stays_in_place``), the paged kernel
+    computes four query heads a K/V head, and a request admitted on a
+    shared page starts from the state stored with it."""
+    import numpy as np
+    import jax
+    import jax.numpy as jnp
+    import deepspeed_tpu as ds
+    from deepspeed_tpu.models.lfm2 import LFM2, LFM2Config
+    from deepspeed_tpu.observability.metrics import get_registry
+
+    layers = LFM2Config().layer_types[:n_layers]
+    model = LFM2(LFM2Config(num_hidden_layers=n_layers, layer_types=layers,
+                            max_position_embeddings=max(max_len, 128),
+                            dtype=jnp.float32, param_dtype=jnp.bfloat16))
+    # float32 activations over the bf16 weights, as the benchmark's cell
+    # runs it: in bf16 a normalised top-4 router's near-ties flip and a
+    # served token leaves the reference by more than the tolerance
+    params = _seeded_params(model)
+    eng = ds.init_inference(model, params=params, dtype=jnp.float32)
+    rng = np.random.default_rng(3)
+    vocab = model.config.vocab_size
+    reqs = _requests(rng, n_requests, vocab, prompt_max, new_max)
+    # the first prompt holds a whole page, and the last request — admitted
+    # once a slot is free, after the first has published — opens with it
+    first = rng.integers(0, vocab, size=page_len + 70, dtype="int32")
+    reqs[0] = (first, reqs[0][1])
+    reqs[-1] = (np.concatenate([first[:page_len], rng.integers(
+        0, vocab, size=20, dtype="int32")]), reqs[-1][1])
+    names = ("serving/prefill_tokens_reused",
+             "serving/state_snapshots_restored", "serving/state_resets",
+             "serving/state_snapshots_stored", "moe/expert_calls")
+    counters = {name: get_registry().counter(name) for name in names}
+    before = {name: c.value for name, c in counters.items()}
+    # ragged generate() refuses such a model (a row's padding would write
+    # state): the served tokens are held to the float32 reference alone —
+    # the family's plain one, every expert computed by XLA's own products
+    # at full precision: the module itself with float32 weights is no
+    # reference on the chip, where the grouped matmul's kernel multiplies
+    # float32 operands in one bf16 pass (0.86 of a logit under the best)
+    from benchmarks.chip.families import lfm2 as family
+    cfg = model.config
+    sizes = {k: getattr(cfg, k) for k in family.SIZE_KEYS}
+    published = {"norm_eps": cfg.norm_eps, "use_expert_bias": True,
+                 "norm_topk_prob": cfg.norm_topk_prob,
+                 "routed_scaling_factor": cfg.routed_scaling_factor,
+                 "rope_parameters": {"rope_theta": cfg.rope_theta}}
+
+    @jax.jit
+    def reference_logits(prm, ids):
+        with jax.default_matmul_precision("highest"):
+            return family.reference_logits(prm, ids, sizes, published,
+                                           near_ties="kept")
+
+    _serve_and_check(eng, model, params, reqs, num_slots, max_len, page_len,
+                     paging_kernel, logit_tol, "serve lfm2",
+                     against_generate=False,
+                     reference_logits=reference_logits)
+    moved = {name: c.value - before[name] for name, c in counters.items()}
+    _say(f"serve lfm2: counted {moved}")
+    _check(moved["serving/prefill_tokens_reused"] >= page_len
+           and moved["serving/state_snapshots_restored"] >= 1,
+           f"serve lfm2: no prefix hit restored a state: {moved}")
+    _check(moved["serving/state_snapshots_restored"]
+           + moved["serving/state_resets"] == n_requests
+           and moved["serving/state_snapshots_stored"] >= 1,
+           f"serve lfm2: admissions and stored states do not add up: {moved}")
+    _check(moved["moe/expert_calls"] > 0
+           and moved["moe/expert_calls"] % (n_layers - 2) == 0,
+           f"serve lfm2: the expert layers' calls were not counted: {moved}")
+    try:
+        eng.generate(np.zeros((2, 8), np.int32), max_new_tokens=2,
+                     prompt_lengths=np.asarray([8, 5], np.int32))
+    except NotImplementedError as e:
+        _say(f"serve lfm2: ragged generate() refused: {e}")
+    else:
+        _check(False, "serve lfm2: ragged generate() did not refuse a "
+                      "model with recurrent state")
 
 
 # ---------------------------------------------------------------------------
@@ -952,7 +1054,8 @@ def main():
     _say(f"compile cache at {cache_dir}")
 
     phases = [("train", phase_train), ("serve", phase_serve),
-              ("olmoe", phase_olmoe), ("kernels", phase_kernels)]
+              ("olmoe", phase_olmoe), ("lfm2", phase_lfm2),
+              ("kernels", phase_kernels)]
     if device["count"] >= 4:
         phases.append(("multichip", phase_multichip))
     else:

@@ -221,7 +221,7 @@ def _walk_with(pool, src, fn):
     def walk(dst, s):
         if _is_attn_unit(dst):
             return fn(dict(dst), s)
-        if isinstance(dst, dict):
+        if isinstance(dst, dict) and not _is_state_unit(dst):
             return {k: walk(v, s[k]) for k, v in dst.items()}
         return dst
 
@@ -459,3 +459,125 @@ def make_paged_view(pool, page_table, lengths):
         }
 
     return {"cache": _map_units(pool, small_state), "kv_pool": _as_dict(pool)}
+
+
+# ---------------------------------------------------------------------------
+# recurrent state beside the pages (models/layers.py ShortConv): a layer
+# whose "cache" unit is a fixed-size state, ``conv_state`` ``[batch, ...]``,
+# and not keys and values. In a page pool such a unit holds two leaves:
+# ``conv_state`` ``[slots, ...]``, each slot's state where its request now
+# stands (what a decode step reads and advances), and ``page_state``
+# ``[pages, ...]``, the state at the END of each page's last token, written
+# by the prefill chunk that filled the page. A page is only ever read
+# together with the state at its end: a prefill chunk starts on a page
+# boundary and takes the state of the page before it, its own request's or
+# one shared through the prefix cache, and position 0 takes zeros. So a
+# prefix hit restores the state with no host work, and a slot's stale
+# state is never read by its next request.
+# ---------------------------------------------------------------------------
+
+_STATE_KEY = "conv_state"
+_PAGE_STATE_KEY = "page_state"
+
+
+def _is_state_unit(d) -> bool:
+    return isinstance(d, dict) and _STATE_KEY in d
+
+
+def _walk_state(tree, fn, *srcs):
+    """Rebuild ``tree`` with ``fn(unit, *src_units)`` at every state unit;
+    each of ``srcs`` mirrors the tree's structure at least down to those
+    units."""
+
+    def walk(node, ss):
+        if _is_state_unit(node):
+            return fn(dict(node), *ss)
+        if isinstance(node, dict) and not _is_attn_unit(node):
+            return {k: walk(v, [s[k] for s in ss] if isinstance(v, dict)
+                            else ss) for k, v in node.items()}
+        return node
+
+    return walk(_as_dict(tree), [_as_dict(s) for s in srcs])
+
+
+def state_units(tree) -> list:
+    """The tree's state units, in walking order: a model without
+    recurrent state has none, and the programs' branch on
+    ``len(state_units(pool))`` is structural — such a model traces
+    exactly what it traced before."""
+    found = []
+    _walk_state(tree, lambda u: found.append(u) or u)
+    return found
+
+
+def has_recurrent_state(tree) -> bool:
+    return len(state_units(tree)) > 0
+
+
+def add_slot_state(pool, slot_cache):
+    """The pool of a model with recurrent state: each state unit's own
+    leaf (``[pages, ...]``, from the pool's shape-only init) becomes
+    ``page_state`` and ``slot_cache``'s (``[slots, ...]``, the same init
+    over the slot batch) ``conv_state``."""
+    return _walk_state(
+        pool, lambda unit, slots: {_STATE_KEY: slots[_STATE_KEY],
+                                   _PAGE_STATE_KEY: unit[_STATE_KEY]},
+        slot_cache)
+
+
+def slot_state_view(cache):
+    """``cache`` as the module takes it: a state unit's ``conv_state``
+    alone."""
+    return _walk_state(cache, lambda u: {_STATE_KEY: u[_STATE_KEY]})
+
+
+def chunk_state_view(cache, pool, prev_page, fresh):
+    """A single-row cache for a prefill chunk: every state unit starts
+    from the state at the end of ``prev_page`` (the physical page before
+    the chunk's first), or from zeros when ``fresh`` (the chunk starts at
+    position 0)."""
+
+    def start(_, unit):
+        at_page = jax.lax.dynamic_index_in_dim(
+            unit[_PAGE_STATE_KEY], prev_page, axis=0, keepdims=True)
+        return {_STATE_KEY: jnp.where(fresh, 0.0, at_page)}
+
+    return _walk_state(cache, start, pool)
+
+
+def store_decode_state(pool, cache_out):
+    """After a decode step: every slot's state as the module left it (a
+    row that held no token kept its own)."""
+    return _walk_state(
+        pool, lambda unit, out: {**unit, _STATE_KEY: out[_STATE_KEY]},
+        cache_out)
+
+
+def store_chunk_state(pool, cache_out, token_tree, slot, page_run):
+    """After a prefill chunk: the slot's state is the row's (the state
+    after the chunk's last live token), and each page of ``page_run``
+    gets the state at its end, cut from the unit's ``trail`` (the
+    chunk's starting state followed by one column a position): page
+    ``i`` of the chunk ends ``(i + 1) * page_len`` columns in. A page
+    the chunk only partly fills gets columns of its padding: such a page
+    is never shared, and no chunk ever starts after it."""
+    n_t = page_run.shape[0]
+
+    def store(unit, out, tok):
+        trail = tok["trail"][0]                    # [taps-1 + chunk, d]
+        width = unit[_STATE_KEY].shape[1]
+        page_len = (trail.shape[0] - width) // n_t
+        ends = jnp.stack([trail[(i + 1) * page_len:(i + 1) * page_len + width]
+                          for i in range(n_t)])
+        return {
+            _STATE_KEY: unit[_STATE_KEY].at[slot].set(out[_STATE_KEY][0]),
+            _PAGE_STATE_KEY: unit[_PAGE_STATE_KEY].at[page_run].set(ends)}
+
+    return _walk_state(pool, store, cache_out, token_tree)
+
+
+def state_bytes(pool) -> int:
+    """Resident bytes of the recurrent state, slots and pages together
+    (0 for a model without)."""
+    return sum(int(leaf.size) * leaf.dtype.itemsize
+               for unit in state_units(pool) for leaf in unit.values())

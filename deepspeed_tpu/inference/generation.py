@@ -365,6 +365,15 @@ def _generate_ragged(module, params, input_ids, lengths, *, max_new_tokens,
     # whole padded batch even though only [0, len_i) per row stays valid
     cache_len = (max(total, width) + 127) // 128 * 128
     cache = init_cache(module, params, b, cache_len)
+    from .cache import has_recurrent_state
+    if has_recurrent_state(cache):
+        # K/V written by a row's padding is masked by its length; a
+        # state it advanced is not
+        raise NotImplementedError(
+            f"ragged generate() of {type(module).__name__}: the prefill "
+            "runs every row over the padded width, and a row's padding "
+            "would write its recurrent (convolution) state; generate "
+            "equal-length rows, or serve() it")
     logits, cache = _prefill_donating(module, params, cache, input_ids,
                                       jnp.arange(width), param_transform)
     last_logits = jnp.take_along_axis(

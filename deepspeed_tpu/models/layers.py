@@ -189,6 +189,51 @@ def _check_sparse_compat(sparsity_config, bias, causal, alibi=False):
             "attention='unidirectional' (the layout encodes causality)")
 
 
+def split_terms(x, n=3):
+    """A float32 array as ``n`` bfloat16 terms whose sum is it to 8 x n
+    bits: ``[n, *x.shape]``, largest first (three are float32's 24).
+    Each term is rounded by ``lax.reduce_precision``, which a compiler
+    may not remove: XLA:TPU takes a float32 -> bfloat16 -> float32 round
+    trip for excess precision it is allowed to keep, the remainder is
+    then zero, and the "three terms" are one bfloat16 product (my chip
+    run, PR 33: 0.92 sigma where this reads under 0.01)."""
+    terms, rest = [], x.astype(jnp.float32)
+    for _ in range(n):
+        term = jax.lax.reduce_precision(rest, exponent_bits=8,
+                                        mantissa_bits=7)
+        terms.append(term.astype(jnp.bfloat16))      # exact
+        rest = rest - term
+    return jnp.stack(terms)
+
+
+def exact_weights(x, w) -> bool:
+    """Whether ``x @ w`` goes as ``dot_exact_weights`` and
+    ``moe.sharded_moe.grouped_matmul`` make it exact: float32
+    activations over weights kept in bfloat16, **traced under**
+    ``jax.default_matmul_precision("highest")``. The caller asks for it
+    (``models/lfm2.py`` does, for a float32 ``dtype``); without that
+    ambient setting the pair is the plain product it always was, one
+    pass of the MXU."""
+    return (x.dtype == jnp.float32 and w.dtype == jnp.bfloat16
+            and jax.config.jax_default_matmul_precision == "highest")
+
+
+def dot_exact_weights(x, w):
+    """``x @ w``; where ``exact_weights`` holds, to float32's accuracy:
+    the activations go as three bfloat16 terms (``split_terms``) through
+    one matmul that accumulates in float32, and the weights as they are,
+    which they are exactly — three passes of the MXU over weights read
+    once, and no float32 copy of a weight is ever made (what XLA makes of
+    ``Precision.HIGHEST`` on a cast weight is six passes, three of them
+    over the zeros of the weight's lower terms). Otherwise the plain
+    product in ``x``'s type."""
+    if exact_weights(x, w):
+        return jnp.sum(jnp.dot(split_terms(x), w,
+                               precision=jax.lax.Precision.DEFAULT,
+                               preferred_element_type=jnp.float32), axis=0)
+    return jnp.dot(x, w.astype(x.dtype))
+
+
 class QDense(nn.Module):
     """DenseGeneral twin that can consume weight-only int8 params.
 
@@ -234,7 +279,7 @@ class QDense(nn.Module):
             y = wo_int8_matmul(x, kernel["q"], kernel["scale"],
                                out_dtype=self.dtype)
         else:
-            y = jnp.dot(x, kernel.astype(self.dtype))
+            y = dot_exact_weights(x, kernel)
         if bias is not None:
             y = y + bias.astype(self.dtype)
         return y
@@ -307,9 +352,14 @@ class SelfAttention(nn.Module):
     rotary: bool = False
     rotary_dim: Optional[int] = None
     rotary_base: float = 10000.0
-    qk_norm: bool = False                # RMSNorm over the whole q and k
-                                         # projections, before the heads split
+    qk_norm: Any = False                 # True: RMSNorm over the whole q and k
+                                         # projections, before the heads split;
+                                         # "head": over each head's values,
+                                         # after it
     norm_epsilon: float = 1e-5           # (qk_norm's)
+    n_kv_heads: Optional[int] = None     # fewer K/V heads than query heads:
+                                         # query head i reads K/V head
+                                         # i // (n_heads // n_kv_heads)
     attn_backend: Optional[str] = None
     alibi: bool = False
     seq_parallel: Optional[str] = None   # None=auto, "ulysses", "ring", "none"
@@ -321,22 +371,33 @@ class SelfAttention(nn.Module):
     def __call__(self, x, mask=None, bias=None, deterministic=True,
                  decode=False, positions=None):
         head_dim = self.d_model // self.n_heads
+        n_kv = self.n_kv_heads or self.n_heads
+        group = self.n_heads // n_kv         # query heads a K/V head serves
+        kv_width = n_kv * head_dim
         qkv = QDense(
-            features=3 * self.d_model, use_bias=self.use_bias, dtype=self.dtype,
-            param_dtype=self.param_dtype,
+            features=self.d_model + 2 * kv_width, use_bias=self.use_bias,
+            dtype=self.dtype, param_dtype=self.param_dtype,
             kernel_init=dense_init(("embed", "qkv")),
             bias_init=nn.with_logical_partitioning(nn.initializers.zeros, ("qkv",)),
             name="qkv")(x)
-        q, k, v = jnp.split(qkv, 3, axis=-1)
-        if self.qk_norm:
-            # here and nowhere else: the flash path, the chunked prefill
-            # and the paged decode all take q and k from this point
+        if group == 1:
+            q, k, v = jnp.split(qkv, 3, axis=-1)
+        else:
+            q, k, v = jnp.split(
+                qkv, [self.d_model, self.d_model + kv_width], axis=-1)
+        # the QK norm sits here and nowhere else: the flash path, the
+        # chunked prefill and the paged decode all take q and k from
+        # this point
+        if self.qk_norm is True:
             q = RMSNorm(epsilon=self.norm_epsilon, name="q_norm")(q)
             k = RMSNorm(epsilon=self.norm_epsilon, name="k_norm")(k)
         b, s = x.shape[0], x.shape[1]
         q = q.reshape(b, s, self.n_heads, head_dim)
-        k = k.reshape(b, s, self.n_heads, head_dim)
-        v = v.reshape(b, s, self.n_heads, head_dim)
+        k = k.reshape(b, s, n_kv, head_dim)
+        v = v.reshape(b, s, n_kv, head_dim)
+        if self.qk_norm == "head":
+            q = RMSNorm(epsilon=self.norm_epsilon, name="q_norm")(q)
+            k = RMSNorm(epsilon=self.norm_epsilon, name="k_norm")(k)
 
         if self.rotary:
             from ..ops.transformer.rotary import apply_rotary_pos_emb
@@ -511,6 +572,11 @@ class SelfAttention(nn.Module):
                     from ..ops.pallas import decode_attention
                     slopes = (alibi_slopes(self.n_heads)
                               if self.alibi else None)
+                    if group > 1:
+                        # the contiguous decode kernel takes one K/V
+                        # head a query head (the paged one groups them)
+                        k_all = jnp.repeat(k_all, group, axis=1)
+                        v_all = jnp.repeat(v_all, group, axis=1)
                     decode_out = decode_attention(
                         q, k_all, v_all, idx + 1, alibi_slopes=slopes,
                         mesh=_usable_global_mesh())
@@ -554,6 +620,12 @@ class SelfAttention(nn.Module):
                 bias_init=nn.with_logical_partitioning(
                     nn.initializers.zeros, ("embed",)),
                 name="out")(out)
+
+        if group > 1:
+            # the flash path and the chunk's gathered row: each K/V head
+            # stands for its group of query heads
+            k = jnp.repeat(k, group, axis=2)
+            v = jnp.repeat(v, group, axis=2)
 
         if self.alibi:
             # computed HERE (not in the model) because only the attention op
@@ -667,6 +739,96 @@ class MLP(nn.Module):
         if self.dropout_rate > 0.0 and not deterministic:
             h = nn.Dropout(rate=self.dropout_rate)(h, deterministic=False)
         return h
+
+
+class GatedMLP(nn.Module):
+    """The gated (SwiGLU) feed-forward of the Llama line and its kin:
+    ``w2(silu(w1 x) * w3 x)``, no bias."""
+    d_model: int
+    d_ff: int
+    dtype: Any = jnp.bfloat16
+    param_dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(self, x):
+        def dense(features, names, name):
+            return QDense(features=features, use_bias=False, dtype=self.dtype,
+                          param_dtype=self.param_dtype,
+                          kernel_init=dense_init(names), name=name)
+        h = jax.nn.silu(dense(self.d_ff, ("embed", "mlp"), "w1")(x)) \
+            * dense(self.d_ff, ("embed", "mlp"), "w3")(x)
+        h = activation_constraint(h, ("batch", "seq", "mlp"))
+        return dense(self.d_model, ("mlp", "embed"), "w2")(h)
+
+
+class ShortConv(nn.Module):
+    """The gated short convolution of the LFM2 line (Liquid AI; HF
+    ``Lfm2ShortConv``): ``[B, C, u] = W_in x`` (three parts of
+    ``d_model``), a depthwise causal convolution of ``kernel`` taps over
+    time on ``B * u`` — ``z_t = sum_j w_j (B u)_{t - (kernel-1) + j}``, no
+    bias, no activation — and ``W_out (C * z)``.
+
+    Its recurrent state is the last ``kernel - 1`` columns of ``B * u``,
+    float32. One computation serves the three forms the engines need: it
+    lays the state before the sequence's own ``B * u`` and sums the
+    ``kernel`` shifted products, so a whole sequence (state zero: no
+    ``decode``), a prefill chunk that takes the carry of the chunk before
+    it and one decode token against its slot's carry are the same
+    products in the same order, and agree to the bit in float32.
+
+    ``decode=True`` keeps the state in the "cache" collection
+    (``conv_state`` ``[batch, kernel - 1, d_model]``) as
+    ``SelfAttention`` keeps its keys and values. ``token_mask``
+    (``[batch, seq]`` bool; the live rows are a prefix of each row) names
+    the positions that hold a token: the new state is the one after the
+    last live position, so an idle slot (no live position) keeps its
+    state and a chunk's right padding writes none. A caller that lists
+    "kv_token" as mutable also gets ``trail`` there: the state followed
+    by the sequence's ``B * u``, ``[batch, kernel - 1 + seq, d_model]``,
+    from which the state at the end of any position is a slice (the
+    paged server keeps one a page, ``inference/cache.py``)."""
+    d_model: int
+    kernel: int = 3
+    dtype: Any = jnp.bfloat16
+    param_dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(self, x, decode=False, token_mask=None):
+        b, s, d = x.shape
+        taps = self.kernel
+        bcu = QDense(features=3 * d, use_bias=False, dtype=self.dtype,
+                     param_dtype=self.param_dtype,
+                     kernel_init=dense_init(("embed", "mlp")),
+                     name="in_proj")(x)
+        gate_b, gate_c, u = jnp.split(bcu.astype(jnp.float32), 3, axis=-1)
+        # [taps, d]: tap j multiplies the column (taps - 1 - j) steps back
+        w = self.param("w", nn.with_logical_partitioning(
+            nn.initializers.variance_scaling(1.0, "fan_in", "normal",
+                                             in_axis=0, out_axis=1),
+            (None, "embed")), (taps, d), self.param_dtype)
+        bu = gate_b * u                                    # [b, s, d] f32
+        state = None
+        if decode:
+            state = self.variable("cache", "conv_state", jnp.zeros,
+                                  (b, taps - 1, d), jnp.float32)
+        carry = (jnp.zeros((b, taps - 1, d), jnp.float32)
+                 if state is None or self.is_initializing() else state.value)
+        trail = jnp.concatenate([carry, bu], axis=1)   # [b, taps-1+s, d]
+        z = sum(w[j].astype(jnp.float32) * trail[:, j:j + s]
+                for j in range(taps))
+        if state is not None and not self.is_initializing():
+            live = (jnp.full((b,), s, jnp.int32) if token_mask is None
+                    else jnp.sum(token_mask, axis=1, dtype=jnp.int32))
+            state.value = jax.vmap(
+                lambda t, n: jax.lax.dynamic_slice_in_dim(t, n, taps - 1))(
+                trail, live)
+            if self.is_mutable_collection("kv_token"):
+                self.variable("kv_token", "trail", lambda: trail).value = \
+                    trail
+        return QDense(features=d, use_bias=False, dtype=self.dtype,
+                      param_dtype=self.param_dtype,
+                      kernel_init=dense_init(("mlp", "embed")),
+                      name="out_proj")(gate_c * z)
 
 
 class Block(nn.Module):

@@ -121,6 +121,15 @@ def expert_stack(module, n_layers, num_experts, d, f, param_dtype):
             one("w_down", (f, d), ("mlp", "embed")))
 
 
+# the spread of a seeded ``expert_bias``: a twentieth of the spread of a
+# seeded router's sigmoid scores (~0.2). A trained model's bias is what
+# keeps its experts balanced; a drawn one of 0.1 unbalanced them as no
+# trained router is (the largest group five times the mean) and made a
+# server's speed the seed's (PERF.md section 6, PR 33). Still not zero:
+# a program that put it in the weights, or left it out, reads wrong
+EXPERT_BIAS_INIT_STD = 0.01
+
+
 class DroplessMoE(nn.Module):
     """A dropless top-k expert layer of gated (SwiGLU) experts, as
     OLMoE, Mixtral and their kin publish it:
@@ -145,6 +154,11 @@ class DroplessMoE(nn.Module):
     num_experts_per_tok: int
     norm_topk_prob: bool = False
     dtype: Any = jnp.bfloat16
+    # the sigmoid router of the DeepSeek-V3 line (``topk_routing``): with
+    # ``use_expert_bias`` the layer holds ``expert_bias`` [E], added to
+    # the scores to choose and never to weigh
+    score: str = "softmax"
+    use_expert_bias: bool = False
 
     @nn.compact
     def __call__(self, x, deterministic=True, token_mask=None, *, experts,
@@ -158,8 +172,18 @@ class DroplessMoE(nn.Module):
             (d, self.num_experts), jnp.float32)
         logits = jnp.dot(tokens.astype(jnp.float32), router,
                          precision=jax.lax.Precision.HIGHEST)
+        # a trained model's bias is the balance its training kept, and a
+        # seeded one's is drawn, not zero: a program that added it to the
+        # weights, or left it out, would otherwise read like one that is
+        # right
+        bias = self.param(
+            "expert_bias", nn.with_logical_partitioning(
+                nn.initializers.normal(EXPERT_BIAS_INIT_STD), (None,)),
+            (self.num_experts,), jnp.float32) \
+            if self.use_expert_bias else None
         probs, weights, chosen = topk_routing(
-            logits, self.num_experts_per_tok, self.norm_topk_prob)
+            logits, self.num_experts_per_tok, self.norm_topk_prob,
+            score=self.score, bias=bias)
         out, counts = dropless_experts(
             tokens.astype(self.dtype), weights, chosen, *experts, layer,
             live=live)

@@ -18,7 +18,7 @@ import jax
 import jax.numpy as jnp
 import flax.linen as nn
 
-from deepspeed_tpu.models.layers import QDense
+from deepspeed_tpu.models.layers import QDense, exact_weights, split_terms
 
 from ..comm.mesh import get_global_mesh
 
@@ -208,18 +208,37 @@ class TopKGate(nn.Module):
 # what a server needs, where a capacity gate would let a neighbour's
 # routing push a token over an expert's limit.
 
-def topk_routing(logits, k: int, renormalize: bool = False):
-    """Softmax over ALL experts in float32, then the k largest.
+def topk_routing(logits, k: int, renormalize: bool = False, *,
+                 score: str = "softmax", bias=None):
+    """Every expert's score in float32, then the k largest.
 
-    logits: [T, E]. Returns (probs [T, E] f32, weights [T, k] f32,
-    experts [T, k] int32). ``renormalize`` divides the k weights by their
-    sum (a ``config.json``'s ``norm_topk_prob``); off, they are used as
-    the softmax gave them."""
-    probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
-    weights, experts = jax.lax.top_k(probs, k)
+    logits: [T, E]. Returns (scores [T, E] f32, weights [T, k] f32,
+    experts [T, k] int32). ``score="softmax"`` (OLMoE, Mixtral): a
+    softmax over ALL experts; ``renormalize`` divides the k weights by
+    their sum (a ``config.json``'s ``norm_topk_prob``); off, they are
+    used as the softmax gave them.
+
+    ``score="sigmoid"`` (the DeepSeek-V3 line's router, as LFM2's MoE
+    publishes it): each expert's score is its own sigmoid. ``bias``
+    ``[E]`` (the load-balancing ``expert_bias``) is added to the scores
+    to CHOOSE the k experts and never weighs them: the weights are the
+    chosen experts' unbiased scores, divided under ``renormalize`` by
+    their sum plus the published ``1e-6``."""
+    if score == "softmax":
+        probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
+        weights, experts = jax.lax.top_k(probs, k)
+        if renormalize:
+            weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
+        return probs, weights, experts.astype(jnp.int32)
+    if score != "sigmoid":
+        raise ValueError(f"unknown router score {score!r}")
+    scores = jax.nn.sigmoid(logits.astype(jnp.float32))
+    _, experts = jax.lax.top_k(
+        scores if bias is None else scores + bias.astype(jnp.float32), k)
+    weights = jnp.take_along_axis(scores, experts, axis=-1)
     if renormalize:
-        weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
-    return probs, weights, experts.astype(jnp.int32)
+        weights = weights / (jnp.sum(weights, axis=-1, keepdims=True) + 1e-6)
+    return scores, weights, experts.astype(jnp.int32)
 
 
 def mean_gate(probs, live=None):
@@ -269,6 +288,19 @@ def grouped_matmul(rows, w, groups, **kw):
     at a time (more where that would take over ``MAX_CALLS`` calls),
     each call with the sizes of the groups' parts that lie in its
     rows."""
+    if exact_weights(rows, w):
+        # float32 rows over weights kept in bfloat16 (models/layers.py
+        # dot_exact_weights): each row goes as its three bfloat16 terms,
+        # side by side in its group, and the three products are summed —
+        # the stack of weights is never cast, and is read once
+        m, n = rows.shape[0], 3
+        terms = split_terms(rows, n).transpose(1, 0, 2).reshape(m * n, -1)
+        # (bfloat16 terms have no lower passes to make: a caller's
+        # default of HIGHEST would only be refused by the kernel)
+        out = grouped_matmul(terms, w, groups * n,
+                             preferred_element_type=jnp.float32,
+                             precision=jax.lax.Precision.DEFAULT)
+        return jnp.sum(out.reshape(m, n, -1), axis=1)
     m = rows.shape[0]
     tile = max(ROW_TILE, -(-m // MAX_CALLS))
     if m <= tile:
@@ -326,10 +358,13 @@ def dropless_experts(tokens, weights, experts, w_gate, w_up, w_down, layer,
     w_gate, w_up, w_down = (w.reshape((-1,) + w.shape[2:])
                             for w in (w_gate, w_up, w_down))
     rows = jnp.take(tokens, order // k, axis=0)     # [T*k, d]
-    g = grouped_matmul(rows, w_gate.astype(rows.dtype), groups)
-    u = grouped_matmul(rows, w_up.astype(rows.dtype), groups)
+    # (exact: float32 rows take bfloat16 weights as they are, grouped_matmul)
+    cast = (lambda w: w) if exact_weights(rows, w_gate) \
+        else (lambda w: w.astype(rows.dtype))
+    g = grouped_matmul(rows, cast(w_gate), groups)
+    u = grouped_matmul(rows, cast(w_up), groups)
     h = jax.nn.silu(g) * u
-    y = grouped_matmul(h, w_down.astype(rows.dtype), groups,
+    y = grouped_matmul(h, cast(w_down), groups,
                        preferred_element_type=jnp.float32)
     # rows past the last group belong to no expert
     in_group = jnp.arange(n_tokens * k) < jnp.sum(counts)

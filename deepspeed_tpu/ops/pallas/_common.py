@@ -92,7 +92,7 @@ def read_slopes(slopes_ref, h0: int, hb: int):
 
 
 def online_softmax_block(q, kblk, vblk, start, valid_len, q_pos, slopes,
-                         m_ref, l_ref, acc_ref, *, hb, alibi):
+                         m_ref, l_ref, acc_ref, *, hb, alibi, group=1):
     """One online-softmax update for an [hb, d, Bk] K^T/V block — THE
     inner loop shared by the decode-attention and paged-attention
     kernels (one definition, or the two online-softmax recurrences
@@ -103,6 +103,11 @@ def online_softmax_block(q, kblk, vblk, start, valid_len, q_pos, slopes,
     call). Per-head scores are hb small matmuls (MHA has distinct K per
     head, so there is no single big matmul); the softmax/statistics
     update is vectorized across the head block.
+
+    ``group`` query heads read each K/V head (grouped-query attention):
+    q, the statistics and the accumulator then hold ``hb * group`` rows,
+    K/V head ``h``'s group in rows ``[h * group, (h + 1) * group)``, and
+    a head's block is multiplied once for its whole group.
 
     ``valid_len`` masks columns (``start + i < valid_len`` attend);
     ``q_pos`` is the query's absolute position — the ALiBi center
@@ -117,9 +122,9 @@ def online_softmax_block(q, kblk, vblk, start, valid_len, q_pos, slopes,
     rows = []
     for h in range(hb):
         kh = kblk[h].astype(jnp.float32)                     # [d, Bk]
-        rows.append(jnp.dot(q[h:h + 1], kh,
-                            preferred_element_type=jnp.float32))  # [1, Bk]
-    s = jnp.concatenate(rows, axis=0)                        # [hb, Bk]
+        rows.append(jnp.dot(q[h * group:(h + 1) * group], kh,
+                            preferred_element_type=jnp.float32))  # [g, Bk]
+    s = jnp.concatenate(rows, axis=0)                        # [hb*g, Bk]
     col = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1) + start
     if alibi:
         s = s + slopes * (col - q_pos).astype(jnp.float32)
@@ -134,11 +139,12 @@ def online_softmax_block(q, kblk, vblk, start, valid_len, q_pos, slopes,
     for h in range(hb):
         # columns past the valid prefix may hold padding garbage —
         # 0-probability x NaN = NaN, so zero the V columns explicitly
-        vh = jnp.where(valid[h:h + 1], vblk[h].astype(jnp.float32), 0.0)
+        vh = jnp.where(valid[h * group:h * group + 1],
+                       vblk[h].astype(jnp.float32), 0.0)
         outs.append(jax.lax.dot_general(
-            p[h:h + 1], vh, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32))             # [1, d]
-    pv = jnp.concatenate(outs, axis=0)                       # [hb, d]
+            p[h * group:(h + 1) * group], vh, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32))             # [g, d]
+    pv = jnp.concatenate(outs, axis=0)                       # [hb*g, d]
     l_ref[...] = corr * l_ref[...] + jnp.sum(p, axis=-1, keepdims=True)
     acc_ref[...] = corr * acc_ref[...] + pv
     m_ref[...] = m_new

@@ -76,6 +76,7 @@ from ._common import read_slopes as _read_slopes
 
 DEFAULT_BLOCK_TOKENS = 512
 DEFAULT_HEAD_BLOCK = 8
+MAX_ROWS = 8          # query-head rows of one grid step
 
 KERNEL = "paged_attention"
 
@@ -96,7 +97,7 @@ def _fold_current_token(q, kn, vn, m_ref, l_ref, acc_ref):
 
 def _dma_kernel(len_ref, ptab_ref, slopes_ref, layer_ref, q_ref, kn_ref,
                 vn_ref, *refs, scale, page_len, ppb, hb, alibi, quant,
-                max_pages):
+                max_pages, group):
     if quant:
         (kp_hbm, vp_hbm, ksp_hbm, vsp_hbm, o_ref,
          kbuf0, vbuf0, kbuf1, vbuf1, ksb0, vsb0, ksb1, vsb1,
@@ -114,7 +115,8 @@ def _dma_kernel(len_ref, ptab_ref, slopes_ref, layer_ref, q_ref, kn_ref,
     m_ref[...] = jnp.full_like(m_ref, NEG_INF)
     l_ref[...] = jnp.zeros_like(l_ref)
     acc_ref[...] = jnp.zeros_like(acc_ref)
-    slopes = _read_slopes(slopes_ref, hi * hb, hb) if alibi else None
+    slopes = (_read_slopes(slopes_ref, hi * hb * group, hb * group)
+              if alibi else None)
 
     def copies(j, slot):
         """The slot's page DMAs for block ``j``: ``ppb`` physical pages
@@ -171,7 +173,7 @@ def _dma_kernel(len_ref, ptab_ref, slopes_ref, layer_ref, q_ref, kn_ref,
                 # length, query position = length (folded in below)
                 _attend_block(q, kblk, vblk, j * bt, length, length,
                               slopes, m_ref, l_ref, acc_ref, hb=hb,
-                              alibi=alibi)
+                              alibi=alibi, group=group)
         return carry
 
     jax.lax.fori_loop(0, nb, body, 0)
@@ -185,9 +187,14 @@ def _dma_kernel(len_ref, ptab_ref, slopes_ref, layer_ref, q_ref, kn_ref,
 def _paged_dma(q_bhd, kp, vp, ptab, lengths, kn, vn, ks, vs, slopes, layer,
                *, scale, page_len, ppb, hb, alibi):
     b, heads, d = q_bhd.shape
-    n_layers, num_pages = kp.shape[:2]
+    n_layers, num_pages, kv_heads = kp.shape[:3]
     max_pages = ptab.shape[1]
-    nhb = heads // hb
+    # grouped-query attention: the grid walks the pool's K/V heads, and
+    # a step takes the whole group of query heads that read its ``hb``:
+    # a page is fetched once for all of them
+    group = heads // kv_heads
+    nhb = kv_heads // hb
+    rows = hb * group
     quant = ks is not None
     # the whole stacked pool is the operand (memory_space ANY: it stays in
     # HBM, nothing is copied for the call); the kernel picks the layer
@@ -204,21 +211,22 @@ def _paged_dma(q_bhd, kp, vp, ptab, lengths, kn, vn, ks, vs, slopes, layer,
         scratch += [sc_buf(), sc_buf(), sc_buf(), sc_buf()]
     scratch += [
         pltpu.SemaphoreType.DMA((2, 4 if quant else 2, ppb)),
-        pltpu.VMEM((hb, 1), jnp.float32),
-        pltpu.VMEM((hb, 1), jnp.float32),
-        pltpu.VMEM((hb, d), jnp.float32),
+        pltpu.VMEM((rows, 1), jnp.float32),
+        pltpu.VMEM((rows, 1), jnp.float32),
+        pltpu.VMEM((rows, d), jnp.float32),
     ]
     # per-token operands ride as [B, heads/hb, hb, d] so the (hb, d)
     # tile is the array's own last two dims: a (1, hb, d) block of
     # [B, H, d] is refused by the Mosaic lowering unless hb % 8 == 0
     # or hb == H
-    tok_spec = lambda: pl.BlockSpec((1, 1, hb, d),
+    # (k_new / v_new arrive one row a QUERY head, as q does)
+    tok_spec = lambda: pl.BlockSpec((1, 1, rows, d),
                                     lambda bi, hi, *_: (bi, hi, 0, 0))
-    tok = lambda x: x.reshape(b, nhb, hb, d)
+    tok = lambda x: x.reshape(b, nhb, rows, d)
     out = pl.pallas_call(
         functools.partial(_dma_kernel, scale=scale, page_len=page_len,
                           ppb=ppb, hb=hb, alibi=alibi, quant=quant,
-                          max_pages=max_pages),
+                          max_pages=max_pages, group=group),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=4,
             grid=(b, nhb),
@@ -227,7 +235,7 @@ def _paged_dma(q_bhd, kp, vp, ptab, lengths, kn, vn, ks, vs, slopes, layer,
             out_specs=tok_spec(),
             scratch_shapes=scratch,
         ),
-        out_shape=jax.ShapeDtypeStruct((b, nhb, hb, d), q_bhd.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, nhb, rows, d), q_bhd.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")),
         interpret=_interpret(),
@@ -250,6 +258,9 @@ def _paged_dense(q_bhd, kp, vp, ptab, lengths, kn, vn, ks, vs, slopes,
     if ks is not None:
         gk = gk.astype(jnp.float32) * ks[at]
         gv = gv.astype(jnp.float32) * vs[at]
+    if gk.shape[2] != heads:                       # grouped-query heads
+        gk = jnp.repeat(gk, heads // gk.shape[2], axis=2)
+        gv = jnp.repeat(gv, heads // gv.shape[2], axis=2)
     m = ptab.shape[1]
     s_tot = m * page_len
     k_all = gk.transpose(0, 2, 3, 1, 4).reshape(b, heads, d, s_tot)
@@ -338,8 +349,18 @@ def paged_attention(q, k_pages, v_pages, page_table, lengths, k_new, v_new,
 
     lengths = jnp.broadcast_to(jnp.asarray(lengths, jnp.int32), (b,))
     page_table = jnp.asarray(page_table, jnp.int32)
-    kn = k_new.reshape(b, heads, d)
-    vn = v_new.reshape(b, heads, d)
+    kv_heads = k_pages.shape[2]
+    if heads % kv_heads:
+        raise ValueError(f"{heads} query heads over a pool of {kv_heads} "
+                         "K/V heads: not a whole group each")
+    kn = k_new.reshape(b, kv_heads, d)
+    vn = v_new.reshape(b, kv_heads, d)
+    if kv_heads != heads:
+        # grouped-query attention: query head i reads K/V head
+        # i // group. The pool keeps kv_heads; the current token's K/V
+        # (one column, [B, heads, d]) rides one row a query head
+        kn = jnp.repeat(kn, heads // kv_heads, axis=1)
+        vn = jnp.repeat(vn, heads // kv_heads, axis=1)
     alibi = alibi_slopes is not None
     slopes = (jnp.asarray(alibi_slopes, jnp.float32) if alibi
               else jnp.zeros((heads,), jnp.float32))
@@ -353,22 +374,32 @@ def paged_attention(q, k_pages, v_pages, page_table, lengths, k_new, v_new,
         KERNEL, structure, sq=b, sk=max_pages * page_len, d=d,
         dtype=k_pages.dtype, causal=True)
     bt = int(entry.get("block_k") or block_tokens or DEFAULT_BLOCK_TOKENS)
-    tp = model_axis_size(mesh, heads)
-    hb = pick_head_block(heads // tp, int(entry.get("head_block")
-                                          or head_block
-                                          or DEFAULT_HEAD_BLOCK))
+    tp = model_axis_size(mesh, kv_heads)
+    group = heads // kv_heads
+    # a grid step holds head_block K/V heads and their query heads, one
+    # row each: at most MAX_ROWS rows. Mosaic (jax 0.9.0) aborts the
+    # process compiling a slice of rows past the first eight
+    # (``limits[i] <= dim(i)``: the head block of 12 of PR 21, and 16 or
+    # 32 rows here), so a group of four takes two K/V heads a step
+    hb = pick_head_block(kv_heads // tp, min(
+        int(entry.get("head_block") or head_block or DEFAULT_HEAD_BLOCK),
+        max(1, MAX_ROWS // group)))
     ppb = max(1, min(bt // page_len, max_pages))
 
-    kernel_ok = page_len % 128 == 0 or _interpret()
+    aligned = page_len % 128 == 0 or _interpret()
+    kernel_ok = aligned and group <= MAX_ROWS
     use_kernel = kernel_ok if impl is None else impl == "kernel"
     if impl == "kernel" and not kernel_ok:
         raise ValueError(
             f"paged_attention kernel needs page_len % 128 == 0 on TPU "
-            f"(got {page_len}); use page_len=128 or impl='dense'")
+            f"(got {page_len}) and at most {MAX_ROWS} query heads a K/V "
+            f"head (got {group}); use page_len=128 or impl='dense'")
     reason = None
     if not use_kernel:
         reason = ("impl='dense' requested" if impl == "dense"
-                  else f"page_len {page_len} not a multiple of 128")
+                  else f"page_len {page_len} not a multiple of 128"
+                  if not aligned else
+                  f"{group} query heads a K/V head, over {MAX_ROWS}")
         log_fallback_on_tpu(KERNEL, "dense", reason)
     tuning.record_dispatch(
         KERNEL, structure, key, source, block_k=ppb * page_len,

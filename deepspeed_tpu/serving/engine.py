@@ -205,6 +205,11 @@ class ServingEngine:
         # runs untouched, bit-identical to the pre-speculation engine.
         self._spec = (NgramProposer(self.config.speculation)
                       if self.config.spec_enabled else None)
+        if self._spec is not None:
+            # a rejected candidate's K/V is simply overwritten; the state
+            # a multi-token step advanced would have to be rolled back
+            self._paged.refuse_state(
+                "serving.speculation (a multi-token verification step)")
         self._slot_cap = n                # admissible slots (autoscaling
                                           # drains above the cap via the
                                           # preemption path; compiled
@@ -815,7 +820,17 @@ class ServingEngine:
             resumed = req.status == PREEMPTED
             self._slot_req[slot] = req
             req._admitted(slot, self._iteration)
-            self.metrics.on_admit(req, shared_tokens=shared)
+            restored = None
+            if self._paged.has_state:
+                # the first chunk's program reads the stored state by
+                # its page: the host's part is to know which, and count
+                args = {"slot": slot, "page": None}
+                with _span("serving/state_restore", args):
+                    args["page"] = self._paged.state_restore_page(slot,
+                                                                  shared)
+                restored = args["page"] is not None
+            self.metrics.on_admit(req, shared_tokens=shared,
+                                  state_restored=restored)
             if resumed:
                 self.metrics.on_resume(req)
             self._prefill_tasks.append(
@@ -902,7 +917,8 @@ class ServingEngine:
                 raise
             self._shed_on_oom(req, "chunk_prefill", e)
             return False
-        self.metrics.on_prefill_chunk(real)
+        self.metrics.on_prefill_chunk(
+            real, real // mgr.page_len if mgr.has_state else 0)
         if counts is not None:
             # an expert layer's routing of this chunk: read back with the
             # first token, by when every earlier chunk has finished

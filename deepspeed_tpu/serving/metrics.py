@@ -186,13 +186,21 @@ class ServingMetrics:
                      iteration=getattr(request, "submitted_iteration",
                                        None))
 
-    def on_admit(self, request=None, shared_tokens: int = 0):
+    def on_admit(self, request=None, shared_tokens: int = 0,
+                 state_restored: Optional[bool] = None):
+        """``state_restored`` (a model with recurrent state only: None
+        otherwise): the admission starts from the state stored with its
+        last shared page, or (False) from zeros."""
         self.requests_admitted += 1
         self.prefills += 1
         self.prefill_tokens_reused += shared_tokens
         if self.registry is not None:
             self.registry.counter("serving/prefill_tokens_reused").inc(
                 shared_tokens)
+            if state_restored is not None:
+                self.registry.counter(
+                    "serving/state_snapshots_restored" if state_restored
+                    else "serving/state_resets").inc(1)
         c = self._cls(request)
         if c is not None:
             c["admitted"] += 1
@@ -200,12 +208,19 @@ class ServingMetrics:
                      iteration=getattr(request, "admitted_iteration",
                                        None))
 
-    def on_prefill_chunk(self, tokens_computed: int):
+    def on_prefill_chunk(self, tokens_computed: int,
+                         state_snapshots: int = 0):
+        """``state_snapshots``: the pages this chunk filled whole, each
+        stored with the recurrent state at its end (0 for a model
+        without such state)."""
         self.prefill_chunks += 1
         self.prefill_tokens_computed += tokens_computed
         if self.registry is not None:
             self.registry.counter("serving/prefill_tokens_computed").inc(
                 tokens_computed)
+            if state_snapshots:
+                self.registry.counter("serving/state_snapshots_stored").inc(
+                    state_snapshots)
 
     def on_decode_dispatch(self, busy_slots: int, num_slots: int):
         """One decode dispatch over ``num_slots`` rows of which

@@ -39,13 +39,17 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from ...inference.cache import (cache_page_len, export_pages,
+from ...inference.cache import (add_slot_state, cache_page_len,
+                                chunk_state_view, export_pages,
                                 extract_token_kv, gather_pages,
-                                import_pages, init_page_pool,
-                                make_paged_view, pool_is_quantized,
-                                quantize_page_pool, scatter_chunk_pages,
-                                scatter_token_pages, set_cache_index)
-from ...inference.generation import _sample_impl, apply_decode
+                                has_recurrent_state, import_pages,
+                                init_page_pool, make_paged_view,
+                                pool_is_quantized, quantize_page_pool,
+                                scatter_chunk_pages, scatter_token_pages,
+                                set_cache_index, slot_state_view,
+                                state_bytes, state_units, store_chunk_state,
+                                store_decode_state)
+from ...inference.generation import _sample_impl, apply_decode, init_cache
 from ...observability.programs import track_program
 from ...observability.trace import span as _span
 from ...utils.logging import log_dist
@@ -118,6 +122,10 @@ def _paged_decode_iter_impl(module, params, pool, page_table, state, rng, it,
     s_max = page_len * page_table.shape[1]
     idx_w = jnp.minimum(lengths, s_max - 1)
     p_ = param_transform(params) if param_transform is not None else params
+    # a model with recurrent state (inference/cache.py): each slot's
+    # rides the "cache" collection, and a row that does not decode keeps
+    # its own (the module is handed ``active``)
+    stateful = len(state_units(pool)) > 0
     if use_kernel:
         # the kernel walks every page of a row's length, so a row that
         # does not decode (released, or waiting for its prefill chunks:
@@ -125,6 +133,8 @@ def _paged_decode_iter_impl(module, params, pool, page_table, state, rng, it,
         # only; its output was thrown away below already. Positions, the
         # append and the new lengths keep idx_w.
         view = make_paged_view(pool, page_table, jnp.where(active, idx_w, 0))
+        if stateful:
+            view["cache"] = slot_state_view(view["cache"])
         logits, vars_out, counts = apply_decode(
             module, {"params": p_, **view}, state["last_token"][:, None],
             idx_w[:, None], lambda: active[:, None], ["cache", "kv_token"])
@@ -138,6 +148,8 @@ def _paged_decode_iter_impl(module, params, pool, page_table, state, rng, it,
     else:
         cache = gather_pages(pool, page_table, dequant_dtype=dequant_dtype)
         cache = set_cache_index(cache, idx_w)
+        if stateful:
+            cache = slot_state_view(cache)
         logits, vars_out, counts = apply_decode(
             module, {"params": p_, "cache": cache},
             state["last_token"][:, None], idx_w[:, None],
@@ -150,6 +162,8 @@ def _paged_decode_iter_impl(module, params, pool, page_table, state, rng, it,
     phys = jnp.take_along_axis(page_table, page_idx[:, None], axis=1)[:, 0]
     phys = jnp.where(active, phys, NULL_PAGE)
     pool = scatter_token_pages(pool, tok, phys, idx_w % page_len)
+    if stateful:
+        pool = store_decode_state(pool, vars_out["cache"])
 
     remaining = jnp.where(active, state["remaining"] - 1, state["remaining"])
     done = active & ((nxt == eos_id) | (remaining <= 0))
@@ -188,6 +202,15 @@ def _chunk_prefill_impl(module, params, pool, state, ptab_row, chunk_ids,
     row = gather_pages(pool, ptab_row[None], scalar_index=True,
                        dequant_dtype=dequant_dtype)
     row = set_cache_index(row, chunk_start)
+    page_len = cache_page_len(pool)
+    stateful = len(state_units(pool)) > 0
+    if stateful:
+        # the chunk starts on a page boundary, from the state at the end
+        # of the page before it: an earlier chunk's, or a shared page's
+        # (a prefix hit restores the state here, with the K/V)
+        first = chunk_start // page_len
+        row = chunk_state_view(row, pool, ptab_row[jnp.maximum(first - 1, 0)],
+                               first == 0)
     positions = chunk_start + jnp.arange(chunk_ids.shape[1])
     p_ = param_transform(params) if param_transform is not None else params
     # the chunk's right padding is no token: an expert layer routes it
@@ -197,7 +220,6 @@ def _chunk_prefill_impl(module, params, pool, state, ptab_row, chunk_ids,
         lambda: (positions < end_pos)[None], ["cache", "kv_token"])
 
     chunk = chunk_ids.shape[1]
-    page_len = cache_page_len(pool)
     tok_tree = vars_out.get("kv_token")
     if tok_tree is None or len(jax.tree.leaves(tok_tree)) == 0:
         tok_tree = _chunk_tree_from_cache(vars_out["cache"], chunk_start,
@@ -205,6 +227,8 @@ def _chunk_prefill_impl(module, params, pool, state, ptab_row, chunk_ids,
     run = jax.lax.dynamic_slice(ptab_row, (chunk_start // page_len,),
                                 (chunk // page_len,))
     pool = scatter_chunk_pages(pool, tok_tree, run)
+    if stateful:
+        pool = store_chunk_state(pool, vars_out["cache"], tok_tree, slot, run)
 
     last_idx = jnp.clip(end_pos - 1 - chunk_start, 0, chunk - 1)
     last = jax.lax.dynamic_slice_in_dim(logits, last_idx, 1,
@@ -312,6 +336,14 @@ class PagedKVManager:
             if getattr(leaf, "ndim", 0) >= 4)
         if self.kv_quant:
             pool = quantize_page_pool(pool)
+        # a model with recurrent state is known by its cache collection:
+        # its pool also keeps each slot's state and the state at each
+        # page's end (inference/cache.py)
+        self.has_state = has_recurrent_state(pool)
+        if self.has_state:
+            pool = add_slot_state(pool, init_cache(
+                self._module, self._params, self._num_slots, self.page_len))
+        self._state_bytes = state_bytes(pool)      # shapes: read once
         # the scatter/gather/kernel paths all key off the scale planes
         # structurally — assert the built pool agrees with the config
         # so a layout drift fails HERE, not as silent fp math
@@ -391,6 +423,7 @@ class PagedKVManager:
         budget pages are garbage nobody copies). Returns
         ``(unit_records, n_filled)``; the caller owns releasing the slot
         once the payload is safely handed off."""
+        self.refuse_state("a page handoff (export_slot)")
         pages = self._slot_pages[slot]
         if pages is None:
             raise ValueError(f"export of unowned slot {slot}")
@@ -409,6 +442,7 @@ class PagedKVManager:
         page-starved — the caller retries on a later step). Shapes never
         change, so the receiver's compiled paged programs stay cached —
         the handoff is a page transfer, not a recompute."""
+        self.refuse_state("a page handoff (import_slot)")
         if self._slot_pages[slot] is not None:
             raise ValueError(f"import into occupied slot {slot}")
         private = self.allocator.alloc(total_pages)
@@ -426,6 +460,27 @@ class PagedKVManager:
             row[:len(private)] = private
             self.page_table = self.page_table.at[slot].set(row)
         return True
+
+    def refuse_state(self, what: str):
+        """Pages alone are not such a model's state: what moves or
+        re-runs them has to move or roll back the recurrent state too,
+        and refuses until it does."""
+        if self.has_state:
+            raise NotImplementedError(
+                f"{what} does not carry recurrent state: "
+                f"{type(self._module).__name__} keeps a convolution state "
+                "beside its K/V pages (a slot's, and one at each page's "
+                "end), which this path neither moves nor rolls back")
+
+    def state_restore_page(self, slot: int, shared_tokens: int):
+        """The physical page whose stored state the slot's first prefill
+        chunk starts from (the last of its shared pages), or None when
+        it starts from zeros. The program reads the state itself
+        (``chunk_state_view``): the host only names it, for the
+        counters."""
+        if not self.has_state or not shared_tokens:
+            return None
+        return self._slot_pages[slot][shared_tokens // self.page_len - 1]
 
     def reset(self):
         """(Re)build the device pool and every host-side ownership structure
@@ -449,6 +504,10 @@ class PagedKVManager:
         return sum(int(leaf.size) * leaf.dtype.itemsize
                    for leaf in jax.tree.leaves(self.pool)
                    if getattr(leaf, "ndim", 0) >= 4)
+
+    def state_bytes(self) -> int:
+        """Resident bytes of the recurrent state (0 for a model without)."""
+        return self._state_bytes
 
     def decode_gather_transient_bytes(self) -> int:
         """Bytes of the contiguous ``[num_slots, h, d, cache_len]`` view
@@ -492,6 +551,8 @@ class PagedKVManager:
             "kernel": self.use_kernel,
             "kv_quant": self.kv_quant,
         }
+        if self.has_state:
+            out["state_bytes"] = self.state_bytes()
         if self.prefix is not None:
             out.update(self.prefix.stats())
             out["prefix_hit_rate"] = (self.prefix.hits

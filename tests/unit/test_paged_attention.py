@@ -233,6 +233,98 @@ class TestStackedPool:
             "model_shards"] == 2
 
 
+class TestGroupedQueryHeads:
+    """Fewer K/V heads than query heads: the pool holds ``kv`` heads, the
+    kernel's grid walks them and a step computes the whole group of query
+    heads that read its K/V heads (query head ``i`` reads K/V head
+    ``i // group``) from one walk of their pages. Held to the same call on
+    a pool whose K/V heads are repeated once a query head."""
+    HEADS, D = 8, 16
+
+    def _case(self, kv, dtype, stacked, seed=11):
+        r = np.random.RandomState(seed)
+        lead = (2,) if stacked else ()
+        kp, vp = (jnp.asarray(r.randn(*lead, 9, kv, self.D, PAGE)
+                              .astype(np.float32)) for _ in range(2))
+        q = jnp.asarray(r.randn(3, 1, self.HEADS, self.D).astype(np.float32))
+        kn, vn = (jnp.asarray(r.randn(3, kv, self.D, 1).astype(np.float32))
+                  for _ in range(2))
+        ptab = jnp.asarray([[1, 4, 2, 0], [3, 5, 6, 7], [8, 0, 0, 0]],
+                           np.int32)
+        lens = jnp.asarray([PAGE + 3, 4 * PAGE - 1, 0], jnp.int32)
+        scales = {}
+        if dtype == "int8":
+            quant = jax.vmap(_quantize_pool) if stacked else _quantize_pool
+            kp, vp, ks, vs = quant(kp, vp)
+            scales = {"k_scale": ks, "v_scale": vs}
+        elif dtype == "bf16":
+            kp, vp = kp.astype(jnp.bfloat16), vp.astype(jnp.bfloat16)
+        return q, kp, vp, ptab, lens, kn, vn, scales
+
+    @staticmethod
+    def _repeated(x, group, stacked):
+        return jnp.repeat(x, group, axis=2 if stacked else 1)
+
+    @pytest.mark.parametrize("kv,head_block", [(1, 1), (2, 2), (4, 4), (4, 1),
+                                               (8, 8)])
+    @pytest.mark.parametrize("stacked", [False, True], ids=["4d", "stacked"])
+    @pytest.mark.parametrize("dtype", ["f32", "bf16", "int8"])
+    def test_kernel_equals_the_call_on_repeated_heads(self, dtype, stacked,
+                                                      kv, head_block):
+        q, kp, vp, ptab, lens, kn, vn, scales = self._case(kv, dtype,
+                                                           stacked)
+        group = self.HEADS // kv
+        layer = {"layer": 1} if stacked else {}
+        tuning.clear_last_dispatch()
+        got = paged_attention(q, kp, vp, ptab, lens, kn, vn, impl="kernel",
+                              head_block=head_block, **layer, **scales)
+        rec = tuning.last_dispatch(KERNEL)["page%d" % PAGE]
+        assert rec["impl"] == "kernel" and rec["head_block"] == head_block
+        rep = lambda x: self._repeated(x, group, stacked)
+        want = paged_attention(
+            q, rep(kp), rep(vp), ptab, lens, jnp.repeat(kn, group, axis=1),
+            jnp.repeat(vn, group, axis=1), impl="kernel", **layer,
+            **{k: rep(v) for k, v in scales.items()})
+        # the same products in the same order: a group's rows share one
+        # matmul where repeated heads have one each
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   atol=1e-5, rtol=1e-5)
+        dense = paged_attention(q, kp, vp, ptab, lens, kn, vn, impl="dense",
+                                **layer, **scales)
+        np.testing.assert_allclose(np.asarray(got), np.asarray(dense),
+                                   atol=2e-2 if dtype == "bf16" else 1e-4,
+                                   rtol=2e-2)
+
+    def test_a_head_reads_its_own_group_and_no_other(self):
+        """Query head ``i`` reads K/V head ``i // group``, not ``i % kv``:
+        zeroing K/V head 1 changes query heads 4..7 of 8 over 2 and
+        leaves 0..3 as they were."""
+        q, kp, vp, ptab, lens, kn, vn, _ = self._case(2, "f32", False)
+        base = paged_attention(q, kp, vp, ptab, lens, kn, vn, impl="kernel")
+        cut = paged_attention(q, kp.at[:, 1].set(0.0), vp.at[:, 1].set(0.0),
+                              ptab, lens, kn.at[:, 1].set(0.0),
+                              vn.at[:, 1].set(0.0), impl="kernel")
+        same = np.asarray(base[:, 0, :4] == cut[:, 0, :4]).all()
+        moved = np.abs(np.asarray(base[:2, 0, 4:] - cut[:2, 0, 4:])).max()
+        assert same and moved > 1e-2
+
+    def test_heads_must_be_whole_groups(self):
+        q, kp, vp, ptab, lens, kn, vn, _ = self._case(3, "f32", False)
+        with pytest.raises(ValueError, match="whole group"):
+            paged_attention(q, kp, vp, ptab, lens, kn, vn)
+
+    def test_grouped_heads_over_the_model_axis(self):
+        from deepspeed_tpu.comm import MeshSpec, build_mesh
+        q, kp, vp, ptab, lens, kn, vn, _ = self._case(4, "bf16", True)
+        want = paged_attention(q, kp, vp, ptab, lens, kn, vn, layer=1,
+                               impl="kernel")
+        mesh = build_mesh(MeshSpec(model=2, data=4))
+        got = jax.jit(lambda i: paged_attention(
+            q, kp, vp, ptab, lens, kn, vn, layer=i, impl="kernel",
+            mesh=mesh))(jnp.int32(1))
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
 class TestZeroRowsBetweenLongRows:
     """What the serving decode program hands the kernel since it masks
     the rows that do not decode: length 0 for a released slot or one

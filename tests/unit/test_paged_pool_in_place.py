@@ -282,3 +282,91 @@ def test_decode_program_leaves_the_pool_where_it_is(one_chip, monkeypatch,
         assert not any(" constant(" in x for x in cond), cond
         compare, = [x for x in cond if " compare(" in x]
         assert compare.lstrip().startswith("ROOT") and "direction=LT" in compare
+
+
+# the LFM2 cell (configs/lfm2-24b-a2b-10l-serve.json) at its published
+# widths, its first four layers: conv, conv (dense), attention, conv
+# (experts) — 32 query heads on 8 K/V heads of 64, 64 experts of 1536
+LFM2_LAYERS = ("conv", "conv", "full_attention", "conv")
+LFM2_PAGES, LFM2_MAX_PAGES = 1025, 32
+
+
+def test_lfm2_decode_program_keeps_pages_and_state_where_they_are(
+        one_chip, monkeypatch):
+    """A model with recurrent state beside its pages: the decode program
+    compiled for the chip holds the grouped-head Mosaic kernel (a grid
+    step of 2 K/V heads and their 8 query heads: 16 or 32 rows a step
+    abort this Mosaic), moves neither the K/V pool nor the state stored
+    a page, rewrites only the slots' state, and its scratch stays rows of
+    activations with the convolution state in the program."""
+    from deepspeed_tpu.inference.cache import (add_slot_state,
+                                               has_recurrent_state)
+    from deepspeed_tpu.inference.generation import init_cache
+    from deepspeed_tpu.models.lfm2 import LFM2, LFM2Config
+    from deepspeed_tpu.ops.pallas import tuning
+    monkeypatch.setattr(
+        importlib.import_module("deepspeed_tpu.ops.pallas.paged_attention"),
+        "_interpret", lambda: False)
+    model = LFM2(LFM2Config(num_hidden_layers=len(LFM2_LAYERS),
+                            layer_types=LFM2_LAYERS,
+                            max_position_embeddings=4096,
+                            dtype=jnp.bfloat16, param_dtype=jnp.bfloat16))
+    import flax.core.meta as flax_meta
+    params = jax.eval_shape(
+        lambda r: flax_meta.unbox(model.init(
+            r, jnp.ones((1, 8), jnp.int32)))["params"],
+        jax.random.PRNGKey(0))
+
+    def pool():
+        return add_slot_state(
+            init_page_pool(model, params, LFM2_PAGES, PAGE_LEN),
+            init_cache(model, params, SLOTS, PAGE_LEN))
+
+    def on_chip(tree):
+        shapes = jax.eval_shape(tree) if callable(tree) else tree
+        return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=one_chip), shapes)
+
+    slot = lambda dtype: jax.ShapeDtypeStruct((SLOTS,), dtype)
+    state = {"lengths": slot(jnp.int32), "last_token": slot(jnp.int32),
+             "active": slot(jnp.bool_), "remaining": slot(jnp.int32)}
+    pool_shapes = on_chip(pool)
+    assert has_recurrent_state(pool_shapes)
+    unit = pool_shapes["layers_0"]["conv"]
+    assert unit["conv_state"].shape == (SLOTS, 2, 2048)
+    assert unit["page_state"].shape == (LFM2_PAGES, 2, 2048)
+    assert pool_shapes["layers_2"]["attn"]["cached_key"].shape \
+        == (LFM2_PAGES, 8, 64, PAGE_LEN)           # the K/V heads, not 32
+    args = (on_chip(params), pool_shapes,
+            on_chip(jax.ShapeDtypeStruct((SLOTS, LFM2_MAX_PAGES), jnp.int32)),
+            on_chip(state), on_chip(lambda: jax.random.PRNGKey(0)),
+            on_chip(jax.ShapeDtypeStruct((), jnp.int32)))
+    static = (65535, 1.0, 0, 1.0, None, True, False, False, True,
+              jnp.bfloat16)
+    tuning.clear_last_dispatch()
+    compiled = jax.jit(
+        _paged_decode_iter_impl, static_argnums=(0, 11, 12, 13, 14, 15, 16),
+        donate_argnums=(2, 4)).lower(model, *args, *static).compile()
+    rec = tuning.last_dispatch("paged_attention")["page%d" % PAGE_LEN]
+    assert (rec["impl"], rec["head_block"]) == ("kernel", 2)
+
+    leaves = jax.tree.leaves(pool_shapes)
+    pool_bytes = sum(x.size * x.dtype.itemsize for x in leaves)
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= pool_bytes
+    # rows of activations: nowhere near a K/V leaf (134 MB), a layer's
+    # page states (16.8 MB) or one expert's weights (18.9 MB)
+    assert mem.temp_size_in_bytes < 8 * 2 ** 20, mem.temp_size_in_bytes
+    hlo = compiled.as_text()
+    assert "tpu_custom_call" in hlo and hlo_has(compiled, "ragged-dot")
+    roots = _roots(_computations(hlo))
+    moved = re.compile(r"\[%d,(?:8,64,%d|2,2048)\]" % (LFM2_PAGES, PAGE_LEN))
+    for line in hlo.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%[\w.\-]+ = (\S+) ([\w\-]+)\(", line)
+        if not m or m.group(1).startswith("(") or not moved.search(
+                m.group(1)):
+            continue
+        op = m.group(2)
+        if op == "fusion":
+            op = roots[re.search(r"calls=%([\w.\-]+)", line).group(1)]
+        assert op in IN_PLACE, f"moved by: {line.strip()[:200]}"
