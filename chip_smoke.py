@@ -48,10 +48,12 @@ TOTAL_BUDGET_S = 1150
 FULL = {
     "train": dict(preset="gpt2-125m", seq=1024, micro=32, steps=4,
                   n_layers=None),
-    "serve": dict(preset="gpt2-125m", num_slots=4, max_len=512,
+    # max_len 1024: a slot holds the prompt of four pages and a little
+    # that is admitted alone and goes in as one wide chunk
+    "serve": dict(preset="gpt2-125m", num_slots=4, max_len=1024,
                   page_len=128, n_requests=10, prompt_max=300, new_max=24,
                   paging_kernel="auto", n_layers=None, logit_tol=0.1),
-    "olmoe": dict(n_layers=2, num_slots=4, max_len=512, page_len=128,
+    "olmoe": dict(n_layers=2, num_slots=4, max_len=1024, page_len=128,
                   n_requests=10, prompt_max=300, new_max=24,
                   paging_kernel="auto", logit_tol=0.1),
     # max_len 2048: a pool of 65 pages, so that one layer's K pages (17
@@ -67,7 +69,7 @@ FULL = {
     "multichip": dict(preset="gpt2-1.3b", seq=1024, micro=4, steps=3,
                       n_layers=None,
                       serve=dict(preset="gpt2-1.3b", n_layers=4, num_slots=4,
-                                 max_len=512, page_len=128, n_requests=8,
+                                 max_len=1024, page_len=128, n_requests=8,
                                  prompt_max=200, new_max=16,
                                  paging_kernel="auto", logit_tol=0.1)),
 }
@@ -283,6 +285,39 @@ def _check_pool_stays_in_place(srv, label):
            f"layer's K pages ({layer_slice}): part of the pool is copied")
 
 
+def _admit_alone(srv, prompt, new, page_len, label):
+    """One prompt of four pages and a little into an empty server with
+    the default ``prefill_chunk``: nothing decodes, so it goes in as one
+    chunk of four pages and one of one, which the counters say."""
+    from deepspeed_tpu.observability.metrics import get_registry
+    counters = [get_registry().counter("serving/" + name)
+                for name in ("prefill_chunk_pages", "prefill_chunks")]
+    before = [c.value for c in counters]
+    _check(not srv.busy, f"{label}: the server is not empty")
+    handle = srv.submit(prompt, max_new_tokens=new)
+    srv.run()
+    pages, chunks = (c.value - b for c, b in zip(counters, before))
+    _say(f"{label}: {len(prompt)} tokens admitted alone went in as "
+         f"{chunks} chunk programs of {pages} pages")
+    _check((pages, chunks) == (-(-len(prompt) // page_len), 2),
+           f"{label}: no chunk of four pages ran: {pages} pages in "
+           f"{chunks} chunks")
+    return handle
+
+
+def _first_divergence_gap(a, b, row_of):
+    """Two token sequences of one request: 0.0 when they agree, else the
+    gap between the two candidates' reference logits where they first
+    part (``row_of(j)``: the reference's row that predicts token j)."""
+    import numpy as np
+    diff = np.nonzero(np.asarray(a) != np.asarray(b))[0]
+    if diff.size == 0:
+        return 0.0
+    j = int(diff[0])
+    row = row_of(j)
+    return abs(float(row[a[j]] - row[b[j]]))
+
+
 def _serve_and_check(eng, module, params, reqs, num_slots, max_len,
                      page_len, paging_kernel, logit_tol, label,
                      against_generate=True, reference_logits=None):
@@ -292,9 +327,9 @@ def _serve_and_check(eng, module, params, reqs, num_slots, max_len,
     from deepspeed_tpu.ops.pallas import tuning
 
     tuning.clear_last_dispatch()
-    srv = eng.serve({"num_slots": num_slots, "max_len": max_len,
-                     "paging": {"page_len": page_len,
-                                "kernel": paging_kernel}})
+    options = {"num_slots": num_slots, "max_len": max_len,
+               "paging": {"page_len": page_len, "kernel": paging_kernel}}
+    srv = eng.serve(options)
     stream = []
     handles = [srv.submit(p, max_new_tokens=m,
                           on_token=lambda r, tok, _s=stream:
@@ -315,6 +350,13 @@ def _serve_and_check(eng, module, params, reqs, num_slots, max_len,
     _check(runs > len(handles),
            f"{label}: streamed tokens never interleaved ({runs} runs over "
            f"{len(handles)} requests)")
+    # a wide prefill chunk on the chip, and the same prompt a page at a
+    # time: both are held to the reference below with the mix's requests
+    long = (np.random.default_rng(len(reqs)).integers(
+        0, module.config.vocab_size, size=4 * page_len + page_len // 4,
+        dtype="int32"), 8)
+    handles.append(_admit_alone(srv, *long, page_len, label))
+    reqs = reqs + [long]
     path = tuning.last_dispatch("paged_decode").get("path")
     _mosaic(path, f"{label} paged decode path")
     kern = tuning.last_dispatch("paged_attention").get(f"page{page_len}")
@@ -323,6 +365,15 @@ def _serve_and_check(eng, module, params, reqs, num_slots, max_len,
          f"in {wall:.2f}s (compiles included); paged kernel {kern}")
     _check_pool_stays_in_place(srv, label)
     srv.close()
+    paged = eng.serve(dict(options, paging=dict(options["paging"],
+                                                prefill_chunk=page_len)))
+    by_page = paged.submit(long[0], max_new_tokens=long[1])
+    paged.run()
+    paged.close()
+    went = paged.metrics
+    _check(by_page.status == "finished" and went.prefill_chunks
+           == went.prefill_chunk_pages == -(-len(long[0]) // page_len),
+           f"{label}: the one-page run did not go a page a chunk")
 
     # logit-level check against the float32 reference: teacher-force every
     # served sequence through a plain float32 forward of the same weights;
@@ -361,9 +412,21 @@ def _serve_and_check(eng, module, params, reqs, num_slots, max_len,
     _check(worst <= logit_tol,
            f"{label}: a served token is {worst:.4f} below the float32 "
            f"reference's best logit (tolerance {logit_tol:.4f})")
+    gap = _first_divergence_gap(
+        handles[-1].output_tokens, by_page.output_tokens,
+        lambda j: ref_logits[len(reqs) - 1, len(long[0]) + j - 1])
+    _say(f"{label}: the prompt prefilled in a chunk of four pages and a "
+         f"page at a time gives " + ("the same tokens" if gap == 0.0 else
+         f"tokens that part at a near-tie of {gap:.4f}"))
+    _check(gap <= logit_tol,
+           f"{label}: wide and one-page prefill diverge by a reference "
+           f"logit gap of {gap:.4f} > {logit_tol:.4f}")
 
+    # every request that was prefilled: the mix, the long prompt, and
+    # the long prompt again a page at a time
+    served = reqs + [long]
     if not against_generate:
-        return
+        return served
     # agreement with generate() (the contiguous-cache one-shot path),
     # reported as a count; a mismatch is a failure only when the two
     # candidates' reference logits differ by more than the tolerance
@@ -377,20 +440,16 @@ def _serve_and_check(eng, module, params, reqs, num_slots, max_len,
                                   prompt_lengths=lens))
     agree = 0
     for i, (h, (p, m)) in enumerate(zip(handles, reqs)):
-        g = gen[i, len(p):len(p) + m]
-        s = np.asarray(h.output_tokens)
-        diff = np.nonzero(g != s)[0]
-        if diff.size == 0:
-            agree += 1
-            continue
-        j = int(diff[0])
-        row = ref_logits[i, len(p) + j - 1]
-        gap = abs(float(row[g[j]] - row[s[j]]))
+        gap = _first_divergence_gap(
+            gen[i, len(p):len(p) + m], h.output_tokens,
+            lambda j: ref_logits[i, len(p) + j - 1])
+        agree += int(gap == 0.0)
         _check(gap <= logit_tol,
-               f"{label}: request {i} diverges from generate() at token {j} "
-               f"by a reference logit gap of {gap:.4f} > {logit_tol:.4f}")
+               f"{label}: request {i} diverges from generate() by a "
+               f"reference logit gap of {gap:.4f} > {logit_tol:.4f}")
     _say(f"{label}: {agree}/{len(reqs)} requests token-identical to "
          f"generate(); the rest diverge at a near-tie inside the tolerance")
+    return served
 
 
 def phase_serve(preset, num_slots, max_len, page_len, n_requests,
@@ -434,8 +493,9 @@ def phase_olmoe(n_layers, num_slots, max_len, page_len, n_requests,
                 for name in ("assignments", "expert_calls",
                              "experts_touched", "experts_offered")}
     before = {name: c.value for name, c in counters.items()}
-    _serve_and_check(eng, model, params, reqs, num_slots, max_len, page_len,
-                     paging_kernel, logit_tol, "serve olmoe")
+    reqs = _serve_and_check(eng, model, params, reqs, num_slots, max_len,
+                            page_len, paging_kernel, logit_tol,
+                            "serve olmoe")
     decode = get_program_registry().get("serving/paged_decode")
     args, kwargs = decode._last_avals
     hlo = decode.lower(*args, **kwargs).compile().as_text()
@@ -516,17 +576,17 @@ def phase_lfm2(n_layers, num_slots, max_len, page_len, n_requests,
             return family.reference_logits(prm, ids, sizes, published,
                                            near_ties="kept")
 
-    _serve_and_check(eng, model, params, reqs, num_slots, max_len, page_len,
-                     paging_kernel, logit_tol, "serve lfm2",
-                     against_generate=False,
-                     reference_logits=reference_logits)
+    served = _serve_and_check(eng, model, params, reqs, num_slots, max_len,
+                              page_len, paging_kernel, logit_tol,
+                              "serve lfm2", against_generate=False,
+                              reference_logits=reference_logits)
     moved = {name: c.value - before[name] for name, c in counters.items()}
     _say(f"serve lfm2: counted {moved}")
     _check(moved["serving/prefill_tokens_reused"] >= page_len
            and moved["serving/state_snapshots_restored"] >= 1,
            f"serve lfm2: no prefix hit restored a state: {moved}")
     _check(moved["serving/state_snapshots_restored"]
-           + moved["serving/state_resets"] == n_requests
+           + moved["serving/state_resets"] == len(served)
            and moved["serving/state_snapshots_stored"] >= 1,
            f"serve lfm2: admissions and stored states do not add up: {moved}")
     _check(moved["moe/expert_calls"] > 0

@@ -249,6 +249,10 @@ class InferenceEngine:
         ``config`` is a ``serving.ServingConfig`` or dict; extra kwargs
         override individual knobs."""
         from ..serving.engine import ServingEngine
-        return ServingEngine(self.module, self.params, config,
-                             param_transform=self._param_transform,
-                             trace_scope=self._own_trace_state, **kwargs)
+        srv = ServingEngine(self.module, self.params, config,
+                            param_transform=self._param_transform,
+                            trace_scope=self._own_trace_state, **kwargs)
+        # every width a prefill chunk may take exists before the first
+        # request: a width met first under load would compile there
+        srv.compile_chunk_programs()
+        return srv

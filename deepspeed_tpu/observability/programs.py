@@ -142,6 +142,34 @@ class TrackedProgram:
             self._comm_counter.inc(rec.collective_bytes_per_call)
         return out
 
+    def compile_ahead(self, *args, static_argnums=()):
+        """Lower and compile the wrapped jit for ``args`` (arrays or
+        ``ShapeDtypeStruct``s; nothing runs, nothing is donated) and
+        return a callable that takes the same arguments and runs that
+        executable: a shape's first dispatch then neither traces nor
+        compiles, which a call through the jit would (``.lower()`` does
+        not fill the jit's own cache). ``static_argnums`` are the
+        wrapped jit's, which the executable leaves out of its call. The
+        record counts the compile and the calls as it does the jit's."""
+        t0 = time.perf_counter()
+        compiled = self._fn.lower(*args).compile()
+        wall = time.perf_counter() - t0
+        rec = self.record
+        rec.compiles += 1
+        rec.compile_wall_s += wall
+        rec.last_compile_wall_s = wall
+        self._snapshot_args(args, {})
+        reg = get_registry()
+        reg.counter("programs/compiles_total").inc()
+        reg.histogram("programs/compile_wall_s").observe(wall)
+        dynamic = [i for i in range(len(args)) if i not in static_argnums]
+
+        def run(*call_args):
+            rec.calls += 1
+            return compiled(*[call_args[i] for i in dynamic])
+
+        return run
+
     def _snapshot_args(self, args, kwargs):
         """Keep the abstract input tree of the compile that just
         happened: shaped leaves become ShapeDtypeStructs (no buffer

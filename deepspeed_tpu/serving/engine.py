@@ -71,8 +71,9 @@ from .qos import QosController
 from .request import PREEMPTED, Request
 from .scheduler import FifoScheduler
 from .metrics import ServingMetrics
-from .paging.manager import (PagedKVManager, _chunk_prefill_jit,
-                             _paged_decode_jit)
+from .paging.config import CHUNK_PAGES, chunk_pages
+from .paging.manager import (CHUNK_PREFILL_STATICS, PagedKVManager,
+                             _chunk_prefill_jit, _paged_decode_jit)
 from .speculation import NgramProposer, _spec_verify_jit
 
 
@@ -192,6 +193,9 @@ class ServingEngine:
         self._readback_ns = 0             # this advance()'s blocked reads
         self._chunk_counts = {}           # slot -> router counts of its
                                           # prefill chunks so far (device)
+        self._chunk_programs = {}         # chunk tokens -> the program
+                                          # compiled ahead at that width
+                                          # (compile_chunk_programs)
         self._iteration = 0
         self._seq = 0
         # QoS plane (serving/qos.py): priority preemption, SLO shedding,
@@ -249,8 +253,8 @@ class ServingEngine:
         ``reset()``, from ``recover()`` — shapes are identical both
         times, so every compiled program stays cached."""
         n = self.config.num_slots
-        self._prefill_tasks = deque()   # (slot, req, prompt, max_new,
-                                        #  [chunk plans])
+        self._prefill_tasks = deque()   # [slot, req, prompt, max_new,
+                                        #  next chunk's start]
         self._state = {
             "lengths": jnp.zeros((n,), jnp.int32),
             "last_token": jnp.zeros((n,), jnp.int32),
@@ -833,49 +837,86 @@ class ServingEngine:
                                   state_restored=restored)
             if resumed:
                 self.metrics.on_resume(req)
-            self._prefill_tasks.append(
-                (slot, req, prompt, max_new,
-                 self._plan_chunks(prompt, shared)))
+            # the plan is where the next chunk starts: the non-shared
+            # tail, cut into chunks as they are dispatched. Always at
+            # least one — the prefix match caps at the last prefill
+            # token, whose logits seed sampling
+            self._prefill_tasks.append([slot, req, prompt, max_new, shared])
 
-    def _plan_chunks(self, prompt, shared_tokens: int):
-        """Split the non-shared prefill tail into page-aligned chunks:
-        full ``chunk_tokens`` chunks, then one tail chunk padded to the
-        smallest page multiple covering the remainder — so chunk widths
-        (the only prefill jit axis) come from a bounded bucket set.
-        Always at least one chunk: the prefix match caps at the last
-        prefill token, whose logits seed sampling. ``prompt`` is the
-        EFFECTIVE prompt (original + any retained partial output for a
-        resumption)."""
-        p_len = int(prompt.shape[0])
+    def _chunk_width(self, tokens_left: int) -> int:
+        """Tokens of the head request's next chunk program, a page
+        multiple covering at most the ``tokens_left`` of its EFFECTIVE
+        prompt (original + any retained partial output for a
+        resumption): a fixed ``prefill_chunk`` cut to the pages left, or
+        the width ``chunk_pages`` chooses from the counts this iteration
+        holds — so chunk widths (the only prefill jit axis) come from a
+        bounded set either way."""
         page = self._paged.page_len
-        cap = self._paged.chunk_tokens
-        chunks, start = [], shared_tokens
-        while start < p_len:
-            remaining = p_len - start
-            width = cap if remaining >= cap else -(-remaining // page) * page
-            chunks.append((start, width))
-            start += width
-        return chunks
+        pages_left = -(-tokens_left // page)
+        fixed = self.config.paging.prefill_chunk
+        if fixed is not None:
+            return min(fixed // page, pages_left) * page
+        # a prefill replica never decodes: a slot staged for handoff
+        # holds pages, not a row that waits for this chunk
+        decoding = 0 if self.prefill_only else self._decoding_slots(
+            sum(r is not None for r in self._slot_req))
+        return page * chunk_pages(
+            len(self._prefill_tasks), decoding, pages_left,
+            degraded=self._qos is not None and self._qos.degraded)
 
     def _run_prefill_chunks(self):
-        """Run at most ``max_chunks_per_iter`` prefill chunks this
-        iteration (the degradation ladder shrinks the budget at level >=
-        2), FIFO across admitted-but-unprefilled requests — the
+        """Run at most ``max_chunks_per_iter`` prefill chunk programs
+        this iteration (the degradation ladder shrinks the budget at
+        level >= 2), FIFO across admitted-but-unprefilled requests — the
         chunked-prefill contract: a long prompt never stalls the decode
-        batch by more than this many chunks per decode dispatch."""
+        batch by more than this many chunk programs per decode dispatch.
+        Each chunk's width is cut here, when it is dispatched."""
         budget = self.config.paging.max_chunks_per_iter
         if self._qos is not None:
             budget = self._qos.max_chunks(budget)
         while budget > 0 and self._prefill_tasks:
-            slot, req, prompt, max_new, chunks = self._prefill_tasks[0]
-            start, width = chunks.pop(0)
+            task = self._prefill_tasks[0]
+            slot, req, prompt, max_new, start = task
+            p_len = int(prompt.shape[0])
+            width = self._chunk_width(p_len - start)
+            task[4] = start + width
+            is_last = task[4] >= p_len
             ok = self._dispatch_chunk(slot, req, prompt, max_new, start,
-                                      width, is_last=not chunks)
+                                      width, is_last=is_last)
             if not ok:
                 return          # OOM containment reset the queue state
-            if not chunks:
+            if is_last:
                 self._prefill_tasks.popleft()
             budget -= 1
+
+    def compile_chunk_programs(self):
+        """Lower and compile the chunk program at every width
+        ``chunk_pages`` may choose, from shapes, running nothing:
+        ``InferenceEngine.serve()`` calls this before the first request,
+        so that a width's first dispatch — which may come hours in, when
+        a queue first builds — neither traces nor compiles. With a fixed
+        ``prefill_chunk`` there is nothing to choose and the one width
+        compiles on first use, as does every width of an engine built
+        directly."""
+        mgr = self._paged
+        if self.config.paging.prefill_chunk is not None:
+            return
+        greedy, has_k, has_p, t, k, p = self._mode
+        zero = jnp.int32(0)
+        with self._trace_scope():
+            for pages in CHUNK_PAGES:
+                tokens = pages * mgr.page_len
+                if pages > mgr.max_pages:
+                    continue
+                self._chunk_programs[tokens] = \
+                    _chunk_prefill_jit.compile_ahead(
+                        self.module, self.params, mgr.pool, self._state,
+                        mgr.page_table[0], np.zeros((1, tokens), np.int32),
+                        zero, zero, zero, zero, jnp.asarray(False),
+                        self._rng, self._eos, t, k, p,
+                        self._param_transform, greedy, has_k, has_p,
+                        mgr.dequant_dtype,
+                        static_argnums=CHUNK_PREFILL_STATICS)
 
     def _dispatch_chunk(self, slot: int, req, prompt, max_new: int,
                         start: int, width: int, is_last: bool) -> bool:
@@ -892,6 +933,8 @@ class ServingEngine:
         padded[0, :real] = prompt[start:start + real]
         greedy, has_k, has_p, t, k, p = self._mode
         mgr = self._paged
+        pages = width // mgr.page_len
+        program = self._chunk_programs.get(width, _chunk_prefill_jit)
         if req.first_chunk_at_ns is None:
             self._chunk_counts.pop(slot, None)  # a preempted prefill's
             req.first_chunk_at_ns = time.perf_counter_ns()
@@ -901,10 +944,10 @@ class ServingEngine:
             with _span("serving/prefill_chunk",
                        {"slot": slot, "request_id": req.request_id,
                         "trace_id": req.trace_id,
-                        "start": start, "tokens": real,
+                        "start": start, "tokens": real, "pages": pages,
                         "last": bool(is_last)}), \
                     _goodput("compute"):
-                mgr.pool, self._state, tok, done, counts = _chunk_prefill_jit(
+                mgr.pool, self._state, tok, done, counts = program(
                     self.module, self.params, mgr.pool, self._state,
                     mgr.page_table[slot], jnp.asarray(padded),
                     jnp.int32(start), jnp.int32(p_len), jnp.int32(slot),
@@ -918,7 +961,7 @@ class ServingEngine:
             self._shed_on_oom(req, "chunk_prefill", e)
             return False
         self.metrics.on_prefill_chunk(
-            real, real // mgr.page_len if mgr.has_state else 0)
+            real, real // mgr.page_len if mgr.has_state else 0, pages=pages)
         if counts is not None:
             # an expert layer's routing of this chunk: read back with the
             # first token, by when every earlier chunk has finished

@@ -115,6 +115,7 @@ class ServingMetrics:
         # Their sum over admitted requests equals total prompt tokens, so
         # reused/total IS the recomputation skipped by prefix sharing.
         self.prefill_chunks = 0
+        self.prefill_chunk_pages = 0
         self.prefill_tokens_computed = 0
         self.prefill_tokens_reused = 0
         self.paged_stats: Optional[dict] = None   # latest manager.stats()
@@ -209,15 +210,20 @@ class ServingMetrics:
                                        None))
 
     def on_prefill_chunk(self, tokens_computed: int,
-                         state_snapshots: int = 0):
+                         state_snapshots: int = 0, pages: int = 1):
         """``state_snapshots``: the pages this chunk filled whole, each
         stored with the recurrent state at its end (0 for a model
-        without such state)."""
+        without such state). ``pages``: the width of the chunk program
+        that ran, padding counted — over the chunks, the mean width the
+        server chose."""
         self.prefill_chunks += 1
+        self.prefill_chunk_pages += pages
         self.prefill_tokens_computed += tokens_computed
         if self.registry is not None:
             self.registry.counter("serving/prefill_tokens_computed").inc(
                 tokens_computed)
+            self.registry.counter("serving/prefill_chunks").inc()
+            self.registry.counter("serving/prefill_chunk_pages").inc(pages)
             if state_snapshots:
                 self.registry.counter("serving/state_snapshots_stored").inc(
                     state_snapshots)
@@ -552,6 +558,7 @@ class ServingMetrics:
         if self.prefill_chunks or self.prefill_tokens_reused:
             total = self.prefill_tokens_computed + self.prefill_tokens_reused
             out["prefill_chunks"] = self.prefill_chunks
+            out["prefill_chunk_pages"] = self.prefill_chunk_pages
             out["prefill_tokens_computed"] = self.prefill_tokens_computed
             out["prefill_tokens_reused"] = self.prefill_tokens_reused
             out["prefill_recompute_skipped_frac"] = (

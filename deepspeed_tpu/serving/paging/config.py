@@ -14,6 +14,32 @@ docs/serving.md, "Paged KV cache").
 from dataclasses import dataclass
 from typing import Optional
 
+# widths, in pages, of the one prefill chunk program an iteration when
+# ``prefill_chunk`` is None, widest first: one constant for every model,
+# each compiled by ``InferenceEngine.serve()`` before the first request.
+# A width costs every process a trace, a lowering and a read from the
+# compile cache (0.6 s for a scanned 1.3B GPT-2, 2.2 s for LFM2's ten
+# unscanned layers: PERF.md section 6, PR 35), which a width of 2 pages
+# between these does not earn back
+CHUNK_PAGES = (4, 1)
+
+
+def chunk_pages(waiting: int, decoding: int, pages_left: int,
+                degraded: bool = False) -> int:
+    """Pages the next prefill chunk takes when the width is the server's
+    to choose: the widest of ``CHUNK_PAGES`` that the head request's
+    ``pages_left`` fill (its last, partly filled page counts as one),
+    and wider than one page only while the requests admitted and
+    ``waiting`` for prefill outnumber the rows that are ``decoding`` and
+    the QoS ladder is not ``degraded`` (the level at which it shrinks
+    the chunk budget). A wide chunk reads the weights once for all its
+    pages, so it is how a queue of long prompts drains; one page is what
+    a decoding row waits for. Host scheduler counts on the iteration
+    clock only, so the choice replays bit-exactly."""
+    if degraded or waiting <= decoding:
+        return 1
+    return next(w for w in CHUNK_PAGES if w <= pages_left)
+
 
 @dataclass
 class PagingConfig:
@@ -33,14 +59,19 @@ class PagingConfig:
                                      # a full-length request at once)
     enable_prefix_cache: bool = True  # radix-tree sharing of full prompt-
                                       # prefix pages (system prompts)
-    prefill_chunk: Optional[int] = None  # tokens prefilled per engine
-                                     # iteration (must be a page_len
-                                     # multiple); None = page_len. Long
-                                     # prompts interleave with decode at
-                                     # this granularity.
-    max_chunks_per_iter: int = 1     # prefill chunks run between two
-                                     # decode dispatches (1 = decode never
-                                     # stalls more than one chunk)
+    prefill_chunk: Optional[int] = None  # tokens of one prefill chunk
+                                     # program (a page_len multiple).
+                                     # None = chosen per dispatch
+                                     # (``chunk_pages``): one page while
+                                     # rows decode, CHUNK_PAGES[0] pages
+                                     # while the requests waiting for
+                                     # prefill outnumber them. A
+                                     # number fixes the width (the tail
+                                     # is cut to the pages left).
+    max_chunks_per_iter: int = 1     # prefill chunk PROGRAMS run between
+                                     # two decode dispatches, whatever
+                                     # their width (1 = a decoding row
+                                     # never waits for more than one)
     kernel: str = "auto"             # paged decode-attention kernel
                                      # (ops/pallas/paged_attention.py):
                                      # "auto" = on real TPU with a
@@ -85,7 +116,8 @@ class PagingConfig:
 
     @property
     def chunk_tokens(self) -> int:
-        """The prefill chunk size (``prefill_chunk`` or one page)."""
+        """The prefill chunk size when it is fixed (``prefill_chunk``),
+        else the narrowest the server chooses: one page."""
         return (self.prefill_chunk if self.prefill_chunk is not None
                 else self.page_len)
 

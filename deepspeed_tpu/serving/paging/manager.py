@@ -253,9 +253,10 @@ def _chunk_prefill_impl(module, params, pool, state, ptab_row, chunk_ids,
     return pool, state, tok, done, counts
 
 
+CHUNK_PREFILL_STATICS = (0, 16, 17, 18, 19, 20)
 _chunk_prefill_jit = track_program(
     "serving/chunk_prefill",
-    jax.jit(_chunk_prefill_impl, static_argnums=(0, 16, 17, 18, 19, 20),
+    jax.jit(_chunk_prefill_impl, static_argnums=CHUNK_PREFILL_STATICS,
             donate_argnums=(2, 3)), subsystem="serving")
 
 
@@ -272,7 +273,6 @@ class PagedKVManager:
         self.cache_len = config.cache_len
         self.max_pages = config.cache_len // self.page_len
         self.num_pages = pcfg.pool_pages(config.num_slots, config.cache_len)
-        self.chunk_tokens = pcfg.chunk_tokens
         self._module = module          # kept for reset() (fault recovery)
         self._params = params
         self._num_slots = config.num_slots
@@ -283,7 +283,8 @@ class PagedKVManager:
             f"paged KV: {self.num_pages - 1} usable pages x "
             f"{self.page_len} tokens "
             f"(= {(self.num_pages - 1) * self.page_len // self.cache_len} "
-            f"full-length rows), prefill chunk {self.chunk_tokens}, "
+            f"full-length rows), prefill chunk "
+            f"{pcfg.prefill_chunk or 'chosen per dispatch'}, "
             f"prefix cache "
             f"{'on' if self.prefix is not None else 'off'}, decode "
             f"{'paged-attention kernel' if self.use_kernel else 'gather'}"

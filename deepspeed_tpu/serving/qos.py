@@ -325,10 +325,17 @@ class QosController:
             return False
         return iteration - request.submitted_iteration >= after
 
+    @property
+    def degraded(self) -> bool:
+        """The ladder is at the level (>= 2) where prefill's share of an
+        iteration shrinks: fewer chunk programs (``max_chunks``) and, of
+        a width the server chooses, one page (paging ``chunk_pages``)."""
+        return self.level >= LEVEL_DEGRADE
+
     def max_chunks(self, configured: int) -> int:
         """The effective paged ``max_chunks_per_iter`` at the current
         ladder level (level >= 2 shrinks prefill's decode interference)."""
-        if self.level >= LEVEL_DEGRADE:
+        if self.degraded:
             return min(configured, self.config.degraded_max_chunks_per_iter)
         return configured
 

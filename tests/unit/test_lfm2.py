@@ -287,8 +287,10 @@ def seen():
 
 
 class Served(DispatchLog):
-    """A ``ServingEngine`` (paged pool, chunked prefill, the prefix cache
-    on, greedy) whose dispatches are logged in order
+    """A ``ServingEngine`` (paged pool, chunked prefill a page at a time
+    — ``prefill_chunk`` is pinned, ``test_prefill_chunk_width.py`` has
+    the widths the server chooses — the prefix cache on, greedy) whose
+    dispatches are logged in order
     (``benchmarks/chip/tools/lfm2_check.py DispatchLog``): ``rows(handle)``
     are the float32 logits each of a request's tokens was sampled from,
     through preemption and resumption too."""
@@ -300,7 +302,8 @@ class Served(DispatchLog):
             {"num_slots": slots, "max_len": 512,
              "paging": {"page_len": PAGE,
                         "num_pages": pages or 4 * slots + 1,
-                        "kernel": kernel, "enable_prefix_cache": prefix}}),
+                        "prefill_chunk": PAGE, "kernel": kernel,
+                        "enable_prefix_cache": prefix}}),
             seen)
 
     def run(self, *prompts, new_tokens=8):

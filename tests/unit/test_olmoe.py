@@ -139,16 +139,18 @@ def _serve(module, params, prompts, new_tokens, seen, slots=3,
     chunked prefill, greedy) and return, per request, the float32 logits
     that each of its tokens was sampled from, ``[new_tokens, V]``.
     Requests are admitted first come first served into slots 0, 1, ...;
-    prefill runs one chunk an iteration in that order; a request's
-    decode logits are its slot's row of the decode dispatches that
-    follow its last chunk."""
+    prefill runs one chunk of one page an iteration in that order
+    (``prefill_chunk`` is pinned: the rows are found by counting
+    chunks); a request's decode logits are its slot's row of the decode
+    dispatches that follow its last chunk."""
     del seen[:]
     with reference.highest():
         srv = ds.init_inference(module, params=params,
                                 dtype=jnp.float32).serve(
             {"num_slots": slots, "max_len": 512,
              "paging": {"page_len": PAGE, "num_pages": 4 * slots + 1,
-                        "kernel": kernel, "enable_prefix_cache": False}})
+                        "prefill_chunk": PAGE, "kernel": kernel,
+                        "enable_prefix_cache": False}})
         handles = [srv.submit(p, max_new_tokens=new_tokens) for p in prompts]
         srv.run()
         srv.close()

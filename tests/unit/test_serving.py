@@ -209,10 +209,12 @@ class TestContinuousBatchingParity:
 
     @pytest.mark.parametrize("prompt_len", [1, 128, 129, 300])
     def test_every_prompt_length_has_a_compiled_home(self, prompt_len):
-        """One token, one page exactly, one page plus one, three chunks:
+        """One token, one page exactly, one page plus one, three pages:
         through the DEFAULT config (no paging block) each equals
-        generate() token for token, from one decode program and one
-        prefill program — a chunk is a page, whatever the prompt."""
+        generate() token for token, from one decode program and the
+        prefill program at one page (admitted alone a prompt goes in the
+        widest chunks its pages fill, and none of these fills four): the
+        chunks hold every page of it once."""
         m, params = _model(vocab=83, max_seq_len=512)
         prompt = np.random.RandomState(prompt_len).randint(
             1, 83, size=prompt_len).astype(np.int32)
@@ -225,8 +227,9 @@ class TestContinuousBatchingParity:
                                   temperature=0.0, max_len=512)
                          )[0, prompt_len:]
         np.testing.assert_array_equal(np.asarray(req.output_tokens), ref)
-        assert eng.metrics.snapshot()["prefill_chunks"] == \
-            -(-prompt_len // 128)
+        snap = eng.metrics.snapshot()
+        assert snap["prefill_chunk_pages"] == snap["prefill_chunks"] \
+            == -(-prompt_len // 128)
 
     def test_the_registry_holds_the_two_paged_programs_and_no_others(self):
         from deepspeed_tpu.observability.programs import get_program_registry
