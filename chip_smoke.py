@@ -62,6 +62,12 @@ FULL = {
     "lfm2": dict(n_layers=4, num_slots=4, max_len=2048, page_len=128,
                  n_requests=10, prompt_max=300, new_max=24,
                  paging_kernel="auto", logit_tol=0.1),
+    # max_len 2048: a pool of 65 pages, so that one layer's latent pages
+    # (19 MB in float32) stay over the decode program's scratch (12.6 MB:
+    # 32 rows of float32 logits over 128,256 words would be 16)
+    "kanana": dict(n_layers=3, num_slots=4, max_len=2048, page_len=128,
+                   n_requests=10, prompt_max=300, new_max=24,
+                   paging_kernel="auto", logit_tol=0.1),
     "kernels": dict(seq=1024, heads=12, batch=2, cache_len=1024,
                     gemv_k=4096, gemv_n=16384, sparse_seq=2048,
                     gemv_timeout_s=180),
@@ -253,6 +259,20 @@ def _requests(rng, n, vocab, prompt_max, new_max):
     return reqs
 
 
+def _requests_with_a_page_shared(rng, n, vocab, prompt_max, new_max,
+                                 page_len):
+    """``_requests`` whose first prompt holds a whole page, and whose
+    last request — admitted once a slot is free, after the first has
+    published — opens with that page: a prefix hit on whole pages."""
+    import numpy as np
+    reqs = _requests(rng, n, vocab, prompt_max, new_max)
+    first = rng.integers(0, vocab, size=page_len + 70, dtype="int32")
+    reqs[0] = (first, reqs[0][1])
+    reqs[-1] = (np.concatenate([first[:page_len], rng.integers(
+        0, vocab, size=20, dtype="int32")]), reqs[-1][1])
+    return reqs
+
+
 def _check_pool_stays_in_place(srv, label):
     """The paged decode program as the chip's compiler built it: the
     donated page pool is its output buffer (``alias_bytes``) and its
@@ -320,7 +340,8 @@ def _first_divergence_gap(a, b, row_of):
 
 def _serve_and_check(eng, module, params, reqs, num_slots, max_len,
                      page_len, paging_kernel, logit_tol, label,
-                     against_generate=True, reference_logits=None):
+                     against_generate=True, reference_logits=None,
+                     kernel="paged_attention"):
     import numpy as np
     import jax
     import jax.numpy as jnp
@@ -359,8 +380,8 @@ def _serve_and_check(eng, module, params, reqs, num_slots, max_len,
     reqs = reqs + [long]
     path = tuning.last_dispatch("paged_decode").get("path")
     _mosaic(path, f"{label} paged decode path")
-    kern = tuning.last_dispatch("paged_attention").get(f"page{page_len}")
-    _mosaic(kern, f"{label} paged_attention kernel")
+    kern = tuning.last_dispatch(kernel).get(f"page{page_len}")
+    _mosaic(kern, f"{label} {kernel} kernel")
     _say(f"{label}: {len(handles)} requests over {num_slots} slots finished "
          f"in {wall:.2f}s (compiles included); paged kernel {kern}")
     _check_pool_stays_in_place(srv, label)
@@ -544,13 +565,8 @@ def phase_lfm2(n_layers, num_slots, max_len, page_len, n_requests,
     eng = ds.init_inference(model, params=params, dtype=jnp.float32)
     rng = np.random.default_rng(3)
     vocab = model.config.vocab_size
-    reqs = _requests(rng, n_requests, vocab, prompt_max, new_max)
-    # the first prompt holds a whole page, and the last request — admitted
-    # once a slot is free, after the first has published — opens with it
-    first = rng.integers(0, vocab, size=page_len + 70, dtype="int32")
-    reqs[0] = (first, reqs[0][1])
-    reqs[-1] = (np.concatenate([first[:page_len], rng.integers(
-        0, vocab, size=20, dtype="int32")]), reqs[-1][1])
+    reqs = _requests_with_a_page_shared(rng, n_requests, vocab, prompt_max,
+                                        new_max, page_len)
     names = ("serving/prefill_tokens_reused",
              "serving/state_snapshots_restored", "serving/state_resets",
              "serving/state_snapshots_stored", "moe/expert_calls")
@@ -600,6 +616,83 @@ def phase_lfm2(n_layers, num_slots, max_len, page_len, n_requests,
     else:
         _check(False, "serve lfm2: ragged generate() did not refuse a "
                       "model with recurrent state")
+
+
+def phase_kanana(n_layers, num_slots, max_len, page_len, n_requests,
+                 prompt_max, new_max, paging_kernel, logit_tol):
+    """Kanana-2-30B-A3B's first layers (the DeepSeek-V3 architecture) at
+    published widths through the same serving path: the pool keeps one
+    compressed vector a token and layer, the decode program walks it with
+    the latent kernel (all 32 query heads a step) and leaves it where it
+    is, a prefix hit shares latent pages, the router's weights are scaled
+    by 2.448 and every live row takes the shared experts once."""
+    import numpy as np
+    import jax
+    import jax.numpy as jnp
+    import deepspeed_tpu as ds
+    from deepspeed_tpu.models.deepseek_v3 import DeepseekV3, DeepseekV3Config
+    from deepspeed_tpu.observability.metrics import get_registry
+    from deepspeed_tpu.ops.pallas import tuning
+
+    # float32 activations over the bf16 weights, as the benchmark's cell
+    # runs it and for LFM2's reason (a normalised sigmoid top-k)
+    model = DeepseekV3(DeepseekV3Config(
+        num_hidden_layers=n_layers, max_position_embeddings=max(max_len, 128),
+        dtype=jnp.float32, param_dtype=jnp.bfloat16))
+    params = _seeded_params(model)
+    eng = ds.init_inference(model, params=params, dtype=jnp.float32)
+    rng = np.random.default_rng(3)
+    vocab = model.config.vocab_size
+    reqs = _requests_with_a_page_shared(rng, n_requests, vocab, prompt_max,
+                                        new_max, page_len)
+    names = ("serving/prefill_tokens_reused", "serving/latent_tokens_walked",
+             "moe/expert_calls", "moe/assignments", "moe/shared_expert_rows")
+    counters = {name: get_registry().counter(name) for name in names}
+    before = {name: c.value for name, c in counters.items()}
+    # generate() refuses a latent cache: the served tokens are held to the
+    # family's plain float32 reference alone
+    from benchmarks.chip.families import deepseek_v3 as family
+    cfg = model.config
+    sizes = {k: getattr(cfg, k) for k in family.SIZE_KEYS}
+    published = {"rms_norm_eps": cfg.rms_norm_eps,
+                 "norm_topk_prob": cfg.norm_topk_prob,
+                 "routed_scaling_factor": cfg.routed_scaling_factor,
+                 "rope_theta": cfg.rope_theta,
+                 "rope_interleave": cfg.rope_interleave}
+
+    @jax.jit
+    def reference_logits(prm, ids):
+        with jax.default_matmul_precision("highest"):
+            return family.reference_logits(prm, ids, sizes, published,
+                                           near_ties="kept")
+
+    _serve_and_check(eng, model, params, reqs, num_slots, max_len, page_len,
+                     paging_kernel, logit_tol, "serve kanana",
+                     against_generate=False,
+                     reference_logits=reference_logits,
+                     kernel="latent_attention")
+    _check(not tuning.last_dispatch("paged_attention"),
+           "serve kanana: the K/V kernel was dispatched over a latent pool")
+    moved = {name: c.value - before[name] for name, c in counters.items()}
+    _say(f"serve kanana: counted {moved}")
+    _check(moved["serving/prefill_tokens_reused"] >= page_len,
+           f"serve kanana: no prefix hit on latent pages: {moved}")
+    _check(moved["serving/latent_tokens_walked"] > 0,
+           f"serve kanana: the latent walk was not counted: {moved}")
+    moe_layers = n_layers - cfg.first_k_dense_replace
+    _check(moved["moe/expert_calls"] > 0
+           and moved["moe/expert_calls"] % moe_layers == 0
+           and moved["moe/assignments"] == cfg.num_experts_per_tok
+           * moved["moe/shared_expert_rows"],
+           f"serve kanana: routed and shared rows do not add up: {moved}")
+    try:
+        eng.generate(np.zeros((2, 8), np.int32), max_new_tokens=2,
+                     prompt_lengths=np.asarray([8, 5], np.int32))
+    except ValueError as e:
+        _say(f"serve kanana: generate() refused: {e}")
+    else:
+        _check(False, "serve kanana: generate() did not refuse a latent "
+                      "cache")
 
 
 # ---------------------------------------------------------------------------
@@ -1115,7 +1208,7 @@ def main():
 
     phases = [("train", phase_train), ("serve", phase_serve),
               ("olmoe", phase_olmoe), ("lfm2", phase_lfm2),
-              ("kernels", phase_kernels)]
+              ("kanana", phase_kanana), ("kernels", phase_kernels)]
     if device["count"] >= 4:
         phases.append(("multichip", phase_multichip))
     else:

@@ -9,6 +9,12 @@ whose attention units hold three leaves (models/layers.py SelfAttention):
   classic equal-length path, or per-row (``[b]`` / ``[L, b]``) on the
   ragged/serving path.
 
+A latent-attention unit (models/layers.py LatentAttention) holds ONE leaf
+beside its index: ``cached_key`` ``[b, 1, d_latent, max_len]``, a token's
+normalised compressed vector followed by its rotated shared key part.
+Its values are the leading rows of the same leaf, so it has no
+``cached_value``; every walker below takes the K/V leaves a unit has.
+
 These helpers walk the tree by attention unit (any dict holding a
 ``cached_key``) so they stay correct for scanned, unrolled, and MoE
 models without hard-coding the module hierarchy. All of them are pure
@@ -21,7 +27,10 @@ import jax.numpy as jnp
 
 from ..serving.paging.allocator import NULL_PAGE
 
-_KV_KEYS = ("cached_key", "cached_value")
+# a unit's K/V leaves beside the keys of the "kv_token" collection that
+# carry a step's tokens for them
+_KV_TOKENS = (("cached_key", "k"), ("cached_value", "v"))
+_KV_KEYS = tuple(name for name, _ in _KV_TOKENS)
 # int8 page pools carry one fp32 scale plane per KV leaf (serving int8
 # KV pages): [num_pages, h, 1, page_len] — one scale per head per token,
 # stored page-shaped so scatters and the paged-attention kernel address
@@ -41,6 +50,26 @@ def _as_dict(tree):
 
 def _is_attn_unit(d) -> bool:
     return isinstance(d, dict) and "cached_key" in d
+
+
+def _kv_leaves(unit):
+    """``(leaf name, its "kv_token" key)`` for the K/V leaves ``unit``
+    holds: both for an attention unit, the keys alone for a latent
+    one."""
+    return [(name, tok) for name, tok in _KV_TOKENS if name in unit]
+
+
+def is_latent_unit(d) -> bool:
+    return _is_attn_unit(d) and "cached_value" not in d
+
+
+def has_latent_units(tree) -> bool:
+    """Whether the cache tree (or page pool) holds a latent-attention
+    unit: what moves, quantizes or re-runs pages by the K/V geometry
+    refuses such a pool by name (serving/paging/manager.py)."""
+    found = []
+    _map_units(tree, lambda u: found.append(is_latent_unit(u)) or u)
+    return any(found)
 
 
 def _map_units(cache, fn):
@@ -182,7 +211,7 @@ def gather_pages(pool, page_table, scalar_index: bool = False,
         out = {}
         stacked = unit["cached_key"].ndim == 5
         quant = "key_scale" in unit
-        for name in _KV_KEYS:
+        for name, _ in _kv_leaves(unit):
             kv = unit[name]
             if stacked:
                 g = kv[:, page_table]              # [L, s, m, h, d, p]
@@ -240,8 +269,8 @@ def extract_token_kv(cache, idx):
         stacked = unit["cached_key"].ndim == 5
         sel = (idx[None, :, None, None, None] if stacked
                else idx[:, None, None, None])
-        return {"k": jnp.take_along_axis(unit["cached_key"], sel, axis=-1),
-                "v": jnp.take_along_axis(unit["cached_value"], sel, axis=-1)}
+        return {tok: jnp.take_along_axis(unit[name], sel, axis=-1)
+                for name, tok in _kv_leaves(unit)}
 
     # rebuild a token tree with the cache's structure, one {"k","v"} dict
     # per attention unit (the kv_token collection's layout)
@@ -320,8 +349,7 @@ def scatter_token_pages(pool, token_tree, pages, offsets):
     def scatter(unit, tok):
         out = dict(unit)
         quant = "key_scale" in unit
-        for name, leaf in (("cached_key", tok["k"]),
-                           ("cached_value", tok["v"])):
+        for name, leaf in ((n, tok[t]) for n, t in _kv_leaves(unit)):
             if quant:
                 # quantize on write: the token's K/V arrives in compute
                 # precision (kv_token), lands int8 with its scale plane
@@ -346,8 +374,7 @@ def scatter_chunk_pages(pool, token_tree, page_run):
         out = dict(unit)
         page_len = unit["cached_key"].shape[-1]
         quant = "key_scale" in unit
-        for name, leaf in (("cached_key", tok["k"]),
-                           ("cached_value", tok["v"])):
+        for name, leaf in ((n, tok[t]) for n, t in _kv_leaves(unit)):
             kv = unit[name]
             writes = [(name, kv, leaf)]
             if quant:

@@ -22,6 +22,7 @@ import jax.numpy as jnp
 import flax.linen as nn
 from jax.ad_checkpoint import checkpoint_name
 
+from ..ops.pallas._common import NEG_INF
 from ..ops.transformer.attention import attention
 
 # Set by the training engine: dict logical-name -> mesh axis (or None).
@@ -698,6 +699,181 @@ class SelfAttention(nn.Module):
             kernel_init=dense_init(("qkv", "embed")),
             bias_init=nn.with_logical_partitioning(nn.initializers.zeros, ("embed",)),
             name="out")(out)
+
+
+class LatentAttention(nn.Module):
+    """Multi-head latent attention (DeepSeek-V2/V3; HF
+    ``modeling_deepseek_v3.py`` with ``q_lora_rank`` null): keys and
+    values are up-projections of ONE compressed vector a token, and that
+    vector, not the heads, is what a cache keeps. With ``x`` the normed
+    input:
+
+        q = x W_q -> [H, nope + rope] = q_nope | q_pe
+        x W_kva -> c_raw [rank] | k_pe_raw [rope];  c = RMSNorm(c_raw)
+        [k_nope | v] = c W_kvb -> [H, nope + v];  q_pe, k_pe = RoPE(.),
+        k_pe one vector shared by every head
+        score = (q_nope . k_nope + q_pe . k_pe) / sqrt(nope + rope)
+
+    ``rope_interleave``: the ``rope`` lanes are de-interleaved
+    (``x0 x1 x2 .. -> x0 x2 .. x1 x3 ..``) and then rotated by halves, as
+    published.
+
+    Two forms of one attention. **Expanded** (``decode=False``: a whole
+    sequence, training, the shape-only init): ``k_nope`` and ``v`` are
+    computed for every position and attention runs over heads, keys
+    ``nope + rope`` wide and values ``v`` wide. **Absorbed** (every
+    ``decode=True`` step — a prefill chunk over its slot's gathered row,
+    the gather path's token, and the paged kernel's): ``W_kvb``'s key
+    half is folded into the query, ``q_lat = q_nope W_kvb^K[h]``, so
+    ``score = q_lat . c + q_pe . k_pe`` reads the cached vector
+    ``c | k_pe`` (``rank + rope`` wide) directly, and the value half is
+    applied after the softmax, ``o = (sum p c) W_kvb^V[h]``: nothing the
+    width of the heads is ever made for a cached position.
+
+    The cache unit is ``cached_key`` ``[b, 1, rank + rope, max_len]``
+    (K^T layout, one "head") and ``cache_index``; there is no
+    ``cached_value`` (inference/cache.py). A step publishes its tokens'
+    vectors as ``kv_token/k``. Under the paged view (``page_table`` in
+    "cache") the step is one token and
+    ``ops.pallas.latent_attention`` walks the pool in place."""
+    n_heads: int
+    d_model: int
+    kv_lora_rank: int
+    qk_nope_head_dim: int
+    qk_rope_head_dim: int
+    v_head_dim: int
+    rotary_base: float = 10000.0
+    rope_interleave: bool = True
+    norm_epsilon: float = 1e-6
+    dtype: Any = jnp.bfloat16
+    param_dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(self, x, decode=False, positions=None):
+        from ..ops.transformer.rotary import apply_rotary_pos_emb
+        heads, rank = self.n_heads, self.kv_lora_rank
+        nope, rope, vd = (self.qk_nope_head_dim, self.qk_rope_head_dim,
+                          self.v_head_dim)
+        b, s = x.shape[0], x.shape[1]
+        scale = (nope + rope) ** -0.5
+
+        def dense(features, names, name):
+            return QDense(features=features, use_bias=False, dtype=self.dtype,
+                          param_dtype=self.param_dtype,
+                          kernel_init=dense_init(names), name=name)
+
+        q = dense(heads * (nope + rope), ("embed", "qkv"), "q_proj")(x)
+        q = q.reshape(b, s, heads, nope + rope)
+        q_nope, q_pe = q[..., :nope], q[..., nope:]
+        kva = dense(rank + rope, ("embed", None), "kv_a_proj")(x)
+        c = RMSNorm(epsilon=self.norm_epsilon,
+                    name="kv_a_norm")(kva[..., :rank])        # [b, s, rank]
+        k_pe = kva[..., rank:][:, :, None, :]                 # [b, s, 1, rope]
+        if self.rope_interleave:
+            unweave = lambda t: t.reshape(t.shape[:-1] + (rope // 2, 2)) \
+                .swapaxes(-1, -2).reshape(t.shape)
+            q_pe, k_pe = unweave(q_pe), unweave(k_pe)
+        q_pe, k_pe = apply_rotary_pos_emb(q_pe, k_pe, positions=positions,
+                                          base=self.rotary_base)
+        # [rank, H, nope + v]: a head's key and value up-projections
+        w_kvb = self.param(
+            "kv_b_proj", nn.with_logical_partitioning(
+                nn.initializers.lecun_normal(), (None, "qkv")),
+            (rank, heads * (nope + vd)), self.param_dtype) \
+            .reshape(rank, heads, nope + vd)
+        out_proj = dense(self.d_model, ("qkv", "embed"), "out")
+
+        if not decode or self.is_initializing():
+            if decode:
+                # the shape-only init of a cache (or of a page pool:
+                # batch = pages, length = one page)
+                self.variable("cache", "cached_key", jnp.zeros,
+                              (b, 1, rank + rope, s), c.dtype)
+                self.variable("cache", "cache_index",
+                              lambda: jnp.zeros((), jnp.int32))
+            kv = dot_exact_weights(c, w_kvb.reshape(rank, -1)) \
+                .reshape(b, s, heads, nope + vd)
+            k = jnp.concatenate(
+                [kv[..., :nope],
+                 jnp.broadcast_to(k_pe, (b, s, heads, rope))], axis=-1)
+            qf = jnp.concatenate([q_nope, q_pe], axis=-1)
+            sc = jnp.einsum("bqhd,bkhd->bhqk", qf, k,
+                            preferred_element_type=jnp.float32) * scale
+            sc = jnp.where(jnp.tril(jnp.ones((s, s), bool)), sc, NEG_INF)
+            o = jnp.einsum("bhqk,bkhd->bqhd",
+                           jax.nn.softmax(sc, axis=-1).astype(kv.dtype),
+                           kv[..., nope:],
+                           preferred_element_type=jnp.float32)
+            return out_proj(o.astype(self.dtype).reshape(b, s, heads * vd))
+
+        mesh = _usable_global_mesh()
+        if mesh is not None and mesh.shape.get("model", 1) > 1:
+            raise ValueError(
+                "mp_size > 1 (tensor-parallel serving) is not built for "
+                "latent attention: every head reads one compressed vector "
+                "a token, and neither the latent pool nor its kernel is "
+                "split over the model axis")
+        if not self.is_mutable_collection("kv_token"):
+            raise ValueError(
+                "latent attention under decode=True publishes the step's "
+                "vectors as the 'kv_token' collection (the serving "
+                "programs list it as mutable); generate() is not built "
+                "for a latent cache: serve() the model")
+        # absorbed: the key up-projection folded into the query
+        wk = w_kvb[..., :nope].astype(self.dtype)             # [rank, H, nope]
+        wv = w_kvb[..., nope:].astype(self.dtype)             # [rank, H, v]
+        q_lat = jnp.einsum("bshn,chn->bshc", q_nope, wk,
+                           preferred_element_type=jnp.float32) \
+            .astype(self.dtype)
+        q_full = jnp.concatenate([q_lat, q_pe], axis=-1)     # [b,s,H,rank+rope]
+        latent = jnp.concatenate([c, k_pe[:, :, 0, :]], axis=-1)
+        lat_t = latent.transpose(0, 2, 1)[:, None]            # [b,1,D,s]
+        self.variable("kv_token", "k", lambda: lat_t).value = lat_t
+        cache_index = self.variable("cache", "cache_index",
+                                    lambda: jnp.zeros((), jnp.int32))
+        idx = cache_index.value
+        if self.has_variable("cache", "page_table"):
+            if s != 1:
+                raise NotImplementedError(
+                    f"paged-pool decode is single-token (got chunk length "
+                    f"{s}); chunked prefill runs through the gathered-row "
+                    "path")
+            from ..ops.pallas.latent_attention import latent_attention
+            o_lat = latent_attention(
+                q_full[:, 0], self.variables["kv_pool"]["cached_key"],
+                self.get_variable("cache", "page_table"), idx, latent[:, 0],
+                value_width=rank, softmax_scale=scale,
+                layer=(self.get_variable("cache", "layer")
+                       if self.has_variable("cache", "layer") else None))
+            o_lat = o_lat[:, None]                            # [b,1,H,rank]
+            cache_index.value = idx + 1
+        else:
+            cached = self.variable("cache", "cached_key", jnp.zeros,
+                                   lat_t.shape, lat_t.dtype)
+            max_len = cached.value.shape[-1]
+            if idx.ndim == 1:
+                lat_all = jax.vmap(
+                    lambda row, u, i: jax.lax.dynamic_update_slice(
+                        row, u, (0, 0, i)))(cached.value, lat_t, idx)
+                rows = idx[:, None] + jnp.arange(s)[None, :]  # [b, s]
+            else:
+                lat_all = jax.lax.dynamic_update_slice(
+                    cached.value, lat_t, (0, 0, 0, idx))
+                rows = jnp.broadcast_to(idx + jnp.arange(s), (b, s))
+            cached.value = lat_all
+            cache_index.value = idx + s
+            lat_all = lat_all[:, 0]                           # [b, D, T]
+            sc = jnp.einsum("bshd,bdt->bhst", q_full, lat_all,
+                            preferred_element_type=jnp.float32) * scale
+            seen = jnp.arange(max_len)[None, None, :] <= rows[:, :, None]
+            sc = jnp.where(seen[:, None], sc, NEG_INF)
+            o_lat = jnp.einsum("bhst,bct->bshc",
+                               jax.nn.softmax(sc, axis=-1).astype(self.dtype),
+                               lat_all[:, :rank],
+                               preferred_element_type=jnp.float32)
+        o = jnp.einsum("bshc,chv->bshv", o_lat.astype(self.dtype), wv,
+                       preferred_element_type=jnp.float32)
+        return out_proj(o.astype(self.dtype).reshape(b, s, heads * vd))
 
 
 class MLP(nn.Module):

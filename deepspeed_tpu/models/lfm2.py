@@ -86,9 +86,6 @@ class LFM2Config:
         if self.conv_bias:
             raise NotImplementedError("conv_bias: the published models "
                                       "have none")
-        if self.routed_scaling_factor != 1.0:
-            raise NotImplementedError(
-                "routed_scaling_factor: the published models have 1")
 
     @property
     def max_seq_len(self):
@@ -150,6 +147,7 @@ class LFM2Layer(nn.Module):
                 num_experts_per_tok=cfg.num_experts_per_tok,
                 norm_topk_prob=cfg.norm_topk_prob, dtype=cfg.dtype,
                 score="sigmoid", use_expert_bias=cfg.use_expert_bias,
+                routed_scaling_factor=cfg.routed_scaling_factor,
                 name="moe")(m, token_mask=token_mask, experts=experts,
                             layer=self.moe_index)
         y = activation_constraint(h + y, ("batch", "seq", "embed"))

@@ -12,7 +12,7 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from deepspeed_tpu.models.layers import QDense
+from deepspeed_tpu.models.layers import GatedMLP, QDense
 
 from ..comm.mesh import get_global_mesh
 from ..utils.logging import logger
@@ -135,7 +135,7 @@ class DroplessMoE(nn.Module):
     OLMoE, Mixtral and their kin publish it:
     ``y = sum_{e in topk(p)} p_e W_down,e (silu(W_gate,e x) * W_up,e x)``,
     ``p = softmax_float32(W_router x)`` over all experts. No capacity, no
-    bias, no shared expert (``sharded_moe.dropless_experts``).
+    bias (``sharded_moe.dropless_experts``).
 
     The router is this module's, float32 whatever the model's dtype, and
     its matmul runs at full precision: two gate probabilities that nearly
@@ -159,6 +159,16 @@ class DroplessMoE(nn.Module):
     # the scores to choose and never to weigh
     score: str = "softmax"
     use_expert_bias: bool = False
+    # what the DeepSeek-V3 line adds to that router: the chosen weights
+    # times ``routed_scaling_factor`` (1: no multiply is traced), their
+    # sum's ``norm_eps``, and ``shared_width`` > 0 for the shared
+    # experts — ONE gated MLP that wide (``n_shared_experts`` times an
+    # expert's width) which every token takes, added unweighted to the
+    # routed sum; its weights are this module's own (``shared``)
+    routed_scaling_factor: float = 1.0
+    norm_eps: float = 1e-6
+    shared_width: int = 0
+    param_dtype: Any = jnp.float32
 
     @nn.compact
     def __call__(self, x, deterministic=True, token_mask=None, *, experts,
@@ -183,12 +193,19 @@ class DroplessMoE(nn.Module):
             if self.use_expert_bias else None
         probs, weights, chosen = topk_routing(
             logits, self.num_experts_per_tok, self.norm_topk_prob,
-            score=self.score, bias=bias)
+            score=self.score, bias=bias, scale=self.routed_scaling_factor,
+            norm_eps=self.norm_eps)
         out, counts = dropless_experts(
             tokens.astype(self.dtype), weights, chosen, *experts, layer,
             live=live)
+        out = out.reshape(b, s, d).astype(x.dtype)
+        if self.shared_width:
+            out = out + GatedMLP(
+                d_model=d, d_ff=self.shared_width, dtype=self.dtype,
+                param_dtype=self.param_dtype, name="shared")(x).astype(
+                    x.dtype)
         aux = {"gate_mean": mean_gate(probs, live), "counts": counts}
-        return out.reshape(b, s, d).astype(x.dtype), aux
+        return out, aux
 
 
 def split_params_into_different_moe_groups_for_optimizer(param_groups):

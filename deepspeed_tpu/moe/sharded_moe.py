@@ -209,7 +209,8 @@ class TopKGate(nn.Module):
 # routing push a token over an expert's limit.
 
 def topk_routing(logits, k: int, renormalize: bool = False, *,
-                 score: str = "softmax", bias=None):
+                 score: str = "softmax", bias=None, scale: float = 1.0,
+                 norm_eps: float = 1e-6):
     """Every expert's score in float32, then the k largest.
 
     logits: [T, E]. Returns (scores [T, E] f32, weights [T, k] f32,
@@ -223,12 +224,16 @@ def topk_routing(logits, k: int, renormalize: bool = False, *,
     ``[E]`` (the load-balancing ``expert_bias``) is added to the scores
     to CHOOSE the k experts and never weighs them: the weights are the
     chosen experts' unbiased scores, divided under ``renormalize`` by
-    their sum plus the published ``1e-6``."""
+    their sum plus ``norm_eps`` (LFM2 publishes ``1e-6``, DeepSeek-V3
+    ``1e-20``), then multiplied by ``scale`` (a ``config.json``'s
+    ``routed_scaling_factor``; at 1 no multiply is traced)."""
     if score == "softmax":
         probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
         weights, experts = jax.lax.top_k(probs, k)
         if renormalize:
             weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
+        if scale != 1.0:
+            weights = weights * scale
         return probs, weights, experts.astype(jnp.int32)
     if score != "sigmoid":
         raise ValueError(f"unknown router score {score!r}")
@@ -237,7 +242,10 @@ def topk_routing(logits, k: int, renormalize: bool = False, *,
         scores if bias is None else scores + bias.astype(jnp.float32), k)
     weights = jnp.take_along_axis(scores, experts, axis=-1)
     if renormalize:
-        weights = weights / (jnp.sum(weights, axis=-1, keepdims=True) + 1e-6)
+        weights = weights / (jnp.sum(weights, axis=-1, keepdims=True)
+                             + norm_eps)
+    if scale != 1.0:
+        weights = weights * scale
     return scores, weights, experts.astype(jnp.int32)
 
 

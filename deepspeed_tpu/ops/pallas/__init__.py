@@ -5,6 +5,7 @@
 | transformer attention + softmax kernels | flash_attention          |
 | inference softmax_context (KV cache)    | decode_attention         |
 | (no reference analog: paged serving)    | paged_attention          |
+| (no reference analog: latent pages)     | latent_attention         |
 | adam/multi_tensor_adam.cu               | fused_adam.fused_adamw   |
 | lamb/fused_lamb_cuda.cpp (trust ratios) | fused_lamb.fused_lamb    |
 | transformer/normalize_kernels.cu        | layernorm.fused_layer_norm |
@@ -17,6 +18,7 @@ tests on the CPU mesh.
 from .decode_attention import decode_attention
 from .flash_attention import flash_attention
 from .paged_attention import paged_attention
+from .latent_attention import latent_attention
 from .fused_adam import fused_adamw, FusedAdamState
 from .fused_lamb import fused_lamb, FusedLambState
 from .layernorm import fused_layer_norm

@@ -214,6 +214,8 @@ class ServingEngine:
             # a multi-token step advanced would have to be rolled back
             self._paged.refuse_state(
                 "serving.speculation (a multi-token verification step)")
+            self._paged.refuse_latent(
+                "serving.speculation (a multi-token verification step)")
         self._slot_cap = n                # admissible slots (autoscaling
                                           # drains above the cap via the
                                           # preemption path; compiled
@@ -1169,6 +1171,14 @@ class ServingEngine:
             self._fold_moe_counts(counts)
             if self._paged.use_kernel:
                 self.metrics.on_decode_harvest(np.count_nonzero(toks >= 0))
+            if self._paged.has_latent:
+                # before the tokens are emitted: a row that kept one
+                # attended its prompt and every token it had generated
+                # but the one it was fed
+                self.metrics.on_latent_walk(sum(
+                    req.prompt.shape[0] + len(req.output_tokens) - 1
+                    for slot, req in enumerate(snapshot)
+                    if req is not None and not req.done and toks[slot] >= 0))
             for slot, req in enumerate(snapshot):
                 if req is None or req.done:  # empty, or cancelled in flight
                     continue
@@ -1180,11 +1190,15 @@ class ServingEngine:
 
     def _fold_moe_counts(self, counts):
         """An expert layer's routing of the dispatches just read back
-        (``[L, E]`` each), folded into the process registry."""
+        (``[L, E]`` each), folded into the process registry; a module
+        with shared experts says how many rows its counts mean went
+        through them (``shared_expert_rows``)."""
         if counts:
+            shared = getattr(self.module, "shared_expert_rows", None)
             with _span("serving/moe_counts", {"dispatches": len(counts)}):
                 for c in counts:
-                    self.metrics.on_moe_counts(c)
+                    self.metrics.on_moe_counts(
+                        c, shared(c) if shared is not None else None)
 
     def _finish(self, slot: int, req: Request):
         self._record_residency(req)

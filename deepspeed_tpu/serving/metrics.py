@@ -250,13 +250,25 @@ class ServingMetrics:
             self.registry.counter("serving/paged_rows_walked").inc(
                 rows_walked)
 
-    def on_moe_counts(self, counts):
+    def on_latent_walk(self, tokens: int):
+        """One decode dispatch over a latent page pool, read back: the
+        pooled tokens the rows that decoded attended, summed (the host's
+        own count: a row's prompt and what it had generated). Times the
+        layers and a token's latent bytes it is what the latent kernel
+        had to read."""
+        if self.registry is not None:
+            self.registry.counter("serving/latent_tokens_walked").inc(tokens)
+
+    def on_moe_counts(self, counts, shared_rows=None):
         """One dispatch's routing, ``[L, E]``: the token-expert pairs
         each layer's router sent to each expert (rows that held no
         request are in none). Per layer call: the pairs, the experts
         that got at least one row — whose weights the grouped matmul
         had to read — and the largest group; the mean group is
-        ``moe/assignments / (moe/expert_calls * E)``."""
+        ``moe/assignments / (moe/expert_calls * E)``. ``shared_rows``:
+        of a model with shared experts, the live rows that went through
+        them, summed over its expert layers (``moe/shared_expert_rows``;
+        the counters above count routed experts only)."""
         if self.registry is not None:
             reg = self.registry
             reg.counter("moe/assignments").inc(int(counts.sum()))
@@ -264,6 +276,8 @@ class ServingMetrics:
             reg.counter("moe/experts_touched").inc(int((counts > 0).sum()))
             reg.counter("moe/experts_offered").inc(int(counts.size))
             reg.counter("moe/load_max").inc(int(counts.max(axis=1).sum()))
+            if shared_rows is not None:
+                reg.counter("moe/shared_expert_rows").inc(int(shared_rows))
 
     # always-on host-loop accounting (process registry, so a reader that
     # runs after the engine is gone still finds it): host clock

@@ -39,10 +39,11 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from ...inference.cache import (add_slot_state, cache_page_len,
+from ...inference.cache import (_kv_leaves, add_slot_state, cache_page_len,
                                 chunk_state_view, export_pages,
                                 extract_token_kv, gather_pages,
-                                has_recurrent_state, import_pages,
+                                has_latent_units, has_recurrent_state,
+                                import_pages,
                                 init_page_pool, make_paged_view,
                                 pool_is_quantized, quantize_page_pool,
                                 scatter_chunk_pages, scatter_token_pages,
@@ -75,10 +76,9 @@ def _chunk_tree_from_cache(cache, start, chunk):
 
     def walk(node):
         if isinstance(node, dict) and "cached_key" in node:
-            return {"k": jax.lax.dynamic_slice_in_dim(
-                        node["cached_key"], start, chunk, axis=-1),
-                    "v": jax.lax.dynamic_slice_in_dim(
-                        node["cached_value"], start, chunk, axis=-1)}
+            return {tok: jax.lax.dynamic_slice_in_dim(
+                        node[name], start, chunk, axis=-1)
+                    for name, tok in _kv_leaves(node)}
         if isinstance(node, dict):
             return {k: walk(v) for k, v in node.items()}
         return node
@@ -335,7 +335,12 @@ class PagedKVManager:
         self.dequant_dtype = next(
             leaf.dtype for leaf in jax.tree.leaves(pool)
             if getattr(leaf, "ndim", 0) >= 4)
+        # a latent-attention model is known by its cache collection too:
+        # a unit of keys with no values beside them
+        self.has_latent = has_latent_units(pool)
         if self.kv_quant:
+            self.refuse_latent("serving.kv_int8 (quantize_page_pool: int8 "
+                               "pages)")
             pool = quantize_page_pool(pool)
         # a model with recurrent state is known by its cache collection:
         # its pool also keeps each slot's state and the state at each
@@ -425,6 +430,8 @@ class PagedKVManager:
         ``(unit_records, n_filled)``; the caller owns releasing the slot
         once the payload is safely handed off."""
         self.refuse_state("a page handoff (export_slot)")
+        self.refuse_latent("a page handoff (fleet/handoff.py export_slot: "
+                           "export_pages)")
         pages = self._slot_pages[slot]
         if pages is None:
             raise ValueError(f"export of unowned slot {slot}")
@@ -444,6 +451,8 @@ class PagedKVManager:
         change, so the receiver's compiled paged programs stay cached —
         the handoff is a page transfer, not a recompute."""
         self.refuse_state("a page handoff (import_slot)")
+        self.refuse_latent("a page handoff (fleet/handoff.py import_slot: "
+                           "import_pages)")
         if self._slot_pages[slot] is not None:
             raise ValueError(f"import into occupied slot {slot}")
         private = self.allocator.alloc(total_pages)
@@ -472,6 +481,18 @@ class PagedKVManager:
                 f"{type(self._module).__name__} keeps a convolution state "
                 "beside its K/V pages (a slot's, and one at each page's "
                 "end), which this path neither moves nor rolls back")
+
+    def refuse_latent(self, what: str):
+        """What is written for pages of K and V heads is not built for a
+        latent pool (one leaf a layer, keys 576 wide whose leading 512
+        rows are the values), and says so by name."""
+        if self.has_latent:
+            raise ValueError(
+                f"{what} is not built for a latent page pool: "
+                f"{type(self._module).__name__} keeps one compressed "
+                "vector a token and layer, not K and V heads, and this "
+                "path's page geometry, scales or verification step "
+                "assume the heads")
 
     def state_restore_page(self, slot: int, shared_tokens: int):
         """The physical page whose stored state the slot's first prefill

@@ -370,3 +370,91 @@ def test_lfm2_decode_program_keeps_pages_and_state_where_they_are(
         if op == "fusion":
             op = roots[re.search(r"calls=%([\w.\-]+)", line).group(1)]
         assert op in IN_PLACE, f"moved by: {line.strip()[:200]}"
+
+
+# the Kanana-2 cell (configs/kanana-2-30b-a3b-serve.json) at its
+# published widths, its first two layers: the dense one and one of
+# experts — 32 heads on a latent 512 + 64 wide, 128 experts of 768 and
+# the shared MLP of 1536; slots of 9216 positions in 72 pages
+KANANA_PAGES, KANANA_MAX_PAGES = 1025, 72
+
+
+def _kanana(dtype):
+    from deepspeed_tpu.models.deepseek_v3 import DeepseekV3, DeepseekV3Config
+    import flax.core.meta as flax_meta
+    model = DeepseekV3(DeepseekV3Config(
+        num_hidden_layers=2, max_position_embeddings=9216, dtype=dtype,
+        param_dtype=jnp.bfloat16))
+    params = jax.eval_shape(
+        lambda r: flax_meta.unbox(model.init(
+            r, jnp.ones((1, 8), jnp.int32)))["params"],
+        jax.random.PRNGKey(0))
+    return model, params
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+def test_kanana_decode_program_walks_the_latent_pool_in_place(
+        one_chip, monkeypatch, dtype):
+    """A latent pool: ONE leaf a layer, ``[pages, 1, 576, page_len]``,
+    no values beside it. The decode program compiled for the chip holds
+    the latent Mosaic kernel (all 32 query heads a grid step, which the
+    paged kernel's head groups could not be: 16 and 32 rows a step abort
+    this Mosaic), aliases the whole pool and moves none of it, and its
+    scratch stays rows of activations."""
+    from deepspeed_tpu.inference.cache import has_latent_units
+    from deepspeed_tpu.ops.pallas import tuning
+    monkeypatch.setattr(
+        importlib.import_module("deepspeed_tpu.ops.pallas.latent_attention"),
+        "_interpret", lambda: False)
+    model, params = _kanana(dtype)
+
+    def on_chip(tree):
+        shapes = jax.eval_shape(tree) if callable(tree) else tree
+        return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=one_chip), shapes)
+
+    pool_shapes = on_chip(
+        lambda: init_page_pool(model, params, KANANA_PAGES, PAGE_LEN))
+    assert has_latent_units(pool_shapes)
+    unit = pool_shapes["layers_1"]["attn"]
+    assert set(unit) == {"cached_key", "cache_index"}
+    assert unit["cached_key"].shape == (KANANA_PAGES, 1, 576, PAGE_LEN)
+    assert unit["cached_key"].dtype == dtype
+    slot = lambda kind: jax.ShapeDtypeStruct((SLOTS,), kind)
+    state = {"lengths": slot(jnp.int32), "last_token": slot(jnp.int32),
+             "active": slot(jnp.bool_), "remaining": slot(jnp.int32)}
+    args = (on_chip(params), pool_shapes,
+            on_chip(jax.ShapeDtypeStruct((SLOTS, KANANA_MAX_PAGES),
+                                         jnp.int32)),
+            on_chip(state), on_chip(lambda: jax.random.PRNGKey(0)),
+            on_chip(jax.ShapeDtypeStruct((), jnp.int32)))
+    static = (128255, 1.0, 0, 1.0, None, True, False, False, True, dtype)
+    tuning.clear_last_dispatch()
+    compiled = jax.jit(
+        _paged_decode_iter_impl, static_argnums=(0, 11, 12, 13, 14, 15, 16),
+        donate_argnums=(2, 4)).lower(model, *args, *static).compile()
+    rec = tuning.last_dispatch("latent_attention")["page%d" % PAGE_LEN]
+    assert (rec["impl"], rec["block_k"]) == ("kernel", 512)
+    assert not tuning.last_dispatch("paged_attention")
+
+    pool_bytes = sum(x.size * x.dtype.itemsize
+                     for x in jax.tree.leaves(pool_shapes))
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= pool_bytes
+    # rows of activations (32 rows of 128,256 float32 logits are 16 MB):
+    # nowhere near a layer's pool leaf (151 MB in bf16)
+    assert mem.temp_size_in_bytes < 32 * 2 ** 20, mem.temp_size_in_bytes
+    hlo = compiled.as_text()
+    assert "%latent_attn" in hlo and hlo_has(compiled, "ragged-dot")
+    roots = _roots(_computations(hlo))
+    moved = re.compile(r"\[%d,1,576,%d\]" % (KANANA_PAGES, PAGE_LEN))
+    for line in hlo.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%[\w.\-]+ = (\S+) ([\w\-]+)\(", line)
+        if not m or m.group(1).startswith("(") or not moved.search(
+                m.group(1)):
+            continue
+        op = m.group(2)
+        if op == "fusion":
+            op = roots[re.search(r"calls=%([\w.\-]+)", line).group(1)]
+        assert op in IN_PLACE, f"moved by: {line.strip()[:200]}"
