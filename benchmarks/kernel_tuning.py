@@ -136,17 +136,34 @@ def _paged_candidates(heads, page_len, max_pages, max_candidates=None):
 
 
 def sweep_paged_attention(slots, heads, head_dim, page_len, max_pages,
-                          dtype="float32", kv_int8=False, trials=3,
-                          warmup=1, max_candidates=None, log=print):
+                          dtype="float32", kv_int8=False, lengths=None,
+                          calls=1, trials=3, warmup=1, max_candidates=None,
+                          log=print):
     """Time candidate (block_k, head_block) tilings of the paged
     decode-attention kernel at one (slots x pages x head-dim) serving
     shape; returns {key: entry} in the shared tuning-artifact format
-    (``block_k`` in TOKENS — pages_per_block = block_k / page_len)."""
+    (``block_k`` in TOKENS — pages_per_block = block_k / page_len), the
+    winner's entry carrying every candidate's time under ``swept``.
+
+    ``lengths``: the pooled tokens of the rows that decode, one number a
+    row; the rows it does not name are at length 0, as the server hands
+    the kernel the rows that do not decode. None = full tables at full
+    lengths, the worst case. ``calls``: that many kernel calls chained
+    in one program (each call's output is the next one's query), the
+    time reported per call: a call of tens of microseconds is not timed
+    by a host clock around one dispatch. The query and the current
+    token's K/V are in the pool's type, as a model hands them."""
     import jax
     import jax.numpy as jnp
     from deepspeed_tpu.ops.pallas import paged_attention, tuning
     from deepspeed_tpu.ops.pallas.paged_attention import KERNEL
 
+    full = max_pages * page_len - 1
+    if lengths is None:
+        lengths = [full] * slots
+    if len(lengths) > slots or max(lengths) > full:
+        raise ValueError(f"lengths {lengths}: at most {slots} rows of at "
+                         f"most {full} pooled tokens")
     num_pages = slots * max_pages + 1
     ks = jax.random.split(jax.random.PRNGKey(0), 5)
     dt = jnp.dtype(dtype)
@@ -160,17 +177,22 @@ def sweep_paged_attention(slots, heads, head_dim, page_len, max_pages,
         kp, ksc = _quantize_kv(kp)
         vp, vsc = _quantize_kv(vp)
         scales = {"k_scale": ksc, "v_scale": vsc}
-    # full tables, full lengths: the worst-case (and steady-state) shape
     ptab = (jnp.arange(slots * max_pages, dtype=jnp.int32) + 1) \
         .reshape(slots, max_pages)
-    lengths = jnp.full((slots,), max_pages * page_len - 1, jnp.int32)
-    q = jax.random.normal(ks[2], (slots, 1, heads, head_dim), jnp.float32)
-    kn = jax.random.normal(ks[3], (slots, heads, head_dim, 1), jnp.float32)
-    vn = jax.random.normal(ks[4], (slots, heads, head_dim, 1), jnp.float32)
+    lengths = jnp.asarray(list(lengths) + [0] * (slots - len(lengths)),
+                          jnp.int32)
+    q = jax.random.normal(ks[2], (slots, 1, heads, head_dim), dt)
+    kn = jax.random.normal(ks[3], (slots, heads, head_dim, 1), dt)
+    vn = jax.random.normal(ks[4], (slots, heads, head_dim, 1), dt)
 
-    fn = jax.jit(lambda *a: paged_attention(*a, impl="kernel", **scales))
+    def chain(q, *rest):
+        return jax.lax.fori_loop(0, calls, lambda _, q: paged_attention(
+            q, *rest, impl="kernel", **scales).astype(q.dtype), q)
+
+    fn = jax.jit(chain)
+    args = (q, kp, vp, ptab, lengths, kn, vn)
     tuning.clear_last_dispatch()
-    jax.block_until_ready(fn(q, kp, vp, ptab, lengths, kn, vn))
+    jax.block_until_ready(fn(*args))
     dispatched = tuning.last_dispatch(KERNEL)
     structure = f"page{page_len}"
     key = dispatched[structure]["key"]
@@ -178,25 +200,23 @@ def sweep_paged_attention(slots, heads, head_dim, page_len, max_pages,
         f"pages{max_pages}x{page_len} {dt.name}"
         f"{' int8' if kv_int8 else ''}: key {key}")
 
-    best = None
+    swept = []
     for bk, hb in _paged_candidates(heads, page_len, max_pages,
                                     max_candidates):
         entry = {"block_k": bk, "head_block": hb}
         with tuning.tuning_table({key: entry}):
             jax.clear_caches()   # force a re-trace with the candidate
             try:
-                ms = _time_it(fn, (q, kp, vp, ptab, lengths, kn, vn),
-                              trials, warmup)
+                ms = _time_it(fn, args, trials, warmup) / calls
             except Exception as e:  # infeasible tiling = skip, not fail
                 log(f"  bk={bk} hb={hb}: infeasible ({e})")
                 continue
-        log(f"  bk={bk} hb={hb}: {ms:.3f} ms")
-        if best is None or ms < best[1]["ms"]:
-            best = (key, {**entry, "ms": round(ms, 4)})
+        log(f"  bk={bk} hb={hb}: {ms:.4f} ms")
+        swept.append({**entry, "ms": round(ms, 5)})
     jax.clear_caches()
-    if best is None:
+    if not swept:
         raise RuntimeError("no feasible paged_attention candidate")
-    return {best[0]: best[1]}
+    return {key: {**min(swept, key=lambda e: e["ms"]), "swept": swept}}
 
 
 def _int_list(text):
@@ -233,6 +253,13 @@ def main(argv=None):
     p.add_argument("--page-len", type=int, default=128)
     p.add_argument("--kv-int8", action="store_true",
                    help="paged sweep: time the int8-page dequant path")
+    p.add_argument("--lengths", type=_int_list, default=None,
+                   help="paged sweep: pooled tokens of the rows that decode, "
+                        "comma-separated (the other rows at length 0; "
+                        "default: every row at the full table)")
+    p.add_argument("--calls", type=int, default=1,
+                   help="paged sweep: kernel calls chained in one timed "
+                        "program (the time is per call)")
     p.add_argument("--out", default="benchmarks/results/flash_tuning.json")
     args = p.parse_args(argv)
 
@@ -259,6 +286,7 @@ def main(argv=None):
                     entries.update(sweep_paged_attention(
                         slots, args.heads, hd, args.page_len, max_pages,
                         dtype=args.dtype, kv_int8=args.kv_int8,
+                        lengths=args.lengths, calls=args.calls,
                         trials=args.trials, warmup=args.warmup,
                         max_candidates=args.max_candidates))
     device = jax.devices()[0].device_kind if on_tpu() else "cpu-interpret"
@@ -270,7 +298,8 @@ def main(argv=None):
                "head_dim": args.head_dim, "dtype": args.dtype,
                "causal": not args.no_causal,
                "slots": args.slots, "max_pages": args.max_pages,
-               "page_len": args.page_len, "kv_int8": args.kv_int8},
+               "page_len": args.page_len, "kv_int8": args.kv_int8,
+               "lengths": args.lengths, "calls": args.calls},
         trials=args.trials,
         note=("interpret-mode timings are NOT representative — regenerate "
               "on hardware" if device == "cpu-interpret" else

@@ -782,14 +782,15 @@ def _kernel_checks(seq, heads, batch, cache_len, gemv_k, gemv_n, sparse_seq,
                    f"not {head_block}")
         return run
 
-    def paged(heads, head_block, int8):
+    def paged(heads, head_block, int8, dtype=jnp.bfloat16, kv_heads=None):
         def run():
             d, page_len, slots, max_pages = 64, 128, 4, cache_len // 128
             num_pages = slots * max_pages + 1
-            q = normal((slots, 1, heads, d))
-            kp, vp = (normal((num_pages, heads, d, page_len))
+            kv = kv_heads or heads
+            q = normal((slots, 1, heads, d), dtype)
+            kp, vp = (normal((num_pages, kv, d, page_len), dtype)
                       for _ in range(2))
-            kn, vn = (normal((slots, heads, d, 1)) for _ in range(2))
+            kn, vn = (normal((slots, kv, d, 1), dtype) for _ in range(2))
             ptab = jnp.asarray(
                 1 + rng.permutation(num_pages - 1)[:slots * max_pages]
                 .reshape(slots, max_pages), jnp.int32)
@@ -809,9 +810,17 @@ def _kernel_checks(seq, heads, batch, cache_len, gemv_k, gemv_n, sparse_seq,
             _check(rec["head_block"] == head_block,
                    f"{heads} heads ran at head block {rec['head_block']}, "
                    f"not {head_block}")
+            # the products run in the pool's own type: a bf16 pool's in
+            # bf16, a float32 pool's and an int8 pool's dequantised
+            # blocks in float32
+            products = "float32" if int8 else jnp.dtype(dtype).name
+            _check(rec["products"] == products,
+                   f"a {'int8' if int8 else products} pool's products ran in "
+                   f"{rec['products']}")
             want = paged_attention(q, kp, vp, ptab, lengths, kn, vn,
                                    impl="dense", **scales)
             _close("paged", got, want, 0.03)
+            return f"products in {rec['products']}"
         return run
 
     def adam_like(make_fused, make_optax, tol):
@@ -994,6 +1003,8 @@ def _kernel_checks(seq, heads, batch, cache_len, gemv_k, gemv_n, sparse_seq,
          paged(6, 2, False)),
         ("paged_attention bf16 pages 3 heads (head block 1)",
          paged(3, 1, False)),
+        ("paged_attention float32 pages 32 heads on 8 (head block 2)",
+         paged(32, 2, False, jnp.float32, kv_heads=8)),
         (f"paged_attention int8 pages {heads} heads", paged(heads, 4, True)),
         ("paged_attention int8 pages 3 heads (head block 1)",
          paged(3, 1, True)),

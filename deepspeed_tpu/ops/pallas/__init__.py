@@ -11,6 +11,12 @@
 | transformer/normalize_kernels.cu        | layernorm.fused_layer_norm |
 | quantization/quantizer.cu               | quantizer.quantize/dequantize |
 
+The attention kernels' matmuls run in the operand dtype (bf16 hot path)
+with fp32 accumulation: flash_attention, latent_attention and, for a pool
+or cache narrower than float32, paged_attention and decode_attention
+(their shared ``_common.online_softmax_block``); a float32 pool's stay
+float32.
+
 Kernels run in interpreter mode automatically off-TPU so the whole suite
 tests on the CPU mesh.
 """

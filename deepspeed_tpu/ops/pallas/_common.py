@@ -91,18 +91,50 @@ def read_slopes(slopes_ref, h0: int, hb: int):
     return jnp.stack([slopes_ref[h0 + h] for h in range(hb)]).reshape(hb, 1)
 
 
+def products_dtype(dtype):
+    """The type ``online_softmax_block`` multiplies a K/V block of
+    ``dtype`` in: the block's own when it is a float narrower than
+    float32 (a bf16 pool: one pass of the MXU, float32 accumulation),
+    else float32."""
+    import jax.numpy as jnp
+    dtype = jnp.dtype(dtype)
+    narrow = jnp.issubdtype(dtype, jnp.floating) and dtype.itemsize < 4
+    return dtype if narrow else jnp.dtype(jnp.float32)
+
+
+def block_query(q, scale, kv_dtype):
+    """A grid step's query rows as ``online_softmax_block`` takes them,
+    float32 ``[rows, d]``: pre-scaled where the products are float32;
+    as they arrived where the products run in a narrower pool's type —
+    the block casts them back to it (exact: they came in it) and the
+    scale goes on the float32 scores."""
+    import jax.numpy as jnp
+    q = q.astype(jnp.float32)
+    return q * scale if products_dtype(kv_dtype) == jnp.float32 else q
+
+
 def online_softmax_block(q, kblk, vblk, start, valid_len, q_pos, slopes,
-                         m_ref, l_ref, acc_ref, *, hb, alibi, group=1):
+                         m_ref, l_ref, acc_ref, *, scale, hb, alibi, group=1):
     """One online-softmax update for an [hb, d, Bk] K^T/V block — THE
     inner loop shared by the decode-attention and paged-attention
     kernels (one definition, or the two online-softmax recurrences
     silently drift).
 
-    q is pre-scaled [hb, d] fp32; ``kblk``/``vblk`` are [hb, d, Bk]
-    refs or arrays (any float dtype — int8 pages dequantize BEFORE this
-    call). Per-head scores are hb small matmuls (MHA has distinct K per
-    head, so there is no single big matmul); the softmax/statistics
-    update is vectorized across the head block.
+    All matmuls run in the blocks' own dtype (``products_dtype``: the
+    bf16 hot path) with fp32 accumulation — the same bf16-in/fp32-acc
+    contract as the flash kernels and the XLA einsum path — and lose
+    nothing to it: a bf16 block goes to the MXU as it lies in VMEM
+    against the query in bf16 (bf16 x bf16 products are exact in
+    float32; ``scale`` and ALiBi go on the float32 scores), and the
+    float32 probabilities go as their three bf16 terms, stacked as rows
+    of one product (the MXU's time is the block's load, not the rows
+    pushed through it). A float32 block (and an int8 page, dequantized
+    BEFORE this call) multiplies in float32 as it always did.
+
+    q is ``block_query``'s [hb, d] fp32; ``kblk``/``vblk`` are [hb, d,
+    Bk] refs or arrays. Per-head scores are hb small matmuls (MHA has
+    distinct K per head, so there is no single big matmul); the
+    softmax/statistics update is vectorized across the head block.
 
     ``group`` query heads read each K/V head (grouped-query attention):
     q, the statistics and the accumulator then hold ``hb * group`` rows,
@@ -119,12 +151,17 @@ def online_softmax_block(q, kblk, vblk, start, valid_len, q_pos, slopes,
     """
     import jax
     import jax.numpy as jnp
+    from jax.experimental.pallas import tpu as pltpu
+    cdt = products_dtype(kblk.dtype)
+    narrow = cdt != jnp.float32
     rows = []
     for h in range(hb):
-        kh = kblk[h].astype(jnp.float32)                     # [d, Bk]
-        rows.append(jnp.dot(q[h * group:(h + 1) * group], kh,
+        kh = kblk[h].astype(cdt)                             # [d, Bk]
+        rows.append(jnp.dot(q[h * group:(h + 1) * group].astype(cdt), kh,
                             preferred_element_type=jnp.float32))  # [g, Bk]
     s = jnp.concatenate(rows, axis=0)                        # [hb*g, Bk]
+    if narrow:
+        s = s * scale
     col = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1) + start
     if alibi:
         s = s + slopes * (col - q_pos).astype(jnp.float32)
@@ -135,15 +172,37 @@ def online_softmax_block(q, kblk, vblk, start, valid_len, q_pos, slopes,
     m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
     corr = jnp.exp(m_prev - m_new)
     p = jnp.exp(s - m_new)                                   # [hb, Bk]
+    # columns past the valid prefix may hold padding garbage —
+    # 0-probability x NaN = NaN, so zero the V columns explicitly
+    nt = (((1,), (1,)), ((), ()))
     outs = []
-    for h in range(hb):
-        # columns past the valid prefix may hold padding garbage —
-        # 0-probability x NaN = NaN, so zero the V columns explicitly
-        vh = jnp.where(valid[h * group:h * group + 1],
-                       vblk[h].astype(jnp.float32), 0.0)
-        outs.append(jax.lax.dot_general(
-            p[h * group:(h + 1) * group], vh, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32))             # [g, d]
+    if narrow:
+        # p as the three bf16 terms whose sum it is to float32's 24 bits
+        terms, rest = [], p
+        for _ in range(3):
+            terms.append(rest.astype(cdt).astype(jnp.float32))
+            rest = rest - terms[-1]
+        for h in range(hb):
+            # the guard is a select on the block's BITS, two bf16 rows a
+            # word: no widened copy of the block is made (and Mosaic
+            # lays no one-row boolean mask over a bf16 tile). It costs
+            # less than a branch around it would: every block takes it
+            bits = pltpu.bitcast(vblk[h], jnp.uint32)        # [d/2, Bk]
+            ok = (jax.lax.broadcasted_iota(jnp.int32, bits.shape, 1)
+                  + start) < valid_len
+            vh = pltpu.bitcast(jnp.where(ok, bits, jnp.uint32(0)), cdt)
+            hs = slice(h * group, (h + 1) * group)
+            ph = jnp.concatenate([t[hs] for t in terms], axis=0)
+            o = jax.lax.dot_general(ph.astype(cdt), vh, nt,
+                                    preferred_element_type=jnp.float32)
+            outs.append(o[2 * group:] + o[group:2 * group] + o[:group])
+    else:
+        for h in range(hb):
+            vh = jnp.where(valid[h * group:h * group + 1],
+                           vblk[h].astype(jnp.float32), 0.0)
+            outs.append(jax.lax.dot_general(
+                p[h * group:(h + 1) * group], vh, nt,
+                preferred_element_type=jnp.float32))             # [g, d]
     pv = jnp.concatenate(outs, axis=0)                       # [hb*g, d]
     l_ref[...] = corr * l_ref[...] + jnp.sum(p, axis=-1, keepdims=True)
     acc_ref[...] = corr * acc_ref[...] + pv

@@ -46,9 +46,10 @@ from jax.sharding import PartitionSpec as P
 
 from . import tuning
 from ._common import NEG_INF
+from ._common import block_query as _block_query
 from ._common import interpret_mode as _interpret
 from ._common import (log_fallback_on_tpu, model_axis_size, over_model_axis,
-                      pick_head_block)
+                      pick_head_block, products_dtype)
 from ._common import online_softmax_block as _attend_block
 from ._common import read_slopes as _read_slopes
 
@@ -105,11 +106,11 @@ def _dma_kernel(len_ref, slopes_ref, q_ref, k_hbm, v_hbm, o_ref,
                 wk, wv = copies(j, parity)
                 wk.wait()
                 wv.wait()
-                q = q_ref[0, 0].astype(jnp.float32) * scale
+                q = _block_query(q_ref[0, 0], scale, k_hbm.dtype)
                 kb, vb = bufs[parity]
                 _attend_block(q, kb, vb, j * block_k, length, length - 1,
-                              slopes, m_ref, l_ref, acc_ref, hb=hb,
-                              alibi=alibi)
+                              slopes, m_ref, l_ref, acc_ref, scale=scale,
+                              hb=hb, alibi=alibi)
         return carry
 
     jax.lax.fori_loop(0, nb, body, 0)
@@ -235,7 +236,9 @@ def decode_attention(q, k, v, length, *, softmax_scale=None,
     tuning.record_dispatch(
         KERNEL, "dma", f"b{b}_h{heads}_d{d}_s{s}", None, block_k=bk,
         head_block=hb, impl="kernel" if use_kernel else "dense",
-        reason=reason, model_shards=tp)
+        reason=reason, model_shards=tp,
+        products=(products_dtype(k.dtype).name if use_kernel
+                  else "float32"))
     heads_1 = P(None, "model")
     out = over_model_axis(
         run, mesh, in_specs=(heads_1, heads_1, heads_1, P(), P("model")),

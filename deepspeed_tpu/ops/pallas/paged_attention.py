@@ -30,6 +30,17 @@ ever cut out to be the operand (on the v5e that cut was a 168 MB copy
 per layer for K and again for V, every decode step). A 4-D pool is the
 same kernel over a stack of one; the two are told apart by rank.
 
+All matmuls run in the pool's dtype (bf16 hot path) with fp32
+accumulation via preferred_element_type — the same bf16-in/fp32-acc
+contract as the flash kernels and the XLA einsum path. Nothing is given
+up for it (``_common.online_softmax_block``): bf16 x bf16 score products
+are exact in float32, the softmax scale and ALiBi go on the float32
+scores, and the float32 probabilities meet a bf16 V block as their three
+bf16 terms. A block is never widened: the ``0 x NaN`` guard on V is a
+select on the block's bits. A float32 pool's products, and an int8
+pool's dequantised blocks, stay float32; the dispatch record
+(``tuning.record_dispatch``) names the type under ``products``.
+
 The current decode step's K/V is NOT in the pool yet (the engine
 appends it in place after the step, quantized when the pool is int8): it
 arrives as separate full-precision ``k_new``/``v_new`` operands and is
@@ -68,9 +79,10 @@ from jax.sharding import PartitionSpec as P
 
 from . import tuning
 from ._common import NEG_INF
+from ._common import block_query as _block_query
 from ._common import interpret_mode as _interpret
 from ._common import (log_fallback_on_tpu, model_axis_size, over_model_axis,
-                      pick_head_block)
+                      pick_head_block, products_dtype)
 from ._common import online_softmax_block as _attend_block
 from ._common import read_slopes as _read_slopes
 
@@ -162,7 +174,7 @@ def _dma_kernel(len_ref, ptab_ref, slopes_ref, layer_ref, q_ref, kn_ref,
             def _compute():
                 for c in copies(j, parity):
                     c.wait()
-                q = q_ref[0, 0].astype(jnp.float32) * scale
+                q = _block_query(q_ref[0, 0], scale, kp_hbm.dtype)
                 if quant:
                     kb, vb, ksb, vsb = bufs[parity]
                     kblk = kb[...].astype(jnp.float32) * ksb[...]
@@ -172,8 +184,8 @@ def _dma_kernel(len_ref, ptab_ref, slopes_ref, layer_ref, q_ref, kn_ref,
                 # pool pages EXCLUDE the current token: valid cols <
                 # length, query position = length (folded in below)
                 _attend_block(q, kblk, vblk, j * bt, length, length,
-                              slopes, m_ref, l_ref, acc_ref, hb=hb,
-                              alibi=alibi, group=group)
+                              slopes, m_ref, l_ref, acc_ref, scale=scale,
+                              hb=hb, alibi=alibi, group=group)
         return carry
 
     jax.lax.fori_loop(0, nb, body, 0)
@@ -404,7 +416,9 @@ def paged_attention(q, k_pages, v_pages, page_table, lengths, k_new, v_new,
     tuning.record_dispatch(
         KERNEL, structure, key, source, block_k=ppb * page_len,
         head_block=hb, impl="kernel" if use_kernel else "dense",
-        reason=reason, model_shards=tp)
+        reason=reason, model_shards=tp,
+        products=(products_dtype(k_pages.dtype).name if use_kernel
+                  else "float32"))
     if use_kernel:
         run = functools.partial(_paged_dma, scale=scale, page_len=page_len,
                                 ppb=ppb, hb=hb, alibi=alibi)

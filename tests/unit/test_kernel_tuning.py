@@ -120,8 +120,12 @@ class TestCacheLayers:
         art = tuning.load_artifact(tuning.DEFAULTS_PATH)
         assert art["entries"], "committed default table must not be empty"
         for key, e in art["entries"].items():
-            assert key.startswith("flash_attention/"), key
-            assert isinstance(e.get("block_q"), int), (key, e)
+            # a flash entry tiles the queries; a paged-decode entry (one
+            # query token a row) the pooled tokens of a DMA block
+            kernel = key.split("/")[0]
+            assert kernel in ("flash_attention", "paged_attention"), key
+            block = "block_q" if kernel == "flash_attention" else "block_k"
+            assert isinstance(e.get(block), int), (key, e)
 
 
 class TestBwdStructures:
@@ -188,6 +192,29 @@ class TestSweepHarness:
             assert 384 % bq == 0 and 384 % bk == 0
         assert candidate_grid("bwd_monolithic", 256, 256) == [
             (256, None), (128, None)]
+
+    def test_paged_sweep_times_the_rows_that_decode(self):
+        """``lengths`` names the rows that decode (the others are handed
+        length 0, as the server hands them), the query goes in the
+        pool's type, ``calls`` chains calls in one timed program, and
+        the winner's entry keeps every candidate's time."""
+        from benchmarks.kernel_tuning import sweep_paged_attention
+        tuning.clear_last_dispatch()
+        (key, entry), = sweep_paged_attention(
+            4, 2, 16, 16, 4, dtype="bfloat16", lengths=[20, 33], calls=2,
+            trials=1, max_candidates=2, log=lambda *a: None).items()
+        assert key.startswith("paged_attention/page16/sq4_sk64_d16_bfloat16")
+        assert len(entry["swept"]) == 2 and entry["ms"] == min(
+            e["ms"] for e in entry["swept"])
+        rec = tuning.last_dispatch("paged_attention")["page16"]
+        assert rec["products"] == "bfloat16" and rec["impl"] == "kernel"
+        # a row cannot be longer than its table
+        with pytest.raises(ValueError, match="lengths"):
+            sweep_paged_attention(4, 2, 16, 16, 4, lengths=[64], trials=1,
+                                  log=lambda *a: None)
+        with pytest.raises(ValueError, match="lengths"):
+            sweep_paged_attention(2, 2, 16, 16, 4, lengths=[5, 5, 5],
+                                  trials=1, log=lambda *a: None)
 
     @pytest.mark.slow  # fresh-interpreter subprocess (~40s); the sweep
     # plumbing itself is covered in-process above

@@ -383,6 +383,188 @@ class TestZeroRowsBetweenLongRows:
         assert np.abs(got[0, 0] - own[0]).max() > 1e-2
 
 
+def _bf16_values(r, *shape):
+    """float32 numbers that bfloat16 holds exactly: the kernel's cast of
+    a query to a bf16 pool's type then loses nothing, and the output
+    comes back in float32 to be compared to 1e-5."""
+    return jnp.asarray(r.randn(*shape), jnp.bfloat16).astype(jnp.float32)
+
+
+class TestProductsInThePoolsType:
+    """A bf16 pool's blocks go to the products as they lie in VMEM (one
+    pass of the MXU for the scores, the probabilities as bf16 terms for
+    the values), with float32 accumulation: bf16 x bf16 products are
+    exact in float32, so the kernel agrees with float32 arithmetic on
+    the same bf16 values to the tolerance the float32 pools are held to.
+    A float32 pool's products and an int8 pool's dequantised blocks stay
+    float32, their program text the parent's."""
+    BLOCK = 2 * PAGE                     # two pages a DMA block
+    # pooled tokens of rows of 1, 2 and 5 blocks, the last one ragged
+    # (in two of them the block's second page holds no valid column)
+    LENGTHS = {1: 5, 2: 3 * PAGE + 7, 5: 8 * PAGE + 1}
+
+    def _case(self, blocks, seed=31, heads=4, d=16):
+        r = np.random.RandomState(seed)
+        kp, vp = (jnp.asarray(r.randn(24, heads, d, PAGE), jnp.bfloat16)
+                  for _ in range(2))
+        q = _bf16_values(r, 2, 1, heads, d)
+        kn, vn = (_bf16_values(r, 2, heads, d, 1) for _ in range(2))
+        ptab = jnp.asarray([np.arange(1, 11), np.arange(11, 21)], jnp.int32)
+        lens = jnp.asarray([self.LENGTHS[blocks], 0], jnp.int32)
+        return q, kp, vp, ptab, lens, kn, vn
+
+    @pytest.mark.parametrize("alibi", [False, True], ids=["plain", "alibi"])
+    @pytest.mark.parametrize("blocks", [1, 2, 5])
+    def test_bf16_pool_agrees_with_float32_arithmetic(self, blocks, alibi):
+        args = self._case(blocks)
+        kw = ({"alibi_slopes": np.linspace(0.1, 0.5, 4).astype(np.float32)}
+              if alibi else {})
+        tuning.clear_last_dispatch()
+        got = paged_attention(*args, impl="kernel", block_tokens=self.BLOCK,
+                              **kw)
+        rec = tuning.last_dispatch(KERNEL)["page%d" % PAGE]
+        assert rec["products"] == "bfloat16" and rec["block_k"] == self.BLOCK
+        # the dense path widens the same bf16 values and multiplies in
+        # float32: what the kernel's products are, term by term
+        want = paged_attention(*args, impl="dense", **kw)
+        assert got.dtype == jnp.float32
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   atol=1e-5, rtol=1e-5)
+
+    @pytest.mark.parametrize("blocks", [1, 2, 5])
+    def test_garbage_past_the_length_never_reaches_the_output(self, blocks):
+        """The columns of a row's last block past its length hold NaN
+        and +-inf (another request's leavings, the null page): the
+        output is finite and the one the clean pool gives, bit for bit."""
+        q, kp, vp, ptab, lens, kn, vn = self._case(blocks)
+        clean = paged_attention(q, kp, vp, ptab, lens, kn, vn, impl="kernel",
+                                block_tokens=self.BLOCK)
+        page, first = divmod(self.LENGTHS[blocks], PAGE)
+        bad = jnp.asarray(np.resize([np.nan, np.inf, -np.inf], PAGE),
+                          jnp.bfloat16)
+        own = int(ptab[0, page])
+
+        def spoil(x):
+            # the null page, the table's pages behind the row's last, and
+            # that page's own columns from the length on
+            x = x.at[0].set(bad).at[np.asarray(ptab[0, page + 1:])].set(bad)
+            return x.at[own].set(jnp.where(jnp.arange(PAGE) >= first, bad,
+                                           x[own]))
+
+        got = paged_attention(q, spoil(kp), spoil(vp), ptab, lens, kn, vn,
+                              impl="kernel", block_tokens=self.BLOCK)
+        assert np.isfinite(np.asarray(got)).all()
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(clean))
+
+    @pytest.mark.parametrize("dtype", ["f32", "int8"])
+    def test_lfm2_geometry_keeps_float32_products(self, dtype):
+        """32 query heads on 8 K/V heads of 64 (a group of 4, two K/V
+        heads a step), a float32 pool and an int8 one: to 1e-5 of the
+        dense path, and the dispatch says float32."""
+        r = np.random.RandomState(41)
+        kp, vp = (jnp.asarray(r.randn(12, 8, 64, PAGE).astype(np.float32))
+                  for _ in range(2))
+        q = jnp.asarray(r.randn(2, 1, 32, 64).astype(np.float32))
+        kn, vn = (jnp.asarray(r.randn(2, 8, 64, 1).astype(np.float32))
+                  for _ in range(2))
+        ptab = jnp.asarray([[1, 2, 3, 4, 5], [6, 7, 0, 0, 0]], jnp.int32)
+        lens = jnp.asarray([4 * PAGE + 3, PAGE + 1], jnp.int32)
+        scales = {}
+        if dtype == "int8":
+            kp, vp, ks, vs = _quantize_pool(kp, vp)
+            scales = {"k_scale": ks, "v_scale": vs}
+        run = lambda impl: paged_attention(
+            q, kp, vp, ptab, lens, kn, vn, impl=impl,
+            block_tokens=self.BLOCK, **scales)
+        tuning.clear_last_dispatch()
+        got = run("kernel")
+        rec = tuning.last_dispatch(KERNEL)["page%d" % PAGE]
+        assert rec["head_block"] == 2 and rec["products"] == "float32"
+        want = run("dense")
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   atol=1e-5, rtol=1e-5)
+
+    # sha256 of the lowered program (StableHLO, no locations: the
+    # interpreted kernel inlined) at the parent of the PR that moved the
+    # bf16 products, commit 6524da7: a float32 pool's and an int8 pool's
+    # path is that program letter for letter. A change that means to
+    # move them records the new text's hash here.
+    PARENT_TEXT = {
+        "f32": "d00cfe70773aaa2fbde7b7382f43ed65"
+               "53ce7f1523c1e065065deeba57183802",
+        "int8": "47eba3125064c20c06a1f7daf6603de4"
+                "6716f0659ae1c1b45d305d594846f214",
+        "decode_f32": "78ea081c611c40b2ea4de062cda86ac1"
+                      "1d7ed7638a9d8c358d259b74420fd556",
+    }
+
+    @staticmethod
+    def _lowered(which):
+        S = jax.ShapeDtypeStruct
+        f32 = jnp.float32
+        if which == "decode_f32":
+            from deepspeed_tpu.ops.pallas import decode_attention
+            cache = S((2, 4, 32, 256), f32)
+            return jax.jit(lambda *a: decode_attention(*a, block_k=128)) \
+                .lower(S((2, 1, 4, 32), f32), cache, cache,
+                       S((2,), jnp.int32)).as_text()
+        pool = S((12, 8, 64, PAGE), jnp.int8 if which == "int8" else f32)
+        new = S((2, 8, 64, 1), f32)
+        args = [S((2, 1, 32, 64), f32), pool, pool, S((2, 5), jnp.int32),
+                S((2,), jnp.int32), new, new]
+        names = ()
+        if which == "int8":
+            names = ("k_scale", "v_scale")
+            args += [S((12, 8, 1, PAGE), f32)] * 2
+        return jax.jit(lambda *a: paged_attention(
+            *a[:7], impl="kernel", block_tokens=2 * PAGE,
+            **dict(zip(names, a[7:])))).lower(*args).as_text()
+
+    @pytest.mark.parametrize("which", sorted(PARENT_TEXT))
+    def test_float32_paths_lower_to_the_parents_text(self, which):
+        import hashlib
+        text = self._lowered(which)
+        assert "bf16" not in text          # every product still float32
+        assert hashlib.sha256(text.encode()).hexdigest() \
+            == self.PARENT_TEXT[which]
+
+    def test_gpt2_twelve_heads_at_head_block_four(self):
+        args = self._case(5, seed=43, heads=12, d=64)
+        tuning.clear_last_dispatch()
+        got = paged_attention(*args, impl="kernel", block_tokens=self.BLOCK)
+        rec = tuning.last_dispatch(KERNEL)["page%d" % PAGE]
+        assert rec["head_block"] == 4 and rec["products"] == "bfloat16"
+        want = paged_attention(*args, impl="dense")
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   atol=1e-5, rtol=1e-5)
+
+    @pytest.mark.parametrize("dtype", ["bf16", "f32"])
+    def test_decode_attention_at_the_same_dtypes(self, dtype):
+        """The contiguous cache's kernel shares the block update: a bf16
+        cache's products are exact too and its garbage past the length
+        guarded, a float32 cache multiplies in float32."""
+        from deepspeed_tpu.ops.pallas import decode_attention
+        from deepspeed_tpu.ops.pallas.decode_attention import _decode_dense
+        r = np.random.RandomState(47)
+        cdt = jnp.bfloat16 if dtype == "bf16" else jnp.float32
+        k, v = (jnp.asarray(r.randn(3, 4, 32, 640), cdt) for _ in range(2))
+        q = _bf16_values(r, 3, 1, 4, 32)
+        lens = jnp.asarray([130, 640, 0], jnp.int32)
+        past = jnp.arange(640)[None, None, None, :] >= lens[:, None, None,
+                                                            None]
+        spoil = lambda x: jnp.where(past, jnp.asarray(jnp.nan, cdt), x)
+        tuning.clear_last_dispatch()
+        got = decode_attention(q, spoil(k), spoil(v), lens, block_k=128)
+        rec = tuning.last_dispatch("decode_attention")["dma"]
+        assert rec["impl"] == "kernel" and rec["products"] == jnp.dtype(
+            cdt).name
+        want = _decode_dense(q[:, 0], k, v, lens, jnp.zeros((4,)),
+                             scale=32 ** -0.5, alibi=False)
+        assert np.isfinite(np.asarray(got)).all()
+        np.testing.assert_allclose(np.asarray(got[:, 0]), np.asarray(want),
+                                   atol=1e-5, rtol=1e-5)
+
+
 class TestTuningDispatch:
     def test_runtime_table_entry_consumed(self):
         """The shape-keyed tuning cache resolves the kernel's blocks at
@@ -414,6 +596,30 @@ class TestTuningDispatch:
         assert disp["source"] == "constants"
         # blocks clamp to the table: 2 pages * 16 tokens < the 512 default
         assert disp["block_k"] == 2 * PAGE
+
+    def test_the_serving_cells_shape_takes_the_swept_block(self):
+        """32 slots x 2048 tokens, 16 heads of 128 in bf16, pages of 128
+        — the key the GPT-2 1.3B serving cells and OLMoE's share — is in
+        the committed table with the block swept on the v5e; a shape
+        beside it still falls to the constants."""
+        S = jax.ShapeDtypeStruct
+        bf = jnp.bfloat16
+
+        def dispatch(slots):
+            pool = S((4, 16, 128, 128), bf)
+            new = S((slots, 16, 128, 1), bf)
+            tuning.clear_last_dispatch()
+            jax.eval_shape(
+                lambda *a: paged_attention(*a, impl="kernel"),
+                S((slots, 1, 16, 128), bf), pool, pool,
+                S((slots, 16), jnp.int32), S((slots,), jnp.int32), new, new)
+            return tuning.last_dispatch(KERNEL)["page128"]
+
+        rec = dispatch(32)
+        assert rec["source"] == "defaults" and rec["products"] == "bfloat16"
+        assert (rec["block_k"], rec["head_block"]) == (256, 8)
+        rec = dispatch(8)
+        assert rec["source"] == "constants" and rec["block_k"] == 512
 
     def test_kernel_knob_validation(self):
         with pytest.raises(ValueError, match="kernel"):
