@@ -325,6 +325,49 @@ def _admit_alone(srv, prompt, new, page_len, label):
     return handle
 
 
+def _check_iteration_log(srv, prompt, written_before, label):
+    """The server's log of its host loop (``serving/iterations``), on
+    the chip: one more request driven by hand, with a pause before each
+    ``advance()`` so that the dispatch in flight has finished before it
+    is read. The rows' phases must sum to the wall time a clock of this
+    function's own takes from the first call to the last, to 0.1%; a
+    read-back must have found its arrays ready (here) and one not (the
+    run above, where the host runs ahead of the device)."""
+    from deepspeed_tpu.observability.metrics import get_registry
+    from deepspeed_tpu.serving.metrics import (CALLER, COMPILES, EMPTY, GC,
+                                               IN_ADVANCE, READBACK, READY)
+    table = get_registry().table("serving/iterations")
+    first = table.count
+    srv.submit(prompt, max_new_tokens=4)
+    t0 = None
+    while srv.busy:
+        time.sleep(0.05)
+        t0 = t0 or time.perf_counter_ns()
+        srv.advance()
+    wall = time.perf_counter_ns() - t0
+    rows = table.read()[written_before - table.count:]
+    by_hand = rows[first - table.count:]
+    inside = sum(sum(r[IN_ADVANCE]) for r in by_hand)
+    between = sum(r[CALLER] + r[EMPTY] for r in by_hand[1:])
+    _check(abs(inside + between - wall) <= wall / 1000,
+           f"{label}: {len(by_hand)} rows of serving/iterations hold "
+           f"{inside} ns inside advance() and {between} ns between, the "
+           f"loop from its first advance() to its last took {wall} ns")
+    read = [r for r in rows if r[READBACK] > 0]
+    ready = sum(r[READY] for r in read)
+    _check(0 < ready < len(read) and any(r[READY] for r in by_hand),
+           f"{label}: of {len(read)} iterations that read tokens back, "
+           f"{ready} found them ready: both kinds should occur")
+    whole = sum(sum(r[IN_ADVANCE]) + r[CALLER] + r[EMPTY] for r in rows)
+    shares = ", ".join(
+        f"{name} {100.0 * sum(r[at] for r in rows) / whole:.2f}%"
+        for name, at in (("caller", CALLER), ("empty", EMPTY), ("gc", GC)))
+    _say(f"{label}: serving/iterations wrote {len(rows)} rows over "
+         f"{whole / 1e9:.2f}s: {shares}; {ready} of {len(read)} read-backs "
+         f"found their arrays ready; "
+         f"{sum(r[COMPILES] > 0 for r in rows)} rows compiled")
+
+
 def _first_divergence_gap(a, b, row_of):
     """Two token sequences of one request: 0.0 when they agree, else the
     gap between the two candidates' reference logits where they first
@@ -341,16 +384,19 @@ def _first_divergence_gap(a, b, row_of):
 def _serve_and_check(eng, module, params, reqs, num_slots, max_len,
                      page_len, paging_kernel, logit_tol, label,
                      against_generate=True, reference_logits=None,
-                     kernel="paged_attention"):
+                     kernel="paged_attention", iteration_log=False):
     import numpy as np
     import jax
     import jax.numpy as jnp
     from deepspeed_tpu.ops.pallas import tuning
 
+    from deepspeed_tpu.observability.metrics import get_registry
+
     tuning.clear_last_dispatch()
     options = {"num_slots": num_slots, "max_len": max_len,
                "paging": {"page_len": page_len, "kernel": paging_kernel}}
     srv = eng.serve(options)
+    rows_before = get_registry().table("serving/iterations").count
     stream = []
     handles = [srv.submit(p, max_new_tokens=m,
                           on_token=lambda r, tok, _s=stream:
@@ -385,6 +431,10 @@ def _serve_and_check(eng, module, params, reqs, num_slots, max_len,
     _say(f"{label}: {len(handles)} requests over {num_slots} slots finished "
          f"in {wall:.2f}s (compiles included); paged kernel {kern}")
     _check_pool_stays_in_place(srv, label)
+    if iteration_log:
+        # (one request more: a family's phase that reconciles its own
+        # counters with the requests it sent leaves this out)
+        _check_iteration_log(srv, reqs[0][0], rows_before, label)
     srv.close()
     paged = eng.serve(dict(options, paging=dict(options["paging"],
                                                 prefill_chunk=page_len)))
@@ -488,7 +538,8 @@ def phase_serve(preset, num_slots, max_len, page_len, n_requests,
                      model.config.vocab_size, prompt_max, new_max)
     _check(n_requests > num_slots, "serve needs more requests than slots")
     _serve_and_check(eng, model, params, reqs, num_slots, max_len, page_len,
-                     paging_kernel, logit_tol, f"serve {preset}")
+                     paging_kernel, logit_tol, f"serve {preset}",
+                     iteration_log=True)
 
 
 def phase_olmoe(n_layers, num_slots, max_len, page_len, n_requests,

@@ -34,7 +34,7 @@ from .memory import (MemoryAccountant, device_memory_stats,
                      estimate_forward_memory_bytes, format_memory_report,
                      get_accountant, is_oom_error, oom_forensics,
                      tree_bytes, write_oom_forensics)
-from .metrics import (Counter, Gauge, Histogram, MetricsRegistry,
+from .metrics import (Counter, Gauge, Histogram, MetricsRegistry, Table,
                       collective_tally, diff_snapshots,
                       format_snapshot_diff, get_registry)
 from .perf import (CHIP_PEAK_TFLOPS, PerfAccountant, detect_chip,
@@ -43,12 +43,13 @@ from .programs import (ProgramRegistry, TrackedProgram,
                        format_program_table, get_program_registry,
                        track_program)
 from .trace import (DeviceProbe, Tracer, activate, active_tracer,
-                    chrome_trace_events, deactivate, format_summary, span,
+                    begin_span, chrome_trace_events, deactivate, format_summary, span,
                     summarize, summarize_trace_file, write_chrome_trace)
 
 __all__ = [
     "ObservabilityConfig", "MemoryConfig", "ExportConfig", "Observability",
-    "Counter", "Gauge", "Histogram", "MetricsRegistry", "get_registry",
+    "Counter", "Gauge", "Histogram", "Table", "MetricsRegistry",
+    "get_registry",
     "GoodputLedger", "GOODPUT_TAXONOMY", "classify_spans", "format_goodput",
     "get_ledger", "reset_ledger",
     "MetricsScrapeClient", "TelemetryServer", "build_statusz",
@@ -65,7 +66,7 @@ __all__ = [
     "write_oom_forensics",
     "ProgramRegistry", "TrackedProgram", "format_program_table",
     "get_program_registry", "track_program",
-    "DeviceProbe", "Tracer", "activate", "active_tracer",
+    "DeviceProbe", "Tracer", "activate", "active_tracer", "begin_span",
     "chrome_trace_events", "deactivate", "format_summary", "span",
     "summarize", "summarize_trace_file", "write_chrome_trace",
 ]

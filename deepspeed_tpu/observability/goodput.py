@@ -201,6 +201,16 @@ def timed(category: str):
     return ledger.timed(category)
 
 
+def note(category: str, seconds: float):
+    """Seconds a caller timed itself, into the shared ledger when one
+    has been started (``timed()``'s rule): the serving engine's phase
+    clock hands over an iteration's ``compute`` from the stamps it took
+    anyway."""
+    ledger = _LEDGER
+    if ledger is not None and ledger._epoch is not None:
+        ledger.note(category, seconds)
+
+
 def note_compile(seconds: float):
     """Out-of-band compile attribution from ``TrackedProgram`` (dropped
     when no ledger is live — library users without an engine)."""

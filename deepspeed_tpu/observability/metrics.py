@@ -1,4 +1,4 @@
-"""Process metrics registry: counters, gauges, histograms.
+"""Process metrics registry: counters, gauges, histograms, tables.
 
 ONE registry (``get_registry()``) is shared by every telemetry producer
 — engine throughput/perf accounting, ``ServingMetrics`` mirrors, and the
@@ -14,6 +14,7 @@ job, ``ds_tpu_report`` on a login node).
 
 import json
 import time
+from array import array
 from collections import deque
 from typing import Callable, Dict, Optional
 
@@ -87,9 +88,66 @@ class Histogram:
         return out
 
 
+class Table:
+    """Fixed-width ring of integer rows: a log that keeps the most
+    recent ``rows`` records whole, where a histogram keeps one number
+    of each. Preallocated (8 bytes a cell); ``write`` copies one row in
+    and allocates nothing: the row is an ``array('q')`` of the table's
+    width, which the writer keeps and fills again."""
+    __slots__ = ("name", "columns", "rows", "count", "_cells")
+
+    def __init__(self, name: str, columns, rows: int):
+        self.name = name
+        self.columns = tuple(columns)
+        if not self.columns or len(set(self.columns)) != len(self.columns):
+            raise ValueError(f"table {name!r} needs distinct column names, "
+                             f"got {self.columns}")
+        self.rows = max(1, int(rows))
+        self.count = 0                  # rows ever written
+        self._cells = array("q", bytes(8 * self.rows * len(self.columns)))
+
+    def write(self, row):
+        width = len(self.columns)
+        if len(row) != width:
+            raise ValueError(f"table {self.name!r} has {width} columns, "
+                             f"the row has {len(row)}")
+        at = (self.count % self.rows) * width
+        self._cells[at:at + width] = row
+        self.count += 1
+
+    def __len__(self):
+        """Rows retained."""
+        return min(self.count, self.rows)
+
+    def read(self):
+        """The retained rows, oldest first, one tuple each in the order
+        of ``columns``."""
+        width, kept = len(self.columns), len(self)
+        first = self.count - kept
+        out = []
+        for i in range(first, self.count):
+            at = (i % self.rows) * width
+            out.append(tuple(self._cells[at:at + width]))
+        return out
+
+    def summary(self) -> dict:
+        """What a snapshot carries of a table: its shape, how much of
+        what was written it still holds, and the newest row. Constant
+        time, because a scrape runs beside the loop that writes; the
+        rows themselves are ``read()``'s."""
+        out = {"columns": list(self.columns), "capacity": self.rows,
+               "count": self.count, "retained": len(self)}
+        if self.count:
+            width = len(self.columns)
+            at = ((self.count - 1) % self.rows) * width
+            out["last"] = dict(zip(self.columns,
+                                   self._cells[at:at + width]))
+        return out
+
+
 class MetricsRegistry:
-    """Named-instrument registry. ``counter``/``gauge``/``histogram``
-    get-or-create (a name keeps its first kind; a kind clash raises);
+    """Named-instrument registry. ``counter``/``gauge``/``histogram``/
+    ``table`` get-or-create (a name keeps its first kind; a kind clash raises);
     ``register_collector`` attaches a callable polled at snapshot time
     for subsystems that already keep their own state (ServingMetrics)."""
 
@@ -97,13 +155,15 @@ class MetricsRegistry:
         self._counters: Dict[str, Counter] = {}
         self._gauges: Dict[str, Gauge] = {}
         self._hists: Dict[str, Histogram] = {}
+        self._tables: Dict[str, Table] = {}
         self._collectors: Dict[str, Callable[[], dict]] = {}
         self._snapshot_seq = 0
 
     def _check_free(self, name, own):
         for kind, table in (("counter", self._counters),
                             ("gauge", self._gauges),
-                            ("histogram", self._hists)):
+                            ("histogram", self._hists),
+                            ("table", self._tables)):
             if table is not own and name in table:
                 raise ValueError(
                     f"metric {name!r} already registered as a {kind}")
@@ -127,6 +187,23 @@ class MetricsRegistry:
             self._hists[name] = Histogram(name, window)
         return self._hists[name]
 
+    def table(self, name: str, columns=None, rows: int = 0
+              ) -> Optional[Table]:
+        """Get-or-create with ``columns`` and ``rows``; a table that is
+        there keeps its size, and other columns than it has raise. With
+        no ``columns``: the table if some writer made it, else None — a
+        reader never creates one."""
+        found = self._tables.get(name)
+        if found is None and columns is not None:
+            self._check_free(name, self._tables)
+            found = self._tables[name] = Table(name, columns, rows)
+        elif found is not None and columns is not None \
+                and tuple(columns) != found.columns:
+            raise ValueError(
+                f"table {name!r} already registered with columns "
+                f"{found.columns}, not {tuple(columns)}")
+        return found
+
     def register_collector(self, name: str, fn: Callable[[], dict]):
         """``fn()`` returns a flat {metric: value} dict merged into
         snapshots under ``collected.<name>``."""
@@ -147,6 +224,8 @@ class MetricsRegistry:
                        if g.value is not None},
             "histograms": {n: h.summary()
                            for n, h in sorted(self._hists.items())},
+            "tables": {n: t.summary()
+                       for n, t in sorted(self._tables.items())},
         }
         if self._collectors:
             out["collected"] = {n: fn()
@@ -188,6 +267,7 @@ class MetricsRegistry:
         self._counters.clear()
         self._gauges.clear()
         self._hists.clear()
+        self._tables.clear()
         self._collectors.clear()
         self._snapshot_seq = 0
 

@@ -67,6 +67,20 @@ def active_tracer():
     return _TRACER
 
 
+def begin_span(name, args=None):
+    """A span no ``with`` block can hold, because it opens in one call
+    and closes in a later one (the caller's turn between two
+    ``advance()`` calls, a pass of the collector between its two
+    callbacks): opened here, closed by ``end()`` on what this returns,
+    which is None when no tracer is active. Such a span may overlap
+    others on its thread without nesting in them: each is recorded as
+    an interval of its own, in the ring and on the profiler's line."""
+    tracer = _TRACER
+    if tracer is None:
+        return None
+    return tracer.span(name, args).__enter__()
+
+
 def activate(tracer):
     """Route ``span()`` calls to ``tracer`` until ``deactivate()``."""
     global _TRACER
@@ -104,6 +118,10 @@ class _Span:
         self._tracer._record(self._name, self._t0, dur,
                              threading.get_ident(), self._args)
         return False
+
+    def end(self):
+        """Close a span opened by ``begin_span``."""
+        self.__exit__(None, None, None)
 
 
 class Tracer:

@@ -58,7 +58,6 @@ import jax.numpy as jnp
 
 from ..inference.generation import _sampling_mode
 from ..observability.goodput import get_ledger as _goodput_ledger
-from ..observability.goodput import timed as _goodput
 from ..observability.fleet import make_trace_id
 from ..observability.memory import get_accountant, is_oom_error, oom_forensics
 from ..observability.programs import track_program
@@ -70,7 +69,8 @@ from . import qos as qos_mod
 from .qos import QosController
 from .request import PREEMPTED, Request
 from .scheduler import FifoScheduler
-from .metrics import ServingMetrics
+from .metrics import (ADMIT, DECODE_DISPATCH, HARVEST, OTHER,
+                      PREFILL_DISPATCH, ServingMetrics, collector_hook)
 from .paging.config import CHUNK_PAGES, chunk_pages
 from .paging.manager import (CHUNK_PREFILL_STATICS, PagedKVManager,
                              _chunk_prefill_jit, _paged_decode_jit)
@@ -190,7 +190,12 @@ class ServingEngine:
         self._slot_req = [None] * n       # host view of slot -> Request
         self._free = deque(range(n))
         self._pending = deque()           # in-flight readbacks, FIFO
-        self._readback_ns = 0             # this advance()'s blocked reads
+        # the host loop's one clock (serving/metrics.py PhaseClock): it
+        # names every nanosecond from one advance() exit to the next and
+        # writes the row of ``serving/iterations``; the collector's
+        # passes are timed while this engine lives (close() ends that)
+        self._clock = self.metrics.clock
+        collector_hook.watch(self._clock)
         self._chunk_counts = {}           # slot -> router counts of its
                                           # prefill chunks so far (device)
         self._chunk_programs = {}         # chunk tokens -> the program
@@ -241,9 +246,9 @@ class ServingEngine:
         self.last_oom_forensics = None    # latest RESOURCE_EXHAUSTED dump
         self._restart_watchdog()
         self._account_memory()
-        # arm the process goodput ledger (observability/goodput.py):
-        # dispatch/readback sites below classify as compute, the gaps
-        # between engine iterations surface as scheduler_idle
+        # arm the process goodput ledger (observability/goodput.py): the
+        # phase clock hands it each iteration's dispatch, read-back and
+        # harvest time as compute, the rest surfaces as scheduler_idle
         _goodput_ledger().start()
         self.telemetry = None             # live endpoint; start_telemetry()
         log_dist(f"serving engine: {n} slots x {self.config.cache_len} "
@@ -373,6 +378,8 @@ class ServingEngine:
         if self._watchdog is not None:
             self._watchdog.stop()
             self._watchdog = None
+        collector_hook.unwatch(self._clock)
+        self._clock.end_caller_span()
         acct = get_accountant()
         for tag in ("serving/params", "serving/kv_pool", "serving/state"):
             acct.discard(tag)
@@ -567,18 +574,27 @@ class ServingEngine:
         (no-op).
 
         An iteration that had work accounts for its own time in the
-        process registry: ``serving/advance_readback_ms`` (blocked on
-        device->host reads) and ``serving/advance_host_ms`` (the rest)."""
-        t0 = time.perf_counter_ns()
+        process registry, from the phase clock's stamps: a row of
+        ``serving/iterations`` (every phase from the previous exit to
+        this one), ``serving/advance_readback_ms`` (blocked on
+        device->host reads) and ``serving/advance_host_ms`` (the rest).
+        A call on an empty server writes nothing: its time is part of
+        the next row's ``empty``."""
+        clock = self._clock
         had_work = self.busy
-        self._readback_ns = 0
-        with _span("serving/advance", {"iteration": self._iteration}):
-            self._advance()
         if had_work:
-            self.metrics.on_advance(time.perf_counter_ns() - t0,
-                                    self._readback_ns)
+            clock.enter(_active_tracer() is not None)
+        else:
+            clock.end_caller_span()     # its requests were cancelled
+        try:
+            with _span("serving/advance", {"iteration": self._iteration}):
+                self._advance()
+        finally:
+            if had_work:
+                clock.exit(self.busy)
 
     def _advance(self):
+        clock = self._clock
         if self._watchdog_report is not None:
             report, self._watchdog_report = self._watchdog_report, None
             self.recover("hung decode dispatch", kind="watchdog",
@@ -593,8 +609,11 @@ class ServingEngine:
             self._watchdog.step_started()
         try:
             with self._trace_scope():
+                clock.switch(ADMIT)
                 self._admit()
+                clock.switch(PREFILL_DISPATCH)
                 self._run_prefill_chunks()
+                clock.switch(DECODE_DISPATCH)
                 if self.prefill_only:
                     # prefill role: no decode ever dispatches (the decode
                     # replica owns generation past token 1), but the
@@ -605,6 +624,7 @@ class ServingEngine:
                     self._iteration += 1
                 else:
                     dispatched = self._dispatch_decode()
+                clock.switch(OTHER)
             # keep at most pipeline_depth dispatches in flight; drain fully
             # when nothing new was dispatched (tail of the workload)
             target = self.config.pipeline_depth if dispatched else 0
@@ -613,15 +633,13 @@ class ServingEngine:
         finally:
             if self._watchdog is not None:
                 self._watchdog.step_finished()
-        with _span("serving/sample"):
-            busy = sum(r is not None for r in self._slot_req)
-            self.metrics.sample(
-                self.scheduler.depth, busy, self.config.num_slots,
-                self._iteration,
-                paged=self._paged.stats(),
-                qos_level=(self._qos.level
-                           if self._qos is not None else None),
-                slot_cap=self._slot_cap)
+        busy = sum(r is not None for r in self._slot_req)
+        self.metrics.sample(
+            self.scheduler.depth, busy, self.config.num_slots,
+            self._iteration,
+            paged=self._paged.stats(),
+            qos_level=(self._qos.level if self._qos is not None else None),
+            slot_cap=self._slot_cap)
         if self._iteration % self.config.metrics_interval == 0:
             self.metrics.flush()
 
@@ -947,8 +965,7 @@ class ServingEngine:
                        {"slot": slot, "request_id": req.request_id,
                         "trace_id": req.trace_id,
                         "start": start, "tokens": real, "pages": pages,
-                        "last": bool(is_last)}), \
-                    _goodput("compute"):
+                        "last": bool(is_last)}):
                 mgr.pool, self._state, tok, done, counts = program(
                     self.module, self.params, mgr.pool, self._state,
                     mgr.page_table[slot], jnp.asarray(padded),
@@ -999,8 +1016,7 @@ class ServingEngine:
         # active request count on the span: trace captures show how full
         # each decode dispatch ran (the SLO-reconstruction groundwork)
         with _span("serving/decode_iter", {"active_requests": busy,
-                                           "iteration": self._iteration}), \
-                _goodput("compute"):
+                                           "iteration": self._iteration}):
             mgr = self._paged
             mgr.pool, self._state, toks, done, counts = _paged_decode_jit(
                 self.module, self.params, mgr.pool, mgr.page_table,
@@ -1077,8 +1093,7 @@ class ServingEngine:
         rng = self._decode_rng
         with _span("serving/spec_verify",
                    {"active_requests": busy, "iteration": self._iteration,
-                    "proposed_tokens": int(counts.sum())}), \
-                _goodput("compute"):
+                    "proposed_tokens": int(counts.sum())}):
             mgr = self._paged
             mgr.pool, self._state, toks, done = _spec_verify_jit(
                 self.module, self.params, mgr.pool, mgr.page_table,
@@ -1094,21 +1109,30 @@ class ServingEngine:
 
     def _read_back(self, *arrays):
         """The blocking device->host reads of one harvest: the only
-        place an iteration waits for the device. Timed always (summed
-        into this ``advance()``'s ``serving/advance_readback_ms``) and
-        spanned, so ``serving/harvest``'s self time is token emission
-        and the callers' ``on_token`` callbacks."""
-        t0 = time.perf_counter_ns()
+        place an iteration waits for the device. Timed always (the
+        iteration's ``readback`` phase) and spanned, so the ``harvest``
+        phase and ``serving/harvest``'s self time are token emission and
+        the callers' ``on_token`` callbacks. Whether the tokens had
+        arrived before the read is asked first and costs no sync: a long
+        read of ready arrays is the host's, of unready ones the
+        device's or the queue's."""
+        outer = self._clock.begin_readback(arrays[0].is_ready())
         with _span("serving/readback"):
             out = [np.asarray(a) for a in arrays]
-        self._readback_ns += time.perf_counter_ns() - t0
+        self._clock.switch(outer)
         return out
 
     def _harvest_one(self):
         """Read back the oldest in-flight dispatch (blocks only on work
         dispatched >= pipeline_depth iterations ago) and stream its
         tokens/completions to their requests."""
-        entry = self._pending.popleft()
+        outer = self._clock.switch(HARVEST)
+        try:
+            self._harvest(self._pending.popleft())
+        finally:
+            self._clock.switch(outer)
+
+    def _harvest(self, entry):
         harvest_args = {"kind": entry[0],
                         "active_requests": sum(r is not None
                                                for r in self._slot_req)}
@@ -1117,8 +1141,7 @@ class ServingEngine:
             # so the stitched fleet trace joins them to their admit
             harvest_args["request_id"] = entry[2].request_id
             harvest_args["trace_id"] = entry[2].trace_id
-        with _span("serving/harvest", harvest_args), \
-                _goodput("compute"):
+        with _span("serving/harvest", harvest_args):
             if entry[0] == "admit":
                 _, slot, req, tok, done, counts = entry
                 if req.done:     # cancelled between dispatch and readback

@@ -15,13 +15,18 @@ Glossary (docs/serving.md has the full definitions):
 - throughput: generated tokens / wall seconds since the first submit
 """
 
+import gc
 import time
+import weakref
+from array import array
 from collections import deque
 from typing import Optional
 
+from ..observability import goodput
 from ..observability.fleet import FlightRecorder
 from ..observability.metrics import get_registry
 from ..observability.metrics import percentile as _percentile_impl
+from ..observability.trace import begin_span
 
 # sliding window for the percentile histories: a long-lived server must
 # not grow per-request lists (or sort all-time history per snapshot)
@@ -33,6 +38,180 @@ HISTORY_WINDOW = 4096
 # the /statusz breadcrumb trail, capped so a flapping fault can't grow
 # the snapshot without bound
 FAULT_LOG_LIMIT = 32
+
+
+# The host loop's log, ``serving/iterations`` in the process registry:
+# one row an ``advance()`` that had work, nanoseconds on
+# ``time.perf_counter_ns`` unless said. ``caller`` | ``empty`` is the
+# time from the previous such call's exit to this one's entry, by
+# whether requests were in flight or queued at that exit; ``admit`` ..
+# ``other`` partition entry -> exit exactly (``IN_ADVANCE``); ``gc`` is
+# collector time anywhere in the row's interval and overlaps the phase
+# it interrupted. Counts: the rows the decode dispatch advanced, the
+# pages of the prefill chunks, compile events of the program registry,
+# whether every read-back's arrays were ready before the blocking read
+# (1 only if there was one), whether a tracer was active at entry.
+ITERATION_COLUMNS = (
+    "t_entry", "caller", "empty", "admit", "prefill_dispatch",
+    "decode_dispatch", "readback", "harvest", "other", "gc",
+    "rows_decoding", "chunk_pages", "compiles", "ready", "traced")
+(T_ENTRY, CALLER, EMPTY, ADMIT, PREFILL_DISPATCH, DECODE_DISPATCH, READBACK,
+ HARVEST, OTHER, GC, ROWS_DECODING, CHUNK_PAGES, COMPILES, READY,
+ TRACED) = range(len(ITERATION_COLUMNS))
+IN_ADVANCE = slice(ADMIT, OTHER + 1)
+# over 70 s of the fastest cell's iterations (4.6 ms each): 1.97 MB
+ITERATION_ROWS = 16_384
+# an iteration whose host time (entry -> exit, less a read-back that
+# waited for arrays not yet ready) passes this is a stall: the newest few
+# are kept with their phase for /statusz; the rows have them all
+HOST_STALL_NS = 50_000_000
+HOST_STALL_LOG = 32
+
+_now = time.perf_counter_ns
+
+
+class PhaseClock:
+    """The one clock of ``ServingEngine.advance()``: a cursor ``t`` and
+    the column time is accruing to. Each boundary reads the clock once
+    (``switch``) and adds the time since the last read to the phase
+    that ends there, so the phases of a row sum to its wall time with
+    nothing between them; the same stamps feed the two ``advance_*``
+    histograms, the goodput ledger's ``compute`` and the stall log
+    (``exit``). Always on. Outside ``advance()`` the cursor rests in
+    ``caller`` or ``empty`` and ``switch`` leaves it there: a harvest
+    the caller asks for between two iterations (``set_slot_cap``) is
+    the caller's time."""
+
+    def __init__(self, metrics):
+        self._metrics = metrics
+        reg = metrics.registry
+        self.table = None if reg is None else reg.table(
+            "serving/iterations", ITERATION_COLUMNS, ITERATION_ROWS)
+        # (the program registry counts in the process registry, always)
+        self._compiles = get_registry().counter("programs/compiles_total")
+        self._blank = array("q", [0] * len(ITERATION_COLUMNS))
+        self.row = array("q", self._blank)
+        self.phase = EMPTY
+        self.inside = False
+        self.gc_ns = 0              # added to by the collector's hook
+        self._compiles_at_entry = 0
+        self._ready = None          # no read-back yet | all ready so far
+        self._caller_span = None
+        self.t = _now()
+
+    def enter(self, traced: bool):
+        """``advance()`` entry, with work: the caller's turn ends."""
+        self.end_caller_span()
+        now = _now()
+        row = self.row
+        row[self.phase] += now - self.t
+        row[T_ENTRY] = now
+        row[TRACED] = traced
+        self.t = now
+        self.phase = OTHER
+        self.inside = True
+        self._compiles_at_entry = self._compiles.value
+
+    def switch(self, phase: int) -> int:
+        """The phase that was running ends here and ``phase`` begins;
+        returns the one that ended, for a nested phase to hand back."""
+        prev = self.phase
+        if self.inside:
+            now = _now()
+            self.row[prev] += now - self.t
+            self.t = now
+            self.phase = phase
+        return prev
+
+    def begin_readback(self, ready: bool) -> int:
+        """``switch(READBACK)``, noting whether the arrays about to be
+        read had already answered ``is_ready()``."""
+        if self.inside:
+            self._ready = ready and self._ready is not False
+        return self.switch(READBACK)
+
+    def exit(self, busy: bool):
+        """``advance()`` exit: the row is written and every account that
+        is kept from these stamps is fed. ``busy``: requests are in
+        flight or queued, so the time to the next entry is the caller's
+        and, while a tracer is active, spanned ``serving/caller``."""
+        now = _now()
+        row = self.row
+        row[self.phase] += now - self.t
+        self.t = now
+        self.inside = False
+        row[GC], self.gc_ns = self.gc_ns, 0
+        row[COMPILES] = self._compiles.value - self._compiles_at_entry
+        ready = row[READY] = int(bool(self._ready))
+        self._ready = None
+        readback = row[READBACK]
+        compute = (row[PREFILL_DISPATCH] + row[DECODE_DISPATCH] + readback
+                   + row[HARVEST])
+        total = compute + row[ADMIT] + row[OTHER]
+        self._metrics.on_advance(total, readback)
+        goodput.note("compute", compute / 1e9)
+        if total - (0 if ready else readback) > HOST_STALL_NS:
+            self._metrics.on_host_stall(row)
+        if self.table is not None:
+            self.table.write(row)
+        row[:] = self._blank
+        self.phase = CALLER if busy else EMPTY
+        if busy:
+            self._caller_span = begin_span("serving/caller")
+
+    def end_caller_span(self):
+        span, self._caller_span = self._caller_span, None
+        if span is not None:
+            span.end()
+
+
+class _CollectorHook:
+    """Python's collector, timed while any engine's clock watches: one
+    entry of ``gc.callbacks`` for the process, so a pass is stamped once
+    whatever the number of engines — into each watching clock's ``gc``
+    column, the histogram ``serving/gc_pause_ms``, a counter per
+    generation and, while a tracer is active, a ``serving/gc`` span from
+    the pass's start to its stop, inside whatever phase it interrupted.
+    It runs only when the collector does. Clocks are held weakly: when
+    the last one has gone, closed or not, the hook takes itself out."""
+
+    def __init__(self):
+        self.clocks = weakref.WeakSet()
+        self._t0 = 0
+        self._span = None
+
+    def __call__(self, phase, info):
+        if phase == "start":
+            self._span = begin_span("serving/gc",
+                                    {"generation": info["generation"]})
+            self._t0 = _now()
+            return
+        took = _now() - self._t0
+        span, self._span = self._span, None
+        if span is not None:
+            span.end()
+        clocks = list(self.clocks)
+        if not clocks:              # every engine went without close()
+            gc.callbacks.remove(self)
+            return
+        for clock in clocks:
+            clock.gc_ns += took
+        reg = get_registry()
+        reg.histogram("serving/gc_pause_ms").observe(took / 1e6)
+        reg.counter(f"serving/gc_collections/gen{info['generation']}").inc()
+
+    def watch(self, clock: PhaseClock):
+        self.clocks.add(clock)
+        if self not in gc.callbacks:
+            gc.callbacks.append(self)
+
+    def unwatch(self, clock: PhaseClock):
+        self.clocks.discard(clock)
+        if not self.clocks and self in gc.callbacks:
+            gc.callbacks.remove(self)
+
+
+collector_hook = _CollectorHook()
 
 
 def _percentile(values, q):
@@ -69,6 +248,7 @@ class ServingMetrics:
                 m = ref()
                 return m.snapshot() if m is not None else {}
             self.registry.register_collector("serving", _collect)
+        self.clock = PhaseClock(self)
 
     def reset(self):
         self.flight.clear()
@@ -132,6 +312,8 @@ class ServingMetrics:
         self.samples = 0
         self.started_at: Optional[float] = None
         self._events = []
+        # the newest iterations past HOST_STALL_NS
+        self.host_stall_log = deque(maxlen=HOST_STALL_LOG)
 
     # -- per-class accounting ----------------------------------------------
     def _cls(self, request) -> Optional[dict]:
@@ -218,6 +400,7 @@ class ServingMetrics:
         server chose."""
         self.prefill_chunks += 1
         self.prefill_chunk_pages += pages
+        self.clock.row[CHUNK_PAGES] += pages
         self.prefill_tokens_computed += tokens_computed
         if self.registry is not None:
             self.registry.counter("serving/prefill_tokens_computed").inc(
@@ -235,6 +418,7 @@ class ServingMetrics:
         busy). The registry pair sums to the batch occupancy."""
         self.decode_iterations += 1
         self.wasted_slot_steps += num_slots - busy_slots
+        self.clock.row[ROWS_DECODING] = busy_slots
         if self.registry is not None:
             self.registry.counter("serving/decode_slots_busy").inc(busy_slots)
             self.registry.counter("serving/decode_slots_offered").inc(
@@ -298,12 +482,26 @@ class ServingMetrics:
 
     def on_advance(self, total_ns: int, readback_ns: int):
         """One ``advance()`` that had work: the part of it spent blocked
-        on device->host reads, and the rest (the host's own time)."""
+        on device->host reads, and the rest (the host's own time). The
+        phase clock's sums: ``serving/iterations`` has the split."""
         if self.registry is not None:
             self.registry.histogram("serving/advance_readback_ms").observe(
                 readback_ns / 1e6)
             self.registry.histogram("serving/advance_host_ms").observe(
                 (total_ns - readback_ns) / 1e6)
+
+    def on_host_stall(self, row):
+        """One iteration past ``HOST_STALL_NS`` on the host's side (a
+        row of ``serving/iterations``, before it is written): the newest
+        are kept with when they began, how long their phases took and
+        which took longest — what an operator asks first."""
+        phases = row[IN_ADVANCE]
+        longest = max(range(len(phases)), key=phases.__getitem__)
+        self.host_stall_log.append({
+            "t_entry_ns": row[T_ENTRY], "ms": sum(phases) / 1e6,
+            "phase": ITERATION_COLUMNS[ADMIT + longest],
+            "phase_ms": phases[longest] / 1e6, "gc_ms": row[GC] / 1e6,
+            "readback_ready": bool(row[READY])})
 
     def on_token(self, n: int = 1):
         """``n`` EMITTED tokens streamed to requests. With speculation
@@ -598,6 +796,8 @@ class ServingMetrics:
         if self.shed_by_reason:
             for reason, n in sorted(self.shed_by_reason.items()):
                 out[f"shed/{reason}"] = n
+        if self.host_stall_log:
+            out["host_stall_log"] = list(self.host_stall_log)
         if self.faults:
             # breadcrumb list (capped): /statusz and the BENCH artifact
             # show WHAT fired, not just that a counter moved

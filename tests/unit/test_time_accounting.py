@@ -26,6 +26,9 @@ HISTOGRAMS = ("serving/queue_wait_ms", "serving/prefill_wait_ms",
               "serving/advance_readback_ms", "serving/advance_host_ms",
               "train/host_to_dispatch_ms")
 RETROACTIVE = ("serving/queue_wait", "serving/decode_residency")
+# open from one call into a later one, so they nest in no advance()
+# (tests/unit/test_iteration_log.py holds them to where they do lie)
+BETWEEN_CALLS = ("serving/caller", "serving/gc")
 SLOTS = 2
 
 
@@ -148,10 +151,11 @@ def test_server_accounts_for_its_time_and_the_counts_reconcile(traced):
     advances = by_name["serving/advance"]
     assert len(advances) == worked + 1          # the idle one is spanned
     live = [e for e in events if e[0].startswith("serving/")
-            and e[0] != "serving/advance" and e[0] not in RETROACTIVE]
+            and e[0] != "serving/advance"
+            and e[0] not in RETROACTIVE + BETWEEN_CALLS]
     assert {"serving/admission", "serving/prefill_chunk",
             "serving/decode_iter", "serving/harvest", "serving/readback",
-            "serving/sample", "serving/page_table_copy"} <= \
+            "serving/page_table_copy"} <= \
         {e[0] for e in live}
     outside = [e[0] for e in live if not _inside(e, advances)]
     assert not outside, outside
@@ -204,8 +208,8 @@ def test_trainer_times_entry_to_dispatch_and_brackets_the_step(traced):
 def test_with_no_tracer_the_new_sites_allocate_no_span(monkeypatch):
     """Tracing off: ``advance()`` and ``train_batch()`` get the shared
     no-op from every ``span()`` call, never a span object, and a whole
-    idle ``advance()`` with its three new sites and two clock reads
-    stays within the disabled-path budget."""
+    idle ``advance()`` with its two sites and no clock read stays within
+    the disabled-path budget."""
     assert active_tracer() is None
     made = []
     real = trace_mod._Span.__init__
@@ -230,5 +234,4 @@ def test_with_no_tracer_the_new_sites_allocate_no_span(monkeypatch):
     assert per_call < 200e-6, f"{per_call * 1e6:.1f}us per idle advance()"
     activate(Tracer())
     eng.advance()
-    assert [a[1] for a in made] == ["serving/advance", "serving/admission",
-                                    "serving/sample"]
+    assert [a[1] for a in made] == ["serving/advance", "serving/admission"]
