@@ -219,6 +219,126 @@ def sweep_paged_attention(slots, heads, head_dim, page_len, max_pages,
     return {key: {**min(swept, key=lambda e: e["ms"]), "swept": swept}}
 
 
+# The grouped expert matmuls of the three expert cells (benchmarks/chip/
+# configs): token-expert pairs a call, bf16 terms a pair (a float32 row
+# goes as three), experts a layer, expert layers in the stack, and the
+# [k, n] of the gate/up and of the down projection (float32 out but for
+# OLMoE's gate/up). Decode calls first, then the chunk programs' calls.
+GROUPED_SHAPES = {
+    "olmoe-decode": (256, 1, 64, 8, 2048, 1024),
+    "lfm2-decode": (128, 3, 64, 8, 2048, 1536),
+    "kanana-decode": (192, 3, 128, 5, 2048, 768),
+    "lfm2-chunk1": (512, 3, 64, 8, 2048, 1536),
+    "lfm2-chunk4": (2048, 3, 64, 8, 2048, 1536),
+    "kanana-chunk1": (768, 3, 128, 5, 2048, 768),
+    "kanana-chunk4": (3072, 3, 128, 5, 2048, 768),
+    "olmoe-chunk4": (4096, 1, 64, 8, 2048, 1024),
+}
+HBM_BYTES_PER_S = 819e9        # v5e (benchmarks/chip/peaks.json)
+
+
+def drawn_groups(pairs, terms, experts, layers, seed=0, sigma=0.5):
+    """Sizes ``[layers * experts]`` as a router gives them: ``pairs``
+    drawn over one layer's experts with lognormal weights (the largest
+    group two to five times the mean, some experts without a row at
+    decode sizes), ``terms`` rows a pair; the other layers' groups
+    empty."""
+    import numpy as np
+    rng = np.random.default_rng(seed)
+    p = rng.lognormal(0.0, sigma, experts)
+    sizes = rng.multinomial(pairs, p / p.sum()) * terms
+    groups = np.zeros(layers * experts, np.int32)
+    layer = layers // 2
+    groups[layer * experts:(layer + 1) * experts] = sizes
+    return groups
+
+
+def sweep_grouped_matmul(pairs, terms, experts, layers, k, n, down=False,
+                         out_dtype="float32", calls=16, trials=3, warmup=1,
+                         candidates=None, log=print):
+    """Time the grouped expert matmul alone at one call's shape — XLA's
+    arm (``jax.lax.ragged_dot`` in calls of 128 rows, as
+    ``moe/sharded_moe.py _ragged_matmul`` makes them) and the Pallas
+    kernel at each ``(block_m, block_n)`` of ``candidates`` — ``calls``
+    matmuls chained in one program (each one's result decides, in a way
+    that never changes them, the next one's sizes), the time per call
+    and the share of it that the call's bytes would take at the HBM's
+    peak: the weights of the groups with rows once, plus rows in and
+    out. ``down``: the down projection's ``[n, k]`` matrices. Returns
+    {key: entry} in the tuning-artifact format, the winner's entry with
+    every arm's time under ``swept``."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from deepspeed_tpu.moe.sharded_moe import _ragged_matmul
+    from deepspeed_tpu.ops.pallas import grouped_matmul as gm
+    from deepspeed_tpu.ops.pallas import tuning
+
+    if down:
+        k, n = n, k
+    m, n_groups = pairs * terms, layers * experts
+    out = jnp.dtype(out_dtype)
+    sizes = drawn_groups(pairs, terms, experts, layers)
+    touched = int((sizes > 0).sum())
+    least = (touched * k * n * 2 + m * k * 2 + m * n * out.itemsize) \
+        / HBM_BYTES_PER_S * 1e6
+    ks = jax.random.split(jax.random.PRNGKey(0), 2)
+    rows = jax.random.normal(ks[0], (m, k), jnp.bfloat16)
+    w = jax.random.normal(ks[1], (n_groups, k, n), jnp.bfloat16) * 0.02
+    groups = jnp.asarray(sizes)
+
+    def chained(matmul):
+        # rows and weights are arguments: closed over, gigabytes of
+        # weights would be constants of the program
+        def run(rows, w, g):
+            def body(_, carry):
+                g, _ = carry
+                y = matmul(rows, w, g)
+                # never true: the next call waits for this one all the same
+                return g + (y[0, 0] > 1e30).astype(g.dtype), y
+            return jax.lax.fori_loop(
+                0, calls, body, (g, jnp.zeros((m, n), out)))[1]
+        return jax.jit(run)
+
+    xla = chained(lambda r, x, g: _ragged_matmul(
+        r, x, g, preferred_element_type=out))
+    kernel = chained(lambda r, x, g: gm.grouped_matmul(r, x, g, out))
+    args = (rows, w, groups)
+    want = np.asarray(xla(*args), np.float32)
+    us = _time_it(xla, args, trials, warmup) / calls * 1e3
+    log(f"grouped_matmul {m} rows ({pairs} pairs x {terms}) x "
+        f"[{n_groups}, {k}, {n}] -> {out.name}: {touched} groups with rows, "
+        f"largest {sizes.max() / (m / experts):.2f} x the mean; the bytes' "
+        f"time {least:.1f} us")
+    log(f"  xla ragged_dot: {us:.1f} us a call, {100 * least / us:.1f}%")
+    swept = [{"impl": "ragged_dot", "us": round(us, 2)}]
+    key = gm.blocks(m, k, n, n_groups, rows.dtype, out)[2]
+    if candidates is None:
+        # (half of n as block_n read the same or worse at every row
+        # tile: my chip run, PR 42)
+        heights = (32, 64, 128) if m <= 1024 else (128, 256, 512)
+        candidates = [(bm, n) for bm in heights if bm <= m]
+    for bm, bn in candidates:
+        entry = {"block_m": bm, "block_n": bn}
+        with tuning.tuning_table({key: entry}):
+            jax.clear_caches()   # force a re-trace with the candidate
+            try:
+                got = np.asarray(kernel(*args), np.float32)
+                us = _time_it(kernel, args, trials, warmup) / calls * 1e3
+            except Exception as e:  # infeasible tiling = skip, not fail
+                log(f"  bm={bm} bn={bn}: infeasible ({str(e)[:200]})")
+                continue
+        err = float(np.abs(got - want).max())
+        log(f"  bm={bm} bn={bn}: {us:.1f} us a call, "
+            f"{100 * least / us:.1f}%; largest difference from xla's "
+            f"{err:.3g}")
+        swept.append({**entry, "us": round(us, 2), "max_diff": err})
+    jax.clear_caches()
+    best = min(swept[1:] or swept, key=lambda e: e["us"])
+    return {key: {**best, "ms": round(best["us"] / 1e3, 5),
+                  "bytes_us": round(least, 2), "swept": swept}}
+
+
 def _int_list(text):
     return [int(x) for x in str(text).split(",") if x]
 
@@ -241,7 +361,8 @@ def main(argv=None):
     p.add_argument("--max-candidates", type=int, default=None,
                    help="cap the per-structure candidate grid (CI smoke)")
     p.add_argument("--kernel", choices=["flash_attention",
-                                        "paged_attention", "all"],
+                                        "paged_attention",
+                                        "grouped_matmul", "all"],
                    default="flash_attention",
                    help="which kernel family to sweep; paged_attention "
                         "sweeps the serving decode kernel over the "
@@ -260,6 +381,9 @@ def main(argv=None):
     p.add_argument("--calls", type=int, default=1,
                    help="paged sweep: kernel calls chained in one timed "
                         "program (the time is per call)")
+    p.add_argument("--shapes", default=",".join(GROUPED_SHAPES),
+                   help="grouped_matmul sweep: which of "
+                        f"{', '.join(GROUPED_SHAPES)}")
     p.add_argument("--out", default="benchmarks/results/flash_tuning.json")
     args = p.parse_args(argv)
 
@@ -289,6 +413,17 @@ def main(argv=None):
                         lengths=args.lengths, calls=args.calls,
                         trials=args.trials, warmup=args.warmup,
                         max_candidates=args.max_candidates))
+    if args.kernel == "grouped_matmul":
+        # (not under "all": its stacks of weights are gigabytes)
+        for name in args.shapes.split(","):
+            pairs, terms, experts, layers, k, n = GROUPED_SHAPES[name]
+            for down in (False, True):
+                bf16_out = name.startswith("olmoe") and not down
+                entries.update(sweep_grouped_matmul(
+                    pairs, terms, experts, layers, k, n, down=down,
+                    out_dtype="bfloat16" if bf16_out else "float32",
+                    calls=args.calls, trials=args.trials,
+                    warmup=args.warmup))
     device = jax.devices()[0].device_kind if on_tpu() else "cpu-interpret"
     tuning.save_artifact(
         args.out, entries, device=device,

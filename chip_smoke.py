@@ -12,7 +12,8 @@ width of gpt2-125m with seeded weights and seeded tokens (no network):
 - olmoe:   the same serving path with OLMoE-1B-7B's block at its
            published widths, two layers deep: a dropless top-8 router
            over 64 SwiGLU experts, RMSNorm, QK-norm and RoPE on the paged
-           path, the grouped expert matmul compiled by Mosaic;
+           path, the grouped expert matmul one Pallas call a matmul
+           (``ops/pallas/grouped_matmul.py``) compiled by Mosaic;
 - lfm2:    the same serving path with LFM2-24B-A2B's first four layers
            at published widths (conv, conv, attention, conv; two dense
            and two expert layers): the gated short convolution's state
@@ -117,6 +118,27 @@ def _mosaic(rec, what):
            f"{what}: ran in interpret mode ({rec})")
     _check(rec.get("impl", "kernel") == "kernel",
            f"{what}: dispatched {rec.get('impl')!r}, not the kernel ({rec})")
+
+
+def _grouped_matmul_traced():
+    from deepspeed_tpu.observability.metrics import get_registry
+    return get_registry().counter("moe/grouped_matmul_traced/kernel").value
+
+
+def _grouped_matmul_engaged(label, n_groups, traced_before):
+    """The expert layers' grouped matmul went to the Pallas kernel
+    (``ops/pallas/grouped_matmul.py``), compiled and not interpreted:
+    the dispatch record of a stack of ``n_groups`` matrices, and the
+    per-compile counter against its reading before the phase."""
+    from deepspeed_tpu.ops.pallas import tuning
+    rec = tuning.last_dispatch("grouped_matmul").get(f"groups{n_groups}")
+    _mosaic(rec, f"{label} grouped matmul")
+    _check(rec.get("block_m") and rec.get("block_n"),
+           f"{label} grouped matmul: the record names no tiles ({rec})")
+    traced = _grouped_matmul_traced() - traced_before
+    _check(traced > 0, f"{label}: moe/grouped_matmul_traced/kernel did not "
+                       "move: no program traced the kernel")
+    _say(f"{label}: grouped matmul {rec}, traced {traced} times")
 
 
 def _close(name, got, want, tol):
@@ -565,16 +587,19 @@ def phase_olmoe(n_layers, num_slots, max_len, page_len, n_requests,
                 for name in ("assignments", "expert_calls",
                              "experts_touched", "experts_offered")}
     before = {name: c.value for name, c in counters.items()}
+    traced = _grouped_matmul_traced()
     reqs = _serve_and_check(eng, model, params, reqs, num_slots, max_len,
                             page_len, paging_kernel, logit_tol,
                             "serve olmoe")
+    _grouped_matmul_engaged("serve olmoe",
+                            n_layers * model.config.num_experts, traced)
     decode = get_program_registry().get("serving/paged_decode")
     args, kwargs = decode._last_avals
     hlo = decode.lower(*args, **kwargs).compile().as_text()
     _check("ragged-dot" in hlo and "tpu_custom_call" in hlo,
            "serve olmoe: the compiled decode program holds no Mosaic "
            "ragged-dot call: the grouped expert matmul did not lower to "
-           "XLA's kernel")
+           "a kernel of that family")
     moved = {name: c.value - before[name] for name, c in counters.items()}
     _say(f"serve olmoe: router counted {moved}")
     k, experts = model.config.num_experts_per_tok, model.config.num_experts
@@ -623,12 +648,13 @@ def phase_lfm2(n_layers, num_slots, max_len, page_len, n_requests,
              "serving/state_snapshots_stored", "moe/expert_calls")
     counters = {name: get_registry().counter(name) for name in names}
     before = {name: c.value for name, c in counters.items()}
+    traced = _grouped_matmul_traced()
     # ragged generate() refuses such a model (a row's padding would write
     # state): the served tokens are held to the float32 reference alone —
     # the family's plain one, every expert computed by XLA's own products
     # at full precision: the module itself with float32 weights is no
-    # reference on the chip, where the grouped matmul's kernel multiplies
-    # float32 operands in one bf16 pass (0.86 of a logit under the best)
+    # reference on the chip, where the grouped matmul multiplies float32
+    # by float32 in one bf16 pass (0.86 of a logit under the best)
     from benchmarks.chip.families import lfm2 as family
     cfg = model.config
     sizes = {k: getattr(cfg, k) for k in family.SIZE_KEYS}
@@ -647,6 +673,9 @@ def phase_lfm2(n_layers, num_slots, max_len, page_len, n_requests,
                               page_len, paging_kernel, logit_tol,
                               "serve lfm2", against_generate=False,
                               reference_logits=reference_logits)
+    _grouped_matmul_engaged(
+        "serve lfm2", model.config.num_moe_layers * model.config.num_experts,
+        traced)
     moved = {name: c.value - before[name] for name, c in counters.items()}
     _say(f"serve lfm2: counted {moved}")
     _check(moved["serving/prefill_tokens_reused"] >= page_len
@@ -700,6 +729,7 @@ def phase_kanana(n_layers, num_slots, max_len, page_len, n_requests,
              "moe/expert_calls", "moe/assignments", "moe/shared_expert_rows")
     counters = {name: get_registry().counter(name) for name in names}
     before = {name: c.value for name, c in counters.items()}
+    traced = _grouped_matmul_traced()
     # generate() refuses a latent cache: the served tokens are held to the
     # family's plain float32 reference alone
     from benchmarks.chip.families import deepseek_v3 as family
@@ -724,6 +754,8 @@ def phase_kanana(n_layers, num_slots, max_len, page_len, n_requests,
                      kernel="latent_attention")
     _check(not tuning.last_dispatch("paged_attention"),
            "serve kanana: the K/V kernel was dispatched over a latent pool")
+    _grouped_matmul_engaged(
+        "serve kanana", cfg.num_moe_layers * cfg.n_routed_experts, traced)
     moved = {name: c.value - before[name] for name, c in counters.items()}
     _say(f"serve kanana: counted {moved}")
     _check(moved["serving/prefill_tokens_reused"] >= page_len,

@@ -11,6 +11,7 @@ same all-to-all the reference issues by hand over its expert process group
 (created in deepspeed/utils/groups.py:107). Gating math is kept identical.
 """
 
+import functools
 import math
 from typing import Optional
 
@@ -20,7 +21,10 @@ import flax.linen as nn
 
 from deepspeed_tpu.models.layers import QDense, exact_weights, split_terms
 
-from ..comm.mesh import get_global_mesh
+from ..comm.mesh import get_global_mesh, peek_global_mesh
+from ..observability.metrics import get_registry
+from ..ops.pallas import grouped_matmul as pallas_grouped
+from ..ops.pallas._common import in_manual_region, on_tpu
 
 
 def _expert_constraint(x, spec_axes):
@@ -274,41 +278,28 @@ def load_balancing_loss(gate_mean, counts, k: int):
     return jnp.sum(gate_mean * share) * n_experts
 
 
-# Rows a grouped-matmul call takes. XLA's kernel for ``ragged_dot`` makes
-# its row tile as tall as the call has rows (up to 512) and multiplies a
-# whole tile for every group that has a row in it: at 256 rows over 64
-# experts that is 63 tiles of 256 rows for 256 rows of work, and the MXU,
-# not the weights' stream, sets the time. Shorter calls waste less and
-# launch more: on a v5e at OLMoE's widths one matmul of 256 rows took
-# 0.64 ms whole, 0.49 in calls of 128 rows, 0.52 of 64, 0.60 of 32 (the
-# weights' stream alone: 0.32; my chip run, PR 28: PERF.md section 6).
-# No more than MAX_CALLS calls a matmul, whatever the rows: a program's
-# size, and the time to trace it, grow with the calls (the page pool's
-# shape-only init traces the model over every token the pool holds).
+# Rows a ``jax.lax.ragged_dot`` call takes, where the grouped matmul is
+# that (``_ragged_matmul``: off the TPU, under differentiation, over more
+# than one device, for operands the Pallas kernel refuses). XLA's kernel
+# for ``ragged_dot`` makes its row tile as tall as the call has rows (up
+# to 512) and multiplies a whole tile for every group that has a row in
+# it: at 256 rows over 64 experts that is 63 tiles of 256 rows for 256
+# rows of work, and the MXU, not the weights' stream, sets the time.
+# Shorter calls waste less and launch more: on a v5e at OLMoE's widths one
+# matmul of 256 rows took 0.64 ms whole, 0.49 in calls of 128 rows, 0.52
+# of 64, 0.60 of 32 (the weights' stream alone: 0.32; my chip run, PR 28:
+# PERF.md section 6). No more than MAX_CALLS calls a matmul, whatever the
+# rows: a program's size, and the time to trace it, grow with the calls
+# (the page pool's shape-only init traces the model over every token the
+# pool holds).
 ROW_TILE = 128
 MAX_CALLS = 8
 
 
-def grouped_matmul(rows, w, groups, **kw):
-    """``rows [m, k]``, sorted by group, times ``w [G, k, n]``: row i by
-    its group's matrix; ``groups [G]`` are the groups' sizes, rows past
-    their sum are in none. ``jax.lax.ragged_dot`` over ``ROW_TILE`` rows
-    at a time (more where that would take over ``MAX_CALLS`` calls),
-    each call with the sizes of the groups' parts that lie in its
-    rows."""
-    if exact_weights(rows, w):
-        # float32 rows over weights kept in bfloat16 (models/layers.py
-        # dot_exact_weights): each row goes as its three bfloat16 terms,
-        # side by side in its group, and the three products are summed —
-        # the stack of weights is never cast, and is read once
-        m, n = rows.shape[0], 3
-        terms = split_terms(rows, n).transpose(1, 0, 2).reshape(m * n, -1)
-        # (bfloat16 terms have no lower passes to make: a caller's
-        # default of HIGHEST would only be refused by the kernel)
-        out = grouped_matmul(terms, w, groups * n,
-                             preferred_element_type=jnp.float32,
-                             precision=jax.lax.Precision.DEFAULT)
-        return jnp.sum(out.reshape(m, n, -1), axis=1)
+def _ragged_matmul(rows, w, groups, **kw):
+    """``jax.lax.ragged_dot`` over ``ROW_TILE`` rows at a time (more
+    where that would take over ``MAX_CALLS`` calls), each call with the
+    sizes of the groups' parts that lie in its rows."""
     m = rows.shape[0]
     tile = max(ROW_TILE, -(-m // MAX_CALLS))
     if m <= tile:
@@ -322,6 +313,71 @@ def grouped_matmul(rows, w, groups, **kw):
                           0, None)
         parts.append(jax.lax.ragged_dot(rows[lo:hi], w, inside, **kw))
     return jnp.concatenate(parts)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _kernel_matmul(rows, w, groups, out_dtype):
+    """The Pallas kernel forward; its gradient is ``_ragged_matmul``'s
+    (``ragged_dot``'s own rule: no cell trains through the kernel)."""
+    return pallas_grouped.grouped_matmul(rows, w, groups, out_dtype)
+
+
+def _kernel_matmul_fwd(rows, w, groups, out_dtype):
+    return _kernel_matmul(rows, w, groups, out_dtype), (rows, w, groups)
+
+
+def _kernel_matmul_bwd(out_dtype, saved, ct):
+    rows, w, groups = saved
+    _, pull = jax.vjp(lambda r, x: _ragged_matmul(
+        r, x, groups, preferred_element_type=out_dtype), rows, w)
+    return (*pull(ct), None)
+
+
+_kernel_matmul.defvjp(_kernel_matmul_fwd, _kernel_matmul_bwd)
+
+
+def _kernel_refusal(rows, w, preferred_element_type):
+    """Why this call goes to ``jax.lax.ragged_dot`` and not to the Pallas
+    kernel, or None: the platform and the devices first, then the
+    kernel's own word on the shapes."""
+    if not on_tpu():
+        return "not on a TPU"
+    mesh = peek_global_mesh()
+    if mesh is not None and mesh.size > 1 and not in_manual_region():
+        return (f"a program over {mesh.size} devices: a Mosaic call is "
+                "not partitioned")
+    return pallas_grouped.refusal(rows, w, preferred_element_type)
+
+
+def grouped_matmul(rows, w, groups, **kw):
+    """``rows [m, k]``, sorted by group, times ``w [G, k, n]``: row i by
+    its group's matrix; ``groups [G]`` are the groups' sizes, rows past
+    their sum are in none. On one TPU a Pallas call a matmul
+    (``ops/pallas/grouped_matmul.py``: the weights of the groups that
+    hold rows in one pipelined walk), elsewhere ``jax.lax.ragged_dot``
+    (``_ragged_matmul``); ``moe/grouped_matmul_traced/kernel`` and
+    ``.../xla`` count which, per compile."""
+    if exact_weights(rows, w):
+        # float32 rows over weights kept in bfloat16 (models/layers.py
+        # dot_exact_weights): each row goes as its three bfloat16 terms,
+        # side by side in its group, and the three products are summed —
+        # the stack of weights is never cast, and is read once
+        m, n = rows.shape[0], 3
+        terms = split_terms(rows, n).transpose(1, 0, 2).reshape(m * n, -1)
+        # (bfloat16 terms have no lower passes to make: a caller's
+        # default of HIGHEST would only be refused by the kernel)
+        out = grouped_matmul(terms, w, groups * n,
+                             preferred_element_type=jnp.float32,
+                             precision=jax.lax.Precision.DEFAULT)
+        return jnp.sum(out.reshape(m, n, -1), axis=1)
+    out_dtype = kw.get("preferred_element_type")
+    reason = _kernel_refusal(rows, w, out_dtype)
+    get_registry().counter("moe/grouped_matmul_traced/"
+                           + ("xla" if reason else "kernel")).inc()
+    if reason:
+        pallas_grouped.record_fallback(rows, w, reason)
+        return _ragged_matmul(rows, w, groups, **kw)
+    return _kernel_matmul(rows, w, groups, out_dtype)
 
 
 def dropless_experts(tokens, weights, experts, w_gate, w_up, w_down, layer,
@@ -346,10 +402,11 @@ def dropless_experts(tokens, weights, experts, w_gate, w_up, w_down, layer,
     whose operand is a buffer: 805 MB written and read again per layer at
     OLMoE's widths, as much as the matmuls themselves move.
 
-    The grouped matmul (``grouped_matmul``) is ``jax.lax.ragged_dot``:
-    on a TPU XLA lowers it to its own Mosaic kernel (``%ragged-dot`` in
-    a trace), which walks the groups that have rows and reads no other's
-    weights."""
+    The grouped matmul (``grouped_matmul``) is on one TPU a Pallas call
+    a matmul (``ops/pallas/grouped_matmul.py``, ``%ragged-dot-grouped``
+    in a trace) and elsewhere ``jax.lax.ragged_dot``, which XLA lowers
+    on a TPU to a Mosaic kernel of its own (``%ragged-dot-none``); either
+    walks the groups that have rows and reads no other's weights."""
     n_tokens, k = experts.shape
     n_experts = w_gate.shape[-3]
     flat = experts.reshape(-1)

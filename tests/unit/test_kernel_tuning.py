@@ -121,10 +121,12 @@ class TestCacheLayers:
         assert art["entries"], "committed default table must not be empty"
         for key, e in art["entries"].items():
             # a flash entry tiles the queries; a paged-decode entry (one
-            # query token a row) the pooled tokens of a DMA block
+            # query token a row) the pooled tokens of a DMA block; a
+            # grouped-matmul entry the rows of a visit
             kernel = key.split("/")[0]
-            assert kernel in ("flash_attention", "paged_attention"), key
-            block = "block_q" if kernel == "flash_attention" else "block_k"
+            block = {"flash_attention": "block_q",
+                     "paged_attention": "block_k",
+                     "grouped_matmul": "block_m"}[kernel]
             assert isinstance(e.get(block), int), (key, e)
 
 
@@ -215,6 +217,31 @@ class TestSweepHarness:
         with pytest.raises(ValueError, match="lengths"):
             sweep_paged_attention(2, 2, 16, 16, 4, lengths=[5, 5, 5],
                                   trials=1, log=lambda *a: None)
+
+    @pytest.mark.parametrize("down,out", [(False, "bfloat16"),
+                                          (True, "float32")],
+                             ids=["gate-up", "down"])
+    def test_grouped_sweep_times_both_arms(self, down, out):
+        """XLA's ``ragged_dot`` arm and the kernel at each row tile, at
+        one call's shape with a router's uneven groups in one layer of
+        the stack; the entry is keyed as the dispatch looks it up."""
+        from benchmarks.kernel_tuning import (drawn_groups,
+                                              sweep_grouped_matmul)
+        sizes = drawn_groups(32, 3, 4, 2)
+        assert sizes.sum() == 96 and (sizes[:4] == 0).all()
+        (key, entry), = sweep_grouped_matmul(
+            32, 3, 4, 2, 128, 256, down=down, out_dtype=out, calls=2,
+            trials=1, log=lambda *a: None).items()
+        k, n = (256, 128) if down else (128, 256)
+        assert key == f"grouped_matmul/groups8/sq96_sk{k}_d{n}_bfloat16_full"
+        arms = entry["swept"]
+        assert arms[0]["impl"] == "ragged_dot"
+        assert [a["block_m"] for a in arms[1:]] == [32, 64]
+        assert entry["block_n"] == n and entry["us"] == min(
+            a["us"] for a in arms[1:])
+        # bf16 x bf16 summed in float32 on both arms
+        assert all(a["max_diff"] <= (1e-5 if out == "float32" else 0.02)
+                   for a in arms[1:])
 
     @pytest.mark.slow  # fresh-interpreter subprocess (~40s); the sweep
     # plumbing itself is covered in-process above

@@ -141,6 +141,46 @@ def _origin(var, frames):
             return made, frames
 
 
+def _decode_operands(model, one_chip, kv_int8=False):
+    """``(args, static, pool_shapes)`` of ``_paged_decode_iter_impl`` for
+    ``model`` at the cell's slots and pages: shapes placed on the
+    described chip — nothing is allocated anywhere."""
+    import flax.core.meta as flax_meta
+    params = jax.eval_shape(
+        lambda r: flax_meta.unbox(model.init(
+            r, jnp.ones((1, 8), jnp.int32)))["params"],
+        jax.random.PRNGKey(0))
+
+    def pool():
+        p = init_page_pool(model, params, PAGES, PAGE_LEN)
+        return quantize_page_pool(p) if kv_int8 else p
+
+    def on_chip(tree):
+        """``tree``'s shapes (of a thunk: of what it would build)."""
+        shapes = jax.eval_shape(tree) if callable(tree) else tree
+        return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=one_chip), shapes)
+
+    slot = lambda dtype: jax.ShapeDtypeStruct((SLOTS,), dtype)
+    state = {"lengths": slot(jnp.int32), "last_token": slot(jnp.int32),
+             "active": slot(jnp.bool_), "remaining": slot(jnp.int32)}
+    pool_shapes = on_chip(pool)
+    # params, pool, page table, state, rng, iteration; then the statics
+    args = (on_chip(params), pool_shapes,
+            on_chip(jax.ShapeDtypeStruct((SLOTS, MAX_PAGES), jnp.int32)),
+            on_chip(state), on_chip(lambda: jax.random.PRNGKey(0)),
+            on_chip(jax.ShapeDtypeStruct((), jnp.int32)))
+    static = (50256, 1.0, 0, 1.0, None, True, False, False, True,
+              jnp.bfloat16)
+    return args, static, pool_shapes
+
+
+def _compile_decode(model, args, static):
+    return jax.jit(
+        _paged_decode_iter_impl, static_argnums=(0, 11, 12, 13, 14, 15, 16),
+        donate_argnums=(2, 4)).lower(model, *args, *static).compile()
+
+
 @pytest.mark.parametrize("scan_layers,kv_int8,experts,temp_before", [
     (True, False, False, 742400), (False, False, False, 2734080),
     (True, True, False, 935936), (True, False, True, 4657664)],
@@ -161,37 +201,8 @@ def test_decode_program_leaves_the_pool_where_it_is(one_chip, monkeypatch,
         model = GPT(GPTConfig(n_layers=LAYERS, scan_layers=scan_layers,
                               dtype=jnp.bfloat16, param_dtype=jnp.bfloat16,
                               **WIDTH))
-    import flax.core.meta as flax_meta
-    params = jax.eval_shape(
-        lambda r: flax_meta.unbox(model.init(
-            r, jnp.ones((1, 8), jnp.int32)))["params"],
-        jax.random.PRNGKey(0))
-
-    def pool():
-        p = init_page_pool(model, params, PAGES, PAGE_LEN)
-        return quantize_page_pool(p) if kv_int8 else p
-
-    def on_chip(tree):
-        """``tree``'s shapes (of a thunk: of what it would build), placed
-        on the described chip — nothing is allocated anywhere."""
-        shapes = jax.eval_shape(tree) if callable(tree) else tree
-        return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
-            x.shape, x.dtype, sharding=one_chip), shapes)
-
-    slot = lambda dtype: jax.ShapeDtypeStruct((SLOTS,), dtype)
-    state = {"lengths": slot(jnp.int32), "last_token": slot(jnp.int32),
-             "active": slot(jnp.bool_), "remaining": slot(jnp.int32)}
-    pool_shapes = on_chip(pool)
-    # params, pool, page table, state, rng, iteration; then the statics
-    args = (on_chip(params), pool_shapes,
-            on_chip(jax.ShapeDtypeStruct((SLOTS, MAX_PAGES), jnp.int32)),
-            on_chip(state), on_chip(lambda: jax.random.PRNGKey(0)),
-            on_chip(jax.ShapeDtypeStruct((), jnp.int32)))
-    static = (50256, 1.0, 0, 1.0, None, True, False, False, True,
-              jnp.bfloat16)
-    compiled = jax.jit(
-        _paged_decode_iter_impl, static_argnums=(0, 11, 12, 13, 14, 15, 16),
-        donate_argnums=(2, 4)).lower(model, *args, *static).compile()
+    args, static, pool_shapes = _decode_operands(model, one_chip, kv_int8)
+    compiled = _compile_decode(model, args, static)
 
     # the lengths the Mosaic call walks are masked by ``active``: a row
     # that does not decode is handed 0, whatever length it still holds
@@ -282,6 +293,70 @@ def test_decode_program_leaves_the_pool_where_it_is(one_chip, monkeypatch,
         assert not any(" constant(" in x for x in cond), cond
         compare, = [x for x in cond if " compare(" in x]
         assert compare.lstrip().startswith("ROOT") and "direction=LT" in compare
+
+
+@pytest.fixture
+def fresh_traces():
+    """The same model at the same shapes is traced in this file as the
+    CPU traces it (``ragged_dot``): that cached trace is not the test's,
+    nor the test's a later one's."""
+    jax.clear_caches()
+    yield
+    jax.clear_caches()
+
+
+def test_olmoe_decode_program_is_one_grouped_call_a_matmul(
+        one_chip, monkeypatch, fresh_traces):
+    """The OLMoE decode program as a TPU traces it (``grouped_matmul``
+    takes its Pallas kernel there), compiled by Mosaic at the cell's
+    widths: a layer's three expert matmuls are three calls named
+    ``ragged-dot-grouped`` — the family the benchmark's ``%ragged-dot``
+    patterns read — with XLA's own ``ragged_dot`` kernel gone, and the
+    ``[L·E, ...]`` stack goes to them whole: the program's scratch stays
+    where the ``ragged_dot`` program's was, nowhere near a layer's slice
+    of the stack (3 x 268 MB)."""
+    from deepspeed_tpu.moe import sharded_moe
+    from deepspeed_tpu.ops.pallas import tuning
+    for module in ("paged_attention", "grouped_matmul"):
+        monkeypatch.setattr(importlib.import_module(
+            f"deepspeed_tpu.ops.pallas.{module}"), "_interpret",
+            lambda: False)
+    monkeypatch.setattr(sharded_moe, "on_tpu", lambda: True)
+    model = OLMoE(OLMoEConfig(num_hidden_layers=LAYERS, dtype=jnp.bfloat16,
+                              param_dtype=jnp.bfloat16, **OLMOE_WIDTH))
+    args, static, _ = _decode_operands(model, one_chip)
+    compiled = _compile_decode(model, args, static)
+    record = tuning.last_dispatch("grouped_matmul")[
+        f"groups{LAYERS * OLMOE_WIDTH['num_experts']}"]
+    assert record["impl"] == "kernel"
+    assert record["block_m"] == 128 and record["block_n"] in (1024, 2048)
+
+    program = jax.make_jaxpr(lambda *a: _paged_decode_iter_impl(
+        model, *a, *static))(*args).jaxpr
+    calls = [eqn for eqn, _ in _equations(((program, None),), "pallas_call")]
+    names = sorted(eqn.params["name"] or "" for eqn in calls)
+    # under the layer scan: the paged kernel (named after its function)
+    # and a layer's three matmuls
+    assert names == [""] + ["ragged-dot-grouped"] * 3, names
+    assert not list(_equations(((program, None),), "ragged_dot_general"))
+    grouped = [eqn for eqn in calls if eqn.params["name"]]
+    stack = LAYERS * OLMOE_WIDTH["num_experts"]
+    for eqn in grouped:
+        rows, weights = eqn.invars[-2:]
+        assert rows.aval.shape[0] == SLOTS * OLMOE_WIDTH["num_experts_per_tok"]
+        assert weights.aval.shape[0] == stack          # whole, not a slice
+
+    hlo = compiled.as_text()
+    lines = [x for x in hlo.splitlines() if "tpu_custom_call" in x]
+    matmuls = [x for x in lines if re.match(
+        r"\s*(?:ROOT )?%ragged-dot-grouped[^ ]* = ", x)]
+    assert len(matmuls) == 3
+    assert "%ragged-dot-none" not in hlo
+    mem = compiled.memory_analysis()
+    # the ``ragged_dot`` program's scratch was 4,657,664 bytes; the
+    # walk's metadata is vectors of a number a visit
+    assert mem.temp_size_in_bytes <= 4657664 + SMALL_VECTORS, \
+        mem.temp_size_in_bytes
 
 
 # the LFM2 cell (configs/lfm2-24b-a2b-10l-serve.json) at its published
