@@ -20,6 +20,11 @@ width of gpt2-125m with seeded weights and seeded tokens (no network):
            beside the paged K/V of 8 heads that 32 query heads read in
            groups, a sigmoid top-4 router with its bias, and a prefix hit
            that restores the state;
+- falcon_h1: the same serving path with Falcon-H1-34B's block at its
+           published widths, two layers deep on an eighth of the
+           vocabulary: a Mamba-2 mixer beside grouped-query attention,
+           the mixer's matrix state a slot updated in place by a Mosaic
+           kernel, and a prefix hit that starts from a snapshot;
 - kernels: every Pallas kernel compiled by Mosaic and run once at a real
            shape against its jnp reference;
 - offload: offload configs really place state in ``pinned_host``, or
@@ -69,6 +74,12 @@ FULL = {
     "kanana": dict(n_layers=3, num_slots=4, max_len=2048, page_len=128,
                    n_requests=10, prompt_max=300, new_max=24,
                    paging_kernel="auto", logit_tol=0.1),
+    # max_len 2048: a pool of 65 pages (one layer's K pages 8.5 MB in
+    # bf16); four snapshots, so that the first request's leaf outlives
+    # the leaves of the requests between it and the one that hits
+    "falcon_h1": dict(n_layers=2, num_slots=4, max_len=2048, page_len=128,
+                      n_requests=10, prompt_max=300, new_max=24,
+                      paging_kernel="auto", logit_tol=0.1),
     "kernels": dict(seq=1024, heads=12, batch=2, cache_len=1024,
                     gemv_k=4096, gemv_n=16384, sparse_seq=2048,
                     gemv_timeout_s=180),
@@ -306,7 +317,8 @@ def _check_pool_stays_in_place(srv, label):
     import jax
     from deepspeed_tpu.observability.programs import get_program_registry
 
-    kv = [x for x in jax.tree.leaves(srv._paged.pool) if x.ndim >= 4]
+    from deepspeed_tpu.inference.cache import kv_leaves
+    kv = kv_leaves(srv._paged.pool)
     if any(len(x.sharding.device_set) > 1 for x in kv):
         return
     mem = get_program_registry().get("serving/paged_decode").analyze() or {}
@@ -406,7 +418,8 @@ def _first_divergence_gap(a, b, row_of):
 def _serve_and_check(eng, module, params, reqs, num_slots, max_len,
                      page_len, paging_kernel, logit_tol, label,
                      against_generate=True, reference_logits=None,
-                     kernel="paged_attention", iteration_log=False):
+                     kernel="paged_attention", iteration_log=False,
+                     paging=None):
     import numpy as np
     import jax
     import jax.numpy as jnp
@@ -416,7 +429,8 @@ def _serve_and_check(eng, module, params, reqs, num_slots, max_len,
 
     tuning.clear_last_dispatch()
     options = {"num_slots": num_slots, "max_len": max_len,
-               "paging": {"page_len": page_len, "kernel": paging_kernel}}
+               "paging": {"page_len": page_len, "kernel": paging_kernel,
+                          **(paging or {})}}
     srv = eng.serve(options)
     rows_before = get_registry().table("serving/iterations").count
     stream = []
@@ -695,6 +709,82 @@ def phase_lfm2(n_layers, num_slots, max_len, page_len, n_requests,
         _say(f"serve lfm2: ragged generate() refused: {e}")
     else:
         _check(False, "serve lfm2: ragged generate() did not refuse a "
+                      "model with recurrent state")
+
+
+def phase_falcon_h1(n_layers, num_slots, max_len, page_len, n_requests,
+                    prompt_max, new_max, paging_kernel, logit_tol):
+    """Falcon-H1-34B's block at published widths through the same serving
+    path, in bf16 as its cell runs it: the chunk-prefill and paged-decode
+    programs carry the mixer's two states beside the K/V pages (the pool
+    stays where it is, the slots' matrix states included:
+    ``_check_pool_stays_in_place``), the state update is the Mosaic kernel
+    of ``ops/pallas/ssm_update.py``, and a request admitted on a shared
+    page starts from the snapshot at that page's end."""
+    import numpy as np
+    import jax
+    import jax.numpy as jnp
+    import deepspeed_tpu as ds
+    from deepspeed_tpu.models.falcon_h1 import FalconH1, FalconH1Config
+    from deepspeed_tpu.observability.metrics import get_registry
+    from deepspeed_tpu.ops.pallas import tuning
+    from benchmarks.chip import manifest
+    from benchmarks.chip.families import falcon_h1 as family
+
+    # the multipliers are the published ones, from the cell's file
+    published = manifest.load_json(os.path.join(
+        os.path.dirname(os.path.abspath(__file__)), "benchmarks", "chip",
+        "configs", "falcon-h1-34b-9l-serve.json"))
+    model = FalconH1(FalconH1Config(
+        num_hidden_layers=n_layers, vocab_size=published["vocab_size"],
+        max_position_embeddings=max(max_len, 128),
+        **{k: published[k] for k in family.MULTIPLIER_KEYS},
+        rope_theta=float(published["rope_theta"]),
+        dtype=jnp.bfloat16, param_dtype=jnp.bfloat16))
+    params = _seeded_params(model)
+    eng = ds.init_inference(model, params=params, dtype=jnp.bfloat16)
+    rng = np.random.default_rng(3)
+    reqs = _requests_with_a_page_shared(rng, n_requests,
+                                        model.config.vocab_size, prompt_max,
+                                        new_max, page_len)
+    names = ("serving/prefill_tokens_reused",
+             "serving/state_snapshots_restored", "serving/state_resets",
+             "serving/state_snapshots_taken", "serving/state_restore_missed")
+    counters = {name: get_registry().counter(name) for name in names}
+    before = {name: c.value for name, c in counters.items()}
+    sizes = dict(family.sizes(published, False), num_hidden_layers=n_layers)
+
+    @jax.jit
+    def reference_logits(prm, ids):
+        with jax.default_matmul_precision("highest"):
+            return family.reference_logits(prm, ids, sizes, published)
+
+    served = _serve_and_check(eng, model, params, reqs, num_slots, max_len,
+                              page_len, paging_kernel, logit_tol,
+                              "serve falcon_h1", against_generate=False,
+                              reference_logits=reference_logits,
+                              paging={"state_snapshots": 2 * num_slots})
+    update, = tuning.last_dispatch("ssm_update").values()
+    _mosaic(update, "serve falcon_h1 state update")
+    _check(update.get("impl") == "kernel",
+           f"serve falcon_h1: the state update is not the kernel: {update}")
+    moved = {name: c.value - before[name] for name, c in counters.items()}
+    _say(f"serve falcon_h1: counted {moved}")
+    _check(moved["serving/prefill_tokens_reused"] >= page_len
+           and moved["serving/state_snapshots_restored"] >= 1,
+           f"serve falcon_h1: no prefix hit restored a snapshot: {moved}")
+    _check(moved["serving/state_snapshots_restored"]
+           + moved["serving/state_resets"] == len(served)
+           and moved["serving/state_snapshots_taken"] >= 2,
+           f"serve falcon_h1: admissions and snapshots do not add up: "
+           f"{moved}")
+    try:
+        eng.generate(np.zeros((2, 8), np.int32), max_new_tokens=2,
+                     prompt_lengths=np.asarray([8, 5], np.int32))
+    except NotImplementedError as e:
+        _say(f"serve falcon_h1: ragged generate() refused: {e}")
+    else:
+        _check(False, "serve falcon_h1: ragged generate() did not refuse a "
                       "model with recurrent state")
 
 
@@ -1302,7 +1392,8 @@ def main():
 
     phases = [("train", phase_train), ("serve", phase_serve),
               ("olmoe", phase_olmoe), ("lfm2", phase_lfm2),
-              ("kanana", phase_kanana), ("kernels", phase_kernels)]
+              ("kanana", phase_kanana), ("falcon_h1", phase_falcon_h1),
+              ("kernels", phase_kernels)]
     if device["count"] >= 4:
         phases.append(("multichip", phase_multichip))
     else:

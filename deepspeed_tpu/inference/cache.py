@@ -87,6 +87,21 @@ def _map_units(cache, fn):
     return walk(cache)
 
 
+def kv_leaves(tree) -> list:
+    """Every K/V leaf (and int8 scale plane) of the tree's attention
+    units, in walking order: what the pool's page bytes and its K/V type
+    are read from — a state unit's leaves are not among them."""
+    found = []
+
+    def grab(unit):
+        found.extend(unit[k] for k in _KV_KEYS + tuple(_SCALE_KEYS.values())
+                     if k in unit)
+        return unit
+
+    _map_units(tree, grab)
+    return found
+
+
 def cache_max_len(cache) -> int:
     """The allocated sequence capacity (static python int)."""
     found = []
@@ -129,14 +144,6 @@ def set_cache_index(cache, lengths):
 # the reserved null page (what masked/unowned table entries and writes
 # name: gathered as garbage, never written by the append).
 # ---------------------------------------------------------------------------
-
-def init_page_pool(module, params, num_pages: int, page_len: int):
-    """Allocate a paged KV pool: ``[num_pages, h, d, page_len]`` per
-    attention unit (``[L, num_pages, ...]`` scan-stacked) — shape-only
-    init, no FLOPs burned."""
-    from .generation import init_cache
-    return init_cache(module, params, num_pages, page_len)
-
 
 def cache_page_len(pool) -> int:
     """Tokens per page of a page pool (static python int)."""
@@ -251,7 +258,10 @@ def _walk_with(pool, src, fn):
         if _is_attn_unit(dst):
             return fn(dict(dst), s)
         if isinstance(dst, dict) and not _is_state_unit(dst):
-            return {k: walk(v, s[k]) for k, v in dst.items()}
+            # a state unit has nothing for ``fn`` and may have nothing in
+            # ``src`` (a mixer publishes no token of a decode step)
+            return {k: v if _is_state_unit(v) else walk(v, s[k])
+                    for k, v in dst.items()}
         return dst
 
     return walk(pool, src)
@@ -489,26 +499,46 @@ def make_paged_view(pool, page_table, lengths):
 
 
 # ---------------------------------------------------------------------------
-# recurrent state beside the pages (models/layers.py ShortConv): a layer
-# whose "cache" unit is a fixed-size state, ``conv_state`` ``[batch, ...]``,
-# and not keys and values. In a page pool such a unit holds two leaves:
-# ``conv_state`` ``[slots, ...]``, each slot's state where its request now
-# stands (what a decode step reads and advances), and ``page_state``
-# ``[pages, ...]``, the state at the END of each page's last token, written
-# by the prefill chunk that filled the page. A page is only ever read
-# together with the state at its end: a prefill chunk starts on a page
-# boundary and takes the state of the page before it, its own request's or
-# one shared through the prefix cache, and position 0 takes zeros. So a
-# prefix hit restores the state with no host work, and a slot's stale
-# state is never read by its next request.
+# recurrent state beside the pages: a layer whose "cache" unit is a
+# fixed-size state (``conv_state [batch, ...]``, and for a state-space
+# mixer ``ssm_state [batch, ...]`` beside it) and not keys and values. In
+# a page pool every such leaf is kept a SLOT (``[slots, ...]``: each
+# slot's state where its request now stands, what a decode step reads and
+# advances), and the states at page ends, from which a prefix hit starts,
+# in one of two layouts:
+#
+# - **a state a page** (models/layers.py ShortConv: 16 KB a page a layer):
+#   ``page_state [pages, ...]``, the state at the END of each page's last
+#   token, written by the prefill chunk that filled the page. A chunk
+#   starts on a page boundary and takes the state of the page before it,
+#   its own request's or one shared through the prefix cache, and position
+#   0 takes zeros: a hit restores the state with no host work.
+# - **a snapshot pool** (models/layers.py Mamba2Mixer: a unit that holds
+#   ``ssm_state``, 4 MB a sequence a layer, 16 times the K/V of the page
+#   it would be stored with): ``snapshots {leaf: [entries, ...]}``, far
+#   fewer entries than pages, with the page -> entry table kept by the
+#   host (serving/paging/snapshots.py, which has the rule for which page
+#   ends get one). A request's first chunk starts from the entry the host
+#   names (a prefix hit), from zeros at position 0, and every later chunk
+#   from its slot's own state; a chunk writes the states at its page ends
+#   (the mixer's own ``chunk_states``, its chunk being a page) to the
+#   entries the host names, entry 0 standing for "none" (never read).
 # ---------------------------------------------------------------------------
 
 _STATE_KEY = "conv_state"
+_MATRIX_STATE_KEY = "ssm_state"
 _PAGE_STATE_KEY = "page_state"
+_SNAPSHOTS_KEY = "snapshots"
+_SLOT_KEYS = (_STATE_KEY, _MATRIX_STATE_KEY)
+NULL_SNAPSHOT = 0
 
 
 def _is_state_unit(d) -> bool:
     return isinstance(d, dict) and _STATE_KEY in d
+
+
+def _slot_leaves(unit) -> dict:
+    return {k: unit[k] for k in _SLOT_KEYS if k in unit}
 
 
 def _walk_state(tree, fn, *srcs):
@@ -541,33 +571,77 @@ def has_recurrent_state(tree) -> bool:
     return len(state_units(tree)) > 0
 
 
-def add_slot_state(pool, slot_cache):
-    """The pool of a model with recurrent state: each state unit's own
-    leaf (``[pages, ...]``, from the pool's shape-only init) becomes
-    ``page_state`` and ``slot_cache``'s (``[slots, ...]``, the same init
-    over the slot batch) ``conv_state``."""
-    return _walk_state(
-        pool, lambda unit, slots: {_STATE_KEY: slots[_STATE_KEY],
-                                   _PAGE_STATE_KEY: unit[_STATE_KEY]},
-        slot_cache)
+def has_snapshot_pool(tree) -> bool:
+    """Whether a state unit of the pool keeps its page-end states in a
+    snapshot pool (it holds a matrix state)."""
+    return any(_MATRIX_STATE_KEY in u for u in state_units(tree))
+
+
+def describe_state(tree) -> str:
+    """What the model keeps beside its K/V pages, for a refusal."""
+    unit = state_units(tree)[0]
+    if _MATRIX_STATE_KEY in unit:
+        return ("a state-space mixer's state (a convolution's last columns "
+                "and a matrix state a slot, with snapshots of some page "
+                "ends)")
+    return "a convolution state (a slot's, and one at each page's end)"
+
+
+def init_page_pool(module, params, num_pages: int, page_len: int,
+                   num_slots: int = 0, snapshots: int = 0):
+    """Allocate a paged KV pool: ``[num_pages, h, d, page_len]`` per
+    attention unit (``[L, num_pages, ...]`` scan-stacked) — shape-only
+    init, no FLOPs burned. A state unit gets its leaves a slot
+    (``num_slots``) and its page-end states in its layout (above):
+    ``page_state`` over the pages, or ``snapshots`` over ``snapshots``
+    entries and the null one."""
+    from .generation import cache_shapes
+
+    def state(unit):
+        def zeros(leaf, rows):
+            return jnp.zeros((rows,) + leaf.shape[1:], leaf.dtype)
+        out = {k: zeros(v, num_slots) for k, v in unit.items()}
+        if _MATRIX_STATE_KEY in unit:
+            out[_SNAPSHOTS_KEY] = {k: zeros(v, snapshots + 1)
+                                   for k, v in unit.items()}
+        else:
+            out[_PAGE_STATE_KEY] = zeros(unit[_STATE_KEY], num_pages)
+        return out
+
+    pool = _walk_state(cache_shapes(module, params, num_pages, page_len),
+                       state)
+    return jax.tree.map(
+        lambda s: (jnp.zeros(s.shape, s.dtype)
+                   if isinstance(s, jax.ShapeDtypeStruct) else s), pool)
 
 
 def slot_state_view(cache):
-    """``cache`` as the module takes it: a state unit's ``conv_state``
+    """``cache`` as the module takes it: a state unit's slot leaves
     alone."""
-    return _walk_state(cache, lambda u: {_STATE_KEY: u[_STATE_KEY]})
+    return _walk_state(cache, _slot_leaves)
 
 
-def chunk_state_view(cache, pool, prev_page, fresh):
-    """A single-row cache for a prefill chunk: every state unit starts
-    from the state at the end of ``prev_page`` (the physical page before
-    the chunk's first), or from zeros when ``fresh`` (the chunk starts at
-    position 0)."""
+def chunk_state_view(cache, pool, prev_page, fresh, slot=None, restore=None):
+    """A single-row cache for a prefill chunk. A unit with a state a page
+    starts from the state at the end of ``prev_page`` (the physical page
+    before the chunk's first); one with a snapshot pool from entry
+    ``restore`` when that is not negative (a prefix hit's first chunk),
+    else from ``slot``'s own state (the chunk before left it there);
+    either from zeros when ``fresh`` (the chunk starts at position 0)."""
+
+    def row(leaf, at):
+        return jax.lax.dynamic_index_in_dim(leaf, at, axis=0, keepdims=True)
 
     def start(_, unit):
-        at_page = jax.lax.dynamic_index_in_dim(
-            unit[_PAGE_STATE_KEY], prev_page, axis=0, keepdims=True)
-        return {_STATE_KEY: jnp.where(fresh, 0.0, at_page)}
+        if _SNAPSHOTS_KEY not in unit:
+            return {_STATE_KEY: jnp.where(
+                fresh, 0.0, row(unit[_PAGE_STATE_KEY], prev_page))}
+        return {k: jnp.where(
+            fresh, jnp.zeros((), leaf.dtype), jnp.where(
+                restore >= 0,
+                row(unit[_SNAPSHOTS_KEY][k], jnp.maximum(restore, 0)),
+                row(leaf, slot)))
+            for k, leaf in _slot_leaves(unit).items()}
 
     return _walk_state(cache, start, pool)
 
@@ -576,35 +650,52 @@ def store_decode_state(pool, cache_out):
     """After a decode step: every slot's state as the module left it (a
     row that held no token kept its own)."""
     return _walk_state(
-        pool, lambda unit, out: {**unit, _STATE_KEY: out[_STATE_KEY]},
-        cache_out)
+        pool, lambda unit, out: {**unit, **_slot_leaves(out)}, cache_out)
 
 
-def store_chunk_state(pool, cache_out, token_tree, slot, page_run):
+def store_chunk_state(pool, cache_out, token_tree, slot, page_run,
+                      snap_run=None):
     """After a prefill chunk: the slot's state is the row's (the state
-    after the chunk's last live token), and each page of ``page_run``
-    gets the state at its end, cut from the unit's ``trail`` (the
-    chunk's starting state followed by one column a position): page
-    ``i`` of the chunk ends ``(i + 1) * page_len`` columns in. A page
-    the chunk only partly fills gets columns of its padding: such a page
-    is never shared, and no chunk ever starts after it."""
+    after the chunk's last live token), and the states at the chunk's
+    page ends go where the unit's layout keeps them. A state a page: each
+    page of ``page_run`` gets the state at its end, cut from the unit's
+    ``trail`` (the chunk's starting state followed by one column a
+    position): page ``i`` of the chunk ends ``(i + 1) * page_len``
+    columns in. A snapshot pool: the state at page ``i``'s end (the
+    mixer's ``chunk_states``) goes to entry ``snap_run[i]``, the null
+    entry where the host wants none. A page the chunk only partly fills
+    gets columns of its padding: such a page is never shared, and no
+    chunk ever starts after it."""
     n_t = page_run.shape[0]
 
     def store(unit, out, tok):
+        new = {k: leaf.at[slot].set(out[k][0])
+               for k, leaf in _slot_leaves(unit).items()}
+        if _SNAPSHOTS_KEY in unit:
+            ends = tok["chunk_states"]
+            if ends[_STATE_KEY].shape[1] != n_t:
+                raise ValueError(
+                    f"the mixer hands back {ends[_STATE_KEY].shape[1]} "
+                    f"chunk-end states for a chunk of {n_t} pages: its "
+                    "chunk size has to be the page length")
+            new[_SNAPSHOTS_KEY] = {
+                k: leaf.at[snap_run].set(ends[k][0])
+                for k, leaf in unit[_SNAPSHOTS_KEY].items()}
+            return new
         trail = tok["trail"][0]                    # [taps-1 + chunk, d]
         width = unit[_STATE_KEY].shape[1]
         page_len = (trail.shape[0] - width) // n_t
         ends = jnp.stack([trail[(i + 1) * page_len:(i + 1) * page_len + width]
                           for i in range(n_t)])
-        return {
-            _STATE_KEY: unit[_STATE_KEY].at[slot].set(out[_STATE_KEY][0]),
-            _PAGE_STATE_KEY: unit[_PAGE_STATE_KEY].at[page_run].set(ends)}
+        new[_PAGE_STATE_KEY] = unit[_PAGE_STATE_KEY].at[page_run].set(ends)
+        return new
 
     return _walk_state(pool, store, cache_out, token_tree)
 
 
 def state_bytes(pool) -> int:
-    """Resident bytes of the recurrent state, slots and pages together
-    (0 for a model without)."""
+    """Resident bytes of the recurrent state, slots and page ends (or
+    snapshots) together (0 for a model without)."""
     return sum(int(leaf.size) * leaf.dtype.itemsize
-               for unit in state_units(pool) for leaf in unit.values())
+               for unit in state_units(pool)
+               for leaf in jax.tree.leaves(unit))

@@ -25,15 +25,20 @@ import jax.numpy as jnp
 from ..observability.programs import track_program
 
 
-def init_cache(module, params, batch_size: int, max_len: int):
-    """Allocate the KV cache by shape-only init (no FLOPs burned)."""
+def cache_shapes(module, params, batch_size: int, max_len: int):
+    """The "cache" collection's shapes, by shape-only init."""
     ids = jnp.zeros((batch_size, max_len), jnp.int32)
 
     def mk(p):
         variables = module.init(jax.random.PRNGKey(0), ids, decode=True)
         return variables["cache"]
-    cache_shape = jax.eval_shape(mk, params)
-    return jax.tree.map(lambda s: jnp.zeros(s.shape, s.dtype), cache_shape)
+    return jax.eval_shape(mk, params)
+
+
+def init_cache(module, params, batch_size: int, max_len: int):
+    """Allocate the KV cache by shape-only init (no FLOPs burned)."""
+    return jax.tree.map(lambda s: jnp.zeros(s.shape, s.dtype),
+                        cache_shapes(module, params, batch_size, max_len))
 
 
 def apply_decode(module, variables, ids, positions, live, mutable):
@@ -45,11 +50,15 @@ def apply_decode(module, variables, ids, positions, live, mutable):
     token — so that an idle slot or a chunk's padding is routed to no
     expert, and hands back its router's counts ``[L, E]``. For any other
     module this is the plain call: ``live`` is not called and ``counts``
-    is None, an empty pytree that adds nothing to the program."""
+    is None, an empty pytree that adds nothing to the program. A module
+    that keeps a recurrent state and routes nothing (``masks_tokens``,
+    models/falcon_h1.py) takes ``live()`` alone."""
     if not getattr(type(module), "routes_tokens", False):
+        mask = ({"token_mask": live()}
+                if getattr(type(module), "masks_tokens", False) else {})
         logits, vars_out = module.apply(
             variables, ids, decode=True, positions=positions,
-            mutable=mutable)
+            mutable=mutable, **mask)
         return logits, vars_out, None
     (logits, router), vars_out = module.apply(
         variables, ids, decode=True, positions=positions, mutable=mutable,

@@ -15,8 +15,10 @@ Logical axis vocabulary:
 """
 
 import functools
-from typing import Any, Callable, Optional
+import math
+from typing import Any, Callable, Optional, Tuple
 
+import numpy as np
 import jax
 import jax.numpy as jnp
 import flax.linen as nn
@@ -361,6 +363,9 @@ class SelfAttention(nn.Module):
     n_kv_heads: Optional[int] = None     # fewer K/V heads than query heads:
                                          # query head i reads K/V head
                                          # i // (n_heads // n_kv_heads)
+    head_dim: Optional[int] = None       # a head's width where it is not
+                                         # d_model // n_heads (Falcon-H1)
+    key_multiplier: float = 1.0          # on k as projected (Falcon-H1's muP)
     attn_backend: Optional[str] = None
     alibi: bool = False
     seq_parallel: Optional[str] = None   # None=auto, "ulysses", "ring", "none"
@@ -371,12 +376,12 @@ class SelfAttention(nn.Module):
     @nn.compact
     def __call__(self, x, mask=None, bias=None, deterministic=True,
                  decode=False, positions=None):
-        head_dim = self.d_model // self.n_heads
+        head_dim = self.head_dim or self.d_model // self.n_heads
         n_kv = self.n_kv_heads or self.n_heads
         group = self.n_heads // n_kv         # query heads a K/V head serves
-        kv_width = n_kv * head_dim
+        q_width, kv_width = self.n_heads * head_dim, n_kv * head_dim
         qkv = QDense(
-            features=self.d_model + 2 * kv_width, use_bias=self.use_bias,
+            features=q_width + 2 * kv_width, use_bias=self.use_bias,
             dtype=self.dtype, param_dtype=self.param_dtype,
             kernel_init=dense_init(("embed", "qkv")),
             bias_init=nn.with_logical_partitioning(nn.initializers.zeros, ("qkv",)),
@@ -385,7 +390,9 @@ class SelfAttention(nn.Module):
             q, k, v = jnp.split(qkv, 3, axis=-1)
         else:
             q, k, v = jnp.split(
-                qkv, [self.d_model, self.d_model + kv_width], axis=-1)
+                qkv, [q_width, q_width + kv_width], axis=-1)
+        if self.key_multiplier != 1.0:
+            k = k * self.key_multiplier
         # the QK norm sits here and nowhere else: the flash path, the
         # chunked prefill and the paged decode all take q and k from
         # this point
@@ -612,7 +619,7 @@ class SelfAttention(nn.Module):
                     causal = False
 
         if decode_out is not None:
-            out = decode_out.reshape(b, s, self.d_model)
+            out = decode_out.reshape(b, s, q_width)
             out = activation_constraint(out, ("batch", "seq", "embed"))
             return QDense(
                 features=self.d_model, use_bias=self.use_bias,
@@ -691,7 +698,7 @@ class SelfAttention(nn.Module):
         # under that policy the backward keeps THIS tensor and recomputes
         # everything else, so the flash kernel never runs twice
         out = checkpoint_name(out, "attn_out")
-        out = out.reshape(b, s, self.d_model)
+        out = out.reshape(b, s, q_width)
         out = activation_constraint(out, ("batch", "seq", "embed"))
         return QDense(
             features=self.d_model, use_bias=self.use_bias, dtype=self.dtype,
@@ -919,11 +926,15 @@ class MLP(nn.Module):
 
 class GatedMLP(nn.Module):
     """The gated (SwiGLU) feed-forward of the Llama line and its kin:
-    ``w2(silu(w1 x) * w3 x)``, no bias."""
+    ``w2(silu(w1 x) * w3 x)``, no bias. Falcon-H1's muP lays a
+    multiplier on the gate before its activation and one on the
+    result."""
     d_model: int
     d_ff: int
     dtype: Any = jnp.bfloat16
     param_dtype: Any = jnp.float32
+    gate_multiplier: float = 1.0
+    down_multiplier: float = 1.0
 
     @nn.compact
     def __call__(self, x):
@@ -931,10 +942,13 @@ class GatedMLP(nn.Module):
             return QDense(features=features, use_bias=False, dtype=self.dtype,
                           param_dtype=self.param_dtype,
                           kernel_init=dense_init(names), name=name)
-        h = jax.nn.silu(dense(self.d_ff, ("embed", "mlp"), "w1")(x)) \
-            * dense(self.d_ff, ("embed", "mlp"), "w3")(x)
+        gate = dense(self.d_ff, ("embed", "mlp"), "w1")(x)
+        if self.gate_multiplier != 1.0:
+            gate = gate * self.gate_multiplier
+        h = jax.nn.silu(gate) * dense(self.d_ff, ("embed", "mlp"), "w3")(x)
         h = activation_constraint(h, ("batch", "seq", "mlp"))
-        return dense(self.d_model, ("mlp", "embed"), "w2")(h)
+        y = dense(self.d_model, ("mlp", "embed"), "w2")(h)
+        return y if self.down_multiplier == 1.0 else y * self.down_multiplier
 
 
 class ShortConv(nn.Module):
@@ -1005,6 +1019,248 @@ class ShortConv(nn.Module):
                       param_dtype=self.param_dtype,
                       kernel_init=dense_init(("mlp", "embed")),
                       name="out_proj")(gate_c * z)
+
+
+def _a_log_init(key, shape, dtype=jnp.float32):
+    """``A = -exp(A_log)`` with ``A`` uniform in 1-16, as published."""
+    return jnp.log(jax.random.uniform(key, shape, jnp.float32, 1.0,
+                                      16.0)).astype(dtype)
+
+
+def _dt_bias_init(key, shape, dtype=jnp.float32):
+    """``softplus(dt_bias)`` log-uniform in 1e-3 - 1e-1, as published:
+    the inverse softplus of such a step."""
+    dt = jnp.exp(jax.random.uniform(key, shape, jnp.float32,
+                                    math.log(1e-3), math.log(1e-1)))
+    return (dt + jnp.log(-jnp.expm1(-dt))).astype(dtype)
+
+
+def _mixer_in_proj_init(d_ssm, bc, in_multiplier, mup):
+    """Fan-in normal over ``z | x | B | C | dt``, with B's and C's columns
+    widened by ``1 / (in_multiplier * mup)`` so that both arrive at unit
+    variance for a normed input whatever the muP factors in front are. At
+    fan-in scale behind Falcon-H1's factors (0.25 x 0.18 and 0.25 x 0.5)
+    ``C . B`` is ~2e-2 a source position and the recurrent part of ``y``
+    ~1% of the skip path's ``D x``: a seeded state that no bf16 logit can
+    tell from zeros. A trained model's B and C are of order one."""
+    base = nn.initializers.variance_scaling(1.0, "fan_in", "normal")
+    gain = np.ones(2 * d_ssm + 2 * bc, np.float32)
+    gain[2 * d_ssm:2 * d_ssm + bc] = 1.0 / (in_multiplier * mup[2])
+    gain[2 * d_ssm + bc:] = 1.0 / (in_multiplier * mup[3])
+
+    def init(key, shape, dtype=jnp.float32):
+        w = base(key, shape, jnp.float32)
+        return w.at[:, :gain.size].multiply(gain).astype(dtype)
+    return nn.with_logical_partitioning(init, ("embed", "mlp"))
+
+
+class Mamba2Mixer(nn.Module):
+    """The selective state-space mixer of Mamba-2 as Falcon-H1 lays it
+    out (HF ``FalconH1Mixer``). With ``u = x * in_multiplier``:
+
+        [z | xBC | dt] = (W_in u) * mup     (mup: one factor over each of
+                                             z, x, B, C, dt)
+        xBC = silu(conv1d(xBC) + b)          depthwise, causal, d_conv taps
+        [x | B | C] = xBC                    x: heads x d_head; B, C:
+                                             groups x d_state
+        dt = softplus(dt + dt_bias);  a = exp(-exp(A_log) dt)
+        S_t = a_t S_{t-1} + dt_t x_t B_t^T;  y_t = S_t C_t + D x_t
+        out = W_out RMSNorm_grouped(y * silu(z))
+
+    Two recurrent states, float32, in the "cache" collection when
+    ``decode``: ``conv_state [batch, d_conv - 1, conv width]``, the last
+    columns of xBC before the convolution (as ``ShortConv`` keeps them),
+    and ``ssm_state [batch, heads, d_state, d_head]`` — ``S`` with the
+    state dimension before the head's, which is how
+    ``ops/pallas/ssm_update.py`` wants it on the chip.
+
+    One computation serves a whole sequence from zero state (no
+    ``decode``), a prefill chunk from a carried state and a decode token
+    against its slot's state. A sequence goes through the published
+    chunked form, ``chunk`` positions at a time: inside a chunk the
+    outputs are matrix products over the chunk's decays, between chunks
+    the state is carried; a position outside ``token_mask`` (``[batch,
+    seq]`` bool, the live positions a prefix of each row) gets a step of
+    0, which leaves the state as it was, so an idle row and a chunk's
+    padding write nothing. One token (``decode`` and ``seq == 1``) is the
+    recurrence itself (``ssm_update``: a Pallas kernel on the TPU, the
+    rows that do not decode skipped and the state aliased).
+
+    A caller that lists "kv_token" as mutable also gets ``chunk_states``
+    there: both states as they stood at the end of every ``chunk``
+    positions, ``[batch, seq / chunk, ...]`` — with ``chunk`` the page
+    length, the states at the page ends, of which the paged server keeps
+    some as snapshots (``inference/cache.py``). The scan's products are
+    float32 at ``Precision.HIGHEST`` whatever ``dtype`` is: they are
+    under 1% of a layer's operations and the state they build is carried
+    over thousands of tokens."""
+    d_model: int
+    d_ssm: int
+    n_heads: int
+    d_head: int
+    d_state: int
+    n_groups: int = 1
+    d_conv: int = 4
+    chunk: int = 128
+    conv_bias: bool = True
+    in_multiplier: float = 1.0
+    mup: Tuple[float, ...] = (1.0, 1.0, 1.0, 1.0, 1.0)   # z, x, B, C, dt
+    norm_epsilon: float = 1e-5
+    norm_before_gate: bool = False
+    state_dtype: Any = jnp.float32
+    dtype: Any = jnp.bfloat16
+    param_dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(self, x, decode=False, token_mask=None):
+        b, s, _ = x.shape
+        h, p, n, g = self.n_heads, self.d_head, self.d_state, self.n_groups
+        taps = self.d_conv
+        bc = g * n
+        conv_dim = self.d_ssm + 2 * bc
+        proj = QDense(features=self.d_ssm + conv_dim + h, use_bias=False,
+                      dtype=self.dtype, param_dtype=self.param_dtype,
+                      kernel_init=_mixer_in_proj_init(
+                          self.d_ssm, bc, self.in_multiplier, self.mup),
+                      name="in_proj")(x * self.in_multiplier)
+        mup = np.concatenate([np.full(w, m, np.float32) for w, m in zip(
+            (self.d_ssm, self.d_ssm, bc, bc, h), self.mup)])
+        proj = proj * mup.astype(proj.dtype)
+        z, xbc, dt = jnp.split(proj, [self.d_ssm, self.d_ssm + conv_dim],
+                               axis=-1)
+        w = self.param("conv_w", nn.with_logical_partitioning(
+            nn.initializers.variance_scaling(1.0, "fan_in", "normal",
+                                             in_axis=0, out_axis=1),
+            (None, "mlp")), (taps, conv_dim), self.param_dtype)
+        vec = lambda name, init, width: self.param(
+            name, nn.with_logical_partitioning(init, (None,)), (width,),
+            jnp.float32)
+        a_log = vec("A_log", _a_log_init, h)
+        dt_bias = vec("dt_bias", _dt_bias_init, h)
+        d_skip = vec("D", nn.initializers.ones, h)
+
+        conv_state = ssm_state = None
+        if decode:
+            conv_state = self.variable("cache", "conv_state", jnp.zeros,
+                                       (b, taps - 1, conv_dim), jnp.float32)
+            ssm_state = self.variable("cache", "ssm_state", jnp.zeros,
+                                      (b, h, n, p), self.state_dtype)
+        carried = conv_state is not None and not self.is_initializing()
+        live = (jnp.ones((b, s), bool) if token_mask is None
+                else token_mask)
+
+        # the convolution, as ShortConv's: the carry before the sequence
+        carry = (conv_state.value if carried
+                 else jnp.zeros((b, taps - 1, conv_dim), jnp.float32))
+        trail = jnp.concatenate([carry, xbc.astype(jnp.float32)], axis=1)
+        conv = sum(w[j].astype(jnp.float32) * trail[:, j:j + s]
+                   for j in range(taps))
+        if self.conv_bias:
+            conv = conv + vec("conv_b", nn.initializers.zeros, conv_dim)
+        xs, bm, cm = jnp.split(jax.nn.silu(conv),
+                               [self.d_ssm, self.d_ssm + bc], axis=-1)
+        xs = xs.reshape(b, s, h, p)
+        bm, cm = bm.reshape(b, s, g, n), cm.reshape(b, s, g, n)
+        dt = jax.nn.softplus(dt.astype(jnp.float32) + dt_bias)
+        dt = jnp.where(live[..., None], dt, 0.0)             # [b, s, h]
+        log_a = -jnp.exp(a_log) * dt                         # <= 0
+        s0 = (ssm_state.value.astype(jnp.float32) if carried
+              else jnp.zeros((b, h, n, p), jnp.float32))
+
+        if carried and s == 1:
+            from ..ops.pallas.ssm_update import ssm_update
+            y, s_new = ssm_update(s0, (dt[..., None] * xs)[:, 0],
+                                  jnp.exp(log_a[:, 0]), bm[:, 0], cm[:, 0],
+                                  live[:, 0])
+            y, ends = y[:, None], None
+        else:
+            y, s_new, ends = _ssd_chunked(xs, dt, log_a, bm, cm, s0,
+                                          self.chunk)
+        y = y + d_skip[:, None] * xs
+
+        if carried:
+            n_live = jnp.sum(live, axis=1, dtype=jnp.int32)
+            conv_state.value = jax.vmap(
+                lambda t, k: jax.lax.dynamic_slice_in_dim(t, k, taps - 1))(
+                trail, n_live)
+            ssm_state.value = s_new.astype(self.state_dtype)
+            if ends is not None and self.is_mutable_collection("kv_token"):
+                q = self.chunk
+                conv_ends = jnp.stack(
+                    [trail[:, (i + 1) * q:(i + 1) * q + taps - 1]
+                     for i in range(s // q)], axis=1)
+                states = {"conv_state": conv_ends,
+                          "ssm_state": ends.astype(self.state_dtype)}
+                self.variable("kv_token", "chunk_states",
+                              lambda: states).value = states
+
+        # the gated norm: one root-mean-square a group
+        y = y.reshape(b, s, self.d_ssm)
+        gate = jax.nn.silu(z.astype(jnp.float32))
+        scale = self.param("norm", nn.with_logical_partitioning(
+            nn.initializers.ones, ("mlp",)), (self.d_ssm,), jnp.float32)
+
+        def grouped_rms(v):
+            v = v.reshape(b, s, g, self.d_ssm // g)
+            v = v * jax.lax.rsqrt(jnp.mean(jnp.square(v), -1, keepdims=True)
+                                  + self.norm_epsilon)
+            return v.reshape(b, s, self.d_ssm) * scale
+        y = (grouped_rms(y) * gate if self.norm_before_gate
+             else grouped_rms(y * gate))
+        return QDense(features=self.d_model, use_bias=False,
+                      dtype=self.dtype, param_dtype=self.param_dtype,
+                      kernel_init=dense_init(("mlp", "embed")),
+                      name="out_proj")(y)
+
+
+def _ssd_chunked(x, dt, log_a, bm, cm, s0, q):
+    """The chunked form of ``S_t = a_t S_{t-1} + dt_t x_t B_t^T``, ``y_t =
+    S_t C_t`` over ``x [b, s, h, p]``, ``dt`` and ``log_a = log a`` ``[b,
+    s, h]``, ``bm`` and ``cm`` ``[b, s, g, n]``, from ``s0 [b, h, n,
+    p]``: ``(y [b, s, h, p], the last state, the state at the end of
+    every q positions [b, s / q, h, n, p])``. A sequence that is no
+    multiple of ``q`` is padded with steps of 0 (the state stands still)
+    and the padding's chunk ends are dropped."""
+    b, s, h, p = x.shape
+    g, n = bm.shape[2:]
+    k = h // g                                   # heads a group serves
+    pad = -s % q
+    if pad:
+        cut = lambda v: jnp.pad(v, ((0, 0), (0, pad)) + ((0, 0),)
+                                * (v.ndim - 2))
+        x, dt, log_a, bm, cm = map(cut, (x, dt, log_a, bm, cm))
+    nc = (s + pad) // q
+    hi = functools.partial(jnp.einsum, precision=jax.lax.Precision.HIGHEST)
+    dx = (x * dt[..., None]).reshape(b, nc, q, g, k, p)
+    bm, cm = bm.reshape(b, nc, q, g, n), cm.reshape(b, nc, q, g, n)
+    cs = jnp.cumsum(log_a.reshape(b, nc, q, g, k), axis=2)  # decay so far
+    # inside a chunk: position t reads position r <= t through C_t . B_r
+    # and the decay between them
+    between = cs[:, :, :, None] - cs[:, :, None, :]          # [b,c,t,r,g,k]
+    causal = jnp.tril(jnp.ones((q, q), bool))[None, None, :, :, None, None]
+    between = jnp.exp(jnp.where(causal, between, -jnp.inf))
+    cb = hi("bctgn,bcrgn->bctrg", cm, bm)
+    y = hi("bctrgk,bcrgkp->bctgkp", cb[..., None] * between, dx)
+    # what a chunk adds to the state by its end, and the state's own decay
+    to_end = jnp.exp(cs[:, :, -1:] - cs)                     # [b,c,r,g,k]
+    added = hi("bcrgn,bcrgkp->bcgknp", bm, dx * to_end[..., None])
+    whole = jnp.exp(cs[:, :, -1])                            # [b,c,g,k]
+    s0 = s0.reshape(b, g, k, n, p)
+
+    def carry(state, chunk):
+        decay, add = chunk
+        new = decay[..., None, None] * state + add
+        return new, (state, new)
+
+    last, (before, after) = jax.lax.scan(
+        carry, s0, (jnp.moveaxis(whole, 1, 0), jnp.moveaxis(added, 1, 0)))
+    before, after = jnp.moveaxis(before, 0, 1), jnp.moveaxis(after, 0, 1)
+    # the state a chunk starts from, read by each of its positions
+    y = y + hi("bctgn,bcgknp->bctgkp", cm, before) \
+        * jnp.exp(cs)[..., None]
+    y = y.reshape(b, nc * q, h, p)[:, :s]
+    ends = after.reshape(b, nc, h, n, p)[:, :s // q]
+    return y, last.reshape(b, h, n, p), ends
 
 
 class Block(nn.Module):

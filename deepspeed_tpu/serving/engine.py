@@ -336,6 +336,9 @@ class ServingEngine:
         acct.registry.gauge("mem/decode_gather_transient").set(
             self._paged.decode_gather_transient_bytes())
         acct.account("serving/state", self._state)
+        if self._paged.has_state and self.metrics.registry is not None:
+            self.metrics.registry.gauge("serving/state_bytes").set(
+                self._paged.state_bytes())
         acct.registry.gauge("mem/kv_pool_resident").set(
             acct.subsystem_bytes("serving/kv_pool"))
 
@@ -853,8 +856,9 @@ class ServingEngine:
                     args["page"] = self._paged.state_restore_page(slot,
                                                                   shared)
                 restored = args["page"] is not None
-            self.metrics.on_admit(req, shared_tokens=shared,
-                                  state_restored=restored)
+            self.metrics.on_admit(
+                req, shared_tokens=shared, state_restored=restored,
+                state_missed=self._paged.state_restore_missed(slot))
             if resumed:
                 self.metrics.on_resume(req)
             # the plan is where the next chunk starts: the non-shared
@@ -928,6 +932,8 @@ class ServingEngine:
                 tokens = pages * mgr.page_len
                 if pages > mgr.max_pages:
                     continue
+                snaps = (() if mgr.snapshots is None else
+                         (jnp.int32(-1), jnp.zeros((pages,), jnp.int32)))
                 self._chunk_programs[tokens] = \
                     _chunk_prefill_jit.compile_ahead(
                         self.module, self.params, mgr.pool, self._state,
@@ -935,7 +941,7 @@ class ServingEngine:
                         zero, zero, zero, zero, jnp.asarray(False),
                         self._rng, self._eos, t, k, p,
                         self._param_transform, greedy, has_k, has_p,
-                        mgr.dequant_dtype,
+                        mgr.dequant_dtype, *snaps,
                         static_argnums=CHUNK_PREFILL_STATICS)
 
     def _dispatch_chunk(self, slot: int, req, prompt, max_new: int,
@@ -955,6 +961,9 @@ class ServingEngine:
         mgr = self._paged
         pages = width // mgr.page_len
         program = self._chunk_programs.get(width, _chunk_prefill_jit)
+        # a snapshot pool's entries for this chunk (nothing for any other
+        # model: its program has no such arguments)
+        snaps = mgr.chunk_snapshots(slot, start, pages)
         if req.first_chunk_at_ns is None:
             self._chunk_counts.pop(slot, None)  # a preempted prefill's
             req.first_chunk_at_ns = time.perf_counter_ns()
@@ -973,14 +982,17 @@ class ServingEngine:
                     jnp.int32(max_new), jnp.asarray(is_last),
                     self._req_rng(req), self._eos, t, k, p,
                     self._param_transform, greedy, has_k, has_p,
-                    mgr.dequant_dtype)
+                    mgr.dequant_dtype, *snaps)
         except Exception as e:
             if not is_oom_error(e):
                 raise
             self._shed_on_oom(req, "chunk_prefill", e)
             return False
         self.metrics.on_prefill_chunk(
-            real, real // mgr.page_len if mgr.has_state else 0, pages=pages)
+            real, real // mgr.page_len
+            if mgr.has_state and mgr.snapshots is None else 0, pages=pages)
+        if mgr.snapshots is not None:
+            self.metrics.on_state_snapshots(mgr.snapshots)
         if counts is not None:
             # an expert layer's routing of this chunk: read back with the
             # first token, by when every earlier chunk has finished

@@ -298,6 +298,8 @@ class ServingMetrics:
         self.prefill_chunk_pages = 0
         self.prefill_tokens_computed = 0
         self.prefill_tokens_reused = 0
+        self._snapshots_seen = {}         # a snapshot table's counts, as
+                                          # last folded into the registry
         self.paged_stats: Optional[dict] = None   # latest manager.stats()
         self.ttft_s = deque(maxlen=self.history_window)
         self.ttft_steps = deque(maxlen=self.history_window)
@@ -370,10 +372,13 @@ class ServingMetrics:
                                        None))
 
     def on_admit(self, request=None, shared_tokens: int = 0,
-                 state_restored: Optional[bool] = None):
+                 state_restored: Optional[bool] = None,
+                 state_missed: bool = False):
         """``state_restored`` (a model with recurrent state only: None
         otherwise): the admission starts from the state stored with its
-        last shared page, or (False) from zeros."""
+        last shared page, or (False) from zeros. ``state_missed`` (a
+        snapshot pool only): it matched deeper in the prefix cache than
+        a snapshot let it start, and its hit was shortened."""
         self.requests_admitted += 1
         self.prefills += 1
         self.prefill_tokens_reused += shared_tokens
@@ -384,6 +389,8 @@ class ServingMetrics:
                 self.registry.counter(
                     "serving/state_snapshots_restored" if state_restored
                     else "serving/state_resets").inc(1)
+            if state_missed:
+                self.registry.counter("serving/state_restore_missed").inc(1)
         c = self._cls(request)
         if c is not None:
             c["admitted"] += 1
@@ -410,6 +417,22 @@ class ServingMetrics:
             if state_snapshots:
                 self.registry.counter("serving/state_snapshots_stored").inc(
                     state_snapshots)
+
+    def on_state_snapshots(self, table):
+        """After a chunk of a model with a snapshot pool
+        (``paging/snapshots.py SnapshotTable``): the table's running
+        counts of entries taken and evicted, brought up to date, and the
+        entries in use."""
+        if self.registry is None:
+            return
+        for name, total in (("taken", table.taken),
+                            ("evicted", table.evicted)):
+            # the process's counter outlives this engine and its table
+            self.registry.counter(f"serving/state_snapshots_{name}").inc(
+                total - self._snapshots_seen.get(name, 0))
+            self._snapshots_seen[name] = total
+        self.registry.gauge("serving/state_snapshots_in_use").set(
+            table.in_use)
 
     def on_decode_dispatch(self, busy_slots: int, num_slots: int):
         """One decode dispatch over ``num_slots`` rows of which

@@ -23,7 +23,7 @@ NULL_PAGE = 0
 class PageAllocator:
     """Fixed pool of ``num_pages`` pages; page 0 reserved as null."""
 
-    def __init__(self, num_pages: int):
+    def __init__(self, num_pages: int, on_free=None):
         if num_pages < 2:
             raise ValueError(
                 f"need at least 2 pages (null + 1 usable), got {num_pages}")
@@ -32,6 +32,9 @@ class PageAllocator:
         # keeps the working set of the pool dense (friendlier gathers)
         self._free: List[int] = list(range(num_pages - 1, 0, -1))
         self._ref: Dict[int, int] = {}
+        # called with the pages a release put back on the free list (a
+        # snapshot pool drops their entries: paging/snapshots.py)
+        self._on_free = on_free
 
     # -- queries -----------------------------------------------------------
     @property
@@ -86,6 +89,8 @@ class PageAllocator:
                 freed.append(p)
             else:
                 self._ref[p] = count - 1
+        if freed and self._on_free is not None:
+            self._on_free(freed)
         return freed
 
     def check(self) -> None:

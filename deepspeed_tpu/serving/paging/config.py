@@ -72,6 +72,13 @@ class PagingConfig:
                                      # two decode dispatches, whatever
                                      # their width (1 = a decoding row
                                      # never waits for more than one)
+    state_snapshots: Optional[int] = None  # entries of the snapshot pool
+                                     # a model with a state-space mixer
+                                     # keeps its page-end states in
+                                     # (paging/snapshots.py; one entry is
+                                     # a sequence's whole state in every
+                                     # layer). None = half the slots. Read
+                                     # by no other model.
     kernel: str = "auto"             # paged decode-attention kernel
                                      # (ops/pallas/paged_attention.py):
                                      # "auto" = on real TPU with a
@@ -102,6 +109,10 @@ class PagingConfig:
             raise ValueError(
                 "serving.paging.max_chunks_per_iter must be >= 1, got "
                 f"{self.max_chunks_per_iter}")
+        if self.state_snapshots is not None and self.state_snapshots < 1:
+            raise ValueError(
+                "serving.paging.state_snapshots must be >= 1, got "
+                f"{self.state_snapshots}")
         if self.kernel not in ("auto", "on", "off"):
             raise ValueError(
                 f"serving.paging.kernel must be 'auto', 'on', or 'off', "
@@ -120,6 +131,13 @@ class PagingConfig:
         else the narrowest the server chooses: one page."""
         return (self.prefill_chunk if self.prefill_chunk is not None
                 else self.page_len)
+
+    def snapshot_entries(self, num_slots: int) -> int:
+        """Usable entries of the snapshot pool (the null one not
+        counted)."""
+        if self.state_snapshots is not None:
+            return self.state_snapshots
+        return max(1, num_slots // 2)
 
     def pool_pages(self, num_slots: int, cache_len: int) -> int:
         """Total pool pages including the reserved null page."""

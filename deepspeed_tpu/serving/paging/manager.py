@@ -39,23 +39,24 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from ...inference.cache import (_kv_leaves, add_slot_state, cache_page_len,
-                                chunk_state_view, export_pages,
-                                extract_token_kv, gather_pages,
+from ...inference.cache import (NULL_SNAPSHOT, _kv_leaves, cache_page_len,
+                                chunk_state_view, describe_state,
+                                export_pages, extract_token_kv, gather_pages,
                                 has_latent_units, has_recurrent_state,
-                                import_pages,
-                                init_page_pool, make_paged_view,
+                                has_snapshot_pool, import_pages,
+                                init_page_pool, kv_leaves, make_paged_view,
                                 pool_is_quantized, quantize_page_pool,
                                 scatter_chunk_pages, scatter_token_pages,
                                 set_cache_index, slot_state_view,
                                 state_bytes, state_units, store_chunk_state,
                                 store_decode_state)
-from ...inference.generation import _sample_impl, apply_decode, init_cache
+from ...inference.generation import _sample_impl, apply_decode
 from ...observability.programs import track_program
 from ...observability.trace import span as _span
 from ...utils.logging import log_dist
 from .allocator import NULL_PAGE, PageAllocator
 from .prefix import PrefixCache
+from .snapshots import SnapshotTable
 
 
 def _token_tree(vars_out, cache, idx):
@@ -187,9 +188,14 @@ _paged_decode_jit = track_program(
 def _chunk_prefill_impl(module, params, pool, state, ptab_row, chunk_ids,
                         chunk_start, end_pos, slot, max_new, is_last, rng,
                         eos_id, t, k, p, param_transform, greedy, has_k,
-                        has_p, dequant_dtype=None):
+                        has_p, dequant_dtype=None, restore=None,
+                        snap_run=None):
     """Prefill one page-aligned chunk of one request through its slot's
     gathered row view and scatter the chunk's K/V into its pages.
+    ``restore`` and ``snap_run`` are a snapshot pool's (inference/
+    cache.py): the entry this chunk starts from (negative: none) and the
+    entry each of its pages' end states goes to; None for every other
+    model, whose program has no such arguments.
 
     ``chunk_ids`` is ``[1, chunk]`` (right-padded to a page multiple,
     ``chunk_start`` page-aligned, ``chunk_start + chunk <= cache_len``
@@ -210,7 +216,7 @@ def _chunk_prefill_impl(module, params, pool, state, ptab_row, chunk_ids,
         # (a prefix hit restores the state here, with the K/V)
         first = chunk_start // page_len
         row = chunk_state_view(row, pool, ptab_row[jnp.maximum(first - 1, 0)],
-                               first == 0)
+                               first == 0, slot, restore)
     positions = chunk_start + jnp.arange(chunk_ids.shape[1])
     p_ = param_transform(params) if param_transform is not None else params
     # the chunk's right padding is no token: an expert layer routes it
@@ -228,7 +234,8 @@ def _chunk_prefill_impl(module, params, pool, state, ptab_row, chunk_ids,
                                 (chunk // page_len,))
     pool = scatter_chunk_pages(pool, tok_tree, run)
     if stateful:
-        pool = store_chunk_state(pool, vars_out["cache"], tok_tree, slot, run)
+        pool = store_chunk_state(pool, vars_out["cache"], tok_tree, slot, run,
+                                 snap_run)
 
     last_idx = jnp.clip(end_pos - 1 - chunk_start, 0, chunk - 1)
     last = jax.lax.dynamic_slice_in_dim(logits, last_idx, 1,
@@ -330,11 +337,13 @@ class PagedKVManager:
         compute dtype BEFORE int8 conversion — gathers dequantize back
         to it, so the gathered view always matches what the attention
         path writes into it."""
+        # a model with recurrent state is known by its cache collection:
+        # its pool also keeps each slot's state, and the states at page
+        # ends one a page or in a snapshot pool (inference/cache.py)
+        entries = self.config.snapshot_entries(self._num_slots)
         pool = init_page_pool(self._module, self._params, self.num_pages,
-                              self.page_len)
-        self.dequant_dtype = next(
-            leaf.dtype for leaf in jax.tree.leaves(pool)
-            if getattr(leaf, "ndim", 0) >= 4)
+                              self.page_len, self._num_slots, entries)
+        self.dequant_dtype = kv_leaves(pool)[0].dtype
         # a latent-attention model is known by its cache collection too:
         # a unit of keys with no values beside them
         self.has_latent = has_latent_units(pool)
@@ -342,13 +351,9 @@ class PagedKVManager:
             self.refuse_latent("serving.kv_int8 (quantize_page_pool: int8 "
                                "pages)")
             pool = quantize_page_pool(pool)
-        # a model with recurrent state is known by its cache collection:
-        # its pool also keeps each slot's state and the state at each
-        # page's end (inference/cache.py)
         self.has_state = has_recurrent_state(pool)
-        if self.has_state:
-            pool = add_slot_state(pool, init_cache(
-                self._module, self._params, self._num_slots, self.page_len))
+        self.snapshots = (SnapshotTable(entries)
+                          if has_snapshot_pool(pool) else None)
         self._state_bytes = state_bytes(pool)      # shapes: read once
         # the scatter/gather/kernel paths all key off the scale planes
         # structurally — assert the built pool agrees with the config
@@ -368,8 +373,20 @@ class PagedKVManager:
         leaves the request queued (admission gates on free pages)."""
         prompt_len = int(prompt.shape[0])
         shared: List[int] = []
+        branch = None
         if self.prefix is not None:
             shared = self.prefix.match(prompt)
+            if shared and self.snapshots is not None:
+                # a hit starts from a stored state: it goes as deep as
+                # the deepest matched page whose end has a snapshot, and
+                # where that is short of the match this request takes
+                # the snapshot the match wanted as it passes (the branch:
+                # paging/snapshots.py)
+                depth = next((d for d in range(len(shared), 0, -1)
+                              if self.snapshots.has(shared[d - 1])), 0)
+                if depth < len(shared):
+                    branch = (len(shared) - 1, shared[-1])
+                    shared = shared[:depth]
             if shared:
                 # pin the matched run BEFORE any eviction below: once
                 # deeper leaves are gone the matched nodes themselves
@@ -390,12 +407,67 @@ class PagedKVManager:
             self.prefix.note_admitted(len(shared))
         pages = shared + private
         self._slot_pages[slot] = pages
+        if self.snapshots is not None:
+            self._plan_snapshots(slot, prompt_len, shared, branch)
         row = np.full((self.max_pages,), NULL_PAGE, np.int32)
         row[:len(pages)] = pages
         with _span("serving/page_table_copy", {"slot": slot,
                                                "pages": len(pages)}):
             self.page_table = self.page_table.at[slot].set(row)
         return len(shared) * self.page_len
+
+    def _plan_snapshots(self, slot, prompt_len, shared, branch):
+        """What the slot's chunks will do with the snapshot pool: the
+        entry its first chunk starts from (pinned until that chunk is
+        dispatched), and the page ends it will store — ``{page index of
+        the prompt: physical page the entry is kept under}``. The branch
+        is kept under the prefix cache's page (retained here until it is
+        stored, so the id cannot change hands); the leaf under the
+        slot's own page, which ``publish`` then shares."""
+        restore = shared[-1] if shared else None
+        if restore is not None:
+            self.snapshots.pin(restore)
+        wanted = {}
+        if branch is not None:
+            self.allocator.retain([branch[1]])
+            wanted[branch[0]] = branch[1]
+        leaf = prompt_len // self.page_len - 1
+        if leaf >= len(shared) and leaf not in wanted:
+            wanted[leaf] = self._slot_pages[slot][leaf]
+        self._slot_snapshots[slot] = {
+            "restore": restore, "wanted": wanted, "missed": branch is not None,
+            "held": None if branch is None else branch[1]}
+
+    def chunk_snapshots(self, slot: int, start: int, pages: int):
+        """The snapshot arguments of the slot's chunk program over
+        ``pages`` pages from token ``start``: ``(restore, snap_run)`` —
+        the entry it starts from (-1: its slot's own state, or zeros at
+        position 0) and the entry each page's end state goes to
+        (``NULL_SNAPSHOT``: none wanted, or every entry pinned) — or
+        ``()`` for a model without a snapshot pool, whose program has no
+        such arguments. Called as the chunk is dispatched: the entries
+        are the table's from here."""
+        if self.snapshots is None:
+            return ()
+        plan = self._slot_snapshots[slot]
+        restore = -1
+        if plan["restore"] is not None:
+            restore = self.snapshots.lookup(plan["restore"])
+            self.snapshots.unpin(plan["restore"])
+            plan["restore"] = None
+        first = start // self.page_len
+        run = np.full((pages,), NULL_SNAPSHOT, np.int32)
+        for i in range(pages):
+            page = plan["wanted"].pop(first + i, None)
+            if page is None:
+                continue
+            entry = self.snapshots.take(page)
+            if entry is not None:
+                run[i] = entry
+            if page == plan["held"]:
+                plan["held"] = None
+                self.allocator.release([page])
+        return jnp.int32(restore), jnp.asarray(run)
 
     def publish(self, slot: int, prompt: np.ndarray) -> int:
         """Insert the prompt's full pages into the prefix cache once its
@@ -415,6 +487,13 @@ class PagedKVManager:
         if pages is None:
             return
         self._slot_pages[slot] = None
+        plan = self._slot_snapshots[slot]
+        if plan is not None:            # released before its chunks ran
+            self._slot_snapshots[slot] = None
+            if plan["restore"] is not None:
+                self.snapshots.unpin(plan["restore"])
+            if plan["held"] is not None:
+                self.allocator.release([plan["held"]])
         self.allocator.release(pages)
         with _span("serving/page_table_copy", {"slot": slot, "pages": 0}):
             self.page_table = self.page_table.at[slot].set(
@@ -478,9 +557,9 @@ class PagedKVManager:
         if self.has_state:
             raise NotImplementedError(
                 f"{what} does not carry recurrent state: "
-                f"{type(self._module).__name__} keeps a convolution state "
-                "beside its K/V pages (a slot's, and one at each page's "
-                "end), which this path neither moves nor rolls back")
+                f"{type(self._module).__name__} keeps "
+                f"{describe_state(self.pool)} beside its K/V pages, which "
+                "this path neither moves nor rolls back")
 
     def refuse_latent(self, what: str):
         """What is written for pages of K and V heads is not built for a
@@ -504,6 +583,12 @@ class PagedKVManager:
             return None
         return self._slot_pages[slot][shared_tokens // self.page_len - 1]
 
+    def state_restore_missed(self, slot: int) -> bool:
+        """Whether the slot's admission matched deeper in the prefix
+        cache than a snapshot let it start (its hit was shortened)."""
+        plan = self._slot_snapshots[slot]
+        return bool(plan and plan["missed"])
+
     def reset(self):
         """(Re)build the device pool and every host-side ownership structure
         from scratch — at construction, and on the fault-containment path
@@ -512,20 +597,22 @@ class PagedKVManager:
         recovery every page's contents are stale anyway. Shapes are
         unchanged, so the compiled paged programs stay cached."""
         self.pool = self._build_pool()
-        self.allocator = PageAllocator(self.num_pages)
+        self.allocator = PageAllocator(
+            self.num_pages,
+            on_free=self.snapshots.drop if self.snapshots else None)
         self.prefix = (PrefixCache(self.page_len, self.allocator)
                        if self.config.enable_prefix_cache else None)
         self.page_table = jnp.full((self._num_slots, self.max_pages),
                                    NULL_PAGE, jnp.int32)
         self._slot_pages: List[Optional[List[int]]] = \
             [None] * self._num_slots
+        self._slot_snapshots: List[Optional[dict]] = [None] * self._num_slots
 
     # -- accounting --------------------------------------------------------
     def pool_bytes(self) -> int:
         """Resident K/V bytes of the pool (all attention units)."""
         return sum(int(leaf.size) * leaf.dtype.itemsize
-                   for leaf in jax.tree.leaves(self.pool)
-                   if getattr(leaf, "ndim", 0) >= 4)
+                   for leaf in kv_leaves(self.pool))
 
     def state_bytes(self) -> int:
         """Resident bytes of the recurrent state (0 for a model without)."""
@@ -575,6 +662,11 @@ class PagedKVManager:
         }
         if self.has_state:
             out["state_bytes"] = self.state_bytes()
+        if self.snapshots is not None:
+            out.update(state_snapshots=self.snapshots.entries,
+                       state_snapshots_in_use=self.snapshots.in_use,
+                       state_snapshots_taken=self.snapshots.taken,
+                       state_snapshots_evicted=self.snapshots.evicted)
         if self.prefix is not None:
             out.update(self.prefix.stats())
             out["prefix_hit_rate"] = (self.prefix.hits

@@ -46,8 +46,31 @@ PINNED_BEFORE_ISSUE_37 = {
 }
 
 
+# ``tests/chip_bench/test_iteration_readers.py`` holds PR 41's eighteen
+# metrics to the END of the manifest's ``per_layer`` (``names[-len(ours):]
+# == ours``). The driver's contract for ``BENCHMARK.json`` is positional
+# the other way: "Put new entries at the end of their lists: one put
+# first or in the middle reads as a change to what was there", and a PR
+# that changes what was there is refused before any run. So ISSUE 44's
+# thirteen ``*.h1chat`` entries follow PR 41's, as PR 37's and PR 41's
+# own followed what they found, and that one line cannot hold. The file
+# is the benchmark's: the case is marked here, strictly and by node id.
+# Its other assertions are not let go: ``tests/chip_bench/
+# test_falconh1_cell.py::test_pr_41s_entries_are_one_unbroken_run_and_
+# its_files_its_own`` holds them (the eighteen sorted, one unbroken run,
+# only this cell's entries after it, the ``iterations_*`` files on disk)
+# until the ``benchmark`` PR that mends the line (PERF.md section 7).
+PINNED_BEFORE_ISSUE_44 = {
+    "tests/chip_bench/test_iteration_readers.py::"
+    "test_the_manifest_gained_these_entries_at_its_end_and_nothing_else":
+        "the manifest's per_layer list goes on after PR 41's entries since "
+        "ISSUE 44 (mend: compare an unbroken run, not the list's end)",
+}
+
+
 def pytest_collection_modifyitems(items):
     for item in items:
-        for node, reason in PINNED_BEFORE_ISSUE_37.items():
+        for node, reason in {**PINNED_BEFORE_ISSUE_37,
+                             **PINNED_BEFORE_ISSUE_44}.items():
             if item.nodeid.endswith(node):
                 item.add_marker(pytest.mark.xfail(reason=reason, strict=True))

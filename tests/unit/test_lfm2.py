@@ -634,8 +634,8 @@ def test_a_state_dropped_at_a_chunk_boundary_fails_the_tolerance(
     module, params = model
     sound = cache_mod.chunk_state_view
     monkeypatch.setattr(manager, "chunk_state_view",
-                        lambda cache, pool, page, fresh: sound(
-                            cache, pool, page, jnp.asarray(True)))
+                        lambda cache, pool, page, fresh, *rest: sound(
+                            cache, pool, page, jnp.asarray(True), *rest))
     prompt = _ids(1, 300, seed=5)[0]
     served = Served(module, params, seen, pages=14)
     handle, = served.run(prompt, new_tokens=4)

@@ -374,9 +374,7 @@ def test_lfm2_decode_program_keeps_pages_and_state_where_they_are(
     abort this Mosaic), moves neither the K/V pool nor the state stored
     a page, rewrites only the slots' state, and its scratch stays rows of
     activations with the convolution state in the program."""
-    from deepspeed_tpu.inference.cache import (add_slot_state,
-                                               has_recurrent_state)
-    from deepspeed_tpu.inference.generation import init_cache
+    from deepspeed_tpu.inference.cache import has_recurrent_state
     from deepspeed_tpu.models.lfm2 import LFM2, LFM2Config
     from deepspeed_tpu.ops.pallas import tuning
     monkeypatch.setattr(
@@ -393,9 +391,7 @@ def test_lfm2_decode_program_keeps_pages_and_state_where_they_are(
         jax.random.PRNGKey(0))
 
     def pool():
-        return add_slot_state(
-            init_page_pool(model, params, LFM2_PAGES, PAGE_LEN),
-            init_cache(model, params, SLOTS, PAGE_LEN))
+        return init_page_pool(model, params, LFM2_PAGES, PAGE_LEN, SLOTS)
 
     def on_chip(tree):
         shapes = jax.eval_shape(tree) if callable(tree) else tree
@@ -436,6 +432,96 @@ def test_lfm2_decode_program_keeps_pages_and_state_where_they_are(
     assert "tpu_custom_call" in hlo and hlo_has(compiled, "ragged-dot")
     roots = _roots(_computations(hlo))
     moved = re.compile(r"\[%d,(?:8,64,%d|2,2048)\]" % (LFM2_PAGES, PAGE_LEN))
+    for line in hlo.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%[\w.\-]+ = (\S+) ([\w\-]+)\(", line)
+        if not m or m.group(1).startswith("(") or not moved.search(
+                m.group(1)):
+            continue
+        op = m.group(2)
+        if op == "fusion":
+            op = roots[re.search(r"calls=%([\w.\-]+)", line).group(1)]
+        assert op in IN_PLACE, f"moved by: {line.strip()[:200]}"
+
+
+# the Falcon-H1 cell (configs/falcon-h1-34b-9l-serve.json) at its
+# published widths, two layers deep, its slice of the vocabulary: a Mamba-2
+# mixer of 32 heads x 256 x 128 (4 MB of float32 state a slot a layer)
+# beside 20 query heads on 4 K/V heads of 128; 64 slots of 2048 positions
+H1_SLOTS, H1_PAGES, H1_MAX_PAGES, H1_SNAPSHOTS = 64, 1025, 16, 32
+
+
+def test_falcon_h1_decode_program_updates_the_slot_state_in_place(
+        one_chip, monkeypatch):
+    """Two kinds of state beside each other: the decode program compiled
+    for the chip holds both Mosaic kernels (paged attention over 4 K/V
+    heads of 128, and the mixer's state update), aliases the whole pool —
+    pages, every slot's matrix state (268 MB a layer) and the snapshot
+    pool — and moves none of it: a copy of one layer's slot state would
+    be 268 MB of scratch, where the program takes rows of activations."""
+    from deepspeed_tpu.inference.cache import has_snapshot_pool
+    from deepspeed_tpu.models.falcon_h1 import FalconH1, FalconH1Config
+    from deepspeed_tpu.ops.pallas import tuning
+    monkeypatch.setattr(
+        importlib.import_module("deepspeed_tpu.ops.pallas.paged_attention"),
+        "_interpret", lambda: False)
+    monkeypatch.setattr(
+        importlib.import_module("deepspeed_tpu.ops.pallas.ssm_update"),
+        "on_tpu", lambda: True)
+    model = FalconH1(FalconH1Config(
+        num_hidden_layers=2, vocab_size=32640, max_position_embeddings=2048,
+        dtype=jnp.bfloat16, param_dtype=jnp.bfloat16))
+    import flax.core.meta as flax_meta
+    params = jax.eval_shape(
+        lambda r: flax_meta.unbox(model.init(
+            r, jnp.ones((1, 8), jnp.int32)))["params"],
+        jax.random.PRNGKey(0))
+
+    def on_chip(tree):
+        shapes = jax.eval_shape(tree) if callable(tree) else tree
+        return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=one_chip), shapes)
+
+    slot = lambda dtype: jax.ShapeDtypeStruct((H1_SLOTS,), dtype)
+    state = {"lengths": slot(jnp.int32), "last_token": slot(jnp.int32),
+             "active": slot(jnp.bool_), "remaining": slot(jnp.int32)}
+    pool_shapes = on_chip(lambda: init_page_pool(
+        model, params, H1_PAGES, PAGE_LEN, H1_SLOTS, H1_SNAPSHOTS))
+    assert has_snapshot_pool(pool_shapes)
+    unit = pool_shapes["layers_0"]["mixer"]
+    assert unit["ssm_state"].shape == (H1_SLOTS, 32, 256, 128)
+    assert unit["conv_state"].shape == (H1_SLOTS, 3, 5120)
+    assert unit["snapshots"]["ssm_state"].shape \
+        == (H1_SNAPSHOTS + 1, 32, 256, 128)      # and the null entry
+    assert pool_shapes["layers_1"]["attn"]["cached_key"].shape \
+        == (H1_PAGES, 4, 128, PAGE_LEN)
+    args = (on_chip(params), pool_shapes,
+            on_chip(jax.ShapeDtypeStruct((H1_SLOTS, H1_MAX_PAGES),
+                                         jnp.int32)),
+            on_chip(state), on_chip(lambda: jax.random.PRNGKey(0)),
+            on_chip(jax.ShapeDtypeStruct((), jnp.int32)))
+    static = (32639, 1.0, 0, 1.0, None, True, False, False, True,
+              jnp.bfloat16)
+    tuning.clear_last_dispatch()
+    compiled = jax.jit(
+        _paged_decode_iter_impl, static_argnums=(0, 11, 12, 13, 14, 15, 16),
+        donate_argnums=(2, 4)).lower(model, *args, *static).compile()
+    rec = tuning.last_dispatch("paged_attention")["page%d" % PAGE_LEN]
+    assert rec["impl"] == "kernel"
+    rec, = tuning.last_dispatch("ssm_update").values()
+    assert rec["impl"] == "kernel"
+
+    pool_bytes = sum(x.size * x.dtype.itemsize
+                     for x in jax.tree.leaves(pool_shapes))
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= pool_bytes
+    # rows of activations (64 x 21504 float32 is 5.5 MB): nowhere near a
+    # layer's slot state (268 MB) or one of its snapshots (4 MB)
+    assert mem.temp_size_in_bytes < 48 * 2 ** 20, mem.temp_size_in_bytes
+    hlo = compiled.as_text()
+    assert hlo.count("%ssm_update") >= 2 and "tpu_custom_call" in hlo
+    roots = _roots(_computations(hlo))
+    moved = re.compile(r"\[(?:%d|%d),32,256,128\]|\[%d,4,128,%d\]" % (
+        H1_SLOTS, H1_SNAPSHOTS + 1, H1_PAGES, PAGE_LEN))
     for line in hlo.splitlines():
         m = re.match(r"\s*(?:ROOT )?%[\w.\-]+ = (\S+) ([\w\-]+)\(", line)
         if not m or m.group(1).startswith("(") or not moved.search(
