@@ -12,9 +12,9 @@ Method: a probe fwd+bwd at the requested shape tells us which kernel
 STRUCTURES that shape dispatches to (resident/streamed/monolithic — read
 back via ``tuning.last_dispatch``, so the sweep can never tune a
 structure the shape doesn't use). Then per structure, each candidate is
-injected as a runtime tuning-table entry and the whole fwd (or fwd+bwd)
-is re-traced and timed. Forward structures are timed on the forward
-alone; backward structures on fwd+bwd with the forward winner pinned.
+injected as a runtime tuning-table entry and the kernel's own call is
+re-traced and timed alone: the forward structure on the forward's call,
+the backward structure on the backward's over one forward's residuals.
 
 Everything but the timing numbers is CPU-runnable (interpret-mode
 kernels): ``--trials 1`` with tiny shapes exercises the full plumbing in
@@ -36,12 +36,11 @@ def _divisor_candidates(dim, cap=1024):
 
 
 def candidate_grid(structure, sq, sk):
-    """(block_q, block_k) candidates for one kernel structure.
-    block_k is None for the monolithic backward (whole-K structure)."""
-    bqs = _divisor_candidates(sq)
-    if structure == "bwd_monolithic":
-        return [(bq, None) for bq in bqs]
-    return [(bq, bk) for bq in bqs for bk in _divisor_candidates(sk)]
+    """(block_q, block_k) candidates for one kernel structure: every
+    structure tiles both sides (the one-pass backward too, since its
+    inner loop over k blocks stops at the diagonal: PR 45)."""
+    return [(bq, bk) for bq in _divisor_candidates(sq)
+            for bk in _divisor_candidates(sk)]
 
 
 def _time_it(fn, args, trials, warmup):
@@ -58,69 +57,95 @@ def _time_it(fn, args, trials, warmup):
 
 def sweep_flash_attention(batch, heads, sq, sk, head_dim, dtype="bfloat16",
                           causal=True, trials=3, warmup=1,
-                          max_candidates=None, log=print):
+                          max_candidates=None, calls=1, log=print):
     """Returns {key: entry} tuning entries for every structure the shape
-    dispatches to, each entry carrying the winning blocks + measured ms."""
+    dispatches to, each entry carrying the winning blocks, their ``ms``
+    and every candidate's time under ``swept``.
+
+    The kernels are timed ALONE, in the layout they run in ([batch,
+    heads, seq, head_dim]: no transposes of the public layout around
+    them): the forward structure on the forward's call, the backward
+    structure on the backward's call(s) over the residuals of one
+    forward. ``calls``: that many calls chained in one program (each
+    call's first result is the next one's query, or the next one's
+    cotangent), the time reported per call."""
+    import importlib
+
     import jax
     import jax.numpy as jnp
     from deepspeed_tpu.ops.pallas import flash_attention, tuning
+    fa = importlib.import_module(
+        "deepspeed_tpu.ops.pallas.flash_attention")
 
     dt = jnp.dtype(dtype)
-    ks = jax.random.split(jax.random.PRNGKey(0), 3)
-    q = jax.random.normal(ks[0], (batch, sq, heads, head_dim), dt)
-    k = jax.random.normal(ks[1], (batch, sk, heads, head_dim), dt)
-    v = jax.random.normal(ks[2], (batch, sk, heads, head_dim), dt)
+    ks = jax.random.split(jax.random.PRNGKey(0), 4)
+    q = jax.random.normal(ks[0], (batch, heads, sq, head_dim), dt)
+    k = jax.random.normal(ks[1], (batch, heads, sk, head_dim), dt)
+    v = jax.random.normal(ks[2], (batch, heads, sk, head_dim), dt)
+    g = jax.random.normal(ks[3], (batch, heads, sq, head_dim), dt)
+    scale = head_dim ** -0.5
 
-    fwd = jax.jit(functools.partial(flash_attention, causal=causal))
-    grad = jax.jit(jax.grad(
-        lambda q, k, v: flash_attention(q, k, v, causal=causal)
-        .astype(jnp.float32).sum(), argnums=(0, 1, 2)))
+    def forward(q, k, v):
+        return fa._flash_fwd(q, k, v, None, None, scale, causal, 0.0,
+                             heads, None)
 
-    # probe: which structures does this shape dispatch to?
+    def backward(q, k, v, o, lse, g):
+        return fa._flash_bwd(scale, causal, 0.0, None, heads, False,
+                             (q, k, v, None, None, o, lse), g)[:3]
+
+    # one call's first result is shaped as the operand that the next
+    # call takes from it (o as q, dq as g), so the calls cannot overlap
+    # or be hoisted out of the loop
+    fwd = jax.jit(lambda q, k, v: jax.lax.fori_loop(
+        0, calls, lambda _, q: forward(q, k, v)[0], q))
+    bwd = jax.jit(lambda q, k, v, o, lse, g: jax.lax.fori_loop(
+        0, calls, lambda _, g: backward(q, k, v, o, lse, g)[0], g))
+
+    # probe: which structures does this shape dispatch to? (through the
+    # public entry point, as a model calls it)
     tuning.clear_last_dispatch()
-    jax.block_until_ready(fwd(q, k, v))
-    jax.block_until_ready(grad(q, k, v))
+    bshd = [jnp.swapaxes(t, 1, 2) for t in (q, k, v)]
+    jax.block_until_ready(jax.jit(jax.grad(
+        lambda q, k, v: flash_attention(q, k, v, causal=causal)
+        .astype(jnp.float32).sum(), argnums=(0, 1, 2)))(*bshd))
     dispatched = tuning.last_dispatch()
     fwd_structs = sorted(s for s in dispatched if s.startswith("fwd"))
     bwd_structs = sorted(s for s in dispatched if s.startswith("bwd"))
     log(f"shape b{batch} h{heads} sq{sq} sk{sk} d{head_dim} {dt.name} "
         f"{'causal' if causal else 'full'}: structures "
         f"{fwd_structs + bwd_structs}")
+    o, lse = jax.jit(forward)(q, k, v)
 
     entries = {}
 
-    def run(structure, timed_fn, pinned):
+    def run(structure, timed_fn, args):
         key = dispatched[structure]["key"]
         cands = candidate_grid(structure, sq, sk)
         if max_candidates:
             cands = cands[:max_candidates]
-        best = None
+        swept = []
         for bq, bk in cands:
-            entry = {"block_q": bq}
-            if bk is not None:
-                entry["block_k"] = bk
-            with tuning.tuning_table({**pinned, key: entry}):
+            entry = {"block_q": bq, "block_k": bk}
+            with tuning.tuning_table({key: entry}):
                 jax.clear_caches()   # force a re-trace with the candidate
                 try:
-                    ms = _time_it(timed_fn, (q, k, v), trials, warmup)
+                    ms = _time_it(timed_fn, args, trials, warmup) / calls
                 except Exception as e:  # infeasible tiling = skip, not fail
-                    log(f"  {structure} bq={bq} bk={bk}: infeasible ({e})")
+                    log(f"  {structure} bq={bq} bk={bk}: infeasible "
+                        f"({str(e)[:200]})")
                     continue
-            log(f"  {structure} bq={bq} bk={bk}: {ms:.3f} ms")
-            if best is None or ms < best[1]["ms"]:
-                best = (key, {**entry, "ms": round(ms, 4)})
-        if best is None:
+                ran = tuning.last_dispatch()[structure]
+            log(f"  {structure} bq={bq} bk={bk}: {ms:.3f} ms a call, tiles "
+                f"{ran.get('tiles_visited')}/{ran.get('tiles_total')}")
+            swept.append({**entry, "ms": round(ms, 4)})
+        if not swept:
             raise RuntimeError(f"no feasible candidate for {structure}")
-        entries[best[0]] = best[1]
-        return {best[0]: {k: v for k, v in best[1].items() if k != "ms"}}
+        entries[key] = {**min(swept, key=lambda e: e["ms"]), "swept": swept}
 
-    pinned = {}
     for s in fwd_structs:
-        pinned.update(run(s, fwd, pinned))
+        run(s, fwd, (q, k, v))
     for s in bwd_structs:
-        # time fwd+bwd with the forward winner pinned so the measurement
-        # isolates the backward tiling
-        pinned.update(run(s, grad, pinned))
+        run(s, bwd, (q, k, v, o, lse, g))
     jax.clear_caches()
     return entries
 
@@ -379,8 +404,8 @@ def main(argv=None):
                         "comma-separated (the other rows at length 0; "
                         "default: every row at the full table)")
     p.add_argument("--calls", type=int, default=1,
-                   help="paged sweep: kernel calls chained in one timed "
-                        "program (the time is per call)")
+                   help="kernel calls chained in one timed program (the "
+                        "time is per call)")
     p.add_argument("--shapes", default=",".join(GROUPED_SHAPES),
                    help="grouped_matmul sweep: which of "
                         f"{', '.join(GROUPED_SHAPES)}")
@@ -400,7 +425,7 @@ def main(argv=None):
                 args.batch, args.heads, args.seq, args.kv_seq or args.seq,
                 hd, dtype=args.dtype, causal=not args.no_causal,
                 trials=args.trials, warmup=args.warmup,
-                max_candidates=args.max_candidates))
+                max_candidates=args.max_candidates, calls=args.calls))
     if args.kernel in ("paged_attention", "all"):
         # the serving-shape grid: pages x slots x head-dim (each combo
         # is its own shape key, so one hardware window tunes them all)

@@ -269,8 +269,17 @@ def phase_train(preset, seq, micro, steps, n_layers):
     _check(fwd and bwd, f"flash fwd+bwd structures not both traced: {flash}")
     for s in fwd + bwd:
         _mosaic(flash[s], f"flash_attention/{s}")
-    _say(f"train: flash structures {fwd + bwd}, blocks "
-         f"{ {s: flash[s].get('block_q') for s in fwd + bwd} }")
+    for s in fwd + bwd:
+        rec = flash[s]
+        _say(f"train: flash {s} as run: block_q {rec.get('block_q')}, "
+             f"block_k {rec.get('block_k')}, tiles "
+             f"{rec.get('tiles_visited')}/{rec.get('tiles_total')} a "
+             f"(batch, head), table {rec['source']}")
+        # a causal call of more than one tile a side computes nothing
+        # above the diagonal (PR 45: the one-pass backward too)
+        _check(max(rec["block_q"], rec["block_k"]) >= seq
+               or rec["tiles_visited"] < rec["tiles_total"],
+               f"flash {s} visits every tile of a causal call: {rec}")
     engine.destroy()
 
 

@@ -11,15 +11,33 @@ csrc/transformer/inference/). Design:
   requires; the public API takes BSHD and transposes at dispatch.
 - TWO kernel structures, selected by whether K/V (lane-padded to 128) fit
   VMEM comfortably (~12MB → seq <= ~8k at head_dim 64):
-  * resident: grid (b, h, q_blocks) with K/V whole in VMEM and a
-    dynamic-trip fori_loop over [Bq, Bk] score tiles — fastest at
-    training lengths (measured 82 TFLOPS fwd+bwd @ s1024 on v5e vs 62
-    for the streamed form);
+  * resident: K/V whole in VMEM, loops over [Bq, Bk] score tiles —
+    what training lengths run. Kernel alone on a v5e (PR 45's sweep,
+    benchmarks/kernel_tuning.py, ms a call, forward | backward):
+    [32,12,1024,64] 1.37 | 2.13 (before PR 45: 1.93 | 3.94),
+    [4,16,2048,128] 0.64 | 1.24 (0.93 | 2.63), both at 512x512 tiles;
+    every block pair's time is in flash_tuning_defaults.json's notes;
   * streamed: grid (b, h, q_blocks, k_blocks) with K/V blocks flowing
     through the grid and the online-softmax state in VMEM scratch —
     compiles and runs at any length (16k/32k+).
-- causal mode never computes blocks above the diagonal (dynamic trip
-  counts in resident form, compute-predication in streamed form).
+- causal mode computes no tile above the diagonal, in EVERY structure:
+  the resident forward, the one-pass backward and the two-pass resident
+  backward end their loops at the q block's last visible key, the
+  streamed kernels predicate the grid step's compute. The dispatch
+  records the static count (``tiles_visited`` / ``tiles_total`` of one
+  (batch, head): 36 / 64 at 2048 with 256² tiles, 3 / 4 at 1024 with
+  512²), so the block sizes decide how much is skipped.
+- a (batch, head) of few tiles (``UNROLLED_SCORES_MAX``) is ONE
+  straight-line program in the resident forward and the one-pass
+  backward: grid (b, h), Python loops over q blocks and k tiles, every
+  offset a constant. There the causal mask (two iotas, a compare, a
+  select on the float32 tile) is built only on the tiles the diagonal
+  crosses — which those are is known while the kernel is traced — and
+  nothing is carried through a loop. Longer calls walk fori_loops with
+  dynamic trip counts and mask every tile they visit: splitting such a
+  loop in two (tiles under the diagonal, then the diagonal's) was
+  measured slower than the masks it saved. A mask that is the identity
+  changes no bit, so both forms give the same values to the last bit.
 - ``bias``: ONE additive [b|1, h|1, sq|1, sk] operand covering both the
   reference kernel's attn-mask input and alibi/relative biases (boolean
   masks are folded to 0/-1e30 by the dispatch layer, the same encoding
@@ -38,8 +56,14 @@ csrc/transformer/inference/). Design:
   keep mask drops softmax PROBS (post-normalization, scaled 1/(1-rate)),
   matching the reference's dropout placement; the softmax denominator
   accumulates UN-dropped probabilities.
-- forward emits the log-sum-exp rows; backward is two passes sharing that
-  LSE (no softmax recompute pass): q-major for dQ, k-major for dK/dV.
+- forward emits the log-sum-exp rows and every backward reads them:
+  p = exp(s - lse), no row max, row sum or divide, fully masked rows
+  exactly 0. Through MONOLITHIC_BWD_MAX_SEQ (4096) and without a full
+  [sq, sk] bias the backward is ONE call, grid (b, h): q/do/o and K/V
+  read once, a loop over q blocks and inside it one over k blocks up to
+  the diagonal, delta = rowsum(do∘o) once a q block, dQ written once a
+  q block, dK/dV summed in float32 VMEM scratch a k block at a time.
+  Beyond, two passes share the LSE (q-major for dQ, k-major for dK/dV).
   dBias is computed in the custom_vjp bwd rule as a dense recompute that
   XLA dead-code-eliminates whenever the bias is not being differentiated
   (the common case: masks and alibi).
@@ -59,8 +83,8 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# Hand-picked FALLBACK tilings (swept once on v5e at s1024: the resident
-# fori prefers block_k 512, the streamed grid 1024). The dispatch consults
+# Hand-picked FALLBACK tilings for shapes nobody swept (the two training
+# cells' shapes were, on a v5e: flash_tuning_defaults.json). The dispatch consults
 # the shape-keyed tuning cache (tuning.py — runtime table, then the
 # $DS_TPU_KERNEL_TUNING_CACHE artifact, then the committed default table)
 # FIRST; these constants only apply on a full cache miss.
@@ -173,11 +197,93 @@ def _causal_mask(s, q_off, k_off):
     return jnp.where(col <= row, s, NEG_INF)
 
 
+def _causal_trips(q_off, block_q, block_k, nkb):
+    """(full, trips) of one q block whose first row sees keys up to
+    ``q_off``: k tiles ``[0, full)`` lie wholly at or under the diagonal
+    (their last column ``<= q_off``: the mask is the identity there),
+    tiles ``[full, trips)`` are crossed by it, and tiles from ``trips``
+    on hold no visible key. Python ints in, Python ints out (a program
+    whose loops are unrolled; the dispatch's count); traced in, traced
+    out."""
+    clip = (jnp.clip if isinstance(q_off, jax.Array)
+            else lambda x, lo, hi: max(lo, min(x, hi)))
+    trips = clip((q_off + block_q - 1) // block_k + 1, 1, nkb)
+    return clip((q_off + 1) // block_k, 0, trips), trips
+
+
+def _tiles_visited(sq, sk, block_q, block_k, causal):
+    """(visited, total) [block_q, block_k] tiles of one (batch, head) of
+    a resident structure — the dispatch record's static count of what
+    the causal trip counts leave."""
+    nqb, nkb = sq // block_q, sk // block_k
+    if not causal:
+        return nqb * nkb, nqb * nkb
+    return sum(_causal_trips(i * block_q + sk - sq, block_q, block_k,
+                             nkb)[1] for i in range(nqb)), nqb * nkb
+
+
+# A (batch, head) whose visited tiles hold at most this many scores is ONE
+# straight-line program: Python loops over q blocks and k tiles, every
+# offset a constant, the causal mask built on the diagonal's tiles only.
+# Measured on a v5e (PR 45, kernel alone, backward at [32,12,1024,64]
+# 512x512 | [4,16,2048,128] 512x512 | 256x256): dynamic-trip fori_loops
+# 3.26 | 1.58 | 2.25 ms a call, unrolled 2.15 | 1.26 | 1.33. The bound is
+# VMEM's: Mosaic keeps a float32 tile of every unrolled step (36 tiles of
+# 512², 4096 long, asked for 38.9 MB of the 16 MB scoped limit; 10 of
+# 512² and 36 of 256², 2048 long, fit). Beyond it the loops are
+# fori_loops again.
+UNROLLED_SCORES_MAX = 3 * 2 ** 20
+# what an unrolled call may take of VMEM: the 16 MiB default is 0.2 MB
+# short of 10 tiles of 512² with dropout's hash tiles beside the scores;
+# the v5e holds 128 MiB (ops/pallas/grouped_matmul.py takes 96)
+UNROLLED_VMEM_LIMIT = 48 * 2 ** 20
+
+
+def _unrolled(sq, sk, block_q, block_k, causal):
+    return (_tiles_visited(sq, sk, block_q, block_k, causal)[0]
+            * block_q * block_k <= UNROLLED_SCORES_MAX)
+
+
+def _compiler_params(unrolled):
+    return (pltpu.CompilerParams(vmem_limit_bytes=UNROLLED_VMEM_LIMIT)
+            if unrolled else None)
+
+
+def _span(lo, hi, body, carry):
+    """``carry = body(j, carry)`` for j in [lo, hi): straight-line code
+    over Python ints, a fori_loop over traced bounds."""
+    if isinstance(lo, int) and isinstance(hi, int):
+        for j in range(lo, hi):
+            carry = body(j, carry)
+        return carry
+    return jax.lax.fori_loop(lo, hi, body, carry)
+
+
+def _k_tiles(causal, q_off, block_q, block_k, nkb, tile, init):
+    """Walk one q block's visible k tiles with ``tile(j, carry,
+    masked)``. Where the offsets are Python ints (an unrolled program)
+    the tiles under the diagonal build no mask and only those it crosses
+    do, at no cost: which is which is known as the kernel is traced.
+    Under traced offsets ONE loop masks every tile, as it always did: a
+    second loop for the diagonal's tiles cost more than the masks it
+    saved at every block size (PR 45: the resident forward 1.93 -> 2.43
+    ms a call at [32,12,1024,64] 512x512), and a mask that is the
+    identity changes no bit either way."""
+    if not causal:
+        return _span(0, nkb, functools.partial(tile, masked=False), init)
+    full, trips = _causal_trips(q_off, block_q, block_k, nkb)
+    if not isinstance(q_off, int):
+        return _span(0, trips, functools.partial(tile, masked=True), init)
+    carry = _span(0, full, functools.partial(tile, masked=False), init)
+    return _span(full, trips, functools.partial(tile, masked=True), carry)
+
+
 from ._common import pick_block as _block
 
-# training-length gate for the single-pass resident backward (its [Bq, S]
-# fp32 tiles + fp32 dK/dV accumulators outgrow VMEM beyond this); module
-# constant so tests can lower it to exercise the long-seq structures
+# training-length gate for the single-pass resident backward (q/do/o, K/V,
+# the three results and the fp32 dK/dV accumulators whole in VMEM outgrow
+# it beyond this); module constant so tests can lower it to exercise the
+# long-seq structures
 MONOLITHIC_BWD_MAX_SEQ = 4096
 
 # a full-extent [.., Bq, sk] bias tile shares VMEM with K/V in the
@@ -193,13 +299,21 @@ def _kv_fits_vmem(s, d, itemsize=2):
 def _probs(q, k, lse, scale, causal, q_off, k_off, bias=None):
     """Probability tile from the saved LSE (one matmul, no running
     softmax): p = exp(s - lse); causal-masked, bias-masked (-1e30) and
-    fully-masked (lse = -inf) entries come out exactly 0."""
+    fully-masked (lse = -inf) entries come out exactly 0. ``causal``
+    says whether THIS tile builds the mask (the diagonal's do)."""
     s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
     if bias is not None:
         s = s + bias.astype(jnp.float32)
     if causal:
         s = _causal_mask(s, q_off, k_off)
-    return jnp.where(lse > NEG_INF / 2, jnp.exp(s - lse), 0.0)
+    return jnp.exp(s - _guard_lse(lse))
+
+
+def _guard_lse(lse):
+    """A fully masked row's lse (-1e30) as +1e30, so that its
+    exp(s - lse) is exactly 0 (not exp(-1e30 + 1e30) = 1) with no select
+    on the [Bq, Bk] tile: one on the [Bq, 1] column instead."""
+    return jnp.where(lse > NEG_INF / 2, lse, -NEG_INF)
 
 
 def _online_step(q, k, v, scale, causal, q_off, k_off, acc, m_acc, l_acc,
@@ -245,11 +359,11 @@ def _bwd_tile(p, do, v, delta, scale, keep, inv_keep, q_dtype):
     return ds, pv
 
 
-def _emit_o_lse(acc, m, l, o_ref, lse_ref):
+def _emit_o_lse(acc, m, l, o_ref, lse_ref, rows=slice(None)):
     safe_l = jnp.where(l > 0.0, l, 1.0)   # fully-masked rows -> zeros
-    o_ref[0, 0] = (acc / safe_l).astype(o_ref.dtype)
+    o_ref[0, 0, rows, :] = (acc / safe_l).astype(o_ref.dtype)
     # LSE residual for backward; -inf rows stay -inf so bwd re-zeroes them
-    lse_ref[0, 0] = jnp.where(l > 0.0, m + jnp.log(safe_l), NEG_INF)
+    lse_ref[0, 0, rows, :] = jnp.where(l > 0.0, m + jnp.log(safe_l), NEG_INF)
 
 
 def _unpack_refs(refs, has_bias, has_drop):
@@ -276,35 +390,45 @@ def _bias_rows(bias_ref, bias_q_full, row_ds):
 
 def _fwd_kernel_resident(q_ref, k_ref, v_ref, *refs, scale, causal, block_q,
                          block_k, causal_shift, has_bias, dropout_rate,
-                         total_heads):
+                         total_heads, unrolled):
+    """K/V whole in VMEM. ``unrolled``: grid (b, h), every q block of the
+    (batch, head) in one straight-line program (``UNROLLED_SCORES_MAX``;
+    the bias broadcast over q); else grid (b, h, q_blocks), one q block
+    a step and a fori_loop over its k tiles."""
     has_drop = dropout_rate > 0.0
     bias_ref, sm_ref, o_ref, lse_ref = _unpack_refs(refs, has_bias, has_drop)
-    bi, hi, qi = pl.program_id(0), pl.program_id(1), pl.program_id(2)
-    q = q_ref[0, 0]                                    # [Bq, d] native dtype
-    d = q.shape[-1]
+    bi, hi = pl.program_id(0), pl.program_id(1)
+    d = q_ref.shape[-1]
     nkb = k_ref.shape[2] // block_k
-    q_off = qi * block_q + causal_shift
-    q_abs = qi * block_q                               # dropout coordinates
     inv_keep = 1.0 / (1.0 - dropout_rate) if has_drop else 1.0
 
-    def body(j, carry):
-        ks = pl.ds(j * block_k, block_k)
-        bias = bias_ref[0, 0, :, ks] if has_bias else None
-        keep = (_tile_keep(sm_ref, bi, hi, q_abs, j * block_k,
-                           (block_q, block_k), dropout_rate, total_heads)
-                if has_drop else None)
-        return _online_step(q, k_ref[0, 0, ks, :], v_ref[0, 0, ks, :],
-                            scale, causal, q_off, j * block_k, *carry,
-                            bias=bias, keep=keep, inv_keep=inv_keep)
+    def q_block(qi, rows):
+        q = q_ref[0, 0, rows, :]                       # [Bq, d] native dtype
+        q_off = qi * block_q + causal_shift
+        q_abs = qi * block_q                           # dropout coordinates
 
-    trips = (jnp.clip((q_off + block_q - 1) // block_k + 1, 1, nkb)
-             if causal else nkb)
-    acc, m, l = jax.lax.fori_loop(
-        0, trips, body,
-        (jnp.zeros((block_q, d), jnp.float32),
-         jnp.full((block_q, 1), NEG_INF, jnp.float32),
-         jnp.zeros((block_q, 1), jnp.float32)))
-    _emit_o_lse(acc, m, l, o_ref, lse_ref)
+        def tile(j, carry, masked):
+            ks = pl.ds(j * block_k, block_k)
+            bias = bias_ref[0, 0, :, ks] if has_bias else None
+            keep = (_tile_keep(sm_ref, bi, hi, q_abs, j * block_k,
+                               (block_q, block_k), dropout_rate, total_heads)
+                    if has_drop else None)
+            return _online_step(q, k_ref[0, 0, ks, :], v_ref[0, 0, ks, :],
+                                scale, masked, q_off, j * block_k, *carry,
+                                bias=bias, keep=keep, inv_keep=inv_keep)
+
+        acc, m, l = _k_tiles(
+            causal, q_off, block_q, block_k, nkb, tile,
+            (jnp.zeros((block_q, d), jnp.float32),
+             jnp.full((block_q, 1), NEG_INF, jnp.float32),
+             jnp.zeros((block_q, 1), jnp.float32)))
+        _emit_o_lse(acc, m, l, o_ref, lse_ref, rows)
+
+    if unrolled:
+        for qi in range(q_ref.shape[2] // block_q):
+            q_block(qi, pl.ds(qi * block_q, block_q))
+    else:
+        q_block(pl.program_id(2), slice(None))
 
 
 def _dq_kernel_resident(q_ref, k_ref, v_ref, do_ref, delta_ref, lse_ref,
@@ -323,22 +447,20 @@ def _dq_kernel_resident(q_ref, k_ref, v_ref, do_ref, delta_ref, lse_ref,
     q_abs = qi * block_q
     inv_keep = 1.0 / (1.0 - dropout_rate) if has_drop else 1.0
 
-    def body(j, acc):
+    def tile(j, acc, masked):
         ks = pl.ds(j * block_k, block_k)
         k = k_ref[0, 0, ks, :]
         v = v_ref[0, 0, ks, :]
         bias = bias_ref[0, 0, :, ks] if has_bias else None
-        p = _probs(q, k, lse, scale, causal, q_off, j * block_k, bias=bias)
+        p = _probs(q, k, lse, scale, masked, q_off, j * block_k, bias=bias)
         keep = (_tile_keep(sm_ref, bi, hi, q_abs, j * block_k,
                            (block_q, block_k), dropout_rate, total_heads)
                 if has_drop else None)
         ds, _ = _bwd_tile(p, do, v, delta, scale, keep, inv_keep, q.dtype)
         return acc + jnp.dot(ds, k, preferred_element_type=jnp.float32)
 
-    trips = (jnp.clip((q_off + block_q - 1) // block_k + 1, 1, nkb)
-             if causal else nkb)
-    acc = jax.lax.fori_loop(0, trips, body,
-                            jnp.zeros((block_q, d), jnp.float32))
+    acc = _k_tiles(causal, q_off, block_q, block_k, nkb, tile,
+                   jnp.zeros((block_q, d), jnp.float32))
     dq_ref[0, 0] = acc.astype(dq_ref.dtype)
 
 
@@ -391,59 +513,78 @@ def _dkv_kernel_resident(q_ref, k_ref, v_ref, do_ref, delta_ref, lse_ref,
     dv_ref[0, 0] = dv_acc.astype(dv_ref.dtype)
 
 
-def _bwd_kernel_monolithic(q_ref, k_ref, v_ref, o_ref, do_ref, *refs,
-                           scale, causal, block_q, seq_q, causal_shift,
-                           has_bias, dropout_rate, total_heads):
-    """Single-pass resident backward: grid (b, h); K/V (and dK/dV fp32
-    accumulators) whole in VMEM, one fori over q blocks recomputing the
-    [Bq, S] softmax from (q, k, o). Measured fastest at training lengths
-    (one kernel launch, K/V and q/do each loaded once). Bias here is
-    restricted to broadcast-q ([.., 1, sk]) by the dispatch — a full
-    [sq, sk] bias won't fit VMEM at this structure's lengths."""
+def _bwd_kernel_monolithic(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
+                           *refs, scale, causal, block_q, block_k,
+                           causal_shift, has_bias, dropout_rate, total_heads,
+                           unrolled):
+    """Single-pass resident backward: grid (b, h); q/do/o, K/V, the three
+    results and the fp32 dK/dV accumulators (VMEM scratch) whole in VMEM.
+    A loop over q blocks, and inside it one over the k blocks up to that
+    q block's last visible key: [Bq, Bk] tiles of p = exp(s - lse) from
+    the forward's LSE. dQ accumulates over the inner loop and is written
+    once a q block. dK/dV accumulate TRANSPOSED, [d, sk]: q and do are
+    turned once a q block ([Bq, d], small) and qᵀ·dS, doᵀ·P add into a
+    k block's columns, so the [Bq, Bk] tiles dS and P are never turned
+    (that was two transposes a tile; measured 2.56 -> 2.14 ms a call at
+    [32,12,1024,64]); the two sums are turned back once at the end.
+    ``unrolled``: the loops are Python's (``UNROLLED_SCORES_MAX``) and only
+    the diagonal's tiles build the causal mask. One launch; K/V and
+    q/do/o each loaded once. Bias here is restricted to broadcast-q
+    ([.., 1, sk]) by the dispatch — a full [sq, sk] bias won't fit VMEM
+    at this structure's lengths."""
     has_drop = dropout_rate > 0.0
-    bias_ref, sm_ref, dq_ref, dk_ref, dv_ref = _unpack_refs(
+    bias_ref, sm_ref, dq_ref, dk_ref, dv_ref, dkt_acc, dvt_acc = _unpack_refs(
         refs, has_bias, has_drop)
     bi, hi = pl.program_id(0), pl.program_id(1)
-    k = k_ref[0, 0]                                    # [S, d] native dtype
-    v = v_ref[0, 0]
-    sk = k.shape[0]
+    d = q_ref.shape[-1]
+    nqb, nkb = q_ref.shape[2] // block_q, k_ref.shape[2] // block_k
     inv_keep = 1.0 / (1.0 - dropout_rate) if has_drop else 1.0
+    dkt_acc[...] = jnp.zeros_like(dkt_acc)
+    dvt_acc[...] = jnp.zeros_like(dvt_acc)
 
-    def body(i, carry):
-        dk_acc, dv_acc = carry
+    def q_block(i, _):
         qs = pl.ds(i * block_q, block_q)
         q = q_ref[0, 0, qs, :]                         # [Bq, d]
-        o = o_ref[0, 0, qs, :].astype(jnp.float32)
         do = do_ref[0, 0, qs, :]
+        qt, dot = q.T, do.T                            # [d, Bq]
+        delta = jnp.sum(do.astype(jnp.float32)
+                        * o_ref[0, 0, qs, :].astype(jnp.float32),
+                        axis=-1, keepdims=True)
+        lse = _guard_lse(lse_ref[0, 0, qs, :])
+        q_off = i * block_q + causal_shift
 
-        s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
-        if has_bias:
-            s = s + bias_ref[0, 0, :, :].astype(jnp.float32)   # [1, S]
-        if causal:
-            s = _causal_mask(s, i * block_q + causal_shift, 0)
-        m = jnp.max(s, axis=-1, keepdims=True)
-        # guard fully-masked rows (bias = -1e30 everywhere): m ~ -1e30
-        p_un = jnp.where(m > NEG_INF / 2, jnp.exp(s - m), 0.0)
-        l = jnp.sum(p_un, axis=-1, keepdims=True)
-        p = p_un / jnp.where(l > 0.0, l, 1.0)          # [Bq, S] fp32
+        def tile(j, dq_acc, masked):
+            ks = pl.ds(j * block_k, block_k)
+            k = k_ref[0, 0, ks, :]                     # [Bk, d]
+            s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
+            if has_bias:
+                s = s + bias_ref[0, 0, :, ks].astype(jnp.float32)  # [1, Bk]
+            if masked:  # ds-tpu: lint-ok[TS001] (a Python bool: _k_tiles)
+                s = _causal_mask(s, q_off, j * block_k)
+            p = jnp.exp(s - lse)                       # [Bq, Bk] fp32
+            keep = (_tile_keep(sm_ref, bi, hi, i * block_q, j * block_k,
+                               (block_q, block_k), dropout_rate, total_heads)
+                    if has_drop else None)
+            ds, pv = _bwd_tile(p, do, v_ref[0, 0, ks, :], delta, scale,
+                               keep, inv_keep, q.dtype)
+            dkt_acc[:, ks] += jnp.dot(qt, ds,
+                                      preferred_element_type=jnp.float32)
+            dvt_acc[:, ks] += jnp.dot(dot, pv,
+                                      preferred_element_type=jnp.float32)
+            return dq_acc + jnp.dot(ds, k, preferred_element_type=jnp.float32)
 
-        delta = jnp.sum(do.astype(jnp.float32) * o, axis=-1, keepdims=True)
-        keep = (_tile_keep(sm_ref, bi, hi, i * block_q, 0,
-                           (block_q, sk), dropout_rate, total_heads)
-                if has_drop else None)
-        ds, pv = _bwd_tile(p, do, v, delta, scale, keep, inv_keep, q.dtype)
+        dq_acc = _k_tiles(causal, q_off, block_q, block_k, nkb, tile,
+                          jnp.zeros((block_q, d), jnp.float32))
+        dq_ref[0, 0, qs, :] = dq_acc.astype(dq_ref.dtype)
+        return _
 
-        dq_ref[0, 0, qs, :] = jnp.dot(
-            ds, k, preferred_element_type=jnp.float32).astype(dq_ref.dtype)
-        dk_acc = dk_acc + jnp.dot(ds.T, q, preferred_element_type=jnp.float32)
-        dv_acc = dv_acc + jnp.dot(pv.T, do, preferred_element_type=jnp.float32)
-        return dk_acc, dv_acc
-
-    dk_acc, dv_acc = jax.lax.fori_loop(
-        0, seq_q // block_q, body,
-        (jnp.zeros(k.shape, jnp.float32), jnp.zeros(v.shape, jnp.float32)))
-    dk_ref[0, 0] = dk_acc.astype(dk_ref.dtype)
-    dv_ref[0, 0] = dv_acc.astype(dv_ref.dtype)
+    if unrolled:
+        for i in range(nqb):
+            q_block(i, 0)
+    else:
+        jax.lax.fori_loop(0, nqb, q_block, 0)
+    dk_ref[0, 0] = dkt_acc[...].T.astype(dk_ref.dtype)
+    dv_ref[0, 0] = dvt_acc[...].T.astype(dv_ref.dtype)
 
 
 # ---------------------------------------------------------------------------
@@ -592,6 +733,9 @@ def _resolve_blocks(structure, sq, sk, d, dtype, causal, block_q,
     if fallback_bk is not None:
         bk = _block(sk, min(int(entry.get("block_k", fallback_bk)), sk))
         rec["block_k"] = bk
+        if "streamed" not in structure:
+            rec["tiles_visited"], rec["tiles_total"] = _tiles_visited(
+                sq, sk, bq, bk, causal)
     if record:
         _tuning.record_dispatch(
             "flash_attention", structure, key,
@@ -659,6 +803,80 @@ def _extra_ops(bias, seeds, bias_spec):
     return tuple(ops), tuple(specs)
 
 
+# The two calls the training cells run, each a jit of its own: a step's
+# program reaches the same call several times while it is traced (the
+# primal, the custom_vjp rule, remat's replay), and an unrolled kernel's
+# body is traced a tile at a time — 0.5 s a visit at 2048 with 512² tiles,
+# +1.6 s of a four-chip cell's set-up (PR 45, my chip runs). Traced and
+# lowered once a shape and tiling, the other visits are calls of one
+# function, which XLA inlines. Every decision the tables or a test's
+# monkeypatch can change is a static argument. (Never dispatched by
+# themselves: traced into the programs the registry tracks.)
+
+@functools.partial(jax.jit,  # ds-tpu: lint-ok[CC001]
+                   static_argnames=("scale", "causal", "has_bias",
+                                    "dropout_rate", "total_heads", "block_q",
+                                    "block_k", "unrolled", "interpret"))
+def _fwd_resident_call(q, k, v, bias, seeds, *, block_q, block_k, unrolled,
+                       interpret, **common):
+    b, h, sq, d = q.shape
+    sk = k.shape[2]
+    rows = sq if unrolled else block_q
+    # (the grid has no q axis when unrolled: the one block is block 0)
+    at = lambda bi, hi, qi=0: (bi, hi, qi, 0)
+    whole = lambda bi, hi, qi=0: (bi, hi, 0, 0)
+    bias_spec = None
+    if bias is not None:
+        bias_spec = (_bias_spec2(bias) if unrolled
+                     else _bias_spec3(bias, block_q))
+    extra, extra_specs = _extra_ops(bias, seeds, bias_spec)
+    q_blk = pl.BlockSpec((1, 1, rows, d), at)
+    kv_full = pl.BlockSpec((1, 1, sk, d), whole)
+    return pl.pallas_call(
+        functools.partial(_fwd_kernel_resident, block_q=block_q,
+                          block_k=block_k, causal_shift=sk - sq,
+                          unrolled=unrolled, **common),
+        grid=(b, h) if unrolled else (b, h, sq // block_q),
+        in_specs=[q_blk, kv_full, kv_full, *extra_specs],
+        out_specs=(q_blk, pl.BlockSpec((1, 1, rows, 1), at)),
+        out_shape=(jax.ShapeDtypeStruct(q.shape, q.dtype),
+                   jax.ShapeDtypeStruct((b, h, sq, 1), jnp.float32)),
+        compiler_params=_compiler_params(unrolled),
+        interpret=interpret,
+    )(q, k, v, *extra)
+
+
+@functools.partial(jax.jit,  # ds-tpu: lint-ok[CC001]
+                   static_argnames=("scale", "causal", "has_bias",
+                                    "dropout_rate", "total_heads", "block_q",
+                                    "block_k", "unrolled", "interpret"))
+def _bwd_monolithic_call(q, k, v, o, g, lse, bias, seeds, *, block_q,
+                         block_k, unrolled, interpret, **common):
+    b, h, sq, d = q.shape
+    sk = k.shape[2]
+    extra, extra_specs = _extra_ops(
+        bias, seeds, _bias_spec2(bias) if bias is not None else None)
+    full_q = pl.BlockSpec((1, 1, sq, d), lambda bi, hi: (bi, hi, 0, 0))
+    full_k = pl.BlockSpec((1, 1, sk, d), lambda bi, hi: (bi, hi, 0, 0))
+    stat_q = pl.BlockSpec((1, 1, sq, 1), lambda bi, hi: (bi, hi, 0, 0))
+    return pl.pallas_call(
+        functools.partial(
+            _bwd_kernel_monolithic, block_q=block_q, block_k=block_k,
+            causal_shift=sk - sq, unrolled=unrolled, **common),
+        grid=(b, h),
+        in_specs=[full_q, full_k, full_k, full_q, full_q, stat_q,
+                  *extra_specs],
+        out_specs=(full_q, full_k, full_k),
+        out_shape=(jax.ShapeDtypeStruct(q.shape, q.dtype),
+                   jax.ShapeDtypeStruct(k.shape, k.dtype),
+                   jax.ShapeDtypeStruct(v.shape, v.dtype)),
+        scratch_shapes=[pltpu.VMEM((d, sk), jnp.float32),
+                        pltpu.VMEM((d, sk), jnp.float32)],
+        compiler_params=_compiler_params(unrolled),
+        interpret=interpret,
+    )(q, k, v, o, g, lse, *extra)
+
+
 def _flash_fwd(q, k, v, bias, seeds, scale, causal, dropout_rate,
                total_heads, block_q):
     b, h, sq, d = q.shape
@@ -687,26 +905,14 @@ def _flash_fwd(q, k, v, bias, seeds, scale, causal, dropout_rate,
         block_q, block_k = _resolve_blocks(
             "fwd_streamed", sq, sk, d, q.dtype, causal, caller_bq,
             DEFAULT_BLOCK_Q, STREAMED_BLOCK_K)
-    q_blk3 = pl.BlockSpec((1, 1, block_q, d),
-                          lambda bi, hi, qi: (bi, hi, qi, 0))
-    lse_blk3 = pl.BlockSpec((1, 1, block_q, 1),
-                            lambda bi, hi, qi: (bi, hi, qi, 0))
     if resident:
-        extra, extra_specs = _extra_ops(
-            bias, seeds, _bias_spec3(bias, block_q) if has_bias else None)
-        kv_full = pl.BlockSpec((1, 1, sk, d),
-                               lambda bi, hi, qi: (bi, hi, 0, 0))
-        o, lse = pl.pallas_call(
-            functools.partial(_fwd_kernel_resident, block_q=block_q,
-                              block_k=block_k,
-                              causal_shift=sk - sq, **common),
-            grid=(b, h, sq // block_q),
-            in_specs=[q_blk3, kv_full, kv_full, *extra_specs],
-            out_specs=(q_blk3, lse_blk3),
-            out_shape=out_shape,
-            interpret=_interpret(),
-        )(q, k, v, *extra)
-        return o, lse
+        # few tiles and no [sq, sk] bias: one straight-line program a
+        # (batch, head), grid (b, h); else a q block a grid step
+        unrolled = (not (has_bias and bias.shape[2] > 1)
+                    and _unrolled(sq, sk, block_q, block_k, causal))
+        return _fwd_resident_call(
+            q, k, v, bias, seeds, block_q=block_q, block_k=block_k,
+            unrolled=unrolled, interpret=_interpret(), **common)
     nkb = sk // block_k
     extra, extra_specs = _extra_ops(
         bias, seeds,
@@ -795,42 +1001,20 @@ def _flash_bwd(scale, causal, dropout_rate, block_q, total_heads,
     dseeds = (np.zeros(seeds.shape, jax.dtypes.float0)
               if seeds is not None else None)
 
-    # Training lengths: the single-pass resident backward wins (one
-    # launch; K/V, q, do each read once; measured best 125M e2e on v5e).
-    # Its VMEM budget: K/V + fp32 dK/dV accumulators + 3 [Bq, S] fp32
-    # tiles — comfortable through 4k. A full-extent bias can't ride in
-    # this structure (its [sq, sk] tile outgrows VMEM) — two-pass then.
+    # Training lengths: the single-pass resident backward (one launch;
+    # K/V, q, do, o each read once). Its VMEM budget: those and the
+    # results whole, fp32 dK/dV accumulators, a few [Bq, Bk] fp32 tiles.
+    # A full-extent bias can't ride in this structure (its [sq, sk] tile
+    # outgrows VMEM) — two-pass then.
     if (sk <= MONOLITHIC_BWD_MAX_SEQ and sq <= MONOLITHIC_BWD_MAX_SEQ
             and not bias_q_full):
-        entry, key, source = _tuning.lookup(
-            "flash_attention", "bwd_monolithic", sq=sq, sk=sk, d=d,
-            dtype=q.dtype, causal=causal)
-        want = (block_q if block_q is not None
-                else int(entry.get("block_q", DEFAULT_BLOCK_Q)))
-        # VMEM cap on the [Bq, S] fp32 score tiles stays authoritative
-        # over any cache entry
-        cap = max(128, (2 ** 19 // max(sk, 1)) // 128 * 128)
-        bq = math.gcd(sq, min(want, sq, cap))
-        if bq % 8 != 0:
-            bq = sq
-        _tuning.record_dispatch(
-            "flash_attention", "bwd_monolithic", key,
-            "caller" if block_q is not None else source, block_q=bq)
-        extra, extra_specs = _extra_ops(
-            bias, seeds, _bias_spec2(bias) if has_bias else None)
-        full_q = pl.BlockSpec((1, 1, sq, d), lambda bi, hi: (bi, hi, 0, 0))
-        full_k = pl.BlockSpec((1, 1, sk, d), lambda bi, hi: (bi, hi, 0, 0))
-        dq, dk, dv = pl.pallas_call(
-            functools.partial(_bwd_kernel_monolithic, block_q=bq, seq_q=sq,
-                              causal_shift=sk - sq, **common),
-            grid=(b, h),
-            in_specs=[full_q, full_k, full_k, full_q, full_q, *extra_specs],
-            out_specs=(full_q, full_k, full_k),
-            out_shape=(jax.ShapeDtypeStruct(q.shape, q.dtype),
-                       jax.ShapeDtypeStruct(k.shape, k.dtype),
-                       jax.ShapeDtypeStruct(v.shape, v.dtype)),
-            interpret=_interpret(),
-        )(q, k, v, o, g, *extra)
+        bq, bk = _resolve_blocks(
+            "bwd_monolithic", sq, sk, d, q.dtype, causal, block_q,
+            DEFAULT_BLOCK_Q, RESIDENT_BLOCK_K)
+        dq, dk, dv = _bwd_monolithic_call(
+            q, k, v, o, g, lse, bias, seeds, block_q=bq, block_k=bk,
+            unrolled=_unrolled(sq, sk, bq, bk, causal),
+            interpret=_interpret(), **common)
         return (dq, dk, dv, dbias, dseeds)
 
     caller_bq = block_q

@@ -349,3 +349,197 @@ class TestUlyssesFlashDropout:
             assert f"{B},{H},{S},{S}" not in hlo
         finally:
             set_global_mesh(None)
+
+
+# ---------------------------------------------------------------------------
+# the one-pass backward (PR 45): [Bq, Bk] tiles up to the diagonal, p from
+# the forward's LSE, the causal mask on the diagonal's tiles only
+# ---------------------------------------------------------------------------
+
+from deepspeed_tpu.ops.pallas import tuning
+
+OP_S = 512
+# (block_q, block_k): 1, 2 and 4 tiles a side of 512, and the two uneven
+# pairs (several diagonal tiles a q block; a diagonal tile wider than it)
+OP_BLOCKS = [(512, 512), (256, 256), (128, 128), (256, 128), (128, 256)]
+OP_VARIANTS = ["causal", "full", "shift", "bias_masked_rows", "dropout"]
+
+
+def _one_pass_case(variant, d, dtype=jnp.float32, seed=21):
+    """(q, k, v, flash kwargs, dense kwargs, rows the dense oracle
+    covers) of one variant. ``shift``: sk > sq, so causal_shift > 0.
+    ``bias_masked_rows``: a [b, 1, 1, sk] bias that pads batch 0's tail
+    and masks EVERY key of batch 1 — rows the kernel owes exact zeros
+    and the dense softmax (uniform there) cannot check."""
+    rng = np.random.default_rng(seed)
+    b, h = 2, 2
+    sq = OP_S // 2 if variant == "shift" else OP_S
+    mk = lambda s: jnp.asarray(rng.standard_normal((b, s, h, d)), dtype)
+    q, k, v = mk(sq), mk(OP_S), mk(OP_S)
+    causal = variant != "full"
+    fkw, dkw, checked = dict(causal=causal), dict(causal=causal), slice(None)
+    if variant == "bias_masked_rows":
+        mask = jnp.ones((b, 1, 1, OP_S), bool).at[0, :, :, -37:].set(False)
+        mask = mask.at[1].set(False)
+        fkw["bias"] = jnp.where(mask, 0.0, fa.NEG_INF).astype(jnp.float32)
+        dkw["mask"] = mask
+        checked = slice(0, 1)
+    if variant == "dropout":
+        fkw.update(dropout_rate=RATE, dropout_rng=KEY)
+        dkw.update(dropout_rate=RATE, deterministic=False,
+                   dropout_mask=fa.attention_dropout_keep(
+                       KEY, RATE, (b, h, sq, OP_S)))
+    return q, k, v, fkw, dkw, checked
+
+
+def _grads(fn, q, k, v):
+    return jax.grad(lambda *a: (fn(*a).astype(jnp.float32) ** 2).sum(),
+                    argnums=(0, 1, 2))(q, k, v)
+
+
+def _bwd_table(q, k, causal, block_q, block_k, structure="bwd_monolithic"):
+    key = tuning.make_key("flash_attention", structure, sq=q.shape[1],
+                          sk=k.shape[1], d=q.shape[-1], dtype=q.dtype,
+                          causal=causal)
+    return tuning.tuning_table({key: {"block_q": block_q,
+                                      "block_k": block_k}})
+
+
+@pytest.mark.parametrize("d", [64, 128])
+@pytest.mark.parametrize("variant", OP_VARIANTS)
+@pytest.mark.parametrize("blocks", OP_BLOCKS,
+                         ids=lambda b: f"{b[0]}x{b[1]}")
+def test_one_pass_backward_matches_dense(blocks, variant, d):
+    q, k, v, fkw, dkw, checked = _one_pass_case(variant, d)
+    tuning.clear_last_dispatch()
+    with _bwd_table(q, k, fkw["causal"], *blocks):
+        got = _grads(lambda *a: fa.flash_attention(*a, **fkw), q, k, v)
+    rec = tuning.last_dispatch()["bwd_monolithic"]
+    bq = min(blocks[0], q.shape[1])
+    assert (rec["block_q"], rec["block_k"]) == (bq, blocks[1])
+    want = _grads(lambda *a: _reference_attention(*a, **dkw), q, k, v)
+    for name, a, g in zip("qkv", want, got):
+        np.testing.assert_allclose(
+            np.asarray(g[checked]), np.asarray(a[checked]), rtol=2e-4,
+            atol=2e-4, err_msg=f"d{name} ({variant}, {blocks}, d{d})")
+        if variant == "bias_masked_rows":
+            # batch 1 sees no key at all: exact zeros, never NaN
+            np.testing.assert_array_equal(np.asarray(g[1]), 0.0)
+
+
+@pytest.mark.parametrize("variant", OP_VARIANTS)
+def test_one_pass_backward_matches_two_pass(variant, monkeypatch):
+    """Both backwards form p = exp(s - lse) from the forward's LSE on
+    the same tiles, so they agree to float32 rounding (the sums over k
+    tiles are taken in another order), far inside the dense oracle's
+    tolerance."""
+    q, k, v, fkw, _, _ = _one_pass_case(variant, 64, seed=22)
+    run = lambda: _grads(lambda *a: fa.flash_attention(*a, **fkw), q, k, v)
+    tuning.clear_last_dispatch()
+    with _bwd_table(q, k, fkw["causal"], 128, 256):
+        one = run()
+    assert "bwd_resident" not in tuning.last_dispatch()
+    monkeypatch.setattr(fa, "MONOLITHIC_BWD_MAX_SEQ", 128)
+    tuning.clear_last_dispatch()
+    with _bwd_table(q, k, fkw["causal"], 128, 256, "bwd_resident"):
+        two = run()
+    assert "bwd_monolithic" not in tuning.last_dispatch()
+    for name, a, g in zip("qkv", two, one):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(a), rtol=2e-6,
+                                   atol=2e-6, err_msg=f"d{name} ({variant})")
+
+
+def test_one_pass_backward_bf16():
+    """The training cells' call: bf16 operands, float32 accumulation,
+    against the float32 dense oracle at bf16 tolerance."""
+    q, k, v, fkw, dkw, _ = _one_pass_case("causal", 64, jnp.bfloat16)
+    with _bwd_table(q, k, True, 256, 128):
+        got = _grads(lambda *a: fa.flash_attention(*a, **fkw), q, k, v)
+    want = _grads(lambda *a: _reference_attention(*a, **dkw),
+                  *(t.astype(jnp.float32) for t in (q, k, v)))
+    for name, a, g in zip("qkv", want, got):
+        assert g.dtype == jnp.bfloat16
+        np.testing.assert_allclose(np.asarray(g, np.float32), np.asarray(a),
+                                   rtol=0.1, atol=0.1, err_msg=f"d{name}")
+
+
+@pytest.mark.parametrize("shift", [0, 256], ids=["square", "sk_gt_sq"])
+@pytest.mark.parametrize("blocks", [(128, 128), (256, 128), (128, 256)],
+                         ids=lambda b: f"{b[0]}x{b[1]}")
+def test_forward_mask_on_diagonal_only_is_bit_identical(blocks, shift):
+    """Skipping the mask on a tile it would leave unchanged changes no
+    bit: the resident forward's ``o`` and ``lse`` equal ``_online_step``
+    run with ``_causal_mask`` built on EVERY visited tile (the parent's
+    forward), to the last bit."""
+    bq, bk = blocks
+    rng = np.random.default_rng(23)
+    sq, sk, d = 512 - shift, 512, 64
+    q = jnp.asarray(rng.standard_normal((1, 2, sq, d)), jnp.bfloat16)
+    k = jnp.asarray(rng.standard_normal((1, 2, sk, d)), jnp.bfloat16)
+    v = jnp.asarray(rng.standard_normal((1, 2, sk, d)), jnp.bfloat16)
+    scale = d ** -0.5
+    key = tuning.make_key("flash_attention", "fwd_resident", sq=sq, sk=sk,
+                          d=d, dtype=q.dtype, causal=True)
+    with tuning.tuning_table({key: {"block_q": bq, "block_k": bk}}):
+        o, lse = fa._flash_fwd(q, k, v, None, None, scale, True, 0.0, 2,
+                               None)
+    rec = tuning.last_dispatch()["fwd_resident"]
+    assert (rec["block_q"], rec["block_k"]) == blocks
+    trips = [min((i * bq + shift + bq - 1) // bk + 1, sk // bk)
+             for i in range(sq // bq)]
+    assert rec["tiles_visited"] == sum(trips)
+    assert rec["tiles_total"] == len(trips) * (sk // bk)
+
+    @jax.jit
+    def masked_everywhere(q, k, v):
+        rows_o, rows_lse = [], []
+        for i in range(sq // bq):
+            q_off = i * bq + sk - sq
+            carry = (jnp.zeros((bq, d), jnp.float32),
+                     jnp.full((bq, 1), fa.NEG_INF, jnp.float32),
+                     jnp.zeros((bq, 1), jnp.float32))
+            for j in range(trips[i]):
+                carry = fa._online_step(
+                    q[i * bq:(i + 1) * bq], k[j * bk:(j + 1) * bk],
+                    v[j * bk:(j + 1) * bk], scale, True, q_off, j * bk,
+                    *carry)
+            acc, m, l = carry
+            rows_o.append((acc / l).astype(q.dtype))
+            rows_lse.append(m + jnp.log(l))
+        return jnp.concatenate(rows_o), jnp.concatenate(rows_lse)
+
+    for b_, h_ in [(0, 0), (0, 1)]:
+        want_o, want_lse = masked_everywhere(q[b_, h_], k[b_, h_], v[b_, h_])
+        np.testing.assert_array_equal(
+            np.asarray(o[b_, h_], np.float32), np.asarray(want_o, np.float32))
+        np.testing.assert_array_equal(np.asarray(lse[b_, h_]),
+                                      np.asarray(want_lse))
+
+
+@pytest.mark.parametrize("variant", OP_VARIANTS)
+def test_fori_loops_equal_the_unrolled_program_bit_for_bit(variant,
+                                                           monkeypatch):
+    """A (batch, head) of few tiles is straight-line code that masks the
+    diagonal's tiles only; one of many walks fori_loops and masks every
+    tile it visits. The same tiles in the same order, and a mask that is
+    the identity changes no bit: forward and gradients are equal to the
+    last bit."""
+    q, k, v, fkw, _, _ = _one_pass_case(variant, 64, seed=24)
+    keys = {s: tuning.make_key(
+        "flash_attention", s, sq=q.shape[1], sk=k.shape[1], d=64,
+        dtype=q.dtype, causal=fkw["causal"])
+        for s in ("fwd_resident", "bwd_monolithic")}
+    table = {key: {"block_q": 128, "block_k": 128} for key in keys.values()}
+
+    def run():
+        with tuning.tuning_table(table):
+            return (fa.flash_attention(q, k, v, **fkw),
+                    *_grads(lambda *a: fa.flash_attention(*a, **fkw),
+                            q, k, v))
+
+    assert fa._unrolled(q.shape[1], OP_S, 128, 128, fkw["causal"])
+    unrolled = run()
+    monkeypatch.setattr(fa, "UNROLLED_SCORES_MAX", 0)
+    for name, a, b_ in zip(("o", "dq", "dk", "dv"), unrolled, run()):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b_),
+                                      err_msg=f"{name} ({variant})")

@@ -62,9 +62,9 @@ class TestCacheLayers:
                                    rtol=5e-3, atol=5e-3)
 
     def test_miss_falls_back_to_committed_defaults(self):
-        # bf16 s1024 d64 causal is a committed default-table entry
+        # bf16 s1024 d128 causal is a committed (hand-seeded) entry
         entry, key, source = tuning.lookup(
-            "flash_attention", "fwd_resident", sq=1024, sk=1024, d=64,
+            "flash_attention", "fwd_resident", sq=1024, sk=1024, d=128,
             dtype=jnp.bfloat16, causal=True)
         assert source == "defaults"
         assert entry["block_q"] == 512 and entry["block_k"] == 512
@@ -149,6 +149,79 @@ class TestBwdStructures:
             np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                        rtol=1e-4, atol=1e-4)
 
+    def test_bwd_monolithic_key_carries_block_k(self):
+        """The one-pass backward's inner loop walks k blocks, so its
+        table entry tiles both sides; an entry without ``block_k`` (an
+        artifact of before PR 45) falls back to the resident constant."""
+        q, k, v = _qkv(512)
+        key = tuning.make_key("flash_attention", "bwd_monolithic",
+                              sq=512, sk=512, d=64, dtype=q.dtype,
+                              causal=True)
+        grad = jax.grad(lambda q, k, v: flash_attention(
+            q, k, v, causal=True).sum(), argnums=(0, 1, 2))
+        with tuning.tuning_table({key: {"block_q": 256, "block_k": 128}}):
+            grad(q, k, v)
+        disp = tuning.last_dispatch()["bwd_monolithic"]
+        assert disp["source"] == "runtime"
+        assert (disp["block_q"], disp["block_k"]) == (256, 128)
+        with tuning.tuning_table({key: {"block_q": 256}}):
+            grad(q, k, v)
+        disp = tuning.last_dispatch()["bwd_monolithic"]
+        assert (disp["block_q"], disp["block_k"]) == (
+            256, fa_mod.RESIDENT_BLOCK_K)
+
+    @pytest.mark.parametrize("sq,sk,bq,bk,causal", [
+        (512, 512, 128, 128, True), (512, 512, 256, 128, True),
+        (512, 512, 128, 256, True), (512, 512, 512, 512, True),
+        (256, 512, 128, 128, True), (256, 512, 128, 256, True),
+        (512, 512, 128, 256, False)],
+        ids=lambda x: str(x))
+    def test_dispatch_records_the_tiles_it_visits(self, sq, sk, bq, bk,
+                                                  causal):
+        """``tiles_visited`` / ``tiles_total`` of one (batch, head)
+        program, forward and backward, equal a count made here from the
+        shape: a q block's k tiles end at its last row's last visible
+        key (``causal_shift`` = sk - sq)."""
+        k1, k2, k3 = jax.random.split(jax.random.PRNGKey(1), 3)
+        q = jax.random.normal(k1, (1, sq, 1, 64), jnp.float32)
+        k = jax.random.normal(k2, (1, sk, 1, 64), jnp.float32)
+        v = jax.random.normal(k3, (1, sk, 1, 64), jnp.float32)
+        kw = dict(sq=sq, sk=sk, d=64, dtype=q.dtype, causal=causal)
+        table = {tuning.make_key("flash_attention", s, **kw):
+                 {"block_q": bq, "block_k": bk}
+                 for s in ("fwd_resident", "bwd_monolithic")}
+        with tuning.tuning_table(table):
+            jax.grad(lambda q: flash_attention(q, k, v, causal=causal)
+                     .sum())(q)
+        visited = 0
+        for i in range(sq // bq):
+            last_key = (i + 1) * bq - 1 + (sk - sq) if causal else sk - 1
+            visited += sum(1 for j in range(sk // bk) if j * bk <= last_key)
+        total = (sq // bq) * (sk // bk)
+        for structure in ("fwd_resident", "bwd_monolithic"):
+            disp = tuning.last_dispatch()[structure]
+            assert (disp["block_q"], disp["block_k"]) == (bq, bk)
+            assert (disp["tiles_visited"], disp["tiles_total"]) == (
+                visited, total), (structure, disp)
+
+    @pytest.mark.parametrize("shape", ["sq1024_sk1024_d64",
+                                       "sq2048_sk2048_d128"])
+    @pytest.mark.parametrize("structure", ["fwd_resident",
+                                           "bwd_monolithic"])
+    def test_training_cells_entries_are_measured(self, structure, shape):
+        """The four entries the two training cells dispatch
+        (``train-125m-zero1``: [32,12,1024,64]; ``train-1p3b-zero3-4chip``:
+        [4,16,2048,128] a chip) were swept on the chip, kernel alone:
+        a time, the device that gave it, and the whole row in a note."""
+        art = tuning.load_artifact(tuning.DEFAULTS_PATH)
+        e = art["entries"][
+            f"flash_attention/{structure}/{shape}_bfloat16_causal"]
+        assert isinstance(e["ms"], float) and e["ms"] > 0
+        assert "hand-seeded" not in e["device"] and "v5" in e["device"]
+        assert isinstance(e["block_q"], int) and isinstance(
+            e["block_k"], int)
+        assert "PR 45" in e["note"]
+
     def test_bwd_two_pass_consults_cache(self, monkeypatch):
         # force past the monolithic gate to reach the two-pass resident bwd
         monkeypatch.setattr(fa_mod, "MONOLITHIC_BWD_MAX_SEQ", 128)
@@ -192,8 +265,9 @@ class TestSweepHarness:
         from benchmarks.kernel_tuning import candidate_grid
         for bq, bk in candidate_grid("fwd_resident", 384, 384):
             assert 384 % bq == 0 and 384 % bk == 0
+        # the one-pass backward tiles the keys too (PR 45): pairs
         assert candidate_grid("bwd_monolithic", 256, 256) == [
-            (256, None), (128, None)]
+            (256, 256), (256, 128), (128, 256), (128, 128)]
 
     def test_paged_sweep_times_the_rows_that_decode(self):
         """``lengths`` names the rows that decode (the others are handed

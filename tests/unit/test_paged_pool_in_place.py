@@ -619,3 +619,44 @@ def test_kanana_decode_program_walks_the_latent_pool_in_place(
         if op == "fusion":
             op = roots[re.search(r"calls=%([\w.\-]+)", line).group(1)]
         assert op in IN_PLACE, f"moved by: {line.strip()[:200]}"
+
+
+# ---------------------------------------------------------------------------
+# the training cells' flash calls (PR 45): Mosaic takes them at the cells'
+# shapes with the committed table's blocks — an unrolled program keeps a
+# float32 tile of every step in VMEM, and 16 MB is the scoped limit
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("shape", [
+    (32, 12, 1024, 64),      # train-125m-zero1
+    (4, 16, 2048, 128),      # train-1p3b-zero3-4chip, a chip's share
+    (8, 16, 2048, 128),      # the same at micro 8 (ROADMAP 1.0c)
+    (1, 8, 4096, 128),       # too many scores to unroll: fori_loops
+], ids=lambda x: "x".join(map(str, x)))
+def test_flash_training_calls_compile_for_the_chip(one_chip, monkeypatch,
+                                                   fresh_traces, shape):
+    from deepspeed_tpu.ops.pallas import tuning
+    fa = importlib.import_module("deepspeed_tpu.ops.pallas.flash_attention")
+    monkeypatch.setattr(fa, "_interpret", lambda: False)
+    b, h, s, d = shape
+    x = jax.ShapeDtypeStruct((b, s, h, d), jnp.bfloat16, sharding=one_chip)
+    tuning.clear_last_dispatch()
+    compiled = jax.jit(jax.grad(
+        lambda q, k, v: fa.flash_attention(q, k, v, causal=True)
+        .astype(jnp.float32).sum(), argnums=(0, 1, 2))).lower(
+            x, x, x).compile()
+    rec = tuning.last_dispatch()
+    assert set(rec) == {"fwd_resident", "bwd_monolithic"}
+    for r in rec.values():
+        assert r["source"] == "defaults"
+        assert r["tiles_visited"] < r["tiles_total"], r
+        assert fa._unrolled(s, s, r["block_q"], r["block_k"], True) == (
+            s < 4096)
+    # one forward and one backward Mosaic call; the backward's result
+    # holds three bf16[B,H,S,D] and no other 4-D bf16 array is a
+    # Mosaic call's result (what the benchmark's trace reader counts)
+    calls = [l for l in compiled.as_text().splitlines()
+             if 'custom_call_target="tpu_custom_call"' in l]
+    bhsd = "bf16[%d,%d,%d,%d]" % (b, h, s, d)
+    results = sorted(l.split(" custom-call(")[0].count(bhsd) for l in calls)
+    assert results == [1, 3], results
