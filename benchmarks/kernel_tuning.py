@@ -150,12 +150,17 @@ def sweep_flash_attention(batch, heads, sq, sk, head_dim, dtype="bfloat16",
     return entries
 
 
-def _paged_candidates(heads, page_len, max_pages, max_candidates=None):
+def _paged_candidates(kv_heads, group, dtype, page_len, max_pages,
+                      max_candidates=None):
     """(block_k tokens, head_block) candidates for the paged decode
     kernel: page_len multiples up to the table width (the DMA block the
-    kernel double-buffers) crossed with head-tile divisors."""
+    kernel double-buffers) crossed with the K/V heads a grid step may
+    take — what the dispatcher makes of 8, 4, 2 and 1 for this pool's
+    type and ``group`` query heads a K/V head."""
+    from deepspeed_tpu.ops.pallas.paged_attention import step_head_block
     bks = [page_len * n for n in (1, 2, 4, 8) if n <= max_pages]
-    hbs = [h for h in (8, 4, 2, 1) if heads % h == 0]
+    hbs = sorted({step_head_block(kv_heads, group, dtype, h)
+                  for h in (8, 4, 2, 1)}, reverse=True)
     cands = [(bk, hb) for bk in bks for hb in hbs]
     return cands[:max_candidates] if max_candidates else cands
 
@@ -163,7 +168,7 @@ def _paged_candidates(heads, page_len, max_pages, max_candidates=None):
 def sweep_paged_attention(slots, heads, head_dim, page_len, max_pages,
                           dtype="float32", kv_int8=False, lengths=None,
                           calls=1, trials=3, warmup=1, max_candidates=None,
-                          log=print):
+                          kv_heads=None, log=print):
     """Time candidate (block_k, head_block) tilings of the paged
     decode-attention kernel at one (slots x pages x head-dim) serving
     shape; returns {key: entry} in the shared tuning-artifact format
@@ -177,12 +182,21 @@ def sweep_paged_attention(slots, heads, head_dim, page_len, max_pages,
     in one program (each call's output is the next one's query), the
     time reported per call: a call of tens of microseconds is not timed
     by a host clock around one dispatch. The query and the current
-    token's K/V are in the pool's type, as a model hands them."""
+    token's K/V are in the pool's type, as a model hands them.
+    ``kv_heads``: the pool's heads where query heads are grouped on them
+    (default: one K/V head a query head); a candidate's ``rows`` are the
+    query-head rows of its grid step, and ``bytes_us`` is what the valid
+    K and V columns take at the HBM's peak."""
     import jax
     import jax.numpy as jnp
     from deepspeed_tpu.ops.pallas import paged_attention, tuning
     from deepspeed_tpu.ops.pallas.paged_attention import KERNEL
 
+    kv_heads = kv_heads or heads
+    if heads % kv_heads:
+        raise ValueError(f"{heads} query heads on {kv_heads} K/V heads: not "
+                         "a whole group each")
+    group = heads // kv_heads
     full = max_pages * page_len - 1
     if lengths is None:
         lengths = [full] * slots
@@ -192,8 +206,9 @@ def sweep_paged_attention(slots, heads, head_dim, page_len, max_pages,
     num_pages = slots * max_pages + 1
     ks = jax.random.split(jax.random.PRNGKey(0), 5)
     dt = jnp.dtype(dtype)
-    kp = jax.random.normal(ks[0], (num_pages, heads, head_dim, page_len), dt)
-    vp = jax.random.normal(ks[1], (num_pages, heads, head_dim, page_len), dt)
+    pool = (num_pages, kv_heads, head_dim, page_len)
+    kp = jax.random.normal(ks[0], pool, dt)
+    vp = jax.random.normal(ks[1], pool, dt)
     scales = {}
     if kv_int8:
         # THE scatter-side quantization rule (inference/cache.py) so the
@@ -207,8 +222,8 @@ def sweep_paged_attention(slots, heads, head_dim, page_len, max_pages,
     lengths = jnp.asarray(list(lengths) + [0] * (slots - len(lengths)),
                           jnp.int32)
     q = jax.random.normal(ks[2], (slots, 1, heads, head_dim), dt)
-    kn = jax.random.normal(ks[3], (slots, heads, head_dim, 1), dt)
-    vn = jax.random.normal(ks[4], (slots, heads, head_dim, 1), dt)
+    kn = jax.random.normal(ks[3], (slots, kv_heads, head_dim, 1), dt)
+    vn = jax.random.normal(ks[4], (slots, kv_heads, head_dim, 1), dt)
 
     def chain(q, *rest):
         return jax.lax.fori_loop(0, calls, lambda _, q: paged_attention(
@@ -221,13 +236,18 @@ def sweep_paged_attention(slots, heads, head_dim, page_len, max_pages,
     dispatched = tuning.last_dispatch(KERNEL)
     structure = f"page{page_len}"
     key = dispatched[structure]["key"]
-    log(f"paged_attention slots{slots} h{heads} d{head_dim} "
+    # K and V, the columns under the lengths (an int8 pool's scale
+    # planes, a 64th of it at most, are left out)
+    least = (2 * int(lengths.sum()) * kv_heads * head_dim * kp.dtype.itemsize
+             / HBM_BYTES_PER_S * 1e6)
+    log(f"paged_attention slots{slots} h{heads} on {kv_heads} d{head_dim} "
         f"pages{max_pages}x{page_len} {dt.name}"
-        f"{' int8' if kv_int8 else ''}: key {key}")
+        f"{' int8' if kv_int8 else ''}: key {key}; the valid bytes' time "
+        f"{least:.1f} us")
 
     swept = []
-    for bk, hb in _paged_candidates(heads, page_len, max_pages,
-                                    max_candidates):
+    for bk, hb in _paged_candidates(kv_heads, group, kp.dtype, page_len,
+                                    max_pages, max_candidates):
         entry = {"block_k": bk, "head_block": hb}
         with tuning.tuning_table({key: entry}):
             jax.clear_caches()   # force a re-trace with the candidate
@@ -236,12 +256,14 @@ def sweep_paged_attention(slots, heads, head_dim, page_len, max_pages,
             except Exception as e:  # infeasible tiling = skip, not fail
                 log(f"  bk={bk} hb={hb}: infeasible ({e})")
                 continue
-        log(f"  bk={bk} hb={hb}: {ms:.4f} ms")
-        swept.append({**entry, "ms": round(ms, 5)})
+        log(f"  bk={bk} hb={hb} ({hb * group} rows a step): {ms:.4f} ms, "
+            f"{100 * least / (ms * 1e3):.1f}% of the bytes' time")
+        swept.append({**entry, "rows": hb * group, "ms": round(ms, 5)})
     jax.clear_caches()
     if not swept:
         raise RuntimeError("no feasible paged_attention candidate")
-    return {key: {**min(swept, key=lambda e: e["ms"]), "swept": swept}}
+    return {key: {**min(swept, key=lambda e: e["ms"]),
+                  "bytes_us": round(least, 2), "swept": swept}}
 
 
 # The grouped expert matmuls of the three expert cells (benchmarks/chip/
@@ -374,6 +396,9 @@ def main(argv=None):
         description="attention block-size sweep -> tuning artifact")
     p.add_argument("--batch", type=int, default=8)
     p.add_argument("--heads", type=int, default=16)
+    p.add_argument("--kv-heads", type=int, default=None,
+                   help="paged sweep: the pool's K/V heads where query "
+                        "heads are grouped on them (default: --heads)")
     p.add_argument("--head-dim", type=_int_list, default=[128],
                    help="head dim, or a comma-separated grid")
     p.add_argument("--seq", type=int, default=1024)
@@ -437,7 +462,8 @@ def main(argv=None):
                         dtype=args.dtype, kv_int8=args.kv_int8,
                         lengths=args.lengths, calls=args.calls,
                         trials=args.trials, warmup=args.warmup,
-                        max_candidates=args.max_candidates))
+                        max_candidates=args.max_candidates,
+                        kv_heads=args.kv_heads))
     if args.kernel == "grouped_matmul":
         # (not under "all": its stacks of weights are gigabytes)
         for name in args.shapes.split(","):
@@ -453,7 +479,8 @@ def main(argv=None):
     tuning.save_artifact(
         args.out, entries, device=device,
         kind=f"{args.kernel}_block_sweep",
-        shape={"batch": args.batch, "heads": args.heads, "seq": args.seq,
+        shape={"batch": args.batch, "heads": args.heads,
+               "kv_heads": args.kv_heads, "seq": args.seq,
                "kv_seq": args.kv_seq or args.seq,
                "head_dim": args.head_dim, "dtype": args.dtype,
                "causal": not args.no_causal,

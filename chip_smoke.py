@@ -992,6 +992,9 @@ def _kernel_checks(seq, heads, batch, cache_len, gemv_k, gemv_n, sparse_seq,
             _check(rec["head_block"] == head_block,
                    f"{heads} heads ran at head block {rec['head_block']}, "
                    f"not {head_block}")
+            _check(rec["rows"] == head_block * heads // kv,
+                   f"a grid step of {rec['rows']} query-head rows, not "
+                   f"{head_block * heads // kv}")
             # the products run in the pool's own type: a bf16 pool's in
             # bf16, a float32 pool's and an int8 pool's dequantised
             # blocks in float32
@@ -1187,6 +1190,10 @@ def _kernel_checks(seq, heads, batch, cache_len, gemv_k, gemv_n, sparse_seq,
          paged(3, 1, False)),
         ("paged_attention float32 pages 32 heads on 8 (head block 2)",
          paged(32, 2, False, jnp.float32, kv_heads=8)),
+        # a bf16 pool's step is not held to eight rows (PR 49): all four
+        # K/V heads and their twenty query heads
+        ("paged_attention bf16 pages 20 heads on 4 (head block 4, 20 rows)",
+         paged(20, 4, False, kv_heads=4)),
         (f"paged_attention int8 pages {heads} heads", paged(heads, 4, True)),
         ("paged_attention int8 pages 3 heads (head block 1)",
          paged(3, 1, True)),

@@ -78,8 +78,15 @@ def pick_head_block(heads: int, want: int) -> int:
     and 8 that divides ``heads`` and ``want``. Those four run compiled on
     the v5e (PR 21) and ``chip_smoke.py``'s kernels phase keeps all four
     covered: GPT-2's 12 heads get 4, and 2 / 1 under ``mp_size`` 2 / 4.
-    Mosaic (jax 0.9.0) aborted the process compiling the kernels'
-    per-head row slices at 12, so no other size is ever chosen."""
+    Mosaic (jax 0.9.0) aborted the process at 12 (PR 21, on the chip,
+    ``limits[i] <= dim(i)``). What is known since PR 49, from compiles
+    for a described v5e: that check is ``online_softmax_block``'s
+    float32 arm cutting one row of its boolean mask at a row past the
+    eighth — a float32 pool at a head block of 12 or 16 still aborts on
+    it, a bf16 pool (whose arm never cuts the mask) compiles at both.
+    Whether PR 21's kernel, which had one arm, died of that same cut was
+    not gone back to; no size but these four has run on the chip, so no
+    other is chosen."""
     import math
     return math.gcd(math.gcd(heads, want), 8)
 

@@ -15,10 +15,11 @@ the same ``[D, tokens]`` block:
 so the grid is the rows alone and a step multiplies ALL heads against a
 block at once — one ``[H, D] x [D, tokens]`` product and one ``[H, tokens]
 x [tokens, value_width]`` product a block, no head's rows ever sliced
-out of the query (``paged_attention`` slices a K/V head's group of rows
-and is held to eight rows a step for it: its ``MAX_ROWS``). Keys and
-values are one buffer: a page is fetched once and its leading rows are
-the values.
+out of the query (``paged_attention`` slices a K/V head's group of rows,
+and where its products are float32 a step is held to eight rows: the
+one-row cut of a boolean mask that Mosaic aborts on, its ``MAX_ROWS``).
+Keys and values are one buffer: a page is fetched once and its leading
+rows are the values.
 
 The walk is ``paged_attention``'s: the row's pages stream HBM -> VMEM in
 place through double-buffered DMAs, the physical page from the

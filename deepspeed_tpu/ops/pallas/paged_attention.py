@@ -22,6 +22,16 @@ attention per page block; no contiguous per-slot view ever
 materializes, and DMA traffic scales with the VALID length, not the
 allocated table width.
 
+Grouped-query heads: the grid walks the pool's K/V heads, and a step
+holds ``head_block`` of them with every query head that reads them, one
+row each (``rows`` in the dispatch record). A step's cost is mostly
+fixed — its first block's fetch, which nothing hides, and the current
+token's fold — so a step takes as many K/V heads as ``step_head_block``
+allows: all four of Falcon-H1's (20 rows, grid ``(slots, 1)``) where
+the pool is bf16, eight rows' worth where the products are float32
+(LFM2's float32 pool: two K/V heads of a group of four). A row's
+arithmetic does not depend on which heads share its step.
+
 A scanned model's pool is layer-stacked, ``[L, num_pages, h, d,
 page_len]``. It reaches this kernel whole — the model's layer scan
 broadcasts it and passes the layer index — and the DMA source is
@@ -88,9 +98,29 @@ from ._common import read_slopes as _read_slopes
 
 DEFAULT_BLOCK_TOKENS = 512
 DEFAULT_HEAD_BLOCK = 8
-MAX_ROWS = 8          # query-head rows of one grid step
+# query-head rows of one grid step where the products are float32
+# (``step_head_block``), and the query heads of one K/V head on either arm
+# (``kernel_ok``: no group over eight has been compiled)
+MAX_ROWS = 8
 
 KERNEL = "paged_attention"
+
+
+def step_head_block(kv_heads, group, pool_dtype, want):
+    """K/V heads of one grid step: ``pick_head_block`` of ``want``, each
+    head with its ``group`` query heads, one row each. Where the products
+    are float32 (a float32 pool, an int8 one) a step is held to MAX_ROWS
+    rows: Mosaic (jax 0.9.0) aborts the process (``limits[i] <= dim(i)``)
+    on the float32 arm's one-row cut of the boolean column mask at a row
+    past the eighth (``_common.online_softmax_block``:
+    ``valid[h * group:h * group + 1]``), so a group of four there takes
+    two K/V heads a step. The narrow arm never cuts that mask (its guard
+    is a select on the block's bits): a bf16 pool's step takes every K/V
+    head the head block allows, 20 rows at five query heads on each of
+    four."""
+    if products_dtype(pool_dtype) == jnp.float32:
+        want = min(want, max(1, MAX_ROWS // group))
+    return pick_head_block(kv_heads, want)
 
 
 def _fold_current_token(q, kn, vn, m_ref, l_ref, acc_ref):
@@ -388,14 +418,9 @@ def paged_attention(q, k_pages, v_pages, page_table, lengths, k_new, v_new,
     bt = int(entry.get("block_k") or block_tokens or DEFAULT_BLOCK_TOKENS)
     tp = model_axis_size(mesh, kv_heads)
     group = heads // kv_heads
-    # a grid step holds head_block K/V heads and their query heads, one
-    # row each: at most MAX_ROWS rows. Mosaic (jax 0.9.0) aborts the
-    # process compiling a slice of rows past the first eight
-    # (``limits[i] <= dim(i)``: the head block of 12 of PR 21, and 16 or
-    # 32 rows here), so a group of four takes two K/V heads a step
-    hb = pick_head_block(kv_heads // tp, min(
-        int(entry.get("head_block") or head_block or DEFAULT_HEAD_BLOCK),
-        max(1, MAX_ROWS // group)))
+    hb = step_head_block(
+        kv_heads // tp, group, k_pages.dtype,
+        int(entry.get("head_block") or head_block or DEFAULT_HEAD_BLOCK))
     ppb = max(1, min(bt // page_len, max_pages))
 
     aligned = page_len % 128 == 0 or _interpret()
@@ -415,8 +440,9 @@ def paged_attention(q, k_pages, v_pages, page_table, lengths, k_new, v_new,
         log_fallback_on_tpu(KERNEL, "dense", reason)
     tuning.record_dispatch(
         KERNEL, structure, key, source, block_k=ppb * page_len,
-        head_block=hb, impl="kernel" if use_kernel else "dense",
-        reason=reason, model_shards=tp,
+        head_block=hb, rows=hb * group,
+        impl="kernel" if use_kernel else "dense", reason=reason,
+        model_shards=tp,
         products=(products_dtype(k_pages.dtype).name if use_kernel
                   else "float32"))
     if use_kernel:

@@ -292,6 +292,42 @@ class TestSweepHarness:
             sweep_paged_attention(2, 2, 16, 16, 4, lengths=[5, 5, 5],
                                   trials=1, log=lambda *a: None)
 
+    @pytest.mark.parametrize("dtype,kv_int8,head_blocks", [
+        ("bfloat16", False, [4, 2, 1]), ("float32", False, [1]),
+        ("float32", True, [1])], ids=["bf16", "f32", "int8"])
+    def test_paged_sweep_of_grouped_heads(self, dtype, kv_int8, head_blocks):
+        """``kv_heads``: the pool is built with the K/V heads and the
+        query heads are grouped on them (20 on 4, Falcon-H1's layout);
+        the head blocks offered are the ones the dispatcher allows — up
+        to every K/V head of a bf16 pool, eight rows' worth where the
+        products are float32 — and every candidate names its rows."""
+        from benchmarks.kernel_tuning import (_paged_candidates,
+                                              sweep_paged_attention)
+        pool = jnp.int8 if kv_int8 else jnp.dtype(dtype)
+        assert _paged_candidates(4, 5, pool, 16, 2) == [
+            (bk, hb) for bk in (16, 32) for hb in head_blocks]
+        # ungrouped heads are offered what they always were
+        assert [hb for _, hb in _paged_candidates(16, 1, pool, 16, 1)] \
+            == [8, 4, 2, 1]
+        (key, entry), = sweep_paged_attention(
+            3, 20, 16, 16, 2, dtype=dtype, kv_int8=kv_int8, kv_heads=4,
+            lengths=[20, 0, 31], calls=2, trials=1,
+            log=lambda *a: None).items()
+        assert key.startswith("paged_attention/page16/sq3_sk32_d16_")
+        swept = entry["swept"]
+        assert [(e["block_k"], e["head_block"]) for e in swept] == [
+            (bk, hb) for bk in (16, 32) for hb in head_blocks]
+        assert all(e["rows"] == 5 * e["head_block"] for e in swept)
+        assert entry["ms"] == min(e["ms"] for e in swept)
+        # 51 valid columns of K and V, 4 heads of 16
+        assert entry["bytes_us"] == round(
+            2 * 51 * 4 * 16 * jnp.dtype(pool).itemsize / 819e9 * 1e6, 2)
+        rec = tuning.last_dispatch("paged_attention")["page16"]
+        assert rec["rows"] == 5 * rec["head_block"]
+        with pytest.raises(ValueError, match="whole group"):
+            sweep_paged_attention(3, 20, 16, 16, 2, kv_heads=3, trials=1,
+                                  log=lambda *a: None)
+
     @pytest.mark.parametrize("down,out", [(False, "bfloat16"),
                                           (True, "float32")],
                              ids=["gate-up", "down"])

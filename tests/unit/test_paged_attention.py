@@ -324,6 +324,101 @@ class TestGroupedQueryHeads:
             mesh=mesh))(jnp.int32(1))
         np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
+    # Five query heads a K/V head over a bf16 pool (Falcon-H1's layout:
+    # 20 on 4 heads of 128): the eight-row cap of a grid step is the
+    # float32 arm's, so the dispatcher hands a step every K/V head —
+    # ``head_block`` = the K/V heads, ``rows`` = five times that — and a
+    # row's arithmetic does not depend on which heads share its step: the
+    # result is ``head_block=1``'s bit for bit. Ragged lengths, a row of
+    # length zero, NaN in every page nobody owns.
+    GROUP, WIDE = 5, 128
+    LENS = [2 * PAGE + 7, 0, 5 * PAGE - 1, PAGE]
+    TABLE = [[1, 4, 2, 0, 0], [0, 0, 0, 0, 0], [3, 5, 6, 7, 8],
+             [9, 0, 0, 0, 0]]
+    L = 2
+
+    def _five_a_head(self, kv, form, seed=61):
+        r = np.random.RandomState(seed)
+        heads = kv * self.GROUP
+        kp, vp = (jnp.asarray(r.randn(self.L, 11, kv, self.WIDE, PAGE),
+                              jnp.bfloat16) for _ in range(2))
+        # the null page and a page no row's table names
+        bad = jnp.asarray([0, 10])
+        kp, vp = kp.at[:, bad].set(jnp.nan), vp.at[:, bad].set(jnp.nan)
+        q = _bf16_values(r, 4, 1, heads, self.WIDE)
+        kn, vn = (_bf16_values(r, 4, kv, self.WIDE, 1) for _ in range(2))
+        kw = {"layer": jnp.int32(self.L - 1)}
+        if form == "4d":
+            kp, vp, kw = kp[-1], vp[-1], {}
+        return (q, kp, vp, jnp.asarray(self.TABLE, jnp.int32),
+                jnp.asarray(self.LENS, jnp.int32), kn, vn), kw
+
+    @pytest.mark.parametrize("form", ["4d", "stacked"])
+    @pytest.mark.parametrize("kv", [2, 4, 8])
+    def test_a_step_takes_every_kv_head_and_rows_keep_their_bits(self, kv,
+                                                                 form):
+        args, kw = self._five_a_head(kv, form)
+        tuning.clear_last_dispatch()
+        got = paged_attention(*args, impl="kernel", block_tokens=2 * PAGE,
+                              **kw)
+        rec = tuning.last_dispatch(KERNEL)["page%d" % PAGE]
+        assert (rec["head_block"], rec["rows"]) == (kv, self.GROUP * kv)
+        assert rec["products"] == "bfloat16" and rec["impl"] == "kernel"
+        one = paged_attention(*args, impl="kernel", block_tokens=2 * PAGE,
+                              head_block=1, **kw)
+        rec = tuning.last_dispatch(KERNEL)["page%d" % PAGE]
+        assert (rec["head_block"], rec["rows"]) == (1, self.GROUP)
+        assert np.isfinite(np.asarray(got)).all()
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(one))
+        dense = paged_attention(*args, impl="dense", **kw)
+        np.testing.assert_allclose(np.asarray(got), np.asarray(dense),
+                                   atol=1e-5, rtol=1e-5)
+        # the row of length zero attends its own token alone
+        own = np.repeat(np.asarray(args[6])[1, :, :, 0], self.GROUP, axis=0)
+        np.testing.assert_allclose(np.asarray(got)[1, 0], own, atol=1e-6)
+
+    @pytest.mark.parametrize("head_block", [1, 2, 4])
+    def test_over_the_model_axis_a_device_takes_its_own_kv_heads(
+            self, head_block):
+        """Four K/V heads over a model axis of two: each device's step
+        holds its two (ten rows), or fewer where the caller asks."""
+        from deepspeed_tpu.comm import MeshSpec, build_mesh
+        args, kw = self._five_a_head(4, "stacked")
+        want = paged_attention(*args, impl="kernel", head_block=1, **kw)
+        mesh = build_mesh(MeshSpec(model=2, data=4))
+        tuning.clear_last_dispatch()
+        got = jax.jit(lambda i: paged_attention(
+            *args, layer=i, impl="kernel", head_block=head_block,
+            mesh=mesh))(kw["layer"])
+        rec = tuning.last_dispatch(KERNEL)["page%d" % PAGE]
+        hb = min(head_block, 2)
+        assert (rec["head_block"], rec["rows"], rec["model_shards"]) == (
+            hb, self.GROUP * hb, 2)
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+    @pytest.mark.parametrize("dtype", ["f32", "int8"])
+    def test_float32_products_keep_eight_rows_a_step(self, dtype):
+        """The same heads over a float32 pool and an int8 one: the
+        float32 arm cuts a row of a boolean mask a K/V head, which
+        Mosaic aborts on past the eighth row — one K/V head a step at a
+        group of five, and the dense path's result."""
+        (q, kp, vp, ptab, lens, kn, vn), _ = self._five_a_head(4, "4d")
+        clean = lambda x: jnp.nan_to_num(x.astype(jnp.float32))
+        kp, vp, scales = clean(kp), clean(vp), {}
+        if dtype == "int8":
+            kp, vp, ks, vs = _quantize_pool(kp, vp)
+            scales = {"k_scale": ks, "v_scale": vs}
+        tuning.clear_last_dispatch()
+        got = paged_attention(q, kp, vp, ptab, lens, kn, vn, impl="kernel",
+                              **scales)
+        rec = tuning.last_dispatch(KERNEL)["page%d" % PAGE]
+        assert (rec["head_block"], rec["rows"], rec["products"]) == (
+            1, self.GROUP, "float32")
+        dense = paged_attention(q, kp, vp, ptab, lens, kn, vn, impl="dense",
+                                **scales)
+        np.testing.assert_allclose(np.asarray(got), np.asarray(dense),
+                                   atol=1e-4, rtol=1e-4)
+
 
 class TestZeroRowsBetweenLongRows:
     """What the serving decode program hands the kernel since it masks
@@ -479,7 +574,8 @@ class TestProductsInThePoolsType:
         tuning.clear_last_dispatch()
         got = run("kernel")
         rec = tuning.last_dispatch(KERNEL)["page%d" % PAGE]
-        assert rec["head_block"] == 2 and rec["products"] == "float32"
+        assert (rec["head_block"], rec["rows"]) == (2, 8)
+        assert rec["products"] == "float32"
         want = run("dense")
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                    atol=1e-5, rtol=1e-5)

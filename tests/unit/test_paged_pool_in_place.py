@@ -181,6 +181,89 @@ def _compile_decode(model, args, static):
         donate_argnums=(2, 4)).lower(model, *args, *static).compile()
 
 
+# ---------------------------------------------------------------------------
+# the paged kernel alone at grouped bf16 shapes, whose grid steps hold more
+# than eight query-head rows (PR 49). Mosaic does not raise on a slice it
+# cannot lay out, it aborts the process: each compile runs in a child with
+# a time limit, so an abort is one failed test and not a dead worker. These
+# stand first in the file: the child has to load the TPU's compiler, which
+# one process at a time may (the worker loads it for the tests below)
+# ---------------------------------------------------------------------------
+
+_COMPILE_PAGED = """
+import json, os, sys
+os.environ.setdefault("TPU_LOG_DIR", "disabled")
+os.environ["JAX_PLATFORMS"] = "cpu"
+sys.path.insert(0, sys.argv[1])
+slots, heads, kv, d, layers, max_pages = map(int, sys.argv[2:8])
+import importlib
+import jax, jax.numpy as jnp
+from jax.experimental import topologies
+from jax.sharding import SingleDeviceSharding
+try:
+    topo = topologies.get_topology_desc(platform="tpu",
+                                        topology_name="v5e:2x2")
+except Exception as e:
+    print(json.dumps({"skip": str(e)[:300]}))
+    sys.exit(0)
+from deepspeed_tpu.ops.pallas import tuning
+pa = importlib.import_module("deepspeed_tpu.ops.pallas.paged_attention")
+pa._interpret = lambda: False         # through Mosaic, as on the chip
+chip = SingleDeviceSharding(topo.devices[0])
+S = lambda shape, t=jnp.bfloat16: jax.ShapeDtypeStruct(shape, t,
+                                                       sharding=chip)
+pool = S((layers, slots * max_pages + 1, kv, d, 128))
+new = S((slots, kv, d, 1))
+compiled = jax.jit(lambda q, kp, vp, table, lens, kn, vn, i:
+                   pa.paged_attention(q, kp, vp, table, lens, kn, vn, layer=i,
+                                      impl="kernel")).lower(
+    S((slots, 1, heads, d)), pool, pool, S((slots, max_pages), jnp.int32),
+    S((slots,), jnp.int32), new, new, S((), jnp.int32)).compile()
+rec = tuning.last_dispatch("paged_attention")["page128"]
+print(json.dumps({"record": rec, "mosaic_calls": compiled.as_text().count(
+    'custom_call_target="tpu_custom_call"')}))
+"""
+
+
+@pytest.mark.parametrize("shape,head_block,rows,source", [
+    # serve-falconh1-chat: 64 rows, 20 query heads on 4 K/V heads of 128, a
+    # stacked pool of 9 layers, 16 pages a row: one step a row, the swept
+    # block of ``flash_tuning_defaults.json``
+    ((64, 20, 4, 128, 9, 16), 4, 20, "defaults"),
+    # LFM2's heads were its pool bf16, and five on each of eight: the most
+    # rows a step any geometry here makes (a group is at most eight)
+    ((32, 32, 8, 64, 2, 32), 8, 32, "constants"),
+    ((64, 40, 8, 128, 2, 16), 8, 40, "defaults"),
+], ids=["falcon-h1-20on4", "32on8-d64", "40on8"])
+def test_grouped_bf16_steps_of_many_rows_compile_for_the_chip(
+        shape, head_block, rows, source):
+    import json
+    import subprocess
+    import sys
+    repo = os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    try:
+        child = subprocess.run(
+            [sys.executable, "-c", _COMPILE_PAGED, repo, *map(str, shape)],
+            capture_output=True, text=True, timeout=300)
+    except subprocess.TimeoutExpired:
+        pytest.fail(f"compiling the paged kernel at {shape} hung")
+    if child.returncode and "libtpu_lockfile" in child.stderr:
+        pytest.skip("another process holds the TPU's compiler")
+    assert child.returncode == 0, (
+        f"the compile at {shape} ended the process with "
+        f"{child.returncode}: {child.stderr[-600:]}")
+    said = json.loads(child.stdout.strip().splitlines()[-1])
+    if "skip" in said:
+        pytest.skip(f"no v5e:2x2 topology can be described here: "
+                    f"{said['skip']}")
+    rec = said["record"]
+    assert (rec["impl"], rec["products"]) == ("kernel", "bfloat16")
+    assert (rec["head_block"], rec["rows"]) == (head_block, rows)
+    assert rec["source"] == source
+    assert said["mosaic_calls"] == 1
+
+
 @pytest.mark.parametrize("scan_layers,kv_int8,experts,temp_before", [
     (True, False, False, 742400), (False, False, False, 2734080),
     (True, True, False, 935936), (True, False, True, 4657664)],
@@ -366,14 +449,19 @@ LFM2_LAYERS = ("conv", "conv", "full_attention", "conv")
 LFM2_PAGES, LFM2_MAX_PAGES = 1025, 32
 
 
+@pytest.mark.parametrize("dtype,head_block,rows", [
+    (jnp.float32, 2, 8), (jnp.bfloat16, 8, 32)], ids=["float32", "bfloat16"])
 def test_lfm2_decode_program_keeps_pages_and_state_where_they_are(
-        one_chip, monkeypatch):
+        one_chip, monkeypatch, dtype, head_block, rows):
     """A model with recurrent state beside its pages: the decode program
-    compiled for the chip holds the grouped-head Mosaic kernel (a grid
-    step of 2 K/V heads and their 8 query heads: 16 or 32 rows a step
-    abort this Mosaic), moves neither the K/V pool nor the state stored
-    a page, rewrites only the slots' state, and its scratch stays rows of
-    activations with the convolution state in the program."""
+    compiled for the chip holds the grouped-head Mosaic kernel — with
+    float32 activations, as the cell serves it, a grid step of 2 K/V
+    heads and their 8 query heads (the float32 arm's one-row cut of its
+    boolean mask aborts this Mosaic past the eighth row); with a bf16
+    pool all 8 K/V heads and 32 rows — moves neither the K/V pool nor
+    the state stored a page, rewrites only the slots' state, and its
+    scratch stays rows of activations with the convolution state in the
+    program."""
     from deepspeed_tpu.inference.cache import has_recurrent_state
     from deepspeed_tpu.models.lfm2 import LFM2, LFM2Config
     from deepspeed_tpu.ops.pallas import tuning
@@ -383,7 +471,7 @@ def test_lfm2_decode_program_keeps_pages_and_state_where_they_are(
     model = LFM2(LFM2Config(num_hidden_layers=len(LFM2_LAYERS),
                             layer_types=LFM2_LAYERS,
                             max_position_embeddings=4096,
-                            dtype=jnp.bfloat16, param_dtype=jnp.bfloat16))
+                            dtype=dtype, param_dtype=jnp.bfloat16))
     import flax.core.meta as flax_meta
     params = jax.eval_shape(
         lambda r: flax_meta.unbox(model.init(
@@ -412,22 +500,25 @@ def test_lfm2_decode_program_keeps_pages_and_state_where_they_are(
             on_chip(jax.ShapeDtypeStruct((SLOTS, LFM2_MAX_PAGES), jnp.int32)),
             on_chip(state), on_chip(lambda: jax.random.PRNGKey(0)),
             on_chip(jax.ShapeDtypeStruct((), jnp.int32)))
-    static = (65535, 1.0, 0, 1.0, None, True, False, False, True,
-              jnp.bfloat16)
+    static = (65535, 1.0, 0, 1.0, None, True, False, False, True, dtype)
     tuning.clear_last_dispatch()
     compiled = jax.jit(
         _paged_decode_iter_impl, static_argnums=(0, 11, 12, 13, 14, 15, 16),
         donate_argnums=(2, 4)).lower(model, *args, *static).compile()
     rec = tuning.last_dispatch("paged_attention")["page%d" % PAGE_LEN]
-    assert (rec["impl"], rec["head_block"]) == ("kernel", 2)
+    assert (rec["impl"], rec["head_block"], rec["rows"]) == (
+        "kernel", head_block, rows)
+    assert rec["products"] == jnp.dtype(dtype).name
 
     leaves = jax.tree.leaves(pool_shapes)
     pool_bytes = sum(x.size * x.dtype.itemsize for x in leaves)
     mem = compiled.memory_analysis()
     assert mem.alias_size_in_bytes >= pool_bytes
-    # rows of activations: nowhere near a K/V leaf (134 MB), a layer's
-    # page states (16.8 MB) or one expert's weights (18.9 MB)
-    assert mem.temp_size_in_bytes < 8 * 2 ** 20, mem.temp_size_in_bytes
+    # rows of activations (in float32, 32 rows of 65,536 logits are
+    # 8.4 MB): nowhere near a K/V leaf (134 MB in bf16), a layer's page
+    # states (16.8 MB in bf16) or one expert's weights (18.9 MB)
+    wide = jnp.dtype(dtype).itemsize // 2
+    assert mem.temp_size_in_bytes < wide * 8 * 2 ** 20, mem.temp_size_in_bytes
     hlo = compiled.as_text()
     assert "tpu_custom_call" in hlo and hlo_has(compiled, "ragged-dot")
     roots = _roots(_computations(hlo))
@@ -506,7 +597,10 @@ def test_falcon_h1_decode_program_updates_the_slot_state_in_place(
         _paged_decode_iter_impl, static_argnums=(0, 11, 12, 13, 14, 15, 16),
         donate_argnums=(2, 4)).lower(model, *args, *static).compile()
     rec = tuning.last_dispatch("paged_attention")["page%d" % PAGE_LEN]
-    assert rec["impl"] == "kernel"
+    # every K/V head and its five query heads in one grid step, at the
+    # block swept on the chip (PR 49): the grid is the 64 rows
+    assert (rec["impl"], rec["head_block"], rec["rows"]) == ("kernel", 4, 20)
+    assert (rec["source"], rec["block_k"]) == ("defaults", 512)
     rec, = tuning.last_dispatch("ssm_update").values()
     assert rec["impl"] == "kernel"
 
@@ -559,10 +653,9 @@ def test_kanana_decode_program_walks_the_latent_pool_in_place(
         one_chip, monkeypatch, dtype):
     """A latent pool: ONE leaf a layer, ``[pages, 1, 576, page_len]``,
     no values beside it. The decode program compiled for the chip holds
-    the latent Mosaic kernel (all 32 query heads a grid step, which the
-    paged kernel's head groups could not be: 16 and 32 rows a step abort
-    this Mosaic), aliases the whole pool and moves none of it, and its
-    scratch stays rows of activations."""
+    the latent Mosaic kernel (all 32 query heads a grid step, no head's
+    rows cut out of the query), aliases the whole pool and moves none of
+    it, and its scratch stays rows of activations."""
     from deepspeed_tpu.inference.cache import has_latent_units
     from deepspeed_tpu.ops.pallas import tuning
     monkeypatch.setattr(
