@@ -155,12 +155,12 @@ def _paged_candidates(kv_heads, group, dtype, page_len, max_pages,
     """(block_k tokens, head_block) candidates for the paged decode
     kernel: page_len multiples up to the table width (the DMA block the
     kernel double-buffers) crossed with the K/V heads a grid step may
-    take — what the dispatcher makes of 8, 4, 2 and 1 for this pool's
+    take — what the dispatcher makes of 16, 8, 4, 2 and 1 for this pool's
     type and ``group`` query heads a K/V head."""
     from deepspeed_tpu.ops.pallas.paged_attention import step_head_block
     bks = [page_len * n for n in (1, 2, 4, 8) if n <= max_pages]
     hbs = sorted({step_head_block(kv_heads, group, dtype, h)
-                  for h in (8, 4, 2, 1)}, reverse=True)
+                  for h in (16, 8, 4, 2, 1)}, reverse=True)
     cands = [(bk, hb) for bk in bks for hb in hbs]
     return cands[:max_candidates] if max_candidates else cands
 

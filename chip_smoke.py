@@ -937,10 +937,11 @@ def _kernel_checks(seq, heads, batch, cache_len, gemv_k, gemv_n, sparse_seq,
         return run
 
     # the decode kernels' head block (_common.pick_head_block) is 8 for 16
-    # heads, 4 for GPT-2's 12, and 2 / 1 for the 6 / 3 heads one device
-    # holds at mp_size 2 / 4: each size is its own Mosaic tiling, and a
-    # refusal there is a SIGABRT
-    def decode(heads, head_block):
+    # heads (16 where a bf16 paged pool's caller or table entry asks for
+    # it: PR 51), 4 for GPT-2's 12, and 2 / 1 for the 6 / 3 heads one
+    # device holds at mp_size 2 / 4: each size is its own Mosaic tiling,
+    # and a refusal there is a SIGABRT
+    def decode(heads, head_block, ask=None):
         def run():
             d = 64
             q = normal((batch * 2, 1, heads, d))
@@ -951,7 +952,9 @@ def _kernel_checks(seq, heads, batch, cache_len, gemv_k, gemv_n, sparse_seq,
             from deepspeed_tpu.ops.pallas.decode_attention import \
                 _decode_dense
             tuning.clear_last_dispatch()
-            got = jax.jit(decode_attention)(q, k, v, lengths)
+            kw = {"head_block": ask} if ask else {}
+            got = jax.jit(lambda *a: decode_attention(*a, **kw))(
+                q, k, v, lengths)
             want = _decode_dense(
                 q[:, 0].astype(jnp.float32), k, v, lengths,
                 jnp.zeros((heads,), jnp.float32), scale=d ** -0.5,
@@ -964,7 +967,8 @@ def _kernel_checks(seq, heads, batch, cache_len, gemv_k, gemv_n, sparse_seq,
                    f"not {head_block}")
         return run
 
-    def paged(heads, head_block, int8, dtype=jnp.bfloat16, kv_heads=None):
+    def paged(heads, head_block, int8, dtype=jnp.bfloat16, kv_heads=None,
+              ask=None):
         def run():
             d, page_len, slots, max_pages = 64, 128, 4, cache_len // 128
             num_pages = slots * max_pages + 1
@@ -984,7 +988,8 @@ def _kernel_checks(seq, heads, batch, cache_len, gemv_k, gemv_n, sparse_seq,
                 (kp, ks), (vp, vs) = _quantize_kv(kp), _quantize_kv(vp)
                 scales = dict(k_scale=ks, v_scale=vs)
             tuning.clear_last_dispatch()
-            got = jax.jit(lambda *a: paged_attention(*a, **scales))(
+            got = jax.jit(lambda *a: paged_attention(
+                *a, head_block=ask, **scales))(
                 q, kp, vp, ptab, lengths, kn, vn)
             rec = tuning.last_dispatch("paged_attention").get(
                 f"page{page_len}")
@@ -1178,12 +1183,18 @@ def _kernel_checks(seq, heads, batch, cache_len, gemv_k, gemv_n, sparse_seq,
         ("attention d=80 (auto -> reference)", head_dim_80),
         (f"decode_attention {heads} heads", decode(heads, 4)),
         ("decode_attention 16 heads (head block 8)", decode(16, 8)),
+        ("decode_attention 16 heads (head block 16, asked for)",
+         decode(16, 16, ask=16)),
         ("decode_attention 6 heads (head block 2)", decode(6, 2)),
         ("decode_attention 3 heads (head block 1)", decode(3, 1)),
         (f"paged_attention bf16 pages {heads} heads",
          paged(heads, 4, False)),
         ("paged_attention bf16 pages 16 heads (head block 8)",
          paged(16, 8, False)),
+        # ... and all sixteen in one grid step where that is asked for, as
+        # the three sixteen-head cells' entry of the tuning table asks
+        ("paged_attention bf16 pages 16 heads (head block 16, asked for)",
+         paged(16, 16, False, ask=16)),
         ("paged_attention bf16 pages 6 heads (head block 2)",
          paged(6, 2, False)),
         ("paged_attention bf16 pages 3 heads (head block 1)",

@@ -74,21 +74,26 @@ def pick_block(dim: int, want: int) -> int:
 
 
 def pick_head_block(heads: int, want: int) -> int:
-    """Heads per grid step for the decode kernels: the largest of 1, 2, 4
-    and 8 that divides ``heads`` and ``want``. Those four run compiled on
-    the v5e (PR 21) and ``chip_smoke.py``'s kernels phase keeps all four
-    covered: GPT-2's 12 heads get 4, and 2 / 1 under ``mp_size`` 2 / 4.
-    Mosaic (jax 0.9.0) aborted the process at 12 (PR 21, on the chip,
-    ``limits[i] <= dim(i)``). What is known since PR 49, from compiles
-    for a described v5e: that check is ``online_softmax_block``'s
-    float32 arm cutting one row of its boolean mask at a row past the
-    eighth — a float32 pool at a head block of 12 or 16 still aborts on
-    it, a bf16 pool (whose arm never cuts the mask) compiles at both.
-    Whether PR 21's kernel, which had one arm, died of that same cut was
-    not gone back to; no size but these four has run on the chip, so no
-    other is chosen."""
+    """Heads per grid step for the decode kernels: the largest of 1, 2, 4,
+    8 and 16 that divides ``heads`` and ``want``. The first four run
+    compiled on the v5e since PR 21 and ``chip_smoke.py``'s kernels phase
+    keeps them covered: GPT-2's 12 heads get 4, and 2 / 1 under
+    ``mp_size`` 2 / 4. Sixteen runs there since PR 51, over a bf16 pool
+    (the paged kernel's sweep and the three sixteen-head serving cells),
+    and is answered only to a caller that asks for it: at a ``want`` of 8
+    every answer is what it was. Mosaic (jax 0.9.0) aborted the process
+    at 12 (PR 21, on the chip, ``limits[i] <= dim(i)``). What is known
+    since PR 49, from compiles for a described v5e: that check is
+    ``online_softmax_block``'s float32 arm cutting one row of its boolean
+    mask at a row past the eighth — a float32 pool at a head block of 12
+    or 16 still aborts on it (``paged_attention.step_head_block`` caps
+    its ``want`` at eight rows before it asks here), a bf16 pool (whose
+    arm never cuts the mask) compiles at both. Whether PR 21's kernel,
+    which had one arm, died of that same cut was not gone back to;
+    twelve has not run on the chip and no cell holds twelve heads a
+    device, so it is not chosen."""
     import math
-    return math.gcd(math.gcd(heads, want), 8)
+    return math.gcd(math.gcd(heads, want), 16)
 
 
 def read_slopes(slopes_ref, h0: int, hb: int):

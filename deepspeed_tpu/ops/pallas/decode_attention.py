@@ -49,9 +49,10 @@ from ._common import NEG_INF
 from ._common import block_query as _block_query
 from ._common import interpret_mode as _interpret
 from ._common import (log_fallback_on_tpu, model_axis_size, over_model_axis,
-                      pick_head_block, products_dtype)
+                      products_dtype)
 from ._common import online_softmax_block as _attend_block
 from ._common import read_slopes as _read_slopes
+from .paged_attention import step_head_block
 
 DEFAULT_BLOCK_K = 512
 DEFAULT_HEAD_BLOCK = 8
@@ -210,7 +211,9 @@ def decode_attention(q, k, v, length, *, softmax_scale=None,
     s = k.shape[3]
     scale = softmax_scale if softmax_scale is not None else 1.0 / math.sqrt(d)
     tp = model_axis_size(mesh, heads)
-    hb = pick_head_block(heads // tp, head_block)
+    # the paged kernel's rule (a float32 cache's step is held to eight
+    # rows, whatever is asked): the two share ``online_softmax_block``
+    hb = step_head_block(heads // tp, 1, k.dtype, head_block)
 
     lengths = jnp.broadcast_to(jnp.asarray(length, jnp.int32), (b,))
     alibi = alibi_slopes is not None

@@ -26,11 +26,16 @@ Grouped-query heads: the grid walks the pool's K/V heads, and a step
 holds ``head_block`` of them with every query head that reads them, one
 row each (``rows`` in the dispatch record). A step's cost is mostly
 fixed — its first block's fetch, which nothing hides, and the current
-token's fold — so a step takes as many K/V heads as ``step_head_block``
-allows: all four of Falcon-H1's (20 rows, grid ``(slots, 1)``) where
-the pool is bf16, eight rows' worth where the products are float32
-(LFM2's float32 pool: two K/V heads of a group of four). A row's
-arithmetic does not depend on which heads share its step.
+token's fold — so a step takes as many K/V heads as the shape's entry of
+the tuning table asks for and ``step_head_block`` allows: all four of
+Falcon-H1's (20 rows, grid ``(slots, 1)``) and all sixteen of the
+ungrouped GPT-2 1.3B and OLMoE pools (16 rows, grid ``(slots, 1)``: PR
+51, each DMA block then one page of 128 tokens) where the pool is bf16,
+eight rows' worth where the products are float32 (LFM2's float32 pool:
+two K/V heads of a group of four), eight heads where nobody swept the
+shape (``DEFAULT_HEAD_BLOCK``). A row's arithmetic does not depend on
+which heads share its step: at one ``block_k`` every head block gives
+the same bits.
 
 A scanned model's pool is layer-stacked, ``[L, num_pages, h, d,
 page_len]``. It reaches this kernel whole — the model's layer scan
@@ -117,7 +122,7 @@ def step_head_block(kv_heads, group, pool_dtype, want):
     two K/V heads a step. The narrow arm never cuts that mask (its guard
     is a select on the block's bits): a bf16 pool's step takes every K/V
     head the head block allows, 20 rows at five query heads on each of
-    four."""
+    four, 16 at sixteen ungrouped heads where ``want`` is 16."""
     if products_dtype(pool_dtype) == jnp.float32:
         want = min(want, max(1, MAX_ROWS // group))
     return pick_head_block(kv_heads, want)

@@ -266,12 +266,17 @@ class TestKernelTiling:
         # boolean mask past the eighth row (PR 49: a float32 pool at 12
         # still aborts compiling for a described v5e, a bf16 pool
         # compiles); whether PR 21's one-armed kernel died of that cut
-        # is not known. The sizes stay the four the chip has run
+        # is not known. The chip has run 1, 2, 4 and 8 since PR 21 and
+        # 16 over a bf16 pool since PR 51 (the paged kernel's sweep and
+        # the sixteen-head serving cells); 16 is answered only to a
+        # caller that asks for it, and 12 to nobody
         assert pick_head_block(12, 8) == 4     # GPT-2
         assert pick_head_block(16, 8) == 8
         assert pick_head_block(20, 8) == 4
-        assert pick_head_block(32, 16) == 8    # only sizes the chip ran
+        assert pick_head_block(16, 16) == 16   # the three bf16 cells
+        assert pick_head_block(32, 16) == 16
         assert pick_head_block(24, 16) == 8
+        assert pick_head_block(8, 16) == 8     # 16 heads at mp_size=2
         assert pick_head_block(36, 12) == 4    # never 12
         assert pick_head_block(4, 8) == 4      # Falcon-H1's K/V heads
         assert pick_head_block(6, 8) == 2      # 12 heads at mp_size=2

@@ -306,9 +306,6 @@ class TestSweepHarness:
         pool = jnp.int8 if kv_int8 else jnp.dtype(dtype)
         assert _paged_candidates(4, 5, pool, 16, 2) == [
             (bk, hb) for bk in (16, 32) for hb in head_blocks]
-        # ungrouped heads are offered what they always were
-        assert [hb for _, hb in _paged_candidates(16, 1, pool, 16, 1)] \
-            == [8, 4, 2, 1]
         (key, entry), = sweep_paged_attention(
             3, 20, 16, 16, 2, dtype=dtype, kv_int8=kv_int8, kv_heads=4,
             lengths=[20, 0, 31], calls=2, trials=1,
@@ -327,6 +324,31 @@ class TestSweepHarness:
         with pytest.raises(ValueError, match="whole group"):
             sweep_paged_attention(3, 20, 16, 16, 2, kv_heads=3, trials=1,
                                   log=lambda *a: None)
+
+    @pytest.mark.parametrize("dtype,kv_int8,head_blocks", [
+        ("bfloat16", False, [16, 8, 4, 2, 1]),
+        ("float32", False, [8, 4, 2, 1]), ("float32", True, [8, 4, 2, 1])],
+        ids=["bf16", "f32", "int8"])
+    def test_paged_sweep_of_sixteen_heads(self, dtype, kv_int8, head_blocks):
+        """Sixteen ungrouped heads, the three bf16 serving cells' layout:
+        the sweep offers a bf16 pool every head in one grid step beside
+        8, 4, 2 and 1, a float32 or int8 pool eight at most, and the
+        dispatch ran each candidate at the head block it names."""
+        from benchmarks.kernel_tuning import sweep_paged_attention
+        ran = []
+        (key, entry), = sweep_paged_attention(
+            3, 16, 16, 16, 2, dtype=dtype, kv_int8=kv_int8,
+            lengths=[20, 0, 31], calls=2, trials=1,
+            log=lambda line: ran.append(line)).items()
+        assert key.startswith("paged_attention/page16/sq3_sk32_d16_")
+        swept = entry["swept"]
+        assert [(e["block_k"], e["head_block"]) for e in swept] == [
+            (bk, hb) for bk in (16, 32) for hb in head_blocks]
+        assert all(e["rows"] == e["head_block"] for e in swept)
+        assert not any("infeasible" in line for line in ran)
+        # the last candidate's dispatch: one head a step
+        rec = tuning.last_dispatch("paged_attention")["page16"]
+        assert (rec["head_block"], rec["rows"]) == (1, 1)
 
     @pytest.mark.parametrize("down,out", [(False, "bfloat16"),
                                           (True, "float32")],

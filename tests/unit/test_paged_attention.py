@@ -661,6 +661,30 @@ class TestProductsInThePoolsType:
                                    atol=1e-5, rtol=1e-5)
 
 
+    @pytest.mark.parametrize("dtype,head_block", [("bf16", 16), ("f32", 8)])
+    def test_decode_attention_asked_for_sixteen_heads(self, dtype,
+                                                      head_block):
+        """The contiguous cache's kernel through the same rule: sixteen
+        heads a step over a bf16 cache where the caller asks, eight over
+        a float32 one (its arm's mask cut aborts Mosaic past the eighth
+        row), and the dense form's result either way."""
+        from deepspeed_tpu.ops.pallas import decode_attention
+        from deepspeed_tpu.ops.pallas.decode_attention import _decode_dense
+        r = np.random.RandomState(53)
+        cdt = jnp.bfloat16 if dtype == "bf16" else jnp.float32
+        k, v = (jnp.asarray(r.randn(2, 16, 32, 256), cdt) for _ in range(2))
+        q = _bf16_values(r, 2, 1, 16, 32)
+        lens = jnp.asarray([130, 0], jnp.int32)
+        tuning.clear_last_dispatch()
+        got = decode_attention(q, k, v, lens, block_k=128, head_block=16)
+        rec = tuning.last_dispatch("decode_attention")["dma"]
+        assert (rec["impl"], rec["head_block"]) == ("kernel", head_block)
+        want = _decode_dense(q[:, 0], k, v, lens, jnp.zeros((16,)),
+                             scale=32 ** -0.5, alibi=False)
+        np.testing.assert_allclose(np.asarray(got[:, 0]), np.asarray(want),
+                                   atol=1e-5, rtol=1e-5)
+
+
 class TestTuningDispatch:
     def test_runtime_table_entry_consumed(self):
         """The shape-keyed tuning cache resolves the kernel's blocks at
@@ -696,8 +720,9 @@ class TestTuningDispatch:
     def test_the_serving_cells_shape_takes_the_swept_block(self):
         """32 slots x 2048 tokens, 16 heads of 128 in bf16, pages of 128
         — the key the GPT-2 1.3B serving cells and OLMoE's share — is in
-        the committed table with the block swept on the v5e; a shape
-        beside it still falls to the constants."""
+        the committed table with the block and the head block swept on
+        the v5e (all sixteen heads a grid step: PR 51); a shape beside
+        it still falls to the constants, eight heads a step."""
         S = jax.ShapeDtypeStruct
         bf = jnp.bfloat16
 
@@ -713,9 +738,130 @@ class TestTuningDispatch:
 
         rec = dispatch(32)
         assert rec["source"] == "defaults" and rec["products"] == "bfloat16"
-        assert (rec["block_k"], rec["head_block"]) == (256, 8)
+        assert (rec["block_k"], rec["head_block"], rec["rows"]) == (
+            128, 16, 16)
         rec = dispatch(8)
         assert rec["source"] == "constants" and rec["block_k"] == 512
+        assert (rec["head_block"], rec["rows"]) == (8, 8)
+
+    # Sixteen ungrouped heads over a bf16 pool at the serving cells' key
+    # (32 slots, 16 pages of 128 a row, heads of 128): the table asks for
+    # all sixteen in one grid step (PR 51), and a row's arithmetic does
+    # not depend on which heads share its step — the result is
+    # ``head_block`` 8's bit for bit. Ragged lengths, rows of length
+    # zero whose tables name poisoned pages, NaN in every page nobody
+    # owns, the stacked pool, ALiBi.
+    CELLS_KEY = "paged_attention/page128/sq32_sk2048_d128_bfloat16_causal"
+    CELLS_LENS = {0: 2 * 128 + 7, 2: 1, 7: 5 * 128 - 1, 9: 128,
+                  31: 7 * 128 + 100}
+    CELLS_PAGES = 21                 # the null page, 18 owned, 2 nobody's
+
+    def _cells_case(self, form, seed=71):
+        r = np.random.RandomState(seed)
+        layers, heads, d, page = 2, 16, 128, 128
+        kp, vp = (jnp.asarray(r.standard_normal(
+            (layers, self.CELLS_PAGES, heads, d, page)).astype(np.float32),
+            jnp.bfloat16) for _ in range(2))
+        bad = jnp.asarray([0, 19, 20])
+        kp, vp = kp.at[:, bad].set(jnp.nan), vp.at[:, bad].set(jnp.nan)
+        lens = np.zeros(32, np.int32)
+        # a row that does not decode still names the pages it held
+        ptab = np.full((32, 16), 19, np.int32)
+        ptab[:, 8:] = 0
+        own = iter(r.permutation(18) + 1)
+        for row, n in self.CELLS_LENS.items():
+            lens[row] = n
+            ptab[row] = 0
+            for j in range(-(-n // page)):
+                ptab[row, j] = next(own)
+        q = _bf16_values(r, 32, 1, heads, d).astype(jnp.bfloat16)
+        kn, vn = (_bf16_values(r, 32, heads, d, 1).astype(jnp.bfloat16)
+                  for _ in range(2))
+        kw = {"layer": jnp.int32(layers - 1)}
+        if form == "4d":
+            kp, vp, kw = kp[-1], vp[-1], {}
+        return (q, kp, vp, jnp.asarray(ptab), jnp.asarray(lens), kn,
+                vn), kw
+
+    def _at_eight(self, block_k):
+        return tuning.tuning_table({self.CELLS_KEY: {"block_k": block_k,
+                                                     "head_block": 8}})
+
+    @pytest.mark.parametrize("alibi", [False, True], ids=["plain", "alibi"])
+    @pytest.mark.parametrize("form", ["4d", "stacked"])
+    def test_sixteen_bf16_heads_take_one_step_and_rows_keep_their_bits(
+            self, form, alibi):
+        args, kw = self._cells_case(form)
+        if alibi:
+            kw["alibi_slopes"] = np.linspace(0.05, 0.8, 16).astype(np.float32)
+        run = jax.jit(lambda *a: paged_attention(*a, impl="kernel", **kw))
+        tuning.clear_last_dispatch()
+        got = np.asarray(run(*args).astype(jnp.float32))
+        rec = tuning.last_dispatch(KERNEL)["page128"]
+        assert rec["key"] == self.CELLS_KEY and rec["source"] == "defaults"
+        assert (rec["head_block"], rec["rows"]) == (16, 16)
+        assert rec["products"] == "bfloat16" and rec["impl"] == "kernel"
+        with self._at_eight(rec["block_k"]):
+            jax.clear_caches()
+            eight = np.asarray(run(*args).astype(jnp.float32))
+            at_eight = tuning.last_dispatch(KERNEL)["page128"]
+        jax.clear_caches()
+        assert (at_eight["head_block"], at_eight["rows"],
+                at_eight["block_k"]) == (8, 8, rec["block_k"])
+        assert np.isfinite(got).all()
+        np.testing.assert_array_equal(got, eight)
+        dense = np.asarray(paged_attention(*args, impl="dense", **kw)
+                           .astype(jnp.float32))
+        np.testing.assert_allclose(got, dense, atol=2e-2, rtol=2e-2)
+        # a row of length zero attends its own token alone
+        own = np.asarray(args[6].astype(jnp.float32))[..., 0]
+        for row in (1, 30):
+            np.testing.assert_array_equal(got[row, 0], own[row])
+
+    @pytest.mark.parametrize("model,data", [(2, 4), (4, 2)],
+                             ids=["8-a-shard", "4-a-shard"])
+    def test_sixteen_bf16_heads_over_the_model_axis(self, model, data):
+        """Under ``mp_size`` 2 | 4 a device holds 8 | 4 of the heads and
+        its grid step takes them all, as before: the same bits again."""
+        from deepspeed_tpu.comm import MeshSpec, build_mesh
+        args, kw = self._cells_case("stacked")
+        mesh = build_mesh(MeshSpec(model=model, data=data))
+        tuning.clear_last_dispatch()
+        got = jax.jit(lambda *a: paged_attention(
+            *a, impl="kernel", mesh=mesh, **kw))(*args)
+        rec = tuning.last_dispatch(KERNEL)["page128"]
+        assert (rec["head_block"], rec["rows"], rec["model_shards"]) == (
+            16 // model, 16 // model, model)
+        with self._at_eight(rec["block_k"]):
+            eight = jax.jit(lambda *a: paged_attention(
+                *a, impl="kernel", **kw))(*args)
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(eight))
+
+    @pytest.mark.parametrize("dtype", ["f32", "int8"])
+    def test_float32_products_of_sixteen_heads_keep_eight_a_step(self,
+                                                                 dtype):
+        """A float32 pool and an int8 one, asked for sixteen heads a
+        step: the float32 arm is held to eight rows (Mosaic aborts on its
+        cut of the boolean mask past the eighth), whatever is asked."""
+        kp, vp = _pool(seed=16, heads=16)
+        q, kn, vn = _operands(seed=17, heads=16)
+        ptab = jnp.asarray([[1, 2, 3], [4, 0, 0], [0, 0, 0]], jnp.int32)
+        lens = jnp.asarray([2 * PAGE + 5, 3, 0], jnp.int32)
+        scales = {}
+        if dtype == "int8":
+            kp, vp, ks, vs = _quantize_pool(kp, vp)
+            scales = {"k_scale": ks, "v_scale": vs}
+        run = lambda impl: paged_attention(
+            q, kp, vp, ptab, lens, kn, vn, impl=impl, head_block=16,
+            **scales)
+        tuning.clear_last_dispatch()
+        got = run("kernel")
+        rec = tuning.last_dispatch(KERNEL)["page%d" % PAGE]
+        assert (rec["impl"], rec["head_block"], rec["rows"],
+                rec["products"]) == ("kernel", 8, 8, "float32")
+        dense = run("dense")
+        np.testing.assert_allclose(np.asarray(got), np.asarray(dense),
+                                   atol=1e-4, rtol=1e-4)
 
     def test_kernel_knob_validation(self):
         with pytest.raises(ValueError, match="kernel"):

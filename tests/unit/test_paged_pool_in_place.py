@@ -182,12 +182,13 @@ def _compile_decode(model, args, static):
 
 
 # ---------------------------------------------------------------------------
-# the paged kernel alone at grouped bf16 shapes, whose grid steps hold more
-# than eight query-head rows (PR 49). Mosaic does not raise on a slice it
-# cannot lay out, it aborts the process: each compile runs in a child with
-# a time limit, so an abort is one failed test and not a dead worker. These
-# stand first in the file: the child has to load the TPU's compiler, which
-# one process at a time may (the worker loads it for the tests below)
+# the paged kernel alone at bf16 shapes whose grid steps hold more than
+# eight query-head rows: grouped heads (PR 49), sixteen ungrouped (PR 51).
+# Mosaic does not raise on a slice it cannot lay out, it aborts the
+# process: each compile runs in a child with a time limit, so an abort is
+# one failed test and not a dead worker. These stand first in the file:
+# the child has to load the TPU's compiler, which one process at a time
+# may (the worker loads it for the tests below)
 # ---------------------------------------------------------------------------
 
 _COMPILE_PAGED = """
@@ -234,8 +235,13 @@ print(json.dumps({"record": rec, "mosaic_calls": compiled.as_text().count(
     # rows a step any geometry here makes (a group is at most eight)
     ((32, 32, 8, 64, 2, 32), 8, 32, "constants"),
     ((64, 40, 8, 128, 2, 16), 8, 40, "defaults"),
-], ids=["falcon-h1-20on4", "32on8-d64", "40on8"])
-def test_grouped_bf16_steps_of_many_rows_compile_for_the_chip(
+    # serve-olmoe-longgen (a stacked pool of 8 layers) and the two
+    # serve-1p3b cells (24): 32 rows, sixteen ungrouped heads of 128, all of
+    # them one grid step at the table's block (PR 51)
+    ((32, 16, 16, 128, 8, 16), 16, 16, "defaults"),
+    ((32, 16, 16, 128, 24, 16), 16, 16, "defaults"),
+], ids=["falcon-h1-20on4", "32on8-d64", "40on8", "olmoe-16", "gpt2-1p3b-16"])
+def test_bf16_steps_of_many_rows_compile_for_the_chip(
         shape, head_block, rows, source):
     import json
     import subprocess
