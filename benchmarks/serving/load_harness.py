@@ -262,21 +262,6 @@ def replay(engine, trace):
     return [handles[t["id"]] for t in trace]
 
 
-def build_demo_model(*, vocab_size=256, max_seq_len=256, d_model=64,
-                     n_layers=2, n_heads=2, seed=0):
-    """Random-init GPT for harness/demo runs (no checkpoint needed)."""
-    import jax
-    import jax.numpy as jnp
-    from deepspeed_tpu.models.gpt import GPT, GPTConfig
-    cfg = GPTConfig(vocab_size=vocab_size, max_seq_len=max_seq_len,
-                    d_model=d_model, n_layers=n_layers, n_heads=n_heads,
-                    dtype=jnp.float32)
-    model = GPT(cfg)
-    params = model.init(jax.random.PRNGKey(seed),
-                        jnp.ones((1, 8), jnp.int32))["params"]
-    return model, params
-
-
 def _scenario_knobs(args):
     """Resolve the trace-shaping knobs for the chosen scenario. The
     ``prefix-adversarial`` scenario fills in any knob the caller left at
@@ -337,6 +322,7 @@ def _qos_config(args):
 
 
 def run_benchmark(args):
+    from deepspeed_tpu.models.gpt import build_demo_model
     from deepspeed_tpu.serving import ServingConfig
     from deepspeed_tpu.serving.engine import ServingEngine
     from deepspeed_tpu.serving.paging import PagingConfig
@@ -691,6 +677,7 @@ def run_spec_benchmark(args):
     ``decode_iterations_ratio`` — emitted-tokens-per-dispatch
     compression on the deterministic step clock (wall tokens/s rides
     along but is hardware-dependent)."""
+    from deepspeed_tpu.models.gpt import build_demo_model
     knobs = _scenario_knobs(args)
     trace = make_trace(
         args.seed, args.num_requests,
@@ -748,6 +735,7 @@ def _build_fleet(args, router: str):
     """One fleet per A/B arm: same model/seed/geometry, only the router
     policy differs — the comparison is dispatch policy, nothing else.
     Prefix affinity exists to feed the radix cache."""
+    from deepspeed_tpu.models.gpt import build_demo_model
     from deepspeed_tpu.serving import ServingConfig
     from deepspeed_tpu.serving.fleet.config import FleetConfig
     from deepspeed_tpu.serving.fleet.manager import ServingFleet

@@ -355,3 +355,17 @@ def gpt_loss_fn(logits, labels, loss_mask=None, z_loss=0.0):
         nll = nll * loss_mask
         return jnp.sum(nll) / jnp.maximum(jnp.sum(loss_mask), 1.0)
     return jnp.mean(nll)
+
+
+def build_demo_model(*, vocab_size=256, max_seq_len=256, d_model=64,
+                     n_layers=2, n_heads=2, seed=0):
+    """Random-init float32 GPT and its params: what a fleet worker
+    without a checkpoint serves, and the load harness, the chaos and
+    serve CLIs' demo model."""
+    cfg = GPTConfig(vocab_size=vocab_size, max_seq_len=max_seq_len,
+                    d_model=d_model, n_layers=n_layers, n_heads=n_heads,
+                    dtype=jnp.float32)
+    model = GPT(cfg)
+    params = model.init(jax.random.PRNGKey(seed),
+                        jnp.ones((1, 8), jnp.int32))["params"]
+    return model, params

@@ -7,7 +7,9 @@ change across requests (every new shape is an XLA recompile). The
 engine therefore decodes a fixed batch of ``num_slots`` rows whose K/V
 lives in a global page pool with one page table per slot
 (serving/paging/, the ``serving.paging`` block) and drives exactly TWO
-compiled programs (serving/paging/manager.py):
+compiled programs, each through the manager that defines them and owns
+what they read and write (serving/paging/manager.py ``PagedKVManager``:
+the engine hands over what only it knows and reads a ``ProgramResult``):
 
 - ``serving/chunk_prefill``: prefill one page-aligned chunk of one
   request into its slot's pages; the last chunk samples the first
@@ -60,7 +62,6 @@ from ..inference.generation import _sampling_mode
 from ..observability.goodput import get_ledger as _goodput_ledger
 from ..observability.fleet import make_trace_id
 from ..observability.memory import get_accountant, is_oom_error, oom_forensics
-from ..observability.programs import track_program
 from ..observability.trace import active_tracer as _active_tracer
 from ..observability.trace import span as _span
 from ..utils.logging import log_dist
@@ -71,10 +72,9 @@ from .request import PREEMPTED, Request
 from .scheduler import FifoScheduler
 from .metrics import (ADMIT, DECODE_DISPATCH, HARVEST, OTHER,
                       PREFILL_DISPATCH, ServingMetrics, collector_hook)
-from .paging.config import CHUNK_PAGES, chunk_pages
-from .paging.manager import (CHUNK_PREFILL_STATICS, PagedKVManager,
-                             _chunk_prefill_jit, _paged_decode_jit)
-from .speculation import NgramProposer, _spec_verify_jit
+from .paging.config import chunk_pages
+from .paging.manager import PagedKVManager
+from .speculation import NgramProposer
 
 
 def _counts_read(counts):
@@ -198,9 +198,6 @@ class ServingEngine:
         collector_hook.watch(self._clock)
         self._chunk_counts = {}           # slot -> router counts of its
                                           # prefill chunks so far (device)
-        self._chunk_programs = {}         # chunk tokens -> the program
-                                          # compiled ahead at that width
-                                          # (compile_chunk_programs)
         self._iteration = 0
         self._seq = 0
         # QoS plane (serving/qos.py): priority preemption, SLO shedding,
@@ -324,23 +321,13 @@ class ServingEngine:
     def _account_memory(self):
         """Tag the engine's resident device buffers in the process HBM
         accountant (observability/memory.py) and publish the serving
-        memory gauges. Shape metadata only — no device reads. The paged
-        decode's contiguous gather scratch is derived from the pool's
-        own leaf shapes (the figure the PR-6 artifact hand-computed)."""
+        memory gauges. Shape metadata only — no device reads. The pool's
+        share, its gather scratch and a model's state bytes are the
+        manager's to state (``PagedKVManager.account``)."""
         acct = get_accountant()
         acct.account("serving/params", self.params)
-        acct.account("serving/kv_pool", num_bytes=self._paged.pool_bytes(),
-                     name="page_pool")
-        acct.account("serving/kv_pool", self._paged.page_table,
-                     name="page_table")
-        acct.registry.gauge("mem/decode_gather_transient").set(
-            self._paged.decode_gather_transient_bytes())
         acct.account("serving/state", self._state)
-        if self._paged.has_state and self.metrics.registry is not None:
-            self.metrics.registry.gauge("serving/state_bytes").set(
-                self._paged.state_bytes())
-        acct.registry.gauge("mem/kv_pool_resident").set(
-            acct.subsystem_bytes("serving/kv_pool"))
+        self._paged.account(acct, self.metrics.registry)
 
     def memory_report(self) -> dict:
         """Serving-side memory block (the BENCH_serving artifact embeds
@@ -510,20 +497,12 @@ class ServingEngine:
         for slot, req in enumerate(self._slot_req):
             if req is not None and req.request_id == request_id:
                 # deactivate the device-side row so in-flight/future decode
-                # iterations mask this slot out, then recycle it
-                self._state = {
-                    **self._state,
-                    "active": self._state["active"].at[slot].set(False),
-                    "remaining": self._state["remaining"].at[slot].set(0),
-                }
-                # drop any unfinished prefill chunks and return the
-                # slot's page references (prefix-published pages stay
-                # alive through the tree's own reference)
+                # iterations mask this slot out, drop any unfinished
+                # prefill chunks, then recycle it
+                self._mask_row(slot)
                 self._prefill_tasks = deque(
                     t for t in self._prefill_tasks if t[0] != slot)
-                self._paged.release_slot(slot)
-                self._slot_req[slot] = None
-                self._free.append(slot)
+                self._vacate(slot)
                 req._cancelled(self._iteration)
                 self.metrics.on_cancel(req)
                 return True
@@ -728,6 +707,23 @@ class ServingEngine:
     def _take_slot(self, slot: int):
         self._free.remove(slot)
 
+    def _vacate(self, slot: int):
+        """Give the slot back: its page references return to the pool
+        (prefix-published pages stay alive through the radix tree's own
+        reference) and the slot is free to admit into."""
+        self._paged.release_slot(slot)
+        self._slot_req[slot] = None
+        self._free.append(slot)
+
+    def _mask_row(self, slot: int):
+        """Deactivate the slot's device row: decode iterations in flight
+        or to come mask it out."""
+        self._state = {
+            **self._state,
+            "active": self._state["active"].at[slot].set(False),
+            "remaining": self._state["remaining"].at[slot].set(0),
+        }
+
     # -- priority preemption -----------------------------------------------
     def _try_preempt_for(self, head: Request, need: str = "slot") -> bool:
         """Free capacity for an at-risk high-priority queue head by
@@ -787,16 +783,10 @@ class ServingEngine:
         drained — undelivered tokens would otherwise be lost to the
         resume prompt."""
         req = self._slot_req[slot]
-        self._state = {
-            **self._state,
-            "active": self._state["active"].at[slot].set(False),
-            "remaining": self._state["remaining"].at[slot].set(0),
-        }
+        self._mask_row(slot)
         self._prefill_tasks = deque(
             t for t in self._prefill_tasks if t[0] != slot)
-        self._paged.release_slot(slot)
-        self._slot_req[slot] = None
-        self._free.append(slot)
+        self._vacate(slot)
         # close this RUNNING period's residency span now: resumption
         # re-stamps admitted_at_ns, so each slot tenancy is recorded
         # exactly once (queue_wait's preempt->re-admit twin)
@@ -836,8 +826,8 @@ class ServingEngine:
             # for a fresh request these are just prompt / max_new_tokens
             prompt = req.effective_prompt()
             max_new = req.remaining_budget()
-            shared = self._paged.try_admit(slot, prompt, max_new)
-            if shared is None:              # page-starved head
+            admission = self._paged.try_admit(slot, prompt, max_new)
+            if admission is None:           # page-starved head
                 if self._try_preempt_for(req, need="pages"):
                     continue                # preemption released pages
                 return
@@ -847,25 +837,15 @@ class ServingEngine:
             resumed = req.status == PREEMPTED
             self._slot_req[slot] = req
             req._admitted(slot, self._iteration)
-            restored = None
-            if self._paged.has_state:
-                # the first chunk's program reads the stored state by
-                # its page: the host's part is to know which, and count
-                args = {"slot": slot, "page": None}
-                with _span("serving/state_restore", args):
-                    args["page"] = self._paged.state_restore_page(slot,
-                                                                  shared)
-                restored = args["page"] is not None
-            self.metrics.on_admit(
-                req, shared_tokens=shared, state_restored=restored,
-                state_missed=self._paged.state_restore_missed(slot))
+            self.metrics.on_admit(req, *admission)
             if resumed:
                 self.metrics.on_resume(req)
             # the plan is where the next chunk starts: the non-shared
             # tail, cut into chunks as they are dispatched. Always at
             # least one — the prefix match caps at the last prefill
             # token, whose logits seed sampling
-            self._prefill_tasks.append([slot, req, prompt, max_new, shared])
+            self._prefill_tasks.append([slot, req, prompt, max_new,
+                                        admission.shared_tokens])
 
     def _chunk_width(self, tokens_left: int) -> int:
         """Tokens of the head request's next chunk program, a page
@@ -918,31 +898,23 @@ class ServingEngine:
         ``chunk_pages`` may choose, from shapes, running nothing:
         ``InferenceEngine.serve()`` calls this before the first request,
         so that a width's first dispatch — which may come hours in, when
-        a queue first builds — neither traces nor compiles. With a fixed
-        ``prefill_chunk`` there is nothing to choose and the one width
-        compiles on first use, as does every width of an engine built
-        directly."""
-        mgr = self._paged
-        if self.config.paging.prefill_chunk is not None:
-            return
-        greedy, has_k, has_p, t, k, p = self._mode
-        zero = jnp.int32(0)
+        a queue first builds — neither traces nor compiles. A width not
+        asked for ahead (a fixed ``prefill_chunk``'s one, every width of
+        an engine built directly) is compiled by the same call at its
+        first dispatch: the manager keeps one table of executables."""
         with self._trace_scope():
-            for pages in CHUNK_PAGES:
-                tokens = pages * mgr.page_len
-                if pages > mgr.max_pages:
-                    continue
-                snaps = (() if mgr.snapshots is None else
-                         (jnp.int32(-1), jnp.zeros((pages,), jnp.int32)))
-                self._chunk_programs[tokens] = \
-                    _chunk_prefill_jit.compile_ahead(
-                        self.module, self.params, mgr.pool, self._state,
-                        mgr.page_table[0], np.zeros((1, tokens), np.int32),
-                        zero, zero, zero, zero, jnp.asarray(False),
-                        self._rng, self._eos, t, k, p,
-                        self._param_transform, greedy, has_k, has_p,
-                        mgr.dequant_dtype, *snaps,
-                        static_argnums=CHUNK_PREFILL_STATICS)
+            self._paged.compile_chunks(
+                self.module, self.params, self._state, self._rng, self._eos,
+                self._mode, self._param_transform)
+
+    def _first_chunk(self, slot: int, req):
+        """Stamp the admission's first chunk (the wait from admission is
+        telemetry: kept out of the dispatch, which decides nothing on a
+        clock) and forget a preempted prefill's router counts."""
+        self._chunk_counts.pop(slot, None)
+        req.first_chunk_at_ns = time.perf_counter_ns()
+        self.metrics.on_prefill_wait(
+            req.first_chunk_at_ns - req.admitted_at_ns)
 
     def _dispatch_chunk(self, slot: int, req, prompt, max_new: int,
                         start: int, width: int, is_last: bool) -> bool:
@@ -957,52 +929,38 @@ class ServingEngine:
         real = min(start + width, p_len) - start
         padded = np.zeros((1, width), np.int32)
         padded[0, :real] = prompt[start:start + real]
-        greedy, has_k, has_p, t, k, p = self._mode
         mgr = self._paged
         pages = width // mgr.page_len
-        program = self._chunk_programs.get(width, _chunk_prefill_jit)
-        # a snapshot pool's entries for this chunk (nothing for any other
-        # model: its program has no such arguments)
-        snaps = mgr.chunk_snapshots(slot, start, pages)
         if req.first_chunk_at_ns is None:
-            self._chunk_counts.pop(slot, None)  # a preempted prefill's
-            req.first_chunk_at_ns = time.perf_counter_ns()
-            self.metrics.on_prefill_wait(
-                req.first_chunk_at_ns - req.admitted_at_ns)
+            self._first_chunk(slot, req)
         try:
             with _span("serving/prefill_chunk",
                        {"slot": slot, "request_id": req.request_id,
                         "trace_id": req.trace_id,
                         "start": start, "tokens": real, "pages": pages,
                         "last": bool(is_last)}):
-                mgr.pool, self._state, tok, done, counts = program(
-                    self.module, self.params, mgr.pool, self._state,
-                    mgr.page_table[slot], jnp.asarray(padded),
-                    jnp.int32(start), jnp.int32(p_len), jnp.int32(slot),
-                    jnp.int32(max_new), jnp.asarray(is_last),
-                    self._req_rng(req), self._eos, t, k, p,
-                    self._param_transform, greedy, has_k, has_p,
-                    mgr.dequant_dtype, *snaps)
+                out = mgr.prefill_chunk(
+                    self.module, self.params, self._state, slot, padded,
+                    start, p_len, max_new, is_last, self._req_rng(req),
+                    self._eos, self._mode, self._param_transform)
         except Exception as e:
             if not is_oom_error(e):
                 raise
             self._shed_on_oom(req, "chunk_prefill", e)
             return False
-        self.metrics.on_prefill_chunk(
-            real, real // mgr.page_len
-            if mgr.has_state and mgr.snapshots is None else 0, pages=pages)
-        if mgr.snapshots is not None:
-            self.metrics.on_state_snapshots(mgr.snapshots)
-        if counts is not None:
+        self._state = out.state
+        self.metrics.on_prefill_chunk(real, out.state_pages, pages=pages,
+                                      snapshot_table=out.snapshot_table)
+        if out.counts is not None:
             # an expert layer's routing of this chunk: read back with the
             # first token, by when every earlier chunk has finished
-            self._chunk_counts.setdefault(slot, []).append(counts)
+            self._chunk_counts.setdefault(slot, []).append(out.counts)
         if is_last:
             # pages below the prompt's full-page boundary are immutable
             # from here (decode appends strictly past them): publish them
             # for copy-free reuse by later identical prefixes
             mgr.publish(slot, prompt)
-            self._pending.append(("admit", slot, req, tok, done,
+            self._pending.append(("admit", slot, req, out.tokens, out.done,
                                   self._chunk_counts.pop(slot, [])))
         return True
 
@@ -1021,24 +979,21 @@ class ServingEngine:
                 return self._dispatch_spec_verify(*proposals)
             if all(r is None for r in self._slot_req):
                 return False    # the proposal drain finished every slot
-        greedy, has_k, has_p, t, k, p = self._mode
         snapshot = list(self._slot_req)
         busy = sum(r is not None for r in snapshot)
-        rng = self._decode_rng
         # active request count on the span: trace captures show how full
         # each decode dispatch ran (the SLO-reconstruction groundwork)
         with _span("serving/decode_iter", {"active_requests": busy,
                                            "iteration": self._iteration}):
-            mgr = self._paged
-            mgr.pool, self._state, toks, done, counts = _paged_decode_jit(
-                self.module, self.params, mgr.pool, mgr.page_table,
-                self._state, rng, jnp.int32(self._iteration),
-                self._eos, t, k, p, self._param_transform, greedy,
-                has_k, has_p, mgr.use_kernel, mgr.dequant_dtype)
+            out = self._paged.decode(
+                self.module, self.params, self._state, self._decode_rng,
+                self._iteration, self._eos, self._mode,
+                self._param_transform)
+        self._state = out.state
         self.metrics.on_decode_dispatch(self._decoding_slots(busy),
                                         self.config.num_slots)
-        self._pending.append(("decode", snapshot, toks, done,
-                              _counts_read(counts)))
+        self._pending.append(("decode", snapshot, out.tokens, out.done,
+                              _counts_read(out.counts)))
         self._iteration += 1
         return True
 
@@ -1099,23 +1054,19 @@ class ServingEngine:
         step would). Counts as one decode iteration on the step clock —
         TTFT/steps percentiles stay iteration-denominated while token
         counters take the full emitted count at harvest."""
-        greedy, has_k, has_p, t, k, p = self._mode
         snapshot = list(self._slot_req)
         busy = sum(r is not None for r in snapshot)
-        rng = self._decode_rng
         with _span("serving/spec_verify",
                    {"active_requests": busy, "iteration": self._iteration,
                     "proposed_tokens": int(counts.sum())}):
-            mgr = self._paged
-            mgr.pool, self._state, toks, done = _spec_verify_jit(
-                self.module, self.params, mgr.pool, mgr.page_table,
-                self._state, jnp.asarray(props), jnp.asarray(counts),
-                rng, jnp.int32(self._iteration), self._eos, t, k, p,
-                self._param_transform, greedy, has_k, has_p,
-                mgr.dequant_dtype)
+            out = self._paged.spec_verify(
+                self.module, self.params, self._state, props, counts,
+                self._decode_rng, self._iteration, self._eos, self._mode,
+                self._param_transform)
+        self._state = out.state
         self.metrics.on_decode_dispatch(self._decoding_slots(busy),
                                         self.config.num_slots)
-        self._pending.append(("spec", snapshot, toks, done, counts))
+        self._pending.append(("spec", snapshot, out.tokens, out.done, counts))
         self._iteration += 1
         return True
 
@@ -1168,11 +1119,7 @@ class ServingEngine:
                     # prefill role: mask the device row (this engine
                     # never decodes it) and stage the slot for a page
                     # handoff — pages stay allocated until export
-                    self._state = {
-                        **self._state,
-                        "active": self._state["active"].at[slot].set(False),
-                        "remaining": self._state["remaining"].at[slot].set(0),
-                    }
+                    self._mask_row(slot)
                     self._handoff_ready.append((slot, req))
                 return
             if entry[0] == "spec":
@@ -1204,16 +1151,10 @@ class ServingEngine:
             _, snapshot, toks, done, counts = entry
             toks, done, *counts = self._read_back(toks, done, *counts)
             self._fold_moe_counts(counts)
-            if self._paged.use_kernel:
-                self.metrics.on_decode_harvest(np.count_nonzero(toks >= 0))
-            if self._paged.has_latent:
-                # before the tokens are emitted: a row that kept one
-                # attended its prompt and every token it had generated
-                # but the one it was fed
-                self.metrics.on_latent_walk(sum(
-                    req.prompt.shape[0] + len(req.output_tokens) - 1
-                    for slot, req in enumerate(snapshot)
-                    if req is not None and not req.done and toks[slot] >= 0))
+            # before the tokens are emitted: what the pool's kernel walked
+            rows, latent = self._paged.decode_walked(toks, snapshot)
+            self.metrics.on_decode_harvest(rows)
+            self.metrics.on_latent_walk(latent)
             for slot, req in enumerate(snapshot):
                 if req is None or req.done:  # empty, or cancelled in flight
                     continue
@@ -1239,11 +1180,7 @@ class ServingEngine:
         self._record_residency(req)
         req._finished(self._iteration)
         self.metrics.on_finish(req)
-        # return the slot's page references; prefix-published pages
-        # survive through the radix tree's own refcount
-        self._paged.release_slot(slot)
-        self._slot_req[slot] = None
-        self._free.append(slot)
+        self._vacate(slot)
 
     # -- fault containment + recovery --------------------------------------
     def _shed_on_oom(self, req: Request, where: str, err: Exception):
@@ -1386,9 +1323,7 @@ class ServingEngine:
                             "max_new_tokens": int(req.max_new_tokens),
                             "priority": int(req.priority)},
             }
-            self._paged.release_slot(slot)
-        self._slot_req[slot] = None
-        self._free.append(slot)
+            self._vacate(slot)
         self.metrics.on_handoff_export(req)
         return payload
 

@@ -49,13 +49,12 @@ def _build_engine(spec: dict):
     from ..engine import ServingEngine
     model_spec = dict(spec.get("model") or {})
     seed = model_spec.pop("seed", 0)
+    from ...models.gpt import GPT, GPTConfig, build_demo_model
     if spec.get("checkpoint"):
-        from ...models.gpt import GPT, GPTConfig
         from ...runtime.checkpointing import load_module_params
         params = load_module_params(spec["checkpoint"])
         module = GPT(GPTConfig(**model_spec))
     else:
-        from benchmarks.serving.load_harness import build_demo_model
         module, params = build_demo_model(seed=seed, **model_spec)
     serving = dict(spec.get("serving") or {})
     serving.pop("fleet", None)      # a replica IS the fleet's leaf

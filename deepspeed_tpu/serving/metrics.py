@@ -399,12 +399,14 @@ class ServingMetrics:
                                        None))
 
     def on_prefill_chunk(self, tokens_computed: int,
-                         state_snapshots: int = 0, pages: int = 1):
+                         state_snapshots: int = 0, pages: int = 1,
+                         snapshot_table=None):
         """``state_snapshots``: the pages this chunk filled whole, each
         stored with the recurrent state at its end (0 for a model
         without such state). ``pages``: the width of the chunk program
         that ran, padding counted — over the chunks, the mean width the
-        server chose."""
+        server chose. ``snapshot_table``: a snapshot pool's, whose
+        counts are brought up to date (``on_state_snapshots``)."""
         self.prefill_chunks += 1
         self.prefill_chunk_pages += pages
         self.clock.row[CHUNK_PAGES] += pages
@@ -417,6 +419,8 @@ class ServingMetrics:
             if state_snapshots:
                 self.registry.counter("serving/state_snapshots_stored").inc(
                     state_snapshots)
+        if snapshot_table is not None:
+            self.on_state_snapshots(snapshot_table)
 
     def on_state_snapshots(self, table):
         """After a chunk of a model with a snapshot pool
@@ -447,23 +451,24 @@ class ServingMetrics:
             self.registry.counter("serving/decode_slots_offered").inc(
                 num_slots)
 
-    def on_decode_harvest(self, rows_walked: int):
+    def on_decode_harvest(self, rows_walked: Optional[int]):
         """One kernel-path paged decode dispatch, read back: the rows
         whose token the program kept (not -1) are the rows it was active
         for, and only those were handed a non-zero length for the paged
         kernel to walk. Against ``serving/decode_slots_offered``: the
-        share of the batch the kernel read pages for."""
-        if self.registry is not None:
+        share of the batch the kernel read pages for. None (the gather
+        path walks no pages by row) counts nothing."""
+        if self.registry is not None and rows_walked is not None:
             self.registry.counter("serving/paged_rows_walked").inc(
                 rows_walked)
 
-    def on_latent_walk(self, tokens: int):
+    def on_latent_walk(self, tokens: Optional[int]):
         """One decode dispatch over a latent page pool, read back: the
         pooled tokens the rows that decoded attended, summed (the host's
         own count: a row's prompt and what it had generated). Times the
         layers and a token's latent bytes it is what the latent kernel
-        had to read."""
-        if self.registry is not None:
+        had to read. None (a pool of K and V heads) counts nothing."""
+        if self.registry is not None and tokens is not None:
             self.registry.counter("serving/latent_tokens_walked").inc(tokens)
 
     def on_moe_counts(self, counts, shared_rows=None):

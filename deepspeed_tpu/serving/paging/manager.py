@@ -33,7 +33,7 @@ request sizes, not ``max_len``). Prefix-cache hits shrink the
 allocation further: shared pages are referenced, not copied.
 """
 
-from typing import List, Optional
+from typing import Any, List, NamedTuple, Optional
 
 import numpy as np
 import jax
@@ -55,6 +55,7 @@ from ...observability.programs import track_program
 from ...observability.trace import span as _span
 from ...utils.logging import log_dist
 from .allocator import NULL_PAGE, PageAllocator
+from .config import CHUNK_PAGES
 from .prefix import PrefixCache
 from .snapshots import SnapshotTable
 
@@ -267,11 +268,41 @@ _chunk_prefill_jit = track_program(
             donate_argnums=(2, 3)), subsystem="serving")
 
 
+class ProgramResult(NamedTuple):
+    """What a served program hands the engine, named here on the host:
+    the impls return plain tuples (a ``NamedTuple`` out of a jit changes
+    the lowered text), and the pool stays with the manager."""
+    state: Any
+    tokens: Any                 # -1 where the row emitted none
+    done: Any
+    counts: Any                 # an expert layer's router counts, or None
+    state_pages: int = 0        # a chunk's whole pages, each stored with
+                                # the state at its end (a state a page)
+    snapshot_table: Any = None  # a chunk's: the snapshot pool's table
+
+
+class Admission(NamedTuple):
+    """What ``try_admit`` reserved, as admission counts it."""
+    shared_tokens: int
+    state_restored: Optional[bool]  # the first chunk starts from a stored
+                                    # state (None: the model keeps none)
+    state_missed: bool          # a hit shortened for want of a snapshot
+
+
+def _sampling(eos, mode, param_transform):
+    """The arguments every impl ends on, in its order (``mode`` is
+    ``generation._sampling_mode``'s tuple)."""
+    greedy, has_k, has_p, t, k, p = mode
+    return eos, t, k, p, param_transform, greedy, has_k, has_p
+
+
 class PagedKVManager:
-    """Host-side owner of the pool, the allocator, the prefix cache, and
-    the per-slot page tables. The engine calls it between jitted
-    dispatches; it never forces a device sync (page-table updates are
-    async ``.at[].set`` dispatches, stamped with trace spans)."""
+    """Host-side owner of the pool, the allocator, the prefix cache and
+    the per-slot page tables, and the one caller of the programs above:
+    the engine hands over what only it knows and reads a
+    ``ProgramResult``; what kinds of state a model keeps is known here.
+    It never forces a device sync (page-table updates are async
+    ``.at[].set`` dispatches, stamped with trace spans)."""
 
     def __init__(self, module, params, config):
         pcfg = config.paging
@@ -285,6 +316,8 @@ class PagedKVManager:
         self._num_slots = config.num_slots
         self.kv_quant = "int8" if config.kv_int8 else None
         self.use_kernel = self._resolve_kernel(pcfg.kernel)
+        self.chunk_programs = {}       # chunk tokens -> its executable
+                                       # (shapes outlive reset())
         self.reset()
         log_dist(
             f"paged KV: {self.num_pages - 1} usable pages x "
@@ -367,8 +400,8 @@ class PagedKVManager:
         return -(-(prompt_len + max_new) // self.page_len)
 
     def try_admit(self, slot: int, prompt: np.ndarray, max_new: int):
-        """Allocate (and prefix-match) pages for one request. Returns the
-        shared token count on success, or None when the pool cannot
+        """Allocate (and prefix-match) pages for one request. Returns its
+        ``Admission`` on success, or None when the pool cannot
         cover the request even after prefix-cache eviction — the caller
         leaves the request queued (admission gates on free pages)."""
         prompt_len = int(prompt.shape[0])
@@ -414,7 +447,16 @@ class PagedKVManager:
         with _span("serving/page_table_copy", {"slot": slot,
                                                "pages": len(pages)}):
             self.page_table = self.page_table.at[slot].set(row)
-        return len(shared) * self.page_len
+        restored = None
+        if self.has_state:
+            # the first chunk's program reads the stored state by its
+            # page (``chunk_state_view``): the host names it and counts
+            args = {"slot": slot, "page": shared[-1] if shared else None}
+            with _span("serving/state_restore", args):
+                restored = bool(shared)
+        plan = self._slot_snapshots[slot]
+        return Admission(len(shared) * self.page_len, restored,
+                         bool(plan and plan["missed"]))
 
     def _plan_snapshots(self, slot, prompt_len, shared, branch):
         """What the slot's chunks will do with the snapshot pool: the
@@ -438,7 +480,7 @@ class PagedKVManager:
             "restore": restore, "wanted": wanted, "missed": branch is not None,
             "held": None if branch is None else branch[1]}
 
-    def chunk_snapshots(self, slot: int, start: int, pages: int):
+    def _chunk_snapshots(self, slot: int, start: int, pages: int):
         """The snapshot arguments of the slot's chunk program over
         ``pages`` pages from token ``start``: ``(restore, snap_run)`` —
         the entry it starts from (-1: its slot's own state, or zeros at
@@ -498,6 +540,95 @@ class PagedKVManager:
         with _span("serving/page_table_copy", {"slot": slot, "pages": 0}):
             self.page_table = self.page_table.at[slot].set(
                 jnp.full((self.max_pages,), NULL_PAGE, jnp.int32))
+
+    # -- the served programs: each argument list is spelled here, once ----
+    def decode(self, module, params, state, rng, iteration, eos, mode,
+               param_transform) -> ProgramResult:
+        """One ``serving/paged_decode`` step over the slot batch."""
+        self.pool, *out = _paged_decode_jit(
+            module, params, self.pool, self.page_table, state, rng,
+            jnp.int32(iteration), *_sampling(eos, mode, param_transform),
+            self.use_kernel, self.dequant_dtype)
+        return ProgramResult(*out)
+
+    def spec_verify(self, module, params, state, proposals, counts, rng,
+                    iteration, eos, mode, param_transform) -> ProgramResult:
+        """One ``serving/spec_verify_iter`` step (serving/speculation.py,
+        which imports this package: hence imported here)."""
+        from ..speculation import _spec_verify_jit
+        self.pool, *out = _spec_verify_jit(
+            module, params, self.pool, self.page_table, state,
+            jnp.asarray(proposals), jnp.asarray(counts), rng,
+            jnp.int32(iteration), *_sampling(eos, mode, param_transform),
+            self.dequant_dtype)
+        return ProgramResult(*out, counts=None)
+
+    def _chunk_program(self, snaps, module, params, state, slot, chunk_ids,
+                       start, end_pos, max_new, is_last, rng, eos, mode,
+                       param_transform):
+        """``(executable, arguments)`` of ``serving/chunk_prefill`` at
+        the width of ``chunk_ids``: the one road to a chunk program. A
+        width not in the table yet is compiled from these very arguments
+        (nothing runs, nothing is donated) and kept."""
+        args = (module, params, self.pool, state, self.page_table[slot],
+                jnp.asarray(chunk_ids), jnp.int32(start), jnp.int32(end_pos),
+                jnp.int32(slot), jnp.int32(max_new), jnp.asarray(is_last),
+                rng, *_sampling(eos, mode, param_transform),
+                self.dequant_dtype, *snaps)
+        width = chunk_ids.shape[1]
+        if width not in self.chunk_programs:
+            self.chunk_programs[width] = _chunk_prefill_jit.compile_ahead(
+                *args, static_argnums=CHUNK_PREFILL_STATICS)
+        return self.chunk_programs[width], args
+
+    def compile_chunks(self, module, params, state, rng, eos, mode,
+                       param_transform):
+        """Compile the chunk program at every width ``chunk_pages`` may
+        choose, from shapes. A fixed ``prefill_chunk`` leaves nothing to
+        choose: its widths compile at their first dispatch."""
+        if self.config.prefill_chunk is not None:
+            return
+        for pages in (w for w in CHUNK_PAGES if w <= self.max_pages):
+            snaps = (() if self.snapshots is None else
+                     (jnp.int32(-1), jnp.zeros((pages,), jnp.int32)))
+            self._chunk_program(
+                snaps, module, params, state, 0,
+                np.zeros((1, pages * self.page_len), np.int32), 0, 0, 0,
+                False, rng, eos, mode, param_transform)
+
+    def prefill_chunk(self, module, params, state, slot, chunk_ids, start,
+                      end_pos, max_new, is_last, rng, eos, mode,
+                      param_transform) -> ProgramResult:
+        """One ``serving/chunk_prefill`` dispatch: ``chunk_ids`` (``[1,
+        width]``, right-padded) of the slot's prompt of ``end_pos``
+        tokens, from token ``start``."""
+        width = chunk_ids.shape[1]
+        program, args = self._chunk_program(
+            self._chunk_snapshots(slot, start, width // self.page_len),
+            module, params, state, slot, chunk_ids, start, end_pos, max_new,
+            is_last, rng, eos, mode, param_transform)
+        self.pool, *out = program(*args)
+        stored = 0
+        if self.has_state and self.snapshots is None:
+            stored = (min(start + width, end_pos) - start) // self.page_len
+        return ProgramResult(*out, state_pages=stored,
+                             snapshot_table=self.snapshots)
+
+    def decode_walked(self, tokens, requests):
+        """``(rows, latent tokens)`` the decode dispatch just read back
+        walked (``requests``: its slot -> request), each None where this
+        pool has no such count: the rows that kept a token, which alone
+        were handed a length, when the paged kernel runs; over a latent
+        pool the tokens those rows attended — a row's prompt and all it
+        had generated but the token it was fed (ask before they emit)."""
+        rows = np.count_nonzero(tokens >= 0) if self.use_kernel else None
+        latent = None
+        if self.has_latent:
+            latent = sum(
+                req.prompt.shape[0] + len(req.output_tokens) - 1
+                for slot, req in enumerate(requests)
+                if req is not None and not req.done and tokens[slot] >= 0)
+        return rows, latent
 
     # -- page-granular handoff (serving/fleet disaggregation) --------------
     def export_slot(self, slot: int, prefill_len: int):
@@ -573,22 +704,6 @@ class PagedKVManager:
                 "path's page geometry, scales or verification step "
                 "assume the heads")
 
-    def state_restore_page(self, slot: int, shared_tokens: int):
-        """The physical page whose stored state the slot's first prefill
-        chunk starts from (the last of its shared pages), or None when
-        it starts from zeros. The program reads the state itself
-        (``chunk_state_view``): the host only names it, for the
-        counters."""
-        if not self.has_state or not shared_tokens:
-            return None
-        return self._slot_pages[slot][shared_tokens // self.page_len - 1]
-
-    def state_restore_missed(self, slot: int) -> bool:
-        """Whether the slot's admission matched deeper in the prefix
-        cache than a snapshot let it start (its hit was shortened)."""
-        plan = self._slot_snapshots[slot]
-        return bool(plan and plan["missed"])
-
     def reset(self):
         """(Re)build the device pool and every host-side ownership structure
         from scratch — at construction, and on the fault-containment path
@@ -617,6 +732,21 @@ class PagedKVManager:
     def state_bytes(self) -> int:
         """Resident bytes of the recurrent state (0 for a model without)."""
         return self._state_bytes
+
+    def account(self, acct, registry):
+        """Tag the pool and the page tables in the HBM accountant
+        (observability/memory.py) and set the pool's gauges, a model's
+        state bytes in the serving metrics' ``registry`` (None: none is
+        kept). Shape metadata only — no device reads."""
+        acct.account("serving/kv_pool", num_bytes=self.pool_bytes(),
+                     name="page_pool")
+        acct.account("serving/kv_pool", self.page_table, name="page_table")
+        acct.registry.gauge("mem/decode_gather_transient").set(
+            self.decode_gather_transient_bytes())
+        acct.registry.gauge("mem/kv_pool_resident").set(
+            acct.subsystem_bytes("serving/kv_pool"))
+        if self.has_state and registry is not None:
+            registry.gauge("serving/state_bytes").set(self.state_bytes())
 
     def decode_gather_transient_bytes(self) -> int:
         """Bytes of the contiguous ``[num_slots, h, d, cache_len]`` view

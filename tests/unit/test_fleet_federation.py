@@ -474,7 +474,7 @@ class TestFederatedFleetEndToEnd:
         decode, KV handoffs as raw v3 blob frames) serves token-exact,
         then a mid-trace rolling update swaps both peers to new weights
         with zero dropped requests and per-version parity."""
-        from benchmarks.serving.load_harness import build_demo_model
+        from deepspeed_tpu.models.gpt import build_demo_model
         from deepspeed_tpu.serving.fleet.manager import ServingFleet
         import dataclasses
         model_spec = {"vocab_size": 1601, "max_seq_len": 128,

@@ -654,7 +654,7 @@ class TestProcessBackendRecovery:
         SIGKILLed worker's requests finish on the survivor token-exact,
         supervision respawns a fresh worker, and new traffic lands on
         the restarted fleet token-exact."""
-        from benchmarks.serving.load_harness import build_demo_model
+        from deepspeed_tpu.models.gpt import build_demo_model
         cfg = _cfg(FleetConfig(replicas=2, backend="process",
                                supervision={"backoff_base_steps": 1}),
                    num_slots=2)

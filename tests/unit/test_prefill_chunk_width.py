@@ -105,11 +105,13 @@ def test_a_fixed_prefill_chunk_stays_the_width_it_was():
         num_slots=2, max_len=256,
         paging=PagingConfig(page_len=PAGE, prefill_chunk=3 * PAGE)))
     eng.compile_chunk_programs()
-    assert eng._chunk_programs == {}          # nothing to choose from
+    assert eng._paged.chunk_programs == {}    # nothing to choose from
     eng.submit(_ids(7 * PAGE + 5, 2), max_new_tokens=2)
     eng.run()
     assert (eng.metrics.prefill_chunks, eng.metrics.prefill_chunk_pages) \
         == (3, 8)                             # 3 + 3 + 2 pages
+    # the same road with its widths: each compiled when first met
+    assert sorted(eng._paged.chunk_programs) == [2 * PAGE, 3 * PAGE]
 
 
 # -- through ServingEngine, logits watched ------------------------------------
@@ -324,7 +326,7 @@ def test_serve_compiles_every_width_and_a_wide_dispatch_compiles_nothing():
     params = bench_model.seeded_params(module, SEED)
     srv = ds.init_inference(module, params=params, dtype=jnp.float32).serve(
         {"num_slots": 2, "max_len": 256, "paging": {"page_len": PAGE}})
-    assert sorted(srv._chunk_programs) == sorted(
+    assert sorted(srv._paged.chunk_programs) == sorted(
         w * PAGE for w in CHUNK_PAGES)
     # what the harness's warm-up runs: a page and a little, then decode
     srv.submit(_ids(PAGE + 2, 61), max_new_tokens=3)
@@ -348,24 +350,34 @@ def test_a_servers_widths_stop_at_its_slots_pages():
     params = bench_model.seeded_params(module, SEED)
     srv = ds.init_inference(module, params=params, dtype=jnp.float32).serve(
         {"num_slots": 2, "max_len": 128, "paging": {"page_len": 64}})
-    assert sorted(srv._chunk_programs) == [64]
+    assert sorted(srv._paged.chunk_programs) == [64]
     srv.close()
 
 
 def test_an_engine_built_directly_compiles_a_width_when_it_meets_it():
     """``ServingEngine(...)`` itself compiles nothing ahead: a width's
-    first dispatch goes through the jit, as every width did."""
+    first dispatch compiles it by the call ``serve()`` makes ahead and
+    keeps it in the same table, which is all a dispatch ever runs."""
     # a vocabulary of its own: no other test's programs fit these shapes
     module = gpt2.build(dict(GPT2_CONFIG, vocab_size=499), False)
     params = bench_model.seeded_params(module, SEED)
     eng = ServingEngine(module, params, ServingConfig(
         num_slots=2, max_len=256, paging=PagingConfig(page_len=PAGE)))
-    assert eng._chunk_programs == {}
-    before = manager._chunk_prefill_jit._cache_size()
+    record = manager._chunk_prefill_jit.record
+    assert eng._paged.chunk_programs == {}
+    before, through_jit = record.compiles, \
+        manager._chunk_prefill_jit._cache_size()
     eng.submit(_ids(7 * PAGE, 63, vocab=499), max_new_tokens=2)
     eng.run()
-    assert manager._chunk_prefill_jit._cache_size() == before + 2
+    assert record.compiles == before + 2
+    assert sorted(eng._paged.chunk_programs) == sorted(
+        w * PAGE for w in CHUNK_PAGES)        # what serve() asks for ahead
+    # nothing went round the table: the jit itself was never called
+    assert manager._chunk_prefill_jit._cache_size() == through_jit
     assert eng.metrics.prefill_chunk_pages == 7
+    # ... and asking for the widths now, as serve() does, compiles nothing
+    eng.compile_chunk_programs()
+    assert record.compiles == before + 2
 
 
 # -- (e) the counter and the span ---------------------------------------------

@@ -180,13 +180,13 @@ class TestContinuousBatchingParity:
         eng = ServingEngine(m, params,
                             ServingConfig(num_slots=4, max_len=128, seed=0))
         decode_before = _paged_decode_jit._cache_size()
-        prefill_before = _chunk_prefill_jit._cache_size()
+        prefill_before = _chunk_prefill_jit.record.compiles
         reqs = [eng.submit(p, max_new_tokens=o, on_token=on_token)
                 for p, o in zip(prompts, outs)]
         eng.run()
 
         assert _paged_decode_jit._cache_size() == decode_before + 1
-        assert _chunk_prefill_jit._cache_size() == prefill_before + 1
+        assert _chunk_prefill_jit.record.compiles == prefill_before + 1
 
         for req, p, o in zip(reqs, prompts, outs):
             assert req.done

@@ -283,7 +283,7 @@ class TestDisaggregatedHandoff:
             FleetConfig(replicas=2, disaggregate=True,
                         prefill_replicas=1), num_slots=2))
         decode_before = _paged_decode_jit._cache_size()
-        chunk_before = _chunk_prefill_jit._cache_size()
+        chunk_before = _chunk_prefill_jit.record.compiles
         prompts = _prompts(0, 4, 131)
         handles = [fleet.submit(pr, max_new_tokens=6, request_id=i)
                    for i, pr in enumerate(prompts)]
@@ -305,7 +305,7 @@ class TestDisaggregatedHandoff:
         # (B's — A, the prefill role, never dispatched one) and only A's
         # chunk-width specializations
         assert _paged_decode_jit._cache_size() == decode_before + 1
-        assert _chunk_prefill_jit._cache_size() > chunk_before
+        assert _chunk_prefill_jit.record.compiles > chunk_before
         assert pre.metrics.prefill_chunks > 0
         fleet.close()
 
@@ -531,7 +531,7 @@ class TestProcessBackend:
         """Two worker subprocesses: outputs token-exact, per-replica
         /metrics + /healthz scrapeable, and a hard-killed worker's
         requests finish on the survivor."""
-        from benchmarks.serving.load_harness import build_demo_model
+        from deepspeed_tpu.models.gpt import build_demo_model
         from deepspeed_tpu.observability.export import MetricsScrapeClient
         cfg = _cfg(FleetConfig(replicas=2, backend="process",
                                replica_telemetry=True), num_slots=2)
@@ -561,7 +561,7 @@ class TestProcessBackend:
     def test_process_disaggregated_handoff_over_the_pipe(self):
         """Cross-process page handoff: the payload travels as the
         serialized wire blob, and outputs stay token-exact."""
-        from benchmarks.serving.load_harness import build_demo_model
+        from deepspeed_tpu.models.gpt import build_demo_model
         cfg = _cfg(FleetConfig(replicas=2, backend="process",
                                disaggregate=True, prefill_replicas=1),
                    num_slots=2)
@@ -576,6 +576,31 @@ class TestProcessBackend:
         for pr, h in zip(prompts, handles):
             _assert_token_exact(m, p, pr, h, 6)
         fleet.close()
+
+
+def test_a_worker_builds_its_engine_without_the_repositorys_benchmarks(
+        monkeypatch):
+    """What a process replica runs to get its engine needs nothing but
+    the installed package: a spec without a checkpoint builds the demo
+    model of ``models/gpt.py`` with ``benchmarks`` unimportable."""
+    import sys
+    from deepspeed_tpu.serving.fleet import worker
+    for name in [n for n in sys.modules
+                 if n == "benchmarks" or n.startswith("benchmarks.")]:
+        monkeypatch.delitem(sys.modules, name)
+    monkeypatch.setitem(sys.modules, "benchmarks", None)
+    with pytest.raises(ImportError):
+        import benchmarks  # noqa: F401
+    eng = worker._build_engine({
+        "model": {"vocab_size": 167, "max_seq_len": 128, "d_model": 32,
+                  "n_layers": 2, "n_heads": 2, "seed": 0},
+        "serving": {"num_slots": 2, "max_len": 128,
+                    "fleet": {"replicas": 2}}})
+    try:
+        assert eng.module.config.vocab_size == 167
+        assert eng.config.num_slots == 2 and eng.config.fleet is None
+    finally:
+        eng.close()
 
 
 # ---------------------------------------------------------------------------

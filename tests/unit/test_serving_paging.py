@@ -448,7 +448,7 @@ class TestPagedDensityAcceptance:
         assert eng._paged.stats()["full_length_rows_equivalent"] == 2
 
         decode_before = _paged_decode_jit._cache_size()
-        chunk_before = _chunk_prefill_jit._cache_size()
+        chunk_before = _chunk_prefill_jit.record.compiles
         reqs = [eng.submit(p, max_new_tokens=o)
                 for p, o in zip(prompts, outs)]
         eng.run()
@@ -468,7 +468,7 @@ class TestPagedDensityAcceptance:
         # compile-once: ONE paged decode program; chunk prefill one per
         # chunk-width bucket (every prompt here pads to one 16-wide chunk)
         assert _paged_decode_jit._cache_size() == decode_before + 1
-        assert _chunk_prefill_jit._cache_size() == chunk_before + 1
+        assert _chunk_prefill_jit.record.compiles == chunk_before + 1
         eng._paged.allocator.check()
         assert eng._paged.allocator.pages_in_use == \
             eng._paged.stats()["prefix_nodes"]   # only the tree holds pages

@@ -35,7 +35,7 @@ from deepspeed_tpu.serving.engine import ServingEngine
 from deepspeed_tpu.serving.qos import (LEVEL_DEGRADE, LEVEL_HEALTHY,
                                        LEVEL_REFUSE, LEVEL_SHED,
                                        QosController)
-import deepspeed_tpu.serving.engine as engine_mod
+import deepspeed_tpu.serving.paging.manager as manager_mod
 
 REPO_ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
@@ -375,6 +375,23 @@ class TestOverloadShedding:
 # fault containment: OOM shed, hung-decode watchdog, recovery
 # ---------------------------------------------------------------------------
 
+class _FaultyChunks:
+    """Stands where the manager's module global ``_chunk_prefill_jit``
+    does (the manager compiles a width ahead, then runs the executable):
+    every executable it hands out calls ``before()`` first."""
+
+    def __init__(self, before):
+        self.before, self.program = before, manager_mod._chunk_prefill_jit
+
+    def compile_ahead(self, *args, **kwargs):
+        run = self.program.compile_ahead(*args, **kwargs)
+
+        def faulty(*call_args):
+            self.before()
+            return run(*call_args)
+        return faulty
+
+
 class TestFaultContainment:
     def test_oom_on_admit_sheds_and_keeps_serving(self, monkeypatch):
         """An injected RESOURCE_EXHAUSTED during prefill sheds exactly that
@@ -386,17 +403,16 @@ class TestFaultContainment:
         r = np.random.RandomState(1)
         reqs = [eng.submit(r.randint(1, 61, size=5), max_new_tokens=4,
                            request_id=i, priority=1) for i in range(3)]
-        orig = engine_mod._chunk_prefill_jit
         calls = {"n": 0}
 
-        def flaky(*a, **kw):
+        def flaky():
             calls["n"] += 1
             if calls["n"] == 2:
                 raise RuntimeError(
                     "RESOURCE_EXHAUSTED: Out of memory while trying to "
                     "allocate 9437184 bytes.")
-            return orig(*a, **kw)
-        monkeypatch.setattr(engine_mod, "_chunk_prefill_jit", flaky)
+        monkeypatch.setattr(manager_mod, "_chunk_prefill_jit",
+                            _FaultyChunks(flaky))
         eng.run()
 
         statuses = [q.status for q in reqs]
@@ -413,9 +429,11 @@ class TestFaultContainment:
         kinds = [f["kind"] for f in snap["faults"]]
         assert "oom" in kinds and "recovery" in kinds
         # a non-OOM error still propagates (no blanket swallowing)
-        monkeypatch.setattr(
-            engine_mod, "_chunk_prefill_jit",
-            lambda *a, **kw: (_ for _ in ()).throw(RuntimeError("boom")))
+        def boom():
+            raise RuntimeError("boom")
+        monkeypatch.setattr(manager_mod, "_chunk_prefill_jit",
+                            _FaultyChunks(boom))
+        eng._paged.chunk_programs.clear()    # compile through the patch
         eng.submit(r.randint(1, 61, size=4), max_new_tokens=2, priority=1)
         with pytest.raises(RuntimeError, match="boom"):
             eng.run()
@@ -434,7 +452,7 @@ class TestFaultContainment:
         reqs = [eng.submit(r.randint(1, 61, size=5), max_new_tokens=6,
                            request_id=f"w{i}", priority=1)
                 for i in range(3)]
-        orig = engine_mod._paged_decode_jit
+        orig = manager_mod._paged_decode_jit
         calls = {"n": 0}
         escalations = []
         # the stall spans two watchdog windows, so the hard-abort
@@ -447,7 +465,7 @@ class TestFaultContainment:
             if calls["n"] == 2:
                 time.sleep(0.5)     # well past the 0.15s watchdog budget
             return orig(*a, **kw)
-        monkeypatch.setattr(engine_mod, "_paged_decode_jit", stalled)
+        monkeypatch.setattr(manager_mod, "_paged_decode_jit", stalled)
         try:
             eng.run()
         finally:
