@@ -45,7 +45,9 @@ and a layer's weights are cast one layer at a time.
 One thing ``reference_logits`` does beyond the equations, for the
 comparison that decides ``correct``: the rows of positions where one of
 its own routers chose on a near-tie come back as zeros — not judged
-(``NEAR_TIE`` below). ``near_ties="kept"`` gives every row as computed."""
+(``NEAR_TIE`` and ``CARRIED_TIE`` below; ``unjudged`` is the rule).
+``near_ties="kept"`` gives every row as computed, ``"gaps"`` also each
+position's closest router choice."""
 
 from . import MOSAIC_KERNEL
 from .. import reference
@@ -234,13 +236,13 @@ def _route(m, p, top_k, config):
                    * weight[..., None], axis=-2)
 
 
-def _near_tie(m, p, top_k):
-    """``[B, S]``: whether a token's choice was a near-tie — the last
-    expert chosen and the first one left out less than ``NEAR_TIE`` apart
-    in the score they are chosen by."""
+def _choice_gap(m, p, top_k):
+    """``[B, S]``: how far apart the last expert chosen and the first
+    one left out lie in the score they are chosen by; a near-tie where
+    it is under ``NEAR_TIE``."""
     import jax
     best, _ = jax.lax.top_k(_scores(m, p)[1], top_k + 1)
-    return best[..., top_k - 1] - best[..., top_k] < NEAR_TIE
+    return best[..., top_k - 1] - best[..., top_k]
 
 
 def _experts(m, weight, stacks, at, lower):
@@ -271,6 +273,37 @@ def _experts(m, weight, stacks, at, lower):
 # later ones at a share of one in their context's length: not followed.
 # A wrong path is wrong at every other position too.
 NEAR_TIE = 1e-5
+# What a near-tie upstream does to the choices after it (PR 54; PERF.md
+# section 2 has every reading). Two computations of this arithmetic settle
+# some of a sequence's near-ties differently - an 8 k document holds ~44 -
+# and every later position attends each such position's vector at a share
+# of one in its context's length, so its scores differ by ~1e-5 a flip
+# where they differed by ~4e-7, and a choice that hangs on more than
+# NEAR_TIE falls. The one failure of ``correct`` on record with a seed
+# and a position (``serve-kanana-docqa`` seed 2154571472, request 12,
+# 0.57 sigma) is this: the row's choice hung on 1.28e-5 after 44
+# near-ties, the program and two other compilations of this reference put
+# the served token first, and the cell's check, alone, the other choice's;
+# served alone outside any window the request reads the same. LFM2's
+# replayed rows held one such flip at 2.8e-5. So once a near-tie lies
+# upstream, a choice under CARRIED_TIE is unjudged too: 3.5 times the
+# larger of the two gaps seen to fall; 4.5% of positions (0.5% at NEAR_TIE
+# alone, 13% at 3e-4); and the control, bf16 activations, keeps 74-93 of
+# its 84-101 tokens of 512 over the limit (``tools/near_tie_probe.py``,
+# my chip runs, PR 54). With no near-tie upstream nothing moves a later
+# score by more than rounding, and such a choice stays judged.
+CARRIED_TIE = 1e-4
+
+
+def unjudged(gap, sizes):
+    """``gap [B, S]`` (each position's closest router choice) -> bool:
+    the positions no comparison can judge. The near-ties (``NEAR_TIE``)
+    and, once a near-tie lies upstream, the choices under
+    ``CARRIED_TIE``."""
+    import jax.numpy as jnp
+    near = gap < NEAR_TIE
+    upstream = jnp.cumsum(near, axis=1) - near > 0
+    return near | (upstream & (gap < CARRIED_TIE))
 
 
 def reference_logits(params, ids, sizes, config, lower=None,
@@ -280,8 +313,9 @@ def reference_logits(params, ids, sizes, config, lower=None,
     float32 tree of weights — one layer's, one expert's, the embedding,
     the head — and gives the tree to compute with. ``near_ties``:
     ``"unjudged"`` zeroes the rows no comparison can judge (above),
-    ``"kept"`` leaves every row as computed, ``"flagged"`` does too and
-    returns ``(logits, near [B, S] bool)``."""
+    ``"kept"`` leaves every row as computed, ``"gaps"`` does too and
+    returns ``(logits, gap [B, S])``: the closest choice any of a
+    position's routers made, a near-tie where it is under ``NEAR_TIE``."""
     import jax
     import jax.numpy as jnp
     eps = config["rms_norm_eps"]
@@ -293,7 +327,7 @@ def reference_logits(params, ids, sizes, config, lower=None,
         x = jnp.asarray(params["wte"][ids], jnp.float32)   # rows, then cast
     else:
         x = lower(f32({"wte": params["wte"]}))["wte"][ids]
-    near = jnp.zeros(ids.shape, bool)
+    gap = jnp.full(ids.shape, jnp.inf)      # the closest choice of any layer
     for i in range(sizes["num_hidden_layers"]):
         p = lower(f32(params[f"layers_{i}"]))       # one layer at a time
         n = _rms(x, p["input_norm"]["scale"], eps)
@@ -303,7 +337,7 @@ def reference_logits(params, ids, sizes, config, lower=None,
             x = h + _swiglu(m, p["mlp"])
         else:
             weight = _route(m, p["moe"], top_k, config)
-            near = near | _near_tie(m, p["moe"], top_k)
+            gap = jnp.minimum(gap, _choice_gap(m, p["moe"], top_k))
             x = h + _experts(m, weight, params["experts"], i - dense, lower)
             if sizes["n_shared_experts"]:
                 x = x + _swiglu(m, p["moe"]["shared"])
@@ -312,9 +346,9 @@ def reference_logits(params, ids, sizes, config, lower=None,
         "lm_head"]["kernel"]
     if near_ties == "kept":
         return logits
-    if near_ties == "flagged":
-        return logits, near
-    return jnp.where(near[..., None], 0.0, logits)
+    if near_ties == "gaps":
+        return logits, gap
+    return jnp.where(unjudged(gap, sizes)[..., None], 0.0, logits)
 
 
 def reference_next_token_losses(params, ids, sizes, config):

@@ -46,8 +46,9 @@ One thing ``reference_logits`` does beyond the equations, for the
 comparison that decides ``correct``: the rows of positions where one of
 its own routers chose on a near-tie, and of the few after them that the
 convolutions carry the other choice into, come back as zeros — not
-judged (``NEAR_TIE`` below has why and how many). ``near_ties="kept"``
-gives every row as computed."""
+judged (``NEAR_TIE`` and ``CARRIED_TIE`` below have why and how many,
+``unjudged`` is the rule). ``near_ties="kept"`` gives every row as
+computed, ``"gaps"`` also each position's closest router choice."""
 
 from . import MOSAIC_KERNEL
 from .. import reference
@@ -228,13 +229,18 @@ def _route(m, p, top_k, config):
     return every
 
 
-def _near_tie(m, p, top_k, config):
-    """``[B, S]``: whether a token's choice was a near-tie — the last
-    expert chosen and the first one left out less than ``NEAR_TIE`` apart
-    in the score they are chosen by."""
+def _choice_gap(m, p, top_k, config):
+    """``[B, S]``: how far apart the last expert chosen and the first
+    one left out lie in the score they are chosen by."""
     import jax
     best, _ = jax.lax.top_k(_scores(m, p, config)[1], top_k + 1)
-    return best[..., top_k - 1] - best[..., top_k] < NEAR_TIE
+    return best[..., top_k - 1] - best[..., top_k]
+
+
+def _near_tie(m, p, top_k, config):
+    """``[B, S]``: whether a token's choice was a near-tie — that gap
+    under ``NEAR_TIE``."""
+    return _choice_gap(m, p, top_k, config) < NEAR_TIE
 
 
 def _experts(m, weight, stacks, at, lower):
@@ -271,6 +277,22 @@ def _experts(m, weight, stacks, at, lower):
 # so about 3 choices in 10,000 are near-ties and ~1.3% of positions are
 # not judged. A wrong path is wrong at every other position too.
 NEAR_TIE = 1e-5
+# What a near-tie upstream does to the choices after it (PR 54; PERF.md
+# section 2 and ``families/deepseek_v3.py CARRIED_TIE`` have the
+# mechanism and every reading). Program and reference settle some of a
+# prompt's near-ties differently, every later position attends each such
+# position at one part in ~2,300, and a choice that hangs on more than
+# NEAR_TIE falls: one served row in ~8,300 read 0.30 sigma where the
+# fourth and fifth scores lay 2.8e-5 apart (seed 2154700305, request 1,
+# row 39; its neighbours read 6e-4), Kanana's one failing run fell at
+# 1.28e-5. Once a near-tie lies upstream, a choice under CARRIED_TIE is
+# unjudged too, with the positions the convolutions carry it into: 3.5
+# times the larger gap seen to fall; 18% of positions with the four after
+# each (1.5-2.7% at NEAR_TIE alone, 43-45% at 3e-4); and the control,
+# bf16 activations, keeps 49-55 of its 75-76 tokens of 640 over the limit
+# (``tools/near_tie_probe.py``, my chip runs, PR 54). With no near-tie
+# upstream such a choice stays judged.
+CARRIED_TIE = 1e-4
 
 
 def _reach(sizes):
@@ -281,6 +303,20 @@ def _reach(sizes):
     return 2 * (sizes["conv_L_cache"] - 1)
 
 
+def unjudged(gap, sizes):
+    """``gap [B, S]`` (each position's closest router choice) -> bool:
+    the positions no comparison can judge. The near-ties (``NEAR_TIE``);
+    once a near-tie lies upstream, the choices under ``CARRIED_TIE``; and
+    the ``_reach`` positions after each of either."""
+    import jax.numpy as jnp
+    near = gap < NEAR_TIE
+    upstream = jnp.cumsum(near, axis=1) - near > 0
+    near = near | (upstream & (gap < CARRIED_TIE))
+    s, reach = gap.shape[1], _reach(sizes)
+    carried = jnp.pad(near, ((0, 0), (reach, 0)))
+    return sum(carried[:, j:j + s] for j in range(reach + 1)) > 0
+
+
 def reference_logits(params, ids, sizes, config, lower=None,
                      near_ties="unjudged"):
     """``[B, S] -> [B, S, V]`` float32 logits. ``params`` is the
@@ -289,7 +325,9 @@ def reference_logits(params, ids, sizes, config, lower=None,
     and gives the tree to compute with. ``near_ties``: ``"unjudged"``
     zeroes the rows no comparison can judge (above), ``"kept"`` leaves
     every row as computed (the tools and tests that compare rows, the
-    losses)."""
+    losses), ``"gaps"`` does too and returns ``(logits, gap [B, S])``:
+    the closest choice any of a position's routers made, a near-tie
+    where it is under ``NEAR_TIE``."""
     import jax
     import jax.numpy as jnp
     lower = lower or (lambda tree: tree)
@@ -300,7 +338,7 @@ def reference_logits(params, ids, sizes, config, lower=None,
     theta = float(config["rope_parameters"]["rope_theta"])
     wte = f32({"wte": params["wte"]})["wte"]
     x = wte[ids]
-    near = jnp.zeros(ids.shape, bool)
+    gap = jnp.full(ids.shape, jnp.inf)      # the closest choice of any layer
     for i, kind in enumerate(sizes["layer_types"]):
         p = f32(params[f"layers_{i}"])          # one layer at a time
         n = _rms(x, p["operator_norm"]["scale"], eps)
@@ -317,16 +355,15 @@ def reference_logits(params, ids, sizes, config, lower=None,
             at = i - sizes["num_dense_layers"]
             top_k = sizes["num_experts_per_tok"]
             weight = _route(m, p["moe"], top_k, config)
-            near = near | _near_tie(m, p["moe"], top_k, config)
+            gap = jnp.minimum(gap, _choice_gap(m, p["moe"], top_k, config))
             x = h + _experts(m, weight, params["experts"], at, lower)
     x = _rms(x, jnp.asarray(params["ln_f"]["scale"], jnp.float32), eps)
     logits = x @ wte.T
     if near_ties == "kept":
         return logits
-    s, reach = ids.shape[1], _reach(sizes)
-    carried = jnp.pad(near, ((0, 0), (reach, 0)))
-    unjudged = sum(carried[:, j:j + s] for j in range(reach + 1)) > 0
-    return jnp.where(unjudged[..., None], 0.0, logits)
+    if near_ties == "gaps":
+        return logits, gap
+    return jnp.where(unjudged(gap, sizes)[..., None], 0.0, logits)
 
 
 def reference_next_token_losses(params, ids, sizes, config):

@@ -10,6 +10,10 @@ shapes, measures for ``--seconds``, checks the outputs against the plain
 float32 reference, and prints one JSON object as the last line of
 standard output. ``--trace 0`` gives the cell's end-to-end metrics,
 ``--trace 1`` its per-layer metrics (one reader each, ``readers/``).
+The line's last key, ``check``, holds each number that decided
+``correct`` beside its limit (``{"value", "limit"}``; a count that has
+no limit carries none), and the same go to standard error as the run's
+last lines there: after a refusal they are what the ledger keeps.
 
 ``setup_s`` runs from the moment the chip is attached (``jax.devices()``
 has returned) to the first instant of the measured window. The seconds
@@ -28,6 +32,7 @@ _T0 = time.monotonic()      # the first statement: `attach` counts from here
 import argparse             # noqa: E402
 import json                 # noqa: E402
 import os                   # noqa: E402
+import shutil               # noqa: E402
 import sys                  # noqa: E402
 
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
@@ -132,7 +137,8 @@ def main(argv=None):
     if args.rehearse:
         scratch = dict(line, metrics={}, device=dict(device))
         if args.trace:
-            _per_layer(cell, out, setup, chip_peaks, peak, scratch)
+            _per_layer(cell, out, setup, chip_peaks, peak, scratch,
+                       rehearse=True)
         _say("rehearsal: control flow and counts only, no metric is "
              f"printed; readers gave a value for {sorted(scratch['metrics'])}")
     elif args.trace:
@@ -142,11 +148,19 @@ def main(argv=None):
         for m in cell.end_to_end():
             line["metrics"][m["name"]] = {"value": values[m["name"]],
                                           "unit": m["unit"]}
+    line["check"] = out["check"]        # last: what decided `correct`
+    for failure in out.get("failures", ()):
+        print(f"token over the limit: {json.dumps(failure)}",
+              file=sys.stderr)
+    for name, number in out["check"].items():
+        print(f"check {name}: {json.dumps(number)}", file=sys.stderr)
+    print(f"correct: {json.dumps(line['correct'])}", file=sys.stderr,
+          flush=True)
     print(json.dumps(line), flush=True)
     return 0
 
 
-def _per_layer(cell, out, setup, chip_peaks, peak, line):
+def _per_layer(cell, out, setup, chip_peaks, peak, line, rehearse=False):
     """``--trace 1``: reduce the capture and hand every per-layer metric
     of the cell to its reader."""
     from benchmarks.chip import readers, xplane
@@ -154,6 +168,8 @@ def _per_layer(cell, out, setup, chip_peaks, peak, line):
     capture = out.get("capture")
     if capture is not None and capture.path:
         trace = xplane.load(capture.path)
+        if rehearse:
+            shutil.rmtree(capture.dir, ignore_errors=True)
         line["device"]["busy_s"] = xplane.busy_s(trace)
         line["device"]["window_s"] = trace.window_s
         line["breakdown"] = {"device_ops": xplane.top_ops(trace),

@@ -28,6 +28,11 @@ from .stats import percentile, rate, samples_beyond, tail, token_spans_ms
 # takes the served logits, which the server does not hand out.
 LOGIT_TOL_SIGMA = 0.1
 CHECKED_REQUESTS = 4
+# A run whose tokens fail says which (``_explain``, PR 54): no refusal
+# of the nine on record before it left a seed, a request or a position
+# behind (PERF.md section 2, "The lottery").
+SAID_FAILURES = 8       # as many failing tokens go to standard error,
+SAID_TOKENS = 64        # and as many to the run's log
 
 
 class Record:
@@ -278,17 +283,13 @@ def run(cell, args, phases, compile_log, devices, say):
     sampled = _sample(out["finished"], args.seed)
     del srv
     gc.collect()                    # the page pool goes before the check
-    t0 = time.monotonic()
-    check = _reference_check(family, params, sampled, sizes, config,
-                             serving["max_len"])
-    say(f"reference check: {check['exact']}/{check['tokens']} served tokens "
-        f"of {len(sampled)} requests are the float32 argmax; largest logit "
-        f"gap {check['max']:.4f} sigma (tolerance {LOGIT_TOL_SIGMA} sigma), "
-        f"mean {check['mean']:.2e}, in {time.monotonic() - t0:.1f}s")
+    verdict = decide(family, params, sizes, config, serving["max_len"], mix,
+                     sampled, args.seed, say)
     if compiled["compile_events"]:
         say(f"COMPILED INSIDE THE WINDOW: {compiled['compiled']}")
-    correct = (check["tokens"] > 0 and check["max"] <= LOGIT_TOL_SIGMA
-               and compiled["compile_events"] == 0)
+    verdict["numbers"]["compiles_in_window"] = {
+        "value": compiled["compile_events"], "limit": 0}
+    correct = verdict["held"] and compiled["compile_events"] == 0
 
     # host-clock samples of a traced run end where its capture begins
     cutoff = hooks.host_cutoff
@@ -302,7 +303,8 @@ def run(cell, args, phases, compile_log, devices, say):
     return {
         "correct": bool(correct), "attempted": len(judged),
         "failed": len(out["failed"]), "window_start": window_start,
-        "memory_peak_bytes": peak,
+        "memory_peak_bytes": peak, "check": verdict["numbers"],
+        "failures": verdict["failures"][:SAID_FAILURES],
         "end_to_end": end_to_end,
         "observed": {
             "series": {
@@ -408,3 +410,108 @@ def _reference_check(family, params, sampled, sizes, config, width):
             "mean": float(flat.mean()) if flat.size else math.inf,
             "exact": int((flat == 0.0).sum()), "tokens": int(flat.size),
             "gaps": gaps}
+
+
+def decide(family, params, sizes, config, width, mix, sampled, seed, say):
+    """``correct`` of the served tokens: every one of the sampled
+    requests within ``LOGIT_TOL_SIGMA`` of its position's best reference
+    logit. Returns ``{"held", "numbers", "tokens", "failures"}``: whether
+    they are, each number that decided beside its limit (the last line's
+    ``check``), the reference check's reading, and one entry a token over
+    the limit (``_explain``)."""
+    t0 = time.monotonic()
+    check = _reference_check(family, params, sampled, sizes, config, width)
+    tol = LOGIT_TOL_SIGMA
+    say(f"reference check: {check['exact']}/{check['tokens']} served tokens "
+        f"of {len(sampled)} requests are the float32 argmax; largest logit "
+        f"gap {check['max']:.4f} sigma (tolerance {tol} sigma), "
+        f"mean {check['mean']:.2e}, in {time.monotonic() - t0:.1f}s")
+    over = [(i, int(j)) for i, gaps in enumerate(check["gaps"])
+            for j in np.flatnonzero(gaps > tol)]
+    numbers = {"tokens_checked": {"value": check["tokens"]},
+               "logit_gap_sigma": {     # no token served: no number
+                   "value": check["max"] if check["tokens"] else None,
+                   "limit": tol},
+               "tokens_over": {"value": len(over), "limit": 0}}
+    out = {"held": check["tokens"] > 0 and not over, "numbers": numbers,
+           "tokens": check, "failures": []}
+    if over:
+        out["failures"] = _explain(family, params, sizes, config, width,
+                                   sampled, check["gaps"], over, mix, seed,
+                                   say)
+        worst = max(out["failures"], key=lambda f: f["gap_sigma"])
+        for name in ("request", "position", "near_tie_back", "choice_gap"):
+            if worst[name] is not None:
+                numbers["worst_token_" + name] = {"value": worst[name]}
+    return out
+
+
+def _spelled(numbers):
+    return ", ".join(f"{k} {v:.4g}" if isinstance(v, float) else f"{k} {v}"
+                     for k, v in numbers.items())
+
+
+def _shared_tokens(spec, mix):
+    """How many tokens a request opens with that others open with too."""
+    if spec.get("kind") != "shared_prefix":
+        return 0
+    return min(mix["shared_prefix"]["tokens"], len(spec["prompt"]))
+
+
+def _choice_gaps(family, params, sizes, config, width):
+    """``fn(ids [n]) -> gap [n]``: the closest choice any router of the
+    float32 reference made at each position of a request (one more pass
+    of the reference, ``near_ties="gaps"``), or None for a family whose
+    reference leaves no near-tie unjudged."""
+    if not hasattr(family, "NEAR_TIE"):
+        return None
+    import jax
+    import jax.numpy as jnp
+    forward = jax.jit(lambda p, ids: family.reference_logits(
+        p, ids, sizes, config, near_ties="gaps")[1][0])
+
+    def gaps(ids):
+        padded = np.zeros((1, width), np.int32)
+        padded[0, :len(ids)] = ids
+        with reference.highest():
+            return np.asarray(forward(params, jnp.asarray(padded)))[:len(ids)]
+
+    return gaps
+
+
+def _explain(family, params, sizes, config, width, sampled, gaps, over, mix,
+             seed, say):
+    """One entry a failing token, what a later session needs to look at
+    it: the seed, the request's index in the mix's sequence, its prompt
+    and shared prefix, the served token's position and its gap; and for
+    a family whose routers can tie, how close the reference's closest
+    choice was at the position that predicted it, how many positions
+    back the reference's nearest own near-tie lies, and how many the
+    request holds."""
+    said, choice = [], {}
+    choice_gaps = _choice_gaps(family, params, sizes, config, width)
+    for i, j in over:
+        rec = sampled[i]
+        prompt = np.asarray(rec.spec["prompt"])
+        if choice_gaps is not None and i not in choice:
+            choice[i] = choice_gaps(np.concatenate(
+                [prompt, np.asarray(rec.handle.output_tokens, np.int32)]))
+        row = len(prompt) + j - 1       # the position that predicted it
+        near = np.flatnonzero(choice[i] < family.NEAR_TIE) \
+            if i in choice else None
+        before = near[near <= row] if near is not None else ()
+        one = {"seed": seed, "request": rec.spec.get("id"),
+               "prompt_len": len(prompt),
+               "shared_prefix": _shared_tokens(rec.spec, mix),
+               "position": row + 1, "gap_sigma": float(gaps[i][j]),
+               "choice_gap": float(choice[i][row]) if i in choice else None,
+               "near_tie_back": int(row - before[-1]) if len(before)
+               else None,
+               "near_ties_in_request": len(near) if near is not None
+               else None}
+        said.append(one)
+        if len(said) <= SAID_TOKENS:
+            say("token over the limit: " + _spelled(one))
+    if len(said) > SAID_TOKENS:
+        say(f"and {len(said) - SAID_TOKENS} more tokens over the limit")
+    return said

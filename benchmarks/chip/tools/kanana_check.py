@@ -130,12 +130,12 @@ def reference_rows(family, params, prompt, output, sizes, config, wrong=None,
         setattr(family, name, fn)
     try:
         with reference.highest():
-            rows, near = jax.jit(lambda p, x: family.reference_logits(
+            rows, gap = jax.jit(lambda p, x: family.reference_logits(
                 p, x, dict(sizes, **over_sizes), dict(config, **over_config),
-                lower, near_ties="flagged"))(params,
-                                             jnp.asarray(padded[None]))
+                lower, near_ties="gaps"))(params, jnp.asarray(padded[None]))
             served = slice(len(prompt) - 1, len(ids) - 1)
-            rows, near = np.asarray(rows[0, served]), np.asarray(near[0])
+            rows = np.asarray(rows[0, served])
+            near = np.asarray(gap[0]) < family.NEAR_TIE
             if flagged:
                 return rows, near[served], int(near[:len(prompt) - 1].sum())
             return rows

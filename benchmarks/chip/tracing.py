@@ -25,6 +25,7 @@ class Capture:
     """Where a capture went: the ``.xplane.pb`` and the host-clock
     seconds it spanned."""
     path = None
+    dir = None
     seconds = None
 
 
@@ -33,7 +34,13 @@ def capture(cell, args):
     import jax
     from deepspeed_tpu.observability import trace as spans
 
-    logdir = os.path.join(args.out_dir, "trace", cell.name)
+    # on the chip one run at a time holds a checkout, and its trace stays
+    # for a look until the next run's takes its place; rehearsals run
+    # several at once (a test file a worker), and two of one cell in one
+    # directory delete or double each other's trace: a directory each,
+    # removed once it is read (``run.py``)
+    logdir = os.path.join(args.out_dir, "trace", cell.name + (
+        f".{os.getpid()}" if args.rehearse else ""))
     shutil.rmtree(logdir, ignore_errors=True)
     options = jax.profiler.ProfileOptions()
     options.python_tracer_level = 0     # no event per Python call
@@ -53,4 +60,4 @@ def capture(cell, args):
                                    "*.xplane.pb"))
     if len(found) != 1:
         raise RuntimeError(f"expected one .xplane.pb under {logdir}: {found}")
-    cap.path = found[0]
+    cap.path, cap.dir = found[0], logdir

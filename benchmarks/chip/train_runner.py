@@ -35,6 +35,10 @@ def _engine_config(config, micro, chips):
     return out
 
 
+# the steps of a rehearsal's window, whatever --seconds says (below)
+REHEARSAL_STEPS = 8
+
+
 def run(cell, args, phases, compile_log, devices, say):
     import jax
     import deepspeed_tpu as ds
@@ -96,8 +100,18 @@ def run(cell, args, phases, compile_log, devices, say):
     else:
         measured_from = window_start
     now = measured_from
-    while now - window_start < args.seconds or not step_s:
-        now = step()
+    if args.rehearse:
+        # a rehearsal proves control flow, never a speed: a fixed number
+        # of steps, so that what `correct` compares - the loss on the
+        # parameters the window leaves - is the seed's and not the
+        # host's load's (PERF.md section 7: at 64 wide the rms wanders
+        # 0.9e-4 to 1.6e-4 with the count of steps against 1.5e-4: it
+        # read 1.63e-4 when 54 steps fitted 1.5 s)
+        for _ in range(REHEARSAL_STEPS):
+            now = step()
+    else:
+        while now - window_start < args.seconds or not step_s:
+            now = step()
     window_s = now - measured_from
     compiled = compile_log.since(in_window)
     peak = peak_bytes(devices)      # before the reference check's own
@@ -127,6 +141,13 @@ def run(cell, args, phases, compile_log, devices, say):
         "correct": bool(correct), "attempted": len(step_s),
         "failed": sum(not math.isfinite(x) for x in losses),
         "window_start": window_start, "memory_peak_bytes": peak,
+        "check": {
+            "loss_rms": {"value": check["rms"], "limit": LOSS_RMS_TOL},
+            "losses_not_finite": {
+                "value": sum(not math.isfinite(x) for x in losses),
+                "limit": 0},
+            "compiles_in_window": {"value": compiled["compile_events"],
+                                   "limit": 0}},
         "end_to_end": {"train_tokens_per_s_chip": tokens_per_s_chip},
         "observed": {
             "series": {"train_step_ms": [1e3 * s for s in step_s]},

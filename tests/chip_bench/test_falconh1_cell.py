@@ -118,7 +118,7 @@ def test_the_cell_is_in_the_manifest_as_the_issue_has_it():
     assert tokens["workloads"][:5] == [
         "serve-1p3b-longprompt", "serve-olmoe-longgen", "serve-lfm2-agent",
         "serve-kanana-docqa", CELL]
-    assert tokens["bound"] == 0.01
+    assert tokens["bound"] == 0.03       # 1% until PR 54's check
     named = [m["name"] for m in M["per_layer"] if m["name"] in H1CHAT]
     assert named == H1CHAT                 # appended, in this order
     for name in H1CHAT:

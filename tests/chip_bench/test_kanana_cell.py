@@ -109,7 +109,7 @@ def test_the_cell_is_in_the_manifest_as_the_issue_has_it():
     assert tokens["workloads"][:4] == [
         "serve-1p3b-longprompt", "serve-olmoe-longgen", "serve-lfm2-agent",
         CELL]
-    assert tokens["bound"] == 0.01
+    assert tokens["bound"] == 0.03       # 1% until PR 54's check
     named = [m["name"] for m in M["per_layer"] if m["name"] in DOCQA]
     assert named == DOCQA                  # appended, in this order
     for name in DOCQA:

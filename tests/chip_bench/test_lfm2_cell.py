@@ -92,7 +92,7 @@ def test_the_cell_is_in_the_manifest_as_the_issue_has_it():
     # appended after the cells that were there (a later cell comes after)
     assert tokens["workloads"][:3] == ["serve-1p3b-longprompt",
                                        "serve-olmoe-longgen", CELL]
-    assert tokens["bound"] == 0.01
+    assert tokens["bound"] == 0.03       # 1% until PR 54's check
     named = [m["name"] for m in M["per_layer"] if m["name"] in AGENT]
     assert named == AGENT                  # appended, in this order
     for name in AGENT:

@@ -79,7 +79,7 @@ def test_the_cell_is_in_the_manifest_as_the_issue_has_it():
     tokens = next(m for m in M["end_to_end"]
                   if m["name"] == "serve_tokens_per_s")
     assert tokens["workloads"] == ["serve-1p3b-longprompt", CELL]
-    assert tokens["bound"] == 0.01
+    assert tokens["bound"] == 0.03       # 1% until PR 54's check
     named = [m["name"] for m in M["per_layer"] if m["name"] in LONGGEN]
     assert named == LONGGEN            # appended, in this order
     for name in LONGGEN:

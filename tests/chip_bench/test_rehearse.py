@@ -13,7 +13,7 @@ import pytest
 
 from ._paths import BENCH, PYTHONPATH, ROOT, RUN
 
-KEYS = {"correct", "attempted", "failed", "metrics", "device"}
+KEYS = {"correct", "attempted", "failed", "metrics", "device", "check"}
 
 
 def _run(run_py, *argv, cwd=ROOT, extra_env=None):
@@ -50,6 +50,22 @@ def test_rehearsal_runs_the_cell_and_prints_the_contracts_last_line(
                               "count": chips, "memory_peak_bytes": 0}
     assert "set-up phases s:" in proc.stdout and "samples:" in proc.stdout
     assert "reference check:" in proc.stdout
+    # what decided `correct` comes last in the line, each number beside
+    # its limit, and the same are the last lines of standard error
+    assert list(line)[-1] == "check"
+    check = line["check"]
+    number = "loss_rms" if cell.startswith("train") else "logit_gap_sigma"
+    assert 0 <= check[number]["value"] <= check[number]["limit"]
+    assert check["compiles_in_window"] == {"value": 0, "limit": 0}
+    assert all(set(n) <= {"value", "limit"} and "value" in n
+               for n in check.values())
+    if cell.startswith("serve"):
+        assert check["tokens_over"] == {"value": 0, "limit": 0}
+        assert check["tokens_checked"]["value"] > 0
+    said = proc.stderr.strip().splitlines()[-len(check) - 1:]
+    assert said[-1] == "correct: true"
+    assert said[:-1] == [f"check {name}: {json.dumps(n)}"
+                         for name, n in check.items()]
 
 
 def test_without_a_chip_and_without_rehearse_it_refuses():
