@@ -25,6 +25,14 @@ width of gpt2-125m with seeded weights and seeded tokens (no network):
            vocabulary: a Mamba-2 mixer beside grouped-query attention,
            the mixer's matrix state a slot updated in place by a Mosaic
            kernel, and a prefix hit that starts from a snapshot;
+- phi4flash: the same serving path with Phi-4-mini-flash's layers at
+           published widths, eight layers deep (mixers 0, 2, 4, window
+           layers 1 and 3, the full layer 5, a memory unit and a cross
+           layer) on an eighth of the vocabulary: the paged kernel over
+           ten cached heads of 128 with four query rows each (the full
+           layer's call and the cross layer's, on the same pages), the
+           contiguous decode kernel on a ring of 512 that wraps, and the
+           refusals by name;
 - kernels: every Pallas kernel compiled by Mosaic and run once at a real
            shape against its jnp reference;
 - offload: offload configs really place state in ``pinned_host``, or
@@ -78,6 +86,11 @@ FULL = {
     # bf16); four snapshots, so that the first request's leaf outlives
     # the leaves of the requests between it and the one that hits
     "falcon_h1": dict(n_layers=2, num_slots=4, max_len=2048, page_len=128,
+                      n_requests=10, prompt_max=300, new_max=24,
+                      paging_kernel="auto", logit_tol=0.1),
+    # max_len 2048: a pool of 65 pages; prompts to 300 and the long one of
+    # 544 + 8 tokens pass the window of 512, so a ring wraps
+    "phi4flash": dict(n_layers=8, num_slots=4, max_len=2048, page_len=128,
                       n_requests=10, prompt_max=300, new_max=24,
                       paging_kernel="auto", logit_tol=0.1),
     "kernels": dict(seq=1024, heads=12, batch=2, cache_len=1024,
@@ -797,6 +810,96 @@ def phase_falcon_h1(n_layers, num_slots, max_len, page_len, n_requests,
                       "model with recurrent state")
 
 
+def phase_phi4flash(n_layers, num_slots, max_len, page_len, n_requests,
+                    prompt_max, new_max, paging_kernel, logit_tol):
+    """Phi-4-mini-flash's layers at published widths through the same
+    serving path, as its cell runs it (float32 activations over bf16
+    weights and a bf16 K/V cache): the decode program holds
+    the paged kernel at ten cached heads of 128 (two halves of 64
+    stacked, four query rows a head) for the full layer and for the
+    cross layer that reads the same pages, and the contiguous decode
+    kernel on the window layers' rings; the pool — pages, rings, states
+    — stays where it is; and what the rings cannot serve is refused."""
+    import numpy as np
+    import jax
+    import jax.numpy as jnp
+    import deepspeed_tpu as ds
+    from deepspeed_tpu.observability.metrics import get_registry
+    from deepspeed_tpu.observability.programs import get_program_registry
+    from deepspeed_tpu.ops.pallas import tuning
+    from benchmarks.chip import manifest
+    from benchmarks.chip.families import phi4flash as family
+
+    published = manifest.load_json(os.path.join(
+        os.path.dirname(os.path.abspath(__file__)), "benchmarks", "chip",
+        "configs", "phi-4-mini-flash-serve.json"))
+    config = dict(published, num_hidden_layers=n_layers,
+                  vocab_size=published["vocab_size"] // 8,
+                  max_position_embeddings=max(max_len, 128))
+    model = family.build(config, False)
+    params = _seeded_params(model)
+    eng = ds.init_inference(model, params=params,
+                            dtype=getattr(jnp, config["compute_dtype"]))
+    reqs = _requests(np.random.default_rng(4), n_requests,
+                     model.config.vocab_size, prompt_max, new_max)
+    names = ("serving/self_decoder_positions",
+             "serving/cross_decoder_positions", "serving/ring_tokens_written")
+    counters = {name: get_registry().counter(name) for name in names}
+    before = {name: c.value for name, c in counters.items()}
+    sizes = family.sizes(config, False)
+
+    @jax.jit
+    def reference_logits(prm, ids):
+        with jax.default_matmul_precision("highest"):
+            return family.reference_logits(prm, ids, sizes, config)
+
+    for option, asked in (("enable_prefix_cache", {"paging": {
+            "page_len": page_len, "enable_prefix_cache": True}}),
+            ("kv_int8", {"paging": {"page_len": page_len,
+                                    "enable_prefix_cache": False},
+                         "quantize": {"kv": "int8"}})):
+        try:
+            eng.serve({"num_slots": num_slots, "max_len": max_len, **asked})
+        except NotImplementedError as e:
+            _say(f"serve phi4flash: {option} refused: {e}")
+        else:
+            _check(False, f"serve phi4flash: {option} was not refused")
+    _serve_and_check(eng, model, params, reqs, num_slots, max_len, page_len,
+                     paging_kernel, logit_tol, "serve phi4flash",
+                     against_generate=False,
+                     reference_logits=reference_logits,
+                     paging={"enable_prefix_cache": False})
+    ring, = tuning.last_dispatch("decode_attention").values()
+    _mosaic(ring, "serve phi4flash ring kernel")
+    _check(ring["key"].endswith(f"_d128_s{published['sliding_window']}"),
+           f"serve phi4flash: the ring kernel ran at {ring['key']}")
+    decode = get_program_registry().get("serving/paged_decode")
+    args, kwargs = decode._last_avals
+    hlo = decode.lower(*args, **kwargs).compile().as_text()
+    kinds = {k: len(family.layers_of(sizes, k))
+             for k in ("window_attn", "shared_attn", "cross_attn")}
+    for kind, n in kinds.items():
+        calls = sum(1 for line in hlo.splitlines()
+                    if f"%{kind}" in line.split(" = ")[0]
+                    and "tpu_custom_call" in line)
+        _check(calls == n, f"serve phi4flash: {calls} Mosaic calls named "
+                           f"%{kind}* in the decode program, not {n}")
+    moved = {name: c.value - before[name] for name, c in counters.items()}
+    _say(f"serve phi4flash: counted {moved}")
+    _check(0 < moved["serving/cross_decoder_positions"] * page_len
+           <= moved["serving/self_decoder_positions"],
+           f"serve phi4flash: the cross-decoder ran on more than one "
+           f"position a chunk: {moved}")
+    try:
+        eng.generate(np.zeros((2, 8), np.int32), max_new_tokens=2,
+                     prompt_lengths=np.asarray([8, 5], np.int32))
+    except NotImplementedError as e:
+        _say(f"serve phi4flash: ragged generate() refused: {e}")
+    else:
+        _check(False, "serve phi4flash: ragged generate() did not refuse a "
+                      "model with recurrent state")
+
+
 def phase_kanana(n_layers, num_slots, max_len, page_len, n_requests,
                  prompt_max, new_max, paging_kernel, logit_tol):
     """Kanana-2-30B-A3B's first layers (the DeepSeek-V3 architecture) at
@@ -1420,7 +1523,7 @@ def main():
     phases = [("train", phase_train), ("serve", phase_serve),
               ("olmoe", phase_olmoe), ("lfm2", phase_lfm2),
               ("kanana", phase_kanana), ("falcon_h1", phase_falcon_h1),
-              ("kernels", phase_kernels)]
+              ("phi4flash", phase_phi4flash), ("kernels", phase_kernels)]
     if device["count"] >= 4:
         phases.append(("multichip", phase_multichip))
     else:

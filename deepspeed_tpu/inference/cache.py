@@ -259,8 +259,9 @@ def _walk_with(pool, src, fn):
             return fn(dict(dst), s)
         if isinstance(dst, dict) and not _is_state_unit(dst):
             # a state unit has nothing for ``fn`` and may have nothing in
-            # ``src`` (a mixer publishes no token of a decode step)
-            return {k: v if _is_state_unit(v) else walk(v, s[k])
+            # ``src`` (a mixer publishes no token of a decode step), nor
+            # may a layer that holds such units alone
+            return {k: v if _is_state_unit(v) else walk(v, s.get(k, {}))
                     for k, v in dst.items()}
         return dst
 
@@ -523,18 +524,35 @@ def make_paged_view(pool, page_table, lengths):
 #   from its slot's own state; a chunk writes the states at its page ends
 #   (the mixer's own ``chunk_states``, its chunk being a page) to the
 #   entries the host names, entry 0 standing for "none" (never read).
+# - **none** (a pool that holds a ring unit): nothing is kept of a page's
+#   end. A **ring unit** is a window-attention layer's keys and values,
+#   ``ring_key`` / ``ring_value [slots, h, d, window]`` (models/layers.py
+#   DifferentialAttention: token ``t`` on lane ``t mod window``), a slot's
+#   like every state above: the decode step writes its token's column in
+#   place and the contiguous decode kernel reads the ring as it stands, a
+#   chunk takes its slot's row and puts it back. A prefix hit would have
+#   to restore the window's contents at the hit's depth (21 MB a sequence
+#   at 8 layers x 512 tokens x 5 KB), which nothing stores: the manager
+#   refuses the prefix cache for such a model by name, and so no unit of
+#   its pool, a mixer's beside the rings neither, keeps page-end states.
 # ---------------------------------------------------------------------------
 
 _STATE_KEY = "conv_state"
 _MATRIX_STATE_KEY = "ssm_state"
 _PAGE_STATE_KEY = "page_state"
 _SNAPSHOTS_KEY = "snapshots"
-_SLOT_KEYS = (_STATE_KEY, _MATRIX_STATE_KEY)
+_RING_KEYS = ("ring_key", "ring_value")
+_SLOT_KEYS = (_STATE_KEY, _MATRIX_STATE_KEY) + _RING_KEYS
 NULL_SNAPSHOT = 0
 
 
+def _is_ring_unit(d) -> bool:
+    return isinstance(d, dict) and _RING_KEYS[0] in d
+
+
 def _is_state_unit(d) -> bool:
-    return isinstance(d, dict) and _STATE_KEY in d
+    """A unit kept a slot: a recurrent state, or a window's ring."""
+    return isinstance(d, dict) and (_STATE_KEY in d or _is_ring_unit(d))
 
 
 def _slot_leaves(unit) -> dict:
@@ -550,8 +568,10 @@ def _walk_state(tree, fn, *srcs):
         if _is_state_unit(node):
             return fn(dict(node), *ss)
         if isinstance(node, dict) and not _is_attn_unit(node):
-            return {k: walk(v, [s[k] for s in ss] if isinstance(v, dict)
-                            else ss) for k, v in node.items()}
+            # a source may lack a layer that published nothing
+            return {k: walk(v, [s.get(k, {}) for s in ss]
+                            if isinstance(v, dict) else ss)
+                    for k, v in node.items()}
         return node
 
     return walk(_as_dict(tree), [_as_dict(s) for s in srcs])
@@ -571,14 +591,28 @@ def has_recurrent_state(tree) -> bool:
     return len(state_units(tree)) > 0
 
 
+def has_ring_units(tree) -> bool:
+    """Whether the cache tree (or pool) holds a window layer's ring."""
+    return any(_is_ring_unit(u) for u in state_units(tree))
+
+
 def has_snapshot_pool(tree) -> bool:
     """Whether a state unit of the pool keeps its page-end states in a
-    snapshot pool (it holds a matrix state)."""
-    return any(_MATRIX_STATE_KEY in u for u in state_units(tree))
+    snapshot pool (it holds a matrix state, beside no ring)."""
+    return any(_SNAPSHOTS_KEY in u for u in state_units(tree))
+
+
+def has_page_states(tree) -> bool:
+    """Whether a state unit of the pool keeps a state a page."""
+    return any(_PAGE_STATE_KEY in u for u in state_units(tree))
 
 
 def describe_state(tree) -> str:
     """What the model keeps beside its K/V pages, for a refusal."""
+    if has_ring_units(tree):
+        return ("a ring of a window's keys and values a slot in each "
+                "window layer, and a state-space mixer's state a slot "
+                "(nothing of either at a page's end)")
     unit = state_units(tree)[0]
     if _MATRIX_STATE_KEY in unit:
         return ("a state-space mixer's state (a convolution's last columns "
@@ -594,13 +628,17 @@ def init_page_pool(module, params, num_pages: int, page_len: int,
     init, no FLOPs burned. A state unit gets its leaves a slot
     (``num_slots``) and its page-end states in its layout (above):
     ``page_state`` over the pages, or ``snapshots`` over ``snapshots``
-    entries and the null one."""
+    entries and the null one, or nothing in a pool with a ring unit."""
     from .generation import cache_shapes
+    shapes = cache_shapes(module, params, num_pages, page_len)
+    slots_only = has_ring_units(shapes)
 
     def state(unit):
         def zeros(leaf, rows):
             return jnp.zeros((rows,) + leaf.shape[1:], leaf.dtype)
         out = {k: zeros(v, num_slots) for k, v in unit.items()}
+        if slots_only:
+            return out
         if _MATRIX_STATE_KEY in unit:
             out[_SNAPSHOTS_KEY] = {k: zeros(v, snapshots + 1)
                                    for k, v in unit.items()}
@@ -608,8 +646,7 @@ def init_page_pool(module, params, num_pages: int, page_len: int,
             out[_PAGE_STATE_KEY] = zeros(unit[_STATE_KEY], num_pages)
         return out
 
-    pool = _walk_state(cache_shapes(module, params, num_pages, page_len),
-                       state)
+    pool = _walk_state(shapes, state)
     return jax.tree.map(
         lambda s: (jnp.zeros(s.shape, s.dtype)
                    if isinstance(s, jax.ShapeDtypeStruct) else s), pool)
@@ -633,9 +670,14 @@ def chunk_state_view(cache, pool, prev_page, fresh, slot=None, restore=None):
         return jax.lax.dynamic_index_in_dim(leaf, at, axis=0, keepdims=True)
 
     def start(_, unit):
-        if _SNAPSHOTS_KEY not in unit:
+        if _PAGE_STATE_KEY in unit:
             return {_STATE_KEY: jnp.where(
                 fresh, 0.0, row(unit[_PAGE_STATE_KEY], prev_page))}
+        if _SNAPSHOTS_KEY not in unit:
+            # kept a slot and nowhere else: the chunk before left it
+            return {k: jnp.where(fresh, jnp.zeros((), leaf.dtype),
+                                 row(leaf, slot))
+                    for k, leaf in _slot_leaves(unit).items()}
         return {k: jnp.where(
             fresh, jnp.zeros((), leaf.dtype), jnp.where(
                 restore >= 0,
@@ -682,6 +724,8 @@ def store_chunk_state(pool, cache_out, token_tree, slot, page_run,
                 k: leaf.at[snap_run].set(ends[k][0])
                 for k, leaf in unit[_SNAPSHOTS_KEY].items()}
             return new
+        if _PAGE_STATE_KEY not in unit:
+            return new
         trail = tok["trail"][0]                    # [taps-1 + chunk, d]
         width = unit[_STATE_KEY].shape[1]
         page_len = (trail.shape[0] - width) // n_t
@@ -694,8 +738,8 @@ def store_chunk_state(pool, cache_out, token_tree, slot, page_run,
 
 
 def state_bytes(pool) -> int:
-    """Resident bytes of the recurrent state, slots and page ends (or
-    snapshots) together (0 for a model without)."""
+    """Resident bytes of the recurrent state and the rings, slots and
+    page ends (or snapshots) together (0 for a model without)."""
     return sum(int(leaf.size) * leaf.dtype.itemsize
                for unit in state_units(pool)
                for leaf in jax.tree.leaves(unit))

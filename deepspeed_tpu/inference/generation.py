@@ -41,7 +41,7 @@ def init_cache(module, params, batch_size: int, max_len: int):
                         cache_shapes(module, params, batch_size, max_len))
 
 
-def apply_decode(module, variables, ids, positions, live, mutable):
+def apply_decode(module, variables, ids, positions, live, mutable, **more):
     """``module.apply(variables, ids, decode=True, ...)`` as a serving
     program makes it: ``(logits, vars_out, counts)``.
 
@@ -52,17 +52,18 @@ def apply_decode(module, variables, ids, positions, live, mutable):
     module this is the plain call: ``live`` is not called and ``counts``
     is None, an empty pytree that adds nothing to the program. A module
     that keeps a recurrent state and routes nothing (``masks_tokens``,
-    models/falcon_h1.py) takes ``live()`` alone."""
+    models/falcon_h1.py) takes ``live()`` alone. ``more`` goes to the
+    module as it is (a chunk program's ``positions_needed``)."""
     if not getattr(type(module), "routes_tokens", False):
         mask = ({"token_mask": live()}
                 if getattr(type(module), "masks_tokens", False) else {})
         logits, vars_out = module.apply(
             variables, ids, decode=True, positions=positions,
-            mutable=mutable, **mask)
+            mutable=mutable, **mask, **more)
         return logits, vars_out, None
     (logits, router), vars_out = module.apply(
         variables, ids, decode=True, positions=positions, mutable=mutable,
-        token_mask=live(), return_router=True)
+        token_mask=live(), return_router=True, **more)
     return logits, vars_out, router["counts"]
 
 

@@ -14,6 +14,7 @@ Logical axis vocabulary:
   "batch"/"seq" - activation dims (constraints only, never params)
 """
 
+import contextlib
 import functools
 import math
 from typing import Any, Callable, Optional, Tuple
@@ -1391,3 +1392,506 @@ def alibi_bias(n_heads: int, q_positions, k_positions, dtype=jnp.float32):
     rel = (k_positions[None, :] - q_positions[:, None]).astype(jnp.float32)
     bias = slopes[:, None, None] * rel[None, :, :]
     return bias[None].astype(dtype)
+
+
+# ---------------------------------------------------------------------------
+# the layers of a decoder-hybrid-decoder (SambaY, arXiv:2507.06607): a
+# Mamba-1 mixer, differential attention (arXiv:2410.05258) full, windowed
+# or reading another layer's keys and values, and a gated memory unit
+# ---------------------------------------------------------------------------
+
+def _a_log_rows_init(key, shape, dtype=jnp.float32):
+    """Mamba-1's ``A = -(1..d_state)`` a channel: ``A_log [d_state,
+    d_inner]``, row ``n`` the log of ``n + 1``."""
+    return jnp.broadcast_to(
+        jnp.log(jnp.arange(1, shape[0] + 1, dtype=jnp.float32))[:, None],
+        shape).astype(dtype)
+
+
+def _widened_columns_init(first, gain):
+    """Fan-in normal whose columns from ``first`` on are ``gain`` times
+    as wide (``MambaMixer``'s B and C; ``_mixer_in_proj_init`` has the
+    reason)."""
+    base = nn.initializers.variance_scaling(1.0, "fan_in", "normal")
+
+    def init(key, shape, dtype=jnp.float32):
+        w = base(key, shape, jnp.float32)
+        return w.at[:, first:].multiply(gain).astype(dtype)
+    return nn.with_logical_partitioning(init, ("mlp", None))
+
+
+def _diagonal_scan(dt, u, bm, cm, a, s0, q):
+    """``S_t = exp(dt_t (x) a) * S_{t-1} + (dt_t u_t) (x) B_t``, ``y_t =
+    S_t C_t`` over ``dt``, ``u`` ``[b, s, d]`` and ``bm``, ``cm`` ``[b,
+    s, n]`` with ``a [n, d]``, from ``s0 [b, n, d]``: ``(y [b, s, d],
+    the last state)``. ``q`` positions at a time (``lax.associative_scan``
+    inside a block, the state carried between blocks), so that the
+    ``[b, q, n, d]`` decays and states of one block are all that is ever
+    whole; a sequence that is no multiple of ``q`` is padded with steps
+    of 0, which leave the state as it was."""
+    b, s, d = u.shape
+    q = min(q, s)
+    pad = -s % q
+    if pad:
+        cut = lambda v: jnp.pad(v, ((0, 0), (0, pad), (0, 0)))
+        dt, u, bm, cm = map(cut, (dt, u, bm, cm))
+
+    def combine(left, right):
+        return left[0] * right[0], right[0] * left[1] + right[1]
+
+    def block(state, part):
+        dt_, u_, b_, c_ = part                               # [b, q, ...]
+        decay = jnp.exp(dt_[:, :, None, :] * a)              # [b, q, n, d]
+        add = (dt_ * u_)[:, :, None, :] * b_[..., None]
+        decay, add = jax.lax.associative_scan(combine, (decay, add), axis=1)
+        states = decay * state[:, None] + add
+        return states[:, -1], jnp.sum(states * c_[..., None], axis=2)
+
+    blocks = lambda v: jnp.moveaxis(
+        v.reshape(b, (s + pad) // q, q, v.shape[-1]), 1, 0)
+    last, y = jax.lax.scan(block, s0, tuple(map(blocks, (dt, u, bm, cm))))
+    return jnp.moveaxis(y, 0, 1).reshape(b, s + pad, d)[:, :s], last
+
+
+class MambaMixer(nn.Module):
+    """The selective state-space mixer of Mamba-1 (arXiv:2312.00752), a
+    diagonal state with a decay a channel AND a state column:
+
+        [x | z] = W_in n
+        x = silu(conv1d(x) + b)              depthwise, causal, d_conv taps
+        [delta | B | C] = W_x x              dt_rank | d_state | d_state
+        dt = softplus(W_dt delta + dt_bias)  a channel
+        S_t = exp(dt_t (x) A) * S_{t-1} + (dt_t x_t) (x) B_t,  A = -exp(A_log)
+        y_t = S_t C_t + D x_t
+        out = W_out (y * silu(z))
+
+    Two recurrent states, float32, in the "cache" collection when
+    ``decode``, as ``Mamba2Mixer`` keeps them: ``conv_state [batch, d_conv
+    - 1, d_inner]`` and ``ssm_state [batch, d_state, d_inner]`` (the
+    channels on the minor dimension; ``A_log`` is laid out the same way).
+    One computation serves a whole sequence from zero state, a prefill
+    chunk from a carried state and a decode token against its slot's
+    state; a position outside ``token_mask`` gets a step of 0 and leaves
+    the state as it was. The convolution, the step sizes, the decays and
+    the scan are float32 whatever ``dtype`` is.
+
+    ``hand_on``: also return ``y`` — the scan's output with the ``D``
+    term, before the gate — which a ``GatedMemoryUnit`` further up reads
+    at the same position (SambaY's memory)."""
+    d_model: int
+    d_inner: int
+    dt_rank: int
+    d_state: int = 16
+    d_conv: int = 4
+    scan_block: int = 128
+    bc_gain: float = 1.0         # seeded B and C columns of W_x, widened
+    hand_on: bool = False
+    state_dtype: Any = jnp.float32
+    dtype: Any = jnp.bfloat16
+    param_dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(self, x, decode=False, token_mask=None):
+        b, s, _ = x.shape
+        d, n, taps = self.d_inner, self.d_state, self.d_conv
+
+        def dense(features, names, name, init=None):
+            return QDense(features=features, use_bias=False,
+                          dtype=self.dtype, param_dtype=self.param_dtype,
+                          kernel_init=init or dense_init(names), name=name)
+        xs, z = jnp.split(dense(2 * d, ("embed", "mlp"), "in_proj")(x), 2,
+                          axis=-1)
+        w = self.param("conv_w", nn.with_logical_partitioning(
+            nn.initializers.variance_scaling(1.0, "fan_in", "normal",
+                                             in_axis=0, out_axis=1),
+            (None, "mlp")), (taps, d), self.param_dtype)
+        vec = lambda name, init, shape: self.param(
+            name, nn.with_logical_partitioning(init, (None,) * len(shape)),
+            shape, jnp.float32)
+        conv_b = vec("conv_b", nn.initializers.zeros, (d,))
+        a_log = vec("A_log", _a_log_rows_init, (n, d))
+        dt_bias = vec("dt_bias", _dt_bias_init, (d,))
+        d_skip = vec("D", nn.initializers.ones, (d,))
+
+        conv_state = ssm_state = None
+        if decode:
+            conv_state = self.variable("cache", "conv_state", jnp.zeros,
+                                       (b, taps - 1, d), jnp.float32)
+            ssm_state = self.variable("cache", "ssm_state", jnp.zeros,
+                                      (b, n, d), self.state_dtype)
+        carried = conv_state is not None and not self.is_initializing()
+        live = (jnp.ones((b, s), bool) if token_mask is None
+                else token_mask)
+
+        carry = (conv_state.value if carried
+                 else jnp.zeros((b, taps - 1, d), jnp.float32))
+        trail = jnp.concatenate([carry, xs.astype(jnp.float32)], axis=1)
+        u = jax.nn.silu(sum(w[j].astype(jnp.float32) * trail[:, j:j + s]
+                            for j in range(taps)) + conv_b)  # [b, s, d]
+        dbc = dense(self.dt_rank + 2 * n, ("mlp", None), "x_proj",
+                    _widened_columns_init(self.dt_rank, self.bc_gain))(u)
+        delta, bm, cm = jnp.split(dbc, [self.dt_rank, self.dt_rank + n],
+                                  axis=-1)
+        dt = dense(d, (None, "mlp"), "dt_proj")(delta)
+        dt = jax.nn.softplus(dt.astype(jnp.float32) + dt_bias)
+        dt = jnp.where(live[..., None], dt, 0.0)
+        bm, cm = bm.astype(jnp.float32), cm.astype(jnp.float32)
+        a = -jnp.exp(a_log)                                  # [n, d]
+        s0 = (ssm_state.value.astype(jnp.float32) if carried
+              else jnp.zeros((b, n, d), jnp.float32))
+        if carried and s == 1:
+            # one token: the recurrence itself, every slot's state read
+            # once and written once
+            s_new = jnp.exp(dt[:, 0, None, :] * a) * s0 \
+                + (dt * u)[:, 0, None, :] * bm[:, 0, :, None]
+            y = jnp.sum(s_new * cm[:, 0, :, None], axis=1)[:, None]
+        else:
+            y, s_new = _diagonal_scan(dt, u, bm, cm, a, s0, self.scan_block)
+        y = y + d_skip * u
+        if carried:
+            n_live = jnp.sum(live, axis=1, dtype=jnp.int32)
+            conv_state.value = jax.vmap(
+                lambda t, k: jax.lax.dynamic_slice_in_dim(t, k, taps - 1))(
+                trail, n_live)
+            ssm_state.value = s_new.astype(self.state_dtype)
+        out = dense(self.d_model, ("mlp", "embed"), "out_proj")(
+            y * jax.nn.silu(z.astype(jnp.float32)))
+        return (out, y) if self.hand_on else out
+
+
+class GatedMemoryUnit(nn.Module):
+    """SambaY's gated memory unit: ``W2 (m * silu(W1 n))`` with ``m`` an
+    earlier mixer's scan output at the same position (``MambaMixer``'s
+    ``hand_on``). No bias, no state: a layer that reads the memory
+    instead of keeping one."""
+    d_model: int
+    d_memory: int
+    dtype: Any = jnp.bfloat16
+    param_dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(self, x, memory):
+        def dense(features, names, name):
+            return QDense(features=features, use_bias=False, dtype=self.dtype,
+                          param_dtype=self.param_dtype,
+                          kernel_init=dense_init(names), name=name)
+        gate = dense(self.d_memory, ("embed", "mlp"), "w1")(x)
+        return dense(self.d_model, ("mlp", "embed"), "w2")(
+            memory.astype(jnp.float32)
+            * jax.nn.silu(gate.astype(jnp.float32)))
+
+
+def _ring_append(rings, cols, lane, live):
+    """Row ``b``'s columns ``cols[i][b]`` (``[h, d, 1]``) written onto
+    lane ``lane[b]`` of ``rings[i][b]`` (``[h, d, window]``) in place, for
+    the rows that are ``live``: one trip a live row for all the rings
+    (a layer's keys and values), which reads the 128 lanes around the
+    token, replaces one and writes them back where they were
+    (``inference/cache.py _append_rows`` has why no scatter over the lane
+    dimension)."""
+    b, h, d, w = rings[0].shape
+    tile = min(128, w)
+    if w % tile:
+        raise ValueError(f"a ring of {w} tokens is no multiple of {tile}")
+    first = jnp.argsort(~live, stable=True)
+    lanes = jax.lax.broadcasted_iota(jnp.int32, (1, 1, 1, tile), 3)
+
+    def append(i, out):
+        row = first[i]
+        start = lane[row] // tile * tile
+        at = (row, 0, 0, start)
+
+        def one(ring, col):
+            part = jax.lax.dynamic_slice(ring, at, (1, h, d, tile))
+            new = jax.lax.dynamic_slice_in_dim(col, row, 1, axis=0)
+            part = jnp.where(lanes == lane[row] - start,
+                             new.astype(ring.dtype), part)
+            return jax.lax.dynamic_update_slice(ring, part, at)
+        return tuple(map(one, out, cols))
+
+    return jax.lax.fori_loop(0, jnp.sum(live, dtype=jnp.int32), append,
+                             tuple(rings))
+
+
+class DifferentialAttention(nn.Module):
+    """Differential attention (Diff Transformer, arXiv:2410.05258) as
+    SambaY lays it out, in its three places:
+
+    - ``kind="full"``: causal over everything before; its keys and
+      values are the one paged unit (``cached_key`` / ``cached_value``,
+      as ``SelfAttention`` keeps them), and it hands them on;
+    - ``kind="window"``: over the last ``window`` tokens, the token
+      itself counted; its keys and values are a ring a slot
+      (``ring_key`` / ``ring_value [batch, h, d, window]``, token ``t``
+      on lane ``t mod window``: with no position encoding the order of
+      the lanes says nothing);
+    - ``kind="cross"``: queries of its own over the keys and values a
+      ``full`` layer handed on (``shared``); no K/V projection, no cache
+      unit.
+
+    Query heads ``0..H/2-1`` are ``q1``, the rest ``q2``; K/V heads
+    ``0..G/2-1`` are ``k1`` and ``v1``, the rest ``k2``, ``v2``; pair
+    ``p`` reads key head ``p // (H / G)`` of its half and the values
+    ``[v1 | v2]`` of that head, ``2 d`` wide:
+
+        a1 = softmax(q1 k1^T / sqrt(d)) v,  a2 = softmax(q2 k2^T / sqrt(d)) v
+        lam = exp(lq1 . lk1) - exp(lq2 . lk2) + lam_init
+        head = RMSNorm_2d(a1 - lam a2) * (1 - lam_init)
+
+    then ``W_o`` over the ``H / 2`` heads of ``2 d``. **How a page is read
+    once**: the cache keeps ``G / 2`` heads ``2 d`` wide, ``[k1_j ; k2_j]``
+    for the keys and ``[v1_j ; v2_j]`` for the values, and a query goes
+    in ``2 d`` wide with zeros over the half it does not read, so that
+    ``q1 . [k1 ; k2] = q1 . k1``. The decode kernels then see plain
+    grouped-query attention, ``2 H / G`` query rows a cached head, and one
+    call gives ``a1`` and ``a2`` of every pair from one pass over the
+    cache. No rotary or other position encoding.
+
+    ``cache_dtype`` rounds the keys and values once, as they leave their
+    projection, for a cache narrower than the activations (bf16 under
+    float32 activations): a chunk attends the same rounded values a later
+    decode step reads back."""
+    n_heads: int
+    n_kv_heads: int
+    d_model: int
+    head_dim: int
+    layer_index: int = 0
+    kind: str = "full"
+    window: Optional[int] = None
+    use_bias: bool = True
+    norm_epsilon: float = 1e-5
+    cache_dtype: Any = None      # the keys' and values' type from the
+                                 # projection on (cache, ring, pages and
+                                 # what attends them); None: ``dtype``
+    dtype: Any = jnp.bfloat16
+    param_dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(self, x, decode=False, positions=None, token_mask=None,
+                 shared=None):
+        if self.kind not in ("full", "window", "cross"):
+            raise ValueError(f"kind {self.kind!r}")
+        b, s, _ = x.shape
+        d, heads = self.head_dim, self.n_heads
+        pairs, kvh = heads // 2, self.n_kv_heads // 2        # 20, 10
+        group = pairs // kvh                                 # 2
+        cross = self.kind == "cross"
+
+        def dense(features, names, name):
+            return QDense(
+                features=features, use_bias=self.use_bias, dtype=self.dtype,
+                param_dtype=self.param_dtype, kernel_init=dense_init(names),
+                bias_init=nn.with_logical_partitioning(
+                    nn.initializers.zeros, (names[1],)), name=name)
+        if cross:
+            q = dense(heads * d, ("embed", "qkv"), "q")(x)
+        else:
+            qkv = dense((heads + 2 * self.n_kv_heads) * d, ("embed", "qkv"),
+                        "qkv")(x)
+            q, k, v = jnp.split(
+                qkv, [heads * d, (heads + self.n_kv_heads) * d], axis=-1)
+            # K^T layout, the halves of a head stacked: [b, kvh, 2 d, s]
+            stack = lambda t: t.reshape(b, s, 2, kvh, d).transpose(
+                0, 3, 2, 4, 1).reshape(b, kvh, 2 * d, s).astype(
+                    self.cache_dtype or t.dtype)
+            kc, vc = stack(k), stack(v)
+        # [b, s, kvh, half, group, d]: the rows of one cached head together
+        q = q.reshape(b, s, 2, kvh, group, d).transpose(0, 1, 3, 2, 4, 5)
+        if positions is None:
+            positions = jnp.arange(s)
+        q_pos = jnp.broadcast_to(positions, (b, s)) if positions.ndim == 2 \
+            else jnp.broadcast_to(positions[None], (b, s))
+        live = (jnp.ones((b, s), bool) if token_mask is None else token_mask)
+        scale = 1.0 / math.sqrt(d)
+        new_shared = None
+
+        def dense_attend(keys, values, k_pos, k_ok):
+            """``[b, s, kvh, 2, group, 2 d]`` float32 over cached heads
+            ``[b, kvh, 2 d, S]`` at positions ``k_pos`` ``[b | 1, S]``."""
+            see = (k_pos[:, None, :] <= q_pos[:, :, None]) & k_ok[:, None, :]
+            if self.window is not None:
+                see &= k_pos[:, None, :] > q_pos[:, :, None] - self.window
+            kk = keys.reshape(b, kvh, 2, d, keys.shape[-1])
+            sc = jnp.einsum("bqjhgd,bjhdk->bjhgqk", q, kk,
+                            preferred_element_type=jnp.float32) * scale
+            sc = jnp.where(see[:, None, None, None], sc, NEG_INF)
+            pr = jax.nn.softmax(sc, axis=-1)
+            # an unwritten column may hold anything: 0 x NaN is NaN
+            seen = jnp.any(see, axis=1)[:, None, None, :]
+            vals = jnp.where(seen, values.astype(jnp.float32), 0.0)
+            return jnp.einsum("bjhgqk,bjek->bqjhge", pr, vals)
+
+        def kernel_rows():
+            """The one token's queries as the decode kernels take them:
+            ``[b, 1, 2 heads, 2 d]`` float32, zeros over the other half."""
+            q1 = jnp.pad(q[:, :, :, 0], ((0, 0),) * 4 + ((0, d),))
+            q2 = jnp.pad(q[:, :, :, 1], ((0, 0),) * 4 + ((d, 0),))
+            rows = jnp.stack([q1, q2], axis=3)       # [b,1,kvh,2,group,2d]
+            return rows.reshape(b, 1, heads, 2 * d).astype(jnp.float32)
+
+        def from_kernel(out):
+            return out.reshape(b, 1, kvh, 2, group, 2 * d)
+
+        def kernel_precision(cached):
+            """The ambient matmul precision for a decode kernel's trace:
+            over a cache narrower than float32 the kernel's products are
+            bf16 x bf16 by construction (exact in float32), and Mosaic
+            refuses them a float32 contract precision, which float32
+            activations' "highest" would ask for."""
+            if jnp.dtype(cached.dtype).itemsize >= 4:
+                return contextlib.nullcontext()
+            return jax.default_matmul_precision("default")
+
+        if cross:
+            if shared is None:
+                raise ValueError("a cross layer reads the keys and values "
+                                 "a full layer hands on (shared)")
+            if "pool" in shared:
+                from ..ops.pallas.paged_attention import paged_attention
+                pool_k, pool_v, ptab, idx, kn, vn = shared["pool"]
+                with kernel_precision(pool_k):
+                    a = from_kernel(paged_attention(
+                        kernel_rows(), pool_k, pool_v, ptab, idx, kn, vn,
+                        softmax_scale=scale))
+            elif "lengths" in shared and s == 1:
+                from ..ops.pallas import decode_attention
+                with kernel_precision(shared["keys"]):
+                    a = from_kernel(decode_attention(
+                        kernel_rows(), shared["keys"], shared["values"],
+                        shared["lengths"], softmax_scale=scale))
+            else:
+                a = dense_attend(shared["keys"], shared["values"],
+                                 shared["k_pos"], shared["k_ok"])
+        elif not decode or self.is_initializing():
+            if decode:
+                if self.kind == "window":
+                    for name in ("ring_key", "ring_value"):
+                        self.variable("cache", name, jnp.zeros,
+                                      (b, kvh, 2 * d, self.window), kc.dtype)
+                else:
+                    for name in ("cached_key", "cached_value"):
+                        self.variable("cache", name, jnp.zeros, kc.shape,
+                                      kc.dtype)
+                    self.variable("cache", "cache_index",
+                                  lambda: jnp.zeros((), jnp.int32))
+            k_pos = q_pos[:1]
+            k_ok = jnp.ones_like(k_pos, bool)
+            a = dense_attend(kc, vc, k_pos, k_ok)
+            if self.kind == "full":
+                new_shared = {"keys": kc, "values": vc, "k_pos": k_pos,
+                              "k_ok": k_ok}
+        elif self.kind == "window":
+            ring_k = self.variable("cache", "ring_key")
+            ring_v = self.variable("cache", "ring_value")
+            w = self.window
+            if s == 1:
+                from ..ops.pallas import decode_attention
+                at = q_pos[:, 0]
+                ring_k.value, ring_v.value = _ring_append(
+                    (ring_k.value, ring_v.value), (kc, vc), at % w,
+                    live[:, 0])
+                with kernel_precision(kc):
+                    a = from_kernel(decode_attention(
+                        kernel_rows(), ring_k.value, ring_v.value,
+                        jnp.minimum(at + 1, w), softmax_scale=scale))
+            else:
+                if s > w:
+                    raise NotImplementedError(
+                        f"a chunk of {s} tokens over a ring of {w}: a "
+                        "chunk is at most the window")
+                # the chunk's positions are one run from ``start``, the
+                # same in every row; lane r holds the last token before
+                # it that is r mod w
+                start = q_pos[:1, 0]                             # [1]
+                lanes = jnp.arange(w)[None]
+                held = start[:, None] - 1 - (start[:, None] - 1 - lanes) % w
+                k_pos = jnp.concatenate([held, q_pos[:1]], axis=1)
+                k_ok = jnp.concatenate(
+                    [jnp.broadcast_to(held >= 0, (b, w)), live], axis=1)
+                a = dense_attend(
+                    jnp.concatenate([ring_k.value, kc], axis=-1),
+                    jnp.concatenate([ring_v.value, vc], axis=-1), k_pos, k_ok)
+                # lane r takes the chunk's live token that is r mod w
+                src = (lanes - start[:, None]) % w               # [1, w]
+                fresh = (src < s) & jnp.take_along_axis(
+                    live, jnp.broadcast_to(jnp.minimum(src, s - 1), (b, w)),
+                    axis=1)
+                take = lambda t: jnp.take(t, jnp.minimum(src[0], s - 1),
+                                          axis=-1)
+                ring_k.value = jnp.where(fresh[:, None, None], take(kc),
+                                         ring_k.value)
+                ring_v.value = jnp.where(fresh[:, None, None], take(vc),
+                                         ring_v.value)
+        else:                                               # full, cached
+            paged = self.has_variable("cache", "page_table")
+            cache_index = self.variable("cache", "cache_index",
+                                        lambda: jnp.zeros((), jnp.int32))
+            if self.is_mutable_collection("kv_token"):
+                self.variable("kv_token", "k", lambda: kc).value = kc
+                self.variable("kv_token", "v", lambda: vc).value = vc
+            idx = cache_index.value
+            if paged:
+                if s != 1:
+                    raise NotImplementedError(
+                        "paged-pool decode is single-token (got chunk "
+                        f"length {s})")
+                from ..ops.pallas.paged_attention import paged_attention
+                pool = self.variables["kv_pool"]
+                if "key_scale" in pool:
+                    raise NotImplementedError(
+                        "int8 K/V pages under differential attention: the "
+                        "layers that read this pool are not handed its "
+                        "scale planes")
+                ptab = self.get_variable("cache", "page_table")
+                operands = (pool["cached_key"], pool["cached_value"], ptab,
+                            idx, kc, vc)
+                with kernel_precision(kc):
+                    a = from_kernel(paged_attention(
+                        kernel_rows(), *operands, softmax_scale=scale))
+                new_shared = {"pool": operands}
+                cache_index.value = idx + 1
+            else:
+                ck = self.variable("cache", "cached_key")
+                cv = self.variable("cache", "cached_value")
+                if idx.ndim == 1:
+                    write = jax.vmap(
+                        lambda c, t, i: jax.lax.dynamic_update_slice(
+                            c, t, (0, 0, i)))
+                    k_all = write(ck.value, kc.astype(ck.value.dtype), idx)
+                    v_all = write(cv.value, vc.astype(cv.value.dtype), idx)
+                else:
+                    k_all = jax.lax.dynamic_update_slice(
+                        ck.value, kc.astype(ck.value.dtype), (0, 0, 0, idx))
+                    v_all = jax.lax.dynamic_update_slice(
+                        cv.value, vc.astype(cv.value.dtype), (0, 0, 0, idx))
+                ck.value, cv.value = k_all, v_all
+                cache_index.value = idx + s
+                k_pos = jnp.arange(k_all.shape[-1])[None]
+                new_shared = {"keys": k_all, "values": v_all, "k_pos": k_pos,
+                              "k_ok": jnp.ones_like(k_pos, bool)}
+                if idx.ndim == 1 and s == 1:
+                    from ..ops.pallas import decode_attention
+                    new_shared["lengths"] = idx + 1
+                    with kernel_precision(k_all):
+                        a = from_kernel(decode_attention(
+                            kernel_rows(), k_all, v_all, idx + 1,
+                            softmax_scale=scale))
+                else:
+                    a = dense_attend(k_all, v_all, k_pos, new_shared["k_ok"])
+
+        # [b, s, kvh, half, group, 2 d] -> pairs of (a1, a2)
+        a = a.astype(jnp.float32)
+        a1 = a[:, :, :, 0].reshape(b, s, pairs, 2 * d)
+        a2 = a[:, :, :, 1].reshape(b, s, pairs, 2 * d)
+        lam_init = 0.8 - 0.6 * math.exp(-0.3 * self.layer_index)
+        lvec = lambda name: self.param(
+            name, nn.with_logical_partitioning(
+                nn.initializers.normal(0.1), (None,)), (d,), jnp.float32)
+        lam = jnp.exp(jnp.sum(lvec("lambda_q1") * lvec("lambda_k1"))) \
+            - jnp.exp(jnp.sum(lvec("lambda_q2") * lvec("lambda_k2"))) \
+            + lam_init
+        out = RMSNorm(epsilon=self.norm_epsilon, name="subln")(
+            a1 - lam * a2) * (1.0 - lam_init)
+        out = dense(self.d_model, ("qkv", "embed"), "out")(
+            out.reshape(b, s, pairs * 2 * d))
+        return (out, new_shared) if self.kind == "full" else out

@@ -62,14 +62,15 @@ KERNEL = "decode_attention"
 
 def _dma_kernel(len_ref, slopes_ref, q_ref, k_hbm, v_hbm, o_ref,
                 kbuf0, vbuf0, kbuf1, vbuf1, sem, m_ref, l_ref, acc_ref,
-                *, scale, block_k, hb, alibi):
+                *, scale, block_k, hb, alibi, group=1):
     b, hi = pl.program_id(0), pl.program_id(1)
     length = len_ref[b]
     nb = pl.cdiv(length, block_k)
     m_ref[...] = jnp.full_like(m_ref, NEG_INF)
     l_ref[...] = jnp.zeros_like(l_ref)
     acc_ref[...] = jnp.zeros_like(acc_ref)
-    slopes = _read_slopes(slopes_ref, hi * hb, hb) if alibi else None
+    slopes = (_read_slopes(slopes_ref, hi * hb * group, hb * group)
+              if alibi else None)
     bufs = ((kbuf0, vbuf0), (kbuf1, vbuf1))
 
     def copies(j, slot):
@@ -111,7 +112,7 @@ def _dma_kernel(len_ref, slopes_ref, q_ref, k_hbm, v_hbm, o_ref,
                 kb, vb = bufs[parity]
                 _attend_block(q, kb, vb, j * block_k, length, length - 1,
                               slopes, m_ref, l_ref, acc_ref, scale=scale,
-                              hb=hb, alibi=alibi)
+                              hb=hb, alibi=alibi, group=group)
         return carry
 
     jax.lax.fori_loop(0, nb, body, 0)
@@ -126,8 +127,13 @@ def _dma_kernel(len_ref, slopes_ref, q_ref, k_hbm, v_hbm, o_ref,
 
 def _decode_dma(q_bhd, k, v, lengths, slopes, *, scale, block_k, hb, alibi):
     b, heads, d = q_bhd.shape
-    s = k.shape[3]
-    nhb = heads // hb
+    kv_heads, s = k.shape[1], k.shape[3]
+    # grouped-query heads, as the paged kernel takes them: the grid walks
+    # the cache's K/V heads and a step holds every query head that reads
+    # its ``hb``, one row each
+    group = heads // kv_heads
+    nhb = kv_heads // hb
+    rows = hb * group
     kr = k.reshape(b, nhb, hb, d, s)
     vr = v.reshape(b, nhb, hb, d, s)
     kv_buf = lambda: pltpu.VMEM((hb, d, block_k), k.dtype)
@@ -135,11 +141,11 @@ def _decode_dma(q_bhd, k, v, lengths, slopes, *, scale, block_k, hb, alibi):
     # array's own last two dims: a (1, hb, d) block of [B, H, d] is
     # refused by the Mosaic lowering unless hb % 8 == 0 or hb == H
     # (12 heads -> hb 4)
-    tok_spec = pl.BlockSpec((1, 1, hb, d),
+    tok_spec = pl.BlockSpec((1, 1, rows, d),
                             lambda bi, hi, *_: (bi, hi, 0, 0))
     out = pl.pallas_call(
         functools.partial(_dma_kernel, scale=scale, block_k=block_k,
-                          hb=hb, alibi=alibi),
+                          hb=hb, alibi=alibi, group=group),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(b, nhb),
@@ -152,16 +158,16 @@ def _decode_dma(q_bhd, k, v, lengths, slopes, *, scale, block_k, hb, alibi):
             scratch_shapes=[
                 kv_buf(), kv_buf(), kv_buf(), kv_buf(),
                 pltpu.SemaphoreType.DMA((2, 2)),
-                pltpu.VMEM((hb, 1), jnp.float32),
-                pltpu.VMEM((hb, 1), jnp.float32),
-                pltpu.VMEM((hb, d), jnp.float32),
+                pltpu.VMEM((rows, 1), jnp.float32),
+                pltpu.VMEM((rows, 1), jnp.float32),
+                pltpu.VMEM((rows, d), jnp.float32),
             ],
         ),
-        out_shape=jax.ShapeDtypeStruct((b, nhb, hb, d), q_bhd.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, nhb, rows, d), q_bhd.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")),
         interpret=_interpret(),
-    )(lengths, slopes, q_bhd.reshape(b, nhb, hb, d), kr, vr)
+    )(lengths, slopes, q_bhd.reshape(b, nhb, rows, d), kr, vr)
     return out.reshape(b, heads, d)
 
 
@@ -170,6 +176,9 @@ def _decode_dense(q_bhd, k, v, lengths, slopes, *, scale, alibi):
     tile (max_len not a multiple of 128). XLA fuses the chain; the mask
     still never leaves registers as a [B,H,1,S] tensor thanks to fusion."""
     s = k.shape[3]
+    if k.shape[1] != q_bhd.shape[1]:               # grouped-query heads
+        k = jnp.repeat(k, q_bhd.shape[1] // k.shape[1], axis=1)
+        v = jnp.repeat(v, q_bhd.shape[1] // v.shape[1], axis=1)
     logits = jnp.einsum("bhd,bhdk->bhk", q_bhd.astype(jnp.float32) * scale,
                         k.astype(jnp.float32))
     col = jnp.arange(s)[None, None, :]
@@ -191,7 +200,10 @@ def decode_attention(q, k, v, length, *, softmax_scale=None,
     """Single-token KV-cache attention over transposed caches.
 
     q: [B, 1, H, d] (or [B, H, d]) — the current token's queries (BSHD).
-    k, v: [B, H, d, S] — the preallocated cache in K^T layout.
+    k, v: [B, H, d, S] — the preallocated cache in K^T layout; or
+        [B, H_kv, d, S] with fewer heads than q (grouped-query
+        attention: query head i reads K/V head i // (H / H_kv), and a
+        head's block is fetched once for its whole group).
     length: int32 scalar or [B] — number of valid cache slots per row
         (the query sits at position length-1). Rows with length <= 0
         (empty serving slots) return zeros.
@@ -209,11 +221,16 @@ def decode_attention(q, k, v, length, *, softmax_scale=None,
     if one != 1:
         raise ValueError(f"decode_attention is single-token (q_len 1), got {one}")
     s = k.shape[3]
+    kv_heads = k.shape[1]
+    if heads % kv_heads:
+        raise ValueError(f"{heads} query heads over a cache of {kv_heads} "
+                         "K/V heads: not a whole group each")
     scale = softmax_scale if softmax_scale is not None else 1.0 / math.sqrt(d)
-    tp = model_axis_size(mesh, heads)
+    tp = model_axis_size(mesh, kv_heads)
     # the paged kernel's rule (a float32 cache's step is held to eight
     # rows, whatever is asked): the two share ``online_softmax_block``
-    hb = step_head_block(heads // tp, 1, k.dtype, head_block)
+    hb = step_head_block(kv_heads // tp, heads // kv_heads, k.dtype,
+                         head_block)
 
     lengths = jnp.broadcast_to(jnp.asarray(length, jnp.int32), (b,))
     alibi = alibi_slopes is not None

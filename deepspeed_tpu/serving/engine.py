@@ -951,6 +951,8 @@ class ServingEngine:
         self._state = out.state
         self.metrics.on_prefill_chunk(real, out.state_pages, pages=pages,
                                       snapshot_table=out.snapshot_table)
+        self.metrics.on_split_chunk(width, out.cross_positions,
+                                    real if mgr.has_rings else None)
         if out.counts is not None:
             # an expert layer's routing of this chunk: read back with the
             # first token, by when every earlier chunk has finished
@@ -1152,9 +1154,10 @@ class ServingEngine:
             toks, done, *counts = self._read_back(toks, done, *counts)
             self._fold_moe_counts(counts)
             # before the tokens are emitted: what the pool's kernel walked
-            rows, latent = self._paged.decode_walked(toks, snapshot)
+            rows, latent, rings = self._paged.decode_walked(toks, snapshot)
             self.metrics.on_decode_harvest(rows)
             self.metrics.on_latent_walk(latent)
+            self.metrics.on_ring_walk(rings)
             for slot, req in enumerate(snapshot):
                 if req is None or req.done:  # empty, or cancelled in flight
                     continue

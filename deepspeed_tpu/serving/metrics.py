@@ -471,6 +471,38 @@ class ServingMetrics:
         if self.registry is not None and tokens is not None:
             self.registry.counter("serving/latent_tokens_walked").inc(tokens)
 
+    def on_ring_walk(self, walk):
+        """One decode dispatch over a pool with window rings, read back:
+        ``(pooled tokens, ring tokens, rows)`` — what each call over the
+        one layer's shared pages read (the rows' contexts, summed), what
+        each call over a ring read (a context and the token itself, at
+        most the window) and the columns each ring was written. Times a
+        token's K/V bytes they are what those calls had to move. None (a
+        pool without rings) counts nothing."""
+        if self.registry is not None and walk is not None:
+            pooled, ring, rows = walk
+            self.registry.counter("serving/shared_kv_tokens_walked").inc(
+                pooled)
+            self.registry.counter("serving/ring_tokens_read").inc(ring)
+            self.registry.counter("serving/ring_tokens_written").inc(rows)
+
+    def on_split_chunk(self, width: int, cross_positions: Optional[int],
+                       ring_tokens: Optional[int]):
+        """One chunk program of a module whose upper layers keep nothing
+        and run on ``cross_positions`` of the chunk's ``width`` positions
+        (models/phi4flash.py: one, where the logits are read); the
+        chunk's ``ring_tokens`` live tokens went onto each window ring.
+        None, None (every other model) counts nothing."""
+        if self.registry is None:
+            return
+        if cross_positions is not None:
+            self.registry.counter("serving/self_decoder_positions").inc(width)
+            self.registry.counter("serving/cross_decoder_positions").inc(
+                cross_positions)
+        if ring_tokens is not None:
+            self.registry.counter("serving/ring_tokens_written").inc(
+                ring_tokens)
+
     def on_moe_counts(self, counts, shared_rows=None):
         """One dispatch's routing, ``[L, E]``: the token-expert pairs
         each layer's router sent to each expert (rows that held no

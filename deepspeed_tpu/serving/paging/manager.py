@@ -42,7 +42,8 @@ import jax.numpy as jnp
 from ...inference.cache import (NULL_SNAPSHOT, _kv_leaves, cache_page_len,
                                 chunk_state_view, describe_state,
                                 export_pages, extract_token_kv, gather_pages,
-                                has_latent_units, has_recurrent_state,
+                                has_latent_units, has_page_states,
+                                has_recurrent_state, has_ring_units,
                                 has_snapshot_pool, import_pages,
                                 init_page_pool, kv_leaves, make_paged_view,
                                 pool_is_quantized, quantize_page_pool,
@@ -220,11 +221,20 @@ def _chunk_prefill_impl(module, params, pool, state, ptab_row, chunk_ids,
                                first == 0, slot, restore)
     positions = chunk_start + jnp.arange(chunk_ids.shape[1])
     p_ = param_transform(params) if param_transform is not None else params
+
+    def last_index():
+        return jnp.clip(end_pos - 1 - chunk_start, 0, chunk_ids.shape[1] - 1)
+    # a module whose upper layers keep nothing (``splits_positions``,
+    # models/phi4flash.py) runs them on the one position whose logits are
+    # read below, not on the chunk: its logits come back ``[1, 1, vocab]``
+    splits = getattr(type(module), "splits_positions", False)
+    needed = {"positions_needed": last_index()[None]} if splits else {}
     # the chunk's right padding is no token: an expert layer routes it
     # nowhere
     logits, vars_out, counts = apply_decode(
         module, {"params": p_, "cache": row}, chunk_ids, positions,
-        lambda: (positions < end_pos)[None], ["cache", "kv_token"])
+        lambda: (positions < end_pos)[None], ["cache", "kv_token"],
+        **needed)
 
     chunk = chunk_ids.shape[1]
     tok_tree = vars_out.get("kv_token")
@@ -238,9 +248,8 @@ def _chunk_prefill_impl(module, params, pool, state, ptab_row, chunk_ids,
         pool = store_chunk_state(pool, vars_out["cache"], tok_tree, slot, run,
                                  snap_run)
 
-    last_idx = jnp.clip(end_pos - 1 - chunk_start, 0, chunk - 1)
-    last = jax.lax.dynamic_slice_in_dim(logits, last_idx, 1,
-                                        axis=1)[:, 0]             # [1, vocab]
+    last = (logits if splits else jax.lax.dynamic_slice_in_dim(
+        logits, last_index(), 1, axis=1))[:, 0]                # [1, vocab]
     tok = _sample_impl(last, rng, t, k, p, greedy, has_k, has_p)[0]
     remaining = max_new - 1
     done = (tok == eos_id) | (remaining <= 0)
@@ -279,6 +288,10 @@ class ProgramResult(NamedTuple):
     state_pages: int = 0        # a chunk's whole pages, each stored with
                                 # the state at its end (a state a page)
     snapshot_table: Any = None  # a chunk's: the snapshot pool's table
+    cross_positions: Optional[int] = None   # a chunk's: the positions its
+                                # module's upper layers ran on, where they
+                                # keep nothing and run on fewer than the
+                                # chunk (``splits_positions``)
 
 
 class Admission(NamedTuple):
@@ -385,6 +398,28 @@ class PagedKVManager:
                                "pages)")
             pool = quantize_page_pool(pool)
         self.has_state = has_recurrent_state(pool)
+        # window layers' rings (inference/cache.py): kept a slot, with
+        # nothing at a page's end for a prefix hit to start from
+        self.has_rings = has_ring_units(pool)
+        if self.has_rings:
+            self.window = next(u["ring_key"].shape[-1]
+                               for u in state_units(pool) if "ring_key" in u)
+            self.refuse_rings(pool, self.config.enable_prefix_cache,
+                              "serving.paging.enable_prefix_cache",
+                              "a prefix hit would start from")
+            self.refuse_rings(pool, self.kv_quant, "serving.kv_int8",
+                              "int8 pages would quantize")
+            widest = self.config.prefill_chunk or self.page_len * max(
+                w for w in CHUNK_PAGES if w <= self.max_pages)
+            if widest > self.window:
+                raise NotImplementedError(
+                    f"a prefill chunk of {widest} tokens over "
+                    f"{type(self._module).__name__}'s window of "
+                    f"{self.window}: a chunk writes each ring lane once, "
+                    "so it is at most the window (serving.paging."
+                    "prefill_chunk, or page_len x "
+                    f"{max(CHUNK_PAGES)} where it is unset)")
+        self.page_states = has_page_states(pool)
         self.snapshots = (SnapshotTable(entries)
                           if has_snapshot_pool(pool) else None)
         self._state_bytes = state_bytes(pool)      # shapes: read once
@@ -609,26 +644,36 @@ class PagedKVManager:
             is_last, rng, eos, mode, param_transform)
         self.pool, *out = program(*args)
         stored = 0
-        if self.has_state and self.snapshots is None:
+        if self.page_states:
             stored = (min(start + width, end_pos) - start) // self.page_len
+        split = getattr(type(module), "splits_positions", False)
         return ProgramResult(*out, state_pages=stored,
-                             snapshot_table=self.snapshots)
+                             snapshot_table=self.snapshots,
+                             cross_positions=1 if split else None)
 
     def decode_walked(self, tokens, requests):
-        """``(rows, latent tokens)`` the decode dispatch just read back
-        walked (``requests``: its slot -> request), each None where this
-        pool has no such count: the rows that kept a token, which alone
-        were handed a length, when the paged kernel runs; over a latent
-        pool the tokens those rows attended — a row's prompt and all it
-        had generated but the token it was fed (ask before they emit)."""
+        """``(rows, latent tokens, ring walk)`` the decode dispatch just
+        read back walked (``requests``: its slot -> request), each None
+        where this pool has no such count: the rows that kept a token,
+        which alone were handed a length, when the paged kernel runs;
+        over a latent pool the tokens those rows attended — a row's
+        prompt and all it had generated but the token it was fed (ask
+        before they emit); over a pool with window rings ``(pooled
+        tokens, ring tokens, rows)``: what a call over the shared pages
+        read (the same contexts), what a call over a ring read (each
+        context and the token itself, at most the window) and the
+        columns a ring was written."""
         rows = np.count_nonzero(tokens >= 0) if self.use_kernel else None
-        latent = None
+        if not (self.has_latent or self.has_rings):
+            return rows, None, None
+        contexts = [req.prompt.shape[0] + len(req.output_tokens) - 1
+                    for slot, req in enumerate(requests)
+                    if req is not None and not req.done and tokens[slot] >= 0]
         if self.has_latent:
-            latent = sum(
-                req.prompt.shape[0] + len(req.output_tokens) - 1
-                for slot, req in enumerate(requests)
-                if req is not None and not req.done and tokens[slot] >= 0)
-        return rows, latent
+            return rows, sum(contexts), None
+        return rows, None, (sum(contexts),
+                            sum(min(c + 1, self.window) for c in contexts),
+                            len(contexts))
 
     # -- page-granular handoff (serving/fleet disaggregation) --------------
     def export_slot(self, slot: int, prefill_len: int):
@@ -691,6 +736,16 @@ class PagedKVManager:
                 f"{type(self._module).__name__} keeps "
                 f"{describe_state(self.pool)} beside its K/V pages, which "
                 "this path neither moves nor rolls back")
+
+    def refuse_rings(self, pool, asked, option: str, why: str):
+        """An option that a pool with window rings cannot serve, by
+        name."""
+        if asked:
+            raise NotImplementedError(
+                f"{option} is not built for {type(self._module).__name__}: "
+                f"it keeps {describe_state(pool)} beside one "
+                f"layer's K/V pages, and {why} what only the pages hold; "
+                f"turn {option} off for this model")
 
     def refuse_latent(self, what: str):
         """What is written for pages of K and V heads is not built for a

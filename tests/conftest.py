@@ -68,9 +68,33 @@ PINNED_BEFORE_ISSUE_44 = {
 }
 
 
+# ISSUE 56's cell ``serve-phi4flash-reason`` runs ``reason-closed-64`` in
+# slots of 4,096 (prompts to 768, chains of thought to 3,072), over the
+# 2,048 that ``test_traffic.py`` holds every mix to: the same line, and the
+# same mend, as ISSUE 37's. And its thirteen ``*.reason`` entries follow
+# h1chat's at the end of the manifest's ``per_layer``, where the driver's
+# contract puts them, so that ``test_falconh1_cell.py``'s "nothing after
+# PR 41's run but h1chat's" cannot hold: both files are the benchmark's,
+# and ``tests/chip_bench/test_phi4flash_cell.py::test_the_entries_before_
+# this_cells_are_as_they_were`` holds every other assertion of the second
+# (PR 41's eighteen one unbroken run, h1chat's thirteen next, the
+# ``iterations_*`` files on disk) until the ``benchmark`` PR that mends it.
+PINNED_BEFORE_ISSUE_56 = {
+    "tests/chip_bench/test_traffic.py::"
+    "test_lengths_stay_inside_the_mix_and_the_server[reason-closed-64]":
+        "reason-closed-64 runs in slots of 4096, not 2048 (ISSUE 56; "
+        "mend: ROADMAP 2.8a)",
+    "tests/chip_bench/test_falconh1_cell.py::"
+    "test_pr_41s_entries_are_one_unbroken_run_and_its_files_its_own":
+        "the manifest's per_layer list goes on after h1chat's entries "
+        "since ISSUE 56 (mend: compare an unbroken run, not the list's end)",
+}
+
+
 def pytest_collection_modifyitems(items):
     for item in items:
         for node, reason in {**PINNED_BEFORE_ISSUE_37,
-                             **PINNED_BEFORE_ISSUE_44}.items():
+                             **PINNED_BEFORE_ISSUE_44,
+                             **PINNED_BEFORE_ISSUE_56}.items():
             if item.nodeid.endswith(node):
                 item.add_marker(pytest.mark.xfail(reason=reason, strict=True))

@@ -240,7 +240,13 @@ print(json.dumps({"record": rec, "mosaic_calls": compiled.as_text().count(
     # them one grid step at the table's block (PR 51)
     ((32, 16, 16, 128, 8, 16), 16, 16, "defaults"),
     ((32, 16, 16, 128, 24, 16), 16, 16, "defaults"),
-], ids=["falcon-h1-20on4", "32on8-d64", "40on8", "olmoe-16", "gpt2-1p3b-16"])
+    # serve-phi4flash-reason: 64 rows, differential attention's 40 query
+    # rows on ten cached heads of 128 (two halves of 64 stacked), one
+    # layer's pool of 32 pages a row: ten heads take two a step (the head
+    # blocks that ran compiled are 1, 2, 4, 8, 16), eight rows
+    ((64, 40, 10, 128, 1, 32), 2, 8, "constants"),
+], ids=["falcon-h1-20on4", "32on8-d64", "40on8", "olmoe-16", "gpt2-1p3b-16",
+        "phi4flash-40on10"])
 def test_bf16_steps_of_many_rows_compile_for_the_chip(
         shape, head_block, rows, source):
     import json
@@ -759,3 +765,93 @@ def test_flash_training_calls_compile_for_the_chip(one_chip, monkeypatch,
     bhsd = "bf16[%d,%d,%d,%d]" % (b, h, s, d)
     results = sorted(l.split(" custom-call(")[0].count(bhsd) for l in calls)
     assert results == [1, 3], results
+
+
+def test_phi4flash_decode_program_keeps_pages_rings_and_states_in_place(
+        one_chip, monkeypatch):
+    """Three kinds of state in one pool: the decode program of the first
+    eight layers' kinds at published widths (mixers, two window layers,
+    the full layer, a memory unit, a cross layer) compiled for the chip
+    holds the paged kernel twice — the full layer's call and the cross
+    layer's, over the same pages — and the contiguous decode kernel once
+    a ring; it aliases the whole pool and moves none of it: a copy of
+    one layer's rings would be 84 MB of scratch, of its pages 335 MB."""
+    from deepspeed_tpu.inference.cache import has_ring_units, state_bytes
+    from deepspeed_tpu.models.phi4flash import Phi4Flash, Phi4FlashConfig
+    from deepspeed_tpu.ops.pallas import tuning
+    for name in ("paged_attention", "decode_attention"):
+        monkeypatch.setattr(
+            importlib.import_module(f"deepspeed_tpu.ops.pallas.{name}"),
+            "_interpret", lambda: False)
+    slots, pages, max_pages = 64, 2049, 32
+    model = Phi4Flash(Phi4FlashConfig(
+        num_hidden_layers=8, vocab_size=25008, max_position_embeddings=4096,
+        dtype=jnp.bfloat16, param_dtype=jnp.bfloat16))
+    import flax.core.meta as flax_meta
+    params = jax.eval_shape(
+        lambda r: flax_meta.unbox(model.init(
+            r, jnp.ones((1, 8), jnp.int32)))["params"],
+        jax.random.PRNGKey(0))
+
+    def on_chip(tree):
+        shapes = jax.eval_shape(tree) if callable(tree) else tree
+        return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=one_chip), shapes)
+
+    slot = lambda dtype: jax.ShapeDtypeStruct((slots,), dtype)
+    state = {"lengths": slot(jnp.int32), "last_token": slot(jnp.int32),
+             "active": slot(jnp.bool_), "remaining": slot(jnp.int32)}
+    pool_shapes = on_chip(lambda: init_page_pool(
+        model, params, pages, PAGE_LEN, slots, 32))
+    assert has_ring_units(pool_shapes)
+    assert sorted(pool_shapes) == [f"layers_{i}" for i in range(6)]
+    assert pool_shapes["layers_5"]["shared_attn"]["cached_key"].shape \
+        == (pages, 10, 128, PAGE_LEN)
+    assert pool_shapes["layers_1"]["window_attn"]["ring_key"].shape \
+        == (slots, 10, 128, 512)
+    mixer = pool_shapes["layers_4"]["mixer"]
+    assert set(mixer) == {"conv_state", "ssm_state"}   # no page-end states
+    assert mixer["ssm_state"].shape == (slots, 16, 5120)
+    assert state_bytes(pool_shapes) == slots * (
+        2 * 2 * 10 * 128 * 512 * 2 + 3 * (16 + 3) * 5120 * 4)
+    args = (on_chip(params), pool_shapes,
+            on_chip(jax.ShapeDtypeStruct((slots, max_pages), jnp.int32)),
+            on_chip(state), on_chip(lambda: jax.random.PRNGKey(0)),
+            on_chip(jax.ShapeDtypeStruct((), jnp.int32)))
+    static = (25007, 1.0, 0, 1.0, None, True, False, False, True,
+              jnp.bfloat16)
+    tuning.clear_last_dispatch()
+    compiled = _compile_decode(model, args, static)
+    rec = tuning.last_dispatch("paged_attention")["page%d" % PAGE_LEN]
+    assert (rec["impl"], rec["head_block"], rec["rows"], rec["products"]) \
+        == ("kernel", 2, 8, "bfloat16")
+    ring, = tuning.last_dispatch("decode_attention").values()
+    assert (ring["impl"], ring["key"], ring["head_block"]) \
+        == ("kernel", "b64_h40_d128_s512", 2)
+
+    hlo = compiled.as_text()
+    calls = [line.split(" = ")[0].strip() for line in hlo.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert sorted(re.sub(r"\.\d+$", "", c) for c in calls) == [
+        "%cross_attn", "%shared_attn", "%window_attn", "%window_attn"]
+    pool_bytes = sum(x.size * x.dtype.itemsize
+                     for x in jax.tree.leaves(pool_shapes))
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= pool_bytes
+    # rows of activations and logits (64 x 25,008 float32 is 6.4 MB)
+    assert mem.temp_size_in_bytes < 48 * 2 ** 20, mem.temp_size_in_bytes
+    roots = _roots(_computations(hlo))
+    moved = re.compile(r"\[%d,10,128,(?:%d|512)\]|\[%d,16,5120\]" % (
+        pages, PAGE_LEN, slots) + r"|\[%d,10,128,512\]" % slots)
+    for line in hlo.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%[\w.\-]+ = (\S+) ([\w\-]+)\(", line)
+        if not m or m.group(1).startswith("(") or not moved.search(
+                m.group(1)):
+            continue
+        op = m.group(2)
+        if op == "fusion":
+            op = roots[re.search(r"calls=%([\w.\-]+)", line).group(1)]
+        # the mixer's update rewrites every slot's state: a fusion whose
+        # root is the new state, in the buffer of the old
+        assert op in IN_PLACE or "16,5120" in m.group(1), \
+            f"moved by: {line.strip()[:200]}"
