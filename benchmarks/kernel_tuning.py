@@ -386,6 +386,77 @@ def sweep_grouped_matmul(pairs, terms, experts, layers, k, n, down=False,
                   "bytes_us": round(least, 2), "swept": swept}}
 
 
+def sweep_ring_append(rows=64, heads=10, head_dim=128, window=512,
+                      dtype="bfloat16", live=(64, 32, 1), calls=16,
+                      trials=3, warmup=1, log=print):
+    """Time the ring's write alone (``ops/pallas/ring_append.py``) at one
+    window layer's rings, ``[rows, heads, head_dim, window]`` for the keys
+    and for the values, with each of ``live`` rows decoding (spread
+    evenly over the slots, as a server's are): ``calls`` calls chained in
+    one program over donated rings, the token's lane moving on by one a
+    call, the time reported per call and per live row beside
+    ``bytes_us_a_row``, what a row's two tiles read and written take at
+    the HBM's peak. The first call of each count is held to a numpy
+    reference bit for bit. Returns {key: entry} in the tuning artifact's
+    format (there is no block to choose: ``swept`` holds the counts)."""
+    import numpy as np
+    import jax
+    import jax.numpy as jnp
+    from deepspeed_tpu.ops.pallas import tuning
+    from deepspeed_tpu.ops.pallas.ring_append import (KERNEL, TILE,
+                                                      ring_append)
+
+    dt = jnp.dtype(dtype)
+    shape = (rows, heads, head_dim, window)
+    ks = jax.random.split(jax.random.PRNGKey(0), 4)
+    cols = tuple(jax.random.normal(k, shape[:3] + (1,), dt) for k in ks[2:])
+    lane0 = jnp.asarray(np.random.default_rng(0).integers(0, window, rows),
+                        jnp.int32)
+    tile = min(TILE, window)
+    least = 2 * 2 * heads * head_dim * tile * dt.itemsize \
+        / HBM_BYTES_PER_S * 1e6
+
+    def chain(rings, cols, lane, mask, n):
+        return jax.lax.fori_loop(0, n, lambda i, r: ring_append(
+            r, cols, (lane + i) % window, mask), rings)
+
+    # (the rings are donated, so each timed call takes the last one's:
+    # ``_time_it`` hands one set of arguments to every call)
+    fn = jax.jit(chain, donate_argnums=(0,), static_argnums=(4,))
+    fresh = lambda: tuple(jax.random.normal(k, shape, dt) for k in ks[:2])
+    swept, key = [], None
+    for n_live in live:
+        mask = np.zeros(rows, bool)
+        mask[np.linspace(0, rows - 1, n_live).round().astype(int)] = True
+        args = (cols, lane0, jnp.asarray(mask))
+        before = [np.array(r) for r in fresh()]
+        after = fn(fresh(), *args, 1)
+        key = tuning.last_dispatch(KERNEL)["tile"]["key"]
+        for want, got, col in zip(before, after, cols):
+            for b in np.flatnonzero(mask):
+                want[b, :, :, int(lane0[b])] = np.array(col)[b, :, :, 0]
+            if not np.array_equal(want, np.array(got)):
+                raise AssertionError(
+                    f"ring_append at {key} with {n_live} live rows differs "
+                    "from the numpy reference")
+        rings = fresh()
+        for _ in range(max(warmup, 1)):
+            rings = jax.block_until_ready(fn(rings, *args, calls))
+        best = float("inf")
+        for _ in range(max(trials, 1)):
+            t0 = time.perf_counter()
+            rings = jax.block_until_ready(fn(rings, *args, calls))
+            best = min(best, time.perf_counter() - t0)
+        us = best * 1e6 / calls
+        log(f"ring_append {key}: {n_live} live rows {us:.1f} us a call, "
+            f"{us / n_live:.2f} us a row (its bytes' time {least:.2f}: "
+            f"{100 * least * n_live / us:.1f}%)")
+        swept.append({"live": n_live, "us": round(us, 2),
+                      "us_a_row": round(us / n_live, 3)})
+    return {key: {"tile": tile, "bytes_us_a_row": round(least, 3),
+                  "swept": swept}}
+
+
 def _int_list(text):
     return [int(x) for x in str(text).split(",") if x]
 
@@ -412,7 +483,8 @@ def main(argv=None):
                    help="cap the per-structure candidate grid (CI smoke)")
     p.add_argument("--kernel", choices=["flash_attention",
                                         "paged_attention",
-                                        "grouped_matmul", "all"],
+                                        "grouped_matmul", "ring_append",
+                                        "all"],
                    default="flash_attention",
                    help="which kernel family to sweep; paged_attention "
                         "sweeps the serving decode kernel over the "
@@ -431,6 +503,10 @@ def main(argv=None):
     p.add_argument("--calls", type=int, default=1,
                    help="kernel calls chained in one timed program (the "
                         "time is per call)")
+    p.add_argument("--live", type=_int_list, default=[64, 32, 1],
+                   help="ring_append sweep: counts of rows that decode "
+                        "(of --slots rows of --kv-heads x --head-dim x "
+                        "--seq rings)")
     p.add_argument("--shapes", default=",".join(GROUPED_SHAPES),
                    help="grouped_matmul sweep: which of "
                         f"{', '.join(GROUPED_SHAPES)}")
@@ -473,6 +549,16 @@ def main(argv=None):
                 entries.update(sweep_grouped_matmul(
                     pairs, terms, experts, layers, k, n, down=down,
                     out_dtype="bfloat16" if bf16_out else "float32",
+                    calls=args.calls, trials=args.trials,
+                    warmup=args.warmup))
+    if args.kernel == "ring_append":
+        # (not under "all": it has no block to choose, only a time)
+        for slots in args.slots:
+            for hd in head_dims:
+                entries.update(sweep_ring_append(
+                    slots, args.kv_heads or args.heads, hd, args.seq,
+                    dtype=args.dtype,
+                    live=[n for n in args.live if n <= slots],
                     calls=args.calls, trials=args.trials,
                     warmup=args.warmup))
     device = jax.devices()[0].device_kind if on_tpu() else "cpu-interpret"

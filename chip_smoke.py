@@ -817,9 +817,10 @@ def phase_phi4flash(n_layers, num_slots, max_len, page_len, n_requests,
     weights and a bf16 K/V cache): the decode program holds
     the paged kernel at ten cached heads of 128 (two halves of 64
     stacked, four query rows a head) for the full layer and for the
-    cross layer that reads the same pages, and the contiguous decode
-    kernel on the window layers' rings; the pool — pages, rings, states
-    — stays where it is; and what the rings cannot serve is refused."""
+    cross layer that reads the same pages, and on the window layers'
+    rings the write (``%ring_append``) and the contiguous decode kernel;
+    the pool — pages, rings, states — stays where it is; and what the
+    rings cannot serve is refused."""
     import numpy as np
     import jax
     import jax.numpy as jnp
@@ -873,11 +874,15 @@ def phase_phi4flash(n_layers, num_slots, max_len, page_len, n_requests,
     _mosaic(ring, "serve phi4flash ring kernel")
     _check(ring["key"].endswith(f"_d128_s{published['sliding_window']}"),
            f"serve phi4flash: the ring kernel ran at {ring['key']}")
+    write, = tuning.last_dispatch("ring_append").values()
+    _mosaic(write, "serve phi4flash ring write")
     decode = get_program_registry().get("serving/paged_decode")
     args, kwargs = decode._last_avals
     hlo = decode.lower(*args, **kwargs).compile().as_text()
     kinds = {k: len(family.layers_of(sizes, k))
              for k in ("window_attn", "shared_attn", "cross_attn")}
+    # a window layer's write is a call of its own, under its own name
+    kinds["ring_append"] = kinds["window_attn"]
     for kind, n in kinds.items():
         calls = sum(1 for line in hlo.splitlines()
                     if f"%{kind}" in line.split(" = ")[0]

@@ -1584,33 +1584,13 @@ class GatedMemoryUnit(nn.Module):
 def _ring_append(rings, cols, lane, live):
     """Row ``b``'s columns ``cols[i][b]`` (``[h, d, 1]``) written onto
     lane ``lane[b]`` of ``rings[i][b]`` (``[h, d, window]``) in place, for
-    the rows that are ``live``: one trip a live row for all the rings
-    (a layer's keys and values), which reads the 128 lanes around the
-    token, replaces one and writes them back where they were
-    (``inference/cache.py _append_rows`` has why no scatter over the lane
-    dimension)."""
-    b, h, d, w = rings[0].shape
-    tile = min(128, w)
-    if w % tile:
-        raise ValueError(f"a ring of {w} tokens is no multiple of {tile}")
-    first = jnp.argsort(~live, stable=True)
-    lanes = jax.lax.broadcasted_iota(jnp.int32, (1, 1, 1, tile), 3)
-
-    def append(i, out):
-        row = first[i]
-        start = lane[row] // tile * tile
-        at = (row, 0, 0, start)
-
-        def one(ring, col):
-            part = jax.lax.dynamic_slice(ring, at, (1, h, d, tile))
-            new = jax.lax.dynamic_slice_in_dim(col, row, 1, axis=0)
-            part = jnp.where(lanes == lane[row] - start,
-                             new.astype(ring.dtype), part)
-            return jax.lax.dynamic_update_slice(ring, part, at)
-        return tuple(map(one, out, cols))
-
-    return jax.lax.fori_loop(0, jnp.sum(live, dtype=jnp.int32), append,
-                             tuple(rings))
+    the rows that are ``live``: one Pallas call for a layer's keys and
+    values (``ops/pallas/ring_append.py``), which reads the 128 lanes
+    around a live row's token, replaces one and writes them back where
+    they were under the next row's read (``inference/cache.py
+    _append_rows`` has why no scatter over the lane dimension)."""
+    from ..ops.pallas.ring_append import ring_append
+    return ring_append(rings, cols, lane, live)
 
 
 class DifferentialAttention(nn.Module):

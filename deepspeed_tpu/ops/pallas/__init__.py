@@ -6,6 +6,7 @@
 | inference softmax_context (KV cache)    | decode_attention         |
 | (no reference analog: paged serving)    | paged_attention          |
 | (no reference analog: latent pages)     | latent_attention         |
+| (no reference analog: a window's ring)  | ring_append.ring_append  |
 | adam/multi_tensor_adam.cu               | fused_adam.fused_adamw   |
 | lamb/fused_lamb_cuda.cpp (trust ratios) | fused_lamb.fused_lamb    |
 | transformer/normalize_kernels.cu        | layernorm.fused_layer_norm |

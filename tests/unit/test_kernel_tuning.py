@@ -375,6 +375,23 @@ class TestSweepHarness:
         assert all(a["max_diff"] <= (1e-5 if out == "float32" else 0.02)
                    for a in arms[1:])
 
+    def test_ring_append_sweep_times_the_rows_that_decode(self):
+        """The ring's write alone: one entry at the rings' shape, a time a
+        count of live rows, each count's first call held to the numpy
+        reference inside the sweep."""
+        from benchmarks.kernel_tuning import sweep_ring_append
+        said = []
+        (key, entry), = sweep_ring_append(
+            4, 2, 8, 256, dtype="bfloat16", live=(4, 1), calls=2, trials=1,
+            log=said.append).items()
+        assert key == "b4_h2_d8_w256_bfloat16"
+        assert entry["tile"] == 128 and entry["bytes_us_a_row"] > 0
+        assert [e["live"] for e in entry["swept"]] == [4, 1]
+        assert all(e["us_a_row"] * e["live"] == pytest.approx(e["us"], 0.01)
+                   for e in entry["swept"])
+        assert len(said) == 2 and "4 live rows" in said[0]
+        assert tuning.last_dispatch("ring_append")["tile"]["key"] == key
+
     @pytest.mark.slow  # fresh-interpreter subprocess (~40s); the sweep
     # plumbing itself is covered in-process above
     def test_bench_cli_kernels_subcommand(self, tmp_path):

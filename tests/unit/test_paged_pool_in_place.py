@@ -773,13 +773,15 @@ def test_phi4flash_decode_program_keeps_pages_rings_and_states_in_place(
     eight layers' kinds at published widths (mixers, two window layers,
     the full layer, a memory unit, a cross layer) compiled for the chip
     holds the paged kernel twice — the full layer's call and the cross
-    layer's, over the same pages — and the contiguous decode kernel once
-    a ring; it aliases the whole pool and moves none of it: a copy of
-    one layer's rings would be 84 MB of scratch, of its pages 335 MB."""
+    layer's, over the same pages — and, once a window layer, the ring's
+    write (``%ring_append``, both rings aliased through it) and the
+    contiguous decode kernel that reads them; it aliases the whole pool
+    and moves none of it: a copy of one layer's rings would be 84 MB of
+    scratch, of its pages 335 MB."""
     from deepspeed_tpu.inference.cache import has_ring_units, state_bytes
     from deepspeed_tpu.models.phi4flash import Phi4Flash, Phi4FlashConfig
     from deepspeed_tpu.ops.pallas import tuning
-    for name in ("paged_attention", "decode_attention"):
+    for name in ("paged_attention", "decode_attention", "ring_append"):
         monkeypatch.setattr(
             importlib.import_module(f"deepspeed_tpu.ops.pallas.{name}"),
             "_interpret", lambda: False)
@@ -828,12 +830,16 @@ def test_phi4flash_decode_program_keeps_pages_rings_and_states_in_place(
     ring, = tuning.last_dispatch("decode_attention").values()
     assert (ring["impl"], ring["key"], ring["head_block"]) \
         == ("kernel", "b64_h40_d128_s512", 2)
+    write, = tuning.last_dispatch("ring_append").values()
+    assert (write["impl"], write["key"], write["tile"]) \
+        == ("kernel", "b64_h10_d128_w512_bfloat16", 128)
 
     hlo = compiled.as_text()
     calls = [line.split(" = ")[0].strip() for line in hlo.splitlines()
              if 'custom_call_target="tpu_custom_call"' in line]
     assert sorted(re.sub(r"\.\d+$", "", c) for c in calls) == [
-        "%cross_attn", "%shared_attn", "%window_attn", "%window_attn"]
+        "%cross_attn", "%ring_append", "%ring_append", "%shared_attn",
+        "%window_attn", "%window_attn"]
     pool_bytes = sum(x.size * x.dtype.itemsize
                      for x in jax.tree.leaves(pool_shapes))
     mem = compiled.memory_analysis()
