@@ -150,19 +150,61 @@ def sweep_flash_attention(batch, heads, sq, sk, head_dim, dtype="bfloat16",
     return entries
 
 
+def _head_block_candidates(kv_heads, group, dtype):
+    """The K/V heads a grid step of the decode kernels may take, most
+    first: what ``step_head_block`` makes of each size it can answer for
+    this cache's type and ``group`` query heads a K/V head (5 and 10 only
+    of a bf16 cache whose heads they divide)."""
+    from deepspeed_tpu.ops.pallas._common import NARROW_HEAD_BLOCKS
+    from deepspeed_tpu.ops.pallas.paged_attention import step_head_block
+    return sorted({step_head_block(kv_heads, group, dtype, h)
+                   for h in NARROW_HEAD_BLOCKS}, reverse=True)
+
+
 def _paged_candidates(kv_heads, group, dtype, page_len, max_pages,
                       max_candidates=None):
     """(block_k tokens, head_block) candidates for the paged decode
     kernel: page_len multiples up to the table width (the DMA block the
-    kernel double-buffers) crossed with the K/V heads a grid step may
-    take — what the dispatcher makes of 16, 8, 4, 2 and 1 for this pool's
-    type and ``group`` query heads a K/V head."""
-    from deepspeed_tpu.ops.pallas.paged_attention import step_head_block
+    kernel double-buffers) crossed with ``_head_block_candidates``."""
     bks = [page_len * n for n in (1, 2, 4, 8) if n <= max_pages]
-    hbs = sorted({step_head_block(kv_heads, group, dtype, h)
-                  for h in (16, 8, 4, 2, 1)}, reverse=True)
-    cands = [(bk, hb) for bk in bks for hb in hbs]
+    cands = [(bk, hb) for bk in bks
+             for hb in _head_block_candidates(kv_heads, group, dtype)]
     return cands[:max_candidates] if max_candidates else cands
+
+
+def _sweep_decode_blocks(kernel, fn, args, key, cands, group, least, calls,
+                         trials, warmup, log):
+    """Time ``fn(*args)`` (``calls`` chained calls of a decode kernel)
+    under each (block_k, head_block) of ``cands`` installed at ``key``;
+    the winner's entry with ``bytes_us`` and every candidate under
+    ``swept``: its rows a grid step, its time a call, and whether its
+    result is, bit for bit, the first head block's at its ``block_k``
+    (a row's arithmetic does not depend on which heads share its step)."""
+    import numpy as np
+    import jax
+    from deepspeed_tpu.ops.pallas import tuning
+    swept, first = [], {}
+    for bk, hb in cands:
+        entry = {"block_k": bk, "head_block": hb}
+        with tuning.tuning_table({key: entry}):
+            jax.clear_caches()   # force a re-trace with the candidate
+            try:
+                out = np.asarray(fn(*args), np.float32)
+                ms = _time_it(fn, args, trials, warmup) / calls
+            except Exception as e:  # infeasible tiling = skip, not fail
+                log(f"  bk={bk} hb={hb}: infeasible ({str(e)[:300]})")
+                continue
+        same = np.array_equal(first.setdefault(bk, out), out)
+        log(f"  bk={bk} hb={hb} ({hb * group} rows a step): {ms:.4f} ms, "
+            f"{100 * least / (ms * 1e3):.1f}% of the bytes' time"
+            f"{'' if same else '; BITS DIFFER from the first head block'}")
+        swept.append({**entry, "rows": hb * group, "ms": round(ms, 5),
+                      "same_bits": bool(same)})
+    jax.clear_caches()
+    if not swept:
+        raise RuntimeError(f"no feasible {kernel} candidate")
+    return {key: {**min(swept, key=lambda e: e["ms"]),
+                  "bytes_us": round(least, 2), "swept": swept}}
 
 
 def sweep_paged_attention(slots, heads, head_dim, page_len, max_pages,
@@ -173,7 +215,9 @@ def sweep_paged_attention(slots, heads, head_dim, page_len, max_pages,
     decode-attention kernel at one (slots x pages x head-dim) serving
     shape; returns {key: entry} in the shared tuning-artifact format
     (``block_k`` in TOKENS — pages_per_block = block_k / page_len), the
-    winner's entry carrying every candidate's time under ``swept``.
+    winner's entry carrying every candidate's time under ``swept`` and
+    whether its result (all ``calls`` chained) is, bit for bit, the first
+    head block's at its ``block_k`` (``same_bits``).
 
     ``lengths``: the pooled tokens of the rows that decode, one number a
     row; the rows it does not name are at length 0, as the server hands
@@ -245,25 +289,11 @@ def sweep_paged_attention(slots, heads, head_dim, page_len, max_pages,
         f"{' int8' if kv_int8 else ''}: key {key}; the valid bytes' time "
         f"{least:.1f} us")
 
-    swept = []
-    for bk, hb in _paged_candidates(kv_heads, group, kp.dtype, page_len,
-                                    max_pages, max_candidates):
-        entry = {"block_k": bk, "head_block": hb}
-        with tuning.tuning_table({key: entry}):
-            jax.clear_caches()   # force a re-trace with the candidate
-            try:
-                ms = _time_it(fn, args, trials, warmup) / calls
-            except Exception as e:  # infeasible tiling = skip, not fail
-                log(f"  bk={bk} hb={hb}: infeasible ({e})")
-                continue
-        log(f"  bk={bk} hb={hb} ({hb * group} rows a step): {ms:.4f} ms, "
-            f"{100 * least / (ms * 1e3):.1f}% of the bytes' time")
-        swept.append({**entry, "rows": hb * group, "ms": round(ms, 5)})
-    jax.clear_caches()
-    if not swept:
-        raise RuntimeError("no feasible paged_attention candidate")
-    return {key: {**min(swept, key=lambda e: e["ms"]),
-                  "bytes_us": round(least, 2), "swept": swept}}
+    return _sweep_decode_blocks(
+        KERNEL, fn, args, key,
+        _paged_candidates(kv_heads, group, kp.dtype, page_len, max_pages,
+                          max_candidates),
+        group, least, calls, trials, warmup, log)
 
 
 # The grouped expert matmuls of the three expert cells (benchmarks/chip/
@@ -457,6 +487,62 @@ def sweep_ring_append(rows=64, heads=10, head_dim=128, window=512,
                   "swept": swept}}
 
 
+def sweep_decode_attention(slots, heads, head_dim, seq, dtype="bfloat16",
+                           lengths=None, calls=1, trials=3, warmup=1,
+                           max_candidates=None, kv_heads=None, log=print):
+    """Time candidate (block_k, head_block) tilings of the contiguous
+    decode kernel (``ops/pallas/decode_attention.py``) over caches
+    ``[slots, kv_heads, head_dim, seq]`` — Phi-4-mini-flash's window rings
+    are ``[64, 10, 128, 512]`` at lengths 512 — with ``sweep_paged_
+    attention``'s conventions: ``lengths`` the valid tokens of the rows
+    that decode (the rows not named at 0; None = every row full),
+    ``calls`` calls chained in one program (each call's output is the
+    next one's query), the time per call, ``bytes_us`` what the valid K
+    and V columns take at the HBM's peak, ``same_bits`` whether a
+    candidate's result is the first head block's at its ``block_k``.
+    Returns {key: entry} in the tuning artifact's format, every candidate
+    under ``swept``."""
+    import jax
+    import jax.numpy as jnp
+    from deepspeed_tpu.ops.pallas import decode_attention, tuning
+    from deepspeed_tpu.ops.pallas.decode_attention import KERNEL
+
+    kv_heads = kv_heads or heads
+    group = heads // kv_heads
+    if lengths is None:
+        lengths = [seq] * slots
+    if len(lengths) > slots or max(lengths) > seq:
+        raise ValueError(f"lengths {lengths}: at most {slots} rows of at "
+                         f"most {seq} tokens")
+    dt = jnp.dtype(dtype)
+    ks = jax.random.split(jax.random.PRNGKey(0), 3)
+    k = jax.random.normal(ks[0], (slots, kv_heads, head_dim, seq), dt)
+    v = jax.random.normal(ks[1], (slots, kv_heads, head_dim, seq), dt)
+    q = jax.random.normal(ks[2], (slots, 1, heads, head_dim), dt)
+    lengths = jnp.asarray(list(lengths) + [0] * (slots - len(lengths)),
+                          jnp.int32)
+
+    def chain(q, *rest):
+        return jax.lax.fori_loop(0, calls, lambda _, q: decode_attention(
+            q, *rest).astype(q.dtype), q)
+
+    fn = jax.jit(chain)
+    args = (q, k, v, lengths)
+    tuning.clear_last_dispatch()
+    jax.block_until_ready(fn(*args))
+    key = tuning.last_dispatch(KERNEL)["dma"]["key"]
+    least = (2 * int(lengths.sum()) * kv_heads * head_dim * dt.itemsize
+             / HBM_BYTES_PER_S * 1e6)
+    log(f"decode_attention slots{slots} h{heads} on {kv_heads} d{head_dim} "
+        f"s{seq} {dt.name}: key {key}; the valid bytes' time {least:.1f} us")
+    cands = [(bk, hb) for bk in _divisor_candidates(seq)[::-1]
+             for hb in _head_block_candidates(kv_heads, group, dt)]
+    return _sweep_decode_blocks(
+        KERNEL, fn, args, key,
+        cands[:max_candidates] if max_candidates else cands, group, least,
+        calls, trials, warmup, log)
+
+
 def _int_list(text):
     return [int(x) for x in str(text).split(",") if x]
 
@@ -484,7 +570,7 @@ def main(argv=None):
     p.add_argument("--kernel", choices=["flash_attention",
                                         "paged_attention",
                                         "grouped_matmul", "ring_append",
-                                        "all"],
+                                        "decode_attention", "all"],
                    default="flash_attention",
                    help="which kernel family to sweep; paged_attention "
                         "sweeps the serving decode kernel over the "
@@ -551,6 +637,16 @@ def main(argv=None):
                     out_dtype="bfloat16" if bf16_out else "float32",
                     calls=args.calls, trials=args.trials,
                     warmup=args.warmup))
+    if args.kernel == "decode_attention":
+        # (not under "all": one serving shape has rings, and names it)
+        for slots in args.slots:
+            for hd in head_dims:
+                entries.update(sweep_decode_attention(
+                    slots, args.heads, hd, args.seq, dtype=args.dtype,
+                    lengths=args.lengths, calls=args.calls,
+                    trials=args.trials, warmup=args.warmup,
+                    max_candidates=args.max_candidates,
+                    kv_heads=args.kv_heads))
     if args.kernel == "ring_append":
         # (not under "all": it has no block to choose, only a time)
         for slots in args.slots:

@@ -865,15 +865,35 @@ def phase_phi4flash(n_layers, num_slots, max_len, page_len, n_requests,
             _say(f"serve phi4flash: {option} refused: {e}")
         else:
             _check(False, f"serve phi4flash: {option} was not refused")
-    _serve_and_check(eng, model, params, reqs, num_slots, max_len, page_len,
-                     paging_kernel, logit_tol, "serve phi4flash",
-                     against_generate=False,
-                     reference_logits=reference_logits,
-                     paging={"enable_prefix_cache": False})
+    # the two decode kernels at the blocks the cell's shapes take from the
+    # committed table (64 slots there: five or ten cached heads a grid
+    # step since PR 58), under this phase's keys
+    window = published["sliding_window"]
+    table = tuning.load_artifact(tuning.DEFAULTS_PATH)["entries"]
+    blocks = {}
+    for kernel, structure, cell_sk, sk in (
+            ("paged_attention", f"page{page_len}", 4096, max_len),
+            ("decode_attention", "dma", window, window)):
+        cell, here = (tuning.make_key(
+            kernel, structure, sq=sq, sk=tokens, d=128, dtype=jnp.bfloat16,
+            causal=True) for sq, tokens in ((64, cell_sk), (num_slots, sk)))
+        blocks[here] = {k: table[cell][k] for k in ("block_k", "head_block")}
+    with tuning.tuning_table(blocks):
+        _serve_and_check(eng, model, params, reqs, num_slots, max_len,
+                         page_len, paging_kernel, logit_tol,
+                         "serve phi4flash", against_generate=False,
+                         reference_logits=reference_logits,
+                         paging={"enable_prefix_cache": False})
     ring, = tuning.last_dispatch("decode_attention").values()
     _mosaic(ring, "serve phi4flash ring kernel")
-    _check(ring["key"].endswith(f"_d128_s{published['sliding_window']}"),
-           f"serve phi4flash: the ring kernel ran at {ring['key']}")
+    paged = tuning.last_dispatch("paged_attention")[f"page{page_len}"]
+    for name, rec in (("ring", ring), ("paged", paged)):
+        want = blocks[rec["key"]]
+        _check((rec["source"], rec["block_k"], rec["head_block"],
+                rec["rows"]) == ("runtime", want["block_k"],
+                                 want["head_block"], 4 * want["head_block"]),
+               f"serve phi4flash: the {name} kernel ran at {rec}, not at "
+               f"{want} with four query rows a cached head")
     write, = tuning.last_dispatch("ring_append").values()
     _mosaic(write, "serve phi4flash ring write")
     decode = get_program_registry().get("serving/paged_decode")
@@ -1046,14 +1066,15 @@ def _kernel_checks(seq, heads, batch, cache_len, gemv_k, gemv_n, sparse_seq,
 
     # the decode kernels' head block (_common.pick_head_block) is 8 for 16
     # heads (16 where a bf16 paged pool's caller or table entry asks for
-    # it: PR 51), 4 for GPT-2's 12, and 2 / 1 for the 6 / 3 heads one
-    # device holds at mp_size 2 / 4: each size is its own Mosaic tiling,
-    # and a refusal there is a SIGABRT
-    def decode(heads, head_block, ask=None):
+    # it: PR 51), 4 for GPT-2's 12, 2 / 1 for the 6 / 3 heads one device
+    # holds at mp_size 2 / 4, and 10 or 5 of ten bf16 heads where asked
+    # (PR 58): each size is its own Mosaic tiling, and a refusal there is
+    # a SIGABRT
+    def decode(heads, head_block, ask=None, kv_heads=None, d=64):
         def run():
-            d = 64
+            kv = kv_heads or heads
             q = normal((batch * 2, 1, heads, d))
-            k, v = (normal((batch * 2, heads, d, cache_len))
+            k, v = (normal((batch * 2, kv, d, cache_len))
                     for _ in range(2))
             lengths = jnp.asarray(
                 rng.integers(1, cache_len, size=batch * 2), jnp.int32)
@@ -1073,12 +1094,15 @@ def _kernel_checks(seq, heads, batch, cache_len, gemv_k, gemv_n, sparse_seq,
             _check(rec["head_block"] == head_block,
                    f"{heads} heads ran at head block {rec['head_block']}, "
                    f"not {head_block}")
+            _check(rec["rows"] == head_block * heads // kv,
+                   f"a grid step of {rec['rows']} query-head rows, not "
+                   f"{head_block * heads // kv}")
         return run
 
     def paged(heads, head_block, int8, dtype=jnp.bfloat16, kv_heads=None,
-              ask=None):
+              ask=None, d=64):
         def run():
-            d, page_len, slots, max_pages = 64, 128, 4, cache_len // 128
+            page_len, slots, max_pages = 128, 4, cache_len // 128
             num_pages = slots * max_pages + 1
             kv = kv_heads or heads
             q = normal((slots, 1, heads, d), dtype)
@@ -1295,6 +1319,15 @@ def _kernel_checks(seq, heads, batch, cache_len, gemv_k, gemv_n, sparse_seq,
          decode(16, 16, ask=16)),
         ("decode_attention 6 heads (head block 2)", decode(6, 2)),
         ("decode_attention 3 heads (head block 1)", decode(3, 1)),
+        # ten cached heads of 128 with four query rows each over a bf16
+        # cache (Phi-4-mini-flash, PR 58): ten or five a grid step where
+        # asked, 40 or 20 rows; two, the constant's answer, otherwise
+        ("decode_attention 40 heads on 10 (head block 10, asked for)",
+         decode(40, 10, ask=10, kv_heads=10, d=128)),
+        ("decode_attention 40 heads on 10 (head block 5, asked for)",
+         decode(40, 5, ask=5, kv_heads=10, d=128)),
+        ("decode_attention 40 heads on 10 (head block 2)",
+         decode(40, 2, kv_heads=10, d=128)),
         (f"paged_attention bf16 pages {heads} heads",
          paged(heads, 4, False)),
         ("paged_attention bf16 pages 16 heads (head block 8)",
@@ -1313,6 +1346,13 @@ def _kernel_checks(seq, heads, batch, cache_len, gemv_k, gemv_n, sparse_seq,
         # K/V heads and their twenty query heads
         ("paged_attention bf16 pages 20 heads on 4 (head block 4, 20 rows)",
          paged(20, 4, False, kv_heads=4)),
+        ("paged_attention bf16 pages 40 heads on 10 (head block 10, asked "
+         "for, 40 rows)", paged(40, 10, False, kv_heads=10, ask=10, d=128)),
+        ("paged_attention bf16 pages 40 heads on 10 (head block 5, asked "
+         "for, 20 rows)", paged(40, 5, False, kv_heads=10, ask=5, d=128)),
+        ("paged_attention float32 pages 40 heads on 10 (asked for 10: head "
+         "block 2)", paged(40, 2, False, jnp.float32, kv_heads=10, ask=10,
+                          d=128)),
         (f"paged_attention int8 pages {heads} heads", paged(heads, 4, True)),
         ("paged_attention int8 pages 3 heads (head block 1)",
          paged(3, 1, True)),

@@ -73,27 +73,49 @@ def pick_block(dim: int, want: int) -> int:
     return b if b % 128 == 0 or b == dim else dim
 
 
-def pick_head_block(heads: int, want: int) -> int:
+# the head blocks that run compiled on the v5e: the powers of two on both
+# arms of ``online_softmax_block`` (1 to 8 since PR 21, 16 since PR 51),
+# five and ten on the narrow arm alone (PR 58)
+HEAD_BLOCKS = (16, 8, 4, 2, 1)
+NARROW_HEAD_BLOCKS = (16, 10, 8, 5, 4, 2, 1)
+
+
+def pick_head_block(heads: int, want: int, narrow: bool = False) -> int:
     """Heads per grid step for the decode kernels: the largest of 1, 2, 4,
-    8 and 16 that divides ``heads`` and ``want``. The first four run
-    compiled on the v5e since PR 21 and ``chip_smoke.py``'s kernels phase
-    keeps them covered: GPT-2's 12 heads get 4, and 2 / 1 under
-    ``mp_size`` 2 / 4. Sixteen runs there since PR 51, over a bf16 pool
-    (the paged kernel's sweep and the three sixteen-head serving cells),
-    and is answered only to a caller that asks for it: at a ``want`` of 8
-    every answer is what it was. Mosaic (jax 0.9.0) aborted the process
-    at 12 (PR 21, on the chip, ``limits[i] <= dim(i)``). What is known
-    since PR 49, from compiles for a described v5e: that check is
-    ``online_softmax_block``'s float32 arm cutting one row of its boolean
-    mask at a row past the eighth — a float32 pool at a head block of 12
-    or 16 still aborts on it (``paged_attention.step_head_block`` caps
-    its ``want`` at eight rows before it asks here), a bf16 pool (whose
-    arm never cuts the mask) compiles at both. Whether PR 21's kernel,
-    which had one arm, died of that same cut was not gone back to;
-    twelve has not run on the chip and no cell holds twelve heads a
-    device, so it is not chosen."""
+    8 and 16 — and, where the cache's products are ``narrow``
+    (``products_dtype``: a bf16 cache), of 5 and 10 — that divides
+    ``heads`` and ``want``. The first four run compiled on the v5e since
+    PR 21 and ``chip_smoke.py``'s kernels phase keeps them covered: GPT-2's
+    12 heads get 4, and 2 / 1 under ``mp_size`` 2 / 4. Sixteen runs there
+    since PR 51, over a bf16 pool (the paged kernel's sweep and the three
+    sixteen-head serving cells), and is answered only to a caller that
+    asks for it: at a ``want`` of 8 every answer is what it was.
+
+    Five and ten run there since PR 58, over a bf16 pool and bf16 rings of
+    ten cached heads with four query rows each (Phi-4-mini-flash: 20 and
+    40 rows a step in both kernels' sweeps, 40 in ``serve-phi4flash-
+    reason`` and the smoke's phases). They divide no constant, so they are
+    answered only to a table entry or a caller that asks: a ten-head bf16
+    cache gets 10 at a ``want`` of 10, 5 at 5 — or at 10 on the five heads
+    a device holds under ``mp_size`` 2 — and 2 at 8 or 16 as before. A
+    float32 or int8 cache of ten heads gets 2 whatever is asked: neither
+    size was compiled on that arm.
+
+    Mosaic (jax 0.9.0) aborted the process at 12 (PR 21, on the chip,
+    ``limits[i] <= dim(i)``). What is known since PR 49, from compiles for
+    a described v5e: that check is ``online_softmax_block``'s float32 arm
+    cutting one row of its boolean mask at a row past the eighth — a
+    float32 pool at a head block of 12 or 16 still aborts on it
+    (``paged_attention.step_head_block`` caps its ``want`` at eight rows
+    before it asks here), a bf16 pool (whose arm never cuts the mask)
+    compiles at both. Whether PR 21's kernel, which had one arm, died of
+    that same cut was not gone back to; twelve has not run on the chip and
+    no cell holds twelve heads a device, so it is not chosen on either
+    arm."""
     import math
-    return math.gcd(math.gcd(heads, want), 16)
+    both = math.gcd(heads, want)
+    return next(b for b in (NARROW_HEAD_BLOCKS if narrow else HEAD_BLOCKS)
+                if both % b == 0)
 
 
 def read_slopes(slopes_ref, h0: int, hb: int):

@@ -30,6 +30,21 @@ need a 128-aligned minor dim):
 - caches whose max_len is not a multiple of 128 take a fused-dense jnp
   fallback (kernel semantics, XLA codegen) — the generation path rounds
   its cache allocation up to 128 so serving always hits the kernel.
+- grouped query heads and the blocks, as the paged kernel has them: the
+  grid walks the cache's K/V heads and a step holds ``head_block`` of
+  them with every query head that reads them, one row each (``rows`` in
+  the dispatch record); ``block_k`` and ``head_block`` resolve through
+  the shape-keyed tuning table (``tuning.lookup``: key
+  ``decode_attention/dma/sq<batch>_sk<max_len>_d<d>_<dtype>_causal``),
+  then the caller's, then ``DEFAULT_BLOCK_K`` and ``DEFAULT_HEAD_BLOCK``,
+  and the head block goes through ``paged_attention.step_head_block``.
+  One shape has an entry: Phi-4-mini-flash's window rings ``[64, 10,
+  128, 512]`` bf16 take all ten cached heads a step (40 rows) in blocks
+  of 128 tokens, swept and run compiled on the v5e in PR 58
+  (``bin/ds_tpu_bench kernels --kernel decode_attention``); every other
+  caller — ``SelfAttention``'s dense-cache decode, ``generate()`` —
+  misses the table and lowers as it did, 512 tokens and eight heads
+  (two of ten, four of twelve).
 
 Inference-only: no custom_vjp (the reference kernel is fwd-only too).
 """
@@ -195,8 +210,8 @@ def _decode_dense(q_bhd, k, v, lengths, slopes, *, scale, alibi):
 
 
 def decode_attention(q, k, v, length, *, softmax_scale=None,
-                     alibi_slopes=None, block_k=DEFAULT_BLOCK_K,
-                     head_block=DEFAULT_HEAD_BLOCK, mesh=None):
+                     alibi_slopes=None, block_k=None, head_block=None,
+                     mesh=None):
     """Single-token KV-cache attention over transposed caches.
 
     q: [B, 1, H, d] (or [B, H, d]) — the current token's queries (BSHD).
@@ -208,6 +223,12 @@ def decode_attention(q, k, v, length, *, softmax_scale=None,
         (the query sits at position length-1). Rows with length <= 0
         (empty serving slots) return zeros.
     alibi_slopes: optional [H] per-head ALiBi slopes (BLOOM).
+    block_k, head_block: the tokens of one DMA block and the K/V heads of
+        one grid step, where the shape has no entry in the tuning table
+        (``tuning.lookup``, key ``decode_attention/dma/sq<B>_sk<S>_d<d>_
+        <dtype>_causal``: an entry wins, as in ``paged_attention``);
+        neither given, ``DEFAULT_BLOCK_K`` and ``DEFAULT_HEAD_BLOCK``.
+        What ``step_head_block`` makes of the head block is what runs.
     mesh: the caller's mesh when its ``model`` axis splits the heads
         (tensor-parallel serving): the kernel runs once per head shard.
         None = one unpartitioned call.
@@ -227,10 +248,16 @@ def decode_attention(q, k, v, length, *, softmax_scale=None,
                          "K/V heads: not a whole group each")
     scale = softmax_scale if softmax_scale is not None else 1.0 / math.sqrt(d)
     tp = model_axis_size(mesh, kv_heads)
-    # the paged kernel's rule (a float32 cache's step is held to eight
-    # rows, whatever is asked): the two share ``online_softmax_block``
-    hb = step_head_block(kv_heads // tp, heads // kv_heads, k.dtype,
-                         head_block)
+    group = heads // kv_heads
+    # blocks resolve as the paged kernel's do (entry, caller, constants)
+    # and the head block goes through its rule: the two kernels share
+    # ``online_softmax_block``
+    entry, key, source = tuning.lookup(KERNEL, "dma", sq=b, sk=s, d=d,
+                                       dtype=k.dtype, causal=True)
+    block_k = int(entry.get("block_k") or block_k or DEFAULT_BLOCK_K)
+    hb = step_head_block(
+        kv_heads // tp, group, k.dtype,
+        int(entry.get("head_block") or head_block or DEFAULT_HEAD_BLOCK))
 
     lengths = jnp.broadcast_to(jnp.asarray(length, jnp.int32), (b,))
     alibi = alibi_slopes is not None
@@ -254,8 +281,8 @@ def decode_attention(q, k, v, length, *, softmax_scale=None,
         log_fallback_on_tpu(KERNEL, "dense", reason)
         run = functools.partial(_decode_dense, scale=scale, alibi=alibi)
     tuning.record_dispatch(
-        KERNEL, "dma", f"b{b}_h{heads}_d{d}_s{s}", None, block_k=bk,
-        head_block=hb, impl="kernel" if use_kernel else "dense",
+        KERNEL, "dma", key, source, block_k=bk, head_block=hb,
+        rows=hb * group, impl="kernel" if use_kernel else "dense",
         reason=reason, model_shards=tp,
         products=(products_dtype(k.dtype).name if use_kernel
                   else "float32"))

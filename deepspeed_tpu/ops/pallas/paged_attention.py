@@ -28,14 +28,20 @@ row each (``rows`` in the dispatch record). A step's cost is mostly
 fixed — its first block's fetch, which nothing hides, and the current
 token's fold — so a step takes as many K/V heads as the shape's entry of
 the tuning table asks for and ``step_head_block`` allows: all four of
-Falcon-H1's (20 rows, grid ``(slots, 1)``) and all sixteen of the
+Falcon-H1's (20 rows, grid ``(slots, 1)``), all sixteen of the
 ungrouped GPT-2 1.3B and OLMoE pools (16 rows, grid ``(slots, 1)``: PR
-51, each DMA block then one page of 128 tokens) where the pool is bf16,
-eight rows' worth where the products are float32 (LFM2's float32 pool:
-two K/V heads of a group of four), eight heads where nobody swept the
-shape (``DEFAULT_HEAD_BLOCK``). A row's arithmetic does not depend on
+51, each DMA block then one page of 128 tokens) and all ten of
+Phi-4-mini-flash's cached heads with their four query rows each (40
+rows, grid ``(slots, 1)``, one page a DMA block: PR 58 — five and ten
+are answered on this arm alone, to an entry or a caller that asks for
+them; at the constants ten heads go two a step, as they did) where the
+pool is bf16, eight rows' worth where the products are float32 (LFM2's
+float32 pool: two K/V heads of a group of four; a float32 pool of ten
+heads two), eight heads where nobody swept the shape
+(``DEFAULT_HEAD_BLOCK``). A row's arithmetic does not depend on
 which heads share its step: at one ``block_k`` every head block gives
-the same bits.
+the same bits (on the chip too: 1, 2, 4 in PR 49, 1, 2, 5, 10 at four
+blocks in PR 58's sweep).
 
 A scanned model's pool is layer-stacked, ``[L, num_pages, h, d,
 page_len]``. It reaches this kernel whole — the model's layer scan
@@ -122,10 +128,14 @@ def step_head_block(kv_heads, group, pool_dtype, want):
     two K/V heads a step. The narrow arm never cuts that mask (its guard
     is a select on the block's bits): a bf16 pool's step takes every K/V
     head the head block allows, 20 rows at five query heads on each of
-    four, 16 at sixteen ungrouped heads where ``want`` is 16."""
-    if products_dtype(pool_dtype) == jnp.float32:
+    four, 16 at sixteen ungrouped heads where ``want`` is 16 — and, on
+    this arm alone, five or ten of ten heads where ``want`` is 5 or 10
+    (PR 58: 20 or 40 rows at a group of four; a float32 ten-head pool
+    keeps its 2)."""
+    narrow = products_dtype(pool_dtype) != jnp.float32
+    if not narrow:
         want = min(want, max(1, MAX_ROWS // group))
-    return pick_head_block(kv_heads, want)
+    return pick_head_block(kv_heads, want, narrow)
 
 
 def _fold_current_token(q, kn, vn, m_ref, l_ref, acc_ref):

@@ -269,7 +269,8 @@ class TestKernelTiling:
         # is not known. The chip has run 1, 2, 4 and 8 since PR 21 and
         # 16 over a bf16 pool since PR 51 (the paged kernel's sweep and
         # the sixteen-head serving cells); 16 is answered only to a
-        # caller that asks for it, and 12 to nobody
+        # caller that asks for it, and 12 to nobody (5 and 10, over a
+        # bf16 cache alone since PR 58: the next test)
         assert pick_head_block(12, 8) == 4     # GPT-2
         assert pick_head_block(16, 8) == 8
         assert pick_head_block(20, 8) == 4
@@ -281,6 +282,42 @@ class TestKernelTiling:
         assert pick_head_block(4, 8) == 4      # Falcon-H1's K/V heads
         assert pick_head_block(6, 8) == 2      # 12 heads at mp_size=2
         assert pick_head_block(3, 8) == 1      # ... and at mp_size=4
+
+    @pytest.mark.parametrize("heads,want,narrow,block", [
+        # ten cached heads (Phi-4-mini-flash's pool and rings, PR 58): ten
+        # or five a step to a bf16 cache whose entry or caller asks for
+        # them, two at every want that was answered before
+        (10, 10, True, 10), (10, 5, True, 5), (10, 20, True, 10),
+        (10, 8, True, 2), (10, 16, True, 2), (10, 4, True, 2),
+        (5, 10, True, 5), (5, 5, True, 5), (5, 8, True, 1),
+        (20, 10, True, 10), (20, 8, True, 4), (15, 10, True, 5),
+        # a want of five or ten changes nothing else: sixteen and four heads
+        (16, 10, True, 2), (4, 10, True, 2), (16, 16, True, 16),
+        # the float32 arm is never answered five or ten
+        (10, 10, False, 2), (10, 5, False, 1), (5, 5, False, 1),
+        (20, 10, False, 2),
+        # twelve stays unchosen on both arms
+        (12, 12, True, 4), (36, 12, True, 4), (24, 12, True, 4),
+        (60, 60, True, 10), (60, 60, False, 4)])
+    def test_five_and_ten_heads_a_step_on_the_narrow_arm_alone(
+            self, heads, want, narrow, block):
+        from deepspeed_tpu.ops.pallas._common import pick_head_block
+        assert pick_head_block(heads, want, narrow) == block
+        if want % 5:
+            # no answer that was given before PR 58 moved
+            assert block == pick_head_block(heads, want)
+
+    @pytest.mark.parametrize("dtype,group,want,block", [
+        ("bfloat16", 4, 10, 10), ("bfloat16", 4, 5, 5), ("bfloat16", 4, 8, 2),
+        ("bfloat16", 1, 10, 10), ("float32", 4, 10, 2), ("float32", 4, 5, 2),
+        ("float32", 1, 10, 2), ("int8", 4, 10, 2), ("int8", 1, 10, 2),
+        ("float16", 4, 10, 10)])
+    def test_a_ten_head_cache_by_its_type(self, dtype, group, want, block):
+        """``step_head_block``: only a cache whose products run narrower
+        than float32 is answered five or ten; a float32 or int8 pool of
+        ten heads keeps two a step whatever is asked."""
+        from deepspeed_tpu.ops.pallas.paged_attention import step_head_block
+        assert step_head_block(10, group, jnp.dtype(dtype), want) == block
 
     def test_decode_kernels_take_twelve_heads(self):
         """12 heads -> head block 4: the per-token operands ride 4-D so

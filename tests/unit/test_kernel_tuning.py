@@ -121,11 +121,13 @@ class TestCacheLayers:
         assert art["entries"], "committed default table must not be empty"
         for key, e in art["entries"].items():
             # a flash entry tiles the queries; a paged-decode entry (one
-            # query token a row) the pooled tokens of a DMA block; a
-            # grouped-matmul entry the rows of a visit
+            # query token a row) the pooled tokens of a DMA block, as a
+            # contiguous cache's does; a grouped-matmul entry the rows of
+            # a visit
             kernel = key.split("/")[0]
             block = {"flash_attention": "block_q",
                      "paged_attention": "block_k",
+                     "decode_attention": "block_k",
                      "grouped_matmul": "block_m"}[kernel]
             assert isinstance(e.get(block), int), (key, e)
 
@@ -349,6 +351,51 @@ class TestSweepHarness:
         # the last candidate's dispatch: one head a step
         rec = tuning.last_dispatch("paged_attention")["page16"]
         assert (rec["head_block"], rec["rows"]) == (1, 1)
+
+    @pytest.mark.parametrize("dtype,head_blocks", [
+        ("bfloat16", [10, 5, 2, 1]), ("float32", [2, 1]), ("int8", [2, 1])])
+    def test_sweeps_offer_ten_cached_heads_five_and_ten(self, dtype,
+                                                        head_blocks):
+        """Ten K/V heads under four query heads each (Phi-4-mini-flash):
+        a bf16 cache's candidates hold ten and five heads a grid step
+        beside two and one (PR 58), a float32 or int8 cache's two at
+        most."""
+        from benchmarks.kernel_tuning import (_head_block_candidates,
+                                              _paged_candidates)
+        assert _head_block_candidates(10, 4, jnp.dtype(dtype)) == head_blocks
+        assert _paged_candidates(10, 4, jnp.dtype(dtype), 128, 2) == [
+            (bk, hb) for bk in (128, 256) for hb in head_blocks]
+
+    def test_decode_attention_sweep_of_the_rings(self):
+        """The contiguous decode kernel alone at a ring's layout: every
+        (block_k, head block) the dispatcher allows, the entry keyed as
+        the dispatch looks it up, each candidate's rows, and at one
+        ``block_k`` every head block's result the first one's bit for
+        bit."""
+        from benchmarks.kernel_tuning import sweep_decode_attention
+        said = []
+        (key, entry), = sweep_decode_attention(
+            3, 40, 16, 256, dtype="bfloat16", kv_heads=10,
+            lengths=[256, 0, 77], calls=2, trials=1,
+            log=said.append).items()
+        assert key == "decode_attention/dma/sq3_sk256_d16_bfloat16_causal"
+        swept = entry["swept"]
+        assert [(e["block_k"], e["head_block"]) for e in swept] == [
+            (bk, hb) for bk in (128, 256) for hb in (10, 5, 2, 1)]
+        assert all(e["rows"] == 4 * e["head_block"] and e["same_bits"]
+                   for e in swept)
+        assert entry["ms"] == min(e["ms"] for e in swept)
+        # 333 valid columns of K and V, ten heads of 16 in bf16
+        assert entry["bytes_us"] == round(
+            2 * 333 * 10 * 16 * 2 / 819e9 * 1e6, 2)
+        assert not any("infeasible" in line or "DIFFER" in line
+                       for line in said)
+        rec = tuning.last_dispatch("decode_attention")["dma"]
+        assert (rec["key"], rec["source"], rec["head_block"]) == (
+            key, "runtime", 1)
+        with pytest.raises(ValueError, match="lengths"):
+            sweep_decode_attention(2, 4, 16, 256, lengths=[257], trials=1,
+                                   log=lambda *a: None)
 
     @pytest.mark.parametrize("down,out", [(False, "bfloat16"),
                                           (True, "float32")],

@@ -685,6 +685,205 @@ class TestProductsInThePoolsType:
                                    atol=1e-5, rtol=1e-5)
 
 
+class TestTenCachedHeads:
+    """Ten cached heads of 128 with four query rows each over a bf16
+    cache (Phi-4-mini-flash's one paged layer and its window rings): the
+    narrow arm's step takes five or ten of them where the table's entry
+    or the caller asks (PR 58), two otherwise, and at one ``block_k``
+    every head block gives head block 1's bits."""
+    KV, GROUP, D = 10, 4, 128
+    LENS = [2 * PAGE + 7, 0, 5 * PAGE - 1, PAGE]
+    TABLE = [[1, 4, 2, 0, 0], [0, 0, 0, 0, 0], [3, 5, 6, 7, 8],
+             [9, 0, 0, 0, 0]]
+
+    def _paged(self, seed=83):
+        r = np.random.RandomState(seed)
+        kp, vp = (jnp.asarray(r.randn(2, 11, self.KV, self.D, PAGE),
+                              jnp.bfloat16) for _ in range(2))
+        bad = jnp.asarray([0, 10])      # the null page and nobody's page
+        kp, vp = kp.at[:, bad].set(jnp.nan), vp.at[:, bad].set(jnp.nan)
+        q = _bf16_values(r, 4, 1, self.KV * self.GROUP, self.D)
+        kn, vn = (_bf16_values(r, 4, self.KV, self.D, 1) for _ in range(2))
+        return (q, kp, vp, jnp.asarray(self.TABLE, jnp.int32),
+                jnp.asarray(self.LENS, jnp.int32), kn, vn)
+
+    @pytest.mark.parametrize("blocks", [1, 2, 4])
+    @pytest.mark.parametrize("head_block", [2, 5, 10])
+    def test_paged_rows_keep_their_bits_at_every_head_block(self, head_block,
+                                                            blocks):
+        args = self._paged()
+        kw = dict(layer=jnp.int32(1), impl="kernel",
+                  block_tokens=blocks * PAGE)
+        tuning.clear_last_dispatch()
+        got = paged_attention(*args, head_block=head_block, **kw)
+        rec = tuning.last_dispatch(KERNEL)["page%d" % PAGE]
+        assert (rec["head_block"], rec["rows"], rec["products"]) == (
+            head_block, self.GROUP * head_block, "bfloat16")
+        one = paged_attention(*args, head_block=1, **kw)
+        assert np.isfinite(np.asarray(got)).all()
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(one))
+        dense = paged_attention(*args, layer=jnp.int32(1), impl="dense")
+        np.testing.assert_allclose(np.asarray(got), np.asarray(dense),
+                                   atol=1e-5, rtol=1e-5)
+        # the row of length zero attends its own token alone
+        own = np.repeat(np.asarray(args[6])[1, :, :, 0], self.GROUP, axis=0)
+        np.testing.assert_allclose(np.asarray(got)[1, 0], own, atol=1e-6)
+
+    @pytest.mark.parametrize("dtype,asked,ran", [
+        ("bf16", 10, 10), ("bf16", 5, 5), ("bf16", None, 2),
+        ("bf16", 16, 2), ("f32", 10, 2), ("f32", 5, 2), ("int8", 10, 2)])
+    def test_only_a_bf16_pool_is_answered_five_or_ten(self, dtype, asked,
+                                                      ran):
+        q, kp, vp, ptab, lens, kn, vn = self._paged()
+        scales = {}
+        if dtype != "bf16":
+            kp, vp = (jnp.nan_to_num(x.astype(jnp.float32)) for x in (kp, vp))
+        if dtype == "int8":
+            kp, vp, ks, vs = jax.vmap(_quantize_pool)(kp, vp)
+            scales = {"k_scale": ks, "v_scale": vs}
+        tuning.clear_last_dispatch()
+        got = paged_attention(q, kp, vp, ptab, lens, kn, vn, layer=1,
+                              impl="kernel", head_block=asked, **scales)
+        rec = tuning.last_dispatch(KERNEL)["page%d" % PAGE]
+        assert (rec["head_block"], rec["rows"]) == (ran, self.GROUP * ran)
+        assert rec["products"] == ("bfloat16" if dtype == "bf16"
+                                   else "float32")
+        dense = paged_attention(q, kp, vp, ptab, lens, kn, vn, layer=1,
+                                impl="dense", **scales)
+        np.testing.assert_allclose(np.asarray(got), np.asarray(dense),
+                                   atol=1e-4, rtol=1e-4)
+
+    def test_five_heads_a_device_over_the_model_axis(self):
+        """Ten cached heads over a model axis of two: a device holds five,
+        and a want of ten gives it all five in one step."""
+        from deepspeed_tpu.comm import MeshSpec, build_mesh
+        args = self._paged()
+        want = paged_attention(*args, layer=jnp.int32(1), impl="kernel",
+                               head_block=1)
+        mesh = build_mesh(MeshSpec(model=2, data=4))
+        tuning.clear_last_dispatch()
+        got = jax.jit(lambda i: paged_attention(
+            *args, layer=i, impl="kernel", head_block=10, mesh=mesh))(
+            jnp.int32(1))
+        rec = tuning.last_dispatch(KERNEL)["page%d" % PAGE]
+        assert (rec["head_block"], rec["rows"], rec["model_shards"]) == (
+            5, 20, 2)
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+    # the rings: ``[b, 10, 128, 512]``, every lane valid once a ring has
+    # wrapped (length 512), a prefix of them before
+    RING_LENS = [512, 77, 0, 300]
+
+    def _ring(self, seed=89):
+        r = np.random.RandomState(seed)
+        k, v = (jnp.asarray(r.randn(4, self.KV, self.D, 512), jnp.bfloat16)
+                for _ in range(2))
+        lens = jnp.asarray(self.RING_LENS, jnp.int32)
+        past = jnp.arange(512)[None, None, None, :] >= lens[:, None, None,
+                                                           None]
+        spoil = lambda x: jnp.where(past, jnp.asarray(jnp.nan, x.dtype), x)
+        q = _bf16_values(r, 4, 1, self.KV * self.GROUP, self.D)
+        return q, k, v, spoil(k), spoil(v), lens
+
+    @pytest.mark.parametrize("block_k", [128, 256, 512])
+    @pytest.mark.parametrize("head_block", [2, 5, 10])
+    def test_ring_rows_keep_their_bits_at_every_head_block(self, head_block,
+                                                           block_k):
+        from deepspeed_tpu.ops.pallas import decode_attention
+        from deepspeed_tpu.ops.pallas.decode_attention import _decode_dense
+        q, k, v, ks, vs, lens = self._ring()
+        tuning.clear_last_dispatch()
+        got = decode_attention(q, ks, vs, lens, block_k=block_k,
+                               head_block=head_block)
+        rec = tuning.last_dispatch("decode_attention")["dma"]
+        assert (rec["impl"], rec["block_k"], rec["head_block"], rec["rows"],
+                rec["products"], rec["source"]) == (
+            "kernel", block_k, head_block, self.GROUP * head_block,
+            "bfloat16", "constants")
+        one = decode_attention(q, ks, vs, lens, block_k=block_k,
+                               head_block=1)
+        assert np.isfinite(np.asarray(got)).all()
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(one))
+        want = _decode_dense(q[:, 0], k, v, lens,
+                             jnp.zeros((self.KV * self.GROUP,)),
+                             scale=self.D ** -0.5, alibi=False)
+        np.testing.assert_allclose(np.asarray(got[:, 0]), np.asarray(want),
+                                   atol=1e-5, rtol=1e-5)
+        assert not np.asarray(got[2]).any()         # the empty slot: zeros
+
+    @pytest.mark.parametrize("dtype,rows", [("bf16", 8), ("f32", 8)])
+    def test_a_table_miss_keeps_the_rings_constants(self, dtype, rows):
+        """No entry for the shape and nothing asked: ``block_k`` 512 and a
+        want of eight heads, of which ten cached heads take two — what
+        ``SelfAttention``'s dense-cache decode and every caller before PR
+        58 got."""
+        from deepspeed_tpu.ops.pallas import decode_attention
+        q, k, v, _, _, lens = self._ring()
+        if dtype == "f32":
+            k, v = k.astype(jnp.float32), v.astype(jnp.float32)
+        tuning.clear_last_dispatch()
+        decode_attention(q, k, v, lens)
+        rec = tuning.last_dispatch("decode_attention")["dma"]
+        assert rec["key"] == ("decode_attention/dma/sq4_sk512_d128_"
+                              f"{k.dtype.name}_causal")
+        assert (rec["source"], rec["block_k"], rec["head_block"],
+                rec["rows"]) == ("constants", 512, 2, rows)
+        # sixteen ungrouped heads: the constant's eight, as before
+        decode_attention(q[:, :, :16], k[:, :8].repeat(2, axis=1),
+                         v[:, :8].repeat(2, axis=1), lens)
+        rec = tuning.last_dispatch("decode_attention")["dma"]
+        assert (rec["block_k"], rec["head_block"], rec["rows"]) == (512, 8, 8)
+
+    def test_the_rings_entry_is_consumed_and_wins_over_the_caller(self):
+        from deepspeed_tpu.ops.pallas import decode_attention
+        q, k, v, _, _, lens = self._ring()
+        key = tuning.make_key("decode_attention", "dma", sq=4, sk=512,
+                              d=self.D, dtype=jnp.bfloat16, causal=True)
+        want = decode_attention(q, k, v, lens, block_k=256, head_block=1)
+        tuning.clear_last_dispatch()
+        with tuning.tuning_table({key: {"block_k": 256, "head_block": 10}}):
+            got = decode_attention(q, k, v, lens, block_k=128, head_block=2)
+            rec = tuning.last_dispatch("decode_attention")["dma"]
+        assert (rec["key"], rec["source"], rec["block_k"], rec["head_block"],
+                rec["rows"]) == (key, "runtime", 256, 10, 40)
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+    @pytest.mark.parametrize("kernel,shape", [
+        ("paged_attention/page128", (64, 32)),
+        ("decode_attention/dma", (64, 512))])
+    def test_the_cells_shapes_are_in_the_committed_table(self, kernel, shape):
+        """``serve-phi4flash-reason``: 64 slots, 32 pages of 128 a row
+        over ten bf16 heads of 128, and rings of 512 — both keys are in
+        ``flash_tuning_defaults.json`` with what was swept on the v5e (PR
+        58), and the dispatch records say so."""
+        from deepspeed_tpu.ops.pallas import decode_attention
+        S, bf = jax.ShapeDtypeStruct, jnp.bfloat16
+        slots = shape[0]
+        q = S((slots, 1, 40, 128), bf)
+        tuning.clear_last_dispatch()
+        if kernel.startswith("paged"):
+            pool = S((slots * shape[1] + 1, 10, 128, 128), bf)
+            new = S((slots, 10, 128, 1), bf)
+            jax.eval_shape(lambda *a: paged_attention(*a, impl="kernel"),
+                           q, pool, pool, S(shape, jnp.int32),
+                           S((slots,), jnp.int32), new, new)
+            rec = tuning.last_dispatch(KERNEL)["page128"]
+        else:
+            ring = S((slots, 10, 128, shape[1]), bf)
+            jax.eval_shape(decode_attention, q, ring, ring,
+                           S((slots,), jnp.int32))
+            rec = tuning.last_dispatch("decode_attention")["dma"]
+        entry = tuning.load_artifact(tuning.DEFAULTS_PATH)["entries"][
+            rec["key"]]
+        assert rec["key"].startswith(kernel + "/sq64_sk")
+        assert rec["source"] == "defaults" and rec["products"] == "bfloat16"
+        assert rec["head_block"] in (5, 10)
+        assert rec["rows"] == 4 * rec["head_block"]
+        assert (rec["block_k"], rec["head_block"]) == (
+            entry["block_k"], entry["head_block"])
+        assert entry["device"] == "TPU v5 lite" and "PR 58" in entry["note"]
+
+
 class TestTuningDispatch:
     def test_runtime_table_entry_consumed(self):
         """The shape-keyed tuning cache resolves the kernel's blocks at

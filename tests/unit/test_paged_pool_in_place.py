@@ -242,11 +242,13 @@ print(json.dumps({"record": rec, "mosaic_calls": compiled.as_text().count(
     ((32, 16, 16, 128, 24, 16), 16, 16, "defaults"),
     # serve-phi4flash-reason: 64 rows, differential attention's 40 query
     # rows on ten cached heads of 128 (two halves of 64 stacked), one
-    # layer's pool of 32 pages a row: ten heads take two a step (the head
-    # blocks that ran compiled are 1, 2, 4, 8, 16), eight rows
-    ((64, 40, 10, 128, 1, 32), 2, 8, "constants"),
+    # layer's pool of 32 pages a row: all ten heads one grid step, 40
+    # rows, at the table's block of one page (PR 58; two a step before)
+    ((64, 40, 10, 128, 1, 32), 10, 40, "defaults"),
+    # the same heads at another slot count, where no entry asks: two
+    ((32, 40, 10, 128, 1, 32), 2, 8, "constants"),
 ], ids=["falcon-h1-20on4", "32on8-d64", "40on8", "olmoe-16", "gpt2-1p3b-16",
-        "phi4flash-40on10"])
+        "phi4flash-40on10", "40on10-unswept"])
 def test_bf16_steps_of_many_rows_compile_for_the_chip(
         shape, head_block, rows, source):
     import json
@@ -825,11 +827,14 @@ def test_phi4flash_decode_program_keeps_pages_rings_and_states_in_place(
     tuning.clear_last_dispatch()
     compiled = _compile_decode(model, args, static)
     rec = tuning.last_dispatch("paged_attention")["page%d" % PAGE_LEN]
-    assert (rec["impl"], rec["head_block"], rec["rows"], rec["products"]) \
-        == ("kernel", 2, 8, "bfloat16")
+    assert (rec["impl"], rec["head_block"], rec["rows"], rec["products"],
+            rec["block_k"], rec["source"]) \
+        == ("kernel", 10, 40, "bfloat16", 128, "defaults")
     ring, = tuning.last_dispatch("decode_attention").values()
-    assert (ring["impl"], ring["key"], ring["head_block"]) \
-        == ("kernel", "b64_h40_d128_s512", 2)
+    assert (ring["impl"], ring["key"], ring["head_block"], ring["rows"],
+            ring["block_k"], ring["source"]) \
+        == ("kernel", "decode_attention/dma/sq64_sk512_d128_bfloat16_causal",
+            10, 40, 128, "defaults")
     write, = tuning.last_dispatch("ring_append").values()
     assert (write["impl"], write["key"], write["tile"]) \
         == ("kernel", "b64_h10_d128_w512_bfloat16", 128)
